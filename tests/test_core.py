@@ -442,3 +442,18 @@ def test_exponential_key_sampler(rng):
     assert float(jnp.min(out)) >= 0.0
     with pytest.raises(Exception, match="rng key"):
         tt.jit(lambda a: ltorch.exponential(a, 2.0))(jnp.ones((4,)))
+
+
+def test_device_resolves_only_to_what_is_there():
+    """Device.jax_device() names a device this process has or raises: no CPU
+    stand-in for a missing TPU, no clamping of an index past the last device."""
+    import jax
+
+    from thunder_tpu.core import devices
+
+    assert devices.Device("cpu:0").jax_device() == jax.devices("cpu")[0]
+    if jax.devices()[0].platform == "cpu":
+        with pytest.raises(RuntimeError, match="tpu"):
+            devices.Device("tpu:0").jax_device()
+    with pytest.raises(RuntimeError, match="cpu"):
+        devices.Device("cpu", len(jax.devices("cpu"))).jax_device()

@@ -205,8 +205,8 @@ def test_seeded_sampling_reproducible_and_per_seed(rng):
 
 def _paged_fixture(rng, B=3, H=4, Hkv=2, D=16, ps=8, P=12, npm=4, dtype=jnp.float32):
     """Random pool + ragged page tables, incl. partially-filled last pages."""
-    k_pages = jnp.asarray(rng.randn(P, ps, Hkv, D), dtype)
-    v_pages = jnp.asarray(rng.randn(P, ps, Hkv, D), dtype)
+    k_pages = jnp.asarray(rng.randn(P, Hkv, ps, D), dtype)
+    v_pages = jnp.asarray(rng.randn(P, Hkv, ps, D), dtype)
     seq_lens = np.asarray([5, 17, 24], np.int32)  # partial, partial, full
     pt = np.zeros((B, npm), np.int32)
     pt[0, :1] = [3]
@@ -222,7 +222,7 @@ def _dense_from_pages(q, k_pages, v_pages, pt, seq_lens):
     from thunder_tpu.inference import cached_sdpa
 
     B, H, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     g = H // Hkv
     dense = tt.jit(lambda q4, k4, v4, pos: cached_sdpa(q4, k4, v4, pos))
     outs = []
@@ -230,10 +230,11 @@ def _dense_from_pages(q, k_pages, v_pages, pt, seq_lens):
         L = int(seq_lens[b])
         npg = -(-L // ps)
         row = np.asarray(pt)[b, :npg]
-        k = np.asarray(k_pages)[row].reshape(npg * ps, Hkv, D)[:L]
-        v = np.asarray(v_pages)[row].reshape(npg * ps, Hkv, D)[:L]
-        k = jnp.asarray(np.repeat(k.transpose(1, 0, 2), g, 0)[None])  # (1, H, L, D)
-        v = jnp.asarray(np.repeat(v.transpose(1, 0, 2), g, 0)[None])
+        # head-major pages (npg, Hkv, ps, D) -> (Hkv, npg*ps, D)
+        k = np.asarray(k_pages)[row].transpose(1, 0, 2, 3).reshape(Hkv, npg * ps, D)[:, :L]
+        v = np.asarray(v_pages)[row].transpose(1, 0, 2, 3).reshape(Hkv, npg * ps, D)[:, :L]
+        k = jnp.asarray(np.repeat(k, g, 0)[None])  # (1, H, L, D)
+        v = jnp.asarray(np.repeat(v, g, 0)[None])
         q4 = jnp.asarray(np.asarray(q)[b][None, :, None, :])  # (1, H, 1, D)
         # the query is the LAST cached token: cached_sdpa's mask needs its
         # position, L-1
@@ -279,6 +280,24 @@ def test_paged_attention_kernel_bf16_tolerance(rng):
     np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
 
 
+def test_paged_chunk_kernel_matches_reference(rng):
+    """The multi-query paged kernel (interpret mode) == the
+    ltorch.paged_chunk_attention gather decomposition, GQA with ragged
+    per-query positions (chunk rows and speculative-verify rows)."""
+    from thunder_tpu.executors.pallasex import paged_chunk_decode
+    from thunder_tpu.ops import ltorch
+
+    q1, kp, vp, pt, _ = _paged_fixture(rng)
+    B, H, D = q1.shape
+    T = 4
+    q = jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
+    q_pos = jnp.asarray([[1, 2, 3, 4], [13, 14, 15, 16], [20, 21, 22, 23]], jnp.int32)
+    ref = tt.jit(lambda q, kp, vp, pt, qp: ltorch.paged_chunk_attention(q, kp, vp, pt, qp))
+    out = np.asarray(paged_chunk_decode(q, kp, vp, pt, q_pos, interpret=True))
+    np.testing.assert_allclose(out, np.asarray(ref(q, kp, vp, pt, q_pos)),
+                               atol=2e-5, rtol=2e-5)
+
+
 def test_paged_attention_vmem_fallback_declines():
     """The ADVICE VMEM-estimation pattern: a page_size x D working set over
     the budget makes the checker decline (the jax gather decomposition runs
@@ -294,8 +313,8 @@ def test_paged_attention_vmem_fallback_declines():
             self.dtype = dtype
 
     q = _P((2, 4, 512))
-    small = _P((8, 32, 2, 512))
-    huge = _P((8, 8192, 2, 512))  # 2 * 2 * 8192*512*4B ≈ 67 MB of k/v blocks
+    small = _P((8, 2, 32, 512))
+    huge = _P((8, 2, 8192, 512))  # 2 * 2 * 8192*512*4B ≈ 67 MB of k/v blocks
     pt = _P((2, 4), "int32")
     sl = _P((2,), "int32")
     os.environ["TT_PAGED_KERNEL"] = "1"

@@ -8,6 +8,7 @@ import importlib.util
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -141,6 +142,15 @@ class TestCostModel:
         t = TensorProxy(name="t0", shape=(4,), dtype=dtypes.float32, device="cpu")
         b = BoundSymbol(sym, (t,), {}, t)
         assert obs_flops.bsym_cost(b) == {"flops": 123.0, "bytes": 456}
+
+    def test_peaks_table_has_no_default(self):
+        """One table keyed by device_kind; a device that is not in it is an
+        error, not a v5e."""
+        assert obs_flops.device_peaks("TPU v5 lite") == (197.0, 819.0)
+        assert obs_flops.device_peaks() == obs_flops.DEVICE_PEAKS[
+            jax.devices()[0].device_kind]
+        with pytest.raises(KeyError, match="TPU v9"):
+            obs_flops.device_peaks("TPU v9")
 
     def test_roofline_tags(self):
         peaks = (100.0, 100.0)  # ridge = 1000 flops/byte

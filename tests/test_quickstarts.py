@@ -116,3 +116,22 @@ def test_perf_gate_checks_committed_artifacts(artifact):
     assert out.returncode == 0, (
         f"perf_gate --check {artifact} failed:\n{out.stdout}\n{out.stderr}")
     assert "perf gate: ok" in out.stdout
+
+
+def test_chip_smoke_refuses_without_a_tpu(tmp_path):
+    """chip_smoke.py's contract off the chip: with no TPU it refuses to run —
+    non-zero exit, the platform it found named, no result line — also from a
+    directory that holds the script and nothing else of the repo. What it
+    does on a TPU is the chip run itself (README, "Testing")."""
+    import json
+    import shutil
+
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "chip_smoke.py"], cwd=tmp_path, env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode not in (0, None), out.stdout
+    assert "platform: cpu" in out.stdout and "refusing to run" in out.stdout
+    with pytest.raises(json.JSONDecodeError):
+        json.loads(out.stdout.strip().splitlines()[-1])

@@ -48,13 +48,9 @@ from .ops import ltorch  # noqa: E402  (registers tensor methods)
 from .ops import clang  # noqa: E402
 from .ops import auto_register  # noqa: E402  (registers fallback op catalog)
 
-try:
-    from .executors import pallasex  # noqa: E402
-    _pallas_exs = [pallasex.ex]
-except Exception:  # pallas unavailable on this backend
-    _pallas_exs = []
+from .executors import pallasex  # noqa: E402
 
-set_default_executors(_pallas_exs + [xlaex.ex])
+set_default_executors([pallasex.ex, xlaex.ex])
 
 # persistent XLA compile cache: warm processes skip the multi-second
 # whole-step compile. Enabled lazily at the first jit() call so the backend

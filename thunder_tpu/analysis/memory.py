@@ -100,12 +100,15 @@ def grouped_mlp_vmem_bytes(block_c: int, D: int, H: int,
                            w_itemsize: int, x_itemsize: int) -> int:
     """Estimated per-program VMEM working set of the grouped-expert MLP
     kernel (pallasex `_grouped_mlp_kernel`): one expert's three weight
-    panels, a (block_c, D) token-bin block, the fused f32 SwiGLU
-    intermediates (gate/up/hidden), and the output block."""
-    w = 3 * D * H * w_itemsize              # w_gate + w_up + w_down(T) panels
-    xb = block_c * D * x_itemsize           # input bin block
+    panels, a (block_c, D) token-bin block and the output block — two
+    buffers each, as Mosaic's pipeline allocates them (counted once, the
+    estimate let d 1024 x h 2816 bf16 through, which the compiler refuses:
+    34.00M against the 16.00M scoped limit) — and the fused f32 SwiGLU
+    intermediates (gate/up/hidden)."""
+    w = 2 * 3 * D * H * w_itemsize          # w_gate + w_up + w_down(T) panels
+    xb = 2 * block_c * D * x_itemsize       # input bin block
     inter = block_c * (3 * H) * 4           # g, u, h in f32
-    out = block_c * D * x_itemsize          # output bin block
+    out = 2 * block_c * D * x_itemsize      # output bin block
     return w + xb + inter + out
 
 

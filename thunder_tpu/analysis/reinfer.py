@@ -233,7 +233,7 @@ def _cat(bsym):
 
 
 @rule(PrimIDs.DYNAMIC_UPDATE_SLICE, PrimIDs.SCATTER, PrimIDs.SCATTER_ADD,
-      PrimIDs.INDEX_ADD, PrimIDs.COPY_WITH_SETITEM)
+      PrimIDs.INDEX_ADD, PrimIDs.INDEX_COPY, PrimIDs.COPY_WITH_SETITEM)
 def _same_as_first(bsym):
     a = _tmeta(bsym.args[0]) if bsym.args else None
     return [(a.shape, a.dtype)] if a else None

@@ -25,13 +25,18 @@ import sys
 import time
 
 
-def run_targets() -> list[dict]:
+_TARGETS = ("llama2_7b_attention", "llama_mlp_7b", "litgpt_qkv_rope")
+
+
+def _measure_targets() -> list[dict]:
+    """Runs in the child started by run_targets: this is the process that
+    initialises JAX and holds the chip while it measures."""
     import numpy as np
 
     from . import targets
 
     rows = []
-    for name in ("llama2_7b_attention", "llama_mlp_7b", "litgpt_qkv_rope"):
+    for name in _TARGETS:
         t0 = time.perf_counter()
         seconds = targets.BENCHMARKS[name](np.random.RandomState(0))
         rows.append({
@@ -40,6 +45,17 @@ def run_targets() -> list[dict]:
             "wall_s": round(time.perf_counter() - t0, 1),
         })
     return rows
+
+
+def run_targets() -> list[dict]:
+    """The microbench targets, in a child of their own: a chip belongs to one
+    process at a time, and this parent goes on to start bench.py children
+    that need it, so the parent itself never initialises JAX."""
+    out = subprocess.run([sys.executable, "-m", "thunder_tpu.benchmarks.bench_7b", "targets"],
+                         capture_output=True, text=True, timeout=3600)
+    if out.returncode != 0:
+        raise RuntimeError(f"7B-shape targets failed: {out.stderr[-800:]}")
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def run_block_stack(B: int = 1, T: int = 2048, iters: int = 10) -> dict:
@@ -61,6 +77,9 @@ def run_block_stack(B: int = 1, T: int = 2048, iters: int = 10) -> dict:
 
 
 def main() -> None:
+    if sys.argv[1:] == ["targets"]:
+        print(json.dumps(_measure_targets()))
+        return
     result = {
         "comment": ("7B-shape single-chip evidence: per-layer dims are exactly "
                     "Llama-2-7B's (width 4096, head_dim 128, MLP 11008, vocab 32k); "

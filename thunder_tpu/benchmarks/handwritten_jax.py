@@ -82,15 +82,6 @@ def rope_cache(cfg, dtype=jnp.float32):
 # --------------------------------------------------------------------------
 
 
-def _library_flash_attention():
-    """jax's shipped TPU flash-attention kernel, if importable."""
-    try:
-        from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
-        return flash_attention
-    except Exception:
-        return None
-
-
 def _norm_f(cfg, x, w, b, eps):
     x32 = x.astype(jnp.float32)
     if cfg.norm_class_name == "RMSNorm":
@@ -158,19 +149,20 @@ def _block_body(cfg, params, blk, w, cos_t, sin_t, compute_dtype, B, T, x):
     if ng != nh:
         k = jnp.repeat(k, q_per_kv, axis=1)
         v = jnp.repeat(v, q_per_kv, axis=1)
-    # the attention a jax user writes today, strongest available first:
-    # jax's library pallas flash kernel (the composite materializes
-    # B·H·T² probabilities for backward — OOM at llama-350m B=4 T=2048
-    # on one 16 GB chip), then the fused composite, then manual softmax
-    lib_flash = _library_flash_attention()
+    # the attention a jax user writes today: jax's library pallas flash
+    # kernel where the composite would materialize B·H·T² probabilities for
+    # backward (OOM at llama-350m B=4 T=2048 on one 16 GB chip), the fused
+    # composite elsewhere
     score_bytes = B * nh * T * T * 2
     big_attention = T >= 4096 or (T >= 2048 and score_bytes >= 256 * 2**20)
-    if lib_flash is not None and big_attention and T % 128 == 0 and hs >= 64:
-        y = lib_flash(q.astype(compute_dtype), k.astype(compute_dtype),
-                      v.astype(compute_dtype), causal=True,
-                      sm_scale=1.0 / math.sqrt(hs))
+    if big_attention and T % 128 == 0 and hs >= 64:
+        from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
+
+        y = flash_attention(q.astype(compute_dtype), k.astype(compute_dtype),
+                            v.astype(compute_dtype), causal=True,
+                            sm_scale=1.0 / math.sqrt(hs))
         y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
-    elif hasattr(jax.nn, "dot_product_attention"):
+    else:
         # rope promotes q/k to f32 (f32 cos/sin); the composite requires
         # uniform dtypes
         y = jax.nn.dot_product_attention(
@@ -179,14 +171,6 @@ def _block_body(cfg, params, blk, w, cos_t, sin_t, compute_dtype, B, T, x):
             v.astype(compute_dtype).transpose(0, 2, 1, 3),
             scale=1.0 / math.sqrt(hs), is_causal=True)
         y = y.reshape(B, T, nh * hs)
-    else:
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                       preferred_element_type=jnp.float32) / math.sqrt(hs)
-        mask = jnp.tril(jnp.ones((T, T), bool))
-        s = jnp.where(mask, s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(compute_dtype)
-        y = jnp.einsum("bhqk,bhkd->bhqd", p, v)
-        y = y.transpose(0, 2, 1, 3).reshape(B, T, nh * hs)
     y = y @ w(f"{blk}.attn.proj.weight").T
     if f"{blk}.attn.proj.bias" in params:
         y = y + w(f"{blk}.attn.proj.bias")

@@ -8,7 +8,8 @@ the host-eager path (the coverage signal: a fallback is correct but slow).
 
 Usage:
     python -m thunder_tpu.benchmarks.hf_coverage [--models gpt2,llama,...]
-    # writes HF_COVERAGE.md at the repo root with the report table
+    # writes the report table to --out (default HF_COVERAGE.md: a generated
+    # file, not committed)
 """
 from __future__ import annotations
 

@@ -162,18 +162,15 @@ def maybe_prewarm(trace, *, where: str = "") -> Optional[dict]:
     unless parallel compilation is enabled; never raises."""
     if not parallel_compile_enabled():
         return None
-    try:
-        # under an ambient jax trace (a ThunderValueAndGrad compiling inside
-        # TrainStep's whole-step jax.jit, a shard_map body) the regions will
-        # be INLINED into the outer program — a standalone region executable
-        # would never be dispatched, so compiling one is pure cold-start
-        # overhead (and the whole-step artifact already covers that path)
-        from jax.core import trace_state_clean
+    import jax
 
-        if not trace_state_clean():
-            return None
-    except ImportError:
-        pass
+    # under an ambient jax trace (a ThunderValueAndGrad compiling inside
+    # TrainStep's whole-step jax.jit, a shard_map body) the regions will
+    # be INLINED into the outer program — a standalone region executable
+    # would never be dispatched, so compiling one is pure cold-start
+    # overhead (and the whole-step artifact already covers that path)
+    if not jax.core.trace_ctx.is_top_level():
+        return None
     try:
         return prewarm_regions(trace, where=where)
     except Exception:

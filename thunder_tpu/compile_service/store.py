@@ -60,31 +60,28 @@ _LOCK_STALE_S = 120.0
 
 def store_dir() -> str:
     """Store root: TT_ARTIFACT_DIR, else the legacy TT_AOT_CACHE_DIR (the
-    aot shim's entries live in the same store), else ~/.cache/thunder_tpu/
-    artifacts."""
-    d = (os.environ.get("TT_ARTIFACT_DIR")
-         or os.environ.get("TT_AOT_CACHE_DIR")
-         or os.path.join(os.path.expanduser("~"), ".cache", "thunder_tpu",
-                         "artifacts"))
-    return d
+    aot shim's entries live in the same store), else ``artifacts`` under the
+    compile-cache root (utils/compile_cache.py: $JAX_COMPILATION_CACHE_DIR,
+    else the checkout's ``.tt_cache``)."""
+    from ..utils.compile_cache import cache_root
+
+    return (os.environ.get("TT_ARTIFACT_DIR")
+            or os.environ.get("TT_AOT_CACHE_DIR")
+            or os.path.join(cache_root(), "artifacts"))
 
 
 def store_enabled() -> bool:
-    """The store is on when a directory is named explicitly (ANY backend —
-    the old CPU-off-by-default heuristic only applies to the implicit
-    default dir, where XLA:CPU executables are machine-specific and cheap
-    to rebuild)."""
+    """The store is on when a directory is named explicitly (ANY backend);
+    in the default directory only on a non-CPU backend, where XLA:CPU
+    executables are machine-specific and cheap to rebuild."""
     if (os.environ.get("TT_NO_ARTIFACT_STORE") == "1"
             or os.environ.get("TT_NO_AOT_CACHE") == "1"):
         return False
     if os.environ.get("TT_ARTIFACT_DIR") or os.environ.get("TT_AOT_CACHE_DIR"):
         return True
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
+    return jax.default_backend() != "cpu"
 
 
 def environment_fingerprint() -> dict:
@@ -274,12 +271,20 @@ class ArtifactStore:
     # -- executables (serialize_executable payloads) --
     def put_executable(self, key: str, compiled, *, kind: str = "step",
                        meta: Optional[dict] = None) -> bool:
-        """Serialize a jax ``Compiled`` and publish it; False on failure."""
-        try:
-            from jax.experimental import serialize_executable as se
+        """Serialize a jax ``Compiled`` and publish it; False when the
+        backend cannot serialize it or its trees do not pickle."""
+        import jax
+        from jax.experimental import serialize_executable as se
 
-            payload = pickle.dumps(se.serialize(compiled))
-        except Exception:
+        try:
+            # with the number of devices it runs on: deserialize_and_load
+            # otherwise loads it across every device of the process, and a
+            # one-device program then fails at its first call on any host
+            # that has more ("Expected args ... to have N shards")
+            n_devices = len(compiled.runtime_executable().local_devices())
+            payload = pickle.dumps(se.serialize(compiled) + (n_devices,))
+        except (ValueError, TypeError, AttributeError, pickle.PicklingError,
+                jax.errors.JaxRuntimeError):
             return False
         return self.put_bytes(key, payload, kind=kind, meta=meta)
 
@@ -292,10 +297,13 @@ class ArtifactStore:
             return None
         payload, _ = got
         try:
+            import jax
             from jax.experimental import serialize_executable as se
 
-            serialized, in_tree, out_tree = pickle.loads(payload)
-            return se.deserialize_and_load(serialized, in_tree, out_tree)
+            serialized, in_tree, out_tree, n_devices = pickle.loads(payload)
+            return se.deserialize_and_load(
+                serialized, in_tree, out_tree,
+                execution_devices=jax.devices()[:n_devices])
         except Exception:
             # digest-valid but undeserializable here (other machine/ABI):
             # evict so the directory doesn't accumulate unusable entries

@@ -107,6 +107,7 @@ class PrimIDs(Enum):
     GROUPED_MM = auto()
     EINSUM = auto()
     SCATTER = auto()
+    INDEX_COPY = auto()
     # memory / interop
     ITEM = auto()
     COPY_WITH_SETITEM = auto()
@@ -887,6 +888,17 @@ def _scatter_meta(a, indices, value, dim):
 
 
 scatter = make_prim(PrimIDs.SCATTER, "scatter", _scatter_meta)
+
+
+def _index_copy_meta(a, indices, value, dim):
+    """Whole-slice write: a with value's slices copied in at positions
+    `indices` (1-D) along dim — a row scatter, where `scatter` writes element
+    by element (on a TPU that difference is a compile of seconds and a run
+    of serialized scalar updates against one windowed DMA per slice)."""
+    return TensorProxy(shape=a.shape, dtype=a.dtype, device=a.device)
+
+
+index_copy = make_prim(PrimIDs.INDEX_COPY, "index_copy", _index_copy_meta)
 
 
 # ---------------------------------------------------------------------------

@@ -2,15 +2,20 @@
 
 The reference delegates data loading to torch DataLoader workers; here the
 host-side batch assembly is a small C++ library (native/loader.cpp) compiled
-on first use, with a pure-numpy fallback when no compiler is available.
+on first use, with a pure-numpy loader (and a warning) where it cannot be
+built; ``TokenLoader.backend`` says which one is in use.
 Batches are (B, T+1) int32: inputs = batch[:, :-1], targets = batch[:, 1:]."""
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import queue
 import subprocess
 import threading
+import warnings
 from typing import Optional
 
 import numpy as np
@@ -19,32 +24,50 @@ from .prefetch import (DevicePrefetchIterator, _drain_and_join,  # noqa: F401
                        _stop_aware_put, prefetch_to_device)
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libttloader.so")
 _CPP_PATH = os.path.join(_NATIVE_DIR, "loader.cpp")
 _build_lock = threading.Lock()
 
 
+def _so_path() -> str:
+    """The library is named after the sha256 of the source it was built
+    from: a binary from another loader.cpp (an older checkout, a copied
+    tree whose mtimes mean nothing) is never loaded, it is rebuilt."""
+    with open(_CPP_PATH, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_NATIVE_DIR, f"libttloader-{digest}.so")
+
+
 def _build_native() -> Optional[str]:
+    """Path of the native library for this loader.cpp, built on first use;
+    None (with a warning that says why) when it cannot be built here."""
     with _build_lock:
-        if os.path.exists(_SO_PATH) and os.path.getmtime(_SO_PATH) >= os.path.getmtime(_CPP_PATH):
-            return _SO_PATH
+        so = _so_path()
+        if os.path.exists(so):
+            return so
         # compile to a pid-unique temp path and rename atomically so a
         # concurrent process never dlopens a half-written .so
-        tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
+        tmp = f"{so}.{os.getpid()}.tmp"
         try:
             subprocess.run(
                 ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
                  _CPP_PATH, "-o", tmp],
                 check=True, capture_output=True, timeout=120,
             )
-            os.replace(tmp, _SO_PATH)
-            return _SO_PATH
-        except Exception:
-            try:
+            os.replace(tmp, so)
+        except (OSError, subprocess.CalledProcessError, subprocess.TimeoutExpired) as e:
+            with contextlib.suppress(OSError):
                 os.unlink(tmp)
-            except OSError:
-                pass
+            detail = getattr(e, "stderr", b"") or b""
+            warnings.warn(
+                f"native token loader not built ({type(e).__name__}: {e}"
+                f"{detail.decode(errors='replace')[-300:]}); TokenLoader uses the "
+                f"numpy loader", stacklevel=3)
             return None
+        for stale in glob.glob(os.path.join(_NATIVE_DIR, "libttloader*.so")):
+            if stale != so:
+                with contextlib.suppress(OSError):
+                    os.unlink(stale)
+        return so
 
 
 def _fallback_worker(tokens: np.ndarray, rng, batch_size: int, span: int,
@@ -97,7 +120,7 @@ class TokenLoader:
     """Random-offset (B, T+1) batch sampler over a binary token file.
 
     next_batch() -> (inputs (B,T) int32, targets (B,T) int32) numpy arrays.
-    Uses the native prefetching loader when g++ is available."""
+    Uses the native prefetching loader where it builds (``backend``)."""
 
     def __init__(self, path: str, batch_size: int, seq_len: int, *, token_bytes: int = 2,
                  seed: int = 0, n_threads: int = 2, queue_depth: int = 4, native: bool = True):
@@ -158,6 +181,11 @@ class TokenLoader:
     @property
     def is_native(self) -> bool:
         return self._handle is not None
+
+    @property
+    def backend(self) -> str:
+        """Which loader serves the batches: "native" (loader.cpp) or "numpy"."""
+        return "native" if self.is_native else "numpy"
 
     @property
     def num_tokens(self) -> int:

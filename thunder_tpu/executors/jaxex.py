@@ -120,13 +120,15 @@ _reg(PrimIDs.TAKE, lambda a, indices, dim: jnp.take(a, indices, axis=dim))
 _reg(PrimIDs.TAKE_ALONG_AXIS, lambda a, indices, dim: jnp.take_along_axis(a, indices, axis=dim))
 
 
-def _index_add(a, indices, value, dim):
+def _at_dim(a, indices, dim):
+    """a.at[:, ..., indices, ...] with `indices` along dim."""
     idx = [builtins.slice(None)] * a.ndim
     idx[dim] = indices
-    return a.at[tuple(idx)].add(value)
+    return a.at[tuple(idx)]
 
 
-_reg(PrimIDs.INDEX_ADD, _index_add)
+_reg(PrimIDs.INDEX_ADD, lambda a, indices, value, dim: _at_dim(a, indices, dim).add(value))
+_reg(PrimIDs.INDEX_COPY, lambda a, indices, value, dim: _at_dim(a, indices, dim).set(value))
 
 
 def _scatter_add(a, indices, value, dim):

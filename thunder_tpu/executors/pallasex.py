@@ -22,15 +22,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific pallas namespace; absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core import dtypes
 from ..core.proxies import TensorProxy
 from ..core.symbol import OpTags, Symbol
 from ..extend import OperatorExecutor, register_executor
+from ..observability import events as _obs
 
 ex = OperatorExecutor("pallas")
 register_executor(ex)
@@ -48,6 +46,11 @@ _GQA_BLOCK_K = int(os.environ.get("TT_FLASH_GQA_BLOCK_K", "512"))
 # 4.52/3.24/3.42 two-pass; 1024-row q blocks blow the 16 MB VMEM limit)
 _FUSED_BLOCK_Q = int(os.environ.get("TT_FLASH_FUSED_BLOCK_Q", "512"))
 _FUSED_BLOCK_K = int(os.environ.get("TT_FLASH_FUSED_BLOCK_K", "512"))
+# scoped VMEM the single-pass backward asks Mosaic for: it keeps whole-length
+# K/V blocks and two (Tk, D) f32 accumulators resident, which at a q group of
+# 4 (llama-350m width, T=2048) is 16.23 MiB — over the compiler's 16 MiB
+# default. Half of a v5e core's 128 MiB.
+_FUSED_BWD_VMEM_LIMIT = 64 * 2**20
 
 
 def _cap_blocks_for_dtype(q, block_q: int, block_k: int, T: int, Tk: int, *extra):
@@ -65,11 +68,16 @@ LOG2E = 1.4426950408889634  # 1/ln 2: base-2 softmax folds this into the scale
 LN2 = 0.6931471805599453
 
 
+def _decline(kernel: str, reason: str) -> bool:
+    """A checker's "no" to a shape it would otherwise claim on this device,
+    counted (``pallas.decline.<kernel>.<reason>``): the op then runs its
+    pure-jax decomposition, and the bus says so."""
+    _obs.inc(f"pallas.decline.{kernel}.{reason}")
+    return False
+
+
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform != "cpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def _interpret() -> bool:
@@ -376,7 +384,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fused_bwd_enabled() -> bool:
-    return pltpu is not None and os.environ.get("TT_FLASH_TWO_PASS_BWD", "0") != "1"
+    return os.environ.get("TT_FLASH_TWO_PASS_BWD", "0") != "1"
 
 
 def _flash_backward_fused(q, k, v, do, lse4, delta4, *, causal, scale,
@@ -415,6 +423,7 @@ def _flash_backward_fused(q, k, v, do, lse4, delta4, *, causal, scale,
         ],
         scratch_shapes=[pltpu.VMEM((Tk, D), jnp.float32),
                         pltpu.VMEM((Tk, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT),
         interpret=_interpret(),
     )(qg, k, v, dog, lseg, deltag)
     return dq.reshape(B, H, T, D), dk, dv
@@ -464,18 +473,16 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool = True, scale=
         interpret=_interpret(),
     )(q, k, v, do, lse4, delta4)
 
-    if g == 1 or pltpu is None:
+    if g == 1:
         # MHA fast path: full-T q/do resident per program (measured faster
-        # than the streaming grid at llama-350m shapes). Also the GQA route
-        # when the TPU pallas namespace is unavailable (no VMEM scratch for
-        # the streaming kernel): per-q-head dk/dv, group-summed below.
+        # than the streaming grid at llama-350m shapes)
         dk, dv = pl.pallas_call(
             functools.partial(_flash_bwd_dkv_kernel_mha, block_q=block_q, causal=causal, scale=scale),
             grid=(B, H, Tk // block_k),
             in_specs=[
                 pl.BlockSpec((None, None, T, D), lambda b, h, j: (b, h, 0, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h // g, j, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h // g, j, 0)),
+                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
+                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
                 pl.BlockSpec((None, None, T, D), lambda b, h, j: (b, h, 0, 0)),
                 pl.BlockSpec((None, None, T, 1), lambda b, h, j: (b, h, 0, 0)),
                 pl.BlockSpec((None, None, T, 1), lambda b, h, j: (b, h, 0, 0)),
@@ -490,9 +497,6 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool = True, scale=
             ],
             interpret=_interpret(),
         )(q, k, v, do, lse4, delta4)
-        if g > 1:
-            dk = dk.reshape(B, Hkv, g, Tk, D).sum(2).astype(k.dtype)
-            dv = dv.reshape(B, Hkv, g, Tk, D).sum(2).astype(v.dtype)
         return dq, dk, dv
 
     # GQA: q heads grouped per kv head — view q/do/lse/delta as (B, Hkv, g, T, ...)
@@ -501,10 +505,8 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool = True, scale=
     lseg = lse4.reshape(B, Hkv, g, T, 1)
     deltag = delta4.reshape(B, Hkv, g, T, 1)
     n_i = T // block_q
-    scratch = []
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((block_k, D), jnp.float32),
-                   pltpu.VMEM((block_k, D), jnp.float32)]
+    scratch = [pltpu.VMEM((block_k, D), jnp.float32),
+               pltpu.VMEM((block_k, D), jnp.float32)]
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale, g=g, n_i=n_i),
         grid=(B, Hkv, Tk // block_k, n_i),
@@ -842,6 +844,7 @@ def _flash_rope_backward_fused(q, k, v, do, lse4, delta4, cos, sin, *, causal,
         ],
         scratch_shapes=[pltpu.VMEM((T, D), jnp.float32),
                         pltpu.VMEM((T, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT),
         interpret=_interpret(),
     )(qg, k, v, dog, lseg, deltag, cos, sin, cos, sin)
     return dq.reshape(B, H, T, D), dk, dv
@@ -896,16 +899,15 @@ def flash_rope_attention_backward(q, k, v, o, lse, cos, sin, do, *, causal: bool
         interpret=_interpret(),
     )(q, k, v, do, lse4, delta4, cos, sin, cos, sin)
 
-    if g == 1 or pltpu is None:
-        # MHA fast path (see flash_attention_backward); doubles as the GQA
-        # no-pltpu fallback — per-q-head dk/dv, group-summed below
+    if g == 1:
+        # MHA fast path (see flash_attention_backward)
         dk, dv = pl.pallas_call(
             functools.partial(_flash_rope_bwd_dkv_kernel_mha, block_q=block_q, causal=causal, scale=scale),
             grid=(B, H, T // block_k),
             in_specs=[
                 pl.BlockSpec((None, None, T, D), lambda b, h, j: (b, h, 0, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h // g, j, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h // g, j, 0)),
+                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
+                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
                 pl.BlockSpec((None, None, T, D), lambda b, h, j: (b, h, 0, 0)),
                 pl.BlockSpec((None, None, T, 1), lambda b, h, j: (b, h, 0, 0)),
                 pl.BlockSpec((None, None, T, 1), lambda b, h, j: (b, h, 0, 0)),
@@ -924,9 +926,6 @@ def flash_rope_attention_backward(q, k, v, o, lse, cos, sin, do, *, causal: bool
             ],
             interpret=_interpret(),
         )(q, k, v, do, lse4, delta4, cos, sin, cos, sin)
-        if g > 1:
-            dk = dk.reshape(B, Hkv, g, T, D).sum(2).astype(k.dtype)
-            dv = dv.reshape(B, Hkv, g, T, D).sum(2).astype(v.dtype)
         return dq, dk, dv
 
     qg = q.reshape(B, Hkv, g, T, D)
@@ -934,10 +933,8 @@ def flash_rope_attention_backward(q, k, v, o, lse, cos, sin, do, *, causal: bool
     lseg = lse4.reshape(B, Hkv, g, T, 1)
     deltag = delta4.reshape(B, Hkv, g, T, 1)
     n_i = T // block_q
-    scratch = []
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((block_k, D), jnp.float32),
-                   pltpu.VMEM((block_k, D), jnp.float32)]
+    scratch = [pltpu.VMEM((block_k, D), jnp.float32),
+               pltpu.VMEM((block_k, D), jnp.float32)]
     dk, dv = pl.pallas_call(
         functools.partial(_flash_rope_bwd_dkv_kernel, causal=causal,
                           scale=scale, g=g, n_i=n_i),
@@ -1459,7 +1456,10 @@ def fp8_linear_fused(x2d, w, sx, sw, *, fmt_max: float = 448.0,
     bn = math.gcd(block_n, N)
     bk = math.gcd(block_k, K)
     n_k = K // bk
-    scalar_spec = pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))
+    # scales in and amaxes out are scalars: SMEM (Mosaic has no scalar store
+    # to VMEM)
+    scalar_spec = pl.BlockSpec((1, 1), lambda i, j, k: (0, 0),
+                               memory_space=pltpu.SMEM)
     out_specs = [pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))]
     out_shape = [jax.ShapeDtypeStruct((M, N), x2d.dtype)]
     if save_quantized:
@@ -1482,7 +1482,7 @@ def fp8_linear_fused(x2d, w, sx, sw, *, fmt_max: float = 448.0,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] if pltpu is not None else [],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=_interpret(),
     )(x2d, w,
       jnp.asarray(sx, jnp.float32).reshape(1, 1),
@@ -1501,7 +1501,7 @@ def fp8_linear_fused_supported(x2d, w) -> bool:
     forced = os.environ.get("TT_FP8_FUSED", "") == "force"
     if not (_on_tpu() or forced):
         return False
-    if pltpu is None or getattr(x2d, "ndim", 0) != 2 or getattr(w, "ndim", 0) != 2:
+    if getattr(x2d, "ndim", 0) != 2 or getattr(w, "ndim", 0) != 2:
         return False
     M, K = x2d.shape
     N = w.shape[0]
@@ -1559,11 +1559,9 @@ def pack_nf4_kernel_layout(packed, absmax, shape, block_size: int = 64):
 def _nf4_codebook_floats():
     # python-float codebook, resolved OUTSIDE kernel tracing (pallas kernels
     # can neither capture array constants nor concretize values mid-trace)
-    import numpy as _np
-
     from ..transforms.quantization import NF4_CODE
 
-    return [float(v) for v in _np.asarray(NF4_CODE)]
+    return [float(v) for v in NF4_CODE]
 
 
 def _nf4_lookup(codes, vals):
@@ -1663,8 +1661,10 @@ ex.register_implementation("quant.linear_nf4_kl", _nf4_kl_impl,
 #
 # Continuous-batching decode attends ONE new token per sequence against a
 # block-paged KV pool (vLLM/PagedAttention, SOSP '23): k/v live in a fixed
-# (n_pages, page_size, Hkv, D) pool per layer and each sequence owns a row
-# of page ids. The kernel gathers a sequence's pages via the page table
+# head-major (n_pages, Hkv, page_size, D) pool per layer — one kv head's
+# page is a whole (page_size, D) block, which Mosaic's block rule needs —
+# and each sequence owns a row of page ids. The kernel gathers a
+# sequence's pages via the page table
 # INSIDE the pallas grid — the table rides as a scalar-prefetch operand so
 # the k/v BlockSpec index maps resolve page ids before each DMA — and runs
 # the flash kernel's online-softmax body (base-2 exp, f32 accumulation)
@@ -1737,14 +1737,14 @@ def _paged_attn_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, scale=None,
                            *, interpret: bool | None = None):
-    """q (B, H, D) against a paged pool (P, page_size, Hkv, D) through
+    """q (B, H, D) against a paged pool (P, Hkv, page_size, D) through
     page_table (B, n_pages_max) int32 / seq_lens (B,) int32 -> (B, H, D).
 
     seq_lens counts valid tokens INCLUDING the current one (whose k/v must
     already be written to its page). interpret=True runs the kernel in
     pallas interpret mode (the CPU equivalence tests)."""
     B, H, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     npm = page_table.shape[1]
     g = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -1754,8 +1754,8 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, scale=None
         grid=(B, Hkv, npm),
         in_specs=[
             pl.BlockSpec((None, None, g, D), lambda b, h, p, pt, sl: (b, h, 0, 0)),
-            pl.BlockSpec((None, ps, None, D), lambda b, h, p, pt, sl: (pt[b, p], 0, h, 0)),
-            pl.BlockSpec((None, ps, None, D), lambda b, h, p, pt, sl: (pt[b, p], 0, h, 0)),
+            pl.BlockSpec((None, None, ps, D), lambda b, h, p, pt, sl: (pt[b, p], h, 0, 0)),
+            pl.BlockSpec((None, None, ps, D), lambda b, h, p, pt, sl: (pt[b, p], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, g, D), lambda b, h, p, pt, sl: (b, h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((g, D), jnp.float32),
@@ -1777,8 +1777,6 @@ def paged_attention_supported(q, k_pages, v_pages, page_table, seq_lens, scale=N
     never claims); shapes must fit the page tiling and the estimated VMEM
     working set must stay under budget — otherwise the pure-jax gather
     decomposition runs."""
-    if pltpu is None:
-        return False
     override = os.environ.get("TT_PAGED_KERNEL")
     if override == "0":
         return False
@@ -1787,7 +1785,7 @@ def paged_attention_supported(q, k_pages, v_pages, page_table, seq_lens, scale=N
     if getattr(q, "ndim", 0) != 3 or getattr(k_pages, "ndim", 0) != 4:
         return False
     B, H, D = q.shape
-    P, ps, Hkv, Dk = k_pages.shape
+    P, Hkv, ps, Dk = k_pages.shape
     shapes_ok = (
         D == Dk and D <= 512
         and tuple(v_pages.shape) == tuple(k_pages.shape)
@@ -1802,8 +1800,10 @@ def paged_attention_supported(q, k_pages, v_pages, page_table, seq_lens, scale=N
 
     kv_item = jnp.dtype(str(k_pages.dtype).rpartition(".")[2]).itemsize
     q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
-    return _budget.within_vmem(_paged_vmem_bytes(ps, D, H // Hkv, kv_item, q_item),
-                               _budget.paged_vmem_limit())
+    if not _budget.within_vmem(_paged_vmem_bytes(ps, D, H // Hkv, kv_item, q_item),
+                               _budget.paged_vmem_limit()):
+        return _decline("paged_attention", "vmem")
+    return True
 
 
 def _paged_attention_impl(q, k_pages, v_pages, page_table, seq_lens, scale=None):
@@ -1823,25 +1823,24 @@ ex.register_implementation("thunder.paged_attention", _paged_attention_impl,
 # and the speculative-decoding verify step (T=k+1 proposals per packed
 # sequence), both with PER-QUERY causal coverage k_pos <= q_pos[b, t]. The
 # kernel is the decode kernel with the q group widened to (g*T, D) and the
-# per-query positions riding as a third scalar-prefetch operand for the
-# masking. Shared (copy-on-write) page tables are transparent: a physical
+# per-query positions riding as a (g*T, 1) VMEM column for the masking.
+# Shared (copy-on-write) page tables are transparent: a physical
 # page shared by N sequences simply appears in N table rows, and partial
 # chunk tables (entries past the written prefix) point at the null page,
 # which the q_pos mask keeps out of the accumulators either way.
 
 
-def _paged_chunk_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
-                        acc_scr, m_scr, l_scr, *, page_size: int, n_q: int,
-                        scale: float):
+def _paged_chunk_kernel(pt_ref, sl_ref, q_ref, qp_ref, k_ref, v_ref, o_ref,
+                        acc_scr, m_scr, l_scr, *, page_size: int, scale: float):
     # grid (B, Hkv, n_pages_max); q_ref (g*T, D) — T queries per kv head
-    # group, flattened into rows; qp_ref carries each query's absolute
-    # position ((B, T) prefetched), sl_ref the per-sequence page coverage
-    # bound (max q_pos + 1) used to skip trailing never-attended pages.
+    # group, flattened into rows; qp_ref (g*T, 1) carries each row's
+    # absolute position as a VMEM column (a vector cannot index the SMEM
+    # prefetch operands); sl_ref is the per-sequence page coverage bound
+    # (max q_pos + 1) used to skip trailing never-attended pages.
     b = pl.program_id(0)
     p = pl.program_id(2)
     n_p = pl.num_programs(2)
     gT, D = q_ref.shape
-    g = gT // n_q
 
     @pl.when(p == 0)
     def _init():
@@ -1857,10 +1856,7 @@ def _paged_chunk_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * (scale * LOG2E)
         k_pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, (gT, page_size), 1)
-        # row r of the flattened q block is query t = r % n_q of its group
-        t_of_row = jax.lax.broadcasted_iota(jnp.int32, (gT, page_size), 0) % n_q
-        q_pos = qp_ref[b, t_of_row]
-        s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        s = jnp.where(k_pos <= qp_ref[:], s, NEG_INF)
         m_prev = m_scr[:][:, 0]
         l_prev = l_scr[:][:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -1881,38 +1877,41 @@ def _paged_chunk_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_chunk_decode(q, k_pages, v_pages, page_table, q_pos, scale=None,
                        *, interpret: bool | None = None):
-    """q (B, H, T, D) against a paged pool (P, page_size, Hkv, D) through
+    """q (B, H, T, D) against a paged pool (P, Hkv, page_size, D) through
     page_table (B, n_pages_max) with per-query positions q_pos (B, T) int32
     -> (B, H, T, D). Each query attends key positions <= its own."""
     B, H, T, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     npm = page_table.shape[1]
     g = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     # (B, Hkv, g*T, D): group rows of one kv head, T queries per group row set
     qg = q.reshape(B, Hkv, g, T, D).reshape(B, Hkv, g * T, D)
+    q_pos = q_pos.astype(jnp.int32)
     seq_lens = jnp.max(q_pos, axis=1) + 1  # page coverage bound per sequence
+    # row r of the flattened q block is query t = r % T of its group
+    qp_rows = jnp.tile(q_pos, (1, g))[:, :, None]  # (B, g*T, 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         grid=(B, Hkv, npm),
         in_specs=[
-            pl.BlockSpec((None, None, g * T, D), lambda b, h, p, pt, sl, qp: (b, h, 0, 0)),
-            pl.BlockSpec((None, ps, None, D), lambda b, h, p, pt, sl, qp: (pt[b, p], 0, h, 0)),
-            pl.BlockSpec((None, ps, None, D), lambda b, h, p, pt, sl, qp: (pt[b, p], 0, h, 0)),
+            pl.BlockSpec((None, None, g * T, D), lambda b, h, p, pt, sl: (b, h, 0, 0)),
+            pl.BlockSpec((None, g * T, 1), lambda b, h, p, pt, sl: (b, 0, 0)),
+            pl.BlockSpec((None, None, ps, D), lambda b, h, p, pt, sl: (pt[b, p], h, 0, 0)),
+            pl.BlockSpec((None, None, ps, D), lambda b, h, p, pt, sl: (pt[b, p], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, g * T, D),
-                               lambda b, h, p, pt, sl, qp: (b, h, 0, 0)),
+                               lambda b, h, p, pt, sl: (b, h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((g * T, D), jnp.float32),
                         pltpu.VMEM((g * T, 1), jnp.float32),
                         pltpu.VMEM((g * T, 1), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_chunk_kernel, page_size=ps, n_q=T, scale=scale),
+        functools.partial(_paged_chunk_kernel, page_size=ps, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g * T, D), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
-    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q_pos.astype(jnp.int32), qg, k_pages, v_pages)
+    )(page_table.astype(jnp.int32), seq_lens, qg, qp_rows, k_pages, v_pages)
     return out.reshape(B, Hkv, g, T, D).reshape(B, H, T, D)
 
 
@@ -1921,8 +1920,6 @@ def paged_chunk_attention_supported(q, k_pages, v_pages, page_table, q_pos,
     """Checker for thunder.paged_chunk_attention: same claim policy as the
     decode kernel (TT_PAGED_KERNEL override, page tiling, VMEM budget with
     the q/accumulator rows widened by T)."""
-    if pltpu is None:
-        return False
     override = os.environ.get("TT_PAGED_KERNEL")
     if override == "0":
         return False
@@ -1931,7 +1928,7 @@ def paged_chunk_attention_supported(q, k_pages, v_pages, page_table, q_pos,
     if getattr(q, "ndim", 0) != 4 or getattr(k_pages, "ndim", 0) != 4:
         return False
     B, H, T, D = q.shape
-    P, ps, Hkv, Dk = k_pages.shape
+    P, Hkv, ps, Dk = k_pages.shape
     shapes_ok = (
         D == Dk and D <= 512
         and tuple(v_pages.shape) == tuple(k_pages.shape)
@@ -1946,9 +1943,11 @@ def paged_chunk_attention_supported(q, k_pages, v_pages, page_table, q_pos,
 
     kv_item = jnp.dtype(str(k_pages.dtype).rpartition(".")[2]).itemsize
     q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
-    return _budget.within_vmem(
-        _budget.paged_chunk_vmem_bytes(ps, D, H // Hkv, T, kv_item, q_item),
-        _budget.paged_vmem_limit())
+    if not _budget.within_vmem(
+            _budget.paged_chunk_vmem_bytes(ps, D, H // Hkv, T, kv_item, q_item),
+            _budget.paged_vmem_limit()):
+        return _decline("paged_chunk_attention", "vmem")
+    return True
 
 
 def _paged_chunk_attention_impl(q, k_pages, v_pages, page_table, q_pos, scale=None):
@@ -2037,8 +2036,6 @@ def grouped_mlp_supported(bins, w_gate, w_up, w_down, group_sizes) -> bool:
     plus a bin block and its f32 SwiGLU intermediates — must fit the VMEM
     budget, otherwise the batched-matmul decomposition runs (the ADVICE
     fallback pattern, unified via analysis/memory.py)."""
-    if pltpu is None:
-        return False
     override = os.environ.get("TT_GROUPED_KERNEL")
     if override == "0":
         return False
@@ -2063,8 +2060,10 @@ def grouped_mlp_supported(bins, w_gate, w_up, w_down, group_sizes) -> bool:
     block_c = math.gcd(cap, _GROUPED_BLOCK_C)
     w_item = jnp.dtype(str(w_gate.dtype).rpartition(".")[2]).itemsize
     x_item = jnp.dtype(str(bins.dtype).rpartition(".")[2]).itemsize
-    return _budget.within_vmem(
-        _budget.grouped_mlp_vmem_bytes(block_c, D, H, w_item, x_item))
+    if not _budget.within_vmem(
+            _budget.grouped_mlp_vmem_bytes(block_c, D, H, w_item, x_item)):
+        return _decline("grouped_mlp", "vmem")
+    return True
 
 
 _grouped_mlp_claimed = _jit_claimed(
@@ -2393,8 +2392,6 @@ def ring_flash_supported(q, k, v) -> bool:
     shard's K/V + the f32 carries — within the VMEM budget via the unified
     analysis/memory.py estimate; otherwise the pure-jax GQA-native
     reference ring runs."""
-    if pltpu is None:
-        return False
     override = os.environ.get("TT_RING_KERNEL")
     if override == "0":
         return False
@@ -2422,8 +2419,10 @@ def ring_flash_supported(q, k, v) -> bool:
 
     q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
     kv_item = jnp.dtype(str(k.dtype).rpartition(".")[2]).itemsize
-    return _budget.within_vmem(
-        _budget.ring_flash_vmem_bytes(block_q, Tk, D, q_item, kv_item))
+    if not _budget.within_vmem(
+            _budget.ring_flash_vmem_bytes(block_q, Tk, D, q_item, kv_item)):
+        return _decline("ring_flash", "vmem")
+    return True
 
 
 def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, block_q, block_k,

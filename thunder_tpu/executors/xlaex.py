@@ -10,6 +10,7 @@ XLA compilation; no CUDA-graph analog needed)."""
 from __future__ import annotations
 
 import time
+import warnings
 from typing import Sequence
 
 import jax
@@ -129,10 +130,12 @@ class XLAFusionExecutor(FusionExecutor):
         def impl(*args):
             # compile_service/parallel_compile.py installs an AOT-compiled
             # (or store-deserialized) executable here: dispatch uses it
-            # directly — no lazy jit compile — and ANY mismatch (tracer
-            # args under an ambient trace, aval/ABI drift) falls back to
-            # the jfn path permanently; prewarming must never change
-            # semantics, only when the compile happened.
+            # directly — no lazy jit compile — and a mismatch it cannot
+            # serve (tracer args under an ambient trace and aval drift raise
+            # TypeError/ValueError from the Compiled call layer, ABI drift
+            # JaxRuntimeError) falls back to the jfn path permanently;
+            # prewarming must never change semantics, only when the compile
+            # happened. Anything else is a bug and propagates.
             pw = impl._prewarmed
             if pw is not None:
                 try:
@@ -145,12 +148,16 @@ class XLAFusionExecutor(FusionExecutor):
                         with _obs_runtime.annotate_call(name):
                             return pw(*args)
                     return pw(*args)
-                except Exception as e:
+                except (TypeError, ValueError, jax.errors.JaxRuntimeError) as e:
                     # the fallback is semantics-preserving but NOT free (a
-                    # hidden lazy recompile follows) — record it so a fleet
-                    # whose prewarmed regions silently disengage is
-                    # distinguishable from one that never prewarmed
+                    # lazy recompile follows) — warn and record it so a fleet
+                    # whose prewarmed regions disengage is distinguishable
+                    # from one that never prewarmed
                     impl._prewarmed = None
+                    warnings.warn(
+                        f"prewarmed executable for {name} could not serve this "
+                        f"call ({type(e).__name__}: {e}); recompiling lazily",
+                        stacklevel=2)
                     if _obs._BUS.enabled:
                         _obs.inc("compile.prewarm_fallback")
                         _obs.event("prewarm_fallback", fusion=name,

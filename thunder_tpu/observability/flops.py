@@ -22,29 +22,34 @@ from __future__ import annotations
 
 from typing import Iterable, Optional
 
-# bf16 MXU peak TFLOP/s and HBM GB/s by TPU generation; the CPU row keeps
-# roofline tags meaningful in tier-1 CI (numbers are order-of-magnitude).
+# (bf16 MXU peak TFLOP/s, HBM GB/s) by ``jax.devices()[0].device_kind``: the
+# one peaks table (bench.py and benchmarks/litgpt_bench.py read it). Source:
+# Google Cloud TPU documentation, per-chip figures; only the v5e kind string
+# has been seen on a chip from this repo. The "cpu" row is nominal — it keeps
+# roofline tags defined in the CPU tests and is no measured peak.
 DEVICE_PEAKS = {
-    "v5 lite": (197.0, 819.0), "v5e": (197.0, 819.0), "v5litepod": (197.0, 819.0),
-    "v5": (459.0, 2765.0), "v5p": (459.0, 2765.0),
-    "v4": (275.0, 1228.0),
-    "v6 lite": (918.0, 1640.0), "v6e": (918.0, 1640.0),
+    "TPU v4": (275.0, 1228.0),
+    "TPU v5 lite": (197.0, 819.0),
+    "TPU v5p": (459.0, 2765.0),
+    "TPU v6 lite": (918.0, 1640.0),
     "cpu": (1.0, 50.0),
 }
 
 
-def device_peaks() -> tuple[float, float]:
-    """(peak_tflops, peak_hbm_gbs) for the local chip generation."""
-    try:
+def device_peaks(device_kind: Optional[str] = None) -> tuple[float, float]:
+    """(peak_tflops, peak_hbm_gbs) of ``device_kind`` (default: the local
+    device). A device that is not in the table is an error, not a default."""
+    if device_kind is None:
         import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        kind = "cpu"
-    for key, val in DEVICE_PEAKS.items():
-        if key in kind:
-            return val
-    return DEVICE_PEAKS["v5e"] if "tpu" in kind else DEVICE_PEAKS["cpu"]
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak FLOP/s and HBM bandwidth recorded for device_kind "
+            f"{device_kind!r}; add it to observability/flops.py DEVICE_PEAKS "
+            f"with its source") from None
 
 
 def _numel(shape) -> int:

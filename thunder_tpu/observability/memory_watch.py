@@ -257,12 +257,14 @@ def census(top_n: int = 10) -> list[dict]:
 
 
 def is_oom(exc: BaseException) -> bool:
-    """RESOURCE_EXHAUSTED shape check: the XlaRuntimeError the allocator
+    """RESOURCE_EXHAUSTED shape check: the JaxRuntimeError the allocator
     raises, or anything whose message says it ran out of device memory."""
+    import jax
+
     msg = str(exc).upper()
     if "RESOURCE_EXHAUSTED" in msg or "OUT OF MEMORY" in msg:
         return True
-    return type(exc).__name__ == "XlaRuntimeError" and "EXHAUSTED" in msg
+    return isinstance(exc, jax.errors.JaxRuntimeError) and "EXHAUSTED" in msg
 
 
 def oom_post_mortem(exc: BaseException, *, step: Optional[int] = None,

@@ -746,32 +746,18 @@ def scatter(a, indices, src, dim):
 
 def index_copy(a, dim, indices, src):
     """Copy rows of src into a at positions `indices` along dim."""
-    from . import ltorch
-
-    d = canonicalize_dim(a.ndim, pyval(dim))
-    idx_shape = [1] * a.ndim
-    idx_shape[d] = -1
-    bshape = list(a.shape)
-    bshape[d] = indices.shape[0]
-    idx = expand(reshape(indices, tuple(idx_shape)), tuple(bshape))
-    return ltorch.scatter(a, d, idx, src)
+    return prims.index_copy(ensure_proxy(a), indices, src,
+                            canonicalize_dim(a.ndim, pyval(dim)))
 
 
 def index_put(a, indices, values, accumulate=False):
     """a[indices] = values (or += with accumulate) — advanced-index write."""
-    from . import ltorch
-
     a = ensure_proxy(a)
     if len(indices) == 1 and not accumulate:
-        d = 0
         idx = indices[0]
-        bshape = list(a.shape)
-        bshape[d] = idx.shape[0]
-        idx_shape = [1] * a.ndim
-        idx_shape[d] = -1
-        full_idx = expand(reshape(idx, tuple(idx_shape)), tuple(bshape))
-        src = values if tuple(values.shape) == tuple(bshape) else expand(values, tuple(bshape))
-        return ltorch.scatter(a, d, full_idx, src)
+        bshape = (idx.shape[0],) + tuple(a.shape[1:])
+        src = values if tuple(values.shape) == bshape else expand(values, bshape)
+        return prims.index_copy(a, idx, src, 0)
     if len(indices) == 1 and accumulate:
         idx = indices[0]
         bshape = list(a.shape)

@@ -905,13 +905,22 @@ def sdpa(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False, scale=None, en
     return prims.matmul(probs, v)
 
 
+def _gather_pages(pages, flat_ids, B: int, npm: int):
+    """Head-major pool (P, Hkv, ps, D) gathered through flat page ids
+    (B*npm,) into dense per-sequence keys or values (B, Hkv, npm*ps, D)."""
+    _, Hkv, ps, D = pages.shape
+    x = reshape(clang.take(pages, flat_ids, 0), (B, npm, Hkv, ps, D))
+    return reshape(permute(x, (0, 2, 1, 3, 4)), (B, Hkv, npm * ps, D))
+
+
 @torchsymbol(name="paged_attention", id="thunder.paged_attention")
 def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None):
     """Decode-step attention of ONE new token per sequence against a
     block-paged KV pool (vLLM/PagedAttention, SOSP '23).
 
     q            (B, H, D)           — the current token's query heads
-    k_pages/v_pages (P, page_size, Hkv, D) — the shared per-layer page pool
+    k_pages/v_pages (P, Hkv, page_size, D) — the shared per-layer page pool
+                 (head-major pages, serving/kv_pages.py)
     page_table   (B, n_pages_max) int — per-sequence page ids; entries beyond
                  the sequence's pages point at the reserved null page 0
     seq_lens     (B,) int            — valid tokens per sequence INCLUDING
@@ -922,17 +931,15 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None):
     symbol whole with a scalar-prefetch paged decode kernel on TPU
     (executors/pallasex.py:paged_attention_decode)."""
     B, H, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     npm = page_table.shape[1]
     T = npm * ps
     check(H % Hkv == 0,
           lambda: f"paged_attention: q heads {H} not divisible by kv heads {Hkv}")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     flat = reshape(page_table, (B * npm,))
-    k = clang.take(k_pages, flat, 0)  # (B*npm, ps, Hkv, D)
-    v = clang.take(v_pages, flat, 0)
-    k = permute(reshape(k, (B, T, Hkv, D)), (0, 2, 1, 3))  # (B, Hkv, T, D)
-    v = permute(reshape(v, (B, T, Hkv, D)), (0, 2, 1, 3))
+    k = _gather_pages(k_pages, flat, B, npm)  # (B, Hkv, T, D)
+    v = _gather_pages(v_pages, flat, B, npm)
     if H != Hkv:
         k = repeat_interleave(k, H // Hkv, 1)
         v = repeat_interleave(v, H // Hkv, 1)
@@ -952,7 +959,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, q_pos, scale=None):
     block-paged pool with PER-QUERY causal coverage (k_pos <= q_pos[b, t]).
 
     q            (B, H, T, D)        — T new tokens' query heads per sequence
-    k_pages/v_pages (P, page_size, Hkv, D) — the shared per-layer page pool
+    k_pages/v_pages (P, Hkv, page_size, D) — the shared per-layer page pool
+                 (head-major pages, serving/kv_pages.py)
     page_table   (B, n_pages_max) int — per-sequence page ids; entries beyond
                  the sequence's pages point at the reserved null page 0
     q_pos        (B, T) int          — each query's ABSOLUTE position; it
@@ -968,7 +976,7 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, q_pos, scale=None):
     executor claims the symbol whole on TPU with a q_pos-prefetch variant of
     the paged decode kernel (executors/pallasex.py:paged_chunk_decode)."""
     B, H, T, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     npm = page_table.shape[1]
     S = npm * ps
     check(H % Hkv == 0,
@@ -977,10 +985,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, q_pos, scale=None):
           lambda: f"paged_chunk_attention: q_pos {q_pos.shape} must be (B, T)=({B}, {T})")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     flat = reshape(page_table, (B * npm,))
-    k = clang.take(k_pages, flat, 0)  # (B*npm, ps, Hkv, D)
-    v = clang.take(v_pages, flat, 0)
-    k = permute(reshape(k, (B, S, Hkv, D)), (0, 2, 1, 3))  # (B, Hkv, S, D)
-    v = permute(reshape(v, (B, S, Hkv, D)), (0, 2, 1, 3))
+    k = _gather_pages(k_pages, flat, B, npm)  # (B, Hkv, S, D)
+    v = _gather_pages(v_pages, flat, B, npm)
     if H != Hkv:
         k = repeat_interleave(k, H // Hkv, 1)
         v = repeat_interleave(v, H // Hkv, 1)

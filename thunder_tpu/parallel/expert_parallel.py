@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.4.35
-    from jax.sharding import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 
 def _dispatch_bins(x, topk_idx, topk_probs, n_expert: int, cap: int):
@@ -143,6 +139,6 @@ def moe_ep_forward(params: dict, x, *, mesh, axis: str = "ep",
     tok_spec = P(token_axes)
     specs_in = (P(), P(axis), P(axis), P(axis), tok_spec)
     out_specs = (tok_spec, P()) if return_stats else tok_spec
-    return shard_map(body, mesh=mesh, in_specs=specs_in, out_specs=out_specs,
-                     check_rep=False)(
+    return jax.shard_map(body, mesh=mesh, in_specs=specs_in, out_specs=out_specs,
+                         check_vma=False)(
         params["gate_w"], params["w_gate"], params["w_up"], params["w_down"], x)

@@ -79,11 +79,7 @@ def initialize(coordinator_address: Optional[str] = None,
             "coordinator address")
     platforms = os.environ.get("JAX_PLATFORMS", "")
     if "cpu" in platforms or not platforms:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              cpu_collectives)
-        except Exception:
-            pass  # older jaxlib without pluggable cpu collectives
+        jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -250,7 +246,10 @@ class ProcResult:
 
 
 class LocalCluster:
-    """Spawn an N-process local jax cluster running one worker source.
+    """CPU harness: spawn an N-process local jax cluster of CPU workers
+    running one worker source. The workers are pinned to JAX_PLATFORMS=cpu
+    with virtual host devices — a chip belongs to one process, so this is not
+    a way to run on chips.
 
         cluster = LocalCluster(nprocs=2)
         results = cluster.run(WORKER_SRC, env={"TT_FAULT": "die@3:host=1"})

@@ -32,7 +32,7 @@ Fleet observability (ISSUE 17) adds a sixth, non-destructive kind:
 
 Memory observability (ISSUE 18) adds a seventh:
 
-  oom         raise a RESOURCE_EXHAUSTED-shaped XlaRuntimeError at dispatch
+  oom         raise a RESOURCE_EXHAUSTED-shaped JaxRuntimeError at dispatch
               time, the exact shape the device allocator produces — so the
               OOM post-mortem path (observability/memory_watch.py forensic
               bundle + ``oom`` cause) is deterministically testable like
@@ -317,18 +317,6 @@ def maybe_sleep(step: int) -> None:
     time.sleep(ms / 1e3)
 
 
-def _oom_exc_type():
-    """The real XlaRuntimeError when the runtime provides it (so catch sites
-    and ``memory_watch.is_oom`` see the genuine type), else a stand-in with
-    the same __name__."""
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError  # type: ignore
-
-        return XlaRuntimeError
-    except Exception:  # noqa: BLE001 - jaxlib layout drift: shape-only fake
-        return type("XlaRuntimeError", (RuntimeError,), {})
-
-
 def maybe_oom(step: int) -> None:
     """oom site: raise the allocator's RESOURCE_EXHAUSTED shape at dispatch
     time — message modeled on the real TPU OOM ("Attempting to allocate
@@ -336,8 +324,9 @@ def maybe_oom(step: int) -> None:
     actually throws, not a sanitized stand-in."""
     if _PLAN is None or not _PLAN.should_fire("oom", step):
         return
-    exc_type = _oom_exc_type()
-    raise exc_type(
+    import jax
+
+    raise jax.errors.JaxRuntimeError(
         f"RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
         f"17179869184 bytes. [injected oom fault at step {step}]")
 

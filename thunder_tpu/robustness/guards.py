@@ -18,7 +18,7 @@ halves:
 
 Transient runtime errors get bounded retry-with-backoff
 (``StepGuard.run_with_retry``), generalizing the one-shot rebuild in
-``training._CompiledWithFallback``: an XlaRuntimeError (or an injected
+``training._CompiledWithFallback``: a JaxRuntimeError (or an injected
 ``faults.InjectedTransientError``) is retried up to ``retry_transient``
 times with exponential backoff. Every intervention is a reason-coded bus
 event (``guard`` events + ``guard.<action>`` counters) so the flight
@@ -52,14 +52,9 @@ def transient_errors() -> tuple:
         return _TRANSIENT_ERRORS
     from .faults import InjectedTransientError
 
-    errs: list[type] = [InjectedTransientError]
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
+    import jax
 
-        errs.append(XlaRuntimeError)
-    except Exception:
-        pass
-    _TRANSIENT_ERRORS = tuple(errs)
+    _TRANSIENT_ERRORS = (InjectedTransientError, jax.errors.JaxRuntimeError)
     return _TRANSIENT_ERRORS
 
 

@@ -1,9 +1,12 @@
 """Block-paged KV cache: a fixed page pool shared by all in-flight sequences.
 
 The vLLM/PagedAttention (SOSP '23) memory design mapped onto the static-shape
-XLA world: each layer owns one `(n_pages, page_size, n_kv_heads, head_dim)`
-device array and every sequence owns an int32 row of page ids into it. The
-pool shape never changes, so ONE compiled decode step serves every mix of
+XLA world: each layer owns one `(n_pages, n_kv_heads, page_size, head_dim)`
+device array and every sequence owns an int32 row of page ids into it. Pages
+are head-major so that one kv head's page is a whole `(page_size, head_dim)`
+tile: the paged kernels' K/V blocks must cover the array's last two
+dimensions (Mosaic's block rule), which a token-major page cannot give per
+head. The pool shape never changes, so ONE compiled decode step serves every mix of
 sequence lengths; allocation is pure host bookkeeping over a free-list, and
 a finished request's pages return to the pool immediately at retirement.
 
@@ -265,7 +268,7 @@ class PagedKVCache:
     def __init__(self, n_layer: int, n_pages: int, page_size: int,
                  n_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
                  allocator: Optional[PageAllocator] = None):
-        shape = (n_pages, page_size, n_kv_heads, head_dim)
+        shape = (n_pages, n_kv_heads, page_size, head_dim)
         self.n_layer = n_layer
         self.n_pages = n_pages
         self.page_size = page_size

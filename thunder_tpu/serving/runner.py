@@ -61,6 +61,31 @@ def _annotated(cfn, name: str):
     return dispatch
 
 
+def _page_blocks(x, ps: int):
+    """(1, Hkv, T, D) keys or values -> (T // ps, Hkv, ps, D): the head-major
+    page blocks the pool stores (kv_pages.py)."""
+    _, Hkv, T, D = x.shape
+    return ltorch.permute(ltorch.reshape(x, (Hkv, T // ps, ps, D)), (1, 0, 2, 3))
+
+
+def _write_tokens(pool, page, slot, tok):
+    """pool[page[n], :, slot[n]] = tok[n] on a head-major pool
+    (P, Hkv, ps, D): page/slot (N,) int32, tok (N, Hkv, D). One joint
+    index_put over (page, head, slot) rows of D."""
+    from ..core import dtypes, prims
+
+    N, Hkv, D = tok.shape
+
+    def rows(v, shape):  # v, viewed as `shape`, spread over the (N, Hkv) rows
+        return ltorch.reshape(ltorch.expand(ltorch.reshape(v, shape), (N, Hkv)),
+                              (N * Hkv,))
+
+    heads = prims.iota(Hkv, dtype=dtypes.int32, device=tok.device)
+    return ltorch.index_put(
+        pool, (rows(page, (N, 1)), rows(heads, (1, Hkv)), rows(slot, (N, 1))),
+        ltorch.reshape(tok, (N * Hkv, D)))
+
+
 def quantize_for_serving(gpt, mode: Optional[str]):
     """Apply weight-only quantization to a GPT before its paged programs are
     traced. ``mode``: None/``"none"`` is a no-op; ``"int8"`` swaps every
@@ -163,13 +188,8 @@ class PagedGPTRunner:
         new_kps, new_vps = [], []
         for li, block in enumerate(gpt.h):
             q, k, v = split_qkv_rope(block, cfg, block.norm_1(x), cos, sin)
-            # page write-out: (1, Hkv, T, hs) -> (T//ps, ps, Hkv, hs) blocks
-            k_blocks = ltorch.reshape(ltorch.permute(k, (0, 2, 1, 3)),
-                                      (T // ps, ps, cfg.n_query_groups, cfg.head_size))
-            v_blocks = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
-                                      (T // ps, ps, cfg.n_query_groups, cfg.head_size))
-            new_kps.append(ltorch.index_put(kps[li], (page_ids,), k_blocks))
-            new_vps.append(ltorch.index_put(vps[li], (page_ids,), v_blocks))
+            new_kps.append(ltorch.index_put(kps[li], (page_ids,), _page_blocks(k, ps)))
+            new_vps.append(ltorch.index_put(vps[li], (page_ids,), _page_blocks(v, ps)))
             kq = _repeat_kv(k, q_per_kv) if cfg.n_query_groups != cfg.n_head else k
             vq = _repeat_kv(v, q_per_kv) if cfg.n_query_groups != cfg.n_head else v
             y = cached_sdpa(q, kq, vq, 0)
@@ -220,8 +240,8 @@ class PagedGPTRunner:
                                    (B, cfg.n_query_groups, cfg.head_size))
             v_tok = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
                                    (B, cfg.n_query_groups, cfg.head_size))
-            kp = ltorch.index_put(kps[li], (page_of, slot), k_tok)
-            vp = ltorch.index_put(vps[li], (page_of, slot), v_tok)
+            kp = _write_tokens(kps[li], page_of, slot, k_tok)
+            vp = _write_tokens(vps[li], page_of, slot, v_tok)
             new_kps.append(kp)
             new_vps.append(vp)
             q3 = ltorch.reshape(q, (B, cfg.n_head, cfg.head_size))
@@ -271,12 +291,8 @@ class PagedGPTRunner:
         new_kps, new_vps = [], []
         for li, block in enumerate(gpt.h):
             q, k, v = split_qkv_rope(block, cfg, block.norm_1(x), cos, sin)
-            k_blocks = ltorch.reshape(ltorch.permute(k, (0, 2, 1, 3)),
-                                      (T // ps, ps, cfg.n_query_groups, cfg.head_size))
-            v_blocks = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
-                                      (T // ps, ps, cfg.n_query_groups, cfg.head_size))
-            kp = ltorch.index_put(kps[li], (chunk_pages,), k_blocks)
-            vp = ltorch.index_put(vps[li], (chunk_pages,), v_blocks)
+            kp = ltorch.index_put(kps[li], (chunk_pages,), _page_blocks(k, ps))
+            vp = ltorch.index_put(vps[li], (chunk_pages,), _page_blocks(v, ps))
             new_kps.append(kp)
             new_vps.append(vp)
             y = ltorch.paged_chunk_attention(q, kp, vp, page_table_row, q_pos)
@@ -333,8 +349,8 @@ class PagedGPTRunner:
                                    (B * K1, cfg.n_query_groups, cfg.head_size))
             v_tok = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
                                    (B * K1, cfg.n_query_groups, cfg.head_size))
-            kp = ltorch.index_put(kps[li], (page_flat, slot_flat), k_tok)
-            vp = ltorch.index_put(vps[li], (page_flat, slot_flat), v_tok)
+            kp = _write_tokens(kps[li], page_flat, slot_flat, k_tok)
+            vp = _write_tokens(vps[li], page_flat, slot_flat, v_tok)
             new_kps.append(kp)
             new_vps.append(vp)
             y = ltorch.paged_chunk_attention(q, kp, vp, page_table, pos_mat)

@@ -58,22 +58,11 @@ def _safe_repr(obj) -> str:
     return _stable_val(obj)
 
 
-def _aot_fallback_errors() -> tuple:
-    """Exception types a stale/mismatched AOT-deserialized executable raises:
-    argument-spec mismatches surface as TypeError/ValueError from the jax
-    Compiled call layer, ABI/runtime mismatches as XlaRuntimeError. Anything
-    else (a genuine bug) must propagate, not silently retrace."""
-    errs: list[type] = [TypeError, ValueError]
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-
-        errs.append(XlaRuntimeError)
-    except Exception:
-        errs.append(RuntimeError)
-    return tuple(errs)
-
-
-_AOT_FALLBACK_ERRORS = _aot_fallback_errors()
+# What a stale or mismatched AOT-deserialized executable raises: argument-spec
+# mismatches surface as TypeError/ValueError from the jax Compiled call layer,
+# ABI/runtime mismatches as JaxRuntimeError. Anything else (a genuine bug)
+# must propagate, not silently retrace.
+_AOT_FALLBACK_ERRORS = (TypeError, ValueError, jax.errors.JaxRuntimeError)
 
 # shared reusable no-op span for disabled-observability hot paths
 _NULL_SPAN = contextlib.nullcontext()
@@ -103,7 +92,7 @@ class _CompiledWithFallback:
                 warnings.warn(
                     f"AOT-cached executable failed at run time "
                     f"({type(e).__name__}: {e}); falling back to the retrace "
-                    f"path. Delete the TT_AOT_CACHE_DIR entry if this "
+                    f"path. Delete the artifact store entry if this "
                     f"persists.", stacklevel=2)
                 _obs_metrics.record_recompile(
                     _obs_metrics.REASON_FALLBACK,
@@ -449,19 +438,24 @@ class TrainStep:
         if not aot_cache.enabled() or getattr(self.tmodule, "_dist_plan", None) is not None:
             return
         jit_fn = self._jitted
-        try:
-            lowered = jit_fn.lower(tparam_arrays, frozen_arrays, self.opt_state, args, kwargs)
-            if getattr(self, "_effect_keys", None) is not None:
-                return  # buffer-mutation epilogues carry module refs: not cacheable
-            compiled = lowered.compile()
-            aot_cache.save_keyed(self._aot_key(tparam_arrays, frozen_arrays, args, kwargs),
-                                 self._model_digest(), compiled)
-        except Exception:
-            return
-        # reuse the compiled program directly (the separate AOT lower/compile
-        # does not populate jax.jit's dispatch cache; without this the first
-        # call would trace the whole step a second time)
+        lowered = jit_fn.lower(tparam_arrays, frozen_arrays, self.opt_state, args, kwargs)
+        if getattr(self, "_effect_keys", None) is not None:
+            return  # buffer-mutation epilogues carry module refs: not cacheable
+        # this is the step's one compile: the compiled program is used
+        # directly (the AOT lower/compile does not populate jax.jit's dispatch
+        # cache, so going back to jit_fn would compile the whole step again),
+        # whether or not the store then takes it
+        compiled = lowered.compile()
         self._jitted = _CompiledWithFallback(compiled, lambda: jit_fn)
+        if not aot_cache.save_keyed(self._aot_key(tparam_arrays, frozen_arrays, args, kwargs),
+                                    self._model_digest(), compiled):
+            import warnings
+
+            _obs_metrics.record_cache("aot", "save_failed")
+            warnings.warn("the whole-step executable was not published to the "
+                          "artifact store (serialization or the store's "
+                          "directory failed); the next process compiles it again",
+                          stacklevel=3)
 
     def _bucketize(self, args, kwargs):
         """Pad batch leaves to the attached BucketLadder's next rung (no-op
@@ -1109,18 +1103,8 @@ def _opt_state_specs(opt_state, param_specs: dict):
 
 
 def _shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map across jax API moves: jax.shard_map (new) falls back to
-    jax.experimental.shard_map (0.4.x), and the check_vma kwarg falls back
-    to its old name check_rep."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax: check_rep
-        return sm(fn, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _dist_in_specs(plan, trainable, frozen, batch_args, batch_kwargs):

@@ -639,6 +639,19 @@ def _index_add_bwd(indices, dim, g):
     return g, None, prims.take(g, indices, dim)
 
 
+@register_augmented_forward(PrimIDs.INDEX_COPY)
+def _index_copy_aug(a, indices, value, dim):
+    return VJPResult(prims.index_copy(a, indices, value, dim),
+                     (indices, dim, value.shape, value.dtype))
+
+
+@register_backward(PrimIDs.INDEX_COPY)
+def _index_copy_bwd(indices, dim, v_shape, v_dtype, g):
+    # the overwritten slices of a get no gradient; value gets theirs
+    zeros = prims.full(v_shape, 0.0, dtype=v_dtype)
+    return prims.index_copy(g, indices, zeros, dim), None, prims.take(g, indices, dim)
+
+
 @register_augmented_forward(PrimIDs.SCATTER_ADD)
 def _scatter_add_aug(a, indices, value, dim):
     return VJPResult(prims.scatter_add(a, indices, value, dim), (indices, dim))

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core import dtypes
 from ..core.proxies import TensorProxy
@@ -117,12 +118,14 @@ class QuantizeInt8Transform(Transform):
 # ---------------------------------------------------------------------------
 
 # bitsandbytes NF4 codebook (quantiles of a standard normal, public constant)
-NF4_CODE = jnp.asarray([
+# (a numpy array: a jax array here would initialise the backend, and take
+# the chip, when the package is merely imported)
+NF4_CODE = np.asarray([
     -1.0, -0.6961928009986877, -0.5250730514526367, -0.39491748809814453,
     -0.28444138169288635, -0.18477343022823334, -0.09105003625154495, 0.0,
     0.07958029955625534, 0.16093020141124725, 0.24611230194568634, 0.33791524171829224,
     0.44070982933044434, 0.5626170039176941, 0.7229568362236023, 1.0,
-], dtype=jnp.float32)
+], dtype=np.float32)
 
 
 def quantize_nf4(w, block_size: int = 64) -> tuple:
@@ -143,7 +146,7 @@ def dequantize_nf4(packed, absmax, shape, block_size: int = 64):
     hi = (packed >> 4) & 0xF
     lo = packed & 0xF
     codes = jnp.stack([hi, lo], axis=1).reshape(-1)
-    vals = NF4_CODE[codes].reshape(-1, block_size) * absmax[:, None]
+    vals = jnp.asarray(NF4_CODE)[codes].reshape(-1, block_size) * absmax[:, None]
     return vals.reshape(shape)
 
 
@@ -275,12 +278,13 @@ def dequantize_nf4_kl(packed_kl, absmax_kl, shape, block_size: int = 64,
 
     N, K = shape
     bk = block_k or nf4_kernel_block_k(K, block_size)
+    code = jnp.asarray(NF4_CODE)
     parts = []
     for j0 in range(0, K, bk):
         byts = packed_kl[:, j0 // 2:(j0 + bk) // 2].astype(jnp.int32)
         hi = (byts >> 4) & 0xF
         lo = byts & 0xF
-        parts.append(jnp.concatenate([NF4_CODE[hi], NF4_CODE[lo]], axis=-1))
+        parts.append(jnp.concatenate([code[hi], code[lo]], axis=-1))
     w = jnp.concatenate(parts, axis=1)
     am = jnp.repeat(absmax_kl.reshape(N, K // block_size), block_size, axis=1)
     return w * am

@@ -20,7 +20,9 @@ Controlled by:
   TT_AOT_CACHE_DIR — legacy alias for the same directory
   TT_NO_AOT_CACHE=1 / TT_NO_ARTIFACT_STORE=1 — disable
 Default-on only on non-CPU backends when no directory is named (CPU
-executables are machine-specific and compile in seconds anyway).
+executables are machine-specific and compile in seconds anyway); the default
+directory is ``artifacts`` under the compile-cache root
+(utils/compile_cache.py).
 """
 from __future__ import annotations
 
@@ -46,18 +48,21 @@ def cache_dir() -> str:
 
 def source_digest() -> str:
     """sha256 over the package's .py sources — a code change invalidates
-    every cached executable (stale programs must never run silently)."""
+    every cached executable (stale programs must never run silently). Files
+    are named by their path inside the package, so two checkouts of one
+    commit agree wherever they sit on disk."""
     global _SRC_DIGEST
     if _SRC_DIGEST is not None:
         return _SRC_DIGEST
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     h = hashlib.sha256()
-    for dirpath, dirnames, filenames in sorted(os.walk(root)):
-        dirnames.sort()
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames[:] = sorted(d for d in dirnames
+                             if d != "__pycache__" and not d.startswith("."))
         for fn in sorted(filenames):
             if fn.endswith(".py"):
                 p = os.path.join(dirpath, fn)
-                h.update(p.encode())
+                h.update(os.path.relpath(p, root).encode())
                 with open(p, "rb") as f:
                     h.update(f.read())
     _SRC_DIGEST = h.hexdigest()
@@ -87,11 +92,8 @@ def step_key(*, inputs, extra: str = "") -> str:
     h = hashlib.sha256()
     h.update(source_digest().encode())
     h.update(jax.__version__.encode())
-    try:
-        h.update(jax.devices()[0].device_kind.encode())
-        h.update(str(len(jax.devices())).encode())
-    except Exception:
-        pass
+    h.update(jax.devices()[0].device_kind.encode())
+    h.update(str(len(jax.devices())).encode())
     h.update(_spec(inputs).encode())
     h.update(extra.encode())
     return h.hexdigest()
