@@ -1,0 +1,8 @@
+"""Device time per traced step in the flash-attention forward kernels (recomputed calls
+included)."""
+from benchmark.lib import readers
+
+
+def read(run):
+    seconds, calls = readers.class_time(run, "flash_fwd")
+    return readers.per_unit_ms(seconds, readers.train_steps_traced(run)) if calls else None
