@@ -46,9 +46,9 @@ from .metrics import (  # noqa: F401
     record_recompile,
 )
 from .runtime import (  # noqa: F401
-    StepTimer,
     annotate_call,
     fusion_scope,
+    phase,
     sample_rate,
     set_sample_rate,
     step_sampled,

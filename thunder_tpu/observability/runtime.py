@@ -1,6 +1,6 @@
 """Runtime-side observability: per-step latency spans and profiler mapping.
 
-Two concerns live here, both strictly opt-in on the hot path:
+Three concerns live here, all strictly opt-in on the hot path:
 
 * ``step_span`` — a latency span per training/inference step (TrainStep
   wraps its ``__call__``). With the bus disabled it returns a shared no-op
@@ -13,6 +13,12 @@ Two concerns live here, both strictly opt-in on the hot path:
   anonymous HLO. Name metadata is baked at trace time and costs nothing at
   run time, so it is always on. ``annotate_call`` adds the matching
   host-side ``jax.profiler.TraceAnnotation`` per dispatch when recording.
+
+* ``phase`` — one named interval on BOTH clocks: a bus span (``perf_counter``,
+  parent ids, attributes) and a ``TraceAnnotation`` of the same name over the
+  same interval, which puts it on the profiler's clock beside the device's
+  ops. It is what lets an idle gap of the device be put down to the part of
+  a host loop that was running (the serving engine's ``engine:*`` phases).
 """
 from __future__ import annotations
 
@@ -20,8 +26,6 @@ import contextlib
 import itertools
 import os
 import threading
-from typing import Optional
-
 from . import events
 
 _NULL = contextlib.nullcontext()
@@ -114,39 +118,32 @@ def annotate_call(name: str):
         return contextlib.nullcontext()
 
 
-class StepTimer:
-    """Aggregating step-latency recorder: ``with timer.record(): step()``.
+class _Phase:
+    """A bus span and a profiler annotation entered and left together."""
 
-    Keeps simple order statistics locally (the event bus keeps the raw
-    timeline) so harnesses can read mean/p50/p95 without re-parsing JSONL.
-    """
+    __slots__ = ("_span", "_ann")
 
-    def __init__(self, name: str = "step", keep: int = 1024):
-        self.name = name
-        self.keep = keep
-        self.durations_ms: list[float] = []
+    def __init__(self, name: str, attrs: dict):
+        self._span = events.Span(name, attrs)
+        self._ann = annotate_call(name)
 
-    @contextlib.contextmanager
-    def record(self, **attrs):
-        import time
+    def __enter__(self) -> events.Span:
+        self._ann.__enter__()
+        return self._span.__enter__()
 
-        t0 = time.perf_counter()
-        with step_span(self.name, **attrs):
-            yield
-        dur = (time.perf_counter() - t0) * 1e3
-        self.durations_ms.append(dur)
-        if len(self.durations_ms) > self.keep:
-            del self.durations_ms[: -self.keep]
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._span.__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
 
-    def stats(self) -> Optional[dict]:
-        if not self.durations_ms:
-            return None
-        xs = sorted(self.durations_ms)
-        n = len(xs)
-        return {
-            "count": n,
-            "mean_ms": round(sum(xs) / n, 3),
-            "p50_ms": round(xs[n // 2], 3),
-            "p95_ms": round(xs[min(n - 1, int(n * 0.95))], 3),
-            "max_ms": round(xs[-1], 3),
-        }
+
+def phase(name: str, **attrs):
+    """One named interval on both clocks (recording only): a bus span with
+    ``attrs`` and a ``jax.profiler.TraceAnnotation`` called ``name`` round the
+    same statements. The annotation is inert while no profiler session runs.
+    With the bus off it returns the shared null context; call sites that
+    compute attributes guard the whole call with the ``enabled()`` they
+    already read, so a disabled bus computes none."""
+    if not events.enabled():
+        return _NULL
+    return _Phase(name, attrs)

@@ -42,7 +42,10 @@ the baseline engine behaves exactly as before (docs/serving.md):
 
 Per-request observability rides the existing bus: request-id-tagged spans,
 ``serve.*`` counters, and flight-recorder records per decode iteration
-(docs/serving.md, docs/observability.md).
+(docs/serving.md, docs/observability.md). The loop itself runs under
+``engine:*`` phases (``observability.runtime.phase``: bus span + profiler
+annotation), one innermost phase at every instant, so a device trace can
+put each idle gap down to admit, prefill, upload, dispatch, fetch or commit.
 
 Sampling is position-keyed — token at position p draws from
 ``fold_in(PRNGKey(seed), p)`` — so a request's stream is identical whether
@@ -91,6 +94,7 @@ class RequestResult:
     # per-request SLO-met flag stamped at retirement when the engine has an
     # SLOPolicy attached (the goodput numerator); None without a policy
     slo_met: Optional[bool] = None
+    queue_s: float = 0.0        # submit -> admitted (pages reserved, slot taken)
 
 
 @dataclass
@@ -108,6 +112,7 @@ class _Request:
     # ONLY when the bus is enabled; None means every downstream trace site
     # exits on one attribute read (the zero-work-when-disabled contract)
     trace_id: Optional[str] = None
+    t_admit: float = 0.0         # first admission (a resumed request keeps it)
     t_first: float = 0.0
     t_last: float = 0.0
     tokens: List[int] = field(default_factory=list)
@@ -527,7 +532,11 @@ class ServingEngine:
     def _loop(self) -> None:
         while not self._stop.is_set():
             if not self._has_work():
-                time.sleep(1e-3)
+                # one phase per idle stretch, not per sleep: an idle engine
+                # must not flood the bus's ring
+                with _obs_runtime.phase("engine:wait"):
+                    while not (self._has_work() or self._stop.is_set()):
+                        time.sleep(1e-3)
                 continue
             try:
                 self._step_once()
@@ -551,10 +560,20 @@ class ServingEngine:
                    PagedKVCache.pages_for(L + max_new, self.page_size))
 
     def _step_once(self) -> None:
-        self._maybe_preempt_for_slo()
-        self._admit()
-        self._advance_prefills()
-        self._decode()
+        obs_on = _obs.enabled()
+        with (_obs_runtime.phase(
+                "engine:iteration", step=self.decode_steps,
+                active=sum(1 for s in self._slots if s is not None),
+                chunking=len(self._chunking),
+                pending=len(self._pending) + len(self._pending_batch))
+              if obs_on else _NULL):
+            # a prefill that admission starts opens its own engine:prefill
+            # inside this phase, so admit's own time is what is left of it
+            with _obs_runtime.phase("engine:admit"):
+                self._maybe_preempt_for_slo()
+                self._admit()
+            self._advance_prefills()
+            self._decode()
 
     def _admit(self) -> None:
         while True:
@@ -593,7 +612,8 @@ class ServingEngine:
             elif req.admit_mode == "chunk":
                 self._start_chunk(req, free_slots[0])
             else:
-                self._prefill(req, free_slots[0])
+                with self._prefill_phase(req):
+                    self._prefill(req, free_slots[0])
 
     def _reserve_pages(self, req: _Request) -> bool:
         """Route one request (prefix hit / chunked / whole-prompt prefill)
@@ -655,11 +675,13 @@ class ServingEngine:
         req.n_shared = n_shared
         req.admit_mode = mode
         req.pages = shared + (self.cache.allocator.alloc(priv) if priv else [])
+        if not req.t_admit:
+            req.t_admit = time.perf_counter()
         if req.trace_id is not None:
             _obs_trace.trace_event(
                 req.trace_id, "admitted", request=req.request_id, mode=mode,
                 covered=covered, shared_pages=n_shared, pages=len(req.pages),
-                queued_ms=round((time.perf_counter() - req.t_submit) * 1e3, 3))
+                queued_ms=round((req.t_admit - req.t_submit) * 1e3, 3))
         return True
 
     def _final_chunk_end(self, L_eff: int, covered: int) -> int:
@@ -795,6 +817,12 @@ class ServingEngine:
                                    request=req.request_id,
                                    error=type(exc).__name__)
 
+    def _prefill_phase(self, req: _Request):
+        """engine:prefill for one request's whole-prompt prefill or one of
+        its chunks: host preparation, uploads, dispatch, first-token fetch."""
+        return _obs_runtime.phase("engine:prefill", request=req.request_id,
+                                  trace_id=req.trace_id)
+
     def _prefill(self, req: _Request, slot: int) -> None:
         obs_on = _obs.enabled()
         resumed = bool(req.tokens)
@@ -876,17 +904,18 @@ class ServingEngine:
         for slot in sorted(self._chunking):
             req = self._chunking[slot]
             while spent < self.prefill_budget:
-                try:
-                    n_toks, logits = self._run_chunk(req)
-                except Exception as e:
-                    del self._chunking[slot]
-                    self._fail(req, e)
-                    break
-                spent += n_toks
-                if req.chunk_pos >= len(req.prompt_eff):
-                    del self._chunking[slot]
-                    self._finish_chunked(req, slot, logits)
-                    break
+                with self._prefill_phase(req):
+                    try:
+                        n_toks, logits = self._run_chunk(req)
+                    except Exception as e:
+                        del self._chunking[slot]
+                        self._fail(req, e)
+                        break
+                    spent += n_toks
+                    if req.chunk_pos >= len(req.prompt_eff):
+                        del self._chunking[slot]
+                        self._finish_chunked(req, slot, logits)
+                        break
             if spent >= self.prefill_budget:
                 return
 
@@ -1033,21 +1062,29 @@ class ServingEngine:
         if not active:
             return
         obs_on = _obs.enabled()
+        phase = _obs_runtime.phase
         t0 = time.perf_counter()
-        self._upload_packed_state()
+        with phase("engine:upload"):
+            self._upload_packed_state()
         try:
             with (_obs_runtime.step_span("serve_decode", active=len(active))
                   if obs_on else _NULL):
-                logits, kps, vps = self.runner.decode_cfn(
-                    self.params, jnp.asarray(self._toks[:, None]),
-                    self.cache.k_pages, self.cache.v_pages,
-                    self._pt_dev, jnp.asarray(self._pos))
-                self.cache.rebind(kps, vps)
-                # the NEXT token's position is pos+1 (this step wrote pos)
-                nxt = self._sampler(logits, self._seeds_dev,
-                                    jnp.asarray(self._pos + 1),
-                                    self._temps_dev)
-                nxt = np.asarray(nxt)
+                with phase("engine:upload"):
+                    toks = jnp.asarray(self._toks[:, None])
+                    pos = jnp.asarray(self._pos)
+                with phase("engine:dispatch"):
+                    logits, kps, vps = self.runner.decode_cfn(
+                        self.params, toks, self.cache.k_pages,
+                        self.cache.v_pages, self._pt_dev, pos)
+                    self.cache.rebind(kps, vps)
+                    # the NEXT token's position is pos+1 (this step wrote
+                    # pos); it goes up with the sampler's enqueue, behind
+                    # the decode program the device already has
+                    nxt = self._sampler(logits, self._seeds_dev,
+                                        jnp.asarray(self._pos + 1),
+                                        self._temps_dev)
+                with phase("engine:fetch"):
+                    nxt = np.asarray(nxt)
         except Exception as e:
             # the packed step failed: every active sequence is implicated —
             # fail their futures and return their pages rather than hanging
@@ -1056,24 +1093,26 @@ class ServingEngine:
                 self._fail(self._slots[i], e)
                 self._clear_slot(i)
             return
-        t_now = time.perf_counter()
-        self.decode_steps += 1
-        if obs_on:
-            _obs_metrics.record_serve("decode_steps")
-            _obs_metrics.record_serve("tokens", delta=len(active))
-            _obs_flight.record_step((t_now - t0) * 1e3, fn="serve_decode",
-                                    active=len(active))
-            # online decode-iteration latency percentiles (unsampled, like
-            # the flight recorder — TT_OBS_SAMPLE only thins the spans)
-            _obs_tel.observe("serve.decode_ms", (t_now - t0) * 1e3)
-            # ONE shared trace event per step carrying every participant
-            # (volume scales with steps, not steps × batch width)
-            _obs_trace.trace_step(
-                [self._slots[i].trace_id for i in active], "decode",
-                dur_ms=(t_now - t0) * 1e3, step=self.decode_steps,
-                active=len(active))
-        for i in active:
-            self._commit(i, self._slots[i], int(nxt[i]), t_now)
+        with phase("engine:commit"):
+            # with the bus on, the step's own records count as commit too
+            t_now = time.perf_counter()
+            self.decode_steps += 1
+            if obs_on:
+                _obs_metrics.record_serve("decode_steps")
+                _obs_metrics.record_serve("tokens", delta=len(active))
+                _obs_flight.record_step((t_now - t0) * 1e3, fn="serve_decode",
+                                        active=len(active))
+                # online decode-iteration latency percentiles (unsampled, like
+                # the flight recorder — TT_OBS_SAMPLE only thins the spans)
+                _obs_tel.observe("serve.decode_ms", (t_now - t0) * 1e3)
+                # ONE shared trace event per step carrying every participant
+                # (volume scales with steps, not steps × batch width)
+                _obs_trace.trace_step(
+                    [self._slots[i].trace_id for i in active], "decode",
+                    dur_ms=(t_now - t0) * 1e3, step=self.decode_steps,
+                    active=len(active))
+            for i in active:
+                self._commit(i, self._slots[i], int(nxt[i]), t_now)
 
     def _spec_decode(self) -> None:
         """Speculative decode iteration: k draft decode steps propose, one
@@ -1087,84 +1126,97 @@ class ServingEngine:
         if not active:
             return
         obs_on = _obs.enabled()
+        phase = _obs_runtime.phase
         k = self.spec_k
         K1 = k + 1
         t0 = time.perf_counter()
-        self._upload_packed_state()
+        with phase("engine:upload"):
+            self._upload_packed_state()
         try:
+            # k draft rounds and the verify step each have their own
+            # upload, dispatch and fetch: (k + 1) of each a decode step
             with (_obs_runtime.step_span("serve_decode", active=len(active),
                                          spec_k=k)
                   if obs_on else _NULL):
                 base_pos = self._pos.copy()
                 cand = [self._toks.copy()]
-                cur = jnp.asarray(self._toks[:, None])
                 for j in range(1, k + 1):
-                    dlog, dkps, dvps = self.draft_runner.decode_cfn(
-                        self.draft_params, cur, self.draft_cache.k_pages,
-                        self.draft_cache.v_pages, self._pt_dev,
-                        jnp.asarray(base_pos + (j - 1)))
-                    self.draft_cache.rebind(dkps, dvps)
-                    dj = np.asarray(self._sampler(
-                        dlog, self._seeds_dev, jnp.asarray(base_pos + j),
-                        self._temps_dev))
-                    cand.append(dj)
-                    cur = jnp.asarray(dj[:, None])
-                toks_mat = np.stack(cand, axis=1)  # (max_batch, k+1)
-                vlog, kps, vps = self.runner.verify_cfn(
-                    self.params, jnp.asarray(toks_mat), self.cache.k_pages,
-                    self.cache.v_pages, self._pt_dev, jnp.asarray(base_pos))
-                self.cache.rebind(kps, vps)
-                B = toks_mat.shape[0]
-                pos_flat = (base_pos[:, None] + 1
-                            + np.arange(K1, dtype=np.int32)[None, :]).reshape(-1)
-                samples = np.asarray(self._sampler(
-                    jnp.reshape(vlog, (B * K1, -1)),
-                    jnp.asarray(np.repeat(self._seeds, K1)),
-                    jnp.asarray(pos_flat),
-                    jnp.asarray(np.repeat(self._temps, K1)))).reshape(B, K1)
+                    with phase("engine:upload"):
+                        cur = jnp.asarray(cand[-1][:, None])
+                        pos = jnp.asarray(base_pos + (j - 1))
+                    with phase("engine:dispatch"):
+                        dlog, dkps, dvps = self.draft_runner.decode_cfn(
+                            self.draft_params, cur, self.draft_cache.k_pages,
+                            self.draft_cache.v_pages, self._pt_dev, pos)
+                        self.draft_cache.rebind(dkps, dvps)
+                        dj = self._sampler(
+                            dlog, self._seeds_dev, jnp.asarray(base_pos + j),
+                            self._temps_dev)
+                    with phase("engine:fetch"):
+                        cand.append(np.asarray(dj))
+                with phase("engine:upload"):
+                    toks_mat = np.stack(cand, axis=1)  # (max_batch, k+1)
+                    toks = jnp.asarray(toks_mat)
+                    pos = jnp.asarray(base_pos)
+                with phase("engine:dispatch"):
+                    vlog, kps, vps = self.runner.verify_cfn(
+                        self.params, toks, self.cache.k_pages,
+                        self.cache.v_pages, self._pt_dev, pos)
+                    self.cache.rebind(kps, vps)
+                    B = toks_mat.shape[0]
+                    pos_flat = (base_pos[:, None] + 1
+                                + np.arange(K1, dtype=np.int32)[None, :]).reshape(-1)
+                    samples = self._sampler(
+                        jnp.reshape(vlog, (B * K1, -1)),
+                        jnp.asarray(np.repeat(self._seeds, K1)),
+                        jnp.asarray(pos_flat),
+                        jnp.asarray(np.repeat(self._temps, K1)))
+                with phase("engine:fetch"):
+                    samples = np.asarray(samples).reshape(B, K1)
         except Exception as e:
             for i in active:
                 self._fail(self._slots[i], e)
                 self._clear_slot(i)
             return
-        t_now = time.perf_counter()
-        self.decode_steps += 1
-        # participant ids captured BEFORE commits (a finishing commit clears
-        # its slot); only read when tracing is on
-        trace_ids = ([self._slots[i].trace_id for i in active]
-                     if obs_on else [])
-        committed_total = 0
-        accepted_total = 0
-        for i in active:
-            req = self._slots[i]
-            m = 0
-            while m < k and toks_mat[i, m + 1] == samples[i, m]:
-                m += 1
-            # commit the accepted samples; min(m+1, k) keeps the draft pool
-            # valid (a bonus k+1th token would advance the target one
-            # position past anything the draft ever wrote)
-            n = min(m + 1, k)
-            self.spec_proposed += k
-            self.spec_accepted += m
-            accepted_total += m
-            for j in range(n):
-                committed_total += 1
-                if not self._commit(i, req, int(samples[i, j]), t_now):
-                    break
-        if obs_on:
-            _obs_metrics.record_serve("decode_steps")
-            _obs_metrics.record_serve("tokens", delta=committed_total)
-            _obs_metrics.record_serve("spec_proposed", delta=k * len(active))
-            _obs_metrics.record_serve("spec_accepted", delta=accepted_total)
-            _obs_flight.record_step((t_now - t0) * 1e3, fn="serve_decode",
-                                    active=len(active), spec_k=k,
-                                    committed=committed_total)
-            _obs_tel.observe("serve.decode_ms", (t_now - t0) * 1e3)
-            _obs_trace.trace_step(trace_ids, "spec_verify",
-                                  dur_ms=(t_now - t0) * 1e3,
-                                  step=self.decode_steps, spec_k=k,
-                                  accepted=accepted_total,
-                                  committed=committed_total)
+        with phase("engine:commit"):
+            t_now = time.perf_counter()
+            self.decode_steps += 1
+            # participant ids captured BEFORE commits (a finishing commit clears
+            # its slot); only read when tracing is on
+            trace_ids = ([self._slots[i].trace_id for i in active]
+                         if obs_on else [])
+            committed_total = 0
+            accepted_total = 0
+            for i in active:
+                req = self._slots[i]
+                m = 0
+                while m < k and toks_mat[i, m + 1] == samples[i, m]:
+                    m += 1
+                # commit the accepted samples; min(m+1, k) keeps the draft pool
+                # valid (a bonus k+1th token would advance the target one
+                # position past anything the draft ever wrote)
+                n = min(m + 1, k)
+                self.spec_proposed += k
+                self.spec_accepted += m
+                accepted_total += m
+                for j in range(n):
+                    committed_total += 1
+                    if not self._commit(i, req, int(samples[i, j]), t_now):
+                        break
+            if obs_on:
+                _obs_metrics.record_serve("decode_steps")
+                _obs_metrics.record_serve("tokens", delta=committed_total)
+                _obs_metrics.record_serve("spec_proposed", delta=k * len(active))
+                _obs_metrics.record_serve("spec_accepted", delta=accepted_total)
+                _obs_flight.record_step((t_now - t0) * 1e3, fn="serve_decode",
+                                        active=len(active), spec_k=k,
+                                        committed=committed_total)
+                _obs_tel.observe("serve.decode_ms", (t_now - t0) * 1e3)
+                _obs_trace.trace_step(trace_ids, "spec_verify",
+                                      dur_ms=(t_now - t0) * 1e3,
+                                      step=self.decode_steps, spec_k=k,
+                                      accepted=accepted_total,
+                                      committed=committed_total)
 
     def _finished(self, req: _Request, tok: int) -> bool:
         if req.future.cancelled():
@@ -1245,6 +1297,7 @@ class ServingEngine:
             n_new_tokens=n_new,
             finish_reason=reason,
             slo_met=slo_met,
+            queue_s=req.t_admit - req.t_submit,
         )
         try:
             # a cancel() from the caller thread can land at ANY point, so a
