@@ -151,6 +151,26 @@ def acquire_trace_interpreted(fn: Callable, args, kwargs,
     return _acquire_with(fn, args, kwargs, grad_mask, call)
 
 
+def donated_arg_names(trc: TraceCtx, args, kwargs, tensor_mask, donated_argnums) -> set:
+    """Names of the trace-arg proxies behind the positional args whose
+    buffers the caller gives up (``donated_argnums``): what goes on the
+    acquired trace as ``trace.donated`` (core/trace.py carries it through
+    every pass, analysis/alias.py checks it for a read after the consuming
+    write, executors/xlaex.py donates those region inputs)."""
+    dmask: list = []
+    for i, a in enumerate(args):
+        dmask.extend([i in donated_argnums] * len(tree_flatten(a)[0]))
+    dmask.extend([False] * len(tree_flatten(kwargs)[0]))
+    tensor_dmask = [d for d, t in zip(dmask, tensor_mask) if t]
+    return {p.name for p, d in zip(trc.args, tensor_dmask) if d}
+
+
+def _argnums(donated_argnums) -> tuple:
+    if isinstance(donated_argnums, int):
+        return (donated_argnums,)
+    return tuple(donated_argnums) if donated_argnums else ()
+
+
 def build_prologue(trc: TraceCtx, tensor_mask, leaves) -> TraceCtx:
     """Prologue trace validating inputs (reference thunder/__init__.py:711-743:
     a cache hit is a prologue that runs without raising)."""
@@ -228,8 +248,9 @@ def _cache_key(leaves, tensor_mask) -> tuple:
 class ThunderCompiledFunction(EpilogueMixin):
     """The callable returned by jit() (reference thunder/__init__.py:881 fn_)."""
 
-    def __init__(self, cd: CompileData):
+    def __init__(self, cd: CompileData, donated_argnums=()):
         self._cd = cd
+        self._donated_argnums = _argnums(donated_argnums)
         self._cs = CompileStats()
         self._cache: dict = {}
         self._transforms: list[Transform] = list(cd.transforms)
@@ -261,6 +282,9 @@ class ThunderCompiledFunction(EpilogueMixin):
                 sp.set(bsyms=len(trc.bound_symbols))
             phases.append(sp)
             cs.last_trace_tracing_time_ns = time.perf_counter_ns() - t0
+            if self._donated_argnums:
+                trc.donated = donated_arg_names(trc, args, kwargs, tensor_mask,
+                                                self._donated_argnums)
 
             # pass-interposed verification (thunder_tpu/analysis): under
             # TT_CHECK_TRACES=1 (or DebugOptions(check_traces=True)) every
@@ -438,6 +462,7 @@ def jit(
     disable_fusion: bool = False,
     interpretation: str | None = None,
     sharp_edges: str = "allow",
+    donated_argnums=None,
     **compile_options,
 ):
     """Compile a callable or Module for TPU execution (reference thunder/__init__.py:315).
@@ -446,6 +471,20 @@ def jit(
     interpreter frontend (provenance-tracked captures, generated prologues) —
     required for arbitrary callables that close over tensors/modules; the
     default direct proxy tracing is faster to compile for framework-native code.
+
+    donated_argnums (an int or a sequence of ints) names the positional
+    arguments whose buffers the caller gives up with every call, as
+    ``jax.jit``'s ``donate_argnums`` does: the caller promises never to read
+    an array it passed there again and to use what the function returns
+    instead. Every array under such an argument may be consumed by the call
+    (``is_deleted()`` afterwards), which lets XLA write an update in place
+    where it would otherwise copy the whole buffer first. A donated array
+    that the program still reads after the region that writes it, or that
+    it returns as it came in, is simply not consumed; a trace that reads a
+    donated buffer after the write that consumed it is refused by
+    ``analysis.check_alias_safety``. It states the caller's calling
+    convention and is no tuning knob; only the direct tracing front end of
+    a plain callable takes it, every other one refuses it.
     """
     from .nn.module import Module, ThunderModule
 
@@ -454,6 +493,13 @@ def jit(
     _is_torch_module = type(fn).__module__.partition(".")[0] == "torch" or any(
         c.__module__.startswith("torch.nn") for c in type(fn).__mro__[:-1]
     )
+    if donated_argnums is not None and (
+            interpretation is not None or cache in ("symbolic values", "same input")
+            or isinstance(fn, Module) or _is_torch_module):
+        raise ValueError(
+            "donated_argnums is honoured only by the direct tracing front end "
+            "of a plain callable (no interpretation=, no symbolic cache, no "
+            "Module): this front end cannot donate, so it refuses the argument")
     if cache in ("symbolic values", "same input") and (isinstance(fn, Module) or _is_torch_module):
         raise ValueError(
             f"cache={cache!r} is only supported for plain callables "
@@ -503,7 +549,7 @@ def jit(
         disable_fusion=disable_fusion,
         compile_options=compile_options,
     )
-    return ThunderCompiledFunction(cd)
+    return ThunderCompiledFunction(cd, donated_argnums=donated_argnums)
 
 
 def compile(fn: Callable, *, recipe=None, plugins=None, **kwargs):

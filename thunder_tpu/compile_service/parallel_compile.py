@@ -87,13 +87,18 @@ def region_key(bsym, avals) -> str:
     input avals (+ the environment fingerprint artifact_key embeds). The
     region's auto-assigned name (``xla_fusion_N`` — a per-process counter,
     not program identity) is stripped so identical programs compiled in
-    different processes/orders share one artifact."""
+    different processes/orders share one artifact. The input positions the
+    region donates (executors/xlaex.py) are part of the executable and not
+    of the text, so they go into the key — only where there are any, so a
+    region that donates nothing keeps the key it always had."""
     sub = bsym.impl.subtrace
     head, nl, body = sub.python().partition("\n")
+    donate = getattr(bsym.impl, "donate_argnums", ())
     return _store.artifact_key(
         kind="region",
         trace=_DEF_NAME.sub("def region(", head, count=1) + nl + body,
         avals="|".join(f"{s.shape}:{s.dtype}" for s in avals),
+        **({"donate": ",".join(map(str, donate))} if donate else {}),
     )
 
 

@@ -121,7 +121,20 @@ class XLAFusionExecutor(FusionExecutor):
         # this region — it works even on backends (CPU) whose per-op
         # events drop the named_scope metadata
         scoped_fn.__name__ = name
-        jfn = jax.jit(scoped_fn)
+        # buffer donation (tt.jit(donated_argnums=...) -> trace.donated): a
+        # region input is given to XLA when the caller gave it up, nothing
+        # after the region reads it and the trace does not return it as it
+        # came in (the RETURN's operands are in consumed_later too). Inlined
+        # into an outer program (TrainStep's whole-step jax.jit) a region
+        # cannot donate: that program's own jax.jit does. With nothing to
+        # donate the call is the bare jax.jit it always was, so every other
+        # program's HLO and compile-cache key stay what they are.
+        donated = getattr(trace, "donated", None)
+        donate = ()
+        if donated and jax.core.trace_ctx.is_top_level():
+            donate = tuple(i for i, p in enumerate(inputs)
+                           if p.name in donated and variableify(p) not in consumed_later)
+        jfn = jax.jit(scoped_fn, donate_argnums=donate) if donate else jax.jit(scoped_fn)
 
         fusion_sym = Symbol(name, None, id=f"xla.{name}", is_prim=True, executor=self, module="xla")
 
@@ -175,6 +188,7 @@ class XLAFusionExecutor(FusionExecutor):
 
         impl.__name__ = name
         impl.jitted = jfn
+        impl.donate_argnums = donate  # part of the region's store key
         impl.subtrace = subtrace
         impl._prewarmed = None
         bsym = BoundSymbol(fusion_sym, tuple(inputs), {}, tuple(outputs), subsymbols=tuple(region), impl=impl)

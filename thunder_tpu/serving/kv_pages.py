@@ -28,6 +28,9 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import events as _obs
+from ..observability import metrics as _obs_metrics
+
 NULL_PAGE = 0
 
 
@@ -263,20 +266,21 @@ class PagedKVCache:
     The device arrays are FUNCTIONAL state: the decode/prefill programs
     return updated pools and the scheduler re-binds `k_pages`/`v_pages`
     each step (same discipline as the dense engine's KVCache tuples).
+    Every program and `copy_page` CONSUME the pools they are given (buffer
+    donation): an array read out of `k_pages` before a dispatch is deleted
+    after it, so read the pools after `rebind`, never across a dispatch.
     """
 
     def __init__(self, n_layer: int, n_pages: int, page_size: int,
                  n_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
                  allocator: Optional[PageAllocator] = None):
-        shape = (n_pages, n_kv_heads, page_size, head_dim)
         self.n_layer = n_layer
         self.n_pages = n_pages
         self.page_size = page_size
         self.n_kv_heads = n_kv_heads
         self.head_dim = head_dim
         self.dtype = dtype
-        self.k_pages = tuple(jnp.zeros(shape, dtype) for _ in range(n_layer))
-        self.v_pages = tuple(jnp.zeros(shape, dtype) for _ in range(n_layer))
+        self.reset_pools()
         # a draft-model cache (speculative decoding) shares the TARGET
         # cache's allocator: one allocation covers both pools, page ids and
         # page tables are identical across the two
@@ -287,15 +291,37 @@ class PagedKVCache:
     def pages_for(n_tokens: int, page_size: int) -> int:
         return max(1, math.ceil(n_tokens / page_size))
 
+    def reset_pools(self) -> None:
+        """Fresh zeroed pools: at construction, and after a failed step whose
+        program had already consumed the old ones (`pools_deleted`)."""
+        shape = (self.n_pages, self.n_kv_heads, self.page_size, self.head_dim)
+        self.k_pages = tuple(jnp.zeros(shape, self.dtype) for _ in range(self.n_layer))
+        self.v_pages = tuple(jnp.zeros(shape, self.dtype) for _ in range(self.n_layer))
+
+    def pools_deleted(self) -> bool:
+        """True when a program consumed the pools and its result was never
+        rebound (the dispatch failed after execution began): every cached
+        key and value is gone."""
+        return any(a.is_deleted() for a in self.k_pages + self.v_pages)
+
     def rebind(self, k_pages, v_pages) -> None:
-        """Adopt the updated pools returned by a compiled step."""
+        """Adopt the updated pools returned by a compiled step. The pools
+        being replaced are the ones that step was given: with the bus on,
+        count whether it consumed them (`serve.pool_donated`) or left them
+        alive, which means XLA copied each before writing
+        (`serve.pool_copied`)."""
+        if _obs.enabled():
+            _obs_metrics.record_serve(
+                "pool_donated" if all(a.is_deleted() for a in self.k_pages + self.v_pages)
+                else "pool_copied")
         self.k_pages = tuple(k_pages)
         self.v_pages = tuple(v_pages)
 
     def copy_page(self, src: int, dst: int) -> None:
         """Device-copy one page's K/V across every layer (the copy-on-write
         body after `PageAllocator.fork`). One cached jax.jit program — src
-        and dst ride as traced scalars, so CoW never recompiles."""
+        and dst ride as traced scalars, so CoW never recompiles; the pools
+        are donated, so one fork copies one page and not the whole pool."""
         import jax
 
         if self._copy_cfn is None:
@@ -303,7 +329,7 @@ class PagedKVCache:
                 return (tuple(kp.at[d].set(kp[s]) for kp in kps),
                         tuple(vp.at[d].set(vp[s]) for vp in vps))
 
-            self._copy_cfn = jax.jit(_copy)
+            self._copy_cfn = jax.jit(_copy, donate_argnums=(0, 1))
         kps, vps = self._copy_cfn(self.k_pages, self.v_pages,
                                   jnp.asarray(src, jnp.int32),
                                   jnp.asarray(dst, jnp.int32))

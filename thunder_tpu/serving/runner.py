@@ -29,7 +29,10 @@ Two compiled programs:
   prefix. Rolled-back positions are simply never committed — their page
   slots hold stale values that the next committed token overwrites.
 
-All are pure functional: pools go in, updated pools come out.
+All are pure functional: pools go in, updated pools come out. Every program
+CONSUMES the pools it is given (`donated_argnums`): the caller rebinds the
+returned ones and never reads the old arrays again, so XLA writes the new
+keys and values in place and copies no pool.
 """
 from __future__ import annotations
 
@@ -156,10 +159,14 @@ class PagedGPTRunner:
         decode.__name__ = "serve_decode"
         chunk_prefill.__name__ = "serve_chunk_prefill"
         verify.__name__ = "serve_verify"
-        self.prefill_cfn = _annotated(_jit(prefill), "serve_prefill")
-        self.decode_cfn = _annotated(_jit(decode), "serve_decode")
-        self.chunk_cfn = _annotated(_jit(chunk_prefill), "serve_chunk_prefill")
-        self.verify_cfn = _annotated(_jit(verify), "serve_verify")
+        # the calling convention, not a knob: every call site passes the
+        # cache's pools and rebinds the returned ones on its next line, so
+        # kps and vps are given up (their positions in each signature above)
+        self.prefill_cfn = _annotated(_jit(prefill, donated_argnums=(3, 4)), "serve_prefill")
+        self.decode_cfn = _annotated(_jit(decode, donated_argnums=(2, 3)), "serve_decode")
+        self.chunk_cfn = _annotated(_jit(chunk_prefill, donated_argnums=(3, 4)),
+                                    "serve_chunk_prefill")
+        self.verify_cfn = _annotated(_jit(verify, donated_argnums=(2, 3)), "serve_verify")
 
     # block plumbing (qkv split/rope, residual/MoE tail) is shared with the
     # dense engine: inference.split_qkv_rope / inference.block_mix — one

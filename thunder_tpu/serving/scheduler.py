@@ -709,10 +709,15 @@ class ServingEngine:
             old = req.pages[req.n_shared - 1]
             new = self.cache.allocator.fork(old)
             if new != old:
-                self.cache.copy_page(old, new)
-                if self.draft_cache is not None:
-                    self.draft_cache.copy_page(old, new)
                 req.pages[req.n_shared - 1] = new
+                try:
+                    self.cache.copy_page(old, new)
+                    if self.draft_cache is not None:
+                        self.draft_cache.copy_page(old, new)
+                except Exception as e:
+                    self._fail(req, e)
+                    self._drop_lost_pools(e)
+                    return
         saved = L_eff if resumed else L_eff - 1
         self.prefix_hits += 1
         self.prefix_tokens_saved += saved
@@ -817,6 +822,31 @@ class ServingEngine:
                                    request=req.request_id,
                                    error=type(exc).__name__)
 
+    def _drop_lost_pools(self, exc: Exception) -> bool:
+        """After a failed dispatch. The programs consume the pools they are
+        given, so a step that failed once execution had begun leaves none
+        behind: every cached key and value is gone. Then allocate fresh
+        pools and fail or drop whatever held pages — the active and the
+        chunking sequences, the prefix cache's nodes — so the allocator ends
+        with every page free and the next request is served. Where the
+        arrays are alive (the failure came before execution) nothing is
+        lost and nothing is done. Returns whether pools were lost."""
+        lost = [c for c in (self.cache, self.draft_cache)
+                if c is not None and c.pools_deleted()]
+        if not lost:
+            return False
+        for c in lost:
+            c.reset_pools()
+        for i, req in enumerate(self._slots):
+            if req is not None:
+                self._fail(req, exc)
+                self._clear_slot(i)
+        for slot in list(self._chunking):
+            self._fail(self._chunking.pop(slot), exc)
+        if self.prefix is not None:
+            self.prefix.clear()
+        return True
+
     def _prefill_phase(self, req: _Request):
         """engine:prefill for one request's whole-prompt prefill or one of
         its chunks: host preparation, uploads, dispatch, first-token fetch."""
@@ -860,6 +890,7 @@ class ServingEngine:
                     tok0 = int(np.asarray(tok0)[0])
         except Exception as e:
             self._fail(req, e)
+            self._drop_lost_pools(e)
             return
         if self.prefix is not None:
             self.prefix.insert(prompt_eff, req.pages)
@@ -910,6 +941,8 @@ class ServingEngine:
                     except Exception as e:
                         del self._chunking[slot]
                         self._fail(req, e)
+                        if self._drop_lost_pools(e):
+                            return  # no chunking sequence is left
                         break
                     spent += n_toks
                     if req.chunk_pos >= len(req.prompt_eff):
@@ -1092,6 +1125,7 @@ class ServingEngine:
             for i in active:
                 self._fail(self._slots[i], e)
                 self._clear_slot(i)
+            self._drop_lost_pools(e)
             return
         with phase("engine:commit"):
             # with the bus on, the step's own records count as commit too
@@ -1177,6 +1211,7 @@ class ServingEngine:
             for i in active:
                 self._fail(self._slots[i], e)
                 self._clear_slot(i)
+            self._drop_lost_pools(e)
             return
         with phase("engine:commit"):
             t_now = time.perf_counter()

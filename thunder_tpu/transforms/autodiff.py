@@ -1468,16 +1468,10 @@ class ThunderValueAndGrad(EpilogueMixin):
             # mark the trace-arg proxies backing donated positional args:
             # every later checkpoint verifies no pass introduces a read of a
             # donated buffer after the write that consumes it
-            from ..core.pytree import tree_flatten as _tf
+            from .. import donated_arg_names
 
-            dmask: list = []
-            for i, a in enumerate(args):
-                lv, _ = _tf(a)
-                dmask.extend([i in self.donated_argnums] * len(lv))
-            lv, _ = _tf(kwargs)
-            dmask.extend([False] * len(lv))
-            tensor_dmask = [d for d, t in zip(dmask, tensor_mask) if t]
-            trc.donated = {p.name for p, d in zip(trc.args, tensor_dmask) if d}
+            trc.donated = donated_arg_names(trc, args, kwargs, tensor_mask,
+                                            self.donated_argnums)
         _an.checkpoint("acquisition", trc, where=where, force=chk)
 
         t1 = _time.perf_counter_ns()
