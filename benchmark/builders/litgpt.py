@@ -60,6 +60,27 @@ def dims(config: dict) -> dict:
         vocab=config["vocab_size"])
 
 
+def kernel_claims(config: dict) -> dict:
+    """What this model needs Pallas to have claimed, ``{program: {symbols:
+    count}}``: one attention kernel a layer in each serving program and in the
+    step's forward and backward traces. Symbols joined by ``+`` are summed: the
+    plain flash kernel (partial rope, decomposed beside it) or the rope-fused
+    one, whichever the configuration takes."""
+    n = int(config["num_hidden_layers"])
+    return {"decode_cfn": {"thunder.paged_attention": n},
+            "chunk_cfn": {"thunder.paged_chunk_attention": n},
+            "forward": {"pallas.rope_flash_fwd+pallas.flash_attention_fwd": n},
+            "backward": {"pallas.rope_flash_bwd+pallas.flash_attention_bwd": n}}
+
+
+def train_flops_per_token(config: dict, seq_len: int) -> float:
+    """Operations the forward and backward passes need per trained token: the
+    dense-GPT count of ``benchmark/lib/costs.py`` at this configuration's sizes."""
+    from benchmark.lib import costs
+
+    return costs.train_flops_per_token(seq_len=seq_len, **dims(config))
+
+
 def program_config(config: dict, name: str, **overrides):
     from thunder_tpu.models.litgpt import Config
 

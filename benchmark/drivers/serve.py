@@ -69,9 +69,11 @@ def check_sample(cell, engine, seed: int, notes: list) -> dict:
     prompts = [loadgen.prompt_tokens(seed, 1_000_000 + i, p, vocab) for i, (p, _) in enumerate(reqs)]
     alone = [serve_all(engine, [p], [n])[0] for p, (_, n) in zip(prompts, reqs)]
     together = serve_all(engine, prompts, [n for _, n in reqs])
+    differ = 0
     for i, (a, b) in enumerate(zip(alone, together)):
         if a.n_new_tokens != reqs[i][1] or not np.array_equal(a.new_tokens, b.new_tokens):
             notes.append(f"sample request {i} {reqs[i]}: alone and batched outputs differ")
+            differ += 1
 
     ref = cell.reference
     params = engine.params
@@ -92,28 +94,41 @@ def check_sample(cell, engine, seed: int, notes: list) -> dict:
                 f"of a chosen token from the reference's top logit {worst:.4f} (margin {margin})")
     if not worst <= margin:
         notes.append(f"a chosen token is {worst} below the reference's top logit (margin {margin})")
-    return {"sample_margin": worst}
+    return {"sample_margin": worst, "sample_differ": differ}
 
 
-def check_kernels(cell, engine, on_tpu: bool, notes: list) -> dict:
+# the compiled programs of ``engine.runner`` whose kernels the builder's ``kernel_claims`` states
+PROGRAMS = ("decode_cfn", "chunk_cfn")
+
+
+def program_claims(engine) -> dict:
+    """What Pallas claimed in each of ``PROGRAMS``, read off the program's last
+    executed trace; a program this cell's traffic never ran is left out."""
     import thunder_tpu as tt
 
-    n_layer = cell.config["num_hidden_layers"]
-    want = {"decode_cfn": "thunder.paged_attention", "chunk_cfn": "thunder.paged_chunk_attention"}
     out = {}
-    if not on_tpu:
-        return out  # off the TPU the Pallas executor declines and XLA runs the decomposition
-    for name, sym in want.items():
+    for name in PROGRAMS:
         traces = tt.last_traces(getattr(engine.runner, name)._cfn)
-        if not traces:
-            continue  # this cell's traffic never ran that program
-        claims = harness.pallas_claims(traces[-1])
-        out[name] = dict(claims)
-        if claims[sym] != n_layer:
-            notes.append(f"{sym} claimed by pallas {claims[sym]} times in {name}, not {n_layer}")
-    if "decode_cfn" not in out:
-        notes.append("the decode program never ran")
+        if traces:
+            out[name] = dict(harness.pallas_claims(traces[-1]))
     return out
+
+
+def check_kernels(cell, claims, notes: list, compared: dict) -> dict:
+    """``claims`` (``program_claims`` of the engine) against what the builder
+    says the model needs: each symbol claimed exactly as often as stated, in
+    every program that ran. ``claims`` is ``None`` off the TPU, where the Pallas
+    executor declines and XLA runs the decomposition: nothing to judge, but the
+    builder is still asked, so that one without ``kernel_claims`` fails here."""
+    want = harness.wanted_claims(cell, PROGRAMS)
+    if claims is None:
+        return {}
+    # a program that is not in ``claims`` is one this cell's traffic never ran
+    for name, symbols, got, count in harness.unheld_claims(want, claims, "==", compared):
+        notes.append(f"{symbols} claimed by pallas {got} times in {name}, not {count}")
+    if "decode_cfn" not in claims:
+        notes.append("the decode program never ran")
+    return claims
 
 
 def decode_regions(engine) -> list:
@@ -213,6 +228,10 @@ def run(cell, opts, env) -> harness.Run:
         observability.enable()  # in memory: the counters and spans the readers use
 
     engine, stats = set_up(cell, opts.seed, notes)
+    compared: dict = {}
+    harness.held(compared, "sample_alone_vs_batched_differ", stats["sample_differ"], "==", 0)
+    harness.held(compared, "sample_margin", stats["sample_margin"], "<=",
+                 float(traffic["correctness"]["margin"]))
     profiler = env.profiler() if opts.trace else None
     try:
         w = offer(traffic, engine, opts.seconds, opts.seed, vocab, env.watch, profiler)
@@ -227,16 +246,19 @@ def run(cell, opts, env) -> harness.Run:
     for r in failed[:3]:
         notes.append(f"request {r.index} ({r.prompt_len} + {r.output_len}) failed: {r.error}")
     good = [r for r in measured if r.ok]
-    if not good:
+    harness.held(compared, "requests_failed", len(failed), "==", 0)
+    if not harness.held(compared, "requests_completed", len(good), ">=", 1):
         notes.append("no request completed inside the window")
-    if any(r.n_new != r.output_len for r in good):
+    if not harness.held(compared, "requests_with_other_token_count",
+                        sum(r.n_new != r.output_len for r in good), "==", 0):
         notes.append("a request came back with another number of tokens than it asked for")
-    if compiles["builds"]:
+    if not harness.held(compared, "executables_built_in_window", compiles["builds"], "==", 0):
         notes.append(f"{compiles['builds']} executables were built inside the window")
     faults = harness.steady_state_faults(counters)
-    if faults:
+    if not harness.held(compared, "program_recompiles_or_fallbacks", sum(faults.values()), "==", 0):
         notes.append(f"the program counted recompiles or fallbacks in the window: {faults}")
-    stats["kernels"] = check_kernels(cell, engine, on_tpu, notes)
+    stats["kernels"] = check_kernels(cell, program_claims(engine) if on_tpu else None, notes,
+                                     compared)
     stats["decode_regions"] = decode_regions(engine)
     stats["engine"] = w["engine"]
     stats["decode_steps"] = w["decode_steps"]
@@ -261,7 +283,7 @@ def run(cell, opts, env) -> harness.Run:
                       window_s=w["window_s"], attempted=len(measured),
                       failed=len(failed), end_to_end=end_to_end, spans=dict(env.spans.durations),
                       records=records, stats=stats, counters=counters, bus=w["bus"],
-                      compiles=compiles, notes=notes)
+                      compiles=compiles, notes=notes, compared=compared)
     if profiler is not None:
         run.trace = profiler.reduce(host_ops_as_device=not on_tpu)
     return run

@@ -19,7 +19,7 @@ from collections import deque
 
 import numpy as np
 
-from benchmark.lib import costs, harness
+from benchmark.lib import harness
 from benchmark.lib.peaks import peaks
 
 WARMUP_STEPS = 3
@@ -138,30 +138,47 @@ def reference_loss(cell, tm, idx, tgt) -> float:
     return float(np.mean([float(fn(params, idx[i], tgt[i])) for i in range(idx.shape[0])]))
 
 
-def check_kernels(step, n_layer: int, on_tpu: bool, notes: list) -> dict:
-    """Flash attention claimed by Pallas in every layer, forward and backward,
-    and compiled by Mosaic where the chip is a TPU."""
+# the step's traces whose kernels the builder's ``kernel_claims`` states
+PROGRAMS = ("forward", "backward")
+
+
+def step_proofs(step) -> dict:
+    """What the step can be judged from: the Pallas claims of its forward and
+    backward traces where this process traced it (``forward``, ``backward``),
+    and the Mosaic calls of its executable where it kept one (``mosaic_calls``;
+    also an executable the artifact store served)."""
     out = {}
-    if not on_tpu:
-        return out  # off the TPU the Pallas executor declines and XLA runs the decomposition
     if hasattr(step, "_vag"):
         cs = step._vag._cs
-        fwd = harness.pallas_claims(cs.last_traces[-1])
-        bwd = harness.pallas_claims(cs.last_backward_traces[-1])
-        out["claims_fwd"], out["claims_bwd"] = dict(fwd), dict(bwd)
-        if fwd["pallas.rope_flash_fwd"] + fwd["pallas.flash_attention_fwd"] < n_layer:
-            notes.append(f"flash forward not claimed by pallas in every layer: {dict(fwd)}")
-        if bwd["pallas.rope_flash_bwd"] + bwd["pallas.flash_attention_bwd"] < n_layer:
-            notes.append(f"flash backward not claimed by pallas in every layer: {dict(bwd)}")
+        out["forward"] = dict(harness.pallas_claims(cs.last_traces[-1]))
+        out["backward"] = dict(harness.pallas_claims(cs.last_backward_traces[-1]))
     compiled = getattr(step._jitted, "_compiled", None)
     if compiled is not None:
         out["mosaic_calls"] = harness.mosaic_calls(compiled)
-        if out["mosaic_calls"] < 2 * n_layer:
-            notes.append(f"{out['mosaic_calls']} Mosaic kernels in the step, "
-                         f"expected at least {2 * n_layer}")
-    if not out:
-        notes.append("no trace and no executable to prove the flash kernels from")
     return out
+
+
+def check_kernels(cell, proofs, notes: list, compared: dict) -> dict:
+    """``proofs`` (``step_proofs``) against what the builder says the model
+    needs: each symbol claimed by Pallas at least as often as stated, forward
+    and backward, and at least as many Mosaic kernels in the executable as the
+    two together. ``proofs`` is ``None`` off the TPU, where the Pallas executor
+    declines and XLA runs the decomposition: nothing to judge, but the builder
+    is still asked, so that one without ``kernel_claims`` fails here."""
+    want = harness.wanted_claims(cell, PROGRAMS)
+    if proofs is None:
+        return {}
+    for name, symbols, got, count in harness.unheld_claims(want, proofs, ">=", compared):
+        notes.append(f"{symbols} claimed by pallas {got} times in the {name} trace, "
+                     f"not the {count} the model needs: {proofs[name]}")
+    if "mosaic_calls" in proofs:
+        least = sum(int(c) for name in PROGRAMS for c in want[name].values())
+        if not harness.held(compared, "mosaic_calls", proofs["mosaic_calls"], ">=", least):
+            notes.append(f"{proofs['mosaic_calls']} Mosaic kernels in the step, "
+                         f"expected at least {least}")
+    if not proofs:
+        notes.append("no trace and no executable to prove the kernels from")
+    return dict(proofs)
 
 
 def xla_step_bytes(step):
@@ -203,6 +220,7 @@ def run(cell, opts, env) -> harness.Run:
     vocab = cell.config["vocab_size"]
     on_tpu = env.devices[0].platform == "tpu"
     notes: list = []
+    compared: dict = {}
     if on_tpu and pallasex._interpret():
         notes.append("pallas kernels would run in interpret mode")
     if opts.trace:
@@ -224,7 +242,7 @@ def run(cell, opts, env) -> harness.Run:
         tol = float(cell.traffic["correctness"]["loss_tolerance"])
         harness.say(f"step 0 loss {first_loss:.6f}, reference {ref_loss:.6f}, "
                     f"difference {abs(first_loss - ref_loss):.6f} (tolerance {tol})")
-        if not abs(first_loss - ref_loss) <= tol:
+        if not harness.held(compared, "step0_loss_gap", abs(first_loss - ref_loss), "<=", tol):
             notes.append(f"step 0 loss {first_loss} against the reference's {ref_loss}")
         for _ in range(WARMUP_STEPS - 1):
             losses.append(step(*next(data)))
@@ -268,18 +286,20 @@ def run(cell, opts, env) -> harness.Run:
     values = [float(v) for v in losses]
     steps = len(values) - n_warm
     bad = [v for v in values[n_warm:] if not math.isfinite(v)]
-    if not all(math.isfinite(v) for v in values):
+    if not harness.held(compared, "losses_not_finite",
+                        sum(not math.isfinite(v) for v in values), "==", 0):
         notes.append("a loss is not finite")
-    if steps < 1:
+    if not harness.held(compared, "steps_in_window", steps, ">=", 1):
         notes.append("no step completed inside the window")
-    elif not np.mean(values[-5:]) < values[0]:
+    elif not harness.held(compared, "loss_last_five_less_first",
+                          float(np.mean(values[-5:])) - values[0], "<", 0.0):
         notes.append(f"the loss did not fall: first {values[0]}, last five {values[-5:]}")
-    if compiles["builds"]:
+    if not harness.held(compared, "executables_built_in_window", compiles["builds"], "==", 0):
         notes.append(f"{compiles['builds']} executables were built inside the window")
     faults = harness.steady_state_faults(counters)
-    if faults:
+    if not harness.held(compared, "program_recompiles_or_fallbacks", sum(faults.values()), "==", 0):
         notes.append(f"the program counted recompiles or fallbacks in the window: {faults}")
-    stats = check_kernels(step, cell.config["num_hidden_layers"], on_tpu, notes)
+    stats = check_kernels(cell, step_proofs(step) if on_tpu else None, notes, compared)
     stats["bytes_in_use"] = harness.memory_in_use_bytes(env.devices[:cell.chips])
     if opts.trace:
         stats["xla_step_bytes"] = xla_step_bytes(step)
@@ -287,7 +307,7 @@ def run(cell, opts, env) -> harness.Run:
                  last_loss=values[-1], reference_loss=ref_loss)
 
     tokens_per_s_per_chip = steps * B * T / window_s / cell.chips
-    flops_per_token = costs.train_flops_per_token(seq_len=T, **cell.builder.dims(cell.config))
+    flops_per_token = float(cell.builder.train_flops_per_token(cell.config, T))
     stats["flops_per_token"] = flops_per_token
     harness.say(f"{steps} steps of {B} x {T} tokens in {window_s:.3f} s; loss "
                 f"{values[0]:.4f} -> {values[-1]:.4f}; {flops_per_token / 1e9:.3f} GFLOP a token")
@@ -301,7 +321,7 @@ def run(cell, opts, env) -> harness.Run:
                       end_to_end={"train_tokens_per_s_per_chip": tokens_per_s_per_chip,
                                   "setup_s": setup_s},
                       spans=dict(spans.durations), stats=stats, counters=counters, bus=bus,
-                      compiles=compiles, traced=traced, notes=notes)
+                      compiles=compiles, traced=traced, notes=notes, compared=compared)
     if traced:
         run.trace = part.profiler.reduce(host_ops_as_device=not on_tpu)
     return run

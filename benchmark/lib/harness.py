@@ -5,6 +5,7 @@ proofs (copied from ``chip_smoke.py``) that the chip's kernels ran.
 from __future__ import annotations
 
 import contextlib
+import operator
 import os
 import shutil
 import time
@@ -175,11 +176,57 @@ def mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def wanted_claims(cell, programs) -> dict:
+    """``{program: {symbols: count}}`` for a driver's ``programs``, from the
+    builder's ``kernel_claims(config)``: what this model needs Pallas to have
+    claimed in each compiled program. A builder without one, or one that leaves
+    out a program its cell's driver judges, is an error and not a run without
+    the check."""
+    kernel_claims = getattr(cell.builder, "kernel_claims", None)
+    if kernel_claims is None:
+        raise AttributeError(f"builder {cell.config['builder']!r} exports no kernel_claims(config): "
+                             f"the {cell.traffic['driver']} driver needs it for {list(programs)}")
+    claims = kernel_claims(cell.config)
+    missing = [p for p in programs if p not in claims]
+    if missing:
+        raise KeyError(f"kernel_claims of builder {cell.config['builder']!r} names no {missing}; "
+                       f"it names {sorted(claims)}")
+    return {p: dict(claims[p]) for p in programs}
+
+
+def unheld_claims(want: dict, claims: dict, rule: str, compared: dict) -> list:
+    """Every stated symbol of every program of ``want`` that ``claims`` has,
+    judged by ``rule`` against its count and recorded in ``compared``. A key of
+    several symbol ids joined by ``+`` has their claims summed. Returns the
+    ``(program, symbols, claimed, count)`` that do not hold."""
+    out = []
+    for program, stated in want.items():
+        if program not in claims:
+            continue
+        for symbols, count in stated.items():
+            got = sum(claims[program].get(s, 0) for s in symbols.split("+"))
+            if not held(compared, f"{program}.{symbols}", got, rule, int(count)):
+                out.append((program, symbols, got, count))
+    return out
+
+
 def steady_state_faults(counters: dict) -> dict:
     """Counters of the program that must stay at zero once every program is compiled."""
     return {k: v for k, v in counters.items()
             if v and (k.startswith("recompile.") or k == "compile.prewarm_fallback"
                       or k == "aot.save_failed")}
+
+
+# -- each number that decides `correct`, beside its limit -----------------------------------------
+
+RULES = {"<": operator.lt, "<=": operator.le, "==": operator.eq, ">=": operator.ge}
+
+
+def held(compared: dict, name: str, value, rule: str, limit) -> bool:
+    """Records one compared number beside its limit (``run.py`` prints them)
+    and says whether it holds; a NaN holds nothing."""
+    compared[name] = (value, rule, limit)
+    return bool(RULES[rule](value, limit))
 
 
 # -- what a driver hands back -----------------------------------------------------------------
@@ -203,6 +250,7 @@ class Run:
     trace: Optional[xplane.Reduction] = None
     traced: dict = field(default_factory=dict)       # what the harness counted while tracing
     notes: list = field(default_factory=list)        # why the run is not correct, if it is not
+    compared: dict = field(default_factory=dict)     # name -> (number, rule, limit): harness.held
 
     @property
     def correct(self) -> bool:

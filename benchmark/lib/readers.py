@@ -72,10 +72,28 @@ def recompiles_in_window(run) -> float:
 
 @lru_cache(maxsize=None)
 def kernel_classes(root: str) -> dict:
-    with open(os.path.join(root, "benchmark", "kernels", "classes.json")) as f:
-        doc = json.load(f)
-    return {"mosaic": re.compile(doc["mosaic"]),
-            "classes": [(c["class"], re.compile(c["pattern"])) for c in doc["classes"]]}
+    """The kernel classes of ``benchmark/kernels/``: ``classes.json`` first,
+    which alone says what marks a compiled Pallas kernel (``mosaic``), then
+    every other ``*.json`` there in name order, each with a ``classes`` list of
+    its own. The order is the order they are tried in, so a file a later PR
+    adds can take no op from a class that was there before it."""
+    kernels = os.path.join(root, "benchmark", "kernels")
+    others = sorted(fn for fn in os.listdir(kernels) if fn.endswith(".json") and fn != "classes.json")
+    mosaic, classes, came_from = None, [], {}
+    for fn in ["classes.json", *others]:
+        with open(os.path.join(kernels, fn)) as f:
+            doc = json.load(f)
+        if fn == "classes.json":
+            mosaic = re.compile(doc["mosaic"])
+        elif "mosaic" in doc:
+            raise ValueError(f"benchmark/kernels/{fn} may not redefine `mosaic`: classes.json does")
+        for c in doc["classes"]:
+            if c["class"] in came_from:
+                raise ValueError(f"kernel class {c['class']!r} is defined twice: in benchmark/kernels/"
+                                 f"{came_from[c['class']]} and in benchmark/kernels/{fn}")
+            came_from[c["class"]] = fn
+            classes.append((c["class"], re.compile(c["pattern"])))
+    return {"mosaic": mosaic, "classes": classes}
 
 
 @lru_cache(maxsize=None)

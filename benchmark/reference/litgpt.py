@@ -117,6 +117,15 @@ def _mlp(config, params, pre, h):
     return _linear(jax.nn.silu(gate) * up, params, pre + "mlp.proj")
 
 
+def control(config: dict) -> tuple:
+    """``(wrong_config, what_is_wrong)``: a configuration the same weights must
+    *not* agree with, for the test that the comparison has teeth: the rope base
+    a hundredth of the published one, so every angle past the first pair turns
+    at another rate."""
+    key = "rotary_emb_base" if config["model_type"] == "gpt_neox" else "rope_theta"
+    return dict(config, **{key: config[key] / 100.0}), f"{key} / 100"
+
+
 def forward(config: dict, params: dict, tokens, *, prefix: str = "", rows=None):
     """Logits ``(T, vocab)`` of one sequence of token ids ``(T,)``; with
     ``rows`` (an index array) only those positions' logits."""

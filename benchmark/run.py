@@ -6,7 +6,9 @@ Measures on the machine it is started on and refuses to measure without a TPU
 holding the chips the cell asks for (exit 2, no result line). The last line of
 standard output is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics with ``--trace 0``, its per-layer
-metrics with ``--trace 1``), ``device``, and with ``--trace 1`` ``breakdown``.
+metrics with ``--trace 1``), ``device``, with ``--trace 1`` ``breakdown``, and
+last ``compared``: each number that decided ``correct`` beside its limit (also
+the last lines on standard error).
 
 ``--rehearse`` is for the CPU tests: the tiny sizes of each file's
 ``rehearsal`` block, any platform, and a result that carries counts, ``correct``
@@ -150,6 +152,15 @@ def main(argv=None) -> int:
             device.pop(k, None)
     else:
         line["metrics"] = metrics
+    # each number that decided `correct` beside its limit: last in the line, and the last
+    # lines on standard error, where the driver's record of a run that is not correct ends
+    # (a NaN goes as a string: it is no JSON)
+    line["compared"] = {name: {"value": value if value == value else "nan", "rule": rule, "limit": limit}
+                        for name, (value, rule, limit) in run.compared.items()}
+    sys.stdout.flush()
+    for name, (value, rule, limit) in run.compared.items():
+        print(f"bench: compared {name}: {value!r} {rule} {limit!r}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

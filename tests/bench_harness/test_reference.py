@@ -1,6 +1,11 @@
-"""The plain reference (`benchmark/reference/litgpt.py`) against the system, at each
+"""The plain reference (`benchmark/reference/<builder>.py`) against the system, at each
 configuration's `rehearsal` size on the CPU: loss, gradients, and the serving margin test.
-On the chip the same comparison runs at the published widths inside every run's set-up."""
+On the chip the same comparison runs at the published widths inside every run's set-up.
+
+The cases follow the manifest: loss and gradients for the configurations that have a cell
+whose traffic file's `driver` is `train`, the serving margin for those with a `serve` cell.
+A configuration that is only served brings no train case, and the control that must fail is
+the reference's own (`control(config)`), so a model without rope brings one that fits it."""
 import numpy as np
 import pytest
 
@@ -9,11 +14,20 @@ import jax.numpy as jnp
 
 from benchmark.lib import loadgen, manifest
 
+
+def cells_by_driver(man: dict, root: str = manifest.ROOT) -> dict:
+    """`{driver: {config: the first of its cells that driver runs}}`, by each traffic file's
+    `driver`."""
+    out: dict = {}
+    for w in man["workloads"]:
+        driver = manifest.load_json(root, "traffic", w["traffic"])["driver"]
+        out.setdefault(driver, {}).setdefault(w["config"], w["name"])
+    return out
+
+
 MAN = manifest.load_manifest()
-CONFIGS = [c["name"] for c in MAN["configs"]]
-# the first train cell and the first serve cell of each configuration
-TRAIN_CELL = {w["config"]: w["name"] for w in reversed(MAN["workloads"]) if ".train-" in w["name"]}
-SERVE_CELL = {w["config"]: w["name"] for w in reversed(MAN["workloads"]) if ".serve-" in w["name"]}
+BY_DRIVER = cells_by_driver(MAN)
+TRAIN_CELL, SERVE_CELL = BY_DRIVER.get("train", {}), BY_DRIVER.get("serve", {})
 
 # float32 on both sides, the same mathematics in another order of summation: agreement to a
 # few float32 roundings of a loss near 6. A wrong rope, bias or layout moves it by 1e-2 or more.
@@ -64,7 +78,7 @@ def reference_loss_and_grads(cell, params, x, y):
 
 
 @pytest.mark.parametrize("autocast", [False, True], ids=["f32", "bf16-autocast"])
-@pytest.mark.parametrize("config", CONFIGS)
+@pytest.mark.parametrize("config", list(TRAIN_CELL))
 def test_loss_and_gradients_agree_with_the_reference(config, autocast):
     cell = tiny_cell(TRAIN_CELL[config])
     x, y = batch(cell)
@@ -87,15 +101,15 @@ def served_sample(cell):
     return engine, stats, notes
 
 
-@pytest.mark.parametrize("config", [c for c in CONFIGS if c in SERVE_CELL])
-def test_serving_margin_against_the_reference(config):
-    cell = manifest.resolve(MAN, SERVE_CELL[config], rehearse=True)
+def serving_margin_holds_and_the_control_fails(cell):
+    """The serving case, for a resolved (rehearsal) cell of any checkout."""
     engine, stats, notes = served_sample(cell)
     assert notes == [] and stats["sample_margin"] <= cell.traffic["correctness"]["margin"]
-    # and the test has teeth: against a reference with another rope base the tokens the
-    # engine chose are no longer the reference's best ones
-    wrong = manifest.merged(cell.config, {"rope_theta": cell.config["rope_theta"] / 100.0})
+    # and the test has teeth: against the reference's own control (a configuration the same
+    # weights must not agree with) the tokens the engine chose are no longer the best ones
     ref = cell.reference
+    wrong, what = ref.control(cell.config)
+    assert wrong != cell.config and what
     p, n = cell.traffic["correctness"]["requests"][-1]
     prompt = loadgen.prompt_tokens(3, 1_000_003, p, cell.config["vocab_size"])
     engine.start()
@@ -107,7 +121,38 @@ def test_serving_margin_against_the_reference(config):
     for config_used, holds in ((cell.config, True), (wrong, False)):
         logits = np.asarray(ref.forward(config_used, engine.params, res.tokens, rows=rows))
         gap = (logits.max(-1) - logits[np.arange(n), res.new_tokens]).max()
-        assert (gap <= cell.traffic["correctness"]["margin"]) == holds, gap
+        assert (gap <= cell.traffic["correctness"]["margin"]) == holds, (what, gap)
+
+
+@pytest.mark.parametrize("config", list(SERVE_CELL))
+def test_serving_margin_against_the_reference(config):
+    serving_margin_holds_and_the_control_fails(manifest.resolve(MAN, SERVE_CELL[config], rehearse=True))
+
+
+def test_the_cases_follow_the_manifest_by_driver():
+    # the cases of PR 22 are still here (a later PR adds to these and takes none away)
+    assert TRAIN_CELL.items() >= {"pythia-410m": "pythia-410m.train-b4-t2048",
+                                  "mistral-7b-v0.3-l8": "mistral-7b-v0.3-l8.train-fsdp4-b4-t4096"}.items()
+    assert SERVE_CELL.items() >= {"mistral-7b-v0.3-l8": "mistral-7b-v0.3-l8.serve-chat"}.items()
+    assert "pythia-410m" not in SERVE_CELL  # it has no serve cell: no serving case, no KeyError
+    for driver, cells in BY_DRIVER.items():
+        for config, cell in cells.items():
+            assert manifest.resolve(MAN, cell).traffic["driver"] == driver
+
+
+def test_a_served_only_configuration_brings_a_serving_case_and_no_train_case(copy, add_served_only_cell):
+    cell_name = add_served_only_cell(copy)
+    config = cell_name.split(".")[0]
+    man = manifest.load_manifest(str(copy))
+    by_driver = cells_by_driver(man, str(copy))
+    assert by_driver["serve"][config] == cell_name and config not in by_driver["train"]
+    # the configurations that were there keep the cases they had
+    assert {d: {c: w for c, w in cells.items() if c != config}
+            for d, cells in by_driver.items()} == BY_DRIVER
+    cell = manifest.resolve(man, cell_name, root=str(copy), rehearse=True)
+    assert "rope_theta" not in cell.config and not hasattr(cell.builder, "build_loss_model")
+    assert "rope" not in cell.reference.control(cell.config)[1]
+    serving_margin_holds_and_the_control_fails(cell)
 
 
 def test_partial_rope_in_serving():

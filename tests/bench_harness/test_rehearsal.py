@@ -14,8 +14,10 @@ from benchmark.lib import manifest
 ROOT = manifest.ROOT
 MAN = manifest.load_manifest()
 CELLS = [w["name"] for w in MAN["workloads"]]
-TRAIN = [c for c in CELLS if ".train-" in c]
-SERVE = [c for c in CELLS if ".serve-" in c]
+# by the traffic file's driver, not by what a cell happens to be called
+DRIVER = {c: manifest.resolve(MAN, c).traffic["driver"] for c in CELLS}
+TRAIN = [c for c in CELLS if DRIVER[c] == "train"]
+SERVE = [c for c in CELLS if DRIVER[c] == "serve"]
 
 
 def run_cell(args, root=ROOT, timeout=600):
@@ -36,10 +38,16 @@ CASES = [(c, 1) for c in CELLS] + [(TRAIN[0], 0), (SERVE[0], 0)]
 
 @pytest.mark.parametrize("cell,trace", CASES)
 def test_cell_runs_end_to_end_on_the_cpu(cell, trace):
-    line = last_line(run_cell(["--workload", cell, "--seed", "5", "--seconds", "3",
-                               "--trace", str(trace), "--rehearse"]))
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device", "rehearsal"}
+    proc = run_cell(["--workload", cell, "--seed", "5", "--seconds", "3",
+                     "--trace", str(trace), "--rehearse"])
+    line = last_line(proc)
+    assert set(line) == {"correct", "attempted", "failed", "metrics", "device", "rehearsal", "compared"}
     assert line["correct"] is True and line["attempted"] > 0 and line["failed"] == 0
+    # each number that decided `correct` beside its limit: last in the line, last on stderr
+    assert list(line)[-1] == "compared" and line["compared"]
+    assert {"value", "rule", "limit"} == set(next(iter(line["compared"].values())))
+    tail = proc.stderr.strip().splitlines()[-len(line["compared"]):]
+    assert [ln.split()[:3] for ln in tail] == [["bench:", "compared", n + ":"] for n in line["compared"]]
     # no number from a CPU run under a metric's name, and no device time either
     assert line["metrics"] == {}
     assert line["device"]["platform"] == "cpu" and "busy_s" not in line["device"]
