@@ -44,24 +44,22 @@ def _engine(gpt, **kw):
 
 
 def _dispatch(engine, program: str):
-    """One direct call of a compiled program on the engine's pools, with the shapes the
-    scheduler gives it. Returns (new k pools, new v pools)."""
+    """One direct call of a compiled program on the engine's cached state, with the shapes the
+    scheduler gives it. Returns (new state,), what `rebind` takes."""
     r, c, B, npm = engine.runner, engine.cache, len(engine._slots), engine.n_pages_max
     i32 = lambda *a: jnp.asarray(*a, dtype=jnp.int32)  # noqa: E731
     table = i32(np.tile(np.arange(1, npm + 1), (B, 1)))
     if program == "prefill":
-        out = r.prefill_cfn(engine.params, i32(np.ones((1, 16))), i32([1, 2]),
-                            c.k_pages, c.v_pages, i32(10))
+        out = r.prefill_cfn(engine.params, i32(np.ones((1, 16))), (i32([1, 2]),), c.state, i32(10),
+                            i32(0))
     elif program == "decode":
-        out = r.decode_cfn(engine.params, i32(np.ones((B, 1))), c.k_pages, c.v_pages,
-                           table, i32(np.arange(B)))
+        out = r.decode_cfn(engine.params, i32(np.ones((B, 1))), c.state, (table,), i32(np.arange(B)))
     elif program == "chunk":
-        out = r.chunk_cfn(engine.params, i32(np.ones((1, 16))), table[:1],
-                          c.k_pages, c.v_pages, i32(8), i32(15))
+        out = r.chunk_cfn(engine.params, i32(np.ones((1, 16))), (table[:1],), c.state, i32(8),
+                          i32(15), i32(0))
     else:
-        out = r.verify_cfn(engine.params, i32(np.ones((B, 3))), c.k_pages, c.v_pages,
-                           table, i32(np.arange(B)))
-    return out[1], out[2]
+        out = r.verify_cfn(engine.params, i32(np.ones((B, 3))), c.state, (table,), i32(np.arange(B)))
+    return (out[1],)
 
 
 def _fusion_impls(cfn) -> list:

@@ -108,6 +108,7 @@ class PrimIDs(Enum):
     EINSUM = auto()
     SCATTER = auto()
     INDEX_COPY = auto()
+    SELECTIVE_SCAN = auto()
     # memory / interop
     ITEM = auto()
     COPY_WITH_SETITEM = auto()
@@ -899,6 +900,26 @@ def _index_copy_meta(a, indices, value, dim):
 
 
 index_copy = make_prim(PrimIDs.INDEX_COPY, "index_copy", _index_copy_meta)
+
+
+def _selective_scan_meta(x, dt, A, B, C, h0):
+    """The state-space recurrence over a sequence from a carried-in state:
+    h_t = exp(dt_t * A) * h_(t-1) + (dt_t * x_t) (x) B_t, y_t = h_t . C_t.
+    x, dt (b, T, d); A (d, n); B, C (b, T, n); h0 (b, d, n) -> (y (b, T, d) in
+    x's type, h_T in h0's type). The recurrence itself runs in float32."""
+    check(x.ndim == 3 and tuple(dt.shape) == tuple(x.shape),
+          lambda: f"selective_scan: x {x.shape} and dt {dt.shape} must both be (b, T, d)")
+    b, T, d = x.shape
+    n = A.shape[1]
+    check(tuple(A.shape) == (d, n) and tuple(B.shape) == (b, T, n) and tuple(C.shape) == (b, T, n)
+          and tuple(h0.shape) == (b, d, n),
+          lambda: f"selective_scan: A {A.shape}, B {B.shape}, C {C.shape}, h0 {h0.shape} do not fit "
+                  f"x {x.shape}")
+    return (TensorProxy(shape=x.shape, dtype=x.dtype, device=x.device),
+            TensorProxy(shape=h0.shape, dtype=h0.dtype, device=h0.device))
+
+
+selective_scan = make_prim(PrimIDs.SELECTIVE_SCAN, "selective_scan", _selective_scan_meta)
 
 
 # ---------------------------------------------------------------------------

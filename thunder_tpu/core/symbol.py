@@ -15,7 +15,7 @@ from typing import Any, Callable, Optional, Sequence
 from .baseutils import SymbolInterface, check
 from .codeutils import ContextInterner, prettyprint, flat_proxies
 from .proxies import Proxy, variableify
-from .trace import get_tracectx
+from .trace import SCOPE_TAG, get_tracectx
 
 
 class _ThreadLocalStack(threading.local):
@@ -133,6 +133,8 @@ class Symbol(SymbolInterface):
             bsym = BoundSymbol(self, args, kwargs, out, subsymbols=tuple(sub))
         if self._bind_postprocess is not None:
             self._bind_postprocess(bsym)
+        if trc.labels:
+            bsym.tags.add(SCOPE_TAG + trc.labels[-1])
         trc.add_bound_symbol(bsym)
         return out
 
@@ -264,6 +266,12 @@ class BoundSymbol:
         )
         key = interner.intern(fn, f"{_ident(self.sym.name)}_")
         line = f"{self._fmt_output(interner)} = {key}({self._fmt_args(interner)})"
+        scope = next((t[len(SCOPE_TAG):] for t in self.tags
+                      if isinstance(t, str) and t.startswith(SCOPE_TAG)), None)
+        if scope is not None:  # trace.named_scope
+            import jax
+
+            return [f"with {interner.intern(jax.named_scope, 'named_scope_')}({scope!r}):", f"  {line}"]
         return [line]
 
     def __repr__(self) -> str:

@@ -33,6 +33,27 @@ def tracectx(trace: "TraceCtx | None"):
         _tracectx.reset(tok)
 
 
+SCOPE_TAG = "scope:"
+
+
+@contextmanager
+def named_scope(name: str):
+    """Every symbol bound inside runs under ``jax.named_scope(name)`` in the
+    compiled program, so that a device profile can put an op down to the part
+    of the model it belongs to. The name rides on the bound symbol as the tag
+    ``scope:<name>`` (symbol.BoundSymbol.exec_lines reads it), which every pass
+    keeps. Outside a trace it does nothing."""
+    trc = get_tracectx()
+    if trc is None:
+        yield
+        return
+    trc.labels.append(name)
+    try:
+        yield
+    finally:
+        trc.labels.pop()
+
+
 class TraceProvenance:
     """Reference thunder/core/trace.py:25 — 'Constructed by <pass> (took N ms)'."""
 
@@ -61,6 +82,9 @@ class TraceCtx(baseutils.TraceInterface):
         # by the epilogue after computation (reference epilogue trace,
         # thunder/core/jit_ext.py:2149)
         self.side_effects: list = []
+        # names of the open `named_scope`s, innermost last: a symbol bound
+        # under one carries it as a tag and runs under jax.named_scope
+        self.labels: list[str] = []
 
     # ---- naming ----
     def make_name(self, prefix: str = "t") -> str:

@@ -130,6 +130,39 @@ def _at_dim(a, indices, dim):
 _reg(PrimIDs.INDEX_ADD, lambda a, indices, value, dim: _at_dim(a, indices, dim).add(value))
 _reg(PrimIDs.INDEX_COPY, lambda a, indices, value, dim: _at_dim(a, indices, dim).set(value))
 
+# time steps a block of the scan holds at once: the (b, block, d, n) decay and
+# input terms of a block are the working set, the state is carried between blocks
+_SCAN_BLOCK = 64
+
+
+def _selective_scan(x, dt, A, B, C, h0):
+    f32 = jnp.float32
+    b, T, d = x.shape
+    A = A.astype(f32)
+    blk = _SCAN_BLOCK if T % _SCAN_BLOCK == 0 else T
+
+    def combine(left, right):
+        (a1, b1), (a2, b2) = left, right
+        return a1 * a2, a2 * b1 + b2
+
+    def block(h, inp):
+        xb, dtb, Bb, Cb = (v.astype(f32) for v in inp)               # (b, blk, ..)
+        decay = jnp.exp(dtb[..., None] * A)                           # (b, blk, d, n)
+        drive = (dtb * xb)[..., None] * Bb[:, :, None, :]
+        drive = drive.at[:, 0].add(decay[:, 0] * h)                   # the carried-in state
+        _, hs = lax.associative_scan(combine, (decay, drive), axis=1)
+        return hs[:, -1], jnp.einsum("btdn,btn->btd", hs, Cb)
+
+    def blocks(v):  # (b, T, k) -> (T // blk, b, blk, k)
+        return jnp.moveaxis(v.reshape(b, T // blk, blk, v.shape[-1]), 1, 0)
+
+    hT, y = lax.scan(block, h0.astype(f32), tuple(blocks(v) for v in (x, dt, B, C)))
+    y = jnp.moveaxis(y, 0, 1).reshape(b, T, d)
+    return y.astype(x.dtype), hT.astype(h0.dtype)
+
+
+_reg(PrimIDs.SELECTIVE_SCAN, _selective_scan)
+
 
 def _scatter_add(a, indices, value, dim):
     return a.at[indices].add(value) if dim == 0 else _scatter_add_general(a, indices, value, dim)

@@ -1686,92 +1686,163 @@ def _paged_vmem_bytes(page_size: int, D: int, g: int, kv_itemsize: int, q_itemsi
     return _budget.paged_decode_vmem_bytes(page_size, D, g, kv_itemsize, q_itemsize)
 
 
-def _paged_attn_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_scr, m_scr, l_scr, *, page_size: int, scale: float):
-    # grid (B, Hkv, n_pages_max) with pages innermost: scratch carries the
-    # online softmax across one sequence's pages; o is written ONCE at the
-    # last page. q_ref: (g, D) — the kv head's q group; k_ref/v_ref:
-    # (page_size, D) — the page the table mapped this grid step to.
+# Two generalisations ride on both paged kernels (a plain GPT uses neither and
+# runs the kernels as they were):
+#
+# * values of another width than the keys: the V pool is (P, Hkv, page_size,
+#   Dv) and the output Dv wide (differential attention reads a pair's two value
+#   heads side by side, twice as wide as QK);
+# * a WINDOW: queries see key positions > q_pos - window only. The grid's page
+#   axis then spans the pages a window can intersect and no more, counted
+#   from the per-sequence first page `lo` (a third scalar-prefetch operand);
+#   table entries below it are never read (the engine has freed those pages).
+
+
+def _paged_softmax_step(q, k, v, live, acc_scr, m_scr, l_scr, scale):
+    """One page of the online softmax (base-2, f32 accumulation) into scratch."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * (scale * LOG2E)
+    s = jnp.where(live, s, NEG_INF)
+    m_prev = m_scr[:][:, 0]
+    l_prev = l_scr[:][:, 0]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    pexp = jnp.exp2(s - m_new[:, None])
+    corr = jnp.exp2(m_prev - m_new)
+    acc_scr[:] = acc_scr[:] * corr[:, None] + jax.lax.dot_general(
+        pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[:] = m_new[:, None]
+    l_scr[:] = (l_prev * corr + jnp.sum(pexp, axis=1))[:, None]
+
+
+def _paged_init(acc_scr, m_scr, l_scr):
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+
+
+def _paged_write(o_ref, acc_scr, l_scr):
+    l = l_scr[:][:, 0]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[:] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
+
+
+def _paged_attn_kernel(*refs, page_size: int, scale: float, window=None):
+    # grid (B, Hkv, pages) with pages innermost: scratch carries the online
+    # softmax across one sequence's pages; o is written ONCE at the last
+    # page. q_ref: (g, D) — the kv head's q group; k_ref: (page_size, D),
+    # v_ref: (page_size, Dv) — the page the table mapped this grid step to.
+    if window is None:
+        pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr = refs
+    else:
+        pt_ref, sl_ref, lo_ref, q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr = refs
     b = pl.program_id(0)
     p = pl.program_id(2)
     n_p = pl.num_programs(2)
-    g, D = q_ref.shape
+    g = q_ref.shape[0]
     seq_len = sl_ref[b]
+    page = p if window is None else lo_ref[b] + p
 
     @pl.when(p == 0)
     def _init():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        _paged_init(acc_scr, m_scr, l_scr)
 
     # pages entirely past the sequence are skipped: their table entries
     # point at the reserved null page, so the DMA is in-bounds but the
     # values are garbage — never let them into the accumulators
-    @pl.when(p * page_size < seq_len)
+    @pl.when(page * page_size < seq_len)
     def _compute():
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * (scale * LOG2E)
         # partially-filled last page: mask slots at/after seq_len
-        k_pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, (g, page_size), 1)
-        s = jnp.where(k_pos < seq_len, s, NEG_INF)
-        m_prev = m_scr[:][:, 0]
-        l_prev = l_scr[:][:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        pexp = jnp.exp2(s - m_new[:, None])
-        corr = jnp.exp2(m_prev - m_new)
-        acc_scr[:] = acc_scr[:] * corr[:, None] + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new[:, None]
-        l_scr[:] = (l_prev * corr + jnp.sum(pexp, axis=1))[:, None]
+        k_pos = page * page_size + jax.lax.broadcasted_iota(jnp.int32, (g, page_size), 1)
+        live = k_pos < seq_len
+        if window is not None:
+            live = live & (k_pos >= seq_len - window)
+        _paged_softmax_step(q_ref[:], k_ref[:], v_ref[:], live, acc_scr, m_scr, l_scr, scale)
 
     @pl.when(p == n_p - 1)
     def _write():
-        l = l_scr[:][:, 0]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[:] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
+        _paged_write(o_ref, acc_scr, l_scr)
 
 
-def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, scale=None,
+def _paged_layout(q_rows: int, k_pages, v_pages, page_table, window, span: int):
+    """What both paged kernels share of their pallas_call: the page axis of
+    the grid, the block specs of q (``q_rows`` rows a kv head), K, V and the
+    output, and the scratch shapes. With a window the grid spans the pages
+    ``span`` positions can touch, from each sequence's first page ``lo``."""
+    ps, D = k_pages.shape[2], k_pages.shape[3]
+    Dv = v_pages.shape[3]
+    npm = page_table.shape[1]
+    n_pages = npm if window is None else min(npm, -(-span // ps) + 1)
+
+    def rows(width):
+        return pl.BlockSpec((None, None, q_rows, width), lambda b, h, p, *_: (b, h, 0, 0))
+
+    if window is None:
+        def kv_index(b, h, p, pt, sl):
+            return (pt[b, p], h, 0, 0)
+    else:
+        def kv_index(b, h, p, pt, sl, lo):
+            return (pt[b, jnp.minimum(lo[b] + p, npm - 1)], h, 0, 0)
+
+    k_spec = pl.BlockSpec((None, None, ps, D), kv_index)
+    v_spec = pl.BlockSpec((None, None, ps, Dv), kv_index)
+    scratch = [pltpu.VMEM((q_rows, Dv), jnp.float32),
+               pltpu.VMEM((q_rows, 1), jnp.float32),
+               pltpu.VMEM((q_rows, 1), jnp.float32)]
+    return n_pages, rows, k_spec, v_spec, scratch
+
+
+def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, scale=None, window=None,
                            *, interpret: bool | None = None):
-    """q (B, H, D) against a paged pool (P, Hkv, page_size, D) through
-    page_table (B, n_pages_max) int32 / seq_lens (B,) int32 -> (B, H, D).
+    """q (B, H, D) against a paged pool — keys (P, Hkv, page_size, D), values
+    (P, Hkv, page_size, Dv) — through page_table (B, n_pages_max) int32 /
+    seq_lens (B,) int32 -> (B, H, Dv).
 
     seq_lens counts valid tokens INCLUDING the current one (whose k/v must
-    already be written to its page). interpret=True runs the kernel in
-    pallas interpret mode (the CPU equivalence tests)."""
+    already be written to its page). With ``window`` only key positions
+    >= seq_len - window are read. interpret=True runs the kernel in pallas
+    interpret mode (the CPU equivalence tests)."""
     B, H, D = q.shape
-    P, Hkv, ps, _ = k_pages.shape
-    npm = page_table.shape[1]
+    Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
     g = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    n_pages, rows, k_spec, v_spec, scratch = _paged_layout(
+        g, k_pages, v_pages, page_table, window, window or 0)
+    seq_lens = seq_lens.astype(jnp.int32)
+    prefetch = [page_table.astype(jnp.int32), seq_lens]
+    if window is not None:
+        prefetch.append(jnp.maximum(seq_lens - window, 0) // ps)
     qg = q.reshape(B, Hkv, g, D)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, npm),
-        in_specs=[
-            pl.BlockSpec((None, None, g, D), lambda b, h, p, pt, sl: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, ps, D), lambda b, h, p, pt, sl: (pt[b, p], h, 0, 0)),
-            pl.BlockSpec((None, None, ps, D), lambda b, h, p, pt, sl: (pt[b, p], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, g, D), lambda b, h, p, pt, sl: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g, D), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32)],
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, Hkv, n_pages),
+        in_specs=[rows(D), k_spec, v_spec],
+        out_specs=rows(Dv),
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, page_size=ps, scale=scale),
+        functools.partial(_paged_attn_kernel, page_size=ps, scale=scale, window=window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dv), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
-    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), qg, k_pages, v_pages)
-    return out.reshape(B, H, D)
+    )(*prefetch, qg, k_pages, v_pages)
+    return out.reshape(B, H, Dv)
 
 
-def paged_attention_supported(q, k_pages, v_pages, page_table, seq_lens, scale=None) -> bool:
+def _paged_shapes_ok(q_heads: int, D: int, k_pages, v_pages, page_table, B: int) -> bool:
+    """What both paged checkers ask of the pools and the table."""
+    if getattr(k_pages, "ndim", 0) != 4 or getattr(v_pages, "ndim", 0) != 4:
+        return False
+    P, Hkv, ps, Dk = k_pages.shape
+    return (D == Dk and D <= 512 and v_pages.shape[3] <= 512
+            and tuple(v_pages.shape[:3]) == (P, Hkv, ps)
+            and q_heads % Hkv == 0
+            and ps % 8 == 0  # sublane tile
+            and getattr(page_table, "ndim", 0) == 2 and page_table.shape[0] == B)
+
+
+def paged_attention_supported(q, k_pages, v_pages, page_table, seq_lens, scale=None,
+                              window=None) -> bool:
     """Checker: the paged decode kernel claims thunder.paged_attention on
     TPU (TT_PAGED_KERNEL=1 forces the claim for interpret-mode A/B, =0
     never claims); shapes must fit the page tiling and the estimated VMEM
@@ -1782,32 +1853,26 @@ def paged_attention_supported(q, k_pages, v_pages, page_table, seq_lens, scale=N
         return False
     if not (_on_tpu() or override == "1"):
         return False
-    if getattr(q, "ndim", 0) != 3 or getattr(k_pages, "ndim", 0) != 4:
+    if getattr(q, "ndim", 0) != 3:
         return False
     B, H, D = q.shape
-    P, Hkv, ps, Dk = k_pages.shape
-    shapes_ok = (
-        D == Dk and D <= 512
-        and tuple(v_pages.shape) == tuple(k_pages.shape)
-        and H % Hkv == 0
-        and ps % 8 == 0  # sublane tile
-        and getattr(page_table, "ndim", 0) == 2 and page_table.shape[0] == B
-        and getattr(seq_lens, "ndim", 0) == 1 and seq_lens.shape[0] == B
-    )
-    if not shapes_ok:
+    if not (_paged_shapes_ok(H, D, k_pages, v_pages, page_table, B)
+            and getattr(seq_lens, "ndim", 0) == 1 and seq_lens.shape[0] == B):
         return False
     from ..analysis import budget as _budget
 
+    Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
     kv_item = jnp.dtype(str(k_pages.dtype).rpartition(".")[2]).itemsize
     q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
-    if not _budget.within_vmem(_paged_vmem_bytes(ps, D, H // Hkv, kv_item, q_item),
+    # the wider of the two widths for both: an upper bound
+    if not _budget.within_vmem(_paged_vmem_bytes(ps, max(D, Dv), H // Hkv, kv_item, q_item),
                                _budget.paged_vmem_limit()):
         return _decline("paged_attention", "vmem")
     return True
 
 
-def _paged_attention_impl(q, k_pages, v_pages, page_table, seq_lens, scale=None):
-    return paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, scale)
+def _paged_attention_impl(q, k_pages, v_pages, page_table, seq_lens, scale=None, window=None):
+    return paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, scale, window)
 
 
 ex.register_implementation("thunder.paged_attention", _paged_attention_impl,
@@ -1830,93 +1895,78 @@ ex.register_implementation("thunder.paged_attention", _paged_attention_impl,
 # which the q_pos mask keeps out of the accumulators either way.
 
 
-def _paged_chunk_kernel(pt_ref, sl_ref, q_ref, qp_ref, k_ref, v_ref, o_ref,
-                        acc_scr, m_scr, l_scr, *, page_size: int, scale: float):
-    # grid (B, Hkv, n_pages_max); q_ref (g*T, D) — T queries per kv head
+def _paged_chunk_kernel(*refs, page_size: int, scale: float, window=None):
+    # grid (B, Hkv, pages); q_ref (g*T, D) — T queries per kv head
     # group, flattened into rows; qp_ref (g*T, 1) carries each row's
     # absolute position as a VMEM column (a vector cannot index the SMEM
     # prefetch operands); sl_ref is the per-sequence page coverage bound
     # (max q_pos + 1) used to skip trailing never-attended pages.
+    if window is None:
+        pt_ref, sl_ref, q_ref, qp_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr = refs
+    else:
+        pt_ref, sl_ref, lo_ref, q_ref, qp_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr = refs
     b = pl.program_id(0)
     p = pl.program_id(2)
     n_p = pl.num_programs(2)
-    gT, D = q_ref.shape
+    page = p if window is None else lo_ref[b] + p
+    gT = q_ref.shape[0]
 
     @pl.when(p == 0)
     def _init():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        _paged_init(acc_scr, m_scr, l_scr)
 
-    @pl.when(p * page_size < sl_ref[b])
+    @pl.when(page * page_size < sl_ref[b])
     def _compute():
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * (scale * LOG2E)
-        k_pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, (gT, page_size), 1)
-        s = jnp.where(k_pos <= qp_ref[:], s, NEG_INF)
-        m_prev = m_scr[:][:, 0]
-        l_prev = l_scr[:][:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        pexp = jnp.exp2(s - m_new[:, None])
-        corr = jnp.exp2(m_prev - m_new)
-        acc_scr[:] = acc_scr[:] * corr[:, None] + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new[:, None]
-        l_scr[:] = (l_prev * corr + jnp.sum(pexp, axis=1))[:, None]
+        k_pos = page * page_size + jax.lax.broadcasted_iota(jnp.int32, (gT, page_size), 1)
+        live = k_pos <= qp_ref[:]
+        if window is not None:
+            live = live & (k_pos > qp_ref[:] - window)
+        _paged_softmax_step(q_ref[:], k_ref[:], v_ref[:], live, acc_scr, m_scr, l_scr, scale)
 
     @pl.when(p == n_p - 1)
     def _write():
-        l = l_scr[:][:, 0]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[:] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
+        _paged_write(o_ref, acc_scr, l_scr)
 
 
-def paged_chunk_decode(q, k_pages, v_pages, page_table, q_pos, scale=None,
+def paged_chunk_decode(q, k_pages, v_pages, page_table, q_pos, scale=None, window=None,
                        *, interpret: bool | None = None):
-    """q (B, H, T, D) against a paged pool (P, Hkv, page_size, D) through
-    page_table (B, n_pages_max) with per-query positions q_pos (B, T) int32
-    -> (B, H, T, D). Each query attends key positions <= its own."""
+    """q (B, H, T, D) against a paged pool — keys (P, Hkv, page_size, D),
+    values (P, Hkv, page_size, Dv) — through page_table (B, n_pages_max) with
+    per-query positions q_pos (B, T) int32 -> (B, H, T, Dv). Each query
+    attends key positions <= its own, and with ``window`` > its own - window."""
     B, H, T, D = q.shape
-    P, Hkv, ps, _ = k_pages.shape
-    npm = page_table.shape[1]
+    Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
     g = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    n_pages, rows, k_spec, v_spec, scratch = _paged_layout(
+        g * T, k_pages, v_pages, page_table, window, T + (window or 0) - 1)
     # (B, Hkv, g*T, D): group rows of one kv head, T queries per group row set
     qg = q.reshape(B, Hkv, g, T, D).reshape(B, Hkv, g * T, D)
     q_pos = q_pos.astype(jnp.int32)
-    seq_lens = jnp.max(q_pos, axis=1) + 1  # page coverage bound per sequence
+    prefetch = [page_table.astype(jnp.int32), jnp.max(q_pos, axis=1) + 1]  # page coverage bound
+    if window is not None:
+        prefetch.append(jnp.maximum(jnp.min(q_pos, axis=1) - window + 1, 0) // ps)
     # row r of the flattened q block is query t = r % T of its group
     qp_rows = jnp.tile(q_pos, (1, g))[:, :, None]  # (B, g*T, 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, npm),
-        in_specs=[
-            pl.BlockSpec((None, None, g * T, D), lambda b, h, p, pt, sl: (b, h, 0, 0)),
-            pl.BlockSpec((None, g * T, 1), lambda b, h, p, pt, sl: (b, 0, 0)),
-            pl.BlockSpec((None, None, ps, D), lambda b, h, p, pt, sl: (pt[b, p], h, 0, 0)),
-            pl.BlockSpec((None, None, ps, D), lambda b, h, p, pt, sl: (pt[b, p], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, g * T, D),
-                               lambda b, h, p, pt, sl: (b, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g * T, D), jnp.float32),
-                        pltpu.VMEM((g * T, 1), jnp.float32),
-                        pltpu.VMEM((g * T, 1), jnp.float32)],
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, Hkv, n_pages),
+        in_specs=[rows(D), pl.BlockSpec((None, g * T, 1), lambda b, h, p, *_: (b, 0, 0)),
+                  k_spec, v_spec],
+        out_specs=rows(Dv),
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
-        functools.partial(_paged_chunk_kernel, page_size=ps, scale=scale),
+        functools.partial(_paged_chunk_kernel, page_size=ps, scale=scale, window=window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g * T, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g * T, Dv), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
-    )(page_table.astype(jnp.int32), seq_lens, qg, qp_rows, k_pages, v_pages)
-    return out.reshape(B, Hkv, g, T, D).reshape(B, H, T, D)
+    )(*prefetch, qg, qp_rows, k_pages, v_pages)
+    return out.reshape(B, Hkv, g, T, Dv).reshape(B, H, T, Dv)
 
 
 def paged_chunk_attention_supported(q, k_pages, v_pages, page_table, q_pos,
-                                    scale=None) -> bool:
+                                    scale=None, window=None) -> bool:
     """Checker for thunder.paged_chunk_attention: same claim policy as the
     decode kernel (TT_PAGED_KERNEL override, page tiling, VMEM budget with
     the q/accumulator rows widened by T)."""
@@ -1925,33 +1975,26 @@ def paged_chunk_attention_supported(q, k_pages, v_pages, page_table, q_pos,
         return False
     if not (_on_tpu() or override == "1"):
         return False
-    if getattr(q, "ndim", 0) != 4 or getattr(k_pages, "ndim", 0) != 4:
+    if getattr(q, "ndim", 0) != 4:
         return False
     B, H, T, D = q.shape
-    P, Hkv, ps, Dk = k_pages.shape
-    shapes_ok = (
-        D == Dk and D <= 512
-        and tuple(v_pages.shape) == tuple(k_pages.shape)
-        and H % Hkv == 0
-        and ps % 8 == 0  # sublane tile
-        and getattr(page_table, "ndim", 0) == 2 and page_table.shape[0] == B
-        and getattr(q_pos, "ndim", 0) == 2 and tuple(q_pos.shape) == (B, T)
-    )
-    if not shapes_ok:
+    if not (_paged_shapes_ok(H, D, k_pages, v_pages, page_table, B)
+            and getattr(q_pos, "ndim", 0) == 2 and tuple(q_pos.shape) == (B, T)):
         return False
     from ..analysis import budget as _budget
 
+    Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
     kv_item = jnp.dtype(str(k_pages.dtype).rpartition(".")[2]).itemsize
     q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
     if not _budget.within_vmem(
-            _budget.paged_chunk_vmem_bytes(ps, D, H // Hkv, T, kv_item, q_item),
+            _budget.paged_chunk_vmem_bytes(ps, max(D, Dv), H // Hkv, T, kv_item, q_item),
             _budget.paged_vmem_limit()):
         return _decline("paged_chunk_attention", "vmem")
     return True
 
 
-def _paged_chunk_attention_impl(q, k_pages, v_pages, page_table, q_pos, scale=None):
-    return paged_chunk_decode(q, k_pages, v_pages, page_table, q_pos, scale)
+def _paged_chunk_attention_impl(q, k_pages, v_pages, page_table, q_pos, scale=None, window=None):
+    return paged_chunk_decode(q, k_pages, v_pages, page_table, q_pos, scale, window)
 
 
 ex.register_implementation("thunder.paged_chunk_attention", _paged_chunk_attention_impl,

@@ -913,90 +913,154 @@ def _gather_pages(pages, flat_ids, B: int, npm: int):
     return reshape(permute(x, (0, 2, 1, 3, 4)), (B, Hkv, npm * ps, D))
 
 
+def _gather_kv(q_heads: int, k_pages, v_pages, page_table):
+    """Keys (B, H, S, D) and values (B, H, S, Dv) of every sequence's pages,
+    each kv head repeated over the query heads that read it."""
+    B, npm = page_table.shape
+    Hkv = k_pages.shape[1]
+    check(q_heads % Hkv == 0 and v_pages.shape[1] == Hkv,
+          lambda: f"paged attention: q heads {q_heads} not divisible by kv heads {Hkv}, or the "
+                  f"value pool has {v_pages.shape[1]} heads")
+    flat = reshape(page_table, (B * npm,))
+    k = _gather_pages(k_pages, flat, B, npm)
+    v = _gather_pages(v_pages, flat, B, npm)
+    if q_heads != Hkv:
+        k = repeat_interleave(k, q_heads // Hkv, 1)
+        v = repeat_interleave(v, q_heads // Hkv, 1)
+    return k, v
+
+
 @torchsymbol(name="paged_attention", id="thunder.paged_attention")
-def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None):
+def paged_attention(q, k_pages, v_pages, page_table, seq_lens, scale=None, window=None):
     """Decode-step attention of ONE new token per sequence against a
     block-paged KV pool (vLLM/PagedAttention, SOSP '23).
 
     q            (B, H, D)           — the current token's query heads
-    k_pages/v_pages (P, Hkv, page_size, D) — the shared per-layer page pool
-                 (head-major pages, serving/kv_pages.py)
+    k_pages      (P, Hkv, page_size, D) — the layer's key pool (head-major
+                 pages, serving/kv_pages.py)
+    v_pages      (P, Hkv, page_size, Dv) — its value pool; Dv == D in a
+                 plain GPT, wider where a head's values are (differential
+                 attention reads a pair's two value heads side by side)
     page_table   (B, n_pages_max) int — per-sequence page ids; entries beyond
                  the sequence's pages point at the reserved null page 0
     seq_lens     (B,) int            — valid tokens per sequence INCLUDING
                  the current one (whose k/v is already written to its page)
+    window       int or None         — with a window the query at position
+                 seq_len - 1 sees key positions > seq_len - 1 - window only;
+                 table entries of pages wholly below that bound are never read
+                 (the engine has freed them)
 
-    The decomposition below is the pure-jax gather reference path (CPU /
-    interpret mode / unclaimed shapes); the pallas executor claims the
-    symbol whole with a scalar-prefetch paged decode kernel on TPU
-    (executors/pallasex.py:paged_attention_decode)."""
+    Returns (B, H, Dv). The decomposition below is the pure-jax gather
+    reference path (CPU / interpret mode / unclaimed shapes); the pallas
+    executor claims the symbol whole with a scalar-prefetch paged decode
+    kernel on TPU (executors/pallasex.py:paged_attention_decode)."""
     B, H, D = q.shape
-    P, Hkv, ps, _ = k_pages.shape
-    npm = page_table.shape[1]
-    T = npm * ps
-    check(H % Hkv == 0,
-          lambda: f"paged_attention: q heads {H} not divisible by kv heads {Hkv}")
+    ps = k_pages.shape[2]
+    T = page_table.shape[1] * ps
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    flat = reshape(page_table, (B * npm,))
-    k = _gather_pages(k_pages, flat, B, npm)  # (B, Hkv, T, D)
-    v = _gather_pages(v_pages, flat, B, npm)
-    if H != Hkv:
-        k = repeat_interleave(k, H // Hkv, 1)
-        v = repeat_interleave(v, H // Hkv, 1)
+    k, v = _gather_kv(H, k_pages, v_pages, page_table)
     qe = reshape(q, (B, H, 1, D))
     scores = clang.mul(prims.matmul(qe, clang.matrix_transpose(k)), scale)  # (B, H, 1, T)
     k_pos = reshape(prims.iota(T, dtype=dtypes.int32, device=q.device), (1, 1, 1, T))
-    live = clang.lt(k_pos, reshape(seq_lens, (B, 1, 1, 1)))
+    lens = reshape(seq_lens, (B, 1, 1, 1))
+    live = clang.lt(k_pos, lens)
+    if window is not None:
+        live = logical_and(live, clang.ge(k_pos, lens - pyval(window)))
     scores = clang.where(live, scores, float("-inf"))
     probs = softmax(scores, -1)
     probs = clang.maybe_convert_to_dtype(probs, v.dtype)
-    return reshape(prims.matmul(probs, v), (B, H, D))
+    return reshape(prims.matmul(probs, v), (B, H, v.shape[-1]))
 
 
 @torchsymbol(name="paged_chunk_attention", id="thunder.paged_chunk_attention")
-def paged_chunk_attention(q, k_pages, v_pages, page_table, q_pos, scale=None):
+def paged_chunk_attention(q, k_pages, v_pages, page_table, q_pos, scale=None, window=None):
     """Multi-query paged attention: T new tokens per sequence attend the
     block-paged pool with PER-QUERY causal coverage (k_pos <= q_pos[b, t]).
 
     q            (B, H, T, D)        — T new tokens' query heads per sequence
-    k_pages/v_pages (P, Hkv, page_size, D) — the shared per-layer page pool
-                 (head-major pages, serving/kv_pages.py)
+    k_pages      (P, Hkv, page_size, D), v_pages (P, Hkv, page_size, Dv) — the
+                 layer's pools, as in ``paged_attention``
     page_table   (B, n_pages_max) int — per-sequence page ids; entries beyond
                  the sequence's pages point at the reserved null page 0
     q_pos        (B, T) int          — each query's ABSOLUTE position; it
                  attends keys at positions <= its own (whose k/v, including
                  its own token's, are already written to their pages)
+    window       int or None         — with a window, keys at positions
+                 > q_pos - window only
 
     One symbol serves both new paged multi-token programs (serving/runner.py):
     the CHUNKED-PREFILL chunk (B=1, T=chunk tokens, positions start..start+T)
     and the SPECULATIVE-DECODING verify step (T=k+1 proposed tokens per
     packed sequence). Shared (copy-on-write) page tables need nothing
     special here — shared pages simply repeat across rows of `page_table`.
-    This decomposition is the pure-jax gather reference path; the pallas
-    executor claims the symbol whole on TPU with a q_pos-prefetch variant of
-    the paged decode kernel (executors/pallasex.py:paged_chunk_decode)."""
+    Returns (B, H, T, Dv). This decomposition is the pure-jax gather
+    reference path; the pallas executor claims the symbol whole on TPU with
+    a q_pos-prefetch variant of the paged decode kernel
+    (executors/pallasex.py:paged_chunk_decode)."""
     B, H, T, D = q.shape
-    P, Hkv, ps, _ = k_pages.shape
-    npm = page_table.shape[1]
-    S = npm * ps
-    check(H % Hkv == 0,
-          lambda: f"paged_chunk_attention: q heads {H} not divisible by kv heads {Hkv}")
+    ps = k_pages.shape[2]
+    S = page_table.shape[1] * ps
     check(tuple(q_pos.shape) == (B, T),
           lambda: f"paged_chunk_attention: q_pos {q_pos.shape} must be (B, T)=({B}, {T})")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    flat = reshape(page_table, (B * npm,))
-    k = _gather_pages(k_pages, flat, B, npm)  # (B, Hkv, S, D)
-    v = _gather_pages(v_pages, flat, B, npm)
-    if H != Hkv:
-        k = repeat_interleave(k, H // Hkv, 1)
-        v = repeat_interleave(v, H // Hkv, 1)
+    k, v = _gather_kv(H, k_pages, v_pages, page_table)
     scores = clang.mul(prims.matmul(q, clang.matrix_transpose(k)), scale)  # (B, H, T, S)
     k_pos = reshape(prims.iota(S, dtype=dtypes.int32, device=q.device), (1, 1, 1, S))
-    live = clang.le(k_pos, reshape(q_pos, (B, 1, T, 1)))
+    qp = reshape(q_pos, (B, 1, T, 1))
+    live = clang.le(k_pos, qp)
+    if window is not None:
+        live = logical_and(live, clang.gt(k_pos, qp - pyval(window)))
     scores = clang.where(live, scores, float("-inf"))
     probs = softmax(scores, -1)
     probs = clang.maybe_convert_to_dtype(probs, v.dtype)
-    return prims.matmul(probs, v)  # (B, H, T, D)
+    return prims.matmul(probs, v)  # (B, H, T, Dv)
+
+
+@torchsymbol(name="causal_conv1d", id="thunder.causal_conv1d")
+def causal_conv1d(x, weight, bias, tail):
+    """Depthwise causal convolution along time with a carried tail.
+
+    x       (B, T, d)      — the new inputs
+    weight  (d, K), bias (d,)
+    tail    (B, K - 1, d)  — the K - 1 inputs before x[:, 0] (zeros at the
+            start of a sequence)
+
+    y[:, t] = bias + sum_j weight[:, j] * xp[:, t + j] with xp = [tail, x].
+    Returns (y (B, T, d), xp (B, T + K - 1, d)): the caller cuts the next
+    tail out of xp where its sequence really ends."""
+    B, T, d = x.shape
+    K = weight.shape[1]
+    check(tuple(tail.shape) == (B, K - 1, d),
+          lambda: f"causal_conv1d: tail {tail.shape} must be (B, K - 1, d)=({B}, {K - 1}, {d})")
+    xp = cat([clang.maybe_convert_to_dtype(tail, x.dtype), x], 1)
+    y = None
+    for j in range(K):
+        term = xp[:, j:j + T] * weight[:, j]
+        y = term if y is None else y + term
+    return y + bias, xp
+
+
+@torchsymbol(name="selective_scan", id="thunder.selective_scan")
+def selective_scan(x, dt, A, B, C, h0):
+    """The state-space recurrence h_t = exp(dt_t A) h_(t-1) + (dt_t x_t) (x) B_t,
+    y_t = h_t . C_t from the carried-in state h0: x, dt (b, T, d); A (d, n);
+    B, C (b, T, n); h0 (b, d, n). Returns (y (b, T, d), h_T (b, d, n)).
+
+    For one token this is the state update, written out in element-wise ops;
+    a sequence goes to ``prims.selective_scan`` (an associative scan in blocks
+    through XLA). Either way the state is computed in float32 and handed back
+    in h0's type."""
+    b, T, d = x.shape
+    if T > 1:
+        return prims.selective_scan(x, dt, A, B, C, h0)
+    f32 = dtypes.float32
+    x1, dt1 = (clang.maybe_convert_to_dtype(reshape(v, (b, d, 1)), f32) for v in (x, dt))
+    B1, C1 = (clang.maybe_convert_to_dtype(reshape(v, (b, 1, A.shape[1])), f32) for v in (B, C))
+    h = clang.maybe_convert_to_dtype(h0, f32)
+    h = exp(dt1 * clang.maybe_convert_to_dtype(A, f32)) * h + (dt1 * x1) * B1
+    y = sum(h * C1, -1)
+    return (clang.maybe_convert_to_dtype(reshape(y, (b, 1, d)), x.dtype),
+            clang.maybe_convert_to_dtype(h, h0.dtype))
 
 
 @torchsymbol(name="grouped_mlp", id="thunder.grouped_mlp")

@@ -1,4 +1,5 @@
-"""Block-paged KV cache: a fixed page pool shared by all in-flight sequences.
+"""Block-paged KV cache: a fixed page pool shared by all in-flight sequences,
+and beside it what other kinds of layer keep (window pools, recurrent state).
 
 The vLLM/PagedAttention (SOSP '23) memory design mapped onto the static-shape
 XLA world: each layer owns one `(n_pages, n_kv_heads, page_size, head_dim)`
@@ -23,7 +24,8 @@ masks its values out via seq_lens; see executors/pallasex.py).
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -32,6 +34,53 @@ from ..observability import events as _obs
 from ..observability import metrics as _obs_metrics
 
 NULL_PAGE = 0
+
+
+# ---------------------------------------------------------------------------
+# What a layer caches. A served layer (serving/runner.py) declares one of
+# these as its ``cache``; PagedKVCache allocates what the declarations add up
+# to and the scheduler keeps its books by them. ``None`` is a layer that
+# caches nothing.
+# ---------------------------------------------------------------------------
+
+
+@dataclass(frozen=True)
+class PagedKV:
+    """The layer owns a key pool ``(n_pages, heads, page_size, k_dim)`` and a
+    value pool ``(n_pages, heads, page_size, v_dim)``. With ``window`` the
+    layer reads key positions ``> pos - window`` only, its pools are cut from
+    the smaller window allocation, and the scheduler frees a page as soon as
+    every position in it is older than that."""
+
+    heads: int
+    k_dim: int
+    v_dim: int
+    window: Optional[int] = None
+
+    @property
+    def kind(self) -> str:
+        return "window" if self.window else "full"
+
+
+@dataclass(frozen=True)
+class ReadsKV:
+    """The layer caches nothing and reads the pools of layer ``of``."""
+
+    of: int
+
+
+@dataclass(frozen=True)
+class Recurrent:
+    """Per-slot arrays ``(max_batch, *shape)`` for each of ``shapes``: state of
+    constant size that every program rewrites and that starts from zero when
+    a slot takes a new sequence."""
+
+    shapes: Tuple[Tuple[int, ...], ...]
+    dtype: object = jnp.float32
+
+    def bytes_per_slot(self) -> int:
+        return sum(math.prod(s) for s in self.shapes) * jnp.dtype(self.dtype).itemsize
+
 
 
 class OutOfPages(Exception):
@@ -261,79 +310,128 @@ class PrefixCache:
 
 
 class PagedKVCache:
-    """Per-layer paged K/V pools plus the allocator that parcels them out.
+    """The cached state of every layer plus the allocators that parcel the
+    page pools out.
+
+    ``layers`` holds one declaration a layer (``PagedKV``, ``ReadsKV``,
+    ``Recurrent`` or ``None``); ``state`` holds one tuple of device arrays a
+    layer: ``(k pool, v pool)``, ``()`` for a layer that reads another's pools
+    or caches nothing, the per-slot arrays of a recurrent layer. Pools of
+    layers without a window are indexed by the pages of ``allocator``, pools
+    of window layers by those of ``window_allocator``: one allocation covers
+    all layers of a kind. A pool that several layers read exists once, in the
+    state of the layer that owns it.
 
     The device arrays are FUNCTIONAL state: the decode/prefill programs
-    return updated pools and the scheduler re-binds `k_pages`/`v_pages`
-    each step (same discipline as the dense engine's KVCache tuples).
-    Every program and `copy_page` CONSUME the pools they are given (buffer
-    donation): an array read out of `k_pages` before a dispatch is deleted
-    after it, so read the pools after `rebind`, never across a dispatch.
+    return updated state and the scheduler re-binds it each step (same
+    discipline as the dense engine's KVCache tuples). Every program and
+    `copy_page` CONSUME the state they are given (buffer donation): an array
+    read out of `state` before a dispatch is deleted after it, so read it
+    after `rebind`, never across a dispatch.
     """
 
     def __init__(self, n_layer: int, n_pages: int, page_size: int,
                  n_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
-                 allocator: Optional[PageAllocator] = None):
-        self.n_layer = n_layer
+                 allocator: Optional[PageAllocator] = None, *,
+                 layers: Optional[Sequence] = None, max_batch: int = 0,
+                 prefill_pages: int = 0):
+        # a plain GPT: every layer owns one pool pair of one shape
+        self.layers = (tuple(layers) if layers is not None else
+                       (PagedKV(n_kv_heads, head_dim, head_dim),) * n_layer)
+        self.n_layer = len(self.layers)
         self.n_pages = n_pages
         self.page_size = page_size
-        self.n_kv_heads = n_kv_heads
-        self.head_dim = head_dim
         self.dtype = dtype
+        self.max_batch = max_batch
+        windows = {d.window for d in self.layers if isinstance(d, PagedKV) and d.window}
+        if len(windows) > 1:
+            raise ValueError(f"window layers of one model must share one window, got {sorted(windows)}")
+        self.window = windows.pop() if windows else None
+        # the window pools hold what a sequence's window spans, for every slot,
+        # and beside it the ``prefill_pages`` one prefill program writes before
+        # the scheduler trims them (one program runs at a time); plus the null page
+        self.n_window_pages = (1 + max_batch * (self.window // page_size + 1) + prefill_pages
+                               if self.window else 0)
         self.reset_pools()
         # a draft-model cache (speculative decoding) shares the TARGET
         # cache's allocator: one allocation covers both pools, page ids and
         # page tables are identical across the two
         self.allocator = allocator if allocator is not None else PageAllocator(n_pages)
+        self.window_allocator = PageAllocator(self.n_window_pages) if self.window else None
         self._copy_cfn = None
 
     @staticmethod
     def pages_for(n_tokens: int, page_size: int) -> int:
         return max(1, math.ceil(n_tokens / page_size))
 
+    def _fresh(self, decl) -> tuple:
+        if isinstance(decl, PagedKV):
+            n = self.n_window_pages if decl.window else self.n_pages
+            return (jnp.zeros((n, decl.heads, self.page_size, decl.k_dim), self.dtype),
+                    jnp.zeros((n, decl.heads, self.page_size, decl.v_dim), self.dtype))
+        if isinstance(decl, Recurrent):
+            return tuple(jnp.zeros((self.max_batch, *shape), decl.dtype) for shape in decl.shapes)
+        return ()
+
     def reset_pools(self) -> None:
-        """Fresh zeroed pools: at construction, and after a failed step whose
-        program had already consumed the old ones (`pools_deleted`)."""
-        shape = (self.n_pages, self.n_kv_heads, self.page_size, self.head_dim)
-        self.k_pages = tuple(jnp.zeros(shape, self.dtype) for _ in range(self.n_layer))
-        self.v_pages = tuple(jnp.zeros(shape, self.dtype) for _ in range(self.n_layer))
+        """Fresh zeroed state: at construction, and after a failed step whose
+        program had already consumed the old arrays (`pools_deleted`)."""
+        self.state = tuple(self._fresh(d) for d in self.layers)
+
+    def _pools(self, which: int) -> tuple:
+        return tuple(s[which] for s, d in zip(self.state, self.layers) if isinstance(d, PagedKV))
+
+    @property
+    def k_pages(self) -> tuple:
+        """The key pools of the layers that own one, in layer order."""
+        return self._pools(0)
+
+    @property
+    def v_pages(self) -> tuple:
+        return self._pools(1)
+
+    def _arrays(self) -> list:
+        return [a for arrs in self.state for a in arrs]
 
     def pools_deleted(self) -> bool:
-        """True when a program consumed the pools and its result was never
+        """True when a program consumed the state and its result was never
         rebound (the dispatch failed after execution began): every cached
-        key and value is gone."""
-        return any(a.is_deleted() for a in self.k_pages + self.v_pages)
+        key, value and recurrent state is gone."""
+        return any(a.is_deleted() for a in self._arrays())
 
-    def rebind(self, k_pages, v_pages) -> None:
-        """Adopt the updated pools returned by a compiled step. The pools
+    def rebind(self, state) -> None:
+        """Adopt the updated state returned by a compiled step. The arrays
         being replaced are the ones that step was given: with the bus on,
         count whether it consumed them (`serve.pool_donated`) or left them
         alive, which means XLA copied each before writing
         (`serve.pool_copied`)."""
         if _obs.enabled():
             _obs_metrics.record_serve(
-                "pool_donated" if all(a.is_deleted() for a in self.k_pages + self.v_pages)
+                "pool_donated" if all(a.is_deleted() for a in self._arrays())
                 else "pool_copied")
-        self.k_pages = tuple(k_pages)
-        self.v_pages = tuple(v_pages)
+        self.state = tuple(tuple(arrs) for arrs in state)
+
+    def recurrent_bytes_per_slot(self) -> int:
+        return sum(d.bytes_per_slot() for d in self.layers if isinstance(d, Recurrent))
 
     def copy_page(self, src: int, dst: int) -> None:
-        """Device-copy one page's K/V across every layer (the copy-on-write
-        body after `PageAllocator.fork`). One cached jax.jit program — src
-        and dst ride as traced scalars, so CoW never recompiles; the pools
-        are donated, so one fork copies one page and not the whole pool."""
+        """Device-copy one page's K/V across every layer without a window (the
+        copy-on-write body after `PageAllocator.fork`). One cached jax.jit
+        program — src and dst ride as traced scalars, so CoW never recompiles;
+        the state is donated, so one fork copies one page and not the whole
+        pool."""
         import jax
 
         if self._copy_cfn is None:
-            def _copy(kps, vps, s, d):
-                return (tuple(kp.at[d].set(kp[s]) for kp in kps),
-                        tuple(vp.at[d].set(vp[s]) for vp in vps))
+            full = [isinstance(d, PagedKV) and not d.window for d in self.layers]
 
-            self._copy_cfn = jax.jit(_copy, donate_argnums=(0, 1))
-        kps, vps = self._copy_cfn(self.k_pages, self.v_pages,
-                                  jnp.asarray(src, jnp.int32),
-                                  jnp.asarray(dst, jnp.int32))
-        self.rebind(kps, vps)
+            def _copy(state, s, d):
+                return tuple(tuple(a.at[d].set(a[s]) for a in arrs) if is_full else arrs
+                             for arrs, is_full in zip(state, full))
+
+            self._copy_cfn = jax.jit(_copy, donate_argnums=(0,))
+        self.rebind(self._copy_cfn(self.state, jnp.asarray(src, jnp.int32),
+                                   jnp.asarray(dst, jnp.int32)))
 
     def utilization(self) -> float:
         return self.allocator.utilization()

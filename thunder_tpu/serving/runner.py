@@ -29,22 +29,32 @@ Two compiled programs:
   prefix. Rolled-back positions are simply never committed — their page
   slots hold stale values that the next committed token overwrites.
 
-All are pure functional: pools go in, updated pools come out. Every program
-CONSUMES the pools it is given (`donated_argnums`): the caller rebinds the
-returned ones and never reads the old arrays again, so XLA writes the new
-keys and values in place and copies no pool.
+All are pure functional: cached state goes in, updated state comes out. Every
+program CONSUMES the state it is given (`donated_argnums`): the caller rebinds
+the returned arrays and never reads the old ones again, so XLA writes the new
+keys, values and recurrent state in place and copies no pool.
+
+The programs know nothing of what a layer is. A served model is a list of
+LAYERS, each of which says what it caches (``kv_pages.PagedKV`` with or
+without a window, ``ReadsKV`` of another layer's pools, ``Recurrent`` per-slot
+arrays, or ``None``) and gives its work for a whole prompt (``prefill``), a
+chunk that starts from stored state (``chunk``), one decode token
+(``decode``) and, where speculative decoding can use it, ``verify``. The
+runner hands each layer the ``Step`` (where the program's tokens are: pages,
+positions, slot) and the layer's own arrays, and collects what comes back.
+``DenseBlock`` below is the dense rope GPT block as one such layer; a model
+that is no dense GPT brings its own through ``model.serving()``
+(models/sambay.py).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
-
-import jax
-import jax.numpy as jnp
+from typing import Optional
 
 from ..inference import block_mix, cached_sdpa, split_qkv_rope
 from ..observability import runtime as _obs_runtime
 from ..ops import clang, ltorch
+from .kv_pages import PagedKV
 
 
 def _annotated(cfn, name: str):
@@ -127,8 +137,190 @@ def _ladder(minimum: int, maximum: int):
     return BucketLadder(minimum, maximum)
 
 
+class Step:
+    """Where one program's tokens are, for the layers it runs.
+
+    Every program sets ``program`` (the name of the layer method it calls),
+    ``states`` (one tuple of arrays a layer, replaced as the layers run, so a
+    later layer that reads an earlier one's pools finds the written ones) and
+    ``shared`` (what a layer or the model leaves for later layers: rope rows,
+    a memory). Pages come by kind, ``"full"`` or ``"window"``
+    (``PagedKV.kind``). Beyond that:
+
+    prefill  ``page_ids[kind]`` (Lb / page_size,) the pages the bucket writes;
+             ``last`` the true last token; ``slot`` the decode slot it is for
+    chunk    ``tables[kind]`` (1, n_pages_max) the sequence's whole table;
+             ``chunk_pages[kind]`` the pages this chunk writes; ``start_pos``;
+             ``q_pos`` (1, T) absolute positions; ``last`` relative to the
+             chunk; ``slot``
+    decode   ``tables[kind]`` (B, n_pages_max); ``pos`` (B,) write positions;
+             ``page_of[kind]`` (B,) and ``slot_in_page`` (B,) where each
+             token's k/v lands; ``seq_lens`` (B,); ``live`` (B,) bool, false
+             for idle slots (they carry pos 0 and a null-page row)
+    verify   as decode with K1 tokens a sequence: ``pos_mat`` (B, K1);
+             ``page_of[kind]`` and ``slot_in_page`` flat (B * K1,)
+    """
+
+    def __init__(self, program: str, page_size: int, **where):
+        self.program = program
+        self.page_size = page_size
+        self.states: list = []
+        self.shared: dict = {}
+        self.__dict__.update(where)
+
+
+def _token_pages(tables: dict, pos, ps: int) -> tuple:
+    """For write positions ``pos`` ((B,) or (B, K)): the page of each kind each
+    lands in and its slot there. Positions at/past a table's coverage (draft
+    proposal steps near the max_new/max_seq cap run up to spec_k - 1 positions
+    ahead) go to the null page: garbage logits for those slots are never
+    committed (scheduler accept rule), and the null page is masked
+    everywhere."""
+    B = pos.shape[0]
+    pos2 = ltorch.reshape(pos, (B, -1))
+    page_of = {}
+    for kind, table in tables.items():
+        cover = table.shape[1] * ps
+        page = ltorch.gather(table, 1, ltorch.floor_divide(
+            ltorch.clamp(pos2, max=cover - 1), ps))
+        page = ltorch.where(ltorch.lt(pos2, cover), page, 0)
+        page_of[kind] = ltorch.reshape(page, tuple(pos.shape))
+    return page_of, ltorch.remainder(pos, ps)
+
+
+class DenseBlock:
+    """One block of a dense rope GPT (models/litgpt.py Block, models/moe.py
+    MoEBlock) as a served layer: paged keys and values of every position.
+    The q/k/v split with rope and the residual/MLP tail are shared with the
+    dense engine (inference.split_qkv_rope / inference.block_mix) — one
+    implementation, so solo and batched decode can never drift."""
+
+    def __init__(self, block, cfg):
+        self.block = block
+        self.cfg = cfg
+        self.cache = PagedKV(cfg.n_query_groups, cfg.head_size, cfg.head_size)
+
+    def _qkv(self, step, x):
+        return split_qkv_rope(self.block, self.cfg, self.block.norm_1(x),
+                              step.shared["cos"], step.shared["sin"])
+
+    def _out(self, x, y, T: int):
+        """y (B, n_head, T, hs) attention output -> the block's output."""
+        cfg = self.cfg
+        y = ltorch.reshape(ltorch.permute(y, (0, 2, 1, 3)),
+                           (x.shape[0], T, cfg.n_head * cfg.head_size))
+        return block_mix(self.block, cfg, x, self.block.attn.proj(y))
+
+    def prefill(self, step, x, state):
+        """Dense causal attention over the padded prompt and page write-out
+        of its K/V. Padding tokens beyond ``step.last`` write garbage K/V
+        into the tail pages — causality keeps them out of every real token's
+        attention and seq_lens masks them out of later paged decode."""
+        from ..models.litgpt import _repeat_kv
+
+        cfg = self.cfg
+        T = x.shape[1]
+        ps = step.page_size
+        page_ids = step.page_ids["full"]
+        q_per_kv = cfg.n_head // cfg.n_query_groups
+        q, k, v = self._qkv(step, x)
+        kp = ltorch.index_put(state[0], (page_ids,), _page_blocks(k, ps))
+        vp = ltorch.index_put(state[1], (page_ids,), _page_blocks(v, ps))
+        kq = _repeat_kv(k, q_per_kv) if cfg.n_query_groups != cfg.n_head else k
+        vq = _repeat_kv(v, q_per_kv) if cfg.n_query_groups != cfg.n_head else v
+        return self._out(x, cached_sdpa(q, kq, vq, 0), T), (kp, vp)
+
+    def decode(self, step, x, state):
+        cfg = self.cfg
+        B = x.shape[0]
+        q, k, v = self._qkv(step, x)
+        k_tok = ltorch.reshape(ltorch.permute(k, (0, 2, 1, 3)),
+                               (B, cfg.n_query_groups, cfg.head_size))
+        v_tok = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
+                               (B, cfg.n_query_groups, cfg.head_size))
+        kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
+        vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
+        q3 = ltorch.reshape(q, (B, cfg.n_head, cfg.head_size))
+        y = ltorch.paged_attention(q3, kp, vp, step.tables["full"], step.seq_lens)
+        y = ltorch.reshape(y, (B, 1, cfg.n_head * cfg.head_size))
+        return block_mix(self.block, cfg, x, self.block.attn.proj(y)), (kp, vp)
+
+    def chunk(self, step, x, state):
+        """The chunk WRITES its pages first and then attends the whole table
+        with per-query coverage k_pos <= start_pos + t, so it sees every
+        previously written page — including pages shared from the prefix
+        cache (copy-on-write sharing; the chunk itself only ever writes
+        UNSHARED pages, because shared coverage always ends at or before
+        the chunk start). Pad tokens past ``step.last`` on the final chunk
+        write garbage K/V into reserved-but-unused page slots; every real
+        query masks them out by position, and decode overwrites each slot
+        before seq_lens ever admits it."""
+        ps = step.page_size
+        q, k, v = self._qkv(step, x)
+        kp = ltorch.index_put(state[0], (step.chunk_pages["full"],), _page_blocks(k, ps))
+        vp = ltorch.index_put(state[1], (step.chunk_pages["full"],), _page_blocks(v, ps))
+        y = ltorch.paged_chunk_attention(q, kp, vp, step.tables["full"], step.q_pos)
+        return self._out(x, y, x.shape[1]), (kp, vp)
+
+    def verify(self, step, x, state):
+        """Writes k/v for ALL k+1 tokens at positions pos..pos+k. Rollback is
+        free: the scheduler commits only the accepted prefix; rejected
+        positions hold stale k/v that the next committed token's write
+        replaces before any mask admits it."""
+        cfg = self.cfg
+        B, K1 = x.shape[0], x.shape[1]
+        q, k, v = self._qkv(step, x)
+        k_tok = ltorch.reshape(ltorch.permute(k, (0, 2, 1, 3)),
+                               (B * K1, cfg.n_query_groups, cfg.head_size))
+        v_tok = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
+                               (B * K1, cfg.n_query_groups, cfg.head_size))
+        kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
+        vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
+        y = ltorch.paged_chunk_attention(q, kp, vp, step.tables["full"], step.pos_mat)
+        return self._out(x, y, K1), (kp, vp)
+
+
+class DenseGPT:
+    """models.litgpt.GPT (or moe.MoEGPT) as a served model: its blocks as
+    layers, its rope table gathered once a program at the program's
+    positions, its embedding and its untied head."""
+
+    def __init__(self, gpt):
+        self.gpt = gpt
+        self.cfg = gpt.cfg
+        self.layers = [DenseBlock(block, gpt.cfg) for block in gpt.h]
+        self.max_positions = gpt.cos.shape[0]  # the rope table's rows
+
+    def begin(self, step) -> None:
+        """Rope rows for the program's positions, into ``step.shared``.
+        Decode and verify clamp positions past the table: those slots'
+        logits are garbage and the accept rule never commits them."""
+        from ..core import prims
+
+        gpt, n_elem = self.gpt, self.cfg.rope_n_elem
+        cos_t, sin_t = clang.ensure_proxy(gpt.cos), clang.ensure_proxy(gpt.sin)
+        if step.program == "prefill":
+            cos, sin = cos_t[:step.T], sin_t[:step.T]
+        elif step.program == "chunk":
+            cos = prims.dynamic_slice(cos_t, (step.start_pos, 0), (step.T, n_elem))
+            sin = prims.dynamic_slice(sin_t, (step.start_pos, 0), (step.T, n_elem))
+        else:
+            pos = step.pos if step.program == "decode" else step.pos_mat
+            B = pos.shape[0]
+            rows = ltorch.reshape(ltorch.clamp(pos, max=self.max_positions - 1), (-1,))
+            cos = ltorch.reshape(clang.take(cos_t, rows, 0), (B, 1, -1, n_elem))
+            sin = ltorch.reshape(clang.take(sin_t, rows, 0), (B, 1, -1, n_elem))
+        step.shared["cos"], step.shared["sin"] = cos, sin
+
+    def embed(self, toks):
+        return self.gpt.wte(toks)
+
+    def head(self, x):
+        return self.gpt.lm_head(self.gpt.ln_f(x))
+
+
 class PagedGPTRunner:
-    """Traces and caches the paged prefill/decode programs for one GPT."""
+    """Traces and caches the paged prefill/decode programs for one model."""
 
     def __init__(self, gpt, *, page_size: int):
         from .. import jit as _jit
@@ -137,232 +329,126 @@ class PagedGPTRunner:
         self.gpt = gpt
         self.cfg = gpt.cfg
         self.page_size = page_size
+        # a model that is no dense GPT says how it is served; a GPT is its blocks
+        self.model = gpt.serving() if hasattr(gpt, "serving") else DenseGPT(gpt)
+        self.page_kinds = tuple(k for k in ("full", "window") if any(
+            isinstance(layer.cache, PagedKV) and layer.cache.kind == k
+            for layer in self.model.layers))
 
-        def prefill(params, idx, page_ids, kps, vps, last_pos):
+        def prefill(params, idx, page_ids, state, last_pos, slot):
             with functional_params(gpt, params):
-                return self._forward_prefill(idx, page_ids, kps, vps, last_pos)
+                return self._forward_prefill(idx, page_ids, state, last_pos, slot)
 
-        def decode(params, toks, kps, vps, page_table, pos):
+        def decode(params, toks, state, tables, pos):
             with functional_params(gpt, params):
-                return self._forward_decode(toks, kps, vps, page_table, pos)
+                return self._forward_decode(toks, state, tables, pos)
 
-        def chunk_prefill(params, idx, page_table_row, kps, vps, start_pos, last_rel):
+        def chunk_prefill(params, idx, table_rows, state, start_pos, last_rel, slot):
             with functional_params(gpt, params):
-                return self._forward_chunk(idx, page_table_row, kps, vps,
-                                           start_pos, last_rel)
+                return self._forward_chunk(idx, table_rows, state, start_pos, last_rel, slot)
 
-        def verify(params, toks, kps, vps, page_table, pos):
+        def verify(params, toks, state, tables, pos):
             with functional_params(gpt, params):
-                return self._forward_verify(toks, kps, vps, page_table, pos)
+                return self._forward_verify(toks, state, tables, pos)
 
         prefill.__name__ = "serve_prefill"
         decode.__name__ = "serve_decode"
         chunk_prefill.__name__ = "serve_chunk_prefill"
         verify.__name__ = "serve_verify"
         # the calling convention, not a knob: every call site passes the
-        # cache's pools and rebinds the returned ones on its next line, so
-        # kps and vps are given up (their positions in each signature above)
-        self.prefill_cfn = _annotated(_jit(prefill, donated_argnums=(3, 4)), "serve_prefill")
-        self.decode_cfn = _annotated(_jit(decode, donated_argnums=(2, 3)), "serve_decode")
-        self.chunk_cfn = _annotated(_jit(chunk_prefill, donated_argnums=(3, 4)),
+        # cache's state and rebinds the returned one on its next line, so
+        # the state is given up (its position in each signature above)
+        self.prefill_cfn = _annotated(_jit(prefill, donated_argnums=(3,)), "serve_prefill")
+        self.decode_cfn = _annotated(_jit(decode, donated_argnums=(2,)), "serve_decode")
+        self.chunk_cfn = _annotated(_jit(chunk_prefill, donated_argnums=(3,)),
                                     "serve_chunk_prefill")
-        self.verify_cfn = _annotated(_jit(verify, donated_argnums=(2, 3)), "serve_verify")
+        self.verify_cfn = _annotated(_jit(verify, donated_argnums=(2,)), "serve_verify")
 
-    # block plumbing (qkv split/rope, residual/MoE tail) is shared with the
-    # dense engine: inference.split_qkv_rope / inference.block_mix — one
-    # implementation, so solo and batched decode can never drift
+    def _by_kind(self, per_kind) -> dict:
+        """The programs take page ids and tables as one array a page kind, in
+        the order of ``page_kinds``."""
+        return dict(zip(self.page_kinds, per_kind))
+
+    def _run_layers(self, step, x, state):
+        step.states = list(state)
+        for i, layer in enumerate(self.model.layers):
+            x, step.states[i] = getattr(layer, step.program)(step, x, step.states[i])
+        return x, tuple(tuple(s) for s in step.states)
 
     # -- prefill ----------------------------------------------------------
-    def _forward_prefill(self, idx, page_ids, kps, vps, last_pos):
-        """idx (1, Lb) bucketed prompt; page_ids (Lb/page_size,) pages to
-        write; last_pos scalar int32 — the true last token. Returns
-        (logits (1, V), new k pools, new v pools). Padding tokens beyond
-        last_pos write garbage K/V into the tail pages — causality keeps
-        them out of every real token's attention and seq_lens masks them
-        out of later paged decode."""
+    def _forward_prefill(self, idx, page_ids, state, last_pos, slot):
+        """idx (1, Lb) bucketed prompt; page_ids: for each page kind the
+        (Lb/page_size,) pages to write; last_pos scalar int32 — the true last
+        token; slot scalar int32 — the decode slot the sequence will take
+        (where its recurrent state goes). Returns (logits (1, V), new state)."""
         from ..core import prims
-        from ..models.litgpt import _repeat_kv
 
-        cfg = self.cfg
-        gpt = self.gpt
         B, T = idx.shape
-        ps = self.page_size
-        n_elem = cfg.rope_n_elem
-        cos = clang.ensure_proxy(gpt.cos)[:T]
-        sin = clang.ensure_proxy(gpt.sin)[:T]
-        q_per_kv = cfg.n_head // cfg.n_query_groups
-        x = gpt.wte(idx)
-        new_kps, new_vps = [], []
-        for li, block in enumerate(gpt.h):
-            q, k, v = split_qkv_rope(block, cfg, block.norm_1(x), cos, sin)
-            new_kps.append(ltorch.index_put(kps[li], (page_ids,), _page_blocks(k, ps)))
-            new_vps.append(ltorch.index_put(vps[li], (page_ids,), _page_blocks(v, ps)))
-            kq = _repeat_kv(k, q_per_kv) if cfg.n_query_groups != cfg.n_head else k
-            vq = _repeat_kv(v, q_per_kv) if cfg.n_query_groups != cfg.n_head else v
-            y = cached_sdpa(q, kq, vq, 0)
-            y = ltorch.reshape(ltorch.permute(y, (0, 2, 1, 3)),
-                               (B, T, cfg.n_head * cfg.head_size))
-            x = block_mix(block, cfg, x, block.attn.proj(y))
+        step = Step("prefill", self.page_size, T=T, page_ids=self._by_kind(page_ids),
+                    last=last_pos, slot=slot)
+        self.model.begin(step)
+        x, state = self._run_layers(step, self.model.embed(idx), state)
         # logits at the TRUE last token (the bucket pads past it)
-        x_last = prims.dynamic_slice(x, (0, last_pos, 0), (B, 1, cfg.n_embd))
-        logits = gpt.lm_head(gpt.ln_f(x_last))[:, 0]
-        return logits, tuple(new_kps), tuple(new_vps)
+        x_last = prims.dynamic_slice(x, (0, last_pos, 0), (B, 1, x.shape[-1]))
+        return self.model.head(x_last)[:, 0], state
 
     # -- decode -----------------------------------------------------------
-    def _forward_decode(self, toks, kps, vps, page_table, pos):
-        """toks (Bcap, 1) current tokens; page_table (Bcap, n_pages_max)
-        int32; pos (Bcap,) int32 — each sequence's write position (= tokens
-        already cached; idle slots carry pos 0 and a null-page row).
-        Returns (logits (Bcap, V), new k pools, new v pools).
-
-        Positions at/past the table's coverage (draft proposal steps near
-        the max_new/max_seq cap run the decode program up to spec_k - 1
-        positions ahead) clamp the rope gather and redirect the k/v write to
-        the null page — garbage logits for those slots are never committed
-        (scheduler accept rule), and the null page is masked everywhere."""
-        cfg = self.cfg
-        gpt = self.gpt
-        B, T = toks.shape  # T == 1
-        ps = self.page_size
-        rope_rows = gpt.cos.shape[0]
-        pos_r = ltorch.clamp(pos, max=rope_rows - 1)
-        # per-sequence rope rows: gather cos/sin at each slot's position
-        cos = ltorch.reshape(clang.take(clang.ensure_proxy(gpt.cos), pos_r, 0),
-                             (B, 1, 1, cfg.rope_n_elem))
-        sin = ltorch.reshape(clang.take(clang.ensure_proxy(gpt.sin), pos_r, 0),
-                             (B, 1, 1, cfg.rope_n_elem))
-        npm = page_table.shape[1]
-        in_bounds = ltorch.lt(pos, npm * ps)
-        page_of = ltorch.gather(page_table, 1, ltorch.reshape(
-            ltorch.floor_divide(ltorch.clamp(pos, max=npm * ps - 1), ps),
-            (B, 1)))[:, 0]  # (B,) page id
-        page_of = ltorch.where(in_bounds, page_of, 0)
-        slot = ltorch.remainder(pos, ps)
-        seq_lens = pos + 1  # attention covers the token being written
-        x = gpt.wte(toks)
-        new_kps, new_vps = [], []
-        for li, block in enumerate(gpt.h):
-            q, k, v = split_qkv_rope(block, cfg, block.norm_1(x), cos, sin)
-            k_tok = ltorch.reshape(ltorch.permute(k, (0, 2, 1, 3)),
-                                   (B, cfg.n_query_groups, cfg.head_size))
-            v_tok = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
-                                   (B, cfg.n_query_groups, cfg.head_size))
-            kp = _write_tokens(kps[li], page_of, slot, k_tok)
-            vp = _write_tokens(vps[li], page_of, slot, v_tok)
-            new_kps.append(kp)
-            new_vps.append(vp)
-            q3 = ltorch.reshape(q, (B, cfg.n_head, cfg.head_size))
-            y = ltorch.paged_attention(q3, kp, vp, page_table, seq_lens)
-            y = ltorch.reshape(y, (B, 1, cfg.n_head * cfg.head_size))
-            x = block_mix(block, cfg, x, block.attn.proj(y))
-        logits = gpt.lm_head(gpt.ln_f(x[:, -1]))
-        return logits, tuple(new_kps), tuple(new_vps)
+    def _forward_decode(self, toks, state, tables, pos):
+        """toks (Bcap, 1) current tokens; tables: for each page kind the
+        (Bcap, n_pages_max) int32 page table; pos (Bcap,) int32 — each
+        sequence's write position (= tokens already cached; idle slots carry
+        pos 0 and a null-page row). Returns (logits (Bcap, V), new state)."""
+        step = Step("decode", self.page_size, tables=self._by_kind(tables), pos=pos)
+        self.model.begin(step)
+        step.page_of, step.slot_in_page = _token_pages(step.tables, pos, self.page_size)
+        step.seq_lens = pos + 1  # attention covers the token being written
+        step.live = ltorch.gt(pos, 0)
+        x, state = self._run_layers(step, self.model.embed(toks), state)
+        return self.model.head(x[:, -1]), state
 
     # -- chunked prefill --------------------------------------------------
-    def _forward_chunk(self, idx, page_table_row, kps, vps, start_pos, last_rel):
+    def _forward_chunk(self, idx, table_rows, state, start_pos, last_rel, slot):
         """idx (1, Cb) one page-aligned chunk of a prompt (Cb a multiple of
-        page_size); page_table_row (1, n_pages_max) the sequence's FULL page
-        table; start_pos scalar int32 (multiple of page_size) — the chunk's
-        absolute first position; last_rel scalar int32 — the true last
-        prompt token RELATIVE to the chunk (only meaningful on the final
-        chunk; earlier chunks' logits are discarded by the scheduler).
-        Returns (logits (1, V), new k pools, new v pools).
+        page_size); table_rows: for each page kind the sequence's FULL
+        (1, n_pages_max) page table; start_pos scalar int32 (multiple of
+        page_size) — the chunk's absolute first position; last_rel scalar
+        int32 — the true last prompt token RELATIVE to the chunk (only
+        meaningful on the final chunk; earlier chunks' logits are discarded
+        by the scheduler); slot as in prefill. Returns (logits (1, V), new
+        state)."""
+        from ..core import dtypes, prims
 
-        The chunk WRITES its pages first and then attends the whole table
-        with per-query coverage k_pos <= start_pos + t, so it sees every
-        previously written page — including pages shared from the prefix
-        cache (copy-on-write sharing; the chunk itself only ever writes
-        UNSHARED pages, because shared coverage always ends at or before
-        the chunk start). Pad tokens past `last_rel` on the final chunk
-        write garbage K/V into reserved-but-unused page slots; every real
-        query masks them out by position, and decode overwrites each slot
-        before seq_lens ever admits it."""
-        cfg = self.cfg
-        gpt = self.gpt
         B, T = idx.shape  # B == 1
         ps = self.page_size
-        n_elem = cfg.rope_n_elem
-        from ..core import dtypes, prims
-
-        cos = prims.dynamic_slice(clang.ensure_proxy(gpt.cos), (start_pos, 0),
-                                  (T, n_elem))
-        sin = prims.dynamic_slice(clang.ensure_proxy(gpt.sin), (start_pos, 0),
-                                  (T, n_elem))
-        chunk_pages = ltorch.reshape(
-            prims.dynamic_slice(page_table_row,
-                                (0, ltorch.floor_divide(start_pos, ps)),
-                                (1, T // ps)), (T // ps,))
-        q_pos = ltorch.reshape(
+        tables = self._by_kind(table_rows)
+        step = Step("chunk", ps, T=T, tables=tables, start_pos=start_pos, last=last_rel,
+                    slot=slot)
+        self.model.begin(step)
+        step.chunk_pages = {
+            kind: ltorch.reshape(prims.dynamic_slice(
+                row, (0, ltorch.floor_divide(start_pos, ps)), (1, T // ps)), (T // ps,))
+            for kind, row in tables.items()}
+        step.q_pos = ltorch.reshape(
             prims.iota(T, dtype=dtypes.int32, device=idx.device) + start_pos, (1, T))
-        x = gpt.wte(idx)
-        new_kps, new_vps = [], []
-        for li, block in enumerate(gpt.h):
-            q, k, v = split_qkv_rope(block, cfg, block.norm_1(x), cos, sin)
-            kp = ltorch.index_put(kps[li], (chunk_pages,), _page_blocks(k, ps))
-            vp = ltorch.index_put(vps[li], (chunk_pages,), _page_blocks(v, ps))
-            new_kps.append(kp)
-            new_vps.append(vp)
-            y = ltorch.paged_chunk_attention(q, kp, vp, page_table_row, q_pos)
-            y = ltorch.reshape(ltorch.permute(y, (0, 2, 1, 3)),
-                               (B, T, cfg.n_head * cfg.head_size))
-            x = block_mix(block, cfg, x, block.attn.proj(y))
-        x_last = prims.dynamic_slice(x, (0, last_rel, 0), (B, 1, cfg.n_embd))
-        logits = gpt.lm_head(gpt.ln_f(x_last))[:, 0]
-        return logits, tuple(new_kps), tuple(new_vps)
+        x, state = self._run_layers(step, self.model.embed(idx), state)
+        x_last = prims.dynamic_slice(x, (0, last_rel, 0), (B, 1, x.shape[-1]))
+        return self.model.head(x_last)[:, 0], state
 
     # -- speculative verify -----------------------------------------------
-    def _forward_verify(self, toks, kps, vps, page_table, pos):
+    def _forward_verify(self, toks, state, tables, pos):
         """toks (Bcap, k+1): each sequence's current token followed by its k
         draft proposals; pos (Bcap,) int32 — the position of toks[:, 0].
-        Writes k/v for ALL k+1 tokens at positions pos..pos+k and returns
-        (logits (Bcap, k+1, V), new k pools, new v pools) — logits at every
-        position, so ONE packed target step scores every proposal.
-
-        Rollback is free: the scheduler commits only the accepted prefix;
-        rejected positions hold stale k/v that the next committed token's
-        write replaces before any mask admits it. Writes past the table's
-        coverage (proposals past the max_seq cap) redirect to the null
-        page; rope gathers clamp — those positions' logits are garbage and
-        the accept rule never commits them."""
-        cfg = self.cfg
-        gpt = self.gpt
-        B, K1 = toks.shape
-        ps = self.page_size
-        npm = page_table.shape[1]
-        n_elem = cfg.rope_n_elem
-        rope_rows = gpt.cos.shape[0]
+        Returns (logits (Bcap, k+1, V), new state) — logits at every
+        position, so ONE packed target step scores every proposal."""
         from ..core import dtypes, prims
 
+        B, K1 = toks.shape
         offs = prims.iota(K1, dtype=dtypes.int32, device=toks.device)
         pos_mat = ltorch.reshape(pos, (B, 1)) + ltorch.reshape(offs, (1, K1))  # (B, K1)
-        flat_pos = ltorch.reshape(ltorch.clamp(pos_mat, max=rope_rows - 1),
-                                  (B * K1,))
-        cos = ltorch.reshape(clang.take(clang.ensure_proxy(gpt.cos), flat_pos, 0),
-                             (B, 1, K1, n_elem))
-        sin = ltorch.reshape(clang.take(clang.ensure_proxy(gpt.sin), flat_pos, 0),
-                             (B, 1, K1, n_elem))
-        in_bounds = ltorch.lt(pos_mat, npm * ps)
-        page_of = ltorch.gather(page_table, 1,
-                                ltorch.floor_divide(
-                                    ltorch.clamp(pos_mat, max=npm * ps - 1), ps))
-        page_of = ltorch.where(in_bounds, page_of, 0)
-        page_flat = ltorch.reshape(page_of, (B * K1,))
-        slot_flat = ltorch.reshape(ltorch.remainder(pos_mat, ps), (B * K1,))
-        x = gpt.wte(toks)
-        new_kps, new_vps = [], []
-        for li, block in enumerate(gpt.h):
-            q, k, v = split_qkv_rope(block, cfg, block.norm_1(x), cos, sin)
-            k_tok = ltorch.reshape(ltorch.permute(k, (0, 2, 1, 3)),
-                                   (B * K1, cfg.n_query_groups, cfg.head_size))
-            v_tok = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
-                                   (B * K1, cfg.n_query_groups, cfg.head_size))
-            kp = _write_tokens(kps[li], page_flat, slot_flat, k_tok)
-            vp = _write_tokens(vps[li], page_flat, slot_flat, v_tok)
-            new_kps.append(kp)
-            new_vps.append(vp)
-            y = ltorch.paged_chunk_attention(q, kp, vp, page_table, pos_mat)
-            y = ltorch.reshape(ltorch.permute(y, (0, 2, 1, 3)),
-                               (B, K1, cfg.n_head * cfg.head_size))
-            x = block_mix(block, cfg, x, block.attn.proj(y))
-        logits = gpt.lm_head(gpt.ln_f(x))  # (B, K1, V)
-        return logits, tuple(new_kps), tuple(new_vps)
+        step = Step("verify", self.page_size, tables=self._by_kind(tables), pos_mat=pos_mat)
+        self.model.begin(step)
+        page_of, slot = _token_pages(step.tables, pos_mat, self.page_size)
+        step.page_of = {k: ltorch.reshape(v, (B * K1,)) for k, v in page_of.items()}
+        step.slot_in_page = ltorch.reshape(slot, (B * K1,))
+        x, state = self._run_layers(step, self.model.embed(toks), state)
+        return self.model.head(x), state  # (B, K1, V)

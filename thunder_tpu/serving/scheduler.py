@@ -74,7 +74,7 @@ from ..observability import runtime as _obs_runtime
 from ..observability import telemetry as _obs_tel
 from ..observability import tracing as _obs_trace
 from ..observability.slo import SLOMonitor, SLOPolicy
-from .kv_pages import PagedKVCache, PrefixCache
+from .kv_pages import PagedKV, PagedKVCache, PrefixCache, Recurrent
 from .runner import PagedGPTRunner, quantize_for_serving
 
 _NULL = contextlib.nullcontext()
@@ -117,6 +117,9 @@ class _Request:
     t_last: float = 0.0
     tokens: List[int] = field(default_factory=list)
     pages: List[int] = field(default_factory=list)
+    # pages of the window layers' pools, by page index (position // page_size):
+    # only those that intersect the window are held
+    win_pages: Dict[int, int] = field(default_factory=dict)
     bucket: int = 0
     # admission-time routing state (set by _reserve_pages each admission —
     # a preempted request is re-routed from scratch on resume)
@@ -142,7 +145,12 @@ def _sample_tokens(logits, seeds, pos, temps):
 
 
 class ServingEngine:
-    """Continuous-batching inference over a models.litgpt.GPT (or MoEGPT).
+    """Continuous-batching inference over a models.litgpt.GPT (or MoEGPT), or
+    over any model whose ``serving()`` gives its layers (serving/runner.py):
+    what state the engine keeps — pages of every position, pages of a window
+    that are freed as they leave it, per-slot recurrent arrays — follows from
+    what those layers declare, not from a keyword here. With recurrent layers
+    ``prefix_sharing=True`` and ``draft_gpt=`` raise ValueError (docs/serving.md).
 
     max_batch   decode slots (sequences packed into one decode step)
     page_size   tokens per KV page
@@ -186,8 +194,10 @@ class ServingEngine:
         self.max_batch = max_batch
         self.page_size = page_size
         self.max_seq = max_seq or cfg.block_size
-        rope_rows = gpt.cos.shape[0]
-        if self.max_seq > rope_rows:
+        self.runner = PagedGPTRunner(gpt, page_size=page_size)
+        # a model with a position table can serve no more positions than it has
+        rope_rows = getattr(self.runner.model, "max_positions", None)
+        if rope_rows is not None and self.max_seq > rope_rows:
             raise ValueError(
                 f"max_seq={self.max_seq} exceeds the model's rope cache "
                 f"({rope_rows} positions); build the GPT with a larger block_size")
@@ -224,9 +234,38 @@ class ServingEngine:
                              f">= page_size={page_size}")
         self.preemption = preemption
 
-        self.cache = PagedKVCache(cfg.n_layer, n_pages, page_size,
-                                  cfg.n_query_groups, cfg.head_size, dtype)
-        self.runner = PagedGPTRunner(gpt, page_size=page_size)
+        # the cached state follows from what the model's layers declare
+        layers = [layer.cache for layer in self.runner.model.layers]
+        self.recurrent = any(isinstance(d, Recurrent) for d in layers)
+        # state that is not pages of every position: say so rather than run wrongly
+        if self.recurrent:
+            if prefix_sharing:
+                raise ValueError(
+                    "prefix_sharing=True cannot serve a model with recurrent layers: a shared "
+                    "page holds keys and values, not the scan state at its boundary, so a "
+                    "request that skipped the shared prefix would start from the wrong state")
+            if draft_gpt is not None:
+                raise ValueError(
+                    "draft_gpt= (speculative decoding) cannot serve a model with recurrent "
+                    "layers: verify writes k+1 positions and rolls the rejected ones back by "
+                    "not committing them, and a scan state cannot be rolled back")
+        if any(isinstance(d, PagedKV) and d.window for d in layers):
+            if prefix_sharing:
+                raise ValueError(
+                    "prefix_sharing=True cannot serve a model with window layers: a prefix hit "
+                    "shares the pages of every position only, so the window layers would find "
+                    "no keys for the end of the prefix that was skipped")
+            if draft_gpt is not None:
+                raise ValueError(
+                    "draft_gpt= (speculative decoding) cannot serve a model with window "
+                    "layers: verify writes k+1 positions and window pages are taken for one "
+                    "position a step, so the others would be written to no page")
+        self.cache = PagedKVCache(
+            len(layers), n_pages, page_size, n_kv_heads=0, head_dim=0, dtype=dtype, layers=layers,
+            max_batch=max_batch,
+            # the most pages one prefill program writes: a whole chunk, or its bucket
+            prefill_pages=max(chunk_tokens, self.ladder.bucket_for(chunk_tokens)) // page_size)
+        self.window = self.cache.window
         self.params = {k: p.data for k, p in gpt.named_parameters()}
         self._sampler = jax.jit(_sample_tokens)
 
@@ -268,6 +307,7 @@ class ServingEngine:
         # re-uploaded, while seeds/temps/page tables only change at
         # (un)assignment — their device copies are cached under _pt_dirty
         self._page_tables = np.zeros((max_batch, self.n_pages_max), np.int32)
+        self._win_tables = np.zeros((max_batch, self.n_pages_max), np.int32)
         self._pos = np.zeros((max_batch,), np.int32)
         self._toks = np.zeros((max_batch,), np.int32)
         self._seeds = np.zeros((max_batch,), np.uint32)
@@ -554,7 +594,9 @@ class ServingEngine:
         """Worst-case pages over the request lifetime: the bucketed prefill
         writes bucket//page_size pages, growth extends to L+max_new tokens.
         Reserving the max at admission means decode can never hit a
-        mid-flight out-of-pages (the admission policy; docs/serving.md)."""
+        mid-flight out-of-pages (the admission policy; docs/serving.md).
+        Window pages need no reservation a request: their pool holds every
+        slot's worst case (``__init__``), which is small by construction."""
         bucket = self.ladder.bucket_for(L)
         return max(bucket // self.page_size,
                    PagedKVCache.pages_for(L + max_new, self.page_size))
@@ -769,8 +811,7 @@ class ServingEngine:
         if victim is None:
             return False
         req = self._slots[victim]
-        self.cache.allocator.free(req.pages)
-        req.pages = []
+        self._free_pages(req)
         self._clear_slot(victim)
         with self._lock:
             self._pending_batch.appendleft(req)
@@ -805,9 +846,7 @@ class ServingEngine:
         # bundle (census + page-pool state) BEFORE freeing this request's
         # pages, so the bundle shows the pool as the allocator saw it
         _obs_mem.maybe_post_mortem(exc, step=self.decode_steps, source="serve")
-        if req.pages:
-            self.cache.allocator.free(req.pages)
-            req.pages = []
+        self._free_pages(req)
         try:
             req.future.set_exception(exc)
         except InvalidStateError:
@@ -821,6 +860,62 @@ class ServingEngine:
             _obs_trace.trace_event(req.trace_id, "failed",
                                    request=req.request_id,
                                    error=type(exc).__name__)
+
+    def _free_pages(self, req: _Request) -> None:
+        """Return every page ``req`` holds, of both kinds."""
+        if req.pages:
+            self.cache.allocator.free(req.pages)
+            req.pages = []
+        if req.win_pages:
+            self.cache.window_allocator.free(list(req.win_pages.values()))
+            req.win_pages = {}
+
+    # -- window pages -----------------------------------------------------
+    # A window layer reads key positions > pos - window only, so a sequence
+    # holds window pages for the page indices that intersect that span and no
+    # others: they are taken as its positions reach a new page and handed
+    # back as soon as every position in them is too old. The window pool is
+    # sized for every slot's span plus one prefill program's writes, so these
+    # allocations cannot fail while the books are right.
+    def _window_cover(self, req: _Request, lo: int, hi: int) -> None:
+        """Hold a window page for every page index positions [lo, hi) touch."""
+        ps = self.page_size
+        missing = [j for j in range(lo // ps, (hi - 1) // ps + 1) if j not in req.win_pages]
+        if missing:
+            req.win_pages.update(zip(missing, self.cache.window_allocator.alloc(len(missing))))
+
+    def _window_trim(self, req: _Request, pos: int) -> None:
+        """Free the window pages the query at ``pos`` (the next position to be
+        written) and every later one cannot see: those wholly older than
+        ``pos - window + 1``, and those past ``pos`` that a padded bucket
+        wrote."""
+        ps = self.page_size
+        oldest = pos - self.window + 1
+        dead = [j for j in req.win_pages if (j + 1) * ps <= oldest or j * ps > pos]
+        if dead:
+            self.cache.window_allocator.free([req.win_pages.pop(j) for j in dead])
+            if _obs.enabled():
+                _obs_metrics.record_serve("window_pages_freed", delta=len(dead))
+
+    def _window_row(self, req: _Request) -> np.ndarray:
+        row = np.zeros((self.n_pages_max,), np.int32)
+        for j, page in req.win_pages.items():
+            row[j] = page
+        return row
+
+    def _tables_for(self, req: _Request) -> tuple:
+        """One (1, n_pages_max) table row a page kind, as the chunk program
+        takes them."""
+        rows = [self.cache.page_table_row(req.pages, self.n_pages_max)]
+        if self.window:
+            rows.append(self._window_row(req))
+        return tuple(jnp.asarray(r[None, :]) for r in rows)
+
+    def _recurrent_reset(self) -> None:
+        """A prefill that starts a sequence starts its recurrent state from
+        zero (the program does, in place of the slot's old rows)."""
+        if self.recurrent and _obs.enabled():
+            _obs_metrics.record_serve("recurrent_resets")
 
     def _drop_lost_pools(self, exc: Exception) -> bool:
         """After a failed dispatch. The programs consume the pools they are
@@ -863,25 +958,28 @@ class ServingEngine:
         n_prompt_pages = bucket // self.page_size
         idx = np.zeros((1, bucket), np.int32)
         idx[0, :L] = prompt_eff
-        page_ids = jnp.asarray(req.pages[:n_prompt_pages], jnp.int32)
         t0 = time.perf_counter()
         try:
+            page_ids = [req.pages[:n_prompt_pages]]
+            if self.window:
+                self._window_cover(req, 0, bucket)
+                page_ids.append([req.win_pages[j] for j in range(n_prompt_pages)])
+            page_ids = tuple(jnp.asarray(ids, jnp.int32) for ids in page_ids)
+            last, slot_dev = jnp.asarray(L - 1, jnp.int32), jnp.asarray(slot, jnp.int32)
+            self._recurrent_reset()
             with (_obs_runtime.step_span("serve_prefill", request=req.request_id,
                                          bucket=bucket, prompt_len=L)
                   if obs_on else _NULL):
-                logits, kps, vps = self.runner.prefill_cfn(
-                    self.params, jnp.asarray(idx), page_ids,
-                    self.cache.k_pages, self.cache.v_pages,
-                    jnp.asarray(L - 1, jnp.int32))
-                self.cache.rebind(kps, vps)
+                logits, state = self.runner.prefill_cfn(
+                    self.params, jnp.asarray(idx), page_ids, self.cache.state, last, slot_dev)
+                self.cache.rebind(state)
                 if self.draft_cache is not None:
                     # the draft pool must hold the prompt too — same pages,
                     # same positions, draft weights (logits discarded)
-                    _, dkps, dvps = self.draft_runner.prefill_cfn(
+                    _, dstate = self.draft_runner.prefill_cfn(
                         self.draft_params, jnp.asarray(idx), page_ids,
-                        self.draft_cache.k_pages, self.draft_cache.v_pages,
-                        jnp.asarray(L - 1, jnp.int32))
-                    self.draft_cache.rebind(dkps, dvps)
+                        self.draft_cache.state, last, slot_dev)
+                    self.draft_cache.rebind(dstate)
                 if not resumed:
                     tok0 = self._sampler(logits,
                                          jnp.asarray([req.seed], jnp.uint32),
@@ -892,6 +990,8 @@ class ServingEngine:
             self._fail(req, e)
             self._drop_lost_pools(e)
             return
+        if self.window:
+            self._window_trim(req, L)
         if self.prefix is not None:
             self.prefix.insert(prompt_eff, req.pages)
         t_done = time.perf_counter()
@@ -937,7 +1037,7 @@ class ServingEngine:
             while spent < self.prefill_budget:
                 with self._prefill_phase(req):
                     try:
-                        n_toks, logits = self._run_chunk(req)
+                        n_toks, logits = self._run_chunk(req, slot)
                     except Exception as e:
                         del self._chunking[slot]
                         self._fail(req, e)
@@ -952,7 +1052,7 @@ class ServingEngine:
             if spent >= self.prefill_budget:
                 return
 
-    def _run_chunk(self, req: _Request):
+    def _run_chunk(self, req: _Request, slot: int):
         """One page-aligned chunk of req's effective prompt: write K/V pages,
         attend everything written so far (shared prefix pages included).
         Returns (tokens_spent, logits) — logits only meaningful when this
@@ -963,7 +1063,8 @@ class ServingEngine:
         remaining = L_eff - start
         if remaining > self.chunk_tokens:
             cb = self.chunk_tokens
-            last_rel = cb - 1  # logits discarded; any in-range index works
+            last_rel = cb - 1  # logits discarded, but recurrent layers keep the state at this
+            # index: it must be the chunk's last position
         else:
             cb = self.chunk_ladder.touch(remaining)
             if start + cb > self.max_seq:
@@ -974,27 +1075,29 @@ class ServingEngine:
         idx = np.zeros((1, cb), np.int32)
         n_real = min(cb, remaining)
         idx[0, :n_real] = req.prompt_eff[start:start + n_real]
-        row = jnp.asarray(
-            self.cache.page_table_row(req.pages, self.n_pages_max)[None, :])
+        if self.window:
+            self._window_cover(req, start, start + cb)
+        rows = self._tables_for(req)
+        where = (jnp.asarray(start, jnp.int32), jnp.asarray(last_rel, jnp.int32),
+                 jnp.asarray(slot, jnp.int32))
+        if start == 0:
+            self._recurrent_reset()
         obs_on = _obs.enabled()
         t0 = time.perf_counter()
         with (_obs_runtime.step_span("serve_prefill", request=req.request_id,
                                      bucket=cb, prompt_len=L_eff, chunk=True,
                                      start=start)
               if obs_on else _NULL):
-            logits, kps, vps = self.runner.chunk_cfn(
-                self.params, jnp.asarray(idx), row, self.cache.k_pages,
-                self.cache.v_pages, jnp.asarray(start, jnp.int32),
-                jnp.asarray(last_rel, jnp.int32))
-            self.cache.rebind(kps, vps)
+            logits, state = self.runner.chunk_cfn(
+                self.params, jnp.asarray(idx), rows, self.cache.state, *where)
+            self.cache.rebind(state)
             if self.draft_cache is not None:
-                _, dkps, dvps = self.draft_runner.chunk_cfn(
-                    self.draft_params, jnp.asarray(idx), row,
-                    self.draft_cache.k_pages, self.draft_cache.v_pages,
-                    jnp.asarray(start, jnp.int32),
-                    jnp.asarray(last_rel, jnp.int32))
-                self.draft_cache.rebind(dkps, dvps)
+                _, dstate = self.draft_runner.chunk_cfn(
+                    self.draft_params, jnp.asarray(idx), rows, self.draft_cache.state, *where)
+                self.draft_cache.rebind(dstate)
         req.chunk_pos = min(start + cb, L_eff)
+        if self.window:
+            self._window_trim(req, req.chunk_pos)
         if obs_on:
             _obs_metrics.record_serve("prefill_tokens", delta=n_real)
             dur_ms = (time.perf_counter() - t0) * 1e3
@@ -1046,6 +1149,9 @@ class ServingEngine:
         self._slots[slot] = req
         self._page_tables[slot] = self.cache.page_table_row(req.pages,
                                                             self.n_pages_max)
+        if self.window:
+            self._window_cover(req, pos, pos + 1)
+            self._win_tables[slot] = self._window_row(req)
         self._pos[slot] = pos
         self._toks[slot] = tok
         self._seeds[slot] = req.seed
@@ -1055,6 +1161,7 @@ class ServingEngine:
     def _clear_slot(self, i: int) -> None:
         self._slots[i] = None
         self._page_tables[i] = 0
+        self._win_tables[i] = 0
         self._pos[i] = 0
         self._toks[i] = 0
         self._seeds[i] = 0
@@ -1065,7 +1172,8 @@ class ServingEngine:
         # page tables / seeds / temps only change at slot (un)assignment;
         # re-upload them then, not per token (pos/toks change every step)
         if self._pt_dirty:
-            self._pt_dev = jnp.asarray(self._page_tables)
+            self._pt_dev = ((jnp.asarray(self._page_tables), jnp.asarray(self._win_tables))
+                            if self.window else (jnp.asarray(self._page_tables),))
             self._seeds_dev = jnp.asarray(self._seeds)
             self._temps_dev = jnp.asarray(self._temps)
             self._pt_dirty = False
@@ -1085,6 +1193,15 @@ class ServingEngine:
             self._retire(req)
             self._clear_slot(i)
             return False
+        if self.window:
+            # the next write lands at pos: take its page where pos enters a
+            # new one, hand back the page that has just left the window
+            pos, ps = int(self._pos[i]), self.page_size
+            if pos % ps == 0 or (pos - self.window + 1) % ps == 0:
+                self._window_cover(req, pos, pos + 1)
+                self._window_trim(req, pos)
+                self._win_tables[i] = self._window_row(req)
+                self._pt_dirty = True
         return True
 
     def _decode(self) -> None:
@@ -1106,10 +1223,9 @@ class ServingEngine:
                     toks = jnp.asarray(self._toks[:, None])
                     pos = jnp.asarray(self._pos)
                 with phase("engine:dispatch"):
-                    logits, kps, vps = self.runner.decode_cfn(
-                        self.params, toks, self.cache.k_pages,
-                        self.cache.v_pages, self._pt_dev, pos)
-                    self.cache.rebind(kps, vps)
+                    logits, state = self.runner.decode_cfn(
+                        self.params, toks, self.cache.state, self._pt_dev, pos)
+                    self.cache.rebind(state)
                     # the NEXT token's position is pos+1 (this step wrote
                     # pos); it goes up with the sampler's enqueue, behind
                     # the decode program the device already has
@@ -1134,6 +1250,7 @@ class ServingEngine:
             if obs_on:
                 _obs_metrics.record_serve("decode_steps")
                 _obs_metrics.record_serve("tokens", delta=len(active))
+                self._record_state(len(active))
                 _obs_flight.record_step((t_now - t0) * 1e3, fn="serve_decode",
                                         active=len(active))
                 # online decode-iteration latency percentiles (unsampled, like
@@ -1147,6 +1264,19 @@ class ServingEngine:
                     active=len(active))
             for i in active:
                 self._commit(i, self._slots[i], int(nxt[i]), t_now)
+
+    def _record_state(self, active: int) -> None:
+        """What the cached state of this decode step's sequences took, summed
+        step by step (bus on): pages of the pools without a window, pages of
+        the window pools, bytes of recurrent state. Over ``serve.tokens`` (the
+        sequences, summed the same way) they give the state a sequence holds."""
+        _obs_metrics.record_serve("state.shared_kv_pages", delta=self.cache.allocator.n_used)
+        if self.window:
+            _obs_metrics.record_serve("state.window_pages",
+                                      delta=self.cache.window_allocator.n_used)
+        if self.recurrent:
+            _obs_metrics.record_serve("state.recurrent_bytes",
+                                      delta=active * self.cache.recurrent_bytes_per_slot())
 
     def _spec_decode(self) -> None:
         """Speculative decode iteration: k draft decode steps propose, one
@@ -1179,10 +1309,9 @@ class ServingEngine:
                         cur = jnp.asarray(cand[-1][:, None])
                         pos = jnp.asarray(base_pos + (j - 1))
                     with phase("engine:dispatch"):
-                        dlog, dkps, dvps = self.draft_runner.decode_cfn(
-                            self.draft_params, cur, self.draft_cache.k_pages,
-                            self.draft_cache.v_pages, self._pt_dev, pos)
-                        self.draft_cache.rebind(dkps, dvps)
+                        dlog, dstate = self.draft_runner.decode_cfn(
+                            self.draft_params, cur, self.draft_cache.state, self._pt_dev, pos)
+                        self.draft_cache.rebind(dstate)
                         dj = self._sampler(
                             dlog, self._seeds_dev, jnp.asarray(base_pos + j),
                             self._temps_dev)
@@ -1193,10 +1322,9 @@ class ServingEngine:
                     toks = jnp.asarray(toks_mat)
                     pos = jnp.asarray(base_pos)
                 with phase("engine:dispatch"):
-                    vlog, kps, vps = self.runner.verify_cfn(
-                        self.params, toks, self.cache.k_pages,
-                        self.cache.v_pages, self._pt_dev, pos)
-                    self.cache.rebind(kps, vps)
+                    vlog, state = self.runner.verify_cfn(
+                        self.params, toks, self.cache.state, self._pt_dev, pos)
+                    self.cache.rebind(state)
                     B = toks_mat.shape[0]
                     pos_flat = (base_pos[:, None] + 1
                                 + np.arange(K1, dtype=np.int32)[None, :]).reshape(-1)
@@ -1262,8 +1390,7 @@ class ServingEngine:
         return len(req.tokens) >= req.max_new_tokens
 
     def _retire(self, req: _Request) -> None:
-        self.cache.allocator.free(req.pages)
-        req.pages = []
+        self._free_pages(req)
         n_new = len(req.tokens)
         # t_first == 0.0 only for a prefix-hit request cancelled before its
         # first committed token — report a zero TTFT rather than a negative
