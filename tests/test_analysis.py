@@ -432,19 +432,64 @@ class TestStructuredError:
 # ---------------------------------------------------------------------------
 
 
+class _P:
+    """A tensor proxy as a checker sees one: shape, rank, dtype name."""
+
+    def __init__(self, shape, dtype="bfloat16"):
+        self.shape, self.ndim, self.dtype = shape, len(shape), dtype
+
+
 class TestBudgetAPI:
     def test_paged_vmem_parity_with_pallas_checker(self):
+        """The decode kernel's block of pages and its working set come from the
+        budget API: blocks of whole pages (every KV head), double-buffered."""
         from thunder_tpu.executors import pallasex
 
-        for ps, D, g, kvi, qi in ((16, 64, 4, 2, 2), (16, 128, 8, 2, 4),
-                                  (512, 512, 64, 4, 4)):
-            assert (pallasex._paged_vmem_bytes(ps, D, g, kvi, qi)
-                    == budget.paged_decode_vmem_bytes(ps, D, g, kvi, qi))
-        # the decline decision: an absurd config must exceed the budget
-        big = budget.paged_decode_vmem_bytes(2048, 512, 64, 4, 4)
-        assert not budget.within_vmem(big, budget.paged_vmem_limit())
-        small = budget.paged_decode_vmem_bytes(16, 64, 4, 2, 2)
-        assert budget.within_vmem(small, budget.paged_vmem_limit())
+        for ps, D, Dv, Hkv, g, kvi, qi in ((64, 128, 128, 8, 4, 2, 2), (64, 128, 256, 10, 4, 2, 2),
+                                           (512, 512, 512, 8, 8, 4, 4)):
+            kv = "bfloat16" if kvi == 2 else "float32"
+            pps, hb = pallasex._paged_decode_blocks(Hkv * g, D, qi, _P((9, Hkv, ps, D), kv),
+                                                    _P((9, Hkv, ps, Dv), kv))
+            assert pps == budget.paged_pages_per_step(ps, D, g, kvi, qi, Dv=Dv, n_kv_heads=Hkv)
+            assert hb == budget.paged_head_block(Hkv, g)
+            fits = budget.paged_decode_vmem_bytes(ps, D, g, kvi, qi, Dv=Dv, n_kv_heads=Hkv,
+                                                  pages_per_step=max(pps, 1))
+            assert budget.within_vmem(fits, budget.paged_vmem_limit()) == (pps > 0)
+            if 0 < pps < budget.PAGED_MAX_PAGES_PER_STEP:  # one page more would not fit
+                assert not budget.within_vmem(
+                    budget.paged_decode_vmem_bytes(ps, D, g, kvi, qi, Dv=Dv, n_kv_heads=Hkv,
+                                                   pages_per_step=pps + 1), budget.paged_vmem_limit())
+        # two buffers of a step's pages of K and of V are the bulk of it
+        est = budget.paged_decode_vmem_bytes(64, 128, 4, 2, 2, n_kv_heads=8, pages_per_step=8)
+        assert 2 * 8 * (8 * 64 * 256 * 2) <= est < 2 * 8 * (8 * 64 * 256 * 2) + 2**20
+        # the decline decision: a page of which not even one fits the budget
+        assert budget.paged_pages_per_step(2048, 512, 64, 4, 4, n_kv_heads=8) == 0
+        assert budget.paged_pages_per_step(16, 128, 4, 2, 2, n_kv_heads=2) == 8
+        # the chunk kernel keeps one page of one KV head a grid program
+        assert budget.paged_chunk_vmem_bytes(16, 64, 4, 1, 2, 2) == 2 * 2 * 16 * 64 * 2 + 4 * 64 * 2 * 2 + 4 * 64 * 4 + 32
+
+    def test_paged_decode_declines_a_page_that_does_not_fit(self, monkeypatch):
+        """`pallas.decline.paged_attention.vmem`: not even one page a step fits."""
+        from thunder_tpu import observability
+        from thunder_tpu.executors import pallasex
+
+        monkeypatch.setenv("TT_PAGED_KERNEL", "1")
+        q, table, lens = _P((2, 32, 128)), _P((2, 4), "int32"), _P((2,), "int32")
+        observability.enable()
+        observability.reset()
+        try:
+            assert pallasex.paged_attention_supported(q, _P((8, 8, 64, 128)), _P((8, 8, 64, 128)), table, lens)
+            assert "pallas.decline.paged_attention.vmem" not in observability.counters()
+            huge = _P((8, 8, 8192, 128))
+            assert not pallasex.paged_attention_supported(q, huge, huge, table, lens)
+            assert observability.counters()["pallas.decline.paged_attention.vmem"] == 1
+            # a smaller budget takes fewer pages a step before it declines
+            monkeypatch.setenv("TT_PAGED_VMEM_LIMIT", str(2**20))
+            assert budget.paged_pages_per_step(64, 128, 4, 2, 2, n_kv_heads=8) == 1
+            monkeypatch.setenv("TT_PAGED_VMEM_LIMIT", str(2**19))
+            assert not pallasex.paged_attention_supported(q, _P((8, 8, 64, 128)), _P((8, 8, 64, 128)), table, lens)
+        finally:
+            observability.disable()
 
     def test_flash_block_cap_parity(self):
         # bf16 keeps the swept blocks; 4-byte operands cap at 256 with gcd

@@ -152,9 +152,11 @@ def test_aot_cache_cross_process_parity(tmp_path):
 def test_aot_cache_stale_source_invalidates(tmp_path, monkeypatch):
     from thunder_tpu.utils import aot_cache
 
-    monkeypatch.setattr(aot_cache, "_SRC_DIGEST", "digest-a")
+    from thunder_tpu.compile_service import store
+
+    monkeypatch.setattr(store, "code_fingerprint", lambda: "digest-a")
     k1 = aot_cache.step_key(inputs=(1, 2), extra="x")
-    monkeypatch.setattr(aot_cache, "_SRC_DIGEST", "digest-b")
+    monkeypatch.setattr(store, "code_fingerprint", lambda: "digest-b")
     k2 = aot_cache.step_key(inputs=(1, 2), extra="x")
     assert k1 != k2
 

@@ -91,6 +91,19 @@ class TestBucketLadder:
 # -- ArtifactStore -----------------------------------------------------------
 
 class TestArtifactStore:
+    def test_a_key_holds_the_version_of_the_code_that_lowered_the_trace(self, monkeypatch):
+        """A trace names a program's symbols, not the kernel a symbol lowers to: an executable
+        another version of this package built is not found under the same trace (PR 28: the
+        rewritten paged decode kernel ran the parent's stored executables until the key held it)."""
+        from thunder_tpu.compile_service import store
+
+        assert store.environment_fingerprint()["code"] == store.code_fingerprint()
+        assert len(store.code_fingerprint()) == 64
+        key = artifact_key(kind="t", trace="def f(): ...")
+        assert key == artifact_key(kind="t", trace="def f(): ...")
+        monkeypatch.setattr(store, "code_fingerprint", lambda: "another version")
+        assert artifact_key(kind="t", trace="def f(): ...") != key
+
     def test_roundtrip_and_counters(self, tmp_path):
         st = ArtifactStore(str(tmp_path))
         key = artifact_key(kind="t", x=1)

@@ -33,33 +33,98 @@ def _attention_ref(q, k, v, mask):
     return jnp.einsum("...qk,...kd->...qd", p, v)
 
 
-def _paged_case(rng, B, H, Hkv, D, ps, npm):
+def _paged_case(rng, B, H, Hkv, D, ps, npm, Dv=None):
     """A head-major pool, ragged page tables and the dense (B, Hkv, S, D)
-    keys/values they address."""
+    keys and (B, Hkv, S, Dv) values they address."""
     P = 1 + B * npm
     k_pages = jnp.asarray(rng.randn(P, Hkv, ps, D), jnp.bfloat16)
-    v_pages = jnp.asarray(rng.randn(P, Hkv, ps, D), jnp.bfloat16)
+    v_pages = jnp.asarray(rng.randn(P, Hkv, ps, Dv or D), jnp.bfloat16)
     pt = 1 + rng.permutation(B * npm).reshape(B, npm).astype(np.int32)
     dense = lambda pages: jnp.asarray(  # noqa: E731
-        np.asarray(pages, np.float32)[pt].transpose(0, 2, 1, 3, 4).reshape(B, Hkv, npm * ps, D))
+        np.asarray(pages, np.float32)[pt].transpose(0, 2, 1, 3, 4).reshape(
+            B, Hkv, npm * ps, pages.shape[3]))
     return k_pages, v_pages, jnp.asarray(pt), dense(k_pages), dense(v_pages)
 
 
-@pytest.mark.parametrize("H,Hkv,D,ps", [(16, 16, 64, 64), (16, 16, 64, 16), (32, 8, 128, 16)])
-def test_paged_decode_kernel_on_chip(H, Hkv, D, ps):
+def _as_served(q, k_pages, v_pages):
+    """Heads narrower than the 128 lanes as the engine caches them
+    (serving/runner.py: heads_a_row): ``r`` neighbouring KV heads side by side
+    a pool row, each query over the lanes of its own key head and zeros over
+    the others'. Returns q, the pools and what takes a query head's own value
+    lanes out of the output; at a head of 128 all four are what they were."""
+    from thunder_tpu.serving.runner import heads_a_row
+
+    (B, H, D), (P, Hkv, ps, _) = q.shape, k_pages.shape
+    r, g = heads_a_row(Hkv, D), H // Hkv
+
+    def pack(pages):
+        rows = pages.reshape(P, Hkv // r, r, ps, -1).transpose(0, 1, 3, 2, 4)
+        return rows.reshape(P, Hkv // r, ps, -1)
+
+    def own(out):
+        out = out.reshape(B, Hkv // r, r, g, r, -1)
+        return jnp.stack([out[:, :, j, :, j] for j in range(r)], 2).reshape(B, H, -1)
+
+    spread = q.reshape(B, Hkv // r, r, g, 1, D) * jnp.eye(r, dtype=q.dtype)[:, None, :, None]
+    return spread.reshape(B, H, r * D), pack(k_pages), pack(v_pages), own
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["plain", "window"])
+@pytest.mark.parametrize("H,Hkv,D,Dv,ps", [(16, 16, 64, 64, 64), (16, 16, 64, 64, 16),
+                                           (32, 8, 128, 128, 16), (32, 8, 64, 64, 64),
+                                           (16, 16, 128, 128, 64), (16, 16, 128, 128, 16),
+                                           (32, 8, 128, 128, 64), (40, 10, 128, 256, 64)])
+def test_paged_decode_kernel_on_chip(H, Hkv, D, Dv, ps, window):
+    """One program a sequence over its live pages: lengths from an idle slot
+    (1, on the null page) to the whole table, the table wider than most of
+    them, and every page past a sequence or below its window full of
+    infinities. Heads of 64 reach the kernel two a row, as the engine caches
+    them."""
     from thunder_tpu.executors import pallasex
 
     rng = np.random.RandomState(0)
     B, npm = 8, 2048 // ps
-    k_pages, v_pages, pt, k, v = _paged_case(rng, B, H, Hkv, D, ps, npm)
-    seq_lens = jnp.asarray(rng.randint(1, npm * ps + 1, (B,)), jnp.int32)
+    k_pages, v_pages, pt, k, v = _paged_case(rng, B, H, Hkv, D, ps, npm, Dv)
+    lens = np.concatenate([[1, npm * ps, 9 * ps, 8 * ps + 1], rng.randint(1, npm * ps // 3, (B - 4,))])
+    pt = np.array(pt)
+    pt[0, 0] = 0  # an idle slot reads the null page's first position and nothing else
+    k, v = (dense.at[0, :, 0].set(pages[0, :, 0].astype(jnp.float32))
+            for dense, pages in ((k, k_pages), (v, v_pages)))
+    dead = np.ones(k_pages.shape[0], bool)
+    dead[0] = False
+    for b, n in enumerate(lens):
+        dead[pt[b, (max(n - window, 0) // ps if window else 0):-(-n // ps)]] = False
+    k_pages, v_pages = k_pages.at[dead].set(jnp.inf), v_pages.at[dead].set(jnp.inf)
+    seq_lens = jnp.asarray(lens, jnp.int32)
     q = jnp.asarray(rng.randn(B, H, D), jnp.bfloat16)
-    out = pallasex.paged_attention_decode(q, k_pages, v_pages, pt, seq_lens)
+    *served, own = _as_served(q, k_pages, v_pages)
+    served += [jnp.asarray(pt), seq_lens, 1 / math.sqrt(D), window]
+    assert pallasex.paged_attention_supported(*served)
+    out = own(pallasex.paged_attention_decode(*served))
     g = H // Hkv
-    mask = (jnp.arange(npm * ps)[None, :] < seq_lens[:, None])[:, None, None, :]
-    ref = _attention_ref(q[:, :, None, :], jnp.repeat(k, g, 1), jnp.repeat(v, g, 1), mask)
+    pos = jnp.arange(npm * ps)[None, :]
+    mask = pos < seq_lens[:, None]
+    if window:
+        mask &= pos >= seq_lens[:, None] - window
+    ref = _attention_ref(q[:, :, None, :], jnp.repeat(k, g, 1), jnp.repeat(v, g, 1), mask[:, None, None, :])
+    assert np.isfinite(np.asarray(out, np.float32)).all()
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref)[:, :, 0],
                                atol=2e-2, rtol=2e-2)
+
+
+def test_paged_decode_takes_no_pool_narrower_than_the_lanes():
+    """Heads the engine cannot pack (three of 64) leave a 64-wide pool: the
+    checker declines it, so the gather decomposition runs, and a direct call
+    is refused by name and not by the compiler."""
+    from thunder_tpu.executors import pallasex
+
+    rng = np.random.RandomState(0)
+    k_pages, v_pages, pt, _, _ = _paged_case(rng, 2, 6, 3, 64, 64, 4)
+    q, lens = jnp.asarray(rng.randn(2, 6, 64), jnp.bfloat16), jnp.asarray([5, 200], jnp.int32)
+    assert _as_served(q, k_pages, v_pages)[1].shape == k_pages.shape
+    assert not pallasex.paged_attention_supported(q, k_pages, v_pages, pt, lens)
+    with pytest.raises(ValueError, match="128 lanes"):
+        pallasex.paged_attention_decode(q, k_pages, v_pages, pt, lens)
 
 
 @pytest.mark.parametrize("H,Hkv,D,ps,T", [(16, 16, 64, 64, 512), (16, 16, 64, 64, 5),

@@ -694,3 +694,100 @@ def test_lane_validation_and_batch_fifo(gpt, rng):
     fut = engine.submit(p, max_new_tokens=3, lane="batch")
     engine.drain()
     assert fut.result().n_new_tokens == 3
+
+
+def test_paged_pages_counters_follow_the_lengths(gpt, rng):
+    """`serve.paged.pages_live`: the pages the decode kernel walks, every slot of every decode
+    step (an idle slot reads one); `serve.paged.pages_spanned`: slots x table width, what a grid
+    of one program a table entry stepped over."""
+    from thunder_tpu import observability
+
+    engine = _engine(gpt)  # 4 slots, pages of 8, a table 8 wide
+    prompt = rng.randint(0, gpt.cfg.vocab_size, (9,)).astype(np.int32)
+    observability.enable()
+    observability.reset()
+    try:
+        fut = engine.submit(prompt, max_new_tokens=20)
+        engine.drain()
+        counters = observability.counters()
+    finally:
+        observability.disable()
+    assert fut.result().n_new_tokens == 20
+    steps = counters["serve.decode_steps"]
+    assert steps == 19  # the first token is the prefill's
+    # the step that writes position p reads p + 1 keys: positions 9 .. 27, beside three idle slots
+    live = sum(-(-(p + 1) // 8) + 3 for p in range(9, 9 + steps))
+    assert counters["serve.paged.pages_live"] == live
+    assert counters["serve.paged.pages_spanned"] == steps * 4 * 8
+    assert sorted(n for n in counters if n.startswith("serve.paged.")) == [
+        "serve.paged.pages_live", "serve.paged.pages_spanned"]
+
+
+# ---------------------------------------------------------------------------
+# heads narrower than the lanes: cached several a row
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n_kv_heads,head_size,expected", [
+    (16, 64, 2), (8, 64, 2), (4, 32, 4), (2, 32, 1), (3, 64, 1), (8, 128, 1), (10, 128, 1),
+    (8, 96, 1), (8, 256, 1), (8, 16, 8)])
+def test_heads_a_row_fills_the_lanes_where_the_head_count_divides(n_kv_heads, head_size, expected):
+    from thunder_tpu.serving.runner import heads_a_row
+
+    assert heads_a_row(n_kv_heads, head_size) == expected
+
+
+@pytest.mark.parametrize("kernel", ["decomposition", "kernel"])
+@pytest.mark.parametrize("n_head,n_query_groups,pack", [(4, 4, 4), (8, 4, 4), (4, 2, 2)],
+                         ids=["mha-4-a-row", "gqa-4-a-row", "gqa-2-a-row"])
+def test_narrow_heads_are_cached_packed_and_match_dense(n_head, n_query_groups, pack, kernel,
+                                                        rng, monkeypatch):
+    """Heads narrower than the 128 lanes are cached `pack` a row (a pool of n_query_groups / pack
+    heads, 128 wide), which is the shape the decode kernel takes on the chip. Prefill, chunked
+    prefill with a shared prefix, decode and speculative verify all write and read such rows, and
+    every request still decodes its exact solo stream: through the gather decomposition and
+    through both paged kernels (interpret mode)."""
+    if kernel == "kernel":
+        monkeypatch.setenv("TT_PAGED_KERNEL", "1")
+    head_size = 128 // pack
+    cfg = Config.from_name("tiny-llama2", block_size=64, n_head=n_head, n_embd=n_head * head_size,
+                           n_query_groups=n_query_groups, head_size=head_size)
+    gpt = GPT(cfg, dtype=jnp.float32)
+    dense = GPTInference(gpt, dtype=jnp.float32)
+    engine = _engine(gpt, prefix_sharing=True, chunk_tokens=16, draft_gpt=gpt, spec_k=2)
+    for cache in (engine.cache, engine.draft_cache):
+        assert all(k.shape == (engine.cache.n_pages, n_query_groups // pack, 8, 128)
+                   for k in cache.k_pages + cache.v_pages)
+    sys_p = rng.randint(0, cfg.vocab_size, (24,)).astype(np.int32)
+    reqs = []
+    for tail_len, n, temp, seed in [(0, 5, 0.0, 11), (5, 6, 0.8, 12), (19, 4, 0.0, 13)]:
+        p = np.concatenate([sys_p, rng.randint(0, cfg.vocab_size, (tail_len,)).astype(np.int32)])
+        reqs.append((p, n, temp, seed,
+                     engine.submit(p, max_new_tokens=n, temperature=temp, seed=seed)))
+        if tail_len == 0:
+            engine.drain()  # warm the prefix cache before the sharers arrive
+    engine.drain()
+    for p, n, temp, seed, fut in reqs:
+        out, _ = dense.generate(jnp.asarray(p[None, :]), n, temperature=temp, seed=seed,
+                                scan_decode=False)
+        np.testing.assert_array_equal(fut.result().new_tokens, np.asarray(out)[0, len(p):])
+    assert engine.prefix_hits > 0 and engine.spec_accepted == engine.spec_proposed > 0
+
+
+def test_plain_decode_of_packed_heads_matches_dense(rng):
+    """Without chunks, sharing or a draft: bucketed prefill writes the packed rows, batched decode
+    reads them (llama-350m's shape in small: MHA, two heads of 64 a row)."""
+    cfg = Config.from_name("tiny-llama2", block_size=64, n_head=4, n_embd=256, n_query_groups=4,
+                           head_size=64)
+    gpt = GPT(cfg, dtype=jnp.float32)
+    dense = GPTInference(gpt, dtype=jnp.float32)
+    engine = _engine(gpt)
+    assert engine.cache.k_pages[0].shape[1:] == (2, 8, 128)
+    reqs = []
+    for L, n in [(3, 6), (17, 5), (30, 4)]:
+        p = rng.randint(0, cfg.vocab_size, (L,)).astype(np.int32)
+        reqs.append((p, n, engine.submit(p, max_new_tokens=n)))
+    engine.drain()
+    for p, n, fut in reqs:
+        out, _ = dense.generate(jnp.asarray(p[None, :]), n, scan_decode=False)
+        np.testing.assert_array_equal(fut.result().new_tokens, np.asarray(out)[0, len(p):])

@@ -370,3 +370,28 @@ def test_named_scope_reaches_the_compiled_program():
     assert tagged and len(tagged) < len(region.subsymbols)
     hlo = region.impl.jitted.lower(x).as_text(debug_info=True)
     assert "mamba" in hlo
+
+
+def test_paged_pages_counters_sum_the_window_pools_and_the_shared_one(model):
+    """A window pool's decode walks what the old grid spanned (the window's pages); the shared
+    pool's walks the live context of a table `max_seq` wide; the two counters sum both kinds."""
+    eng = engine_for(model, max_batch=2)
+    observability.enable()
+    observability.reset()
+    try:
+        res = serve(eng, prompts([40], seed=3), [150])[0]
+        counters = observability.counters()
+    finally:
+        observability.disable()
+    assert res.n_new_tokens == 150
+    steps, width = counters["serve.decode_steps"], 256 // PAGE
+    positions = range(40, 40 + steps)  # the step that writes position p reads p + 1 keys
+    assert steps == 149
+    ends = [-(-(p + 1) // PAGE) for p in positions]
+    w_live = sum(e - max(p + 1 - WINDOW, 0) // PAGE + 1 for e, p in zip(ends, positions))  # + the idle slot
+    w_spanned = steps * 2 * (WINDOW // PAGE + 1)
+    live = sum(e + 1 for e in ends)
+    assert counters["serve.paged.pages_live"] == w_live + live
+    assert counters["serve.paged.pages_spanned"] == w_spanned + steps * 2 * width
+    assert 0.5 < w_live / w_spanned <= 1.0 and live / (steps * 2 * width) < 0.3
+    assert not [name for name in counters if name.startswith("serve.paged.window")]

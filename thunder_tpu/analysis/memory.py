@@ -75,25 +75,66 @@ _REGION_BUDGET: list = [None]
 # ---------------------------------------------------------------------------
 
 
-def paged_decode_vmem_bytes(page_size: int, D: int, g: int,
-                            kv_itemsize: int, q_itemsize: int) -> int:
+PAGED_MAX_PAGES_PER_STEP = 8
+
+
+def paged_head_block(n_kv_heads: int, g: int) -> int:
+    """KV heads the paged decode kernel multiplies at once: the fewest that
+    divide ``n_kv_heads`` and give the matmul 8 query rows (one f32 sublane
+    tile), so a group of ``g`` < 8 rows does not leave the tile mostly empty."""
+    for hb in range(1, n_kv_heads + 1):
+        if n_kv_heads % hb == 0 and hb * g >= 8:
+            return hb
+    return n_kv_heads
+
+
+def paged_decode_vmem_bytes(page_size: int, D: int, g: int, kv_itemsize: int, q_itemsize: int,
+                            *, n_kv_heads: int, pages_per_step: int,
+                            Dv: Optional[int] = None) -> int:
     """Estimated per-program VMEM working set of the paged-attention decode
-    kernel: double-buffered k/v page blocks, the q group block, and the f32
-    accumulator/output tiles (pallasex `_paged_attn_kernel`)."""
-    kv = 2 * (2 * page_size * D * kv_itemsize)  # k + v, double-buffered DMA
-    qb = g * D * q_itemsize
-    acc = g * D * 4 + 2 * g * 4  # f32 acc + m/l scratch
-    out = g * D * q_itemsize
-    return kv + qb + acc + out
+    kernel (pallasex `_paged_attn_kernel`), which does all KV heads of one
+    sequence: two buffers of ``pages_per_step`` whole pages (every KV head)
+    of K and of V that its own DMAs fill, the q and output blocks Mosaic's
+    pipeline double-buffers, q stacked by head block, the f32 accumulator with
+    its m/l columns, and the f32 scores, probabilities and masks of one head
+    block over one step's keys. ``Dv`` is the values' width where it is not
+    the keys'."""
+    Dv = D if Dv is None else Dv
+    H = n_kv_heads * g
+    hb = paged_head_block(n_kv_heads, g)
+    kv = 2 * pages_per_step * n_kv_heads * page_size * (D + Dv) * kv_itemsize
+    qo = 2 * H * (D + Dv) * q_itemsize
+    scratch = H * D * q_itemsize + H * Dv * 4 + 2 * H * 4
+    scores = 4 * (hb * g) * (pages_per_step * hb * page_size) * 4
+    return kv + qo + scratch + scores
+
+
+def paged_pages_per_step(page_size: int, D: int, g: int, kv_itemsize: int, q_itemsize: int,
+                         *, n_kv_heads: int, Dv: Optional[int] = None) -> int:
+    """Pages the paged decode kernel copies and multiplies a loop step: as
+    many as fit the budget, ``PAGED_MAX_PAGES_PER_STEP`` at most (a longer
+    block only adds to what a short sequence computes past its end); 0 when
+    not even one page a step fits, and the checker then declines."""
+    limit = paged_vmem_limit()
+    for pps in range(PAGED_MAX_PAGES_PER_STEP, 0, -1):
+        if within_vmem(paged_decode_vmem_bytes(page_size, D, g, kv_itemsize, q_itemsize, Dv=Dv,
+                                               n_kv_heads=n_kv_heads, pages_per_step=pps), limit):
+            return pps
+    return 0
 
 
 def paged_chunk_vmem_bytes(page_size: int, D: int, g: int, T: int,
                            kv_itemsize: int, q_itemsize: int) -> int:
     """VMEM working set of the multi-query paged-attention kernel
-    (pallasex `_paged_chunk_kernel`): same page-pair streaming as the decode
-    kernel but the q block, accumulator, and m/l scratch carry g*T rows (T
-    chunk/verify tokens per kv-head group) instead of g."""
-    return paged_decode_vmem_bytes(page_size, D, g * T, kv_itemsize, q_itemsize)
+    (pallasex `_paged_chunk_kernel`), one page of one KV head a grid program:
+    double-buffered k/v page blocks, the q block of g*T rows (T chunk/verify
+    tokens per kv-head group), and the f32 accumulator/output tiles."""
+    rows = g * T
+    kv = 2 * (2 * page_size * D * kv_itemsize)  # k + v, double-buffered DMA
+    qb = rows * D * q_itemsize
+    acc = rows * D * 4 + 2 * rows * 4  # f32 acc + m/l scratch
+    out = rows * D * q_itemsize
+    return kv + qb + acc + out
 
 
 def grouped_mlp_vmem_bytes(block_c: int, D: int, H: int,

@@ -6,7 +6,7 @@ Three pillars (ROADMAP #3 — kill the cold start):
   (whole-step programs, fusion-region executables) are keyed by a sha256
   over everything that could change the program (canonical trace text,
   transform stack, mesh/sharding spec, jax/jaxlib version, device kind,
-  input avals) and published atomically (tmp dir + ``os.replace`` + a
+  this package's sources, input avals) and published atomically (tmp dir + ``os.replace`` + a
   sha256 ``manifest.json`` — the CheckpointManager pattern at artifact
   scale). Reads are lock-free and digest-verified BEFORE any ``pickle``
   deserialization; publishes serialize under a best-effort lock file.

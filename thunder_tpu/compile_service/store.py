@@ -9,7 +9,8 @@ Layout (under ``store_dir()``)::
 
 ``key`` is a sha256 hex digest over the artifact's full identity
 (``artifact_key``): canonical trace text, transform stack, mesh/sharding
-spec, jax/jaxlib versions, device kind/count, and input avals. Anything
+spec, jax/jaxlib versions, device kind/count, this package's own sources
+(``code_fingerprint``), and input avals. Anything
 that could change the compiled program changes the key — a hit can never
 run a stale program.
 
@@ -38,6 +39,7 @@ observability bus — and ALSO records ``artifact.*`` counters plus
 from __future__ import annotations
 
 import contextlib
+import functools
 import hashlib
 import json
 import os
@@ -84,10 +86,34 @@ def store_enabled() -> bool:
     return jax.default_backend() != "cpu"
 
 
+@functools.lru_cache(maxsize=None)
+def code_fingerprint() -> str:
+    """sha256 over this package's .py sources, once a process — a code change
+    invalidates every cached executable (stale programs must never run
+    silently). A trace names a program's symbols, not what they lower to: a
+    Pallas kernel's body, an executor's lowering and a pass are this code.
+    Files are named by their path inside the package, so two checkouts of one
+    commit agree wherever they sit on disk."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    h = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames[:] = sorted(d for d in dirnames
+                             if d != "__pycache__" and not d.startswith("."))
+        for fn in sorted(filenames):
+            if fn.endswith(".py"):
+                p = os.path.join(dirpath, fn)
+                h.update(os.path.relpath(p, root).encode())
+                with open(p, "rb") as f:
+                    h.update(f.read())
+    return h.hexdigest()
+
+
 def environment_fingerprint() -> dict:
     """The environment fields every key embeds: a serialized executable is
-    only valid for the jax/jaxlib version and device kind that built it."""
-    env = {"jax": "?", "jaxlib": "?", "device_kind": "?", "n_devices": 0}
+    only valid for the jax/jaxlib version and device kind that built it, and
+    for the version of this package that lowered the trace."""
+    env = {"jax": "?", "jaxlib": "?", "device_kind": "?", "n_devices": 0,
+           "code": code_fingerprint()}
     try:
         import jax
 

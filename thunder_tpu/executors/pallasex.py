@@ -1661,30 +1661,24 @@ ex.register_implementation("quant.linear_nf4_kl", _nf4_kl_impl,
 #
 # Continuous-batching decode attends ONE new token per sequence against a
 # block-paged KV pool (vLLM/PagedAttention, SOSP '23): k/v live in a fixed
-# head-major (n_pages, Hkv, page_size, D) pool per layer — one kv head's
-# page is a whole (page_size, D) block, which Mosaic's block rule needs —
-# and each sequence owns a row of page ids. The kernel gathers a
-# sequence's pages via the page table
-# INSIDE the pallas grid — the table rides as a scalar-prefetch operand so
-# the k/v BlockSpec index maps resolve page ids before each DMA — and runs
-# the flash kernel's online-softmax body (base-2 exp, f32 accumulation)
-# across the page axis in VMEM scratch. The ltorch.paged_attention
-# decomposition (ops/ltorch.py) is the pure-jax gather reference path for
-# CPU/interpret mode and for shapes the kernel declines.
-
-# decode working set is small (one page pair + one q group per program), but
-# absurd page_size x D configs must fall back, not fail-to-compile: estimate
-# VMEM like _cap_blocks_for_dtype and decline the claim over the budget
-# (ADVICE r5: estimate + automatic fallback instead of an env escape hatch).
-# Both the estimate formula and the fit decision live in the unified budget
-# API (analysis/memory.py) — this module keeps thin aliases.
-
-
-def _paged_vmem_bytes(page_size: int, D: int, g: int, kv_itemsize: int, q_itemsize: int) -> int:
-    from ..analysis import budget as _budget
-
-    return _budget.paged_decode_vmem_bytes(page_size, D, g, kv_itemsize, q_itemsize)
-
+# head-major (n_pages, Hkv, page_size, D) pool per layer, so a page is one
+# contiguous (Hkv, page_size, D) block, and each sequence owns a row of page
+# ids. The decode kernel runs ONE grid program a sequence. The pools stay in
+# HBM; the program walks the sequence's own live pages (from the window's
+# first page where there is a window), `pages_per_step` whole pages a loop
+# step, copying them itself into a double-buffered VMEM scratch (the next
+# block in flight while this one is multiplied) and carrying the flash
+# kernel's online softmax (base-2 exp, f32 accumulation) across the steps in
+# VMEM scratch. A table entry past the sequence or below the window is never
+# read, and a slot with nothing in it costs one page. The
+# ltorch.paged_attention decomposition (ops/ltorch.py) is the pure-jax gather
+# reference path for CPU/interpret mode and for shapes the kernel declines.
+#
+# Absurd page_size x D configs must fall back, not fail-to-compile: the block
+# of pages is sized against the VMEM budget and the claim declined when not
+# even one page a step fits (ADVICE r5: estimate + automatic fallback instead
+# of an env escape hatch). The estimate, the block size and the fit decision
+# live in the unified budget API (analysis/memory.py).
 
 # Two generalisations ride on both paged kernels (a plain GPT uses neither and
 # runs the kernels as they were):
@@ -1692,27 +1686,32 @@ def _paged_vmem_bytes(page_size: int, D: int, g: int, kv_itemsize: int, q_itemsi
 # * values of another width than the keys: the V pool is (P, Hkv, page_size,
 #   Dv) and the output Dv wide (differential attention reads a pair's two value
 #   heads side by side, twice as wide as QK);
-# * a WINDOW: queries see key positions > q_pos - window only. The grid's page
-#   axis then spans the pages a window can intersect and no more, counted
-#   from the per-sequence first page `lo` (a third scalar-prefetch operand);
-#   table entries below it are never read (the engine has freed those pages).
+# * a WINDOW: queries see key positions > q_pos - window only. The page axis
+#   then spans the pages a window can intersect and no more, counted from the
+#   per-sequence first page `lo` (a third scalar-prefetch operand); table
+#   entries below it are never read (the engine has freed those pages).
 
 
-def _paged_softmax_step(q, k, v, live, acc_scr, m_scr, l_scr, scale):
-    """One page of the online softmax (base-2, f32 accumulation) into scratch."""
+def _paged_softmax_update(q, k, v, live, acc, m_prev, l_prev, scale):
+    """One block of keys of the online softmax (base-2, f32 accumulation):
+    the new (acc, m, l) from the old; m and l are columns."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * (scale * LOG2E)
     s = jnp.where(live, s, NEG_INF)
-    m_prev = m_scr[:][:, 0]
-    l_prev = l_scr[:][:, 0]
+    m_prev = m_prev[:, 0]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
     pexp = jnp.exp2(s - m_new[:, None])
     corr = jnp.exp2(m_prev - m_new)
-    acc_scr[:] = acc_scr[:] * corr[:, None] + jax.lax.dot_general(
+    acc = acc * corr[:, None] + jax.lax.dot_general(
         pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    m_scr[:] = m_new[:, None]
-    l_scr[:] = (l_prev * corr + jnp.sum(pexp, axis=1))[:, None]
+    return acc, m_new[:, None], (l_prev[:, 0] * corr + jnp.sum(pexp, axis=1))[:, None]
+
+
+def _paged_softmax_step(q, k, v, live, acc_scr, m_scr, l_scr, scale):
+    """One page of the online softmax into scratch."""
+    acc_scr[:], m_scr[:], l_scr[:] = _paged_softmax_update(
+        q, k, v, live, acc_scr[:], m_scr[:], l_scr[:], scale)
 
 
 def _paged_init(acc_scr, m_scr, l_scr):
@@ -1727,47 +1726,122 @@ def _paged_write(o_ref, acc_scr, l_scr):
     o_ref[:] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
 
 
-def _paged_attn_kernel(*refs, page_size: int, scale: float, window=None):
-    # grid (B, Hkv, pages) with pages innermost: scratch carries the online
-    # softmax across one sequence's pages; o is written ONCE at the last
-    # page. q_ref: (g, D) — the kv head's q group; k_ref: (page_size, D),
-    # v_ref: (page_size, Dv) — the page the table mapped this grid step to.
+def _paged_attn_kernel(*refs, scale: float, window, pages_per_step: int, head_block: int):
+    # grid (B,), in order: one program does every KV head of one sequence.
+    # q_ref (Hkv, g, D), o_ref (Hkv, g, Dv); k_hbm / v_hbm are the whole pools,
+    # left in HBM. k_buf (2, pps, Hkv, page_size, D) and v_buf (.., Dv) take
+    # `pps` whole pages a step; sems (2, 2) is [k or v, buffer]; slot_ref holds
+    # the buffer a program's first step is in, which the program before it
+    # began to fill during its own last step. Heads go through the MXU
+    # `head_block` at a time, their query groups stacked into the rows of one
+    # matmul against the step's keys of all those heads, with the products of a
+    # row and another head's keys masked like dead positions: a group of 4
+    # rows alone leaves the tiles mostly empty. (Multiplying a step's live
+    # pages only, two or four at a time, was slower at every length: v5e, PR 28.)
+    # q_scr, acc_scr, m_scr and l_scr hold a head block a leading index.
     if window is None:
-        pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr = refs
+        pt_ref, sl_ref, q_ref, k_hbm, v_hbm, o_ref, *scratch = refs
     else:
-        pt_ref, sl_ref, lo_ref, q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr = refs
+        pt_ref, sl_ref, lo_ref, q_ref, k_hbm, v_hbm, o_ref, *scratch = refs
+    k_buf, v_buf, sems, slot_ref, q_scr, acc_scr, m_scr, l_scr = scratch
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    n_p = pl.num_programs(2)
-    g = q_ref.shape[0]
+    Hkv, g, D = q_ref.shape
+    ps, Dv = k_buf.shape[3], v_buf.shape[4]
+    pps, hb = pages_per_step, head_block
+    rows, cols = hb * g, pps * hb * ps
+
+    def span(seq):
+        """Pages [first, end) hold what sequence ``seq``'s query sees."""
+        first = 0 if window is None else lo_ref[seq]
+        return first, (sl_ref[seq] + ps - 1) // ps
+
+    def copies(seq, page, slot, j):
+        pid = pt_ref[seq, page]
+        return (pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[slot, j], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[slot, j], sems.at[1, slot]))
+
+    def fetch(seq, step, slot):
+        first, end = span(seq)
+        for j in range(pps):
+            page = first + step * pps + j
+
+            @pl.when(page < end)
+            def _start():
+                for c in copies(seq, page, slot, j):
+                    c.start()
+
+            # past the sequence nothing is copied and the table is not read:
+            # the keys there are masked whatever they are, and a probability
+            # of zero still needs values that are numbers
+            @pl.when(page >= end)
+            def _blank():
+                v_buf[slot, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+
+    first, end = span(b)
     seq_len = sl_ref[b]
-    page = p if window is None else lo_ref[b] + p
+    n_steps = jnp.maximum((end - first + pps - 1) // pps, 1)
 
-    @pl.when(p == 0)
-    def _init():
-        _paged_init(acc_scr, m_scr, l_scr)
+    @pl.when(b == 0)
+    def _first():
+        slot_ref[0] = 0
+        fetch(0, 0, 0)
 
-    # pages entirely past the sequence are skipped: their table entries
-    # point at the reserved null page, so the DMA is in-bounds but the
-    # values are garbage — never let them into the accumulators
-    @pl.when(page * page_size < seq_len)
-    def _compute():
-        # partially-filled last page: mask slots at/after seq_len
-        k_pos = page * page_size + jax.lax.broadcasted_iota(jnp.int32, (g, page_size), 1)
+    slot0 = slot_ref[0]
+    for h in range(Hkv):
+        q_scr[h // hb, (h % hb) * g:(h % hb + 1) * g, :] = q_ref[h]
+    _paged_init(acc_scr, m_scr, l_scr)
+    q_blocks = [q_scr[i] for i in range(Hkv // hb)]
+    # column c of a step's scores is position c % ps of its page c // (hb * ps),
+    # under head (c // ps) % hb of the block; row r is of head r // g
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    offset = (col // (hb * ps)) * ps + col % ps
+    if hb > 1:
+        own_head = (col // ps) % hb == jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) // g
+
+    def step_body(i, carry):
+        slot = (slot0 + i) % 2
+        page0 = first + i * pps
+
+        @pl.when(i + 1 < n_steps)
+        def _next_step():
+            fetch(b, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == n_steps) & (b + 1 < pl.num_programs(0)))
+        def _next_sequence():
+            fetch(b + 1, 0, 1 - slot)
+            slot_ref[0] = 1 - slot
+
+        for j in range(pps):
+            @pl.when(page0 + j < end)
+            def _wait():
+                for c in copies(b, page0 + j, slot, j):
+                    c.wait()
+
+        k_pos = page0 * ps + offset
         live = k_pos < seq_len
         if window is not None:
             live = live & (k_pos >= seq_len - window)
-        _paged_softmax_step(q_ref[:], k_ref[:], v_ref[:], live, acc_scr, m_scr, l_scr, scale)
+        if hb > 1:
+            live = live & own_head
+        for hi, q in enumerate(q_blocks):
+            at = (slot, slice(None), slice(hi * hb, (hi + 1) * hb))
+            acc_scr[hi], m_scr[hi], l_scr[hi] = _paged_softmax_update(
+                q, k_buf[at].reshape(cols, D), v_buf[at].reshape(cols, Dv), live,
+                acc_scr[hi], m_scr[hi], l_scr[hi], scale)
+        return carry
 
-    @pl.when(p == n_p - 1)
-    def _write():
-        _paged_write(o_ref, acc_scr, l_scr)
+    jax.lax.fori_loop(0, n_steps, step_body, 0)
+
+    for h in range(Hkv):
+        at = (h // hb, slice((h % hb) * g, (h % hb + 1) * g), slice(None))
+        l = l_scr[at]
+        o_ref[h] = (acc_scr[at] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def _paged_layout(q_rows: int, k_pages, v_pages, page_table, window, span: int):
-    """What both paged kernels share of their pallas_call: the page axis of
-    the grid, the block specs of q (``q_rows`` rows a kv head), K, V and the
-    output, and the scratch shapes. With a window the grid spans the pages
+    """The chunk kernel's pallas_call layout: the page axis of the grid
+    (B, Hkv, pages), the block specs of q (``q_rows`` rows a kv head), K, V
+    and the output, and the scratch shapes. With a window the grid spans the pages
     ``span`` positions can touch, from each sequence's first page ``lo``."""
     ps, D = k_pages.shape[2], k_pages.shape[3]
     Dv = v_pages.shape[3]
@@ -1792,6 +1866,41 @@ def _paged_layout(q_rows: int, k_pages, v_pages, page_table, window, span: int):
     return n_pages, rows, k_spec, v_spec, scratch
 
 
+def _paged_decode_blocks(q_heads: int, D: int, q_itemsize: int, k_pages, v_pages):
+    """(pages a loop step, KV heads a matmul) of the decode kernel for these
+    pools: what fits the budget, from the shapes alone. 0 pages: nothing fits."""
+    from ..analysis import budget as _budget
+
+    Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
+    kv_item = jnp.dtype(str(k_pages.dtype).rpartition(".")[2]).itemsize
+    pps = _budget.paged_pages_per_step(ps, D, q_heads // Hkv, kv_item, q_itemsize,
+                                       Dv=Dv, n_kv_heads=Hkv)
+    return pps, _budget.paged_head_block(Hkv, q_heads // Hkv)
+
+
+_PAGED_REFUSALS = {
+    "lanes": "the kernel copies whole pages out of HBM itself, which the compiler takes only of "
+             "rows that fill the 128 lanes (cache narrow heads several a row: "
+             "serving/runner.py heads_a_row)",
+    "vmem": "not one page of every KV head fits the VMEM budget twice over "
+            "(analysis.budget.paged_vmem_limit)",
+}
+
+
+def _paged_decode_refusal(q_heads: int, D: int, q_itemsize: int, k_pages, v_pages,
+                          compiled: bool):
+    """Why the decode kernel cannot take these pools, or None: ``"lanes"`` when
+    it is to be compiled and a pool's rows do not fill the 128 lanes (the
+    kernel copies whole pages out of HBM itself, and Mosaic pads a narrower
+    pool's rows in HBM and then refuses the slice of one page; the interpreter
+    takes any width), ``"vmem"`` when not one page a loop step fits the budget."""
+    if compiled and (D % 128 or v_pages.shape[3] % 128):
+        return "lanes"
+    if _paged_decode_blocks(q_heads, D, q_itemsize, k_pages, v_pages)[0] == 0:
+        return "vmem"
+    return None
+
+
 def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, scale=None, window=None,
                            *, interpret: bool | None = None):
     """q (B, H, D) against a paged pool — keys (P, Hkv, page_size, D), values
@@ -1806,26 +1915,43 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, scale=None
     Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
     g = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    n_pages, rows, k_spec, v_spec, scratch = _paged_layout(
-        g, k_pages, v_pages, page_table, window, window or 0)
+    interpret = _interpret() if interpret is None else interpret
+    if not interpret:  # what the checker declines, a direct call is refused by name
+        refusal = _paged_decode_refusal(H, D, q.dtype.itemsize, k_pages, v_pages, True)
+        if refusal:
+            raise ValueError(f"paged_attention_decode cannot take keys {tuple(k_pages.shape)} and "
+                             f"values {tuple(v_pages.shape)}: {_PAGED_REFUSALS[refusal]}")
+    pps, hb = _paged_decode_blocks(H, D, q.dtype.itemsize, k_pages, v_pages)
+    pps = max(pps, 1)  # the interpreter has no VMEM to run out of
     seq_lens = seq_lens.astype(jnp.int32)
     prefetch = [page_table.astype(jnp.int32), seq_lens]
     if window is not None:
         prefetch.append(jnp.maximum(seq_lens - window, 0) // ps)
-    qg = q.reshape(B, Hkv, g, D)
+
+    def rows(width):
+        return pl.BlockSpec((None, Hkv, g, width), lambda b, *_: (b, 0, 0, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(B, Hkv, n_pages),
-        in_specs=[rows(D), k_spec, v_spec],
+        grid=(B,),
+        in_specs=[rows(D), pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=rows(Dv),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((2, pps, Hkv, ps, D), k_pages.dtype),
+                        pltpu.VMEM((2, pps, Hkv, ps, Dv), v_pages.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((Hkv // hb, hb * g, D), q.dtype),
+                        pltpu.VMEM((Hkv // hb, hb * g, Dv), jnp.float32),
+                        pltpu.VMEM((Hkv // hb, hb * g, 1), jnp.float32),
+                        pltpu.VMEM((Hkv // hb, hb * g, 1), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, page_size=ps, scale=scale, window=window),
+        functools.partial(_paged_attn_kernel, scale=scale, window=window,
+                          pages_per_step=pps, head_block=hb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dv), q.dtype),
-        interpret=_interpret() if interpret is None else interpret,
-    )(*prefetch, qg, k_pages, v_pages)
+        interpret=interpret,
+    )(*prefetch, q.reshape(B, Hkv, g, D), k_pages, v_pages)
     return out.reshape(B, H, Dv)
 
 
@@ -1845,9 +1971,9 @@ def paged_attention_supported(q, k_pages, v_pages, page_table, seq_lens, scale=N
                               window=None) -> bool:
     """Checker: the paged decode kernel claims thunder.paged_attention on
     TPU (TT_PAGED_KERNEL=1 forces the claim for interpret-mode A/B, =0
-    never claims); shapes must fit the page tiling and the estimated VMEM
-    working set must stay under budget — otherwise the pure-jax gather
-    decomposition runs."""
+    never claims); shapes must fit the page tiling, the pools' rows fill the
+    lanes and at least one whole page a loop step fits the VMEM budget —
+    otherwise the pure-jax gather decomposition runs."""
     override = os.environ.get("TT_PAGED_KERNEL")
     if override == "0":
         return False
@@ -1859,16 +1985,9 @@ def paged_attention_supported(q, k_pages, v_pages, page_table, seq_lens, scale=N
     if not (_paged_shapes_ok(H, D, k_pages, v_pages, page_table, B)
             and getattr(seq_lens, "ndim", 0) == 1 and seq_lens.shape[0] == B):
         return False
-    from ..analysis import budget as _budget
-
-    Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
-    kv_item = jnp.dtype(str(k_pages.dtype).rpartition(".")[2]).itemsize
     q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
-    # the wider of the two widths for both: an upper bound
-    if not _budget.within_vmem(_paged_vmem_bytes(ps, max(D, Dv), H // Hkv, kv_item, q_item),
-                               _budget.paged_vmem_limit()):
-        return _decline("paged_attention", "vmem")
-    return True
+    refusal = _paged_decode_refusal(H, D, q_item, k_pages, v_pages, _on_tpu())
+    return _decline("paged_attention", refusal) if refusal else True
 
 
 def _paged_attention_impl(q, k_pages, v_pages, page_table, seq_lens, scale=None, window=None):
@@ -1887,8 +2006,10 @@ ex.register_implementation("thunder.paged_attention", _paged_attention_impl,
 # against the same paged pool: a chunked-prefill chunk (B=1, T=chunk tokens)
 # and the speculative-decoding verify step (T=k+1 proposals per packed
 # sequence), both with PER-QUERY causal coverage k_pos <= q_pos[b, t]. The
-# kernel is the decode kernel with the q group widened to (g*T, D) and the
-# per-query positions riding as a (g*T, 1) VMEM column for the masking.
+# kernel keeps the grid (B, Hkv, pages) of one page of one KV head a program:
+# the table rides as a scalar-prefetch operand so the k/v BlockSpec index maps
+# resolve page ids before each DMA, the q block is (g*T, D) and the per-query
+# positions ride as a (g*T, 1) VMEM column for the masking.
 # Shared (copy-on-write) page tables are transparent: a physical
 # page shared by N sequences simply appears in N table rows, and partial
 # chunk tables (entries past the written prefix) point at the null page,

@@ -49,6 +49,7 @@ that is no dense GPT brings its own through ``model.serving()``
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 from ..inference import block_mix, cached_sdpa, split_qkv_rope
@@ -72,6 +73,54 @@ def _annotated(cfn, name: str):
 
     dispatch._cfn = cfn
     return dispatch
+
+
+LANES = 128  # a TPU vector register's lanes: what a pool row should fill
+
+
+def heads_a_row(n_kv_heads: int, head_size: int) -> int:
+    """KV heads a pool row holds side by side: as many as fill the 128 lanes,
+    where the head count divides. The paged decode kernel copies whole pages
+    out of HBM itself, which Mosaic takes only of rows that fill the lanes (a
+    narrower row is padded to 128 in HBM anyway), so a head of 64 is cached two
+    a row, as models/sambay.py caches a pair."""
+    r = LANES // head_size if LANES % head_size == 0 else 1
+    return r if n_kv_heads % r == 0 else 1
+
+
+def _pack_heads(x, r: int):
+    """(B, Hkv, T, D) keys or values -> (B, Hkv // r, T, r * D): ``r``
+    neighbouring heads side by side in one row."""
+    if r == 1:
+        return x
+    B, Hkv, T, D = x.shape
+    x = ltorch.permute(ltorch.reshape(x, (B, Hkv // r, r, T, D)), (0, 1, 3, 2, 4))
+    return ltorch.reshape(x, (B, Hkv // r, T, r * D))
+
+
+def _spread_queries(q, r: int, g: int):
+    """(B, H, T, D) queries -> (B, H, T, r * D) against rows of ``r`` packed
+    heads: each query over the lanes of its own key head (query head h reads
+    key head h // g), zeros over the others', so the packed row's product is
+    the one head's and a paged kernel sees Hkv // r heads of r * g queries."""
+    if r == 1:
+        return q
+    B, H, T, D = q.shape
+    q = ltorch.reshape(q, (B, H // (r * g), r, g, T, D))
+    zeros = ltorch.zeros_like(q[:, :, 0])
+    rows = [ltorch.cat([q[:, :, j] if i == j else zeros for i in range(r)], -1) for j in range(r)]
+    return ltorch.reshape(ltorch.stack(rows, 2), (B, H, T, r * D))
+
+
+def _own_lanes(y, r: int, g: int):
+    """(B, H, T, r * D) attention output over packed value rows -> (B, H, T, D):
+    each query head's own value head."""
+    if r == 1:
+        return y
+    B, H, T, RD = y.shape
+    y = ltorch.reshape(y, (B, H // (r * g), r, g, T, r, RD // r))
+    return ltorch.reshape(ltorch.stack([y[:, :, j, :, :, j] for j in range(r)], 2),
+                          (B, H, T, RD // r))
 
 
 def _page_blocks(x, ps: int):
@@ -193,16 +242,40 @@ class DenseBlock:
     MoEBlock) as a served layer: paged keys and values of every position.
     The q/k/v split with rope and the residual/MLP tail are shared with the
     dense engine (inference.split_qkv_rope / inference.block_mix) — one
-    implementation, so solo and batched decode can never drift."""
+    implementation, so solo and batched decode can never drift. Heads
+    narrower than the lanes are cached ``pack`` a row (``heads_a_row``) and
+    the queries of the paged programs spread to match: a pool of
+    ``n_query_groups // pack`` heads, ``pack * head_size`` wide."""
 
     def __init__(self, block, cfg):
         self.block = block
         self.cfg = cfg
-        self.cache = PagedKV(cfg.n_query_groups, cfg.head_size, cfg.head_size)
+        self.pack = heads_a_row(cfg.n_query_groups, cfg.head_size)
+        self.cache = PagedKV(cfg.n_query_groups // self.pack, self.pack * cfg.head_size,
+                             self.pack * cfg.head_size)
+        self.scale = 1.0 / math.sqrt(cfg.head_size)  # of a key head, not of the packed row
 
     def _qkv(self, step, x):
         return split_qkv_rope(self.block, self.cfg, self.block.norm_1(x),
                               step.shared["cos"], step.shared["sin"])
+
+    def _rows(self, k, v):
+        """k and v (B, n_query_groups, T, hs) as the pool's rows."""
+        return _pack_heads(k, self.pack), _pack_heads(v, self.pack)
+
+    def _tokens(self, k, v):
+        """The pool rows of k and v (B, n_query_groups, T, hs), a token each:
+        two (B * T, heads, width)."""
+        return tuple(ltorch.reshape(ltorch.permute(rows, (0, 2, 1, 3)),
+                                    (-1, self.cache.heads, self.cache.k_dim))
+                     for rows in self._rows(k, v))
+
+    def _paged(self, attend, q, kp, vp, table, where):
+        """``attend`` (a paged attention of ops/ltorch.py) for q (B, n_head, T,
+        hs) against the pools -> (B, n_head, T, hs)."""
+        g = self.cfg.n_head // self.cfg.n_query_groups
+        y = attend(_spread_queries(q, self.pack, g), kp, vp, table, where, self.scale)
+        return _own_lanes(y, self.pack, g)
 
     def _out(self, x, y, T: int):
         """y (B, n_head, T, hs) attention output -> the block's output."""
@@ -224,26 +297,26 @@ class DenseBlock:
         page_ids = step.page_ids["full"]
         q_per_kv = cfg.n_head // cfg.n_query_groups
         q, k, v = self._qkv(step, x)
-        kp = ltorch.index_put(state[0], (page_ids,), _page_blocks(k, ps))
-        vp = ltorch.index_put(state[1], (page_ids,), _page_blocks(v, ps))
+        k_rows, v_rows = self._rows(k, v)
+        kp = ltorch.index_put(state[0], (page_ids,), _page_blocks(k_rows, ps))
+        vp = ltorch.index_put(state[1], (page_ids,), _page_blocks(v_rows, ps))
         kq = _repeat_kv(k, q_per_kv) if cfg.n_query_groups != cfg.n_head else k
         vq = _repeat_kv(v, q_per_kv) if cfg.n_query_groups != cfg.n_head else v
         return self._out(x, cached_sdpa(q, kq, vq, 0), T), (kp, vp)
 
     def decode(self, step, x, state):
-        cfg = self.cfg
-        B = x.shape[0]
         q, k, v = self._qkv(step, x)
-        k_tok = ltorch.reshape(ltorch.permute(k, (0, 2, 1, 3)),
-                               (B, cfg.n_query_groups, cfg.head_size))
-        v_tok = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
-                               (B, cfg.n_query_groups, cfg.head_size))
+        k_tok, v_tok = self._tokens(k, v)
         kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
         vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
-        q3 = ltorch.reshape(q, (B, cfg.n_head, cfg.head_size))
-        y = ltorch.paged_attention(q3, kp, vp, step.tables["full"], step.seq_lens)
-        y = ltorch.reshape(y, (B, 1, cfg.n_head * cfg.head_size))
-        return block_mix(self.block, cfg, x, self.block.attn.proj(y)), (kp, vp)
+
+        def attend(q4, kp, vp, table, seq_lens, scale):  # one query a sequence: (B, H, D)
+            B, H, _, D = q4.shape
+            y = ltorch.paged_attention(ltorch.reshape(q4, (B, H, D)), kp, vp, table, seq_lens, scale)
+            return ltorch.reshape(y, (B, H, 1, y.shape[-1]))
+
+        y = self._paged(attend, q, kp, vp, step.tables["full"], step.seq_lens)
+        return self._out(x, y, 1), (kp, vp)
 
     def chunk(self, step, x, state):
         """The chunk WRITES its pages first and then attends the whole table
@@ -257,9 +330,10 @@ class DenseBlock:
         before seq_lens ever admits it."""
         ps = step.page_size
         q, k, v = self._qkv(step, x)
-        kp = ltorch.index_put(state[0], (step.chunk_pages["full"],), _page_blocks(k, ps))
-        vp = ltorch.index_put(state[1], (step.chunk_pages["full"],), _page_blocks(v, ps))
-        y = ltorch.paged_chunk_attention(q, kp, vp, step.tables["full"], step.q_pos)
+        k_rows, v_rows = self._rows(k, v)
+        kp = ltorch.index_put(state[0], (step.chunk_pages["full"],), _page_blocks(k_rows, ps))
+        vp = ltorch.index_put(state[1], (step.chunk_pages["full"],), _page_blocks(v_rows, ps))
+        y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"], step.q_pos)
         return self._out(x, y, x.shape[1]), (kp, vp)
 
     def verify(self, step, x, state):
@@ -267,17 +341,12 @@ class DenseBlock:
         free: the scheduler commits only the accepted prefix; rejected
         positions hold stale k/v that the next committed token's write
         replaces before any mask admits it."""
-        cfg = self.cfg
-        B, K1 = x.shape[0], x.shape[1]
         q, k, v = self._qkv(step, x)
-        k_tok = ltorch.reshape(ltorch.permute(k, (0, 2, 1, 3)),
-                               (B * K1, cfg.n_query_groups, cfg.head_size))
-        v_tok = ltorch.reshape(ltorch.permute(v, (0, 2, 1, 3)),
-                               (B * K1, cfg.n_query_groups, cfg.head_size))
+        k_tok, v_tok = self._tokens(k, v)
         kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
         vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
-        y = ltorch.paged_chunk_attention(q, kp, vp, step.tables["full"], step.pos_mat)
-        return self._out(x, y, K1), (kp, vp)
+        y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"], step.pos_mat)
+        return self._out(x, y, x.shape[1]), (kp, vp)
 
 
 class DenseGPT:

@@ -292,10 +292,11 @@ class ServingEngine:
             # the draft pool SHARES the target allocator: one allocation and
             # one page table cover both models, so sharing/CoW/preemption
             # bookkeeping never runs twice
-            self.draft_cache = PagedKVCache(
-                dcfg.n_layer, n_pages, page_size, dcfg.n_query_groups,
-                dcfg.head_size, dtype, allocator=self.cache.allocator)
             self.draft_runner = PagedGPTRunner(draft_gpt, page_size=page_size)
+            self.draft_cache = PagedKVCache(
+                dcfg.n_layer, n_pages, page_size, n_kv_heads=0, head_dim=0, dtype=dtype,
+                allocator=self.cache.allocator,
+                layers=[layer.cache for layer in self.draft_runner.model.layers])
             self.draft_params = {k: p.data
                                  for k, p in draft_gpt.named_parameters()}
         else:
@@ -1251,6 +1252,7 @@ class ServingEngine:
                 _obs_metrics.record_serve("decode_steps")
                 _obs_metrics.record_serve("tokens", delta=len(active))
                 self._record_state(len(active))
+                self._record_paged_pages()
                 _obs_flight.record_step((t_now - t0) * 1e3, fn="serve_decode",
                                         active=len(active))
                 # online decode-iteration latency percentiles (unsampled, like
@@ -1277,6 +1279,24 @@ class ServingEngine:
         if self.recurrent:
             _obs_metrics.record_serve("state.recurrent_bytes",
                                       delta=active * self.cache.recurrent_bytes_per_slot())
+
+    def _record_paged_pages(self) -> None:
+        """Pages the paged decode kernel walked in this decode step (bus on),
+        summed over every slot of the packed program (an idle slot reads one)
+        and over the page kinds, beside what a grid of one program a table
+        entry stepped over: the table's width a slot, or the window's span."""
+        ps, slots = self.page_size, len(self._pos)
+        lens = self._pos.astype(np.int64) + 1
+        ends = -(-lens // ps)
+        live = spanned = 0
+        if any(isinstance(d, PagedKV) and not d.window for d in self.cache.layers):
+            live += int(ends.sum())
+            spanned += slots * self.n_pages_max
+        if self.window:
+            live += int((ends - np.maximum(lens - self.window, 0) // ps).sum())
+            spanned += slots * min(self.n_pages_max, -(-self.window // ps) + 1)
+        _obs_metrics.record_serve("paged.pages_live", delta=live)
+        _obs_metrics.record_serve("paged.pages_spanned", delta=spanned)
 
     def _spec_decode(self) -> None:
         """Speculative decode iteration: k draft decode steps propose, one

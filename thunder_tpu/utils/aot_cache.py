@@ -33,8 +33,6 @@ import os
 from ..compile_service import store as _cs
 from ..observability import metrics as _obs_metrics
 
-_SRC_DIGEST: str | None = None
-
 
 def enabled() -> bool:
     return _cs.store_enabled()
@@ -47,26 +45,9 @@ def cache_dir() -> str:
 
 
 def source_digest() -> str:
-    """sha256 over the package's .py sources — a code change invalidates
-    every cached executable (stale programs must never run silently). Files
-    are named by their path inside the package, so two checkouts of one
-    commit agree wherever they sit on disk."""
-    global _SRC_DIGEST
-    if _SRC_DIGEST is not None:
-        return _SRC_DIGEST
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    h = hashlib.sha256()
-    for dirpath, dirnames, filenames in os.walk(root):
-        dirnames[:] = sorted(d for d in dirnames
-                             if d != "__pycache__" and not d.startswith("."))
-        for fn in sorted(filenames):
-            if fn.endswith(".py"):
-                p = os.path.join(dirpath, fn)
-                h.update(os.path.relpath(p, root).encode())
-                with open(p, "rb") as f:
-                    h.update(f.read())
-    _SRC_DIGEST = h.hexdigest()
-    return _SRC_DIGEST
+    """sha256 over the package's .py sources (``store.code_fingerprint``,
+    which every key of the store embeds)."""
+    return _cs.code_fingerprint()
 
 
 def _spec(tree) -> str:

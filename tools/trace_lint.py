@@ -117,10 +117,13 @@ def _budget_table() -> list[dict]:
     from thunder_tpu.analysis import budget
 
     rows = []
-    for ps, D, g, item in ((16, 64, 4, 2), (16, 128, 8, 2), (512, 512, 32, 4)):
-        nb = budget.paged_decode_vmem_bytes(ps, D, g, item, item)
+    for ps, D, Hkv, g, item in ((64, 128, 8, 4, 2), (16, 128, 2, 8, 2), (4096, 512, 8, 4, 4)):
+        pps = budget.paged_pages_per_step(ps, D, g, item, item, n_kv_heads=Hkv)
+        nb = budget.paged_decode_vmem_bytes(ps, D, g, item, item, n_kv_heads=Hkv,
+                                            pages_per_step=max(pps, 1))
         rows.append({"kernel": "paged_attention_decode",
-                     "shape": f"page_size={ps} D={D} g={g} itemsize={item}",
+                     "shape": (f"page_size={ps} D={D} kv_heads={Hkv} g={g} itemsize={item} "
+                               f"pages_per_step={pps}"),
                      "est_bytes": nb,
                      "fits": budget.within_vmem(nb, budget.paged_vmem_limit())})
     for widest, bq, bk, T in ((2, 512, 1024, 2048), (4, 512, 1024, 2048)):
