@@ -39,6 +39,17 @@ def rng():
     return np.random.RandomState(1234)
 
 
+@pytest.fixture
+def pallas_claims(monkeypatch):
+    """Interpreted claims: the checkers of the kernels that claim only on the
+    chip (paged, grouped MLP, ring flash, int8 and fp8 linear) claim here too,
+    and their kernels run in Pallas interpret mode. Patches the one predicate
+    they all ask; undone when the test ends."""
+    from thunder_tpu.executors import pallasex
+
+    monkeypatch.setattr(pallasex, "_claims_on_platform", lambda: True)
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running test (deselected by the tier-1 run)")

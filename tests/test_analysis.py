@@ -468,12 +468,11 @@ class TestBudgetAPI:
         # the chunk kernel keeps one page of one KV head a grid program
         assert budget.paged_chunk_vmem_bytes(16, 64, 4, 1, 2, 2) == 2 * 2 * 16 * 64 * 2 + 4 * 64 * 2 * 2 + 4 * 64 * 4 + 32
 
-    def test_paged_decode_declines_a_page_that_does_not_fit(self, monkeypatch):
+    def test_paged_decode_declines_a_page_that_does_not_fit(self, monkeypatch, pallas_claims):
         """`pallas.decline.paged_attention.vmem`: not even one page a step fits."""
         from thunder_tpu import observability
         from thunder_tpu.executors import pallasex
 
-        monkeypatch.setenv("TT_PAGED_KERNEL", "1")
         q, table, lens = _P((2, 32, 128)), _P((2, 4), "int32"), _P((2,), "int32")
         observability.enable()
         observability.reset()
@@ -484,9 +483,9 @@ class TestBudgetAPI:
             assert not pallasex.paged_attention_supported(q, huge, huge, table, lens)
             assert observability.counters()["pallas.decline.paged_attention.vmem"] == 1
             # a smaller budget takes fewer pages a step before it declines
-            monkeypatch.setenv("TT_PAGED_VMEM_LIMIT", str(2**20))
+            monkeypatch.setattr(budget, "paged_vmem_limit", lambda: 2**20)
             assert budget.paged_pages_per_step(64, 128, 4, 2, 2, n_kv_heads=8) == 1
-            monkeypatch.setenv("TT_PAGED_VMEM_LIMIT", str(2**19))
+            monkeypatch.setattr(budget, "paged_vmem_limit", lambda: 2**19)
             assert not pallasex.paged_attention_supported(q, _P((8, 8, 64, 128)), _P((8, 8, 64, 128)), table, lens)
         finally:
             observability.disable()

@@ -298,12 +298,10 @@ def test_paged_chunk_kernel_matches_reference(rng):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_paged_attention_vmem_fallback_declines():
+def test_paged_attention_vmem_fallback_declines(pallas_claims):
     """The ADVICE VMEM-estimation pattern: a page_size x D working set over
     the budget makes the checker decline (the jax gather decomposition runs
     instead of a kernel that would fail to fit VMEM)."""
-    import os
-
     from thunder_tpu.executors import pallasex
 
     class _P:
@@ -317,9 +315,5 @@ def test_paged_attention_vmem_fallback_declines():
     huge = _P((8, 2, 8192, 512))  # 2 * 2 * 8192*512*4B ≈ 67 MB of k/v blocks
     pt = _P((2, 4), "int32")
     sl = _P((2,), "int32")
-    os.environ["TT_PAGED_KERNEL"] = "1"
-    try:
-        assert pallasex.paged_attention_supported(q, small, small, pt, sl)
-        assert not pallasex.paged_attention_supported(q, huge, huge, pt, sl)
-    finally:
-        del os.environ["TT_PAGED_KERNEL"]
+    assert pallasex.paged_attention_supported(q, small, small, pt, sl)
+    assert not pallasex.paged_attention_supported(q, huge, huge, pt, sl)

@@ -225,19 +225,18 @@ class TestFusedFP8:
                                    rtol=1e-5, atol=1e-4)
         assert float(ax) == float(ax_ref) and float(aw) == float(aw_ref)
 
-    def test_checker_requires_tpu_or_force(self, monkeypatch):
+    def test_checker_requires_tpu_or_force(self, request):
         from thunder_tpu.executors.pallasex import fp8_linear_fused_supported
 
         x = jnp.zeros((64, 256), jnp.float32)
         w = jnp.zeros((128, 256), jnp.float32)
-        monkeypatch.delenv("TT_FP8_FUSED", raising=False)
         assert not fp8_linear_fused_supported(x, w)  # CPU: off by default
-        monkeypatch.setenv("TT_FP8_FUSED", "force")
+        request.getfixturevalue("pallas_claims")
         assert fp8_linear_fused_supported(x, w)
         # misaligned shapes never claim, even forced
         assert not fp8_linear_fused_supported(jnp.zeros((64, 250)), w)
 
-    def test_forced_fused_training_matches_unfused(self, monkeypatch):
+    def test_forced_fused_training_matches_unfused(self, request):
         """End-to-end: the fp8 training transform produces the same losses
         whether the linears dispatch to the fused kernel or the unfused
         four-program road."""
@@ -258,14 +257,14 @@ class TestFusedFP8:
             def forward(self, xx, yy):
                 return ltorch.mse_loss(self.fc2(ltorch.relu(self.fc1(xx))), yy)
 
-        def run(mode):
-            monkeypatch.setenv("TT_FP8_FUSED", mode)
+        def run():
             tm = tt.jit(Net(), transforms=[FP8TrainingTransform()])
             step = TrainStep(tm, optim.AdamW(lr=1e-2))
             return [float(step(x, y)) for _ in range(3)]
 
-        losses_unfused = run("0")
-        losses_fused = run("force")
+        losses_unfused = run()  # off the chip the checker declines
+        request.getfixturevalue("pallas_claims")
+        losses_fused = run()
         np.testing.assert_allclose(losses_fused, losses_unfused, rtol=1e-6)
 
 
@@ -346,8 +345,8 @@ class TestInt8Decode:
         with pytest.raises(ValueError, match="quantization mode"):
             quantize_for_serving(gpt, "int4")
 
-    def test_int8_kernel_checker_gated_off_tpu(self, monkeypatch):
-        """Without TT_INT8_PALLAS_CPU the interpret-mode kernel must not
+    def test_int8_kernel_checker_gated_off_tpu(self, request):
+        """Unless a test turns the claim on, the interpret-mode kernel must not
         claim the op on CPU — serving there measures the XLA dequant-matmul,
         not a per-call interpreter."""
         from thunder_tpu.executors.pallasex import _int8_linear_supported
@@ -355,7 +354,6 @@ class TestInt8Decode:
         x = jnp.zeros((8, 256), jnp.bfloat16)
         q = jnp.zeros((128, 256), jnp.int8)
         s = jnp.zeros((128,), jnp.float32)
-        monkeypatch.delenv("TT_INT8_PALLAS_CPU", raising=False)
         assert not _int8_linear_supported(x, q, s)
-        monkeypatch.setenv("TT_INT8_PALLAS_CPU", "1")
+        request.getfixturevalue("pallas_claims")
         assert _int8_linear_supported(x, q, s)

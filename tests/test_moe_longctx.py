@@ -17,6 +17,7 @@ import pytest
 
 import thunder_tpu as tt
 from thunder_tpu import nn, observability, optim
+from thunder_tpu.analysis import budget
 from thunder_tpu.models.moe import MoEConfig, MoEMLP, publish_moe_stats
 from thunder_tpu.ops import ltorch
 from thunder_tpu.parallel import make_mesh
@@ -24,6 +25,10 @@ from thunder_tpu.training import TrainStep, _shard_map_compat
 
 pytestmark = pytest.mark.skipif(len(jax.devices()) < 8,
                                 reason="needs 8 virtual devices")
+
+
+# two orders of one float32 sum (test_grouped_vs_dense_bit_identity says why)
+_SUM_ORDER = dict(rtol=0, atol=float(np.finfo(np.float32).eps))
 
 
 def _moe_pair(cfg, rng, N=64):
@@ -44,9 +49,16 @@ def _moe_pair(cfg, rng, N=64):
 @pytest.mark.parametrize("scenario", ["drop_free", "over_capacity", "odd_E"])
 def test_grouped_vs_dense_bit_identity(scenario, rng):
     """The grouped (packed-bins) road and the one-hot einsum road share the
-    router and the capacity/drop decision, so their outputs are EQUAL —
+    router and the capacity/drop decision, so their outputs are equal —
     including dropped tokens (zero weight vs never-binned) and ragged
-    per-expert loads."""
+    per-expert loads — up to the order of a float32 sum: the einsum adds a
+    token's experts (and its zero-weighted non-choices) in one order, the
+    bins' combine in another, and the sums differ in their last places. On
+    this tree (outputs up to 0.25 in size): drop_free 262 of 2,048 elements
+    by at most 1.5e-8 (one unit in the last place), over_capacity 1,019 by at
+    most 6.7e-8, odd_E 1,742 by at most 5.2e-8, the empty-expert case below
+    227 by at most 6.0e-8. Relative to an output near zero that is up to
+    1.8e-2, so `_SUM_ORDER` is absolute: float32's epsilon."""
     cfg = {
         "drop_free": MoEConfig(n_embd=32, intermediate_size=48, n_expert=8,
                                n_expert_per_token=2, capacity_factor=None),
@@ -56,7 +68,7 @@ def test_grouped_vs_dense_bit_identity(scenario, rng):
                            n_expert_per_token=2, capacity_factor=1.0),
     }[scenario]
     _, _, out_g, out_d = _moe_pair(cfg, rng)
-    np.testing.assert_array_equal(out_g, out_d)
+    np.testing.assert_allclose(out_g, out_d, **_SUM_ORDER)
 
 
 @pytest.mark.moe
@@ -75,7 +87,7 @@ def test_grouped_vs_dense_empty_expert_and_drops(rng):
     out_g = np.asarray(tt.jit(m)(x))
     cfg.dispatch = "dense"
     out_d = np.asarray(tt.jit(m)(x))
-    np.testing.assert_array_equal(out_g, out_d)
+    np.testing.assert_allclose(out_g, out_d, **_SUM_ORDER)
     # capacity(64) = ceil(0.25*64*1/4)=4 -> rounded to the 8-row sublane
     # tile; 64 assignments to expert 0 minus cap kept = 56 dropped, and the
     # dropped tokens contribute EXACT zeros (their row is all-zero output
@@ -100,22 +112,21 @@ def _grouped_args(rng, E=4, cap=16, D=32, H=48, fill=None):
 
 
 @pytest.mark.moe
-def test_grouped_kernel_interpret_matches_decomposition(rng, monkeypatch):
-    """TT_GROUPED_KERNEL=1 forces the Pallas kernel's claim (interpret mode
-    off-TPU); its output matches the pure-jax decomposition bit-closely,
+def test_grouped_kernel_interpret_matches_decomposition(rng, request):
+    """The `pallas_claims` fixture turns the Pallas kernel's claim on (interpret
+    mode off-TPU); its output matches the pure-jax decomposition bit-closely,
     including ragged group_sizes (an empty expert and a partial bin)."""
     args = _grouped_args(rng, fill=[16, 0, 7, 16])
     fn = lambda *a: ltorch.sum(ltorch.grouped_mlp(*a))
 
-    monkeypatch.setenv("TT_GROUPED_KERNEL", "0")
-    ref = float(tt.jit(fn)(*args))
-    monkeypatch.setenv("TT_GROUPED_KERNEL", "1")
+    ref = float(tt.jit(fn)(*args))  # off the chip: the decomposition
+    request.getfixturevalue("pallas_claims")
     got = float(tt.jit(fn)(*args))
     assert abs(got - ref) <= 1e-4 * max(1.0, abs(ref))
 
 
 @pytest.mark.moe
-def test_grouped_kernel_grad_rule_matches(rng, monkeypatch):
+def test_grouped_kernel_grad_rule_matches(rng, request):
     """The executor-claimed grad rule (pallas.grouped_mlp_fwd/bwd prims)
     produces the same gradients as differentiating the decomposition."""
     args = _grouped_args(rng, fill=[16, 0, 7, 16])
@@ -123,8 +134,9 @@ def test_grouped_kernel_grad_rule_matches(rng, monkeypatch):
         ltorch.grouped_mlp(b, wg, wu, wd, gs) ** 2)
 
     grads = {}
-    for claim in ("0", "1"):
-        monkeypatch.setenv("TT_GROUPED_KERNEL", claim)
+    for claim in ("0", "1"):  # the decomposition, then the claimed kernel
+        if claim == "1":
+            request.getfixturevalue("pallas_claims")
         (g, _) = tt.grad(tt.jit(loss), argnums=(0, 1, 2, 3))(*args)
         # one entry per positional arg; the int group_sizes grad is None
         grads[claim] = [np.asarray(t) for t in g if t is not None]
@@ -135,23 +147,21 @@ def test_grouped_kernel_grad_rule_matches(rng, monkeypatch):
 
 @pytest.mark.moe
 @pytest.mark.analysis
-def test_grouped_kernel_vmem_decline(rng, monkeypatch):
-    """A tiny TT_VMEM_LIMIT makes the checker DECLINE (even when forced) —
+def test_grouped_kernel_vmem_decline(rng, monkeypatch, request):
+    """A tiny VMEM budget makes the checker DECLINE (even when it claims) —
     the decomposition fallback runs and the program still produces the
     reference numbers. The budget comes from analysis/memory.py, the same
     estimate the bench artifact commits."""
     from thunder_tpu.executors import pallasex
 
     args = _grouped_args(rng)
-    monkeypatch.setenv("TT_GROUPED_KERNEL", "1")
-    assert pallasex.grouped_mlp_supported(*args)
-    monkeypatch.setenv("TT_VMEM_LIMIT", "4096")
-    assert not pallasex.grouped_mlp_supported(*args)
     fn = lambda *a: ltorch.sum(ltorch.grouped_mlp(*a))
+    ref = float(tt.jit(fn)(*args))  # off the chip: the decomposition
+    request.getfixturevalue("pallas_claims")
+    assert pallasex.grouped_mlp_supported(*args)
+    monkeypatch.setattr(budget, "vmem_limit", lambda: 4096)
+    assert not pallasex.grouped_mlp_supported(*args)
     declined = float(tt.jit(fn)(*args))
-    monkeypatch.setenv("TT_GROUPED_KERNEL", "0")
-    monkeypatch.delenv("TT_VMEM_LIMIT")
-    ref = float(tt.jit(fn)(*args))
     assert abs(declined - ref) <= 1e-5 * max(1.0, abs(ref))
 
 
@@ -204,8 +214,8 @@ def test_gqa_ring_matches_dense(T, causal, rng):
 @pytest.mark.longctx
 @pytest.mark.slow  # interpret-mode shard_map grads; runs in the -m longctx lane
 @pytest.mark.parametrize("T", [32, 64])
-def test_streaming_ring_flash_matches_dense(T, rng, monkeypatch):
-    """TT_RING_KERNEL=1 forces the streaming flash kernel into the ring
+def test_streaming_ring_flash_matches_dense(T, rng, pallas_claims):
+    """The `pallas_claims` fixture puts the streaming flash kernel into the ring
     (interpret mode off-TPU); forward AND backward match the dense GQA
     reference — the bwd runs the flash recompute, not a saved-probs path."""
     from jax.sharding import PartitionSpec as P
@@ -216,7 +226,6 @@ def test_streaming_ring_flash_matches_dense(T, rng, monkeypatch):
     q = jnp.asarray(rng.randn(B, Hq, T, D), jnp.float32)
     k = jnp.asarray(rng.randn(B, Hkv, T, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, Hkv, T, D), jnp.float32)
-    monkeypatch.setenv("TT_RING_KERNEL", "1")
     mesh = make_mesh({"sp": sp})
     spec = P(None, None, "sp")
 
@@ -247,9 +256,9 @@ def test_streaming_ring_flash_matches_dense(T, rng, monkeypatch):
 
 @pytest.mark.longctx
 @pytest.mark.analysis
-def test_ring_flash_vmem_decline(rng, monkeypatch):
+def test_ring_flash_vmem_decline(rng, monkeypatch, pallas_claims):
     """The streaming kernel's checker declines when one step's working set
-    exceeds TT_VMEM_LIMIT — the ring still runs (pure-jax GQA road) and
+    exceeds the VMEM budget — the ring still runs (pure-jax GQA road) and
     matches dense."""
     from thunder_tpu.executors import pallasex
 
@@ -257,9 +266,8 @@ def test_ring_flash_vmem_decline(rng, monkeypatch):
     q = jnp.asarray(rng.randn(B, Hq, T // sp, D), jnp.float32)
     k = jnp.asarray(rng.randn(B, Hkv, T // sp, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, Hkv, T // sp, D), jnp.float32)
-    monkeypatch.setenv("TT_RING_KERNEL", "1")
     assert pallasex.ring_flash_supported(q, k, v)
-    monkeypatch.setenv("TT_VMEM_LIMIT", "1024")
+    monkeypatch.setattr(budget, "vmem_limit", lambda: 1024)
     assert not pallasex.ring_flash_supported(q, k, v)
 
     qf = jnp.asarray(rng.randn(B, Hq, T, D), jnp.float32)

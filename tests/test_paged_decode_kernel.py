@@ -107,19 +107,16 @@ def test_nothing_outside_the_live_pages_is_read(window):
     np.testing.assert_array_equal(got, want)
 
 
-def test_a_pool_too_large_for_eight_pages_takes_fewer_a_step():
+def test_a_pool_too_large_for_eight_pages_takes_fewer_a_step(monkeypatch):
     """The block follows the budget: the same call with 256 KiB and with the default 14 MiB."""
     rng = np.random.default_rng(3)
     args = _case(rng, [1, 40, 100, 256], g=4, Hkv=2, D=128, Dv=128, ps=16, npm=16)
     want = _decomposition(*args, None)
     sizes = (16, 128, 4, 4, 4)
     assert budget.paged_pages_per_step(*sizes, n_kv_heads=2) == 8
-    os.environ["TT_PAGED_VMEM_LIMIT"] = str(2**18)
-    try:
-        assert budget.paged_pages_per_step(*sizes, n_kv_heads=2) == 3
-        got = _kernel(*args, None)
-    finally:
-        del os.environ["TT_PAGED_VMEM_LIMIT"]
+    monkeypatch.setattr(budget, "paged_vmem_limit", lambda: 2**18)
+    assert budget.paged_pages_per_step(*sizes, n_kv_heads=2) == 3
+    got = _kernel(*args, None)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
@@ -175,8 +172,7 @@ class _Proxy:
 
 
 @pytest.fixture
-def forced_claim(monkeypatch):
-    monkeypatch.setenv("TT_PAGED_KERNEL", "1")
+def forced_claim(pallas_claims):
     from thunder_tpu import observability
     observability.enable()
     observability.reset()

@@ -59,12 +59,15 @@ def test_flash_noncausal(rng):
     np.testing.assert_allclose(np.asarray(o), np.asarray(_ref_attn(q, k, v, causal=False)), atol=2e-3)
 
 
-def test_checker_accepts_gpt2_shapes():
-    class FakeProxy:
-        def __init__(self, shape):
-            self.shape = shape
-            self.ndim = len(shape)
+class _Operand:
+    """What a checker reads of a proxy."""
 
+    def __init__(self, shape, dtype="bfloat16"):
+        self.shape, self.ndim, self.dtype = shape, len(shape), dtype
+
+
+def test_checker_accepts_gpt2_shapes():
+    FakeProxy = _Operand
     q = FakeProxy((2, 12, 4096, 64))
     assert pallasex.flash_attention_supported(q, q, q, None, 0.0, True, None)
     # T=1024 claims too (bf16-dot kernels beat the composite from T>=1024)
@@ -87,6 +90,61 @@ def test_checker_accepts_gpt2_shapes():
     assert not pallasex.flash_attention_supported(q, q, v_bad, None, 0.0, True, None)
     k_short = FakeProxy((2, 12, 512, 64))
     assert not pallasex.flash_attention_supported(q, k_short, k_short, None, 0.0, False, None)
+
+
+# the cells' and chip_smoke.py's shapes (T 2,048 and 4,096; heads 64 and 128; groups 1 and 4)
+# fit; 8,192 fits the plain kernels only (the rope tables stay whole in VMEM beside K and V);
+# 16,384 fits neither forward under the compiler's 16 MiB
+_FLASH_VMEM_CASES = ([(T, D, g, ("flash_attention", "rope_sdpa"))
+                      for T in (2048, 4096) for D in (64, 128) for g in (1, 4)]
+                     + [(8192, 128, 4, ("flash_attention",)), (8192, 64, 1, ("flash_attention",)),
+                        (16384, 128, 4, ()), (16384, 64, 1, ()), (32768, 128, 1, ())])
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "rope_sdpa"])
+@pytest.mark.parametrize("T,D,g,fits", _FLASH_VMEM_CASES,
+                         ids=[f"T{T}-D{D}-g{g}" for T, D, g, _ in _FLASH_VMEM_CASES])
+def test_flash_checkers_claim_what_fits_vmem_and_decline_the_rest(kernel, T, D, g, fits):
+    """A sequence whose whole-length K and V (and rope tables) the estimate says do not fit is
+    declined with `pallas.decline.<kernel>.vmem` on the bus (the rope checker goes through the
+    plain one, whose counter it is when the plain kernel does not fit either) and XLA's
+    composite runs; it used to be claimed and then refused by the compiler."""
+    from thunder_tpu import observability
+
+    q, kv, table = _Operand((4, 8 * g, T, D)), _Operand((4, 8, T, D)), _Operand((T, D), "float32")
+    observability.enable()
+    observability.reset()
+    try:
+        if kernel == "flash_attention":
+            claimed = pallasex.flash_attention_supported(q, kv, kv, None, 0.0, True, None)
+        else:
+            claimed = pallasex.rope_sdpa_supported(q, kv, kv, table, table, True, None)
+        declines = [k for k in observability.counters() if k.startswith("pallas.decline.")]
+    finally:
+        observability.disable()
+    assert claimed == (kernel in fits)
+    if claimed:
+        assert declines == []
+    else:
+        by = kernel if "flash_attention" in fits else "flash_attention"
+        assert declines == [f"pallas.decline.{by}.vmem"]
+
+
+def test_the_choice_of_kernel_reads_no_environment():
+    """Which kernel runs is the checkers' decision from the platform, the shapes and a VMEM
+    estimate: the executor, the fp8 road and the two VMEM budgets read no variable."""
+    import inspect
+
+    from thunder_tpu.analysis import memory
+    from thunder_tpu.transforms import fp8_training
+
+    sources = {"executors/pallasex.py": inspect.getsource(pallasex),
+               "transforms/fp8_training.py": inspect.getsource(fp8_training),
+               "analysis/memory.py: vmem_limit": inspect.getsource(memory.vmem_limit),
+               "analysis/memory.py: paged_vmem_limit": inspect.getsource(memory.paged_vmem_limit),
+               "analysis/memory.py: within_vmem": inspect.getsource(memory.within_vmem)}
+    for name, source in sources.items():
+        assert "environ" not in source and "getenv" not in source, name
 
 
 def test_sdpa_symbol_claims_flash_end_to_end(rng):

@@ -164,7 +164,7 @@ class TestFusedInt8Linear:
         want = np.asarray(x, np.float32) @ (np.asarray(w, np.float32) * np.asarray(s)[:, None]).T
         np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
 
-    def test_pallas_claims_quantized_linear(self, rng, monkeypatch):
+    def test_pallas_claims_quantized_linear(self, rng, pallas_claims):
         import jax.numpy as jnp
 
         import thunder_tpu as tt
@@ -173,8 +173,7 @@ class TestFusedInt8Linear:
         from thunder_tpu.transforms.quantization import QuantizeInt8Transform
 
         # the checker declines off-TPU (interpret mode is a debug path, not
-        # a serving path); force the claim to exercise the kernel here
-        monkeypatch.setenv("TT_INT8_PALLAS_CPU", "1")
+        # a serving path); the fixture turns the claim on to exercise the kernel
         calls = {"n": 0}
         orig = px._int8_linear_impl
 
@@ -205,12 +204,10 @@ class TestFusedInt8Linear:
             px.ex.register_implementation("quant.linear_int8", orig,
                                           checker=px._int8_linear_supported)
 
-    def test_checker_declines_large_m_and_odd_shapes(self, rng, monkeypatch):
+    def test_checker_declines_large_m_and_odd_shapes(self, rng, pallas_claims):
         from thunder_tpu.core.proxies import TensorProxy
         from thunder_tpu.core import dtypes as dt
         from thunder_tpu.executors import pallasex as px
-
-        monkeypatch.setenv("TT_INT8_PALLAS_CPU", "1")
 
         def p(shape, dtype=dt.bfloat16):
             return TensorProxy(shape=shape, dtype=dtype, device=None)

@@ -741,14 +741,14 @@ def test_heads_a_row_fills_the_lanes_where_the_head_count_divides(n_kv_heads, he
 @pytest.mark.parametrize("n_head,n_query_groups,pack", [(4, 4, 4), (8, 4, 4), (4, 2, 2)],
                          ids=["mha-4-a-row", "gqa-4-a-row", "gqa-2-a-row"])
 def test_narrow_heads_are_cached_packed_and_match_dense(n_head, n_query_groups, pack, kernel,
-                                                        rng, monkeypatch):
+                                                        rng, request):
     """Heads narrower than the 128 lanes are cached `pack` a row (a pool of n_query_groups / pack
     heads, 128 wide), which is the shape the decode kernel takes on the chip. Prefill, chunked
     prefill with a shared prefix, decode and speculative verify all write and read such rows, and
     every request still decodes its exact solo stream: through the gather decomposition and
     through both paged kernels (interpret mode)."""
     if kernel == "kernel":
-        monkeypatch.setenv("TT_PAGED_KERNEL", "1")
+        request.getfixturevalue("pallas_claims")
     head_size = 128 // pack
     cfg = Config.from_name("tiny-llama2", block_size=64, n_head=n_head, n_embd=n_head * head_size,
                            n_query_groups=n_query_groups, head_size=head_size)

@@ -227,10 +227,9 @@ def _claims(cfn) -> dict:
             if "paged" in k}
 
 
-def test_the_programs_claim_what_the_builders_state(model, monkeypatch):
+def test_the_programs_claim_what_the_builders_state(model, pallas_claims):
     """With the claim forced (interpret mode), the hybrid's programs hold its builder's counts and
     the dense GPT's four programs claim what they claimed: one paged kernel a layer."""
-    monkeypatch.setenv("TT_PAGED_KERNEL", "1")
     eng = engine_for(model)
     res = serve(eng, prompts([20, 70], seed=7), [4, 4])
     assert max(gap(CONFIG, eng.params, r, p, 4) for r, p in zip(res, (20, 70))) < LOGIT_TOL

@@ -12,15 +12,17 @@ Two layers:
   estimate-and-decline checkers that grew inside ``executors/pallasex.py``
   (flash block capping, paged-attention working-set decline) now call
   through here, so every kernel/fusion budget question — "does this region
-  fit VMEM?", "what is this step's peak HBM?" — has a single answer with a
-  single set of knobs.
+  fit VMEM?", "what is this step's peak HBM?" — has a single answer from a
+  single set of budgets.
 
-Env knobs: ``TT_VMEM_LIMIT`` (per-core VMEM budget for region checks,
-default 16 MiB — the v4/v5 scoped-VMEM figure the flash kernels were swept
-against), ``TT_PAGED_VMEM_LIMIT`` (paged-decode claim budget, default
-14 MiB, kept from pallasex), ``TT_CHECK_REGION_BUDGET`` (bytes; when set,
-the pass checkpoints flag any fusion region whose live-range peak exceeds
-it).
+The two VMEM budgets are the chip's and are constants: ``vmem_limit()`` (16
+MiB, the v4/v5 scoped-VMEM default a kernel gets when it asks for nothing;
+the flash kernels were swept against it) and ``paged_vmem_limit()`` (14 MiB,
+the paged kernels' claim budget). To A/B a kernel against its decomposition,
+leave the Pallas executor out of ``tt.jit(fn, executors=[...])``. One
+verifier setting is read from the environment: ``TT_CHECK_REGION_BUDGET``
+(bytes; when set, the pass checkpoints flag any fusion region whose
+live-range peak exceeds it).
 """
 from __future__ import annotations
 
@@ -41,11 +43,11 @@ DEFAULT_PAGED_VMEM_LIMIT = 14 * 2**20
 
 
 def vmem_limit() -> int:
-    return int(os.environ.get("TT_VMEM_LIMIT", str(DEFAULT_VMEM_LIMIT)))
+    return DEFAULT_VMEM_LIMIT
 
 
 def paged_vmem_limit() -> int:
-    return int(os.environ.get("TT_PAGED_VMEM_LIMIT", str(DEFAULT_PAGED_VMEM_LIMIT)))
+    return DEFAULT_PAGED_VMEM_LIMIT
 
 
 def within_vmem(nbytes: int, limit: Optional[int] = None) -> bool:
@@ -165,6 +167,60 @@ def ring_flash_vmem_bytes(block_q: int, T_blk: int, D: int,
     acc = block_q * D * 4 + 2 * block_q * 4  # o acc + m/l carries (f32)
     out = block_q * D * 4
     return qb + kv + acc + out
+
+
+def _lane_padded(D: int) -> int:
+    return -(-D // 128) * 128
+
+
+def _rope_tables_vmem_bytes(block_q: int, Tk: int, Dp: int) -> int:
+    """The f32 cos/sin of the rope-flash kernels: the q block's rows in two
+    buffers and the whole-length tables in one (their block never changes)."""
+    return (2 * 2 * block_q + 2 * Tk) * Dp * 4
+
+
+def flash_fwd_vmem_bytes(block_q: int, block_k: int, Tk: int, D: int,
+                         q_itemsize: int, kv_itemsize: int, *, rope: bool = False) -> int:
+    """Estimated per-program VMEM working set of the flash forward (pallasex
+    `_flash_fwd_kernel`, `_flash_rope_fwd_kernel`), which asks Mosaic for
+    nothing and so gets `vmem_limit()`: whole-length K and V and the q, o and
+    lse blocks, two buffers each; the f32 scores and probabilities of one
+    (block_q, block_k) tile; with rope the cos/sin tables. Rows
+    narrower than the 128 lanes are padded to them. Held against the v5e's
+    compiler (PR 29; bf16 and f32, heads of 64 and 128, groups of 1 and 4, the
+    longest multiple of 1,024 that compiles): at heads of 128 it says "fits" up
+    to that length and not beyond (bf16 11,264 plain and 5,120 with rope; f32
+    7,168 and 4,096); at heads of 64 the compiler takes more in some cases (up
+    to 18,432 plain, 11,264-16,384 with rope), so there the estimate declines
+    early and never late."""
+    Dp = _lane_padded(D)
+    kv = 2 * 2 * Tk * Dp * kv_itemsize
+    qo = 2 * 2 * block_q * Dp * q_itemsize + 2 * block_q * 128 * 4
+    scores = 2 * block_q * block_k * 4
+    tables = _rope_tables_vmem_bytes(block_q, Tk, Dp) if rope else 0
+    return kv + qo + scores + tables
+
+
+def flash_bwd_vmem_bytes(block_q: int, block_k: int, Tk: int, D: int, g: int,
+                         q_itemsize: int, kv_itemsize: int, *, rope: bool = False) -> int:
+    """Estimated per-program VMEM working set of the single-pass flash backward
+    (pallasex `_flash_bwd_fused_kernel`, `_flash_rope_bwd_fused_kernel`), to be
+    held against the limit its call asks Mosaic for: whole-length K, V, dK and
+    dV, two buffers each, and the two whole-length f32 accumulators; the q
+    group's q, do and dq blocks with their lse and delta columns, two buffers
+    each; four f32 (block_k, block_q) tiles; with rope the tables as in the
+    forward. Held against the v5e's compiler as the forward's was: at a group
+    of 1 it says "fits" up to the last length that compiles under 64 MiB and
+    not beyond (bf16 19,456, with rope at heads of 128 14,336; f32 12,288); at
+    a group of 4 the compiler takes up to 21,504 and the estimate declines
+    early. The forward's limit is the tighter one unless the group is large."""
+    Dp = _lane_padded(D)
+    kv = (2 * 4 * kv_itemsize + 2 * 4) * Tk * Dp
+    rows = g * block_q
+    qdo = 2 * 3 * rows * Dp * q_itemsize + 2 * 2 * rows * 128 * 4
+    tiles = 4 * block_q * block_k * 4
+    tables = _rope_tables_vmem_bytes(block_q, Tk, Dp) if rope else 0
+    return kv + qdo + tiles + tables
 
 
 def flash_block_cap(widest_itemsize: int, block_q: int, block_k: int,

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -36,16 +35,14 @@ register_executor(ex)
 # swept on v5e (llama-350m, B=4, T=2048, D=64, fwd+bwd step): 512/1024 gave
 # 39.4% MFU vs 23.8% at 128/128 — large q blocks amortize the k/v loop,
 # k-major blocks keep the MXU fed during the online-softmax accumulation
-DEFAULT_BLOCK_Q = int(os.environ.get("TT_FLASH_BLOCK_Q", "512"))
-DEFAULT_BLOCK_K = int(os.environ.get("TT_FLASH_BLOCK_K", "1024"))
-# k-block cap for the GQA streaming dkv backward (swept separately: its
-# working set scales with block_k x block_q tiles plus the group's q/do)
-_GQA_BLOCK_K = int(os.environ.get("TT_FLASH_GQA_BLOCK_K", "512"))
+DEFAULT_BLOCK_Q = 512
+DEFAULT_BLOCK_K = 1024
 # single-pass fused backward blocks (swept on v5e across llama-350m/llama-1b/
 # nanogpt shapes: 512/512 wins everywhere — 4.11/2.75/2.80 ms fwd+bwd vs
-# 4.52/3.24/3.42 two-pass; 1024-row q blocks blow the 16 MB VMEM limit)
-_FUSED_BLOCK_Q = int(os.environ.get("TT_FLASH_FUSED_BLOCK_Q", "512"))
-_FUSED_BLOCK_K = int(os.environ.get("TT_FLASH_FUSED_BLOCK_K", "512"))
+# 4.52/3.24/3.42 for a dq pass and a dkv pass; 1024-row q blocks blow the
+# 16 MB VMEM limit)
+_FUSED_BLOCK_Q = 512
+_FUSED_BLOCK_K = 512
 # scoped VMEM the single-pass backward asks Mosaic for: it keeps whole-length
 # K/V blocks and two (Tk, D) f32 accumulators resident, which at a q group of
 # 4 (llama-350m width, T=2048) is 16.23 MiB — over the compiler's 16 MiB
@@ -63,6 +60,8 @@ def _cap_blocks_for_dtype(q, block_q: int, block_k: int, T: int, Tk: int, *extra
 
     widest = max(jnp.dtype(t.dtype).itemsize for t in (q,) + tuple(extra))
     return _budget.flash_block_cap(widest, block_q, block_k, T, Tk)
+
+
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634  # 1/ln 2: base-2 softmax folds this into the scale
 LN2 = 0.6931471805599453
@@ -78,6 +77,16 @@ def _decline(kernel: str, reason: str) -> bool:
 
 def _on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
+
+
+def _claims_on_platform() -> bool:
+    """Who claims: the one rule of every checker whose kernel pays only on the
+    chip (paged, grouped MLP, ring flash, int8 and fp8 linear) — the platform.
+    Off the chip their ops run the pure-jax decomposition; the tests patch
+    this to run the kernels interpreted (tests/conftest.py: pallas_claims).
+    To A/B a kernel against its decomposition on the chip, leave `pallasex.ex`
+    out of `tt.jit(fn, executors=[...])`."""
+    return _on_tpu()
 
 
 def _interpret() -> bool:
@@ -177,148 +186,15 @@ def flash_attention_forward(q, k, v, *, causal: bool = True, scale=None,
 
 
 # ===========================================================================
-# Flash attention — backward (recompute blockwise; dq kernel + dkv kernel)
+# Flash attention — backward (recompute blockwise, one pass: dq, dk and dv)
 # ===========================================================================
-
-
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-                         block_k: int, causal: bool, scale: float):
-    block_q, D = q_ref.shape
-    T = k_ref.shape[0]
-    qi = pl.program_id(2)
-    q = q_ref[:]
-    do = do_ref[:]
-    lse2 = lse_ref[:][:, 0] * LOG2E  # natural-log lse -> log2 units
-    delta = delta_ref[:][:, 0]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-
-    def body(j, dq_acc):
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * (scale * LOG2E)
-        if causal:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        p = jnp.exp2(s - lse2[:, None])
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        return dq_acc + jax.lax.dot_general(ds.astype(k_blk.dtype), k_blk,
-                                            (((1,), (0,)), ((), ())),
-                                            preferred_element_type=jnp.float32)
-
-    n_k = T // block_k
-    if causal:
-        n_k = jnp.minimum(n_k, ((qi + 1) * block_q + block_k - 1) // block_k)
-    dq = jax.lax.fori_loop(0, n_k, body, jnp.zeros((block_q, D), jnp.float32))
-    dq_ref[:] = dq.astype(dq_ref.dtype)
-
-
-def _dkv_tile(k_blk, v_blk, q, do, lse2, delta, k_pos_t, q_pos_t, causal,
-              scale, dk_acc, dv_acc):
-    """One (k-block x q-tile) contribution to dk/dv, transposed orientation
-    (rows = k positions) in log2 units — the single source of truth for all
-    four dkv kernels (MHA/GQA x plain/rope)."""
-    s_t = jax.lax.dot_general(k_blk, q, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32) * (scale * LOG2E)  # (bk, bq)
-    if causal:
-        s_t = jnp.where(k_pos_t <= q_pos_t, s_t, NEG_INF)
-    p_t = jnp.exp2(s_t - lse2[None, :])
-    dv_acc = dv_acc + jax.lax.dot_general(p_t.astype(do.dtype), do,
-                                          (((1,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-    dp_t = jax.lax.dot_general(v_blk, do, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)  # (bk, bq)
-    ds_t = (p_t * (dp_t - delta[None, :]) * scale).astype(q.dtype)
-    dk_acc = dk_acc + jax.lax.dot_general(ds_t, q, (((1,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-    return dk_acc, dv_acc
-
-
-def _flash_bwd_dkv_kernel_mha(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *,
-                          block_q: int, causal: bool, scale: float):
-    block_k, D = k_ref.shape
-    T = q_ref.shape[0]
-    ki = pl.program_id(2)
-    k_blk = k_ref[:]
-    v_blk = v_ref[:]
-    # work in the TRANSPOSED orientation (rows = k positions): every dot then
-    # contracts lhs dim 1 against rhs dim 0/1 naturally — the straight
-    # orientation needs pᵀ/dsᵀ for dv/dk, and those in-kernel transposes of
-    # (block_q, block_k) tiles cost more than the matmuls themselves
-    k_pos_t = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
-
-    def body(i, carry):
-        q = q_ref[pl.ds(i * block_q, block_q), :]
-        do = do_ref[pl.ds(i * block_q, block_q), :]
-        lse2 = lse_ref[pl.ds(i * block_q, block_q), :][:, 0] * LOG2E
-        delta = delta_ref[pl.ds(i * block_q, block_q), :][:, 0]
-        q_pos_t = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
-        return _dkv_tile(k_blk, v_blk, q, do, lse2, delta, k_pos_t, q_pos_t,
-                         causal, scale, *carry)
-
-    z = jnp.zeros((block_k, D), jnp.float32)
-    i0 = (ki * block_k) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(i0, T // block_q, body, (z, z))
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
-
-
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                          dk_scr, dv_scr, *, causal: bool, scale: float, g: int, n_i: int):
-    # GQA-aware, VMEM-bounded: grid (B, Hkv, T//block_k, T//block_q) streams
-    # q/do in (g, block_q, D) tiles (innermost-fastest on the TPU's
-    # sequential grid); dk/dv accumulate in VMEM scratch across the i axis
-    # and write ONCE at the last i — kv-grad HBM stays (B, Hkv, T, D), not
-    # g× (advisor r3 finding), with working set independent of T and g.
-    block_k, D = k_ref.shape
-    block_q = q_ref.shape[1]
-    ki = pl.program_id(2)
-    ii = pl.program_id(3)
-
-    @pl.when(ii == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    # causal skip: the (j, i) tile contributes only when some q_pos >= k_pos
-    live = (ki * block_k <= (ii + 1) * block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        k_blk = k_ref[:]
-        v_blk = v_ref[:]
-        # work in the TRANSPOSED orientation (rows = k positions): every dot
-        # then contracts lhs dim 1 against rhs dim 0/1 naturally — the
-        # straight orientation needs pᵀ/dsᵀ for dv/dk, and those in-kernel
-        # transposes of (block_q, block_k) tiles cost more than the matmuls
-        k_pos_t = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
-        q_pos_t = ii * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
-        dk_acc = dk_scr[:]
-        dv_acc = dv_scr[:]
-        for h in range(g):  # static unroll over the q-head group
-            dk_acc, dv_acc = _dkv_tile(
-                k_blk, v_blk, q_ref[h], do_ref[h], lse_ref[h][:, 0] * LOG2E,
-                delta_ref[h][:, 0], k_pos_t, q_pos_t, causal, scale,
-                dk_acc, dv_acc)
-        dk_scr[:] = dk_acc
-        dv_scr[:] = dv_acc
-
-    @pl.when(ii == n_i - 1)
-    def _write():
-        dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _fused_bwd_tile(q, do, lse2, delta, k_blk, v_blk, sl, k_pos_t, q_pos_t,
                     causal, scale, dk_scr, dv_scr, dq_acc):
     """One (i, j) tile of the single-pass backward, shared by the plain and
-    rope fused kernels (the _dkv_tile role for the fused design): computes
-    s/p ONCE, accumulates dk/dv into the VMEM scratch slice and returns the
-    updated dq accumulator. Transposed orientation (rows = k positions)."""
+    rope fused kernels: computes s/p ONCE, accumulates dk/dv into the VMEM
+    scratch slice and returns the updated dq accumulator. Transposed orientation (rows = k positions)."""
     s_t = jax.lax.dot_general(k_blk, q, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32) * (scale * LOG2E)
     if causal:
@@ -383,17 +259,28 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _fused_bwd_enabled() -> bool:
-    return os.environ.get("TT_FLASH_TWO_PASS_BWD", "0") != "1"
+def _fused_bwd_blocks(block_q: int, block_k: int, T: int, Tk: int):
+    """The single-pass backward's blocks, for its two calls and their checker
+    (gcd keeps divisibility: a non-divisor block would truncate the grid)."""
+    return math.gcd(min(block_q, _FUSED_BLOCK_Q), T), math.gcd(min(block_k, _FUSED_BLOCK_K), Tk)
 
 
-def _flash_backward_fused(q, k, v, do, lse4, delta4, *, causal, scale,
-                          block_q, block_k):
+def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool = True, scale=None,
+                             block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if jnp.dtype(do.dtype).itemsize > jnp.dtype(q.dtype).itemsize:
+        # fp8/mixed rewrites can hand a f32 cotangent to a bf16 attention:
+        # matching q's precision keeps the swept bf16 block sizes (delta is
+        # accumulated in f32 regardless)
+        do = do.astype(q.dtype)
     B, H, T, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
-    g = H // Hkv
-    block_q = math.gcd(min(block_q, _FUSED_BLOCK_Q), T)
-    block_k = math.gcd(min(block_k, _FUSED_BLOCK_K), Tk)
+    g = H // Hkv  # GQA: one program does a kv head's whole q group
+    block_q, block_k = _cap_blocks_for_dtype(q, min(block_q, T), min(block_k, Tk), T, Tk, k, v, do)
+    block_q, block_k = _fused_bwd_blocks(block_q, block_k, T, Tk)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # (B,H,T)
+    lse4 = lse[..., None]
+    delta4 = delta[..., None]
     qg = q.reshape(B, Hkv, g, T, D)
     dog = do.reshape(B, Hkv, g, T, D)
     lseg = lse4.reshape(B, Hkv, g, T, 1)
@@ -427,109 +314,6 @@ def _flash_backward_fused(q, k, v, do, lse4, delta4, *, causal, scale,
         interpret=_interpret(),
     )(qg, k, v, dog, lseg, deltag)
     return dq.reshape(B, H, T, D), dk, dv
-
-
-def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool = True, scale=None,
-                             block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
-    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if jnp.dtype(do.dtype).itemsize > jnp.dtype(q.dtype).itemsize:
-        # fp8/mixed rewrites can hand a f32 cotangent to a bf16 attention:
-        # matching q's precision keeps the swept bf16 block sizes (delta is
-        # accumulated in f32 regardless)
-        do = do.astype(q.dtype)
-    B, H, T, D = q.shape
-    Tk = k.shape[2]
-    Hkv = k.shape[1]
-    g = H // Hkv  # GQA: dk/dv computed per q head, group-summed below
-    block_q = min(block_q, T)
-    block_k = min(block_k, Tk)
-    block_q, block_k = _cap_blocks_for_dtype(q, block_q, block_k, T, Tk, k, v, do)
-    if g > 1:
-        # grouped-kv vmem guard for the streaming dkv grid; gcd keeps
-        # divisibility under overrides (a non-divisor block would silently
-        # truncate the dkv grid). TT_FLASH_GQA_BLOCK_K tunes it.
-        block_k = math.gcd(min(block_k, _GQA_BLOCK_K), Tk)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # (B,H,T)
-    lse4 = lse[..., None]
-    delta4 = delta[..., None]
-
-    if _fused_bwd_enabled():
-        return _flash_backward_fused(q, k, v, do, lse4, delta4, causal=causal,
-                                     scale=scale, block_q=block_q, block_k=block_k)
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_k=block_k, causal=causal, scale=scale),
-        grid=(B, H, T // block_q),
-        in_specs=[
-            pl.BlockSpec((None, None, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((None, None, Tk, D), lambda b, h, i: (b, h // g, 0, 0)),
-            pl.BlockSpec((None, None, Tk, D), lambda b, h, i: (b, h // g, 0, 0)),
-            pl.BlockSpec((None, None, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((None, None, block_q, 1), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((None, None, block_q, 1), lambda b, h, i: (b, h, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, block_q, D), lambda b, h, i: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
-        interpret=_interpret(),
-    )(q, k, v, do, lse4, delta4)
-
-    if g == 1:
-        # MHA fast path: full-T q/do resident per program (measured faster
-        # than the streaming grid at llama-350m shapes)
-        dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel_mha, block_q=block_q, causal=causal, scale=scale),
-            grid=(B, H, Tk // block_k),
-            in_specs=[
-                pl.BlockSpec((None, None, T, D), lambda b, h, j: (b, h, 0, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-                pl.BlockSpec((None, None, T, D), lambda b, h, j: (b, h, 0, 0)),
-                pl.BlockSpec((None, None, T, 1), lambda b, h, j: (b, h, 0, 0)),
-                pl.BlockSpec((None, None, T, 1), lambda b, h, j: (b, h, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
-                jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype),
-            ],
-            interpret=_interpret(),
-        )(q, k, v, do, lse4, delta4)
-        return dq, dk, dv
-
-    # GQA: q heads grouped per kv head — view q/do/lse/delta as (B, Hkv, g, T, ...)
-    qg = q.reshape(B, Hkv, g, T, D)
-    dog = do.reshape(B, Hkv, g, T, D)
-    lseg = lse4.reshape(B, Hkv, g, T, 1)
-    deltag = delta4.reshape(B, Hkv, g, T, 1)
-    n_i = T // block_q
-    scratch = [pltpu.VMEM((block_k, D), jnp.float32),
-               pltpu.VMEM((block_k, D), jnp.float32)]
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale, g=g, n_i=n_i),
-        grid=(B, Hkv, Tk // block_k, n_i),
-        in_specs=[
-            pl.BlockSpec((None, None, g, block_q, D), lambda b, hk, j, i: (b, hk, 0, i, 0)),
-            pl.BlockSpec((None, None, block_k, D), lambda b, hk, j, i: (b, hk, j, 0)),
-            pl.BlockSpec((None, None, block_k, D), lambda b, hk, j, i: (b, hk, j, 0)),
-            pl.BlockSpec((None, None, g, block_q, D), lambda b, hk, j, i: (b, hk, 0, i, 0)),
-            pl.BlockSpec((None, None, g, block_q, 1), lambda b, hk, j, i: (b, hk, 0, i, 0)),
-            pl.BlockSpec((None, None, g, block_q, 1), lambda b, hk, j, i: (b, hk, 0, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, block_k, D), lambda b, hk, j, i: (b, hk, j, 0)),
-            pl.BlockSpec((None, None, block_k, D), lambda b, hk, j, i: (b, hk, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, Tk, D), v.dtype),
-        ],
-        scratch_shapes=scratch,
-        interpret=_interpret(),
-    )(qg, k, v, dog, lseg, deltag)
-    return dq, dk, dv
 
 
 # ===========================================================================
@@ -648,116 +432,6 @@ def flash_rope_attention_forward(q, k, v, cos, sin, *, causal: bool = True, scal
     return o, lse[..., 0]
 
 
-def _flash_rope_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                              cq_ref, sq_ref, ck_ref, sk_ref, dq_ref, *,
-                              block_k: int, causal: bool, scale: float):
-    block_q, D = q_ref.shape
-    T = k_ref.shape[0]
-    qi = pl.program_id(2)
-    q = _rope_block(q_ref[:].astype(jnp.float32), cq_ref[:], sq_ref[:]).astype(q_ref.dtype)
-    do = do_ref[:]
-    lse2 = lse_ref[:][:, 0] * LOG2E  # natural-log lse -> log2 units
-    delta = delta_ref[:][:, 0]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-
-    def body(j, dq_acc):
-        k_blk = _rope_block(k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32),
-                            ck_ref[pl.ds(j * block_k, block_k), :],
-                            sk_ref[pl.ds(j * block_k, block_k), :]).astype(k_ref.dtype)
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :]
-        ss = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * (scale * LOG2E)
-        if causal:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            ss = jnp.where(k_pos <= q_pos, ss, NEG_INF)
-        pp = jnp.exp2(ss - lse2[:, None])
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = pp * (dp - delta[:, None]) * scale
-        return dq_acc + jax.lax.dot_general(ds.astype(k_blk.dtype), k_blk,
-                                            (((1,), (0,)), ((), ())),
-                                            preferred_element_type=jnp.float32)
-
-    n_k = T // block_k
-    if causal:
-        n_k = jnp.minimum(n_k, ((qi + 1) * block_q + block_k - 1) // block_k)
-    dq_r = jax.lax.fori_loop(0, n_k, body, jnp.zeros((block_q, D), jnp.float32))
-    dq_ref[:] = _rope_vjp_block(dq_r, cq_ref[:], sq_ref[:]).astype(dq_ref.dtype)
-
-
-def _flash_rope_bwd_dkv_kernel_mha(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                               cq_ref, sq_ref, ck_ref, sk_ref, dk_ref, dv_ref, *,
-                               block_q: int, causal: bool, scale: float):
-    block_k, D = k_ref.shape
-    T = q_ref.shape[0]
-    ki = pl.program_id(2)
-    k_blk = _rope_block(k_ref[:].astype(jnp.float32), ck_ref[:], sk_ref[:]).astype(k_ref.dtype)
-    v_blk = v_ref[:]
-    k_pos_t = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
-
-    def body(i, carry):
-        q = _rope_block(q_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32),
-                        cq_ref[pl.ds(i * block_q, block_q), :],
-                        sq_ref[pl.ds(i * block_q, block_q), :]).astype(q_ref.dtype)
-        do = do_ref[pl.ds(i * block_q, block_q), :]
-        lse2 = lse_ref[pl.ds(i * block_q, block_q), :][:, 0] * LOG2E
-        delta = delta_ref[pl.ds(i * block_q, block_q), :][:, 0]
-        q_pos_t = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
-        return _dkv_tile(k_blk, v_blk, q, do, lse2, delta, k_pos_t, q_pos_t,
-                         causal, scale, *carry)
-
-    z = jnp.zeros((block_k, D), jnp.float32)
-    i0 = (ki * block_k) // block_q if causal else 0
-    dk_r, dv = jax.lax.fori_loop(i0, T // block_q, body, (z, z))
-    dk_ref[:] = _rope_vjp_block(dk_r, ck_ref[:], sk_ref[:]).astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
-
-
-
-
-def _flash_rope_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                               cq_ref, sq_ref, ck_ref, sk_ref, dk_ref, dv_ref,
-                               dk_scr, dv_scr, *, causal: bool, scale: float,
-                               g: int, n_i: int):
-    # GQA-aware, VMEM-bounded (see _flash_bwd_dkv_kernel): 4-D grid streams
-    # (g, block_q, D) q/do tiles, scratch accumulates dk/dv across i, the
-    # rope VJP rotation applies once at the final write
-    block_k, D = k_ref.shape
-    block_q = q_ref.shape[1]
-    ki = pl.program_id(2)
-    ii = pl.program_id(3)
-
-    @pl.when(ii == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    live = (ki * block_k <= (ii + 1) * block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        k_blk = _rope_block(k_ref[:].astype(jnp.float32), ck_ref[:], sk_ref[:]).astype(k_ref.dtype)
-        v_blk = v_ref[:]
-        k_pos_t = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
-        q_pos_t = ii * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
-        dk_acc = dk_scr[:]
-        dv_acc = dv_scr[:]
-        for h in range(g):  # static unroll over the q-head group
-            q = _rope_block(q_ref[h].astype(jnp.float32),
-                            cq_ref[:], sq_ref[:]).astype(q_ref.dtype)
-            dk_acc, dv_acc = _dkv_tile(
-                k_blk, v_blk, q, do_ref[h], lse_ref[h][:, 0] * LOG2E,
-                delta_ref[h][:, 0], k_pos_t, q_pos_t, causal, scale,
-                dk_acc, dv_acc)
-        dk_scr[:] = dk_acc
-        dv_scr[:] = dv_acc
-
-    @pl.when(ii == n_i - 1)
-    def _write():
-        dk_ref[:] = _rope_vjp_block(dk_scr[:], ck_ref[:], sk_ref[:]).astype(dk_ref.dtype)
-        dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
-
-
 def _flash_rope_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                  cq_ref, sq_ref, ck_ref, sk_ref,
                                  dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
@@ -804,13 +478,22 @@ def _flash_rope_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_rope_backward_fused(q, k, v, do, lse4, delta4, cos, sin, *, causal,
-                               scale, block_q, block_k):
+def flash_rope_attention_backward(q, k, v, o, lse, cos, sin, do, *, causal: bool = True,
+                                  scale=None, block_q: int = DEFAULT_BLOCK_Q,
+                                  block_k: int = DEFAULT_BLOCK_K):
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if jnp.dtype(do.dtype).itemsize > jnp.dtype(q.dtype).itemsize:
+        do = do.astype(q.dtype)  # see flash_attention_backward
     B, H, T, D = q.shape
     Hkv = k.shape[1]
     g = H // Hkv
-    block_q = math.gcd(min(block_q, _FUSED_BLOCK_Q), T)
-    block_k = math.gcd(min(block_k, _FUSED_BLOCK_K), T)
+    block_q, block_k = _cap_blocks_for_dtype(q, min(block_q, T), min(block_k, T), T, T, k, v, do)
+    block_q, block_k = _fused_bwd_blocks(block_q, block_k, T, T)
+    cos = cos.astype(jnp.float32)
+    sin = sin.astype(jnp.float32)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    lse4 = lse[..., None]
+    delta4 = delta[..., None]
     qg = q.reshape(B, Hkv, g, T, D)
     dog = do.reshape(B, Hkv, g, T, D)
     lseg = lse4.reshape(B, Hkv, g, T, 1)
@@ -850,121 +533,6 @@ def _flash_rope_backward_fused(q, k, v, do, lse4, delta4, cos, sin, *, causal,
     return dq.reshape(B, H, T, D), dk, dv
 
 
-def flash_rope_attention_backward(q, k, v, o, lse, cos, sin, do, *, causal: bool = True,
-                                  scale=None, block_q: int = DEFAULT_BLOCK_Q,
-                                  block_k: int = DEFAULT_BLOCK_K):
-    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    if jnp.dtype(do.dtype).itemsize > jnp.dtype(q.dtype).itemsize:
-        # fp8/mixed rewrites can hand a f32 cotangent to a bf16 attention:
-        # matching q's precision keeps the swept bf16 block sizes (delta is
-        # accumulated in f32 regardless)
-        do = do.astype(q.dtype)
-    B, H, T, D = q.shape
-    Hkv = k.shape[1]
-    g = H // Hkv  # GQA: dk/dv per-q-head partials group-summed at the end
-    block_q = min(block_q, T)
-    block_k = min(block_k, T)
-    block_q, block_k = _cap_blocks_for_dtype(q, block_q, block_k, T, T, k, v, do)
-    if g > 1:
-        # grouped-kv vmem guard (see flash_attention_backward)
-        block_k = math.gcd(min(block_k, _GQA_BLOCK_K), T)
-    cos = cos.astype(jnp.float32)
-    sin = sin.astype(jnp.float32)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    lse4 = lse[..., None]
-    delta4 = delta[..., None]
-
-    if _fused_bwd_enabled():
-        return _flash_rope_backward_fused(q, k, v, do, lse4, delta4, cos, sin,
-                                          causal=causal, scale=scale,
-                                          block_q=block_q, block_k=block_k)
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_rope_bwd_dq_kernel, block_k=block_k, causal=causal, scale=scale),
-        grid=(B, H, T // block_q),
-        in_specs=[
-            pl.BlockSpec((None, None, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((None, None, T, D), lambda b, h, i: (b, h // g, 0, 0)),
-            pl.BlockSpec((None, None, T, D), lambda b, h, i: (b, h // g, 0, 0)),
-            pl.BlockSpec((None, None, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((None, None, block_q, 1), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((None, None, block_q, 1), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((block_q, D), lambda b, h, i: (i, 0)),
-            pl.BlockSpec((block_q, D), lambda b, h, i: (i, 0)),
-            pl.BlockSpec((T, D), lambda b, h, i: (0, 0)),
-            pl.BlockSpec((T, D), lambda b, h, i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, block_q, D), lambda b, h, i: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
-        interpret=_interpret(),
-    )(q, k, v, do, lse4, delta4, cos, sin, cos, sin)
-
-    if g == 1:
-        # MHA fast path (see flash_attention_backward)
-        dk, dv = pl.pallas_call(
-            functools.partial(_flash_rope_bwd_dkv_kernel_mha, block_q=block_q, causal=causal, scale=scale),
-            grid=(B, H, T // block_k),
-            in_specs=[
-                pl.BlockSpec((None, None, T, D), lambda b, h, j: (b, h, 0, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-                pl.BlockSpec((None, None, T, D), lambda b, h, j: (b, h, 0, 0)),
-                pl.BlockSpec((None, None, T, 1), lambda b, h, j: (b, h, 0, 0)),
-                pl.BlockSpec((None, None, T, 1), lambda b, h, j: (b, h, 0, 0)),
-                pl.BlockSpec((T, D), lambda b, h, j: (0, 0)),
-                pl.BlockSpec((T, D), lambda b, h, j: (0, 0)),
-                pl.BlockSpec((block_k, D), lambda b, h, j: (j, 0)),
-                pl.BlockSpec((block_k, D), lambda b, h, j: (j, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-                pl.BlockSpec((None, None, block_k, D), lambda b, h, j: (b, h, j, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B, H, T, D), k.dtype),
-                jax.ShapeDtypeStruct((B, H, T, D), v.dtype),
-            ],
-            interpret=_interpret(),
-        )(q, k, v, do, lse4, delta4, cos, sin, cos, sin)
-        return dq, dk, dv
-
-    qg = q.reshape(B, Hkv, g, T, D)
-    dog = do.reshape(B, Hkv, g, T, D)
-    lseg = lse4.reshape(B, Hkv, g, T, 1)
-    deltag = delta4.reshape(B, Hkv, g, T, 1)
-    n_i = T // block_q
-    scratch = [pltpu.VMEM((block_k, D), jnp.float32),
-               pltpu.VMEM((block_k, D), jnp.float32)]
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_rope_bwd_dkv_kernel, causal=causal,
-                          scale=scale, g=g, n_i=n_i),
-        grid=(B, Hkv, T // block_k, n_i),
-        in_specs=[
-            pl.BlockSpec((None, None, g, block_q, D), lambda b, hk, j, i: (b, hk, 0, i, 0)),
-            pl.BlockSpec((None, None, block_k, D), lambda b, hk, j, i: (b, hk, j, 0)),
-            pl.BlockSpec((None, None, block_k, D), lambda b, hk, j, i: (b, hk, j, 0)),
-            pl.BlockSpec((None, None, g, block_q, D), lambda b, hk, j, i: (b, hk, 0, i, 0)),
-            pl.BlockSpec((None, None, g, block_q, 1), lambda b, hk, j, i: (b, hk, 0, i, 0)),
-            pl.BlockSpec((None, None, g, block_q, 1), lambda b, hk, j, i: (b, hk, 0, i, 0)),
-            pl.BlockSpec((block_q, D), lambda b, hk, j, i: (i, 0)),
-            pl.BlockSpec((block_q, D), lambda b, hk, j, i: (i, 0)),
-            pl.BlockSpec((block_k, D), lambda b, hk, j, i: (j, 0)),
-            pl.BlockSpec((block_k, D), lambda b, hk, j, i: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, block_k, D), lambda b, hk, j, i: (b, hk, j, 0)),
-            pl.BlockSpec((None, None, block_k, D), lambda b, hk, j, i: (b, hk, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, T, D), k.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, T, D), v.dtype),
-        ],
-        scratch_shapes=scratch,
-        interpret=_interpret(),
-    )(qg, k, v, dog, lseg, deltag, cos, sin, cos, sin)
-    return dq, dk, dv
-
-
 def rope_sdpa_supported(q, k, v, cos, sin, is_causal=True, scale=None) -> bool:
     """Claim fused rope+attention when the plain flash checker would claim
     the sdpa AND rope covers the full (even) head dim."""
@@ -977,6 +545,7 @@ def rope_sdpa_supported(q, k, v, cos, sin, is_causal=True, scale=None) -> bool:
         and D % 2 == 0
         and getattr(cos, "shape", None) == (T, D)
         and getattr(sin, "shape", None) == (T, D)
+        and _flash_fits_vmem("rope_sdpa", q, k, rope=True)
     )
 
 
@@ -1062,29 +631,41 @@ def flash_attention_supported(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=
     # the pallas kernels beat XLA's composite attention from T=1024 up
     # (measured v5e: nanogpt-124m B=8 T=1024 +20% step throughput; the
     # composite additionally OOMs at llama-350m B=4 T=2048 fwd+bwd).
-    # TT_FLASH_SDPA overrides: "0" never claims (composite path), "1"
-    # claims whenever the tiling fits (benchmark/profiling A/B)
-    override = os.environ.get("TT_FLASH_SDPA")
-    if override == "0":
-        return False
-    T = q.shape[-2]
-    long_enough = (override == "1") or T >= 1024
     shapes_ok = (
         q.shape[-1] <= 512  # any head dim (Mosaic pads the minor dim in VMEM)
-        and long_enough
+        and q.shape[-2] >= 1024
         and q.shape[-2] % DEFAULT_BLOCK_Q == 0
         and k.shape[-2] % DEFAULT_BLOCK_K == 0
         and q.shape[-2] == k.shape[-2]
         # GQA/MQA: the k/v BlockSpecs index kv head = q head // group, and
-        # the dkv backward computes per-q-head partials group-summed outside
-        # (shared kv outputs written from grouped programs would race)
+        # the backward does a kv head's whole q group in one program
         and q.shape[0] == k.shape[0] == v.shape[0]
         and k.shape[1] == v.shape[1]
         and q.shape[1] % k.shape[1] == 0
         and q.shape[-1] == k.shape[-1] == v.shape[-1]
         and k.shape[-2] == v.shape[-2]
     )
-    return bool(shapes_ok)
+    return bool(shapes_ok) and _flash_fits_vmem("flash_attention", q, k, rope=False)
+
+
+def _flash_fits_vmem(kernel: str, q, k, *, rope: bool) -> bool:
+    """Both kernels keep whole-length K and V in VMEM: the forward must fit the
+    compiler's default limit and the backward the one its call asks for, or
+    the checker declines and XLA's composite runs (a sequence too long for
+    them would otherwise be claimed and then refused by the compiler)."""
+    from ..analysis import budget as _budget
+
+    T, D, Tk, g = q.shape[-2], q.shape[-1], k.shape[-2], q.shape[1] // k.shape[1]
+    q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
+    kv_item = jnp.dtype(str(k.dtype).rpartition(".")[2]).itemsize
+    block_q, block_k = _budget.flash_block_cap(
+        max(q_item, kv_item), min(DEFAULT_BLOCK_Q, T), min(DEFAULT_BLOCK_K, Tk), T, Tk)
+    fwd = _budget.flash_fwd_vmem_bytes(block_q, block_k, Tk, D, q_item, kv_item, rope=rope)
+    bwd = _budget.flash_bwd_vmem_bytes(*_fused_bwd_blocks(block_q, block_k, T, Tk),
+                                       Tk, D, g, q_item, kv_item, rope=rope)
+    if _budget.within_vmem(fwd) and _budget.within_vmem(bwd, _FUSED_BWD_VMEM_LIMIT):
+        return True
+    return _decline(kernel, "vmem")
 
 
 # symbol registration: claims ltorch.sdpa whole ------------------------------
@@ -1348,8 +929,7 @@ def _int8_linear_supported(x, qweight, scale, bias=None):
     # the kernel is a TPU HBM-residency play; on CPU the interpret-mode
     # pallas path is a per-call interpreter, far slower than XLA's
     # dequant-matmul — serving benchmarks must measure the XLA path there
-    # (TT_INT8_PALLAS_CPU=1 re-enables the claim for kernel tests)
-    if not (_on_tpu() or os.environ.get("TT_INT8_PALLAS_CPU") == "1"):
+    if not _claims_on_platform():
         return False
     # whole-M block (no M grid): claim the serving/decode regime; huge-M
     # prefill/training shapes stay on the XLA path (compute-bound there)
@@ -1495,11 +1075,10 @@ def fp8_linear_fused(x2d, w, sx, sw, *, fmt_max: float = 448.0,
 
 
 def fp8_linear_fused_supported(x2d, w) -> bool:
-    """Dispatch gate for the fp8 training executor: TPU (or forced via
-    TT_FP8_FUSED=force for interpret-mode testing), tile-aligned shapes.
-    The CPU/jnp unfused reference stays the fallback everywhere else."""
-    forced = os.environ.get("TT_FP8_FUSED", "") == "force"
-    if not (_on_tpu() or forced):
+    """Dispatch gate for the fp8 training executor: the chip
+    (`_claims_on_platform`), tile-aligned shapes. The CPU/jnp unfused
+    reference stays the fallback everywhere else."""
+    if not _claims_on_platform():
         return False
     if getattr(x2d, "ndim", 0) != 2 or getattr(w, "ndim", 0) != 2:
         return False
@@ -1970,14 +1549,10 @@ def _paged_shapes_ok(q_heads: int, D: int, k_pages, v_pages, page_table, B: int)
 def paged_attention_supported(q, k_pages, v_pages, page_table, seq_lens, scale=None,
                               window=None) -> bool:
     """Checker: the paged decode kernel claims thunder.paged_attention on
-    TPU (TT_PAGED_KERNEL=1 forces the claim for interpret-mode A/B, =0
-    never claims); shapes must fit the page tiling, the pools' rows fill the
-    lanes and at least one whole page a loop step fits the VMEM budget —
-    otherwise the pure-jax gather decomposition runs."""
-    override = os.environ.get("TT_PAGED_KERNEL")
-    if override == "0":
-        return False
-    if not (_on_tpu() or override == "1"):
+    the chip (`_claims_on_platform`); shapes must fit the page tiling, the
+    pools' rows fill the lanes and at least one whole page a loop step fits
+    the VMEM budget — otherwise the pure-jax gather decomposition runs."""
+    if not _claims_on_platform():
         return False
     if getattr(q, "ndim", 0) != 3:
         return False
@@ -2089,12 +1664,9 @@ def paged_chunk_decode(q, k_pages, v_pages, page_table, q_pos, scale=None, windo
 def paged_chunk_attention_supported(q, k_pages, v_pages, page_table, q_pos,
                                     scale=None, window=None) -> bool:
     """Checker for thunder.paged_chunk_attention: same claim policy as the
-    decode kernel (TT_PAGED_KERNEL override, page tiling, VMEM budget with
-    the q/accumulator rows widened by T)."""
-    override = os.environ.get("TT_PAGED_KERNEL")
-    if override == "0":
-        return False
-    if not (_on_tpu() or override == "1"):
+    decode kernel (the chip, page tiling, VMEM budget with the q/accumulator
+    rows widened by T)."""
+    if not _claims_on_platform():
         return False
     if getattr(q, "ndim", 0) != 4:
         return False
@@ -2134,7 +1706,7 @@ ex.register_implementation("thunder.paged_chunk_attention", _paged_chunk_attenti
 # skipped (zero write, no MXU work), partially-padding blocks compute them
 # anyway — SwiGLU(0) = 0 exactly, so both roads agree bitwise on padding.
 
-_GROUPED_BLOCK_C = int(os.environ.get("TT_GROUPED_BLOCK_C", "128"))
+_GROUPED_BLOCK_C = 128
 
 
 def _grouped_mlp_kernel(gs_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, *, block_c: int):
@@ -2194,16 +1766,12 @@ def grouped_mlp_fused(bins, w_gate, w_up, w_down, group_sizes, *,
 
 
 def grouped_mlp_supported(bins, w_gate, w_up, w_down, group_sizes) -> bool:
-    """Checker: the grouped kernel claims thunder.grouped_mlp on TPU
-    (TT_GROUPED_KERNEL=1 forces the claim for interpret-mode A/B, =0 never
-    claims); the per-program working set — one expert's three weight panels
-    plus a bin block and its f32 SwiGLU intermediates — must fit the VMEM
-    budget, otherwise the batched-matmul decomposition runs (the ADVICE
-    fallback pattern, unified via analysis/memory.py)."""
-    override = os.environ.get("TT_GROUPED_KERNEL")
-    if override == "0":
-        return False
-    if not (_on_tpu() or override == "1"):
+    """Checker: the grouped kernel claims thunder.grouped_mlp on the chip
+    (`_claims_on_platform`); the per-program working set — one expert's three
+    weight panels plus a bin block and its f32 SwiGLU intermediates — must
+    fit the VMEM budget, otherwise the batched-matmul decomposition runs (the
+    ADVICE fallback pattern, unified via analysis/memory.py)."""
+    if not _claims_on_platform():
         return False
     if getattr(bins, "ndim", 0) != 3 or getattr(w_gate, "ndim", 0) != 3:
         return False
@@ -2409,7 +1977,7 @@ def ring_flash_step(q, kb, vb, o, m, l, q_off, k_off, *, causal: bool,
 def _ring_flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                               delta_ref, dq_ref, *, block_k: int, causal: bool,
                               scale: float):
-    # the flash dq recompute (see _flash_bwd_dq_kernel) with GLOBAL causal
+    # the flash dq recompute (p from the saved lse, blockwise) with GLOBAL causal
     # positions; lse is the GLOBAL log-sum-exp (all ring steps), so p for
     # this shard's keys is exact and dq contributions just add across steps
     block_q, D = q_ref.shape
@@ -2446,6 +2014,26 @@ def _ring_flash_bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         n_k = jnp.clip((lim + block_k - 1) // block_k, 0, n_k)
     dq = jax.lax.fori_loop(0, n_k, body, jnp.zeros((block_q, D), jnp.float32))
     dq_ref[:] = dq
+
+
+def _dkv_tile(k_blk, v_blk, q, do, lse2, delta, k_pos_t, q_pos_t, causal,
+              scale, dk_acc, dv_acc):
+    """One (k-block x q-tile) contribution to dk/dv, transposed orientation
+    (rows = k positions) in log2 units."""
+    s_t = jax.lax.dot_general(k_blk, q, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32) * (scale * LOG2E)  # (bk, bq)
+    if causal:
+        s_t = jnp.where(k_pos_t <= q_pos_t, s_t, NEG_INF)
+    p_t = jnp.exp2(s_t - lse2[None, :])
+    dv_acc = dv_acc + jax.lax.dot_general(p_t.astype(do.dtype), do,
+                                          (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.float32)
+    dp_t = jax.lax.dot_general(v_blk, do, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)  # (bk, bq)
+    ds_t = (p_t * (dp_t - delta[None, :]) * scale).astype(q.dtype)
+    dk_acc = dk_acc + jax.lax.dot_general(ds_t, q, (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.float32)
+    return dk_acc, dv_acc
 
 
 def _ring_flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -2550,16 +2138,12 @@ def ring_flash_bwd_step(q, kb, vb, do, lse, delta, q_off, k_off, *, causal: bool
 
 
 def ring_flash_supported(q, k, v) -> bool:
-    """Checker for the streaming ring path inside dist.ring_attention: TPU
-    (TT_RING_KERNEL=1 forces for interpret-mode A/B, =0 never), equal-size
-    shards on the flash tiling, and one step's working set — q block + this
-    shard's K/V + the f32 carries — within the VMEM budget via the unified
-    analysis/memory.py estimate; otherwise the pure-jax GQA-native
-    reference ring runs."""
-    override = os.environ.get("TT_RING_KERNEL")
-    if override == "0":
-        return False
-    if not (_on_tpu() or override == "1"):
+    """Checker for the streaming ring path inside dist.ring_attention: the
+    chip (`_claims_on_platform`), equal-size shards on the flash tiling, and
+    one step's working set — q block + this shard's K/V + the f32 carries —
+    within the VMEM budget via the unified analysis/memory.py estimate;
+    otherwise the pure-jax GQA-native reference ring runs."""
+    if not _claims_on_platform():
         return False
     if getattr(q, "ndim", 0) != 4 or getattr(k, "ndim", 0) != 4:
         return False

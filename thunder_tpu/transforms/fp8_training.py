@@ -27,7 +27,6 @@ TPU-first redesign:
 from __future__ import annotations
 
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +34,7 @@ import jax.numpy as jnp
 from ..core import dtypes
 from ..core.proxies import TensorProxy
 from ..core.transform_common import Transform
+from ..executors import pallasex
 from ..extend import StatefulExecutor, register_executor
 from ..nn.module import Parameter
 
@@ -66,20 +66,6 @@ def _q(x, scale, fmt_max, dtype):
     return jnp.clip(x.astype(jnp.float32) * scale, -fmt_max, fmt_max).astype(dtype)
 
 
-def _use_fused(x, w) -> bool:
-    """Route through the fused Pallas kernel (executors/pallasex.py
-    fp8_linear_fused): quantize + amax + matmul in one VMEM pass, killing
-    the separate memory-bound scaling programs the profiler blamed for the
-    fp8 road's 0.83x-of-bf16 regression. TT_FP8_FUSED=0 disables."""
-    if os.environ.get("TT_FP8_FUSED", "1") == "0":
-        return False
-    try:
-        from ..executors.pallasex import fp8_linear_fused_supported
-    except Exception:
-        return False
-    return fp8_linear_fused_supported(x, w)
-
-
 def _linear_fwd_meta(x, w, bias, hist_x, hist_w, margin=0):
     # the operand amaxes come back as extra outputs: the fused kernel
     # reduces them in the matmul's VMEM pass, and even unfused this lets
@@ -96,10 +82,11 @@ def _linear_fwd_impl(state: FP8Recipe, x, w, bias, hist_x, hist_w, margin=0):
     # one); the executor state carries the default recipe/formats
     sx = _scale_from_hist(hist_x, E4M3_MAX, margin)
     sw = _scale_from_hist(hist_w, E4M3_MAX, margin)
-    if _use_fused(x, w):
-        from ..executors.pallasex import fp8_linear_fused
-
-        y, ax, aw = fp8_linear_fused(x, w, sx, sw, fmt_max=E4M3_MAX)
+    # quantize + amax + matmul in one VMEM pass where the kernel's checker
+    # claims (the separate memory-bound scaling programs were what the
+    # profiler blamed for the fp8 road's 0.83x-of-bf16 regression)
+    if pallasex.fp8_linear_fused_supported(x, w):
+        y, ax, aw = pallasex.fp8_linear_fused(x, w, sx, sw, fmt_max=E4M3_MAX)
     else:
         xq = _q(x, sx, E4M3_MAX, jnp.float8_e4m3fn)
         wq = _q(w, sw, E4M3_MAX, jnp.float8_e4m3fn)
@@ -128,10 +115,8 @@ def _aug_fwd_meta(x, w, bias, hist_x, hist_w, margin=0):
 def _aug_fwd_impl(state: FP8Recipe, x, w, bias, hist_x, hist_w, margin=0):
     sx = _scale_from_hist(hist_x, E4M3_MAX, margin)
     sw = _scale_from_hist(hist_w, E4M3_MAX, margin)
-    if _use_fused(x, w):
-        from ..executors.pallasex import fp8_linear_fused
-
-        y, xq, wq, ax, aw = fp8_linear_fused(x, w, sx, sw, fmt_max=E4M3_MAX,
+    if pallasex.fp8_linear_fused_supported(x, w):
+        y, xq, wq, ax, aw = pallasex.fp8_linear_fused(x, w, sx, sw, fmt_max=E4M3_MAX,
                                              save_quantized=True)
     else:
         xq = _q(x, sx, E4M3_MAX, jnp.float8_e4m3fn)
