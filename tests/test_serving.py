@@ -791,3 +791,183 @@ def test_plain_decode_of_packed_heads_matches_dense(rng):
     for p, n, fut in reqs:
         out, _ = dense.generate(jnp.asarray(p[None, :]), n, scan_decode=False)
         np.testing.assert_array_equal(fut.result().new_tokens, np.asarray(out)[0, len(p):])
+
+
+# ---------------------------------------------------------------------------
+# one decode step in flight: the next step is dispatched before the last
+# one's tokens are fetched
+# ---------------------------------------------------------------------------
+
+# (prompt length, tokens asked for, temperature, seed): mixed lengths, greedy and sampled
+_MIXED = [(5, 12, 0.0, 0), (13, 7, 0.9, 42), (9, 16, 0.0, 0), (20, 5, 0.7, 7), (3, 10, 0.0, 0)]
+
+
+def _mixed_requests(rng, gpt):
+    return [(rng.randint(0, gpt.cfg.vocab_size, (L,)).astype(np.int32), n, temp, seed)
+            for L, n, temp, seed in _MIXED]
+
+
+def _alone(gpt, reqs):
+    """What each request gives served alone, one engine for all, one request at a time."""
+    engine = _engine(gpt)
+    out = []
+    for p, n, temp, seed in reqs:
+        fut = engine.submit(p, max_new_tokens=n, temperature=temp, seed=seed)
+        engine.drain()
+        out.append(fut.result().new_tokens)
+    return out
+
+
+def _bus_counters(run):
+    from thunder_tpu import observability
+
+    observability.enable()
+    observability.reset()
+    try:
+        out = run()
+        return out, observability.counters()
+    finally:
+        observability.disable()
+        observability.reset()
+
+
+@pytest.mark.parametrize("end", ["length", "eos", "cancel", "preempt"])
+def test_requests_admitted_while_others_decode_give_what_each_gives_alone(gpt, rng, end):
+    """Admissions land the step in flight and the next step is fed from the host; every other
+    step is fed the sampler's output on the device. Token for token nothing may show: ends by
+    length, by an `eos_id` the seeded run is known to produce (the step dispatched before the
+    end was seen is thrown away), by a cancelled Future, and across a preemption."""
+    reqs = _mixed_requests(rng, gpt)
+    want = _alone(gpt, reqs)
+    engine = _engine(gpt, max_batch=3)   # five requests on three slots: slots are reused
+    eos = {}
+    if end == "eos":
+        # request 2 ends early at its 6th token (an id it does not produce before)
+        k = next(j for j in range(4, 16) if want[2][j] not in want[2][:j])
+        eos[2] = int(want[2][k])
+        want[2] = want[2][:k + 1]
+    lanes = {0: "batch"} if end == "preempt" else {}
+
+    def submit(i):
+        p, n, temp, seed = reqs[i]
+        return engine.submit(p, max_new_tokens=n, temperature=temp, seed=seed, eos_id=eos.get(i),
+                             lane=lanes.get(i, "interactive"))
+
+    def run():
+        futs = {i: submit(i) for i in (0, 1)}
+        for _ in range(3):
+            engine._step_once()
+        futs[2] = submit(2)                  # admitted while 0 and 1 decode
+        for _ in range(3):
+            engine._step_once()
+        if end == "cancel":
+            assert futs[0].cancel()          # mid-decode, a token of its in flight
+        if end == "preempt":
+            assert engine._inflight is not None and engine._preempt_one()
+            assert engine._inflight is None  # the victim kept the token it had in flight
+        futs[3], futs[4] = submit(3), submit(4)
+        engine.drain()
+        return futs
+
+    futs, counters = _bus_counters(run)
+    for i, fut in futs.items():
+        if end == "cancel" and i == 0:
+            assert fut.cancelled()
+            continue
+        res = fut.result(timeout=5)
+        np.testing.assert_array_equal(res.new_tokens, want[i])
+        assert res.finish_reason == ("eos" if i in eos else "length")
+    assert engine._inflight is None and engine.cache.allocator.n_used == 0
+    assert all(s is None for s in engine._slots)
+    # a token is thrown away only where the host learned of an end at a commit, and then only
+    # if a step was in flight (an admission's prefill may have landed it: the eos case)
+    discarded = counters.get("serve.decode_discarded", 0)
+    assert discarded <= 1 and (end == "eos" or discarded == (end == "cancel"))
+    if end != "cancel":
+        # committed tokens only: each request's first token is its prefill's
+        assert counters["serve.tokens"] == sum(len(w) - 1 for w in want)
+    if end == "preempt":
+        assert engine.preempted == 1 and engine.resumed == 1
+
+
+def test_an_end_the_host_learns_at_commit_costs_one_step_whose_token_is_thrown_away(gpt, rng):
+    """`eos_id` is seen when the token is committed, one dispatch later: the step in flight
+    then is wasted, its token counted in `serve.decode_discarded` and not in `serve.tokens`,
+    and the pages it wrote to are the request's own, freed at that commit."""
+    engine = _engine(gpt)
+    p = rng.randint(0, gpt.cfg.vocab_size, (6,)).astype(np.int32)
+    probe = engine.submit(p, max_new_tokens=8)
+    engine.drain()
+    stream = probe.result().new_tokens
+    k = next(j for j in range(2, 8) if stream[j] not in stream[:j])
+    steps0 = engine.decode_steps
+
+    def run():
+        fut = engine.submit(p, max_new_tokens=30, eos_id=int(stream[k]))
+        engine.drain()
+        return fut.result(timeout=5)
+
+    res, counters = _bus_counters(run)
+    assert res.finish_reason == "eos"
+    np.testing.assert_array_equal(res.new_tokens, stream[:k + 1])
+    # k steps gave the tokens after the prefill's; one more was in flight when the end was seen
+    assert engine.decode_steps - steps0 == k + 1 == counters["serve.decode_steps"]
+    assert counters["serve.tokens"] == k and counters["serve.decode_discarded"] == 1
+    assert engine._inflight is None and engine.cache.allocator.n_used == 0
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 9])
+def test_a_request_alone_runs_the_decode_steps_it_needs_and_no_more(gpt, dense, rng, n):
+    """The host knows a request's last token by count before it dispatches: n tokens are the
+    prefill's and n - 1 decode steps, as in a loop that fetches before it dispatches, and a
+    step with no live sequence is never made."""
+    engine = _engine(gpt)
+    p = rng.randint(0, gpt.cfg.vocab_size, (6,)).astype(np.int32)
+    fut = engine.submit(p, max_new_tokens=n)
+    engine.drain()
+    out, _ = dense.generate(jnp.asarray(p[None, :]), n, scan_decode=False)
+    np.testing.assert_array_equal(fut.result().new_tokens, np.asarray(out)[0, 6:])
+    assert engine.decode_steps == n - 1 and engine._inflight is None
+    engine._step_once()  # nothing outstanding: nothing is dispatched
+    assert engine.decode_steps == n - 1
+
+
+def test_most_decode_steps_are_dispatched_with_the_step_before_unfetched(gpt, rng):
+    """`serve.decode_overlapped` over `serve.decode_steps`: every step but the first after an
+    activation. Four requests admitted in one pass and one admitted later: 2 of 23 steps follow
+    an activation. Without `eos_id` or a cancel no token is thrown away."""
+    engine = _engine(gpt)
+
+    def run():
+        futs = [engine.submit(rng.randint(0, gpt.cfg.vocab_size, (L,)).astype(np.int32),
+                              max_new_tokens=n) for L, n in [(5, 24), (9, 20), (12, 16), (7, 12)]]
+        for _ in range(6):
+            engine._step_once()
+        futs.append(engine.submit(rng.randint(0, gpt.cfg.vocab_size, (4,)).astype(np.int32),
+                                  max_new_tokens=8))
+        engine.drain()
+        return [f.result(timeout=5).n_new_tokens for f in futs]
+
+    got, counters = _bus_counters(run)
+    assert got == [24, 20, 16, 12, 8]
+    steps = counters["serve.decode_steps"]
+    assert steps == engine.decode_steps == 23
+    assert counters["serve.decode_overlapped"] == steps - 2
+    assert counters["serve.decode_overlapped"] / steps > 0.9
+    assert "serve.decode_discarded" not in counters
+    assert counters["serve.tokens"] == sum(got) - len(got)
+
+
+def test_the_speculative_path_records_no_overlapped_step(gpt, rng):
+    """Verify needs the accepted count on the host: its path fetches before it dispatches."""
+    engine = _engine(gpt, draft_gpt=gpt, spec_k=2)
+
+    def run():
+        fut = engine.submit(rng.randint(0, gpt.cfg.vocab_size, (6,)).astype(np.int32),
+                            max_new_tokens=9)
+        engine.drain()
+        return fut.result(timeout=5)
+
+    res, counters = _bus_counters(run)
+    assert res.n_new_tokens == 9 and counters["serve.decode_steps"] > 0
+    assert "serve.decode_overlapped" not in counters and engine._inflight is None

@@ -19,6 +19,7 @@ from thunder_tpu.models.litgpt import Config as GPTConfig, GPT
 from thunder_tpu.nn.module import functional_params
 from thunder_tpu.ops import ltorch
 from thunder_tpu.serving import ServingEngine
+from thunder_tpu.serving.kv_pages import Recurrent
 
 pytestmark = pytest.mark.serve
 
@@ -111,6 +112,67 @@ def test_prefill_then_decode_agrees_with_the_reference_alone_and_batched(engine)
         assert a.n_new_tokens == n and np.array_equal(a.new_tokens, b.new_tokens)
         assert gap(CONFIG, engine.params, a, p, n) < LOGIT_TOL
     assert engine.cache.allocator.n_used == 0 and engine.cache.window_allocator.n_used == 0
+
+
+def test_sequences_admitted_while_others_decode_give_what_each_gives_alone(model):
+    """One decode step stays in flight: window pages are taken and handed back for the step
+    being dispatched while the step before it may still read them, and an admission lands the
+    step in flight. Token for token nothing may show, and no page of either kind is left."""
+    eng = engine_for(model, max_batch=3)
+    reqs = list(zip(prompts([p for p, _ in SAMPLE], seed=7), [n for _, n in SAMPLE]))
+    alone = [serve(eng, [p], [n])[0] for p, n in reqs]
+    observability.enable()
+    observability.reset()
+    try:
+        futs = [eng.submit(p, max_new_tokens=n) for p, n in reqs[:2]]
+        for _ in range(5):
+            eng._step_once()
+        assert eng._inflight is not None
+        futs += [eng.submit(p, max_new_tokens=n) for p, n in reqs[2:]]
+        eng.drain()
+        counters = observability.counters()
+    finally:
+        observability.disable()
+        observability.reset()
+    for a, fut in zip(alone, futs):
+        assert np.array_equal(a.new_tokens, fut.result(timeout=5).new_tokens)
+    assert eng._inflight is None
+    assert eng.cache.allocator.n_used == 0 and eng.cache.window_allocator.n_used == 0
+    steps = counters["serve.decode_steps"]
+    # four activations in a run of about ninety steps, and the passes whose only work is a chunk
+    assert counters["serve.decode_overlapped"] / steps > 0.85
+    assert "serve.decode_discarded" not in counters
+    assert counters["serve.tokens"] == sum(n - 1 for _, n in SAMPLE)
+
+
+def recurrent_rows(eng, slot: int) -> list:
+    """The rows slot ``slot`` holds in every recurrent array, on the host."""
+    return [np.asarray(a[slot]) for layer, arrays in zip(eng.cache.layers, eng.cache.state)
+            if isinstance(layer, Recurrent) for a in arrays]
+
+
+def test_a_request_alone_leaves_its_slot_as_its_last_needed_step_left_it(model):
+    """A sequence whose last token by count is in flight is not in the next step: n tokens are
+    the prefill's and n - 1 decode steps, and the scan state of its slot is the one after step
+    n - 1 (what `benchmark/drivers/serve_added.py` compares with the reference's). The witness
+    is a longer request on a fresh engine, read after exactly n - 1 steps."""
+    n, prompt = 12, prompts([40], seed=9)[0]
+    eng = engine_for(model, max_batch=2)
+    res = serve(eng, [prompt], [n])[0]
+    assert res.n_new_tokens == n and eng.decode_steps == n - 1 and eng._inflight is None
+    kept = recurrent_rows(eng, 0)
+    assert kept and all(np.abs(r).max() > 0 for r in kept)
+    eng._step_once()   # nothing outstanding: no step, and the slot's rows stay
+    assert eng.decode_steps == n - 1
+    witness = engine_for(model, max_batch=2)
+    fut = witness.submit(prompt, max_new_tokens=n + 8)
+    while witness.decode_steps < n - 1:
+        witness._step_once()
+    for got, want in zip(kept, recurrent_rows(witness, 0)):
+        np.testing.assert_array_equal(got, want)
+    witness.drain()
+    assert np.array_equal(fut.result(timeout=5).new_tokens[:n], res.new_tokens)
+    assert any(not np.array_equal(a, b) for a, b in zip(kept, recurrent_rows(witness, 0)))
 
 
 def test_the_halved_window_control_fails(engine):

@@ -160,10 +160,10 @@ def run(args) -> dict:
         # rung, and the verify program compile — AND the prefix cache ends
         # warm, which is the steady state the measured phase models
         for spec in specs:
-            engine.submit(spec[0], 2, lane=spec[2])
+            engine.submit(spec[0], 3, lane=spec[2])  # as warmup(): both ways a step is fed
         engine.drain()
     else:
-        engine.warmup(sorted({len(p) for p, _, _ in specs}), max_new_tokens=2)
+        engine.warmup(sorted({len(p) for p, _, _ in specs}))
     observability.reset()
     engine.reset_slo_accounting()  # warmup must not pollute goodput/windows
 

@@ -40,6 +40,17 @@ the baseline engine behaves exactly as before (docs/serving.md):
   their pages in cache, and bit-identical thanks to position-keyed
   sampling.
 
+The plain decode path keeps ONE decode step in flight: a pass of the loop
+dispatches the next step, whose tokens are the sampler's output of the step
+before and never leave the device, and only then fetches and commits that
+step before's tokens, so admission, uploads, the dispatch and the runtime's
+wake-up all run under a program the chip already has. Whatever changes the
+slots for another reason (an activation, a preemption, a failure, stop)
+lands the step in flight first (``_land``). A sequence whose last token by
+count is in flight is left out of the next step, and a step with no live
+sequence is never dispatched; an end the host learns at commit (``eos_id``, a
+cancelled Future) costs one step whose token is thrown away.
+
 Per-request observability rides the existing bus: request-id-tagged spans,
 ``serve.*`` counters, and flight-recorder records per decode iteration
 (docs/serving.md, docs/observability.md). The loop itself runs under
@@ -131,6 +142,15 @@ class _Request:
     chunk_pos: int = -1          # next chunk start (chunk mode only)
 
 
+@dataclass
+class _Step:
+    """A decode step in flight: dispatched, its sampled tokens not fetched."""
+
+    nxt: jax.Array               # (max_batch, 1) int32 on the device: the next step's tokens
+    reqs: Dict[int, _Request]    # slot -> the sequence that was in the step
+    t0: float                    # when the pass that dispatched it began its decode
+
+
 def _sample_tokens(logits, seeds, pos, temps):
     """Position-keyed sampling: token at position p for request seed s draws
     from fold_in(PRNGKey(s), p). temps == 0 -> greedy argmax."""
@@ -142,6 +162,20 @@ def _sample_tokens(logits, seeds, pos, temps):
         return jnp.where(t > 0, sampled, jnp.argmax(l, -1))
 
     return jax.vmap(one)(logits, seeds, pos, temps).astype(jnp.int32)
+
+
+def _sample_step(logits, seeds, pos, temps):
+    """The decode step's sampler: tokens for the positions after ``pos``, as
+    the (max_batch, 1) column the next step's program takes, so that they
+    need not leave the device in between."""
+    return _sample_tokens(logits, seeds, pos + 1, temps)[:, None]
+
+
+def _upload(host: np.ndarray) -> jax.Array:
+    """A copy of ``host`` on the device. A copy, because the engine goes on
+    writing its packed host arrays while the step that was given them is in
+    flight, and the CPU backend's arrays share the memory they were made from."""
+    return jnp.asarray(np.array(host))
 
 
 class ServingEngine:
@@ -268,6 +302,7 @@ class ServingEngine:
         self.window = self.cache.window
         self.params = {k: p.data for k, p in gpt.named_parameters()}
         self._sampler = jax.jit(_sample_tokens)
+        self._step_sampler = jax.jit(_sample_step)
 
         self.prefix = (PrefixCache(self.cache.allocator, page_size)
                        if prefix_sharing else None)
@@ -318,6 +353,7 @@ class ServingEngine:
         self._temps_dev = None
         self._pt_dirty = True
         self._slots: List[Optional[_Request]] = [None] * max_batch
+        self._inflight: Optional[_Step] = None  # the plain decode path's step in flight
 
         self._pending: deque = deque()        # interactive lane (admits first)
         self._pending_batch: deque = deque()  # batch lane (preemptible)
@@ -438,6 +474,7 @@ class ServingEngine:
             self._stop.clear()
             self.drain()
             self._stop.set()
+        self._land()  # what the step in flight finished is delivered, not failed
         exc = RuntimeError("serving engine stopped")
         for i, req in enumerate(self._slots):
             if req is not None:
@@ -468,10 +505,13 @@ class ServingEngine:
             return
         while self._has_work():
             self._step_once()
+        self._land()  # a step whose every token is thrown away (an early end) may be left
 
-    def warmup(self, prompt_lens, max_new_tokens: int = 2) -> None:
+    def warmup(self, prompt_lens, max_new_tokens: int = 3) -> None:
         """Pre-compile the decode step and the prefill bucket for each
-        prompt length (steady state then never recompiles)."""
+        prompt length (steady state then never recompiles). Three tokens a
+        request: the prefill's, one from a step fed from the host and one
+        from a step fed the sampler's output on the device."""
         for L in prompt_lens:
             self.submit(np.zeros((L,), np.int32), max_new_tokens)
         self.drain()
@@ -573,6 +613,7 @@ class ServingEngine:
     def _loop(self) -> None:
         while not self._stop.is_set():
             if not self._has_work():
+                self._land()  # only a step whose every token is thrown away can be left
                 # one phase per idle stretch, not per sleep: an idle engine
                 # must not flood the bus's ring
                 with _obs_runtime.phase("engine:wait"):
@@ -803,6 +844,7 @@ class ServingEngine:
         """Spill the most recently admitted batch-lane sequence: free its
         pages (shared ones just decref — the prefix cache keeps them warm)
         and requeue it at the FRONT of the batch lane for resume."""
+        self._land()  # the victim keeps the token it has in flight
         victim = None
         for i, r in enumerate(self._slots):
             if (r is not None and r.lane == "batch"
@@ -847,6 +889,7 @@ class ServingEngine:
         # bundle (census + page-pool state) BEFORE freeing this request's
         # pages, so the bundle shows the pool as the allocator saw it
         _obs_mem.maybe_post_mortem(exc, step=self.decode_steps, source="serve")
+        self._land()  # the other sequences' tokens in flight are theirs whatever failed here
         self._free_pages(req)
         try:
             req.future.set_exception(exc)
@@ -927,6 +970,7 @@ class ServingEngine:
         with every page free and the next request is served. Where the
         arrays are alive (the failure came before execution) nothing is
         lost and nothing is done. Returns whether pools were lost."""
+        self._land()
         lost = [c for c in (self.cache, self.draft_cache)
                 if c is not None and c.pools_deleted()]
         if not lost:
@@ -986,6 +1030,9 @@ class ServingEngine:
                                          jnp.asarray([req.seed], jnp.uint32),
                                          jnp.asarray([L], jnp.int32),
                                          jnp.asarray([req.temperature], jnp.float32))
+                    # behind the step in flight on the device: commit that one
+                    # while the prefill runs, then wait for the first token
+                    self._land()
                     tok0 = int(np.asarray(tok0)[0])
         except Exception as e:
             self._fail(req, e)
@@ -1135,6 +1182,7 @@ class ServingEngine:
             tok0 = self._sampler(logits, jnp.asarray([req.seed], jnp.uint32),
                                  jnp.asarray([L_eff], jnp.int32),
                                  jnp.asarray([req.temperature], jnp.float32))
+            self._land()  # as in _prefill: commit the step in flight under the chunk
             tok0 = int(np.asarray(tok0)[0])
         except Exception as e:
             self._fail(req, e)
@@ -1147,6 +1195,9 @@ class ServingEngine:
         self._activate(req, slot, pos=L_eff, tok=tok0)
 
     def _activate(self, req: _Request, slot: int, *, pos: int, tok: int) -> None:
+        # its first token comes from the host, so the next step is fed from the
+        # host: no step may be in flight (where a first token was fetched, none is)
+        self._land()
         self._slots[slot] = req
         self._page_tables[slot] = self.cache.page_table_row(req.pages,
                                                             self.n_pages_max)
@@ -1161,111 +1212,190 @@ class ServingEngine:
 
     def _clear_slot(self, i: int) -> None:
         self._slots[i] = None
-        self._page_tables[i] = 0
-        self._win_tables[i] = 0
-        self._pos[i] = 0
+        self._rest_slot(i)
         self._toks[i] = 0
         self._seeds[i] = 0
         self._temps[i] = 0.0
+
+    def _rest_slot(self, i: int) -> None:
+        """Slot i as the decode program sees an idle one: position 0 and a
+        null-page row, so a step writes to the null page and leaves the slot's
+        recurrent rows alone."""
+        self._page_tables[i] = 0
+        self._win_tables[i] = 0
+        self._pos[i] = 0
         self._pt_dirty = True
 
     def _upload_packed_state(self) -> None:
         # page tables / seeds / temps only change at slot (un)assignment;
         # re-upload them then, not per token (pos/toks change every step)
         if self._pt_dirty:
-            self._pt_dev = ((jnp.asarray(self._page_tables), jnp.asarray(self._win_tables))
-                            if self.window else (jnp.asarray(self._page_tables),))
-            self._seeds_dev = jnp.asarray(self._seeds)
-            self._temps_dev = jnp.asarray(self._temps)
+            self._pt_dev = ((_upload(self._page_tables), _upload(self._win_tables))
+                            if self.window else (_upload(self._page_tables),))
+            self._seeds_dev = _upload(self._seeds)
+            self._temps_dev = _upload(self._temps)
             self._pt_dirty = False
 
     def _commit(self, i: int, req: _Request, tok: int, t_now: float) -> bool:
         """Commit one generated token to slot i; returns False when the
-        request finished (retired, slot cleared)."""
+        request finished (retired, slot cleared). The slot's position is its
+        caller's to advance."""
         if req.t_first == 0.0:
             # prefix-hit admissions skip prefill: TTFT stamps at the first
             # committed token instead
             req.t_first = t_now
         req.tokens.append(tok)
         req.t_last = t_now
-        self._pos[i] += 1
         self._toks[i] = tok
         if self._finished(req, tok):
             self._retire(req)
             self._clear_slot(i)
             return False
-        if self.window:
-            # the next write lands at pos: take its page where pos enters a
-            # new one, hand back the page that has just left the window
-            pos, ps = int(self._pos[i]), self.page_size
-            if pos % ps == 0 or (pos - self.window + 1) % ps == 0:
-                self._window_cover(req, pos, pos + 1)
-                self._window_trim(req, pos)
-                self._win_tables[i] = self._window_row(req)
-                self._pt_dirty = True
         return True
 
+    def _window_step(self, i: int) -> None:
+        """Before the step that writes slot i's position is dispatched: take
+        that position's window page where it enters a new one, hand back the
+        page that has just left the window. Both follow from the position
+        alone. A page handed back may still be read by the step in flight; the
+        device runs what is dispatched in order, so whoever writes it next does
+        so after that read."""
+        pos, ps = int(self._pos[i]), self.page_size
+        if pos % ps == 0 or (pos - self.window + 1) % ps == 0:
+            req = self._slots[i]
+            self._window_cover(req, pos, pos + 1)
+            self._window_trim(req, pos)
+            self._win_tables[i] = self._window_row(req)
+            self._pt_dirty = True
+
     def _decode(self) -> None:
+        """One pass of the plain decode path: dispatch the next step, then
+        fetch and commit the one before it."""
         if self.draft_cache is not None and self.spec_k > 0:
             self._spec_decode()
             return
-        active = [i for i, s in enumerate(self._slots) if s is not None]
-        if not active:
+        prev = self._inflight
+        live = []
+        for i, req in enumerate(self._slots):
+            if req is None:
+                continue
+            flying = prev is not None and prev.reqs.get(i) is req
+            if len(req.tokens) + flying < req.max_new_tokens:
+                live.append(i)
+            else:
+                # its last token by count is in flight: it idles from this step on
+                self._rest_slot(i)
+        if not live:
+            self._land()  # a step with no live sequence is never dispatched
             return
-        obs_on = _obs.enabled()
-        phase = _obs_runtime.phase
+        # taken out while the next step is dispatched: a failure's clean-up, which
+        # lands "the step in flight", then finds none, and prev is settled below
+        self._inflight = None
         t0 = time.perf_counter()
-        with phase("engine:upload"):
+        with _obs_runtime.phase("engine:upload"):
+            if self.window:
+                for i in live:
+                    self._window_step(i)
             self._upload_packed_state()
+        with (_obs_runtime.step_span("serve_decode", active=len(live))
+              if _obs.enabled() else _NULL):
+            step = self._dispatch(live, prev, t0)
+            fetched = self._fetch(prev)
+        self._commit_step(prev, fetched)
+        self._inflight = step
+
+    def _dispatch(self, live: List[int], prev: Optional[_Step], t0: float) -> Optional[_Step]:
+        """Enqueue the decode step of the ``live`` slots and its sampler. The
+        token a sequence feeds is the one the sampler produced for it in
+        ``prev``, the step in flight, still on the device; with no step in
+        flight the tokens go up from the host. Returns the step, or None after
+        a failure (every live sequence failed, pages returned)."""
+        phase = _obs_runtime.phase
         try:
-            with (_obs_runtime.step_span("serve_decode", active=len(active))
-                  if obs_on else _NULL):
-                with phase("engine:upload"):
-                    toks = jnp.asarray(self._toks[:, None])
-                    pos = jnp.asarray(self._pos)
-                with phase("engine:dispatch"):
-                    logits, state = self.runner.decode_cfn(
-                        self.params, toks, self.cache.state, self._pt_dev, pos)
-                    self.cache.rebind(state)
-                    # the NEXT token's position is pos+1 (this step wrote
-                    # pos); it goes up with the sampler's enqueue, behind
-                    # the decode program the device already has
-                    nxt = self._sampler(logits, self._seeds_dev,
-                                        jnp.asarray(self._pos + 1),
-                                        self._temps_dev)
-                with phase("engine:fetch"):
-                    nxt = np.asarray(nxt)
+            with phase("engine:upload"):
+                toks = prev.nxt if prev is not None else _upload(self._toks[:, None])
+                pos = _upload(self._pos)
+            with phase("engine:dispatch"):
+                logits, state = self.runner.decode_cfn(
+                    self.params, toks, self.cache.state, self._pt_dev, pos)
+                self.cache.rebind(state)
+                # the NEXT token's position is pos+1 (this step wrote pos)
+                nxt = self._step_sampler(logits, self._seeds_dev, pos, self._temps_dev)
+                # the host's copy starts as the sampler ends, not when the fetch asks
+                nxt.copy_to_host_async()
         except Exception as e:
-            # the packed step failed: every active sequence is implicated —
+            # the packed step failed: every live sequence is implicated —
             # fail their futures and return their pages rather than hanging
             # the whole engine (pending requests still get admitted)
-            for i in active:
+            for i in live:
                 self._fail(self._slots[i], e)
                 self._clear_slot(i)
             self._drop_lost_pools(e)
+            return None
+        self.decode_steps += 1
+        if _obs.enabled():
+            # what the step read and held follows from the positions it was given
+            _obs_metrics.record_serve("decode_steps")
+            _obs_metrics.record_serve("decode_overlapped", delta=int(prev is not None))
+            self._record_state(len(live))
+            self._record_paged_pages()
+        self._pos[live] += 1
+        return _Step(nxt, {i: self._slots[i] for i in live}, t0)
+
+    def _fetch(self, step: Optional[_Step]) -> Optional[np.ndarray]:
+        """The sampled tokens of ``step`` on the host, (max_batch,). None where
+        there is no step, or it failed: then the sequences that were in it
+        have failed."""
+        if step is None:
+            return None
+        with _obs_runtime.phase("engine:fetch"):
+            try:
+                return np.asarray(step.nxt)[:, 0]
+            except Exception as e:
+                for i, req in step.reqs.items():
+                    if self._slots[i] is req:
+                        self._fail(req, e)
+                        self._clear_slot(i)
+                self._drop_lost_pools(e)
+                return None
+
+    def _commit_step(self, step: Optional[_Step], nxt: Optional[np.ndarray]) -> None:
+        """Commit the tokens ``_fetch`` gave for ``step`` (None: nothing to
+        commit) to the sequences that were in it. One that ended at a commit since the step was dispatched (``eos_id``,
+        a cancelled Future) or was failed has left its slot: its token is
+        thrown away."""
+        if nxt is None:
             return
-        with phase("engine:commit"):
+        with _obs_runtime.phase("engine:commit"):
             # with the bus on, the step's own records count as commit too
             t_now = time.perf_counter()
-            self.decode_steps += 1
-            if obs_on:
-                _obs_metrics.record_serve("decode_steps")
-                _obs_metrics.record_serve("tokens", delta=len(active))
-                self._record_state(len(active))
-                self._record_paged_pages()
-                _obs_flight.record_step((t_now - t0) * 1e3, fn="serve_decode",
-                                        active=len(active))
-                # online decode-iteration latency percentiles (unsampled, like
-                # the flight recorder — TT_OBS_SAMPLE only thins the spans)
-                _obs_tel.observe("serve.decode_ms", (t_now - t0) * 1e3)
+            kept = [(i, req) for i, req in step.reqs.items() if self._slots[i] is req]
+            if _obs.enabled():
+                dur_ms = (t_now - step.t0) * 1e3
+                _obs_metrics.record_serve("tokens", delta=len(kept))
+                if len(kept) < len(step.reqs):
+                    _obs_metrics.record_serve("decode_discarded",
+                                              delta=len(step.reqs) - len(kept))
+                _obs_flight.record_step(dur_ms, fn="serve_decode", active=len(step.reqs))
+                # online decode-step latency percentiles, dispatch to commit
+                # (unsampled, like the flight recorder — TT_OBS_SAMPLE only
+                # thins the spans)
+                _obs_tel.observe("serve.decode_ms", dur_ms)
                 # ONE shared trace event per step carrying every participant
                 # (volume scales with steps, not steps × batch width)
                 _obs_trace.trace_step(
-                    [self._slots[i].trace_id for i in active], "decode",
-                    dur_ms=(t_now - t0) * 1e3, step=self.decode_steps,
-                    active=len(active))
-            for i in active:
-                self._commit(i, self._slots[i], int(nxt[i]), t_now)
+                    [req.trace_id for req in step.reqs.values()], "decode",
+                    dur_ms=dur_ms, step=self.decode_steps, active=len(step.reqs))
+            for i, req in kept:
+                self._commit(i, req, int(nxt[i]), t_now)
+
+    def _land(self) -> None:
+        """Fetch and commit the step in flight, if there is one: the one place
+        that does. Whatever changes the slots outside ``_decode`` calls it
+        first, so a dispatch that finds a step in flight finds every live
+        sequence in it."""
+        step, self._inflight = self._inflight, None
+        self._commit_step(step, self._fetch(step))
 
     def _record_state(self, active: int) -> None:
         """What the cached state of this decode step's sequences took, summed
@@ -1384,6 +1514,7 @@ class ServingEngine:
                 accepted_total += m
                 for j in range(n):
                     committed_total += 1
+                    self._pos[i] += 1
                     if not self._commit(i, req, int(samples[i, j]), t_now):
                         break
             if obs_on:
