@@ -171,6 +171,71 @@ def test_grouped_expert_mlp_kernel_on_chip():
                                atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("tokens_in", [128, 512], ids=["decode-128-slots", "chunk-512"])
+def test_ragged_expert_mlp_kernel_on_chip(tokens_in):
+    """The ragged expert kernel at the widths and row counts of the cell
+    mistral-small-4-119b-ep4-l6.serve-rollouts (32 held experts of 4096 x 2048,
+    `tokens_in` tokens of 4 choices each), uneven groups with empty experts
+    among them, against each row through its own expert in float32."""
+    from thunder_tpu.executors import pallasex
+    from thunder_tpu.models.moe import ragged_tile
+
+    rng = np.random.RandomState(0)
+    E, D, H = 32, 4096, 2048
+    tile = ragged_tile(tokens_in * 4, 128)
+    R = -(-tokens_in * 4 // tile) * tile + E * tile
+    sizes = rng.multinomial(tokens_in, rng.dirichlet(np.full(E, 0.5))).astype(np.int32)
+    sizes[:3] = 0
+    padded = -(-sizes // tile) * tile
+    starts = np.cumsum(padded) - padded
+    rows = np.zeros((R, D), np.float32)
+    of = np.full(R, -1)
+    for e in range(E):
+        rows[starts[e]:starts[e] + sizes[e]] = rng.randn(sizes[e], D) * 0.5
+        of[starts[e]:starts[e] + sizes[e]] = e
+    rows = jnp.asarray(rows, jnp.bfloat16)
+    wg, wu = (jnp.asarray(rng.randn(E, D, H) / math.sqrt(D), jnp.bfloat16) for _ in range(2))
+    wd = jnp.asarray(rng.randn(E, H, D) / math.sqrt(H), jnp.bfloat16)
+    assert pallasex.ragged_mlp_supported(rows, wg, wu, wd, jnp.asarray(sizes), tile)
+    out = np.asarray(pallasex.ragged_mlp_fused(rows, wg, wu, wd, jnp.asarray(sizes), tile), np.float32)
+    assert np.abs(out[of < 0]).max() == 0.0           # padding rows and the tiles past the last group
+    f32 = lambda t: jnp.asarray(t, jnp.float32)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        for e in np.flatnonzero(sizes)[:6]:
+            x = f32(rows[starts[e]:starts[e] + sizes[e]])
+            ref = (jax.nn.silu(x @ f32(wg[e])) * (x @ f32(wu[e]))) @ f32(wd[e])
+            np.testing.assert_allclose(out[starts[e]:starts[e] + sizes[e]], np.asarray(ref), atol=3e-2, rtol=3e-2)
+
+
+def test_latent_decode_kernel_on_chip():
+    """The decode kernel over one latent pool at the cell's shapes: 128 slots
+    (idle ones among them), 32 heads on rows of 320 numbers padded to 384,
+    values the first 256 columns, ragged contexts up to 4096."""
+    from thunder_tpu.executors import pallasex
+
+    rng = np.random.RandomState(0)
+    B, H, W, vw, ps, npm = 128, 32, 384, 256, 64, 64
+    lens = np.where(rng.rand(B) < 0.85, rng.randint(2, 4096, B), 1).astype(np.int32)
+    P = 1 + int(np.sum(-(-lens // ps)))
+    pool = rng.randn(P, ps, W).astype(np.float32)
+    pool[..., 320:] = 0.0
+    pool = jnp.asarray(pool, jnp.bfloat16)
+    table, nxt = np.zeros((B, npm), np.int32), 1
+    for b in np.flatnonzero(lens > 1):
+        n = -(-int(lens[b]) // ps)
+        table[b, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    q = jnp.asarray(rng.randn(B, H, W) * 0.5, jnp.bfloat16)
+    scale = 0.19
+    out = np.asarray(pallasex.paged_latent_decode(q, pool, jnp.asarray(table), jnp.asarray(lens), scale, vw),
+                     np.float32)
+    dense = np.asarray(pool, np.float32)[table].reshape(B, npm * ps, W)
+    mask = (np.arange(npm * ps)[None, :] < lens[:, None])[:, None, :]
+    s = np.einsum("bhw,bsw->bhs", np.asarray(q, np.float32), dense) * scale
+    p = np.asarray(jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1))
+    np.testing.assert_allclose(out, np.einsum("bhs,bsv->bhv", p, dense[..., :vw]), atol=2e-2, rtol=2e-2)
+
+
 def test_ring_flash_kernels_on_chip():
     """The streaming ring-flash forward and backward step kernels, driven
     through ring_flash_attention over a one-chip ring (GQA 16q/4kv, D=64):

@@ -140,19 +140,67 @@ def paged_chunk_vmem_bytes(page_size: int, D: int, g: int, T: int,
 
 
 def grouped_mlp_vmem_bytes(block_c: int, D: int, H: int,
-                           w_itemsize: int, x_itemsize: int) -> int:
-    """Estimated per-program VMEM working set of the grouped-expert MLP
-    kernel (pallasex `_grouped_mlp_kernel`): one expert's three weight
-    panels, a (block_c, D) token-bin block and the output block — two
-    buffers each, as Mosaic's pipeline allocates them (counted once, the
-    estimate let d 1024 x h 2816 bf16 through, which the compiler refuses:
-    34.00M against the 16.00M scoped limit) — and the fused f32 SwiGLU
-    intermediates (gate/up/hidden)."""
-    w = 2 * 3 * D * H * w_itemsize          # w_gate + w_up + w_down(T) panels
-    xb = 2 * block_c * D * x_itemsize       # input bin block
-    inter = block_c * (3 * H) * 4           # g, u, h in f32
-    out = 2 * block_c * D * x_itemsize      # output bin block
-    return w + xb + inter + out
+                           w_itemsize: int, x_itemsize: int,
+                           block_h: Optional[int] = None) -> int:
+    """Estimated per-program VMEM working set of an expert-MLP kernel: one
+    expert's three weight tiles over ``block_h`` hidden columns (the whole
+    panels, ``block_h = H``, in pallasex `_grouped_mlp_kernel`, which has no
+    tiles; a tile of them in `_ragged_mlp_kernel`), a (block_c, D) block of rows
+    and the output block — two buffers each, as Mosaic's pipeline allocates
+    them (counted once, the estimate let d 1024 x h 2816 bf16 through, which
+    the compiler refuses: 34.00M against the 16.00M scoped limit) — the fused
+    f32 SwiGLU intermediates (gate/up/hidden) over the tile and, where the
+    hidden dimension is tiled, the f32 accumulator the tiles add up in."""
+    bh = H if block_h is None else block_h
+    w = 2 * 3 * D * bh * w_itemsize         # w_gate + w_up + w_down(T) tiles
+    xb = 2 * block_c * D * x_itemsize       # input rows
+    inter = block_c * (3 * bh) * 4          # g, u, h in f32
+    out = 2 * block_c * D * x_itemsize      # output rows
+    acc = block_c * D * 4 if bh < H else 0
+    return w + xb + inter + out + acc
+
+
+# scoped VMEM the ragged expert kernel asks Mosaic for: it streams weight tiles
+# and is the faster the larger (the more contiguous) they are; three eighths of
+# a v5e core's 128 MiB
+RAGGED_MLP_VMEM_LIMIT = 48 * 2**20
+
+
+def ragged_mlp_block_h(tile: int, D: int, H: int, w_itemsize: int, x_itemsize: int) -> int:
+    """Hidden columns a weight tile of the ragged expert kernel holds: the
+    most of 1024, 512, 256, 128 (or H itself) that divide H and whose working
+    set fits ``RAGGED_MLP_VMEM_LIMIT``; 0 when none does, and the checker then
+    declines."""
+    for bh in sorted({b for b in (H, 1024, 512, 256, 128) if b <= min(H, 1024) and H % b == 0},
+                     reverse=True):
+        if within_vmem(grouped_mlp_vmem_bytes(tile, D, H, w_itemsize, x_itemsize, bh),
+                       RAGGED_MLP_VMEM_LIMIT):
+            return bh
+    return 0
+
+
+def latent_decode_vmem_bytes(page_size: int, row: int, v_width: int, heads: int, itemsize: int,
+                             q_itemsize: int, pages_per_step: int) -> int:
+    """Estimated per-program VMEM working set of the paged latent decode kernel
+    (pallasex `_latent_attn_kernel`): two buffers of ``pages_per_step`` whole
+    pages of rows, the q and output blocks twice, the f32 accumulator with its
+    m/l columns, and the f32 scores, probabilities and mask of one step."""
+    pages = 2 * pages_per_step * page_size * row * itemsize
+    qo = 2 * heads * (row + v_width) * q_itemsize
+    scratch = heads * v_width * 4 + 2 * heads * 4
+    scores = 4 * heads * pages_per_step * page_size * 4
+    return pages + qo + scratch + scores
+
+
+def latent_pages_per_step(page_size: int, row: int, v_width: int, heads: int, itemsize: int,
+                          q_itemsize: int) -> int:
+    """Pages the latent decode kernel copies and multiplies a loop step: as
+    ``paged_pages_per_step``, 0 when not one fits."""
+    for pps in range(PAGED_MAX_PAGES_PER_STEP, 0, -1):
+        if within_vmem(latent_decode_vmem_bytes(page_size, row, v_width, heads, itemsize,
+                                                q_itemsize, pps), paged_vmem_limit()):
+            return pps
+    return 0
 
 
 def ring_flash_vmem_bytes(block_q: int, T_blk: int, D: int,

@@ -1694,6 +1694,169 @@ ex.register_implementation("thunder.paged_chunk_attention", _paged_chunk_attenti
                            checker=paged_chunk_attention_supported)
 
 
+# ---------------------------------------------------------------------------
+# Paged attention — decode over ONE latent pool (multi-head latent attention)
+# ---------------------------------------------------------------------------
+#
+# The absorbed form of latent attention (ops/ltorch.py paged_latent_attention)
+# has no per-head keys or values: a cached row IS every head's key, and its
+# first `v_width` columns every head's value. The kernel is the decode kernel
+# above with one pool and one head of keys: one grid program a sequence walks
+# its live pages `pages_per_step` a loop step through a double-buffered VMEM
+# scratch (the next block, or the next sequence's first, in flight), all the
+# query heads are the rows of one matmul against the step's rows, and the
+# values are a slice of the same VMEM tile: a row crosses HBM once.
+
+
+def _latent_attn_kernel(pt_ref, sl_ref, q_ref, c_hbm, o_ref, c_buf, sems, slot_ref,
+                        acc_scr, m_scr, l_scr, *, scale: float, pages_per_step: int, v_width: int):
+    # grid (B,), in order. q_ref (H, W), o_ref (H, v_width); c_hbm the whole
+    # pool (P, page_size, W), left in HBM; c_buf (2, pps, page_size, W); sems
+    # (2,) one a buffer; slot_ref the buffer this program's first step is in.
+    b = pl.program_id(0)
+    pps = pages_per_step
+    ps, W = c_buf.shape[2], c_buf.shape[3]
+    H = q_ref.shape[0]
+    cols = pps * ps
+
+    def end_of(seq):
+        return (sl_ref[seq] + ps - 1) // ps
+
+    def copy(seq, page, slot, j):
+        return pltpu.make_async_copy(c_hbm.at[pt_ref[seq, page]], c_buf.at[slot, j], sems.at[slot])
+
+    def fetch(seq, step, slot):
+        end = end_of(seq)
+        for j in range(pps):
+            page = step * pps + j
+
+            @pl.when(page < end)
+            def _start():
+                copy(seq, page, slot, j).start()
+
+            # past the sequence nothing is copied: the rows there are masked,
+            # and a probability of zero still needs values that are numbers
+            @pl.when(page >= end)
+            def _blank():
+                c_buf[slot, j] = jnp.zeros((ps, W), c_buf.dtype)
+
+    end = end_of(b)
+    seq_len = sl_ref[b]
+    n_steps = jnp.maximum((end + pps - 1) // pps, 1)
+
+    @pl.when(b == 0)
+    def _first():
+        slot_ref[0] = 0
+        fetch(0, 0, 0)
+
+    slot0 = slot_ref[0]
+    _paged_init(acc_scr, m_scr, l_scr)
+    q = q_ref[:]
+    offset = jax.lax.broadcasted_iota(jnp.int32, (H, cols), 1)
+
+    def step_body(i, carry):
+        slot = (slot0 + i) % 2
+        page0 = i * pps
+
+        @pl.when(i + 1 < n_steps)
+        def _next_step():
+            fetch(b, i + 1, 1 - slot)
+
+        @pl.when((i + 1 == n_steps) & (b + 1 < pl.num_programs(0)))
+        def _next_sequence():
+            fetch(b + 1, 0, 1 - slot)
+            slot_ref[0] = 1 - slot
+
+        for j in range(pps):
+            @pl.when(page0 + j < end)
+            def _wait():
+                copy(b, page0 + j, slot, j).wait()
+
+        rows = c_buf[slot].reshape(cols, W)
+        live = page0 * ps + offset < seq_len
+        _paged_softmax_step(q, rows, rows[:, :v_width], live, acc_scr, m_scr, l_scr, scale)
+        return carry
+
+    jax.lax.fori_loop(0, n_steps, step_body, 0)
+    _paged_write(o_ref, acc_scr, l_scr)
+
+
+def _latent_pages_per_step(H: int, q_itemsize: int, pool, v_width: int) -> int:
+    from ..analysis import budget as _budget
+
+    ps, W = pool.shape[1], pool.shape[2]
+    item = jnp.dtype(str(pool.dtype).rpartition(".")[2]).itemsize
+    return _budget.latent_pages_per_step(ps, W, v_width, H, item, q_itemsize)
+
+
+def paged_latent_decode(q, pool, page_table, seq_lens, scale, v_width: int,
+                        *, interpret: bool | None = None):
+    """q (B, H, W) against a paged latent pool (P, page_size, W) through
+    page_table (B, n_pages_max) int32 / seq_lens (B,) int32 (valid rows,
+    the current token's included) -> (B, H, v_width): every head scores each
+    row over its W columns and takes its first ``v_width`` as the value."""
+    B, H, W = q.shape
+    ps = pool.shape[1]
+    interpret = _interpret() if interpret is None else interpret
+    pps = _latent_pages_per_step(H, q.dtype.itemsize, pool, v_width)
+    if not interpret and (W % 128 or v_width % 128 or not pps):
+        raise ValueError(f"paged_latent_decode cannot take rows {tuple(pool.shape)} with values "
+                         f"{v_width} wide: " + _PAGED_REFUSALS["lanes" if W % 128 or v_width % 128 else "vmem"])
+    pps = max(pps, 1)  # the interpreter has no VMEM to run out of
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((None, H, W), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, H, v_width), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, pps, ps, W), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((H, v_width), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_attn_kernel, scale=scale, pages_per_step=pps, v_width=v_width),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, v_width), q.dtype),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), q, pool)
+
+
+def paged_latent_attention_supported(q, pool, page_table, q_pos, scale, v_width) -> bool:
+    """Checker: the latent decode kernel claims thunder.paged_latent_attention
+    on the chip for ONE query a sequence (the decode step; a chunk's and the
+    verify step's queries run the gather decomposition), rows that fill the
+    128 lanes, values a whole number of lane groups, and at least one page a
+    loop step inside the VMEM budget."""
+    if not _claims_on_platform():
+        return False
+    if getattr(q, "ndim", 0) != 4 or getattr(pool, "ndim", 0) != 3 or q.shape[2] != 1:
+        return False
+    B, H, _, W = q.shape
+    if not (pool.shape[2] == W and pool.shape[1] % 8 == 0 and 0 < v_width <= W <= 1024
+            and getattr(page_table, "ndim", 0) == 2 and page_table.shape[0] == B
+            and tuple(q_pos.shape) == (B, 1)):
+        return False
+    if _on_tpu() and (W % 128 or v_width % 128):
+        return _decline("paged_latent_attention", "lanes")
+    q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
+    if not _latent_pages_per_step(H, q_item, pool, v_width):
+        return _decline("paged_latent_attention", "vmem")
+    return True
+
+
+def _paged_latent_attention_impl(q, pool, page_table, q_pos, scale, v_width):
+    B, H, _, W = q.shape
+    out = paged_latent_decode(q.reshape(B, H, W), pool, page_table, q_pos[:, 0] + 1, scale, v_width)
+    return out.reshape(B, H, 1, v_width)
+
+
+ex.register_implementation("thunder.paged_latent_attention", _paged_latent_attention_impl,
+                           checker=paged_latent_attention_supported)
+
+
 # ===========================================================================
 # Grouped-expert MLP (MoE capacity-routed dispatch)
 # ===========================================================================
@@ -1864,6 +2027,140 @@ def _register_grouped_mlp_grad_rule():
 
 
 _register_grouped_mlp_grad_rule()
+
+
+# ---------------------------------------------------------------------------
+# Ragged expert MLP (drop-free dispatch: rows sorted by expert)
+# ---------------------------------------------------------------------------
+#
+# ops/ltorch.py ragged_mlp: the rows of each expert lie together from a
+# tile-aligned offset, so a tile of rows is ONE expert's. The grid runs (row
+# tile, hidden block), hidden blocks innermost: a program multiplies its tile
+# by a (D, block_h) slab of that expert's gate and up panels and a (block_h, D)
+# slab of its down panel and adds into an f32 accumulator, so the working set
+# is a few tiles however wide the expert (the capacity-bin kernel above keeps
+# three whole panels twice). Which expert a tile belongs to rides as a
+# scalar-prefetch operand, so the weight index maps resolve it before each
+# DMA; the tiles past the last group point at the block that was fetched last
+# and compute nothing, so an expert with no rows costs no weight read.
+
+
+def _ragged_mlp_kernel(te_ref, nl_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref):
+    # grid (R // tile, H // block_h). te_ref (tiles,) expert of each row tile,
+    # nl_ref (1,) the tiles that hold rows; x_ref (tile, D); wg/wu (D, block_h),
+    # wd (block_h, D) of the tile's expert; acc_ref (tile, D) f32
+    i, j = pl.program_id(0), pl.program_id(1)
+    live = i < nl_ref[0]
+
+    @pl.when(live & (j == 0))
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _compute():
+        x = x_ref[:]
+        wd = wd_ref[:]
+        g = jax.lax.dot_general(x, wg_ref[:], (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        u = jax.lax.dot_general(x, wu_ref[:], (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        h = (g * (1.0 / (1.0 + jnp.exp(-g)))) * u
+        acc_ref[:] += jax.lax.dot_general(h.astype(wd.dtype), wd, (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _write():
+        o_ref[:] = jnp.where(live, acc_ref[:], 0.0).astype(o_ref.dtype)
+
+
+def _ragged_block_h(tile: int, D: int, H: int, w_dtype, x_dtype) -> int:
+    from ..analysis import budget as _budget
+
+    w_item = jnp.dtype(str(w_dtype).rpartition(".")[2]).itemsize
+    x_item = jnp.dtype(str(x_dtype).rpartition(".")[2]).itemsize
+    return _budget.ragged_mlp_block_h(tile, D, H, w_item, x_item)
+
+
+def ragged_mlp_fused(rows, w_gate, w_up, w_down, group_sizes, tile: int, *,
+                     block_h: int | None = None, interpret: bool | None = None):
+    """rows (R, D), sorted by expert in tile-aligned ragged groups, through
+    their experts' panels (E, D, H)/(E, H, D) -> (R, D); rows outside every
+    group come back zero (ops/ltorch.py ragged_mlp has the layout)."""
+    from ..analysis import budget as _budget
+
+    R, D = rows.shape
+    E, _, H = w_gate.shape
+    if block_h is None:
+        block_h = _ragged_block_h(tile, D, H, w_gate.dtype, rows.dtype) or math.gcd(H, 128)
+    n_tiles, nj = R // tile, H // block_h
+    tiles_of = (group_sizes.astype(jnp.int32) + (tile - 1)) // tile
+    ends = jnp.cumsum(tiles_of)
+    n_live = ends[-1:]
+    tile_expert = jnp.minimum(jnp.searchsorted(ends, jnp.arange(n_tiles, dtype=jnp.int32),
+                                               side="right"), E - 1).astype(jnp.int32)
+
+    def row_tile(i, nl):     # a tile past the last group: the block that is there
+        return jnp.maximum(jnp.minimum(i, nl[0] - 1), 0)
+
+    def hidden(i, j, nl):
+        return jnp.where(i < nl[0], j, nj - 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_tiles, nj),
+        in_specs=[
+            pl.BlockSpec((tile, D), lambda i, j, te, nl: (row_tile(i, nl), 0)),
+            pl.BlockSpec((None, D, block_h), lambda i, j, te, nl: (te[row_tile(i, nl)], 0, hidden(i, j, nl))),
+            pl.BlockSpec((None, D, block_h), lambda i, j, te, nl: (te[row_tile(i, nl)], 0, hidden(i, j, nl))),
+            pl.BlockSpec((None, block_h, D), lambda i, j, te, nl: (te[row_tile(i, nl)], hidden(i, j, nl), 0)),
+        ],
+        out_specs=pl.BlockSpec((tile, D), lambda i, j, te, nl: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((tile, D), jnp.float32)],
+    )
+    return pl.pallas_call(
+        _ragged_mlp_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, D), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_budget.RAGGED_MLP_VMEM_LIMIT),
+        interpret=_interpret() if interpret is None else interpret,
+    )(tile_expert, n_live, rows, w_gate, w_up, w_down)
+
+
+def ragged_mlp_supported(rows, w_gate, w_up, w_down, group_sizes, tile) -> bool:
+    """Checker: the ragged kernel claims thunder.ragged_mlp on the chip
+    (`_claims_on_platform`) where rows and panels belong together, the tile is
+    a whole number of sublane tiles and D and H of lane groups, and a weight
+    tile over some block of the hidden dimension fits the kernel's VMEM limit
+    (analysis/memory.py ragged_mlp_block_h); otherwise the decomposition runs."""
+    if not _claims_on_platform():
+        return False
+    if getattr(rows, "ndim", 0) != 2 or getattr(w_gate, "ndim", 0) != 3:
+        return False
+    R, D = rows.shape
+    E, _, H = w_gate.shape
+    if not (tuple(w_gate.shape) == (E, D, H) and tuple(w_up.shape) == (E, D, H)
+            and tuple(w_down.shape) == (E, H, D)
+            and getattr(group_sizes, "ndim", 0) == 1 and group_sizes.shape[0] == E
+            and tile % 8 == 0 and R % tile == 0):
+        return False
+    if _on_tpu() and (D % 128 or H % 128 or tile % 16):
+        return _decline("ragged_mlp", "lanes")
+    if not _ragged_block_h(tile, D, H, w_gate.dtype, rows.dtype):
+        return _decline("ragged_mlp", "vmem")
+    return True
+
+
+_ragged_mlp_claimed = _jit_claimed(
+    lambda rows, w_gate, w_up, w_down, group_sizes, tile: ragged_mlp_fused(
+        rows, w_gate, w_up, w_down, group_sizes, tile),
+    ("tile",), lambda rows, w_gate, w_up, w_down, group_sizes, tile: (
+        (rows, w_gate, w_up, w_down, group_sizes), {"tile": tile}))
+
+
+ex.register_implementation("thunder.ragged_mlp", _ragged_mlp_claimed,
+                           checker=ragged_mlp_supported)
 
 
 # ===========================================================================

@@ -105,21 +105,6 @@ def split_qkv_rope(block, cfg, x_n, cos, sin):
     return q, k, v
 
 
-def block_mix(block, cfg, x, h):
-    """Residual + MLP/MoE tail of one block (the other half of the shared
-    plumbing; see split_qkv_rope)."""
-    mlp = getattr(block, "mlp", None)
-    is_moe = mlp is None
-    if is_moe:
-        mlp = block.moe  # MoE decoder blocks (models/moe.py MoEBlock)
-    if cfg.parallel_residual and not is_moe:
-        # MoEBlock.forward is always sequential (moe.py:92-93); only
-        # litgpt Blocks honor parallel_residual
-        return x + h + mlp(block.norm_2(x))
-    x = x + h
-    return x + mlp(block.norm_2(x))
-
-
 class GPTInference:
     """Greedy/temperature generation over a models.litgpt.GPT or
     models.moe.MoEGPT (Mixtral-style MoE decoder).
@@ -171,7 +156,7 @@ class GPTInference:
             vq = _repeat_kv(v_cache, q_per_kv) if ng != nh else v_cache
             y = cached_sdpa(q, kq, vq, pos)
             y = ltorch.reshape(ltorch.permute(y, (0, 2, 1, 3)), (B, T, nh * cfg.head_size))
-            x = block_mix(block, cfg, x, block.attn.proj(y))
+            x = block.tail(x, block.attn.proj(y))
         x = gpt.ln_f(x)
         logits = gpt.lm_head(x[:, -1])  # only last position needed for generation
         return logits, tuple(new_ks), tuple(new_vs)

@@ -248,7 +248,12 @@ class Block(nn.Module):
         self.mlp = {"LLaMAMLP": LLaMAMLP, "GptNeoxMLP": GptNeoxMLP}[cfg.mlp_class_name](cfg, dtype)
 
     def forward(self, x, cos, sin):
-        h = self.attn(self.norm_1(x), cos, sin)
+        return self.tail(x, self.attn(self.norm_1(x), cos, sin))
+
+    def tail(self, x, h):
+        """The block's output from its input ``x`` and its attention's output
+        ``h``: the residuals and the MLP. What a caller that runs the attention
+        itself (the cached and the paged engines) asks of a block."""
         if self.cfg.parallel_residual:
             return x + h + self.mlp(self.norm_2(x))
         x = x + h

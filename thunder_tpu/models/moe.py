@@ -26,8 +26,9 @@ class MoEConfig:
     intermediate_size: int = 256
     n_expert: int = 8
     n_expert_per_token: int = 2
-    # None = drop-free (capacity N: the worst case of every token routing one
-    # of its k choices to the same expert); a float opts into Switch-style
+    # None = drop-free: the grouped road sorts the rows by expert into ragged
+    # groups (``ragged_experts``: no bins, no capacity) and the dense road's
+    # capacity is N, which no expert can exceed; a float opts into Switch-style
     # drops with cap = ceil(cf * N * K / E) rounded up to the sublane tile
     capacity_factor: float | None = None
     # "grouped" packs tokens into per-expert capacity bins and runs
@@ -67,7 +68,9 @@ class MoEMLP(nn.Module):
     def capacity(self, n_tokens: int) -> int:
         cfg = self.cfg
         if cfg.capacity_factor is None:
-            return n_tokens  # drop-free: an expert appears at most once per token
+            # an expert appears at most once per token. The dense road's bound only: the
+            # grouped road's drop-free dispatch is ragged and has no bins (forward)
+            return n_tokens
         cap = math.ceil(cfg.capacity_factor * n_tokens * cfg.n_expert_per_token / cfg.n_expert)
         return min(n_tokens, (cap + 7) // 8 * 8)  # sublane-tile rounding
 
@@ -85,6 +88,18 @@ class MoEMLP(nn.Module):
         topk_probs, topk_idx = ltorch.topk(probs, K, -1)  # (N, K)
         # normalize selected probabilities (Mixtral convention)
         topk_probs = topk_probs / ltorch.sum(topk_probs, -1, keepdim=True)
+
+        if cfg.capacity_factor is None and cfg.dispatch == "grouped":
+            # drop-free: rows sorted by expert, ragged groups, every expert held
+            out, counts, _ = ragged_experts(xf, topk_idx, topk_probs,
+                                            (self.w_gate, self.w_up, self.w_down), (0, E))
+            if events.enabled():
+                lsm = ltorch.log_softmax(router_logits, -1)
+                entropy = -ltorch.sum(ltorch.sum(probs * lsm, -1), 0) / N
+                self.update_buffer("moe_expert_load", counts.to(probs.dtype) / (N * K))
+                self.update_buffer("moe_dropped_tokens", ltorch.zeros_like(entropy))
+                self.update_buffer("moe_router_entropy", entropy)
+            return ltorch.reshape(ltorch.to(out, dtype=x.dtype), (B, T, D))
 
         # capacity/drop decision shared by BOTH roads: slot rank within each
         # expert is FIFO by flattened (token, k) index via cumsum of one-hot
@@ -138,6 +153,140 @@ class MoEMLP(nn.Module):
         return ltorch.reshape(out, (B, T, D))
 
 
+def ragged_tile(n_rows: int, n_routed: int) -> int:
+    """Rows a tile of the ragged dispatch holds (one expert's, ``ltorch.ragged_mlp``):
+    about twice what an expert sees when ``n_rows`` (tokens x experts a token)
+    spread evenly over ``n_routed`` experts, so that a group mostly fills one
+    tile and its panels are read once; between the bf16 sublane tile (16) and
+    the MXU's 128 rows."""
+    want = -(-2 * n_rows // n_routed)
+    return min(128, max(16, -(-want // 16) * 16))
+
+
+def ragged_experts(xf, idx, w, panels, held: tuple, *, live=None, n_routed: int | None = None):
+    """The routed part of an expert layer for the experts HELD here, dropping
+    nothing: the rows of the held experts sorted by expert into ragged groups,
+    ``ltorch.ragged_mlp`` over them, and each token's chosen rows summed with
+    their weights. What experts outside ``held`` would add is left out.
+
+    xf (N, D) tokens; idx (N, K) int the experts each chose, numbered over the
+    whole layer; w (N, K) float32 their weights; panels (w_gate (E, D, H), w_up,
+    w_down (E, H, D)) of the ``E = hi - lo`` experts ``held = (lo, hi)``; live
+    (N,) bool or None: tokens that are padding (an idle slot, a bucket's tail)
+    enter no group and cost no panel. Shapes are static: the rows buffer has
+    room for every choice of every token plus a tile of slack a held expert.
+    Returns (out (N, D) float32, group_sizes (E,) int32, held_rows (N * K,) bool)."""
+    from ..core import dtypes, prims
+
+    N, D = xf.shape
+    K = idx.shape[1]
+    lo, hi = held
+    E = hi - lo
+    tile = ragged_tile(N * K, n_routed or E)
+    R = -(-N * K // tile) * tile + E * tile
+    i32 = dtypes.int32
+    flat = ltorch.to(ltorch.reshape(idx, (N * K,)), dtype=i32)
+    here = ltorch.logical_and(ltorch.ge(flat, lo), ltorch.lt(flat, hi))
+    if live is not None:
+        here = ltorch.logical_and(here, ltorch.reshape(
+            ltorch.expand(ltorch.unsqueeze(live, 1), (N, K)), (N * K,)))
+    oh = ltorch.to(ltorch.one_hot(ltorch.where(here, flat - lo, E), E + 1)[:, :E], dtype=i32)
+    counts = ltorch.sum(oh, 0)                                            # (E,)
+    rank = ltorch.sum(ltorch.cumsum(oh, 0) * oh, 1) - 1                   # place in its group
+    padded = ltorch.floor_divide(counts + (tile - 1), tile) * tile
+    starts = ltorch.cumsum(padded, 0) - padded                            # tile-aligned
+    dest = ltorch.where(here, ltorch.sum(oh * ltorch.unsqueeze(starts, 0), 1) + rank, R)
+    # the one scatter is of token numbers; activations move by gathers only
+    token = ltorch.floor_divide(prims.iota(N * K, dtype=i32, device=xf.device), K)
+    src = ltorch.index_put(ltorch.full((R + 1,), N, dtype=i32, device=xf.device), (dest,), token)
+    zero = ltorch.zeros(1, D, device=xf.device, dtype=xf.dtype)
+    rows = clang.take(ltorch.cat([xf, zero], 0), src[:R], 0)              # (R, D), padding zero
+    y = ltorch.ragged_mlp(rows, *panels, counts, tile)
+    picked = clang.take(ltorch.cat([y, zero], 0), dest, 0)                # (N * K, D)
+    f32 = dtypes.float32
+    out = ltorch.sum(ltorch.reshape(
+        ltorch.to(picked, dtype=f32) * ltorch.reshape(ltorch.to(w, dtype=f32), (N * K, 1)),
+        (N, K, D)), 1)
+    return out, counts, here
+
+
+class HeldExperts(nn.Module):
+    """An expert layer that is TOLD WHICH EXPERTS IT HOLDS, as one chip of an
+    expert-parallel group holds them (DeepSeek-V3's layer, arXiv:2412.19437):
+    the router scores all ``n_routed`` experts in float32 (sigmoid, with a
+    per-expert bias added for the choice only), the ``n_expert_per_token``
+    largest are chosen, their scores
+    normalised (``norm_topk_prob``) and scaled; the layer computes the rows of
+    its own experts ``held = (lo, hi)`` through ``ragged_experts``, drops no
+    token, leaves out what the absent experts would add, and adds the shared
+    expert, which every chip of the group computes alike. On one chip it runs
+    without the exchange; nothing stands in for the other chips."""
+
+    def __init__(self, n_embd: int, width: int, n_routed: int, held: tuple, n_expert_per_token: int,
+                 *, n_shared: int = 1, norm_topk_prob: bool = True, routed_scaling_factor: float = 1.0,
+                 dtype=jnp.float32):
+        super().__init__()
+        lo, hi = held
+        if not 0 <= lo < hi <= n_routed:
+            raise ValueError(f"experts held [{lo}, {hi}) are no range of the {n_routed} routed")
+        self.n_routed, self.held, self.k = n_routed, (lo, hi), n_expert_per_token
+        self.norm_topk_prob, self.scaling = norm_topk_prob, routed_scaling_factor
+        e = hi - lo
+        self.gate = nn.Linear(n_embd, n_routed, bias=False, dtype=dtype)
+        self.e_score_correction_bias = nn.Parameter(jnp.zeros((n_routed,), jnp.float32))
+        self.w_gate = nn.Parameter(jnp.zeros((e, n_embd, width), dtype))
+        self.w_up = nn.Parameter(jnp.zeros((e, n_embd, width), dtype))
+        self.w_down = nn.Parameter(jnp.zeros((e, width, n_embd), dtype))
+        self.shared_width = n_shared * width
+        if n_shared:
+            self.shared_gate = nn.Linear(n_embd, self.shared_width, bias=False, dtype=dtype)
+            self.shared_up = nn.Linear(n_embd, self.shared_width, bias=False, dtype=dtype)
+            self.shared_down = nn.Linear(self.shared_width, n_embd, bias=False, dtype=dtype)
+
+    def route(self, xf):
+        """(idx (N, K), w (N, K) float32) of the tokens xf (N, D)."""
+        from ..core import dtypes
+
+        f32 = dtypes.float32
+        logits = ltorch.linear(ltorch.to(xf, dtype=f32), ltorch.to(self.gate.weight, dtype=f32))
+        s = ltorch.sigmoid(logits)
+        _, idx = ltorch.topk(s + self.e_score_correction_bias, self.k, -1)
+        w = ltorch.take_along_dim(s, idx, 1)
+        if self.norm_topk_prob:
+            w = w / (ltorch.sum(w, -1, keepdim=True) + 1e-20)
+        return idx, w * self.scaling
+
+    def forward(self, x, live=None, counted=None):
+        """x (B, T, D); live (B * T,) bool or None marks the tokens that are no
+        padding. With ``counted`` (a list) the layer appends its four
+        ``serving.runner.ROUTING_COUNTERS`` of this call as one (4,) int32."""
+        from ..core import dtypes
+        from ..core.trace import named_scope
+
+        B, T, D = x.shape
+        N = B * T
+        xf = ltorch.reshape(x, (N, D))
+        with named_scope("moe_router"):
+            idx, w = self.route(xf)
+        with named_scope("moe_experts"):
+            out, counts, here = ragged_experts(xf, idx, w, (self.w_gate, self.w_up, self.w_down),
+                                               self.held, live=live, n_routed=self.n_routed)
+            if counted is not None:
+                i32 = dtypes.int32
+                routed = (ltorch.full((), N * self.k, dtype=i32, device=xf.device) if live is None
+                          else ltorch.sum(ltorch.to(live, dtype=i32)) * self.k)
+                counted.append(ltorch.stack([
+                    ltorch.to(routed, dtype=i32),
+                    ltorch.sum(ltorch.to(here, dtype=i32)),
+                    ltorch.sum(ltorch.to(ltorch.gt(counts, 0), dtype=i32)),
+                    ltorch.amax(counts)], 0))
+            out = ltorch.to(out, dtype=x.dtype)
+        if self.shared_width:
+            with named_scope("shared_expert"):
+                out = out + self.shared_down(ltorch.silu(self.shared_gate(xf)) * self.shared_up(xf))
+        return ltorch.reshape(out, (B, T, D))
+
+
 def publish_moe_stats(model: nn.Module, **attrs) -> int:
     """Publish every MoEMLP's routing-health buffers (refreshed by the last
     traced step while observability was enabled) to the ``moe.*`` telemetry
@@ -168,7 +317,11 @@ class MoEBlock(nn.Module):
         self.moe = MoEMLP(moe_cfg, dtype)
 
     def forward(self, x, cos, sin):
-        x = x + self.attn(self.norm_1(x), cos, sin)
+        return self.tail(x, self.attn(self.norm_1(x), cos, sin))
+
+    def tail(self, x, h):
+        """As ``litgpt.Block.tail``; an expert block is always sequential."""
+        x = x + h
         return x + self.moe(self.norm_2(x))
 
 

@@ -1016,6 +1016,89 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, q_pos, scale=None, wi
     return prims.matmul(probs, v)  # (B, H, T, Dv)
 
 
+@torchsymbol(name="paged_latent_attention", id="thunder.paged_latent_attention")
+def paged_latent_attention(q, pool, page_table, q_pos, scale, v_width):
+    """Attention against a paged LATENT pool (multi-head latent attention in
+    its absorbed form, arXiv:2405.04434): every head's keys are the cached row
+    itself and its values the row's first ``v_width`` columns, so a layer has
+    ONE pool with no head axis and a row is read once for all heads.
+
+    q            (B, H, T, W)  — the queries in the row's coordinates: a head's
+                 no-rope query carried into the latent by the key half of the
+                 up-projection, its rope query, zeros over the row's padding
+    pool         (P, page_size, W) — the layer's rows: the normed latent, the
+                 roped shared key, padding to whole 128-lane groups
+    page_table   (B, n_pages_max) int — per-sequence page ids, null page 0
+                 past the sequence's pages
+    q_pos        (B, T) int    — each query's absolute position; it sees the
+                 rows at positions <= its own (its own already written)
+    v_width      int           — columns of a row that are values
+
+    Returns (B, H, T, v_width), still in the latent: the caller carries it out
+    through the value half of the up-projection. One symbol for the decode step
+    (T = 1), the chunk and the verify step. The decomposition below is the
+    pure-jax gather path; on the chip the pallas executor claims T = 1 with a
+    kernel that walks each sequence's live pages
+    (executors/pallasex.py: paged_latent_decode)."""
+    B, H, T, W = q.shape
+    P, ps, Wp = pool.shape
+    check(W == Wp, lambda: f"paged_latent_attention: queries {W} wide against rows {Wp} wide")
+    check(tuple(q_pos.shape) == (B, T),
+          lambda: f"paged_latent_attention: q_pos {q_pos.shape} must be (B, T)=({B}, {T})")
+    npm = page_table.shape[1]
+    S = npm * ps
+    v_width = pyval(v_width)
+    rows = reshape(clang.take(pool, reshape(page_table, (B * npm,)), 0), (B, 1, S, W))
+    scores = clang.mul(prims.matmul(q, clang.matrix_transpose(rows)), pyval(scale))  # (B, H, T, S)
+    k_pos = reshape(prims.iota(S, dtype=dtypes.int32, device=q.device), (1, 1, 1, S))
+    scores = clang.where(clang.le(k_pos, reshape(q_pos, (B, 1, T, 1))), scores, float("-inf"))
+    probs = clang.maybe_convert_to_dtype(softmax(scores, -1), pool.dtype)
+    return prims.matmul(probs, rows[..., :v_width])  # (B, H, T, v_width)
+
+
+@torchsymbol(name="ragged_mlp", id="thunder.ragged_mlp")
+def ragged_mlp(rows, w_gate, w_up, w_down, group_sizes, tile):
+    """SwiGLU expert MLP over rows SORTED BY EXPERT in ragged groups: no
+    capacity, nothing dropped, an expert without rows costs nothing.
+
+    rows         (R, D)    — expert e's ``group_sizes[e]`` rows lie together,
+                 starting at the ``tile``-aligned offset
+                 ``sum(ceil(group_sizes[:e] / tile)) * tile``; every other row
+                 is padding and MUST be zero (SwiGLU keeps it zero)
+    w_gate/w_up  (E, D, H), w_down (E, H, D) — the held experts' panels
+    group_sizes  (E,) int  — rows of each held expert
+    tile         int       — the alignment: a run of ``tile`` rows is one
+                 expert's, which is what lets a kernel stream one expert's
+                 panels a row tile; R is a multiple of it
+
+    Returns (R, D): each row through its own expert, padding rows zero. The
+    decomposition multiplies every row by every expert with the other
+    experts' rows blanked (CPU and shapes the kernel declines); on the chip
+    the pallas executor claims it with a kernel tiled over the hidden
+    dimension that reads only the panels of experts that have rows
+    (executors/pallasex.py: ragged_mlp_fused)."""
+    check(rows.ndim == 2 and w_gate.ndim == 3, lambda: "ragged_mlp: rows (R, D), panels (E, D, H)")
+    R, D = rows.shape
+    E, _, H = w_gate.shape
+    tile = pyval(tile)
+    check(R % tile == 0, lambda: f"ragged_mlp: {R} rows are no multiple of the tile {tile}")
+    check(tuple(w_gate.shape) == (E, D, H) and tuple(w_up.shape) == (E, D, H)
+          and tuple(w_down.shape) == (E, H, D) and tuple(group_sizes.shape) == (E,),
+          lambda: f"ragged_mlp: panels {w_gate.shape}, {w_up.shape}, {w_down.shape} and sizes "
+                  f"{group_sizes.shape} do not belong together")
+    sizes = clang.maybe_convert_to_dtype(group_sizes, dtypes.int32)
+    padded = floor_divide(sizes + (tile - 1), tile) * tile
+    ends = cumsum(padded, 0)
+    starts = ends - padded
+    r = reshape(prims.iota(R, dtype=dtypes.int32, device=rows.device), (1, R))
+    own = logical_and(clang.ge(r, reshape(starts, (E, 1))),
+                      clang.lt(r, reshape(starts + sizes, (E, 1))))          # (E, R)
+    xe = clang.where(unsqueeze(own, -1), unsqueeze(rows, 0), 0.0)             # (E, R, D)
+    xe = clang.maybe_convert_to_dtype(xe, rows.dtype)
+    h = silu(prims.matmul(xe, w_gate)) * prims.matmul(xe, w_up)
+    return sum(prims.matmul(h, w_down), 0)
+
+
 @torchsymbol(name="causal_conv1d", id="thunder.causal_conv1d")
 def causal_conv1d(x, weight, bias, tail):
     """Depthwise causal convolution along time with a carried tail.

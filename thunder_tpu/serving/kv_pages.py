@@ -63,6 +63,25 @@ class PagedKV:
 
 
 @dataclass(frozen=True)
+class PagedLatent:
+    """The layer owns ONE pool ``(n_pages, page_size, row)`` with no head axis:
+    a token's row is what latent attention caches for it (``width`` numbers:
+    the normed latent and the roped shared key; values are a slice of the same
+    row), padded with zeros to whole 128-lane groups. Its pages are those of
+    every position, handed out by the same allocator as a ``PagedKV`` without
+    a window."""
+
+    width: int
+
+    kind = "full"
+    window = None
+
+    @property
+    def row(self) -> int:
+        return -(-self.width // 128) * 128
+
+
+@dataclass(frozen=True)
 class ReadsKV:
     """The layer caches nothing and reads the pools of layer ``of``."""
 
@@ -313,10 +332,11 @@ class PagedKVCache:
     """The cached state of every layer plus the allocators that parcel the
     page pools out.
 
-    ``layers`` holds one declaration a layer (``PagedKV``, ``ReadsKV``,
-    ``Recurrent`` or ``None``); ``state`` holds one tuple of device arrays a
-    layer: ``(k pool, v pool)``, ``()`` for a layer that reads another's pools
-    or caches nothing, the per-slot arrays of a recurrent layer. Pools of
+    ``layers`` holds one declaration a layer (``PagedKV``, ``PagedLatent``,
+    ``ReadsKV``, ``Recurrent`` or ``None``); ``state`` holds one tuple of device
+    arrays a layer: ``(k pool, v pool)``, ``(latent pool,)``, ``()`` for a layer
+    that reads another's pools or caches nothing, the per-slot arrays of a
+    recurrent layer. Pools of
     layers without a window are indexed by the pages of ``allocator``, pools
     of window layers by those of ``window_allocator``: one allocation covers
     all layers of a kind. A pool that several layers read exists once, in the
@@ -369,6 +389,8 @@ class PagedKVCache:
             n = self.n_window_pages if decl.window else self.n_pages
             return (jnp.zeros((n, decl.heads, self.page_size, decl.k_dim), self.dtype),
                     jnp.zeros((n, decl.heads, self.page_size, decl.v_dim), self.dtype))
+        if isinstance(decl, PagedLatent):
+            return (jnp.zeros((self.n_pages, self.page_size, decl.row), self.dtype),)
         if isinstance(decl, Recurrent):
             return tuple(jnp.zeros((self.max_batch, *shape), decl.dtype) for shape in decl.shapes)
         return ()
@@ -415,7 +437,7 @@ class PagedKVCache:
         return sum(d.bytes_per_slot() for d in self.layers if isinstance(d, Recurrent))
 
     def copy_page(self, src: int, dst: int) -> None:
-        """Device-copy one page's K/V across every layer without a window (the
+        """Device-copy one page's rows across every layer without a window (the
         copy-on-write body after `PageAllocator.fork`). One cached jax.jit
         program — src and dst ride as traced scalars, so CoW never recompiles;
         the state is donated, so one fork copies one page and not the whole
@@ -423,7 +445,7 @@ class PagedKVCache:
         import jax
 
         if self._copy_cfn is None:
-            full = [isinstance(d, PagedKV) and not d.window for d in self.layers]
+            full = [isinstance(d, (PagedKV, PagedLatent)) and not d.window for d in self.layers]
 
             def _copy(state, s, d):
                 return tuple(tuple(a.at[d].set(a[s]) for a in arrs) if is_full else arrs

@@ -36,8 +36,8 @@ keys, values and recurrent state in place and copies no pool.
 
 The programs know nothing of what a layer is. A served model is a list of
 LAYERS, each of which says what it caches (``kv_pages.PagedKV`` with or
-without a window, ``ReadsKV`` of another layer's pools, ``Recurrent`` per-slot
-arrays, or ``None``) and gives its work for a whole prompt (``prefill``), a
+without a window, ``PagedLatent`` rows with no head axis, ``ReadsKV`` of
+another layer's pools, ``Recurrent`` per-slot arrays, or ``None``) and gives its work for a whole prompt (``prefill``), a
 chunk that starts from stored state (``chunk``), one decode token
 (``decode``) and, where speculative decoding can use it, ``verify``. The
 runner hands each layer the ``Step`` (where the program's tokens are: pages,
@@ -52,10 +52,10 @@ import functools
 import math
 from typing import Optional
 
-from ..inference import block_mix, cached_sdpa, split_qkv_rope
+from ..inference import cached_sdpa, split_qkv_rope
 from ..observability import runtime as _obs_runtime
 from ..ops import clang, ltorch
-from .kv_pages import PagedKV
+from .kv_pages import PagedKV, PagedLatent
 
 
 def _annotated(cfn, name: str):
@@ -76,6 +76,13 @@ def _annotated(cfn, name: str):
 
 
 LANES = 128  # a TPU vector register's lanes: what a pool row should fill
+
+# What a decode program whose layers route tokens to experts counts of one step, summed over
+# its layers (``serve.<name>`` on the bus): rows routed (live tokens x experts a token), those
+# on experts held here, held experts with a row, the most rows on one held expert. A layer
+# leaves its four in ``step.shared["counted"]`` when it is traced with the bus on, the program
+# then has one small output more, and the scheduler records it as the step's tokens land.
+ROUTING_COUNTERS = ("moe.rows_routed", "moe.rows_held", "moe.experts_touched", "moe.rows_max")
 
 
 def heads_a_row(n_kv_heads: int, head_size: int) -> int:
@@ -241,7 +248,7 @@ class DenseBlock:
     """One block of a dense rope GPT (models/litgpt.py Block, models/moe.py
     MoEBlock) as a served layer: paged keys and values of every position.
     The q/k/v split with rope and the residual/MLP tail are shared with the
-    dense engine (inference.split_qkv_rope / inference.block_mix) — one
+    dense engine (inference.split_qkv_rope / the block's own ``tail``) — one
     implementation, so solo and batched decode can never drift. Heads
     narrower than the lanes are cached ``pack`` a row (``heads_a_row``) and
     the queries of the paged programs spread to match: a pool of
@@ -282,7 +289,7 @@ class DenseBlock:
         cfg = self.cfg
         y = ltorch.reshape(ltorch.permute(y, (0, 2, 1, 3)),
                            (x.shape[0], T, cfg.n_head * cfg.head_size))
-        return block_mix(self.block, cfg, x, self.block.attn.proj(y))
+        return self.block.tail(x, self.block.attn.proj(y))
 
     def prefill(self, step, x, state):
         """Dense causal attention over the padded prompt and page write-out
@@ -401,7 +408,7 @@ class PagedGPTRunner:
         # a model that is no dense GPT says how it is served; a GPT is its blocks
         self.model = gpt.serving() if hasattr(gpt, "serving") else DenseGPT(gpt)
         self.page_kinds = tuple(k for k in ("full", "window") if any(
-            isinstance(layer.cache, PagedKV) and layer.cache.kind == k
+            isinstance(layer.cache, (PagedKV, PagedLatent)) and layer.cache.kind == k
             for layer in self.model.layers))
 
         def prefill(params, idx, page_ids, state, last_pos, slot):
@@ -466,14 +473,23 @@ class PagedGPTRunner:
         """toks (Bcap, 1) current tokens; tables: for each page kind the
         (Bcap, n_pages_max) int32 page table; pos (Bcap,) int32 — each
         sequence's write position (= tokens already cached; idle slots carry
-        pos 0 and a null-page row). Returns (logits (Bcap, V), new state)."""
+        pos 0 and a null-page row). Returns (logits (Bcap, V), new state), and
+        after them the (4,) int32 ``ROUTING_COUNTERS`` of the step where the
+        layers counted them (bus on at trace time)."""
         step = Step("decode", self.page_size, tables=self._by_kind(tables), pos=pos)
         self.model.begin(step)
         step.page_of, step.slot_in_page = _token_pages(step.tables, pos, self.page_size)
         step.seq_lens = pos + 1  # attention covers the token being written
         step.live = ltorch.gt(pos, 0)
         x, state = self._run_layers(step, self.model.embed(toks), state)
-        return self.model.head(x[:, -1]), state
+        logits = self.model.head(x[:, -1])
+        counted = step.shared.get("counted")
+        if not counted:
+            return logits, state
+        total = counted[0]
+        for c in counted[1:]:
+            total = total + c
+        return logits, state, total
 
     # -- chunked prefill --------------------------------------------------
     def _forward_chunk(self, idx, table_rows, state, start_pos, last_rel, slot):

@@ -85,8 +85,8 @@ from ..observability import runtime as _obs_runtime
 from ..observability import telemetry as _obs_tel
 from ..observability import tracing as _obs_trace
 from ..observability.slo import SLOMonitor, SLOPolicy
-from .kv_pages import PagedKV, PagedKVCache, PrefixCache, Recurrent
-from .runner import PagedGPTRunner, quantize_for_serving
+from .kv_pages import PagedKV, PagedKVCache, PagedLatent, PrefixCache, Recurrent
+from .runner import ROUTING_COUNTERS, PagedGPTRunner, quantize_for_serving
 
 _NULL = contextlib.nullcontext()
 
@@ -106,6 +106,10 @@ class RequestResult:
     # SLOPolicy attached (the goodput numerator); None without a policy
     slo_met: Optional[bool] = None
     queue_s: float = 0.0        # submit -> admitted (pages reserved, slot taken)
+    # the pages of every position the sequence held when it retired, in position order. They
+    # are back in the pool; their rows stay as written until another sequence takes them, which
+    # is how a test reads what the engine cached for a request it served alone
+    pages: tuple = ()
 
 
 @dataclass
@@ -149,6 +153,9 @@ class _Step:
     nxt: jax.Array               # (max_batch, 1) int32 on the device: the next step's tokens
     reqs: Dict[int, _Request]    # slot -> the sequence that was in the step
     t0: float                    # when the pass that dispatched it began its decode
+    # what the program counted of its own work (ROUTING_COUNTERS, int32 on the device), where
+    # it was traced with the bus on and its layers route; it lands with the tokens
+    counted: Optional[jax.Array] = None
 
 
 def _sample_tokens(logits, seeds, pos, temps):
@@ -271,6 +278,7 @@ class ServingEngine:
         # the cached state follows from what the model's layers declare
         layers = [layer.cache for layer in self.runner.model.layers]
         self.recurrent = any(isinstance(d, Recurrent) for d in layers)
+        self.latent = any(isinstance(d, PagedLatent) for d in layers)
         # state that is not pages of every position: say so rather than run wrongly
         if self.recurrent:
             if prefix_sharing:
@@ -1316,13 +1324,15 @@ class ServingEngine:
                 toks = prev.nxt if prev is not None else _upload(self._toks[:, None])
                 pos = _upload(self._pos)
             with phase("engine:dispatch"):
-                logits, state = self.runner.decode_cfn(
+                logits, state, *counted = self.runner.decode_cfn(
                     self.params, toks, self.cache.state, self._pt_dev, pos)
                 self.cache.rebind(state)
                 # the NEXT token's position is pos+1 (this step wrote pos)
                 nxt = self._step_sampler(logits, self._seeds_dev, pos, self._temps_dev)
                 # the host's copy starts as the sampler ends, not when the fetch asks
                 nxt.copy_to_host_async()
+                for c in counted:
+                    c.copy_to_host_async()
         except Exception as e:
             # the packed step failed: every live sequence is implicated —
             # fail their futures and return their pages rather than hanging
@@ -1340,7 +1350,7 @@ class ServingEngine:
             self._record_state(len(live))
             self._record_paged_pages()
         self._pos[live] += 1
-        return _Step(nxt, {i: self._slots[i] for i in live}, t0)
+        return _Step(nxt, {i: self._slots[i] for i in live}, t0, *counted)
 
     def _fetch(self, step: Optional[_Step]) -> Optional[np.ndarray]:
         """The sampled tokens of ``step`` on the host, (max_batch,). None where
@@ -1373,6 +1383,9 @@ class ServingEngine:
             if _obs.enabled():
                 dur_ms = (t_now - step.t0) * 1e3
                 _obs_metrics.record_serve("tokens", delta=len(kept))
+                if step.counted is not None:  # computed before the tokens that were just fetched
+                    for name, n in zip(ROUTING_COUNTERS, np.asarray(step.counted)):
+                        _obs_metrics.record_serve(name, delta=int(n))
                 if len(kept) < len(step.reqs):
                     _obs_metrics.record_serve("decode_discarded",
                                               delta=len(step.reqs) - len(kept))
@@ -1399,10 +1412,13 @@ class ServingEngine:
 
     def _record_state(self, active: int) -> None:
         """What the cached state of this decode step's sequences took, summed
-        step by step (bus on): pages of the pools without a window, pages of
-        the window pools, bytes of recurrent state. Over ``serve.tokens`` (the
+        step by step (bus on): pages of the pools without a window (again as
+        ``state.latent_pages`` where layers cache latent rows), pages of the
+        window pools, bytes of recurrent state. Over ``serve.tokens`` (the
         sequences, summed the same way) they give the state a sequence holds."""
         _obs_metrics.record_serve("state.shared_kv_pages", delta=self.cache.allocator.n_used)
+        if self.latent:
+            _obs_metrics.record_serve("state.latent_pages", delta=self.cache.allocator.n_used)
         if self.window:
             _obs_metrics.record_serve("state.window_pages",
                                       delta=self.cache.window_allocator.n_used)
@@ -1419,7 +1435,7 @@ class ServingEngine:
         lens = self._pos.astype(np.int64) + 1
         ends = -(-lens // ps)
         live = spanned = 0
-        if any(isinstance(d, PagedKV) and not d.window for d in self.cache.layers):
+        if any(isinstance(d, (PagedKV, PagedLatent)) and not d.window for d in self.cache.layers):
             live += int(ends.sum())
             spanned += slots * self.n_pages_max
         if self.window:
@@ -1541,6 +1557,7 @@ class ServingEngine:
         return len(req.tokens) >= req.max_new_tokens
 
     def _retire(self, req: _Request) -> None:
+        held = tuple(req.pages)
         self._free_pages(req)
         n_new = len(req.tokens)
         # t_first == 0.0 only for a prefix-hit request cancelled before its
@@ -1611,6 +1628,7 @@ class ServingEngine:
             finish_reason=reason,
             slo_met=slo_met,
             queue_s=req.t_admit - req.t_submit,
+            pages=held,
         )
         try:
             # a cancel() from the caller thread can land at ANY point, so a
