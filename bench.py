@@ -966,8 +966,11 @@ def _longctx_rows() -> list[dict]:
     observability.disable()
     engine.stop()
     g = scfg.n_head // scfg.n_query_groups
-    chunk_est = budget.paged_chunk_vmem_bytes(16, scfg.n_embd // scfg.n_head,
-                                              g, chunk, 4, 4)
+    head = scfg.n_embd // scfg.n_head
+    q_tile, heads, pps = budget.paged_chunk_blocks(16, head, g, chunk, 4, 4,
+                                                   n_kv_heads=scfg.n_query_groups)
+    chunk_est = budget.paged_chunk_vmem_bytes(16, head, g, q_tile or chunk, 4, 4,
+                                              heads=max(heads, 1), pages_per_step=max(pps, 1))
     rows.append({
         "platform": jax.devices()[0].platform,
         "metric": (f"{T}-context paged serve: {prompt_len}-token prompt, "

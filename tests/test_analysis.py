@@ -465,8 +465,22 @@ class TestBudgetAPI:
         # the decline decision: a page of which not even one fits the budget
         assert budget.paged_pages_per_step(2048, 512, 64, 4, 4, n_kv_heads=8) == 0
         assert budget.paged_pages_per_step(16, 128, 4, 2, 2, n_kv_heads=2) == 8
-        # the chunk kernel keeps one page of one KV head a grid program
-        assert budget.paged_chunk_vmem_bytes(16, 64, 4, 1, 2, 2) == 2 * 2 * 16 * 64 * 2 + 4 * 64 * 2 * 2 + 4 * 64 * 4 + 32
+        # the chunk kernel's working set (PR 32: a tile of the queries against whole pages): two
+        # buffers of a step's pages of K and of V for the program's heads, the q, output and position
+        # blocks twice, the f32 accumulator with its two lane-padded columns, four of a step's scores
+        assert budget.paged_chunk_vmem_bytes(16, 64, 4, 1, 2, 2, heads=1, pages_per_step=1) == (
+            2 * 16 * (64 + 64) * 2 + 2 * 4 * (64 + 64) * 2 + 2 * 4 * 128 * 4 + 4 * (64 + 256) * 4 + 4 * 4 * 16 * 4)
+        # checker parity: the tile, the heads a program and the pages a step are the budget's
+        for ps, D, Dv, Hkv, g, T in ((64, 128, 128, 8, 4, 512), (64, 128, 256, 10, 4, 512),
+                                     (64, 128, 128, 8, 4, 5), (4096, 512, 512, 8, 8, 512)):
+            blocks = pallasex._paged_chunk_blocks(Hkv * g, T, D, 2, _P((9, Hkv, ps, D)), _P((9, Hkv, ps, Dv)))
+            assert blocks == budget.paged_chunk_blocks(ps, D, g, T, 2, 2, Dv=Dv, n_kv_heads=Hkv)
+            q_tile, heads, pps = blocks
+            if pps:
+                assert T % q_tile == 0 and Hkv % heads == 0
+                assert budget.within_vmem(budget.paged_chunk_vmem_bytes(
+                    ps, D, g, q_tile, 2, 2, heads=heads, pages_per_step=pps, Dv=Dv), budget.paged_vmem_limit())
+        assert blocks == (0, 0, 0)  # a page of which not one fits beside a tile of the queries
 
     def test_paged_decode_declines_a_page_that_does_not_fit(self, monkeypatch, pallas_claims):
         """`pallas.decline.paged_attention.vmem`: not even one page a step fits."""

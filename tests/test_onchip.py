@@ -50,23 +50,26 @@ def _as_served(q, k_pages, v_pages):
     """Heads narrower than the 128 lanes as the engine caches them
     (serving/runner.py: heads_a_row): ``r`` neighbouring KV heads side by side
     a pool row, each query over the lanes of its own key head and zeros over
-    the others'. Returns q, the pools and what takes a query head's own value
-    lanes out of the output; at a head of 128 all four are what they were."""
+    the others'. q is (B, H, D), or (B, H, T, D) with T queries a sequence.
+    Returns q, the pools and what takes a query head's own value lanes out of
+    the output; at a head of 128 all four are what they were."""
     from thunder_tpu.serving.runner import heads_a_row
 
-    (B, H, D), (P, Hkv, ps, _) = q.shape, k_pages.shape
+    (B, H, *mid, D), (P, Hkv, ps, _) = q.shape, k_pages.shape
     r, g = heads_a_row(Hkv, D), H // Hkv
+    one = (None,) * len(mid)
 
     def pack(pages):
         rows = pages.reshape(P, Hkv // r, r, ps, -1).transpose(0, 1, 3, 2, 4)
         return rows.reshape(P, Hkv // r, ps, -1)
 
     def own(out):
-        out = out.reshape(B, Hkv // r, r, g, r, -1)
-        return jnp.stack([out[:, :, j, :, j] for j in range(r)], 2).reshape(B, H, -1)
+        out = out.reshape(B, Hkv // r, r, g, *mid, r, -1)
+        return jnp.stack([out[:, :, j, ..., j, :] for j in range(r)], 2).reshape(B, H, *mid, -1)
 
-    spread = q.reshape(B, Hkv // r, r, g, 1, D) * jnp.eye(r, dtype=q.dtype)[:, None, :, None]
-    return spread.reshape(B, H, r * D), pack(k_pages), pack(v_pages), own
+    spread = (q.reshape(B, Hkv // r, r, g, *mid, 1, D)
+              * jnp.eye(r, dtype=q.dtype)[(slice(None), None) + one + (slice(None), None)])
+    return spread.reshape(B, H, *mid, r * D), pack(k_pages), pack(v_pages), own
 
 
 @pytest.mark.parametrize("window", [None, 512], ids=["plain", "window"])
@@ -127,25 +130,68 @@ def test_paged_decode_takes_no_pool_narrower_than_the_lanes():
         pallasex.paged_attention_decode(q, k_pages, v_pages, pt, lens)
 
 
-@pytest.mark.parametrize("H,Hkv,D,ps,T", [(16, 16, 64, 64, 512), (16, 16, 64, 64, 5),
-                                          (32, 8, 128, 16, 128)])
-def test_paged_chunk_kernel_on_chip(H, Hkv, D, ps, T):
-    """Chunked prefill (B=1, T chunk tokens) and speculative verify (T=k+1)
-    rows against the pool, per-query causal coverage."""
+_RAGGED = tuple(int(n) for n in np.random.RandomState(1).randint(150, 900, 48))
+CHUNK_CASES = {  # H, Hkv, D, Dv, ps, table width, T, each sequence's first position, window
+    "longprompt": (32, 8, 128, 128, 64, 128, 512, (0, 2048, 6656), None),
+    "longprompt_final_rung": (32, 8, 128, 128, 64, 128, 128, (2048, 6656), None),
+    "reasoning_shared_pool": (40, 10, 128, 256, 64, 48, 512, (0, 1024, 2560), None),
+    "reasoning_window": (40, 10, 128, 256, 64, 48, 512, (0, 1024, 2560), 512),
+    "verify_b48_t5": (32, 8, 128, 128, 64, 32, 5, _RAGGED, None),
+    "head_64_as_served": (16, 16, 64, 64, 64, 32, 512, (0, 512, 1024), None),
+    "head_64_as_served_verify": (16, 16, 64, 64, 64, 32, 5, (0, 1024), None),
+    "pages_of_16": (32, 8, 128, 128, 16, 128, 128, (512, 1024), None),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_paged_chunk_kernel_on_chip(case):
+    """Chunked prefill (T chunk tokens a sequence) and speculative verify
+    (T = k + 1) against the pool, per-query causal coverage, at the shapes of
+    the callers: the long-prompt cell's (chunks at 0, 2,048 and 6,656 of a
+    table 128 wide), the reasoning model's shared pool (values twice as wide)
+    and window layers, a verify step of 48 sequences, heads of 64 two a row as
+    the engine caches them. Every page past a sequence's last position or
+    below its first query's window is full of infinities."""
+    from thunder_tpu.executors import pallasex
+
+    H, Hkv, D, Dv, ps, npm, T, starts, window = CHUNK_CASES[case]
+    rng = np.random.RandomState(0)
+    B = len(starts)
+    k_pages, v_pages, pt, k, v = _paged_case(rng, B, H, Hkv, D, ps, npm, Dv)
+    pt = np.asarray(pt)
+    dead = np.ones(k_pages.shape[0], bool)
+    for b, start in enumerate(starts):
+        dead[pt[b, (max(start - window + 1, 0) // ps if window else 0):-(-(start + T) // ps)]] = False
+    k_pages, v_pages = k_pages.at[dead].set(jnp.inf), v_pages.at[dead].set(jnp.inf)
+    q_pos = jnp.asarray(np.asarray(starts)[:, None] + np.arange(T)[None, :], jnp.int32)
+    q = jnp.asarray(rng.randn(B, H, T, D), jnp.bfloat16)
+    *served, own = _as_served(q, k_pages, v_pages)
+    served += [jnp.asarray(pt), q_pos, 1 / math.sqrt(D), window]
+    assert pallasex.paged_chunk_attention_supported(*served)
+    out = own(pallasex.paged_chunk_decode(*served))
+    g = H // Hkv
+    pos = jnp.arange(npm * ps)[None, None, :]
+    mask = pos <= q_pos[:, :, None]
+    if window:
+        mask &= pos > q_pos[:, :, None] - window
+    ref = _attention_ref(q, jnp.repeat(k, g, 1), jnp.repeat(v, g, 1), mask[:, None])
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=2e-2, rtol=2e-2)
+
+
+def test_paged_chunk_takes_no_pool_narrower_than_the_lanes():
+    """As the decode kernel: a 64-wide pool is declined, and a direct call
+    refused by name and not by the compiler."""
     from thunder_tpu.executors import pallasex
 
     rng = np.random.RandomState(0)
-    B, npm = 2, 2048 // ps
-    k_pages, v_pages, pt, k, v = _paged_case(rng, B, H, Hkv, D, ps, npm)
-    start = jnp.asarray([[512], [1024]], jnp.int32)
-    q_pos = start + jnp.arange(T, dtype=jnp.int32)[None, :]
-    q = jnp.asarray(rng.randn(B, H, T, D), jnp.bfloat16)
-    out = pallasex.paged_chunk_decode(q, k_pages, v_pages, pt, q_pos)
-    g = H // Hkv
-    mask = (jnp.arange(npm * ps)[None, None, :] <= q_pos[:, :, None])[:, None]
-    ref = _attention_ref(q, jnp.repeat(k, g, 1), jnp.repeat(v, g, 1), mask)
-    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
-                               atol=2e-2, rtol=2e-2)
+    k_pages, v_pages, pt, _, _ = _paged_case(rng, 2, 6, 3, 64, 64, 4)
+    q = jnp.asarray(rng.randn(2, 6, 16, 64), jnp.bfloat16)
+    q_pos = jnp.asarray(np.asarray([[5], [200]]) + np.arange(16)[None], jnp.int32)
+    assert not pallasex.paged_chunk_attention_supported(q, k_pages, v_pages, pt, q_pos)
+    with pytest.raises(ValueError, match="128 lanes"):
+        pallasex.paged_chunk_decode(q, k_pages, v_pages, pt, q_pos)
 
 
 def test_grouped_expert_mlp_kernel_on_chip():

@@ -723,6 +723,60 @@ def test_paged_pages_counters_follow_the_lengths(gpt, rng):
         "serve.paged.pages_live", "serve.paged.pages_spanned"]
 
 
+def _paged_counters(run):
+    """The `serve.paged.*` counters of what ``run`` serves with the bus on."""
+    from thunder_tpu import observability
+
+    observability.enable()
+    observability.reset()
+    try:
+        run()
+        counters = observability.counters()
+    finally:
+        observability.disable()
+    return {n[len("serve.paged."):]: v for n, v in counters.items() if n.startswith("serve.paged.")}, counters
+
+
+def test_chunk_pages_counters_follow_the_chunks(gpt, rng):
+    """`serve.paged.chunk_pages_live`: the pages a chunk's queries can see, once a chunk dispatch;
+    `serve.paged.chunk_pages_spanned`: the table's width, what a grid of one program a table entry
+    stepped over. Beside the decode step's pair, which the chunks leave alone."""
+    engine = _engine(gpt, chunk_tokens=16, prefill_budget=16)  # pages of 8, a table 8 wide
+
+    def run():
+        for L in (48, 23):  # chunks at 0, 16, 32; then at 0 and a final one of 7 tokens on a rung of 8
+            fut = engine.submit(rng.randint(0, gpt.cfg.vocab_size, (L,)).astype(np.int32), max_new_tokens=3)
+            engine.drain()
+            assert fut.result().n_new_tokens == 3
+
+    paged, counters = _paged_counters(run)
+    assert counters["serve.prefill_tokens"] == 48 + 23
+    ends = [-(-(start + cb) // 8) for start, cb in ((0, 16), (16, 16), (32, 16), (0, 16), (16, 8))]
+    assert paged["chunk_pages_live"] == sum(ends) == 17
+    assert paged["chunk_pages_spanned"] == len(ends) * 8
+    steps = counters["serve.decode_steps"]
+    assert steps == 4 and paged["pages_spanned"] == steps * 4 * 8
+    assert sorted(paged) == ["chunk_pages_live", "chunk_pages_spanned", "pages_live", "pages_spanned"]
+
+
+def test_chunk_pages_counters_count_every_slot_of_a_verify_step(gpt, rng):
+    """A verify dispatch is the packed program: every slot's k + 1 queries, an idle slot's on the
+    null page."""
+    engine = _engine(gpt, draft_gpt=gpt, spec_k=2)
+
+    def run():
+        fut = engine.submit(rng.randint(0, gpt.cfg.vocab_size, (9,)).astype(np.int32), max_new_tokens=7)
+        engine.drain()
+        assert fut.result().n_new_tokens == 7
+
+    paged, counters = _paged_counters(run)
+    steps = counters["serve.decode_steps"]
+    assert steps == 3 and engine.spec_accepted == engine.spec_proposed == 6
+    # a perfect draft: the sequence's three queries start at 9, 11, 13; three idle slots at 0
+    assert paged["chunk_pages_live"] == sum(-(-(pos + 3) // 8) + 3 for pos in (9, 11, 13)) == 15
+    assert paged["chunk_pages_spanned"] == steps * 4 * 8
+
+
 # ---------------------------------------------------------------------------
 # heads narrower than the lanes: cached several a row
 # ---------------------------------------------------------------------------

@@ -456,3 +456,24 @@ def test_paged_pages_counters_sum_the_window_pools_and_the_shared_one(model):
     assert counters["serve.paged.pages_spanned"] == w_spanned + steps * 2 * width
     assert 0.5 < w_live / w_spanned <= 1.0 and live / (steps * 2 * width) < 0.3
     assert not [name for name in counters if name.startswith("serve.paged.window")]
+
+
+def test_chunk_pages_counters_sum_the_window_pools_and_the_shared_one(model):
+    """A chunk's queries see the shared pool from its first page and a window pool from the page of
+    the first query's window on; the old grid stepped over the whole table, or over the span of the
+    chunk's windows. The two counters sum both kinds, once a chunk dispatch."""
+    eng = engine_for(model, max_batch=2)  # chunks of 32, a table 32 wide
+    observability.enable()
+    observability.reset()
+    try:
+        res = serve(eng, prompts([100], seed=3), [2])[0]
+        counters = observability.counters()
+    finally:
+        observability.disable()
+    assert res.n_new_tokens == 2 and counters["serve.prefill_tokens"] == 100
+    chunks = [(0, 32), (32, 32), (64, 32), (96, 16)]  # the last: 4 tokens on the rung of 16
+    ends = [-(-(start + cb) // PAGE) for start, cb in chunks]
+    w_live = sum(e - max(start - WINDOW + 1, 0) // PAGE for e, (start, _) in zip(ends, chunks))
+    w_spanned = sum(-(-(cb + WINDOW - 1) // PAGE) + 1 for _, cb in chunks)
+    assert counters["serve.paged.chunk_pages_live"] == w_live + sum(ends) == 20 + 38
+    assert counters["serve.paged.chunk_pages_spanned"] == w_spanned + len(chunks) * (256 // PAGE) == 26 + 128

@@ -125,18 +125,63 @@ def paged_pages_per_step(page_size: int, D: int, g: int, kv_itemsize: int, q_ite
     return 0
 
 
-def paged_chunk_vmem_bytes(page_size: int, D: int, g: int, T: int,
-                           kv_itemsize: int, q_itemsize: int) -> int:
-    """VMEM working set of the multi-query paged-attention kernel
-    (pallasex `_paged_chunk_kernel`), one page of one KV head a grid program:
-    double-buffered k/v page blocks, the q block of g*T rows (T chunk/verify
-    tokens per kv-head group), and the f32 accumulator/output tiles."""
-    rows = g * T
-    kv = 2 * (2 * page_size * D * kv_itemsize)  # k + v, double-buffered DMA
-    qb = rows * D * q_itemsize
-    acc = rows * D * 4 + 2 * rows * 4  # f32 acc + m/l scratch
-    out = rows * D * q_itemsize
-    return kv + qb + acc + out
+# query rows of one KV head (g x the tile) and keys of one loop step the chunk
+# kernel multiplies at once. What a step costs beside its scores (the running
+# maximum, the rescale of the accumulator: columns of f32, a vreg for 8 rows)
+# goes with the rows, so the keys amortise it: 1,024 a step ran 1.25 times as
+# fast as 512, 2,048 slower again (their f32 scores outgrow what they save),
+# and 512 rows as fast as 1,024 (v5e, PR 32)
+PAGED_CHUNK_MAX_ROWS = 512
+PAGED_CHUNK_KEYS_PER_STEP = 1024
+
+
+def paged_chunk_vmem_bytes(page_size: int, D: int, g: int, q_tile: int,
+                           kv_itemsize: int, q_itemsize: int, *, heads: int,
+                           pages_per_step: int, Dv: Optional[int] = None) -> int:
+    """Estimated per-program VMEM working set of the multi-query paged-attention
+    kernel (pallasex `_paged_chunk_kernel`), which does ``heads`` KV heads of
+    one tile of ``q_tile`` queries: two buffers of ``pages_per_step`` pages
+    (those heads) of K and of V that its own DMAs fill; the q, output and
+    position blocks Mosaic's pipeline double-buffers, ``g * q_tile`` rows a
+    head; the f32 accumulator with its m/l columns (a column of f32 takes a
+    whole 128-lane tile a sublane group); and the f32 scores, probabilities
+    and mask of one head over one step's keys. ``Dv`` is the values' width
+    where it is not the keys'."""
+    Dv = D if Dv is None else Dv
+    rows = g * q_tile
+    kv = 2 * pages_per_step * heads * page_size * (D + Dv) * kv_itemsize
+    qo = 2 * heads * rows * (D + Dv) * q_itemsize + 2 * rows * 128 * 4
+    scratch = heads * rows * (Dv + 2 * 128) * 4
+    scores = 4 * rows * pages_per_step * page_size * 4
+    return kv + qo + scratch + scores
+
+
+def paged_chunk_blocks(page_size: int, D: int, g: int, T: int, kv_itemsize: int, q_itemsize: int,
+                       *, n_kv_heads: int, Dv: Optional[int] = None) -> tuple:
+    """(query tile, KV heads a program, pages a loop step) of the chunk kernel,
+    from the shapes alone: the largest tile of the T queries that keeps a KV
+    head's rows within ``PAGED_CHUNK_MAX_ROWS`` and fits (all of T where that
+    is few, as in a verify step; else a divisor of T that is a multiple of 16,
+    the bf16 sublane tile), then as many pages a step as fit beside it, those of
+    ``PAGED_CHUNK_KEYS_PER_STEP`` keys at most, then as many of the KV heads as
+    still fit (a page then crosses HBM in fewer, larger copies). (0, 0, 0)
+    when not one page a step of one head fits, and the checker then declines."""
+    tiles = [t for t in range(min(T, PAGED_CHUNK_MAX_ROWS // g) // 16 * 16, 0, -16) if T % t == 0]
+    if g * T <= PAGED_CHUNK_MAX_ROWS or not tiles:
+        tiles = [T] + [t for t in tiles if t < T]
+    limit = paged_vmem_limit()
+
+    def fits(q_tile, heads, pps):
+        return within_vmem(paged_chunk_vmem_bytes(page_size, D, g, q_tile, kv_itemsize, q_itemsize,
+                                                  heads=heads, pages_per_step=pps, Dv=Dv), limit)
+
+    for q_tile in tiles:
+        for pps in range(max(PAGED_CHUNK_KEYS_PER_STEP // page_size, 1), 0, -1):
+            if fits(q_tile, 1, pps):
+                heads = max(h for h in range(1, n_kv_heads + 1)
+                            if n_kv_heads % h == 0 and fits(q_tile, h, pps))
+                return q_tile, heads, pps
+    return 0, 0, 0
 
 
 def grouped_mlp_vmem_bytes(block_c: int, D: int, H: int,

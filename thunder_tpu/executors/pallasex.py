@@ -1417,34 +1417,6 @@ def _paged_attn_kernel(*refs, scale: float, window, pages_per_step: int, head_bl
         o_ref[h] = (acc_scr[at] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def _paged_layout(q_rows: int, k_pages, v_pages, page_table, window, span: int):
-    """The chunk kernel's pallas_call layout: the page axis of the grid
-    (B, Hkv, pages), the block specs of q (``q_rows`` rows a kv head), K, V
-    and the output, and the scratch shapes. With a window the grid spans the pages
-    ``span`` positions can touch, from each sequence's first page ``lo``."""
-    ps, D = k_pages.shape[2], k_pages.shape[3]
-    Dv = v_pages.shape[3]
-    npm = page_table.shape[1]
-    n_pages = npm if window is None else min(npm, -(-span // ps) + 1)
-
-    def rows(width):
-        return pl.BlockSpec((None, None, q_rows, width), lambda b, h, p, *_: (b, h, 0, 0))
-
-    if window is None:
-        def kv_index(b, h, p, pt, sl):
-            return (pt[b, p], h, 0, 0)
-    else:
-        def kv_index(b, h, p, pt, sl, lo):
-            return (pt[b, jnp.minimum(lo[b] + p, npm - 1)], h, 0, 0)
-
-    k_spec = pl.BlockSpec((None, None, ps, D), kv_index)
-    v_spec = pl.BlockSpec((None, None, ps, Dv), kv_index)
-    scratch = [pltpu.VMEM((q_rows, Dv), jnp.float32),
-               pltpu.VMEM((q_rows, 1), jnp.float32),
-               pltpu.VMEM((q_rows, 1), jnp.float32)]
-    return n_pages, rows, k_spec, v_spec, scratch
-
-
 def _paged_decode_blocks(q_heads: int, D: int, q_itemsize: int, k_pages, v_pages):
     """(pages a loop step, KV heads a matmul) of the decode kernel for these
     pools: what fits the budget, from the shapes alone. 0 pages: nothing fits."""
@@ -1466,18 +1438,22 @@ _PAGED_REFUSALS = {
 }
 
 
-def _paged_decode_refusal(q_heads: int, D: int, q_itemsize: int, k_pages, v_pages,
-                          compiled: bool):
-    """Why the decode kernel cannot take these pools, or None: ``"lanes"`` when
+def _paged_refusal(D: int, v_pages, pages_per_step: int, compiled: bool):
+    """Why a paged kernel cannot take these pools, or None: ``"lanes"`` when
     it is to be compiled and a pool's rows do not fill the 128 lanes (the
     kernel copies whole pages out of HBM itself, and Mosaic pads a narrower
     pool's rows in HBM and then refuses the slice of one page; the interpreter
-    takes any width), ``"vmem"`` when not one page a loop step fits the budget."""
+    takes any width), ``"vmem"`` when not one page a loop step fits the budget
+    (``pages_per_step`` 0, from the kernel's blocks)."""
     if compiled and (D % 128 or v_pages.shape[3] % 128):
         return "lanes"
-    if _paged_decode_blocks(q_heads, D, q_itemsize, k_pages, v_pages)[0] == 0:
-        return "vmem"
-    return None
+    return None if pages_per_step else "vmem"
+
+
+def _paged_decode_refusal(q_heads: int, D: int, q_itemsize: int, k_pages, v_pages,
+                          compiled: bool):
+    return _paged_refusal(D, v_pages, _paged_decode_blocks(q_heads, D, q_itemsize, k_pages, v_pages)[0],
+                          compiled)
 
 
 def paged_attention_decode(q, k_pages, v_pages, page_table, seq_lens, scale=None, window=None,
@@ -1581,47 +1557,135 @@ ex.register_implementation("thunder.paged_attention", _paged_attention_impl,
 # against the same paged pool: a chunked-prefill chunk (B=1, T=chunk tokens)
 # and the speculative-decoding verify step (T=k+1 proposals per packed
 # sequence), both with PER-QUERY causal coverage k_pos <= q_pos[b, t]. The
-# kernel keeps the grid (B, Hkv, pages) of one page of one KV head a program:
-# the table rides as a scalar-prefetch operand so the k/v BlockSpec index maps
-# resolve page ids before each DMA, the q block is (g*T, D) and the per-query
-# positions ride as a (g*T, 1) VMEM column for the masking.
-# Shared (copy-on-write) page tables are transparent: a physical
-# page shared by N sequences simply appears in N table rows, and partial
-# chunk tables (entries past the written prefix) point at the null page,
-# which the q_pos mask keeps out of the accumulators either way.
+# kernel is the decode kernel's pattern with a tile of queries in the place
+# of one: the grid is (B, groups of KV heads, tiles of the T queries), the
+# pools stay in HBM, and a program walks the pages ITS tile's queries can see
+# (from the window's first page where there is a window, to the page of the
+# tile's last position and no further), `pages_per_step` whole pages a loop
+# step through a double-buffered VMEM scratch that its own copies fill (the
+# next step's, or the next program's first, in flight). A step's scores are
+# one matmul of a KV head's query rows (g x tile) against all of the step's
+# keys, so the maximum, the accumulator's rescale and the mask are paid once a
+# step. The tile, the heads a program and the pages a step follow from the
+# shapes and the VMEM budget (analysis/memory.py: paged_chunk_blocks); the
+# per-query positions ride as a (rows, 1) VMEM column for the masking, and
+# each tile's coverage bound (and first window page) as scalar-prefetch
+# operands beside the table. Shared (copy-on-write) page tables are
+# transparent: a physical page shared by N sequences simply appears in N table
+# rows. Table entries past a tile's last visible page are never read.
 
 
-def _paged_chunk_kernel(*refs, page_size: int, scale: float, window=None):
-    # grid (B, Hkv, pages); q_ref (g*T, D) — T queries per kv head
-    # group, flattened into rows; qp_ref (g*T, 1) carries each row's
-    # absolute position as a VMEM column (a vector cannot index the SMEM
-    # prefetch operands); sl_ref is the per-sequence page coverage bound
-    # (max q_pos + 1) used to skip trailing never-attended pages.
+def _paged_chunk_kernel(*refs, scale: float, window, pages_per_step: int):
+    # grid (B, Hkv // hg, T // Tq), in order. q_ref (hg, G, R, D) and o_ref
+    # (hg, G, R, Dv) hold `rows` = G * R = g * Tq query rows a KV head, row r
+    # query r % Tq of the tile; qp_ref (rows, 1) their absolute positions (a
+    # vector cannot index the SMEM prefetch operands); end_ref and lo_ref hold
+    # a tile's coverage bound (its last position + 1) and first window page at
+    # [sequence * tiles + tile]. k_hbm / v_hbm are the whole pools, left in
+    # HBM; k_buf (2, pps, hg, page_size, D) and v_buf (.., Dv) take the
+    # program's heads of `pps` pages a step; sems (2, 2) is [k or v, buffer];
+    # slot_ref holds the buffer a program's first step is in, which the
+    # program before it began to fill during its own last step.
     if window is None:
-        pt_ref, sl_ref, q_ref, qp_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr = refs
+        pt_ref, end_ref, q_ref, qp_ref, k_hbm, v_hbm, o_ref, *scratch = refs
     else:
-        pt_ref, sl_ref, lo_ref, q_ref, qp_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr = refs
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-    n_p = pl.num_programs(2)
-    page = p if window is None else lo_ref[b] + p
-    gT = q_ref.shape[0]
+        pt_ref, end_ref, lo_ref, q_ref, qp_ref, k_hbm, v_hbm, o_ref, *scratch = refs
+    k_buf, v_buf, sems, slot_ref, acc_scr, m_scr, l_scr = scratch
+    b, hi, qi = (pl.program_id(a) for a in range(3))
+    n_b, n_h, n_q = (pl.num_programs(a) for a in range(3))
+    hg, ps, D = k_buf.shape[2:]
+    Dv = v_buf.shape[4]
+    pps = pages_per_step
+    rows, cols = acc_scr.shape[1], pps * ps
 
-    @pl.when(p == 0)
-    def _init():
-        _paged_init(acc_scr, m_scr, l_scr)
+    def span(seq, tile):
+        """Pages [first, end) hold what the queries of ``tile`` of ``seq`` see."""
+        at = seq * n_q + tile
+        return (0 if window is None else lo_ref[at]), (end_ref[at] + ps - 1) // ps
 
-    @pl.when(page * page_size < sl_ref[b])
-    def _compute():
-        k_pos = page * page_size + jax.lax.broadcasted_iota(jnp.int32, (gT, page_size), 1)
-        live = k_pos <= qp_ref[:]
+    def copy_pages(seq, heads, tile, step, slot, wait: bool):
+        """Start, or wait for, the copies of the pages of loop step ``step``
+        of that program into buffer ``slot``. Past the tile's last page
+        nothing is copied and the table is not read: the keys there are
+        masked by position whatever they are."""
+        first, end = span(seq, tile)
+        page0 = first + step * pps
+
+        def one(j, carry):
+            at = (pt_ref[seq, page0 + j], pl.ds(heads * hg, hg))
+            for c in (pltpu.make_async_copy(k_hbm.at[at], k_buf.at[slot, j], sems.at[0, slot]),
+                      pltpu.make_async_copy(v_hbm.at[at], v_buf.at[slot, j], sems.at[1, slot])):
+                c.wait() if wait else c.start()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(end - page0, 0, pps), one, 0)
+
+    first, end = span(b, qi)
+    n_steps = jnp.maximum((end - first + pps - 1) // pps, 1)
+    # the program after this one: the next tile, else the next heads, else the next sequence
+    last_tile, last_heads = qi + 1 == n_q, hi + 1 == n_h
+    nxt = (jnp.where(last_tile & last_heads, b + 1, b),
+           jnp.where(last_tile, jnp.where(last_heads, 0, hi + 1), hi),
+           jnp.where(last_tile, 0, qi + 1))
+
+    @pl.when((b == 0) & (hi == 0) & (qi == 0))
+    def _first():
+        # a probability of zero still needs values that are numbers: where no
+        # live page was ever copied the buffers hold zeros, not what VMEM held
+        v_buf[:] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
+        copy_pages(0, 0, 0, 0, 0, wait=False)
+
+    slot0 = slot_ref[0]
+    _paged_init(acc_scr, m_scr, l_scr)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+
+    def step_body(i, carry):
+        slot = (slot0 + i) % 2
+        page0 = first + i * pps
+
+        @pl.when(i + 1 < n_steps)
+        def _next_step():
+            copy_pages(b, hi, qi, i + 1, 1 - slot, wait=False)
+
+        @pl.when((i + 1 == n_steps) & (nxt[0] < n_b))
+        def _next_program():
+            copy_pages(*nxt, 0, 1 - slot, wait=False)
+            slot_ref[0] = 1 - slot
+
+        copy_pages(b, hi, qi, i, slot, wait=True)
+
+        # column c of a step's scores is position page0 * ps + c: its pages
+        # follow one another. (Steps that every query of the tile sees whole
+        # need no mask; leaving it out of them bought nothing: v5e, PR 32.)
+        rel = qp_ref[:] - page0 * ps
+        live = col <= rel
         if window is not None:
-            live = live & (k_pos > qp_ref[:] - window)
-        _paged_softmax_step(q_ref[:], k_ref[:], v_ref[:], live, acc_scr, m_scr, l_scr, scale)
+            live = live & (col > rel - window)
+        for h in range(hg):
+            acc_scr[h], m_scr[h], l_scr[h] = _paged_softmax_update(
+                q_ref[h].reshape(rows, D), k_buf[slot, :, h].reshape(cols, D),
+                v_buf[slot, :, h].reshape(cols, Dv), live, acc_scr[h], m_scr[h], l_scr[h], scale)
+        return carry
 
-    @pl.when(p == n_p - 1)
-    def _write():
-        _paged_write(o_ref, acc_scr, l_scr)
+    jax.lax.fori_loop(0, n_steps, step_body, 0)
+
+    for h in range(hg):
+        l = l_scr[h]
+        out = acc_scr[h] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[h] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
+
+
+def _paged_chunk_blocks(q_heads: int, T: int, D: int, q_itemsize: int, k_pages, v_pages):
+    """(query tile, KV heads a program, pages a loop step) of the chunk kernel
+    for these queries and pools: what fits the budget, from the shapes alone.
+    0 pages: nothing fits."""
+    from ..analysis import budget as _budget
+
+    Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
+    kv_item = jnp.dtype(str(k_pages.dtype).rpartition(".")[2]).itemsize
+    return _budget.paged_chunk_blocks(ps, D, q_heads // Hkv, T, kv_item, q_itemsize,
+                                      Dv=Dv, n_kv_heads=Hkv)
 
 
 def paged_chunk_decode(q, k_pages, v_pages, page_table, q_pos, scale=None, window=None,
@@ -1634,38 +1698,62 @@ def paged_chunk_decode(q, k_pages, v_pages, page_table, q_pos, scale=None, windo
     Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
     g = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    n_pages, rows, k_spec, v_spec, scratch = _paged_layout(
-        g * T, k_pages, v_pages, page_table, window, T + (window or 0) - 1)
-    # (B, Hkv, g*T, D): group rows of one kv head, T queries per group row set
-    qg = q.reshape(B, Hkv, g, T, D).reshape(B, Hkv, g * T, D)
+    interpret = _interpret() if interpret is None else interpret
+    Tq, hg, pps = _paged_chunk_blocks(H, T, D, q.dtype.itemsize, k_pages, v_pages)
+    refusal = None if interpret else _paged_refusal(D, v_pages, pps, True)
+    if refusal:  # what the checker declines, a direct call is refused by name
+        raise ValueError(f"paged_chunk_decode cannot take {T} queries a sequence, keys "
+                         f"{tuple(k_pages.shape)} and values {tuple(v_pages.shape)}: "
+                         f"{_PAGED_REFUSALS[refusal]}")
+    if not pps:  # the interpreter has no VMEM to run out of
+        Tq, hg, pps = T, Hkv, 1
+    n_q = T // Tq
+    # a KV head's rows of one tile: its g query heads' Tq queries, (g, Tq) of
+    # (g, T) where the queries are tiled and the whole (1, g * T) where not
+    # (a T that is no multiple of the sublane tile then needs no relayout)
+    G, R = (1, g * T) if n_q == 1 else (g, T)
     q_pos = q_pos.astype(jnp.int32)
-    prefetch = [page_table.astype(jnp.int32), jnp.max(q_pos, axis=1) + 1]  # page coverage bound
+    tiles = q_pos.reshape(B, n_q, Tq)
+    # a tile's coverage bound, inside the table, and the first page of its window
+    prefetch = [page_table.astype(jnp.int32),
+                jnp.minimum(jnp.max(tiles, axis=2) + 1, page_table.shape[1] * ps).reshape(-1)]
     if window is not None:
-        prefetch.append(jnp.maximum(jnp.min(q_pos, axis=1) - window + 1, 0) // ps)
-    # row r of the flattened q block is query t = r % T of its group
-    qp_rows = jnp.tile(q_pos, (1, g))[:, :, None]  # (B, g*T, 1)
+        prefetch.append((jnp.maximum(jnp.min(tiles, axis=2) - window + 1, 0) // ps).reshape(-1))
+    # row r of a tile's q block is query r % Tq of the tile
+    qp_rows = jnp.broadcast_to(tiles[:, :, None, :], (B, n_q, g, Tq)).reshape(B, n_q * g * Tq, 1)
+
+    def rows(width):
+        return pl.BlockSpec((None, hg, G, R // n_q, width), lambda b, h, t, *_: (b, h, 0, t, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(B, Hkv, n_pages),
-        in_specs=[rows(D), pl.BlockSpec((None, g * T, 1), lambda b, h, p, *_: (b, 0, 0)),
-                  k_spec, v_spec],
+        grid=(B, Hkv // hg, n_q),
+        in_specs=[rows(D), pl.BlockSpec((None, g * Tq, 1), lambda b, h, t, *_: (b, t, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=rows(Dv),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((2, pps, hg, ps, D), k_pages.dtype),
+                        pltpu.VMEM((2, pps, hg, ps, Dv), v_pages.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((hg, g * Tq, Dv), jnp.float32),
+                        pltpu.VMEM((hg, g * Tq, 1), jnp.float32),
+                        pltpu.VMEM((hg, g * Tq, 1), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_chunk_kernel, page_size=ps, scale=scale, window=window),
+        functools.partial(_paged_chunk_kernel, scale=scale, window=window, pages_per_step=pps),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g * T, Dv), q.dtype),
-        interpret=_interpret() if interpret is None else interpret,
-    )(*prefetch, qg, qp_rows, k_pages, v_pages)
-    return out.reshape(B, Hkv, g, T, Dv).reshape(B, H, T, Dv)
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, R, Dv), q.dtype),
+        interpret=interpret,
+    )(*prefetch, q.reshape(B, Hkv, G, R, D), qp_rows, k_pages, v_pages)
+    return out.reshape(B, H, T, Dv)
 
 
 def paged_chunk_attention_supported(q, k_pages, v_pages, page_table, q_pos,
                                     scale=None, window=None) -> bool:
     """Checker for thunder.paged_chunk_attention: same claim policy as the
-    decode kernel (the chip, page tiling, VMEM budget with the q/accumulator
-    rows widened by T)."""
+    decode kernel (the chip, page tiling, pools whose rows fill the lanes, at
+    least one page a loop step beside a tile of the queries inside the VMEM
+    budget)."""
     if not _claims_on_platform():
         return False
     if getattr(q, "ndim", 0) != 4:
@@ -1674,16 +1762,9 @@ def paged_chunk_attention_supported(q, k_pages, v_pages, page_table, q_pos,
     if not (_paged_shapes_ok(H, D, k_pages, v_pages, page_table, B)
             and getattr(q_pos, "ndim", 0) == 2 and tuple(q_pos.shape) == (B, T)):
         return False
-    from ..analysis import budget as _budget
-
-    Hkv, ps, Dv = k_pages.shape[1], k_pages.shape[2], v_pages.shape[3]
-    kv_item = jnp.dtype(str(k_pages.dtype).rpartition(".")[2]).itemsize
     q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
-    if not _budget.within_vmem(
-            _budget.paged_chunk_vmem_bytes(ps, max(D, Dv), H // Hkv, T, kv_item, q_item),
-            _budget.paged_vmem_limit()):
-        return _decline("paged_chunk_attention", "vmem")
-    return True
+    refusal = _paged_refusal(D, v_pages, _paged_chunk_blocks(H, T, D, q_item, k_pages, v_pages)[2], _on_tpu())
+    return _decline("paged_chunk_attention", refusal) if refusal else True
 
 
 def _paged_chunk_attention_impl(q, k_pages, v_pages, page_table, q_pos, scale=None, window=None):

@@ -1156,6 +1156,7 @@ class ServingEngine:
             self._window_trim(req, req.chunk_pos)
         if obs_on:
             _obs_metrics.record_serve("prefill_tokens", delta=n_real)
+            self._record_chunk_pages(np.asarray([start]), cb)
             dur_ms = (time.perf_counter() - t0) * 1e3
             _obs_tel.observe("serve.prefill_ms", dur_ms)
             _obs_trace.trace_event(req.trace_id, "prefill_chunk",
@@ -1426,23 +1427,44 @@ class ServingEngine:
             _obs_metrics.record_serve("state.recurrent_bytes",
                                       delta=active * self.cache.recurrent_bytes_per_slot())
 
+    def _record_pages(self, name: str, kinds, ends, window_first, window_span: int) -> None:
+        """``serve.paged.<name>pages_live``: pages [0, ends) a sequence of the
+        pools of ``kinds`` without a window and [window_first, ends) of the
+        window pools; ``<name>pages_spanned``: what a grid of one program a
+        table entry stepped over, the table's width a sequence or the pages
+        ``window_span`` positions can touch."""
+        seqs = len(ends)
+        live = spanned = 0
+        if any(isinstance(d, kinds) and not d.window for d in self.cache.layers):
+            live += int(ends.sum())
+            spanned += seqs * self.n_pages_max
+        if self.window:
+            live += int((ends - window_first).sum())
+            spanned += seqs * min(self.n_pages_max, -(-window_span // self.page_size) + 1)
+        _obs_metrics.record_serve(f"paged.{name}pages_live", delta=live)
+        _obs_metrics.record_serve(f"paged.{name}pages_spanned", delta=spanned)
+
     def _record_paged_pages(self) -> None:
         """Pages the paged decode kernel walked in this decode step (bus on),
         summed over every slot of the packed program (an idle slot reads one)
         and over the page kinds, beside what a grid of one program a table
         entry stepped over: the table's width a slot, or the window's span."""
-        ps, slots = self.page_size, len(self._pos)
+        ps, window = self.page_size, self.window or 0
         lens = self._pos.astype(np.int64) + 1
-        ends = -(-lens // ps)
-        live = spanned = 0
-        if any(isinstance(d, (PagedKV, PagedLatent)) and not d.window for d in self.cache.layers):
-            live += int(ends.sum())
-            spanned += slots * self.n_pages_max
-        if self.window:
-            live += int((ends - np.maximum(lens - self.window, 0) // ps).sum())
-            spanned += slots * min(self.n_pages_max, -(-self.window // ps) + 1)
-        _obs_metrics.record_serve("paged.pages_live", delta=live)
-        _obs_metrics.record_serve("paged.pages_spanned", delta=spanned)
+        self._record_pages("", (PagedKV, PagedLatent), -(-lens // ps),
+                           np.maximum(lens - window, 0) // ps, window)
+
+    def _record_chunk_pages(self, first_pos: np.ndarray, n_queries: int) -> None:
+        """Pages the queries of this chunk or verify dispatch can see (bus on):
+        each sequence's ``n_queries`` positions from ``first_pos`` on, summed
+        over the sequences of the program and over the page kinds a paged chunk
+        kernel reads, beside the table's width a sequence, or the span of the
+        queries' windows."""
+        ps, window = self.page_size, self.window or 0
+        first_pos = np.asarray(first_pos, np.int64)
+        ends = np.minimum(-(-(first_pos + n_queries) // ps), self.n_pages_max)
+        self._record_pages("chunk_", PagedKV, ends, np.maximum(first_pos - window + 1, 0) // ps,
+                           n_queries + window - 1)
 
     def _spec_decode(self) -> None:
         """Speculative decode iteration: k draft decode steps propose, one
@@ -1491,6 +1513,8 @@ class ServingEngine:
                     vlog, state = self.runner.verify_cfn(
                         self.params, toks, self.cache.state, self._pt_dev, pos)
                     self.cache.rebind(state)
+                    if obs_on:
+                        self._record_chunk_pages(base_pos, K1)
                     B = toks_mat.shape[0]
                     pos_flat = (base_pos[:, None] + 1
                                 + np.arange(K1, dtype=np.int32)[None, :]).reshape(-1)
