@@ -364,6 +364,57 @@ def test_the_decode_step_counts_its_routing_with_the_bus_on():
     assert observability.counters().get("serve.moe.rows_routed", 0) in (0, c["serve.moe.rows_routed"])
 
 
+def _beside(model, mixes: bool):
+    """A short request decodes while prompts of 50 and 130 are chunked beside it (chunks of 32).
+    Returns (tokens, counters with the bus on, the engine)."""
+    toks = tokens(140, seed=3)
+    eng = ServingEngine(model, **ENGINE)
+    assert eng._mixes and eng.runner.mixes
+    eng._mixes = mixes   # False: a chunk program and a decode program a pass, as before
+    observability.enable()
+    try:
+        observability.reset()
+        first = eng.submit(toks[:12], max_new_tokens=24)
+        for _ in range(3):
+            eng._step_once()
+        rest = [eng.submit(toks[:n], max_new_tokens=6) for n in (50, 130)]
+        eng.drain()
+        counters = observability.counters()
+    finally:
+        observability.disable()
+        observability.reset()
+    return [f.result(timeout=5).new_tokens for f in [first] + rest], counters, eng
+
+
+def test_decode_rows_beside_a_chunk_share_its_ragged_call_and_give_the_same_tokens():
+    """The latent block offers `mixed`: in a pass with a chunk due the decode rows go through
+    the chunk's program, ONE ragged expert call a layer over both kinds of rows, the chunk's
+    queries through the chunk's attention and the decode rows through theirs. Token for token
+    what the two programs give; the routing counters count the decode rows only, as the decode
+    program's do, so their relations hold over mixed and plain steps alike."""
+    model = seeded(TINY)
+    want, two, _ = _beside(model, False)
+    got, one, eng = _beside(model, True)
+    for a, b in zip(want, got):
+        assert np.array_equal(a, b)
+    assert "serve.decode_mixed" not in two and one["serve.decode_mixed"] >= 5
+    layers = TINY.n_layer
+    for c in (one, two):
+        assert c["serve.tokens"] == (24 - 1) + 2 * (6 - 1)
+        assert c["serve.moe.rows_routed"] == 2 * layers * c["serve.tokens"]
+        assert 0 < c["serve.moe.rows_held"] < c["serve.moe.rows_routed"]
+        assert c["serve.moe.rows_max"] <= c["serve.moe.rows_held"]
+        assert 0 < c["serve.moe.experts_touched"] <= 4 * layers * c["serve.decode_steps"]
+        assert c.get("serve.pool_copied", 0) == 0
+    # the same rows on the same experts whichever program a step rode in: the new sequences join
+    # the decode step a pass later where they were chunked beside a step, so the steps differ
+    # by which rows they hold and the sums do not
+    assert one["serve.moe.rows_held"] == two["serve.moe.rows_held"]
+    symbols = [b.sym.name for b in tt.last_traces(eng.runner.chunk_cfn._cfn)[0].bound_symbols]
+    assert symbols.count("ragged_mlp") == layers
+    assert symbols.count("paged_latent_attention") == 2 * layers   # the chunk's queries; the decode rows
+
+
 # -- both kernels through the v5e's compiler, at the published widths (no chip needed) --------------------
 
 @pytest.fixture(scope="module")

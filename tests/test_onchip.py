@@ -479,3 +479,57 @@ def test_fused_quantized_linears_on_chip():
     # adaptive block width (the llama MLP K)
     K2 = 2816
     assert px.nf4_kernel_block_k(K2) == 256
+
+
+def test_a_decode_row_reads_the_same_through_the_decode_program_and_beside_a_chunk():
+    """A sequence's decode rows go through `decode_cfn` (max_batch rows) when it is served
+    alone and through the chunk program (512 + max_batch rows) when a prompt chunk is due in
+    the same pass. The benchmark holds four requests to identical tokens alone and batched, so
+    a row's logits may not depend on which of the two shapes its matmuls had: bit for bit the
+    same, at the long-prompt cell's widths (two layers of them), whatever the chunk's start."""
+    from thunder_tpu.models.litgpt import GPT, Config
+    from thunder_tpu.serving import ServingEngine
+
+    cfg = Config(name="mistral-7b-widths-l2", block_size=2048, vocab_size=32768, padded_vocab_size=32768,
+                 n_layer=2, n_head=32, n_query_groups=8, n_embd=4096, head_size=128,
+                 intermediate_size=14336, rope_base=1000000, norm_eps=1e-5)
+    gpt = GPT(cfg, dtype=jnp.bfloat16)
+    key = jax.random.key(35)
+    for i, (name, p) in enumerate(sorted(gpt.named_parameters())):
+        if p.data.ndim >= 2:
+            p.data = (0.02 * jax.random.normal(jax.random.fold_in(key, i), p.data.shape,
+                                               jnp.float32)).astype(jnp.bfloat16)
+    eng = ServingEngine(gpt, max_batch=12, page_size=64, max_seq=2048, chunk_tokens=512, min_bucket=512)
+    assert eng.runner.mixes
+    rng = np.random.RandomState(35)
+    lens = [515, 700, 1300, 33, 1600]
+    for L in lens:
+        eng.submit(rng.randint(0, cfg.vocab_size, (L,)).astype(np.int32), max_new_tokens=40)
+    while eng._chunking or eng._pending or sum(s is not None for s in eng._slots) < len(lens):
+        eng._step_once()
+    for _ in range(3):
+        eng._step_once()
+    eng._land()
+    live = np.flatnonzero(eng._pos > 0)
+    assert len(live) == len(lens)
+
+    def i32(a):
+        return jnp.asarray(np.array(a), jnp.int32)
+
+    toks, tables, pos = i32(eng._toks[:, None]), (i32(eng._page_tables),), i32(eng._pos)
+    alone, state = eng.runner.decode_cfn(eng.params, toks, eng.cache.state, tables, pos)
+    alone = np.asarray(alone.astype(jnp.float32))[live]
+    assert np.isfinite(alone).all() and alone.std() > 0.1
+    T = eng.chunk_tokens
+    row = (i32(eng.cache.page_table_row(eng.cache.allocator.alloc(3 * T // 64), eng.n_pages_max)[None]),)
+    for start in (0, 2 * T):
+        idx = i32(rng.randint(0, cfg.vocab_size, (1, T)))
+        _, beside, state = eng.runner.chunk_cfn(eng.params, idx, row, state, i32(start), i32(T - 1),
+                                                i32(11), (toks, tables, pos))
+        # the decode rows rewrite the k/v they wrote in the step above, at the same positions
+        beside = np.asarray(beside.astype(jnp.float32))[live]
+        assert np.array_equal(alone, beside), (
+            f"chunk at {start}: {int((alone != beside).sum())} of {alone.size} logits differ, "
+            f"by {np.abs(alone - beside).max()} at most")
+    eng.cache.rebind(state)
+    eng.stop()

@@ -179,8 +179,11 @@ def test_fused_cross_entropy_matches(rng):
     np.testing.assert_allclose(np.asarray(loss), ref, atol=2e-4)
 
 
-def test_fused_rms_norm_matches(rng):
-    x = jnp.asarray(rng.randn(32, 256).astype(np.float32))
+@pytest.mark.parametrize("rows", [32, 256, 524, 640], ids=lambda n: f"rows{n}")
+def test_fused_rms_norm_matches(rng, rows):
+    """Every row is written, also where the block of 256 does not divide them (524: a chunk's
+    512 rows and a dozen decode rows in one program; 640: 512 and 128)."""
+    x = jnp.asarray(rng.randn(rows, 256).astype(np.float32))
     w = jnp.asarray(rng.randn(256).astype(np.float32))
     out = pallasex.fused_rms_norm(x, w)
     ref = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * w

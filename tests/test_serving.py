@@ -1025,3 +1025,252 @@ def test_the_speculative_path_records_no_overlapped_step(gpt, rng):
     res, counters = _bus_counters(run)
     assert res.n_new_tokens == 9 and counters["serve.decode_steps"] > 0
     assert "serve.decode_overlapped" not in counters and engine._inflight is None
+
+
+# ---------------------------------------------------------------------------
+# a prompt chunk and the live decode rows in one program
+# ---------------------------------------------------------------------------
+
+# (prompt length, tokens asked for, temperature, seed): with chunks of 16, the prompts over 16
+# tokens go through chunks (40: two whole ones and a final rung of 8; 23: one and a final of 7)
+_BESIDE = [(9, 20, 0.0, 0), (40, 6, 0.9, 42), (23, 9, 0.0, 0), (52, 4, 0.7, 7), (33, 5, 0.0, 0)]
+
+
+def _chunking_engine(gpt, mixes: bool, **kw):
+    """Chunks of 16 on three slots. ``mixes`` False: the engine as it was before a chunk's
+    program took the decode step's rows (a chunk program and a decode program a pass)."""
+    kw.setdefault("max_batch", 3)
+    engine = _engine(gpt, chunk_tokens=16, prefill_budget=16, **kw)
+    assert engine._mixes and engine.runner.mixes
+    engine._mixes = mixes
+    return engine
+
+
+def _serve_beside(gpt, reqs, mixes: bool, end: str):
+    """Request 0 decodes while the long prompts of 1 and 2 arrive and are chunked beside it, then
+    3 and 4 take the slots that free. Returns ({i: tokens or None}, counters, engine)."""
+    engine = _chunking_engine(gpt, mixes, prefix_sharing=(end == "prefix"))
+    eos = {}
+    if end == "eos":
+        probe = _engine(gpt)
+        p, n, temp, seed = reqs[0]
+        fut = probe.submit(p, max_new_tokens=n, temperature=temp, seed=seed)
+        probe.drain()
+        stream = fut.result().new_tokens
+        k = next(j for j in range(5, 20) if stream[j] not in stream[:j])
+        eos[0] = int(stream[k])  # request 0 ends at its (k + 1)th token, chunks beside it
+    lanes = {0: "batch"} if end == "preempt" else {}
+
+    def submit(i):
+        p, n, temp, seed = reqs[i]
+        return engine.submit(p, max_new_tokens=n, temperature=temp, seed=seed, eos_id=eos.get(i),
+                             lane=lanes.get(i, "interactive"))
+
+    def run():
+        futs = {0: submit(0)}
+        for _ in range(3):
+            engine._step_once()
+        futs[1], futs[2] = submit(1), submit(2)   # chunked while 0 decodes
+        for _ in range(2):
+            engine._step_once()
+        assert engine._chunking and engine._inflight is not None
+        if end == "cancel":
+            assert futs[0].cancel()                # a token of its in flight, in a chunk's program
+        if end == "preempt":
+            assert engine._preempt_one()           # 0 comes back as chunks of prompt + tokens
+            assert engine._inflight is None
+        futs[3], futs[4] = submit(3), submit(4)
+        engine.drain()
+        return futs
+
+    futs, counters = _bus_counters(run)
+    out = {i: (None if f.cancelled() else f.result(timeout=5).new_tokens) for i, f in futs.items()}
+    assert engine._inflight is None and engine.cache.allocator.n_used == (
+        len(engine.prefix) if end == "prefix" else 0)
+    assert all(s is None for s in engine._slots) and not engine._chunking
+    return out, counters, engine
+
+
+@pytest.mark.parametrize("end", ["length", "eos", "cancel", "preempt", "prefix"])
+def test_decode_rows_that_ride_in_a_chunks_program_give_what_the_two_programs_give(gpt, rng, end):
+    """In a pass with a chunk due the decode step's rows go through the chunk's program. Token
+    for token nothing may show against a chunk program and a decode program a pass: long prompts
+    arriving while another decodes, a final chunk's first token, an end by `eos_id` and a cancel
+    while a step is in flight in a chunk's program, a preempted request's resumed chunks, prefix
+    sharing (request 4's prompt starts with request 1's)."""
+    reqs = [(rng.randint(0, gpt.cfg.vocab_size, (L,)).astype(np.int32), n, temp, seed)
+            for L, n, temp, seed in _BESIDE]
+    if end == "prefix":
+        reqs[4] = (np.concatenate([reqs[1][0][:32], reqs[4][0][:1]]),) + reqs[4][1:]
+    want, two, _ = _serve_beside(gpt, reqs, False, end)
+    got, one, engine = _serve_beside(gpt, reqs, True, end)
+    for i in want:
+        if want[i] is None:
+            assert got[i] is None and end == "cancel" and i == 0
+        else:
+            np.testing.assert_array_equal(got[i], want[i])
+    assert "serve.decode_mixed" not in two and one["serve.decode_mixed"] >= 4
+    # a step a pass either way, and the same tokens committed
+    assert one["serve.tokens"] == two["serve.tokens"]
+    assert one["serve.decode_mixed"] <= one["serve.decode_steps"] == engine.decode_steps
+    if end == "preempt":
+        assert engine.preempted == 1 and engine.resumed == 1
+    if end == "prefix":
+        assert engine.prefix_hits == 1 and one["serve.prefix_tokens_saved"] == 32
+
+
+def test_a_mixed_step_is_counted_as_the_two_dispatches_were(gpt, rng):
+    """One request decodes, a prompt of 40 arrives: its chunks at 0, 16 and 32 (a rung of 8) each
+    carry a decode step (`serve.decode_mixed` 3 of 11), the step after its activation is fed
+    from the host, and every counter of the decode step and of the chunks reads what it reads
+    with a chunk program and a decode program a pass."""
+    def run(mixes):
+        engine = _chunking_engine(gpt, mixes, max_batch=4)
+        rs = np.random.RandomState(5)
+
+        def serve():
+            a = engine.submit(rs.randint(0, gpt.cfg.vocab_size, (5,)).astype(np.int32), max_new_tokens=12)
+            for _ in range(2):
+                engine._step_once()
+            b = engine.submit(rs.randint(0, gpt.cfg.vocab_size, (40,)).astype(np.int32), max_new_tokens=4)
+            engine.drain()
+            return a.result(timeout=5).new_tokens, b.result(timeout=5).new_tokens
+
+        toks, counters = _bus_counters(serve)
+        return toks, counters, engine
+
+    (a2, b2), two, _ = run(False)
+    (a1, b1), one, engine = run(True)
+    np.testing.assert_array_equal(a1, a2)
+    np.testing.assert_array_equal(b1, b2)
+    assert one["serve.decode_mixed"] == 3 and "serve.decode_mixed" not in two
+    same = ["serve.decode_steps", "serve.tokens", "serve.decode_overlapped", "serve.prefill_tokens",
+            "serve.paged.chunk_pages_live", "serve.paged.chunk_pages_spanned", "serve.paged.pages_spanned",
+            "serve.prefills"]
+    assert {k: one[k] for k in same} == {k: two[k] for k in same}
+    assert one["serve.decode_steps"] == engine.decode_steps == 11
+    assert one["serve.tokens"] == (12 - 1) + (4 - 1)
+    assert one["serve.decode_overlapped"] == 11 - 2   # the first step, and the one after the activation
+    assert one["serve.paged.chunk_pages_spanned"] == 3 * 8 and one["serve.paged.chunk_pages_live"] == 2 + 4 + 5
+    assert one["serve.paged.pages_spanned"] == 11 * 4 * 8
+    # the sequence that was chunked joins the decode step one pass later than it did: its rows
+    # are read a step later, the first one's as before
+    assert one["serve.paged.pages_live"] <= two["serve.paged.pages_live"]
+    assert "serve.decode_discarded" not in one and "serve.pool_copied" not in one
+
+
+def test_nothing_is_built_when_a_chunk_first_meets_live_rows(gpt, rng):
+    """`warmup` runs every chunk rung with idle decode rows only. The same executable then
+    serves a chunk with live rows beside it, fed from the host or from the sampler: no trace, no
+    executable is built after warm-up."""
+    import jax.monitoring
+    from thunder_tpu import observability
+
+    engine = _chunking_engine(gpt, True)
+    engine.warmup([5, 17, 25, 33])  # the decode step, a bucket, chunk rungs 8 and 16 behind a chunk of 16
+    chunk, decode = engine.runner.chunk_cfn._cfn, engine.runner.decode_cfn._cfn
+    traced = (chunk.cache_misses, decode.cache_misses)
+    built = []
+
+    def on_build(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            built.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_build)
+    observability.enable()
+    observability.reset()
+    try:
+        futs = [engine.submit(rng.randint(0, gpt.cfg.vocab_size, (L,)).astype(np.int32), max_new_tokens=n)
+                for L, n in [(6, 14), (40, 5), (23, 6)]]
+        engine.drain()
+        counters = observability.counters()
+    finally:
+        observability.disable()
+        observability.reset()
+        jax.monitoring.unregister_event_duration_listener(on_build)
+    assert [f.result().n_new_tokens for f in futs] == [14, 5, 6]
+    assert counters["serve.decode_mixed"] >= 4
+    assert not {k: v for k, v in counters.items() if k.startswith("recompile.")}
+    assert (chunk.cache_misses, decode.cache_misses) == traced and not built
+
+
+def test_the_chunk_program_takes_each_kind_of_row_through_its_own_attention(gpt, rng):
+    """One `paged_chunk_attention` and one `paged_attention` a layer in the chunk program's
+    trace (never a second chunk call for the decode rows), the decode program's as it was; an
+    engine with a draft model keeps the chunk program without decode rows and never mixes."""
+    def attends(cfn):
+        from collections import Counter
+
+        return Counter(b.sym.name for b in tt.last_traces(cfn._cfn)[0].bound_symbols
+                       if "paged" in b.sym.name)
+
+    def serve(engine):
+        futs = [engine.submit(rng.randint(0, gpt.cfg.vocab_size, (L,)).astype(np.int32), max_new_tokens=6)
+                for L in (7, 40)]
+        engine.drain()
+        return [f.result().new_tokens for f in futs]
+
+    n = gpt.cfg.n_layer
+    engine = _engine(gpt, chunk_tokens=16)
+    got, counters = _bus_counters(lambda: serve(engine))
+    assert counters["serve.decode_mixed"] == 3
+    assert attends(engine.runner.chunk_cfn) == {"paged_chunk_attention": n, "paged_attention": n}
+    assert attends(engine.runner.decode_cfn) == {"paged_attention": n}
+    drafted = _engine(gpt, chunk_tokens=16, draft_gpt=gpt, spec_k=2)
+    assert not drafted._mixes and drafted._idle_rows is None
+    want, counters = _bus_counters(lambda: serve(drafted))
+    assert "serve.decode_mixed" not in counters
+    assert attends(drafted.runner.chunk_cfn) == {"paged_chunk_attention": n}
+    assert attends(drafted.draft_runner.chunk_cfn) == {"paged_chunk_attention": n}
+
+
+def test_a_capacity_bound_expert_block_does_not_offer_the_mixed_program(rng):
+    """An expert's capacity follows from the rows a program has, so decode rows beside a chunk's
+    would be dropped where alone they are not: such a block offers no `mixed`, and the engine
+    keeps the two programs. A drop-free expert block mixes like a dense one."""
+    from thunder_tpu.models.moe import MoEConfig, MoEGPT
+
+    cfg = Config.from_name("tiny-llama2", block_size=64, n_layer=1)
+    def moe(capacity_factor):
+        return MoEGPT(cfg, MoEConfig(n_embd=cfg.n_embd, intermediate_size=64, n_expert=4,
+                                     n_expert_per_token=2, capacity_factor=capacity_factor),
+                      dtype=jnp.float32)
+
+    assert _engine(moe(None), chunk_tokens=16)._mixes
+    bound = _engine(moe(1.0), chunk_tokens=16)
+    assert not bound.runner.mixes and not bound._mixes
+    futs = [bound.submit(rng.randint(0, cfg.vocab_size, (L,)).astype(np.int32), max_new_tokens=4)
+            for L in (6, 30)]
+    bound.drain()
+    assert [f.result().n_new_tokens for f in futs] == [4, 4]
+
+
+def test_programs_a_decode_row_may_run_in_either_of_round_at_every_op(gpt, rng):
+    """A sequence's decode rows run in `decode_cfn` alone and in the chunk program beside a chunk.
+    XLA by default keeps the intermediates of a fused chain in float32, and which chains it fuses
+    follows from the shapes: on the chip a row read other bits in one program than in the other
+    (PERF.md, PR 35). So both programs' XLA regions are compiled with
+    `xla_allow_excess_precision` off (`tt.jit(round_every_op=True)` -> `trace.round_every_op` ->
+    `jax.jit(compiler_options=)`), the option is part of a region's artifact key, and the
+    programs that run at one shape only keep the default."""
+    from thunder_tpu.compile_service import parallel_compile
+
+    engine = _engine(gpt, chunk_tokens=16)
+    futs = [engine.submit(rng.randint(0, gpt.cfg.vocab_size, (L,)).astype(np.int32), max_new_tokens=4)
+            for L in (7, 40)]
+    engine.drain()
+    assert [f.result().n_new_tokens for f in futs] == [4, 4]
+
+    def options(cfn):
+        regions = parallel_compile.fusion_regions(tt.last_traces(cfn._cfn)[-1])
+        assert regions
+        return {repr(b.impl.compiler_options) for b in regions}
+
+    strict = {repr({"xla_allow_excess_precision": False})}
+    assert options(engine.runner.decode_cfn) == options(engine.runner.chunk_cfn) == strict
+    assert options(engine.runner.prefill_cfn) == {"None"}
+    region = parallel_compile.fusion_regions(tt.last_traces(engine.runner.decode_cfn._cfn)[-1])[0]
+    avals = parallel_compile._region_avals(region)
+    key = parallel_compile.region_key(region, avals)
+    region.impl.compiler_options = None
+    assert parallel_compile.region_key(region, avals) != key

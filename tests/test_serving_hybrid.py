@@ -145,6 +145,37 @@ def test_sequences_admitted_while_others_decode_give_what_each_gives_alone(model
     assert counters["serve.tokens"] == sum(n - 1 for _, n in SAMPLE)
 
 
+def test_the_hybrid_never_mixes_a_chunk_with_the_decode_step(model):
+    """Its layers offer no `mixed` (a scan state, a window, a memory another layer reads), so the
+    engine keeps a chunk program without decode rows and a decode program a pass, and counts no
+    `serve.decode_mixed`."""
+    eng = engine_for(model)
+    assert not eng.runner.mixes and not eng._mixes and eng._idle_rows is None
+    assert not any(hasattr(layer, "mixed") for layer in eng.runner.model.layers)
+    ps = prompts([20, 100], seed=11)
+    observability.enable()
+    try:
+        observability.reset()
+        first = eng.submit(ps[0], max_new_tokens=12)
+        for _ in range(3):
+            eng._step_once()
+        second = eng.submit(ps[1], max_new_tokens=4)   # four chunks of 32 beside the first's steps
+        eng.drain()
+        counters = observability.counters()
+    finally:
+        observability.disable()
+        observability.reset()
+    assert first.result().n_new_tokens == 12 and second.result().n_new_tokens == 4
+    assert "serve.decode_mixed" not in counters and counters["serve.decode_steps"] >= 11
+    trace = tt.last_traces(eng.runner.chunk_cfn._cfn)[0]
+    assert not any(b.sym.name == "paged_attention" for b in trace.bound_symbols)
+    # and its programs are compiled as they were: no row of theirs runs at two shapes
+    from thunder_tpu.compile_service import parallel_compile
+    for cfn in (eng.runner.chunk_cfn, eng.runner.decode_cfn):
+        regions = parallel_compile.fusion_regions(tt.last_traces(cfn._cfn)[-1])
+        assert regions and all(b.impl.compiler_options is None for b in regions)
+
+
 def recurrent_rows(eng, slot: int) -> list:
     """The rows slot ``slot`` holds in every recurrent array, on the host."""
     return [np.asarray(a[slot]) for layer, arrays in zip(eng.cache.layers, eng.cache.state)
@@ -308,11 +339,16 @@ def test_the_programs_claim_what_the_builders_state(model, pallas_claims):
     litgpt = manifest.load_module(manifest.ROOT, "builders", "litgpt")
     stated = litgpt.kernel_claims({"num_hidden_layers": cfg.n_layer})
     assert _claims(dense.runner.decode_cfn) == stated["decode_cfn"]
-    assert _claims(dense.runner.chunk_cfn) == stated["chunk_cfn"]
+    # the chunk's rows through the chunk kernel as stated, and the decode rows that ride in the
+    # program through the decode kernel, never through a second chunk call
+    assert _claims(dense.runner.chunk_cfn) == dict(stated["chunk_cfn"],
+                                                   **{"thunder.paged_attention": cfg.n_layer})
     assert _claims(dense.runner.prefill_cfn) == {}
     drafted = ServingEngine(gpt, draft_gpt=GPT(cfg, dtype=jnp.float32), spec_k=2, **spec)
-    serve(drafted, prompts([10], seed=9), [5])
+    serve(drafted, prompts([10, 40], seed=9), [5, 5])
     assert _claims(drafted.runner.verify_cfn) == {"thunder.paged_chunk_attention": cfg.n_layer}
+    # an engine with a draft model keeps the chunk program that takes no decode rows
+    assert _claims(drafted.runner.chunk_cfn) == stated["chunk_cfn"]
 
 
 # ---------------------------------------------------------------------------

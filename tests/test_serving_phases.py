@@ -88,12 +88,15 @@ def served(request, models, tmp_path_factory):
         finally:
             jax.profiler.stop_trace()
         records = observability.records()
+        counters = observability.counters()
     finally:
         observability.disable()
         observability.reset()
     spans = [r for r in records if r["kind"] == "span"]
     return dict(mode=mode, spec=spec, engine=engine, results=results, records=records,
                 spans=spans, names=Counter(r["name"] for r in spans),
+                # decode steps whose rows rode in a chunk's program (the plain path, chunks due)
+                mixed=counters.get("serve.decode_mixed", 0),
                 annotations=host_annotations(trace_dir))
 
 
@@ -119,9 +122,12 @@ def test_phases_nest_under_an_iteration_and_siblings_do_not_overlap(served):
         if r["name"] == "engine:iteration":
             continue
         up = list(ancestors(r))
-        # under one iteration; between the two only other phases or the old serve_decode span
+        # under one iteration; between the two only other phases or the old serve_decode span,
+        # and the chunk's serve_prefill span where the step rides in the chunk's program
         assert up and up[-1]["name"] == "engine:iteration", r
-        assert all(a["name"] in PHASES | {"serve_decode"} for a in up[:-1]), r
+        assert all(a["name"] in PHASES | {"serve_decode", "serve_prefill"} for a in up[:-1]), r
+        if "serve_prefill" in {a["name"] for a in up[:-1]}:
+            assert served["mixed"] and r["name"] != "engine:prefill"
         assert r["ts_ms"] >= up[0]["ts_ms"] - 0.002 and end(r) <= end(up[0]) + 0.002
         children.setdefault(r["parent"], []).append(r)
     for sibs in children.values():
@@ -163,11 +169,16 @@ def test_the_profiler_sees_the_same_phases_and_one_serve_decode_a_dispatch(serve
     ann, names = served["annotations"], served["names"]
     for name in PHASES | {"engine:iteration"}:
         assert ann[name] == names[name], name
-    steps = served["engine"].decode_steps
-    # the old names are not reused: one annotation for each dispatch of a compiled program
-    assert ann["serve_decode"] == (SPEC_K if served["spec"] else 1) * steps
+    steps, mixed = served["engine"].decode_steps, served["mixed"]
+    # the old names are not reused: one annotation for each dispatch of a compiled program; a
+    # step that rode in a chunk's program was dispatched as that chunk's `serve_chunk_prefill`
+    # the second prompt's two chunks run beside the first request's decode steps
+    assert mixed == (2 if (served["mode"], served["spec"]) == ("chunk", False) else 0)
+    assert ann["serve_decode"] == (SPEC_K if served["spec"] else 1) * steps - mixed
     assert ann["serve_verify"] == (steps if served["spec"] else 0)
-    assert ann["serve_decode"] + ann["serve_verify"] == ann["engine:dispatch"]
+    assert ann["serve_decode"] + ann["serve_verify"] + mixed == ann["engine:dispatch"]
+    if served["mode"] == "chunk":  # chunks at 0, 16, 32 of 40 tokens and at 0, 16 of 23
+        assert ann["serve_chunk_prefill"] == (2 if served["spec"] else 1) * 5 >= mixed
 
 
 def test_queue_wait_is_stamped_at_admission(served):

@@ -285,6 +285,8 @@ class ThunderCompiledFunction(EpilogueMixin):
             if self._donated_argnums:
                 trc.donated = donated_arg_names(trc, args, kwargs, tensor_mask,
                                                 self._donated_argnums)
+            if cd.compile_options.get("round_every_op"):
+                trc.round_every_op = True
 
             # pass-interposed verification (thunder_tpu/analysis): under
             # TT_CHECK_TRACES=1 (or DebugOptions(check_traces=True)) every

@@ -94,11 +94,13 @@ def region_key(bsym, avals) -> str:
     sub = bsym.impl.subtrace
     head, nl, body = sub.python().partition("\n")
     donate = getattr(bsym.impl, "donate_argnums", ())
+    options = getattr(bsym.impl, "compiler_options", None)
     return _store.artifact_key(
         kind="region",
         trace=_DEF_NAME.sub("def region(", head, count=1) + nl + body,
         avals="|".join(f"{s.shape}:{s.dtype}" for s in avals),
         **({"donate": ",".join(map(str, donate))} if donate else {}),
+        **({"options": ",".join(f"{k}={v}" for k, v in sorted(options.items()))} if options else {}),
     )
 
 

@@ -196,6 +196,10 @@ def from_trace(trace: TraceCtx) -> TraceCtx:
     donated = getattr(trace, "donated", None)
     if donated:
         t.donated = set(donated)
+    # the caller's word that every op of the program rounds to its own type (tt.jit's
+    # round_every_op): the XLA executor compiles the regions accordingly
+    if getattr(trace, "round_every_op", False):
+        t.round_every_op = True
     return t
 
 

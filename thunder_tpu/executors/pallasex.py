@@ -825,7 +825,10 @@ def fused_rms_norm(x2d, w, eps: float = 1e-6, block_n: int = 256):
     block_n = min(block_n, N)
     return pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
-        grid=(N // block_n,),
+        # every row, also where the block does not divide them (a chunk's 512 rows and a dozen
+        # decode rows: `N // block_n` blocks left the last rows unwritten); a row is its own
+        # reduction, so what the last block reads past the end touches no row that is kept
+        grid=(pl.cdiv(N, block_n),),
         in_specs=[
             pl.BlockSpec((block_n, D), lambda i: (i, 0)),
             pl.BlockSpec((1, D), lambda i: (0, 0)),

@@ -134,7 +134,15 @@ class XLAFusionExecutor(FusionExecutor):
         if donated and jax.core.trace_ctx.is_top_level():
             donate = tuple(i for i, p in enumerate(inputs)
                            if p.name in donated and variableify(p) not in consumed_later)
-        jfn = jax.jit(scoped_fn, donate_argnums=donate) if donate else jax.jit(scoped_fn)
+        # tt.jit(round_every_op=True) -> trace.round_every_op: XLA keeps no intermediate of a
+        # fused chain in a wider type than the op's own (it would, by default, and which chains
+        # it fuses follows from the shapes), so a row's bits do not depend on what else the
+        # program holds. Without it the call is the jax.jit it always was.
+        options = ({"xla_allow_excess_precision": False}
+                   if getattr(trace, "round_every_op", False) else None)
+        jit_kw = dict(**({"donate_argnums": donate} if donate else {}),
+                      **({"compiler_options": options} if options else {}))
+        jfn = jax.jit(scoped_fn, **jit_kw)
 
         fusion_sym = Symbol(name, None, id=f"xla.{name}", is_prim=True, executor=self, module="xla")
 
@@ -189,6 +197,7 @@ class XLAFusionExecutor(FusionExecutor):
         impl.__name__ = name
         impl.jitted = jfn
         impl.donate_argnums = donate  # part of the region's store key
+        impl.compiler_options = options  # and so are these
         impl.subtrace = subtrace
         impl._prewarmed = None
         bsym = BoundSymbol(fusion_sym, tuple(inputs), {}, tuple(outputs), subsymbols=tuple(region), impl=impl)

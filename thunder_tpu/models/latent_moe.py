@@ -209,6 +209,7 @@ class LatentAttention(nn.Module):
 
     # -- absorbed: against the cached rows ---------------------------------------
     def absorbed(self, q_nope, q_rope, pool, table, q_pos):
+        """The heads' outputs (B, T, H * v), before the output projection."""
         cfg = self.cfg
         B, T, H, nope = q_nope.shape
         r, v = cfg.kv_lora_rank, cfg.v_head_dim
@@ -222,8 +223,8 @@ class LatentAttention(nn.Module):
         o_lat = ltorch.paged_latent_attention(ltorch.cat(parts, -1), pool, table, q_pos, self.scale, r)
         o_lat = ltorch.reshape(ltorch.permute(o_lat, (1, 0, 2, 3)), (H, B * T, r))
         y = ltorch.matmul(o_lat, ltorch.transpose(w_v, 1, 2))                 # (H, B * T, v)
-        return self.o(ltorch.reshape(ltorch.permute(ltorch.reshape(y, (H, B, T, v)), (1, 2, 0, 3)),
-                                     (B, T, H * v)))
+        return ltorch.reshape(ltorch.permute(ltorch.reshape(y, (H, B, T, v)), (1, 2, 0, 3)),
+                              (B, T, H * v))
 
     # -- served ------------------------------------------------------------------
     def prefill(self, step, x, state):
@@ -234,30 +235,56 @@ class LatentAttention(nn.Module):
         pool = ltorch.index_put(state[0], (step.page_ids["full"],), blocks)
         return self.expanded(*self.queries(x, where), c, k_rope), (pool,)
 
-    def chunk(self, step, x, state):
-        """Writes the chunk's rows, then attends the whole table: the pages
-        written before (shared prefix pages among them) and its own."""
-        where = step.shared["rope"]
-        c, k_rope = self.latent(x, where)
-        ps = step.page_size
-        blocks = ltorch.reshape(self.rows(c, k_rope), (x.shape[1] // ps, ps, self.cache.row))
-        pool = ltorch.index_put(state[0], (step.chunk_pages["full"],), blocks)
-        return self.absorbed(*self.queries(x, where), pool, step.tables["full"], step.q_pos), (pool,)
+    def _projected(self, x, where):
+        """What the absorbed form needs of the normed tokens x: the queries
+        (q_nope, q_rope) and the pool's rows (B, T, row)."""
+        return self.queries(x, where), self.rows(*self.latent(x, where))
 
-    def _tokens(self, step, x, state, q_pos):
+    def _chunk_rows(self, step, q, rows, state):
+        """Writes a chunk's rows (1, T, row), then attends the whole table: the
+        pages written before (shared prefix pages among them) and its own."""
+        ps = step.page_size
+        blocks = ltorch.reshape(rows, (rows.shape[1] // ps, ps, self.cache.row))
+        pool = ltorch.index_put(state[0], (step.chunk_pages["full"],), blocks)
+        return self.absorbed(*q, pool, step.tables["full"], step.q_pos), (pool,)
+
+    def _token_rows(self, step, q, rows, state, q_pos):
         """decode and verify: every token's row to its page and slot, then the
         absorbed form at the tokens' positions."""
-        where = step.shared["rope"]
-        c, k_rope = self.latent(x, where)
-        tok = ltorch.reshape(self.rows(c, k_rope), (-1, self.cache.row))
+        tok = ltorch.reshape(rows, (-1, self.cache.row))
         pool = ltorch.index_put(state[0], (step.page_of["full"], step.slot_in_page), tok)
-        return self.absorbed(*self.queries(x, where), pool, step.tables["full"], q_pos), (pool,)
+        return self.absorbed(*q, pool, step.tables["full"], q_pos), (pool,)
+
+    def chunk(self, step, x, state):
+        y, state = self._chunk_rows(step, *self._projected(x, step.shared["rope"]), state)
+        return self.o(y), state
 
     def decode(self, step, x, state):
-        return self._tokens(step, x, state, ltorch.reshape(step.pos, (-1, 1)))
+        y, state = self._token_rows(step, *self._projected(x, step.shared["rope"]), state,
+                                    ltorch.reshape(step.pos, (-1, 1)))
+        return self.o(y), state
 
     def verify(self, step, x, state):
-        return self._tokens(step, x, state, step.pos_mat)
+        y, state = self._token_rows(step, *self._projected(x, step.shared["rope"]), state,
+                                    step.pos_mat)
+        return self.o(y), state
+
+    def mixed(self, step, x, state):
+        """A chunk's T rows and after them one row a decode slot, x (1, T + B, D):
+        one operand of the two low-rank paths and of the output projection; the
+        writes and the absorbed attention split, each kind of row through the
+        body its own program runs (``step.chunk``, ``step.decode``)."""
+        T = step.chunk.T
+        B = x.shape[1] - T
+        (q_nope, q_rope), rows = self._projected(x, step.shared["rope"])
+
+        def seqs(a):  # the decode rows, a sequence each: (1, B, ..) -> (B, 1, ..)
+            return ltorch.reshape(a[:, T:], (B, 1) + tuple(a.shape[2:]))
+
+        y_c, state = self._chunk_rows(step.chunk, (q_nope[:, :T], q_rope[:, :T]), rows[:, :T], state)
+        y_d, state = self._token_rows(step.decode, (seqs(q_nope), seqs(q_rope)), rows[:, T:], state,
+                                      ltorch.reshape(step.decode.pos, (-1, 1)))
+        return self.o(ltorch.cat([y_c, ltorch.reshape(y_d, (1, B, y_d.shape[-1]))], 1)), state
 
 
 class Block(nn.Module):
@@ -288,15 +315,17 @@ class Block(nn.Module):
                 h, state = getattr(self.attn, program)(step, self.norm_1(x), state)
             x = x + h
             counted = None
-            if program == "decode" and _obs.enabled():  # a trace-time gate, as in moe.MoEMLP
+            if program in ("decode", "mixed") and _obs.enabled():  # a trace-time gate, as in moe.MoEMLP
                 counted = step.shared.setdefault("counted", [])
-            return x + self.experts(self.norm_2(x), step.shared["live"], counted), state
+            # a mixed program counts its decode rows only, as the decode program does
+            return x + self.experts(self.norm_2(x), step.shared["live"], counted,
+                                    step.shared.get("counted_rows")), state
 
         run.__name__ = program
         return run
 
     prefill, chunk = _served("prefill"), _served("chunk")
-    decode, verify = _served("decode"), _served("verify")
+    decode, verify, mixed = _served("decode"), _served("verify"), _served("mixed")
     del _served
 
 
@@ -350,7 +379,8 @@ class _Served:
     def begin(self, step) -> None:
         """The program's positions: their rope rows and query scale, and which
         of its tokens are no padding (an idle decode slot, a bucket's tail),
-        for the expert layers."""
+        for the expert layers; in a mixed program also which rows are the
+        decode step's, whose routing alone is counted."""
         i32 = dtypes.int32
         if step.program in ("prefill", "chunk"):
             t = ltorch.reshape(prims.iota(step.T, dtype=i32, device=step.last.device), (1, step.T))
@@ -359,6 +389,14 @@ class _Served:
         elif step.program == "decode":
             pos = ltorch.reshape(step.pos, (-1, 1))
             live = ltorch.gt(step.pos, 0)
+        elif step.program == "mixed":  # the chunk's T rows, then one a decode slot
+            chunk, pos_d = step.chunk, step.decode.pos
+            t = ltorch.reshape(prims.iota(chunk.T, dtype=i32, device=pos_d.device), (1, chunk.T))
+            pos = ltorch.cat([chunk.q_pos, ltorch.reshape(pos_d, (1, -1))], 1)
+            live = ltorch.cat([ltorch.reshape(ltorch.le(t, chunk.last), (chunk.T,)),
+                               ltorch.gt(pos_d, 0)], 0)
+            step.shared["counted_rows"] = ltorch.ge(
+                prims.iota(pos.shape[1], dtype=i32, device=pos_d.device), chunk.T)
         else:
             pos = step.pos_mat
             live = ltorch.reshape(ltorch.expand(ltorch.gt(pos[:, :1], 0), tuple(pos.shape)), (-1,))

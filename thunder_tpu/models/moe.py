@@ -256,10 +256,12 @@ class HeldExperts(nn.Module):
             w = w / (ltorch.sum(w, -1, keepdim=True) + 1e-20)
         return idx, w * self.scaling
 
-    def forward(self, x, live=None, counted=None):
+    def forward(self, x, live=None, counted=None, counted_rows=None):
         """x (B, T, D); live (B * T,) bool or None marks the tokens that are no
         padding. With ``counted`` (a list) the layer appends its four
-        ``serving.runner.ROUTING_COUNTERS`` of this call as one (4,) int32."""
+        ``serving.runner.ROUTING_COUNTERS`` of this call as one (4,) int32, of
+        the tokens ``counted_rows`` (B * T,) bool marks where it is given (the
+        decode rows of a program that runs a prompt chunk beside them)."""
         from ..core import dtypes
         from ..core.trace import named_scope
 
@@ -273,6 +275,14 @@ class HeldExperts(nn.Module):
                                                self.held, live=live, n_routed=self.n_routed)
             if counted is not None:
                 i32 = dtypes.int32
+                if counted_rows is not None:
+                    lo, hi = self.held
+                    live = counted_rows if live is None else ltorch.logical_and(live, counted_rows)
+                    here = ltorch.logical_and(here, ltorch.reshape(ltorch.expand(
+                        ltorch.unsqueeze(counted_rows, 1), (N, self.k)), (N * self.k,)))
+                    flat = ltorch.to(ltorch.reshape(idx, (N * self.k,)), dtype=i32)
+                    counts = ltorch.sum(ltorch.to(ltorch.one_hot(
+                        ltorch.where(here, flat - lo, hi - lo), hi - lo + 1)[:, :hi - lo], dtype=i32), 0)
                 routed = (ltorch.full((), N * self.k, dtype=i32, device=xf.device) if live is None
                           else ltorch.sum(ltorch.to(live, dtype=i32)) * self.k)
                 counted.append(ltorch.stack([

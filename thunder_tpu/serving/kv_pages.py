@@ -354,7 +354,7 @@ class PagedKVCache:
                  n_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
                  allocator: Optional[PageAllocator] = None, *,
                  layers: Optional[Sequence] = None, max_batch: int = 0,
-                 prefill_pages: int = 0):
+                 prefill_pages: int = 0, device=None):
         # a plain GPT: every layer owns one pool pair of one shape
         self.layers = (tuple(layers) if layers is not None else
                        (PagedKV(n_kv_heads, head_dim, head_dim),) * n_layer)
@@ -363,6 +363,10 @@ class PagedKVCache:
         self.page_size = page_size
         self.dtype = dtype
         self.max_batch = max_batch
+        # where the state lives. The engine names the weights' device, so that fresh pools are
+        # committed to it as the pools a program returns are: ``jax.jit`` builds an executable
+        # anew for an operand that is committed where it was not
+        self.device = device
         windows = {d.window for d in self.layers if isinstance(d, PagedKV) and d.window}
         if len(windows) > 1:
             raise ValueError(f"window layers of one model must share one window, got {sorted(windows)}")
@@ -385,14 +389,17 @@ class PagedKVCache:
         return max(1, math.ceil(n_tokens / page_size))
 
     def _fresh(self, decl) -> tuple:
+        def zeros(shape, dtype):
+            return jnp.zeros(shape, dtype, device=self.device)
+
         if isinstance(decl, PagedKV):
             n = self.n_window_pages if decl.window else self.n_pages
-            return (jnp.zeros((n, decl.heads, self.page_size, decl.k_dim), self.dtype),
-                    jnp.zeros((n, decl.heads, self.page_size, decl.v_dim), self.dtype))
+            return (zeros((n, decl.heads, self.page_size, decl.k_dim), self.dtype),
+                    zeros((n, decl.heads, self.page_size, decl.v_dim), self.dtype))
         if isinstance(decl, PagedLatent):
-            return (jnp.zeros((self.n_pages, self.page_size, decl.row), self.dtype),)
+            return (zeros((self.n_pages, self.page_size, decl.row), self.dtype),)
         if isinstance(decl, Recurrent):
-            return tuple(jnp.zeros((self.max_batch, *shape), decl.dtype) for shape in decl.shapes)
+            return tuple(zeros((self.max_batch, *shape), decl.dtype) for shape in decl.shapes)
         return ()
 
     def reset_pools(self) -> None:

@@ -215,6 +215,9 @@ class Step:
              for idle slots (they carry pos 0 and a null-page row)
     verify   as decode with K1 tokens a sequence: ``pos_mat`` (B, K1);
              ``page_of[kind]`` and ``slot_in_page`` flat (B * K1,)
+    mixed    a chunk's T rows and after them one row a decode slot: ``chunk``
+             and ``decode``, the two programs' own ``Step``s; ``states`` and
+             ``shared`` are this step's
     """
 
     def __init__(self, program: str, page_size: int, **where):
@@ -261,6 +264,11 @@ class DenseBlock:
         self.cache = PagedKV(cfg.n_query_groups // self.pack, self.pack * cfg.head_size,
                              self.pack * cfg.head_size)
         self.scale = 1.0 / math.sqrt(cfg.head_size)  # of a key head, not of the packed row
+        moe = getattr(block, "moe", None)
+        if moe is not None and moe.cfg.capacity_factor is not None:
+            # an expert's capacity follows from how many rows the program has: decode rows
+            # beside a chunk's would be dropped where alone they are not
+            self.mixed = None
 
     def _qkv(self, step, x):
         return split_qkv_rope(self.block, self.cfg, self.block.norm_1(x),
@@ -311,8 +319,10 @@ class DenseBlock:
         vq = _repeat_kv(v, q_per_kv) if cfg.n_query_groups != cfg.n_head else v
         return self._out(x, cached_sdpa(q, kq, vq, 0), T), (kp, vp)
 
-    def decode(self, step, x, state):
-        q, k, v = self._qkv(step, x)
+    def _decode_rows(self, step, q, k, v, state):
+        """One token a sequence, q (B, n_head, 1, hs), k and v (B, n_query_groups, 1,
+        hs): each token's k/v to its page and slot, then attention over the
+        sequence's pages. Returns (y (B, n_head, 1, hs), new state)."""
         k_tok, v_tok = self._tokens(k, v)
         kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
         vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
@@ -322,26 +332,51 @@ class DenseBlock:
             y = ltorch.paged_attention(ltorch.reshape(q4, (B, H, D)), kp, vp, table, seq_lens, scale)
             return ltorch.reshape(y, (B, H, 1, y.shape[-1]))
 
-        y = self._paged(attend, q, kp, vp, step.tables["full"], step.seq_lens)
-        return self._out(x, y, 1), (kp, vp)
+        return self._paged(attend, q, kp, vp, step.tables["full"], step.seq_lens), (kp, vp)
 
-    def chunk(self, step, x, state):
-        """The chunk WRITES its pages first and then attends the whole table
-        with per-query coverage k_pos <= start_pos + t, so it sees every
-        previously written page — including pages shared from the prefix
-        cache (copy-on-write sharing; the chunk itself only ever writes
-        UNSHARED pages, because shared coverage always ends at or before
-        the chunk start). Pad tokens past ``step.last`` on the final chunk
-        write garbage K/V into reserved-but-unused page slots; every real
-        query masks them out by position, and decode overwrites each slot
-        before seq_lens ever admits it."""
+    def _chunk_rows(self, step, q, k, v, state):
+        """A chunk's rows, q (1, n_head, T, hs): the chunk WRITES its pages first
+        and then attends the whole table with per-query coverage k_pos <=
+        start_pos + t, so it sees every previously written page — including
+        pages shared from the prefix cache (copy-on-write sharing; the chunk
+        itself only ever writes UNSHARED pages, because shared coverage always
+        ends at or before the chunk start). Pad tokens past ``step.last`` on
+        the final chunk write garbage K/V into reserved-but-unused page slots;
+        every real query masks them out by position, and decode overwrites
+        each slot before seq_lens ever admits it."""
         ps = step.page_size
-        q, k, v = self._qkv(step, x)
         k_rows, v_rows = self._rows(k, v)
         kp = ltorch.index_put(state[0], (step.chunk_pages["full"],), _page_blocks(k_rows, ps))
         vp = ltorch.index_put(state[1], (step.chunk_pages["full"],), _page_blocks(v_rows, ps))
         y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"], step.q_pos)
-        return self._out(x, y, x.shape[1]), (kp, vp)
+        return y, (kp, vp)
+
+    def decode(self, step, x, state):
+        y, state = self._decode_rows(step, *self._qkv(step, x), state)
+        return self._out(x, y, 1), state
+
+    def chunk(self, step, x, state):
+        y, state = self._chunk_rows(step, *self._qkv(step, x), state)
+        return self._out(x, y, x.shape[1]), state
+
+    def mixed(self, step, x, state):
+        """A chunk's T rows and, after them, one row a decode slot, x (1, T + B,
+        D): ONE operand of the norm, the q/k/v projection, the rope, the output
+        projection and the tail, so the block's weights are read once for both.
+        Only the writes and the attention split, each kind of row through the
+        body its own program runs (``step.chunk``, ``step.decode``: the two
+        programs' ``Step``s). The two write to different pages: a decode row
+        to its own sequence's, an idle one to the null page."""
+        T = step.chunk.T
+        q, k, v = self._qkv(step, x)
+
+        def seqs(a):  # the decode rows, a sequence each: (1, H, B, hs) <-> (B, H, 1, hs)
+            return ltorch.permute(a, (2, 1, 0, 3))
+
+        y_c, state = self._chunk_rows(step.chunk, q[:, :, :T], k[:, :, :T], v[:, :, :T], state)
+        y_d, state = self._decode_rows(step.decode, seqs(q[:, :, T:]), seqs(k[:, :, T:]),
+                                       seqs(v[:, :, T:]), state)
+        return self._out(x, ltorch.cat([y_c, seqs(y_d)], 2), x.shape[1]), state
 
     def verify(self, step, x, state):
         """Writes k/v for ALL k+1 tokens at positions pos..pos+k. Rollback is
@@ -370,7 +405,8 @@ class DenseGPT:
     def begin(self, step) -> None:
         """Rope rows for the program's positions, into ``step.shared``.
         Decode and verify clamp positions past the table: those slots'
-        logits are garbage and the accept rule never commits them."""
+        logits are garbage and the accept rule never commits them. A mixed
+        program's rows are gathered by position, all of them at once."""
         from ..core import prims
 
         gpt, n_elem = self.gpt, self.cfg.rope_n_elem
@@ -380,6 +416,10 @@ class DenseGPT:
         elif step.program == "chunk":
             cos = prims.dynamic_slice(cos_t, (step.start_pos, 0), (step.T, n_elem))
             sin = prims.dynamic_slice(sin_t, (step.start_pos, 0), (step.T, n_elem))
+        elif step.program == "mixed":  # (T + B, n_elem): the chunk's rows, then the decode rows'
+            rows = ltorch.cat([ltorch.reshape(step.chunk.q_pos, (-1,)),
+                               ltorch.clamp(step.decode.pos, max=self.max_positions - 1)], 0)
+            cos, sin = clang.take(cos_t, rows, 0), clang.take(sin_t, rows, 0)
         else:
             pos = step.pos if step.program == "decode" else step.pos_mat
             B = pos.shape[0]
@@ -410,6 +450,9 @@ class PagedGPTRunner:
         self.page_kinds = tuple(k for k in ("full", "window") if any(
             isinstance(layer.cache, (PagedKV, PagedLatent)) and layer.cache.kind == k
             for layer in self.model.layers))
+        # the chunk program takes the decode step's rows too where EVERY layer can run both
+        # kinds of rows through its weights at once (a ``mixed`` beside chunk and decode)
+        self.mixes = all(callable(getattr(layer, "mixed", None)) for layer in self.model.layers)
 
         def prefill(params, idx, page_ids, state, last_pos, slot):
             with functional_params(gpt, params):
@@ -419,9 +462,9 @@ class PagedGPTRunner:
             with functional_params(gpt, params):
                 return self._forward_decode(toks, state, tables, pos)
 
-        def chunk_prefill(params, idx, table_rows, state, start_pos, last_rel, slot):
+        def chunk_prefill(params, idx, table_rows, state, start_pos, last_rel, slot, rows=None):
             with functional_params(gpt, params):
-                return self._forward_chunk(idx, table_rows, state, start_pos, last_rel, slot)
+                return self._forward_chunk(idx, table_rows, state, start_pos, last_rel, slot, rows)
 
         def verify(params, toks, state, tables, pos):
             with functional_params(gpt, params):
@@ -434,9 +477,14 @@ class PagedGPTRunner:
         # the calling convention, not a knob: every call site passes the
         # cache's state and rebinds the returned one on its next line, so
         # the state is given up (its position in each signature above)
+        # where a sequence's decode rows run now in the decode program and now beside a chunk's
+        # rows, both programs round at every op: by default XLA keeps a fused chain's
+        # intermediates in float32, which chains it fuses follows from the shapes, and a row
+        # would get other bits in one program than in the other (a greedy token at a near-tie)
+        both = {"round_every_op": True} if self.mixes else {}
         self.prefill_cfn = _annotated(_jit(prefill, donated_argnums=(3,)), "serve_prefill")
-        self.decode_cfn = _annotated(_jit(decode, donated_argnums=(2,)), "serve_decode")
-        self.chunk_cfn = _annotated(_jit(chunk_prefill, donated_argnums=(3,)),
+        self.decode_cfn = _annotated(_jit(decode, donated_argnums=(2,), **both), "serve_decode")
+        self.chunk_cfn = _annotated(_jit(chunk_prefill, donated_argnums=(3,), **both),
                                     "serve_chunk_prefill")
         self.verify_cfn = _annotated(_jit(verify, donated_argnums=(2,)), "serve_verify")
 
@@ -469,6 +517,26 @@ class PagedGPTRunner:
         return self.model.head(x_last)[:, 0], state
 
     # -- decode -----------------------------------------------------------
+    def _decode_step(self, tables, pos) -> Step:
+        step = Step("decode", self.page_size, tables=self._by_kind(tables), pos=pos)
+        step.page_of, step.slot_in_page = _token_pages(step.tables, pos, self.page_size)
+        step.seq_lens = pos + 1  # attention covers the token being written
+        step.live = ltorch.gt(pos, 0)
+        return step
+
+    @staticmethod
+    def _counted(step):
+        """What the layers counted of the step (``ROUTING_COUNTERS``), summed
+        over them, as a tuple the program appends to what it returns: empty
+        where none counted (bus off at trace time, or no layer routes)."""
+        counted = step.shared.get("counted")
+        if not counted:
+            return ()
+        total = counted[0]
+        for c in counted[1:]:
+            total = total + c
+        return (total,)
+
     def _forward_decode(self, toks, state, tables, pos):
         """toks (Bcap, 1) current tokens; tables: for each page kind the
         (Bcap, n_pages_max) int32 page table; pos (Bcap,) int32 — each
@@ -476,23 +544,13 @@ class PagedGPTRunner:
         pos 0 and a null-page row). Returns (logits (Bcap, V), new state), and
         after them the (4,) int32 ``ROUTING_COUNTERS`` of the step where the
         layers counted them (bus on at trace time)."""
-        step = Step("decode", self.page_size, tables=self._by_kind(tables), pos=pos)
+        step = self._decode_step(tables, pos)
         self.model.begin(step)
-        step.page_of, step.slot_in_page = _token_pages(step.tables, pos, self.page_size)
-        step.seq_lens = pos + 1  # attention covers the token being written
-        step.live = ltorch.gt(pos, 0)
         x, state = self._run_layers(step, self.model.embed(toks), state)
-        logits = self.model.head(x[:, -1])
-        counted = step.shared.get("counted")
-        if not counted:
-            return logits, state
-        total = counted[0]
-        for c in counted[1:]:
-            total = total + c
-        return logits, state, total
+        return (self.model.head(x[:, -1]), state) + self._counted(step)
 
     # -- chunked prefill --------------------------------------------------
-    def _forward_chunk(self, idx, table_rows, state, start_pos, last_rel, slot):
+    def _forward_chunk(self, idx, table_rows, state, start_pos, last_rel, slot, rows=None):
         """idx (1, Cb) one page-aligned chunk of a prompt (Cb a multiple of
         page_size); table_rows: for each page kind the sequence's FULL
         (1, n_pages_max) page table; start_pos scalar int32 (multiple of
@@ -500,7 +558,17 @@ class PagedGPTRunner:
         int32 — the true last prompt token RELATIVE to the chunk (only
         meaningful on the final chunk; earlier chunks' logits are discarded
         by the scheduler); slot as in prefill. Returns (logits (1, V), new
-        state)."""
+        state).
+
+        With ``rows`` — the decode step's ``(toks, tables, pos)`` as
+        ``_forward_decode`` takes them — the decode rows ride in this program
+        (``self.mixes``): every layer runs its ``mixed`` over the chunk's T rows
+        and the Bcap decode rows as one operand of its weights, the head takes
+        the chunk's last row and the decode rows together, and the program
+        returns (chunk logits (1, V), decode logits (Bcap, V), new state) and
+        the decode rows' routing counters as the decode program does. Idle
+        slots are what they are there, so the same executable serves a chunk
+        with no live decode row."""
         from ..core import dtypes, prims
 
         B, T = idx.shape  # B == 1
@@ -508,16 +576,27 @@ class PagedGPTRunner:
         tables = self._by_kind(table_rows)
         step = Step("chunk", ps, T=T, tables=tables, start_pos=start_pos, last=last_rel,
                     slot=slot)
-        self.model.begin(step)
+        if rows is None:
+            self.model.begin(step)
         step.chunk_pages = {
             kind: ltorch.reshape(prims.dynamic_slice(
                 row, (0, ltorch.floor_divide(start_pos, ps)), (1, T // ps)), (T // ps,))
             for kind, row in tables.items()}
         step.q_pos = ltorch.reshape(
             prims.iota(T, dtype=dtypes.int32, device=idx.device) + start_pos, (1, T))
-        x, state = self._run_layers(step, self.model.embed(idx), state)
-        x_last = prims.dynamic_slice(x, (0, last_rel, 0), (B, 1, x.shape[-1]))
-        return self.model.head(x_last)[:, 0], state
+        if rows is None:
+            x, state = self._run_layers(step, self.model.embed(idx), state)
+            x_last = prims.dynamic_slice(x, (0, last_rel, 0), (B, 1, x.shape[-1]))
+            return self.model.head(x_last)[:, 0], state
+        toks, dec_tables, pos = rows
+        n = toks.shape[0]
+        both = Step("mixed", ps, chunk=step, decode=self._decode_step(dec_tables, pos))
+        self.model.begin(both)
+        x = self.model.embed(ltorch.cat([idx, ltorch.reshape(toks, (1, n))], 1))
+        x, state = self._run_layers(both, x, state)
+        x_last = prims.dynamic_slice(x, (0, last_rel, 0), (1, 1, x.shape[-1]))
+        logits = self.model.head(ltorch.cat([x_last, x[:, T:]], 1))[0]  # (1 + Bcap, V)
+        return (logits[:1], logits[1:], state) + self._counted(both)
 
     # -- speculative verify -----------------------------------------------
     def _forward_verify(self, toks, state, tables, pos):

@@ -178,13 +178,6 @@ def _sample_step(logits, seeds, pos, temps):
     return _sample_tokens(logits, seeds, pos + 1, temps)[:, None]
 
 
-def _upload(host: np.ndarray) -> jax.Array:
-    """A copy of ``host`` on the device. A copy, because the engine goes on
-    writing its packed host arrays while the step that was given them is in
-    flight, and the CPU backend's arrays share the memory they were made from."""
-    return jnp.asarray(np.array(host))
-
-
 class ServingEngine:
     """Continuous-batching inference over a models.litgpt.GPT (or MoEGPT), or
     over any model whose ``serving()`` gives its layers (serving/runner.py):
@@ -302,13 +295,16 @@ class ServingEngine:
                     "draft_gpt= (speculative decoding) cannot serve a model with window "
                     "layers: verify writes k+1 positions and window pages are taken for one "
                     "position a step, so the others would be written to no page")
+        self.params = {k: p.data for k, p in gpt.named_parameters()}
+        # what the programs are given beside the weights goes to the weights' device, committed
+        # to it as what the programs return is (``_upload``)
+        self._device = next(iter(next(iter(self.params.values())).devices()))
         self.cache = PagedKVCache(
             len(layers), n_pages, page_size, n_kv_heads=0, head_dim=0, dtype=dtype, layers=layers,
-            max_batch=max_batch,
+            max_batch=max_batch, device=self._device,
             # the most pages one prefill program writes: a whole chunk, or its bucket
             prefill_pages=max(chunk_tokens, self.ladder.bucket_for(chunk_tokens)) // page_size)
         self.window = self.cache.window
-        self.params = {k: p.data for k, p in gpt.named_parameters()}
         self._sampler = jax.jit(_sample_tokens)
         self._step_sampler = jax.jit(_sample_step)
 
@@ -338,7 +334,7 @@ class ServingEngine:
             self.draft_runner = PagedGPTRunner(draft_gpt, page_size=page_size)
             self.draft_cache = PagedKVCache(
                 dcfg.n_layer, n_pages, page_size, n_kv_heads=0, head_dim=0, dtype=dtype,
-                allocator=self.cache.allocator,
+                allocator=self.cache.allocator, device=self._device,
                 layers=[layer.cache for layer in self.draft_runner.model.layers])
             self.draft_params = {k: p.data
                                  for k, p in draft_gpt.named_parameters()}
@@ -362,6 +358,15 @@ class ServingEngine:
         self._pt_dirty = True
         self._slots: List[Optional[_Request]] = [None] * max_batch
         self._inflight: Optional[_Step] = None  # the plain decode path's step in flight
+        # a chunk's program takes the decode step's rows too, and in a pass with a chunk due
+        # the step rides in it (one read of the weights for both), where every layer of the
+        # model can run both kinds of rows at once and the decode path is the plain one; the
+        # rows of a chunk dispatch no step rides in are idle slots, all of them
+        self._mixes = self.runner.mixes and draft_gpt is None
+        self._idle_rows = (
+            self._upload(self._toks[:, None]),
+            tuple(self._upload(self._page_tables) for _ in self.runner.page_kinds),
+            self._upload(self._pos)) if self._mixes else None
 
         self._pending: deque = deque()        # interactive lane (admits first)
         self._pending_batch: deque = deque()  # batch lane (preemptible)
@@ -664,8 +669,8 @@ class ServingEngine:
             with _obs_runtime.phase("engine:admit"):
                 self._maybe_preempt_for_slo()
                 self._admit()
-            self._advance_prefills()
-            self._decode()
+            if not self._advance_prefills():  # else the decode step rode in a chunk's program
+                self._decode()
 
     def _admit(self) -> None:
         while True:
@@ -1080,39 +1085,46 @@ class ServingEngine:
             return
         self._activate(req, slot, pos=L, tok=tok0)
 
-    def _advance_prefills(self) -> None:
+    def _advance_prefills(self) -> bool:
         """Run queued prefill chunks under the per-iteration token budget.
         At least one chunk always runs when any is pending (progress even
         when a single chunk exceeds the budget); chunks from multiple
-        requests share the budget in slot order."""
-        if not self._chunking:
-            return
+        requests share the budget in slot order. Returns whether this pass's
+        decode step rode in a chunk's program (``_run_chunk``): the first
+        dispatch that finds live rows takes them, so a pass makes one step."""
+        rode = False
         spent = 0
         for slot in sorted(self._chunking):
             req = self._chunking[slot]
             while spent < self.prefill_budget:
                 with self._prefill_phase(req):
                     try:
-                        n_toks, logits = self._run_chunk(req, slot)
+                        n_toks, logits, carried = self._run_chunk(req, slot, ride=not rode)
                     except Exception as e:
-                        del self._chunking[slot]
-                        self._fail(req, e)
-                        if self._drop_lost_pools(e):
-                            return  # no chunking sequence is left
+                        # a failed dispatch that carried a step has dropped lost pools already
+                        if self._chunking.pop(slot, None) is not None:
+                            self._fail(req, e)
+                        if self._drop_lost_pools(e) or not self._chunking:
+                            return rode  # no chunking sequence is left
                         break
+                    rode = rode or carried
                     spent += n_toks
                     if req.chunk_pos >= len(req.prompt_eff):
                         del self._chunking[slot]
                         self._finish_chunked(req, slot, logits)
                         break
             if spent >= self.prefill_budget:
-                return
+                break
+        return rode
 
-    def _run_chunk(self, req: _Request, slot: int):
+    def _run_chunk(self, req: _Request, slot: int, ride: bool = False):
         """One page-aligned chunk of req's effective prompt: write K/V pages,
         attend everything written so far (shared prefix pages included).
-        Returns (tokens_spent, logits) — logits only meaningful when this
-        was the final chunk."""
+        Returns (tokens_spent, logits, carried) — logits only meaningful when
+        this was the final chunk. Where the engine mixes and ``ride`` allows,
+        the pass's decode step is dispatched in the same program (``carried``):
+        its rows share every read of the weights with the chunk's, and the step
+        before it is fetched and committed behind it as ``_decode`` does."""
         ps = self.page_size
         L_eff = len(req.prompt_eff)
         start = req.chunk_pos
@@ -1140,21 +1152,40 @@ class ServingEngine:
             self._recurrent_reset()
         obs_on = _obs.enabled()
         t0 = time.perf_counter()
+        live = self._live_slots() if ride and self._mixes else []
+        logits = None
+
+        def program(toks, tables, pos):  # the chunk's, with the rows of a decode step
+            nonlocal logits
+            logits, step_logits, state, *counted = self.runner.chunk_cfn(
+                self.params, jnp.asarray(idx), rows, self.cache.state, *where, (toks, tables, pos))
+            self.cache.rebind(state)
+            return step_logits, counted
+
         with (_obs_runtime.step_span("serve_prefill", request=req.request_id,
                                      bucket=cb, prompt_len=L_eff, chunk=True,
                                      start=start)
               if obs_on else _NULL):
-            logits, state = self.runner.chunk_cfn(
-                self.params, jnp.asarray(idx), rows, self.cache.state, *where)
-            self.cache.rebind(state)
-            if self.draft_cache is not None:
-                _, dstate = self.draft_runner.chunk_cfn(
-                    self.draft_params, jnp.asarray(idx), rows, self.draft_cache.state, *where)
-                self.draft_cache.rebind(dstate)
+            if live:
+                failed = self._decode_step(live, program)
+                if failed is not None:
+                    raise failed
+            elif self._mixes:
+                program(*self._idle_rows)
+            else:
+                logits, state = self.runner.chunk_cfn(
+                    self.params, jnp.asarray(idx), rows, self.cache.state, *where)
+                self.cache.rebind(state)
+                if self.draft_cache is not None:
+                    _, dstate = self.draft_runner.chunk_cfn(
+                        self.draft_params, jnp.asarray(idx), rows, self.draft_cache.state, *where)
+                    self.draft_cache.rebind(dstate)
         req.chunk_pos = min(start + cb, L_eff)
         if self.window:
             self._window_trim(req, req.chunk_pos)
         if obs_on:
+            if live:
+                _obs_metrics.record_serve("decode_mixed")
             _obs_metrics.record_serve("prefill_tokens", delta=n_real)
             self._record_chunk_pages(np.asarray([start]), cb)
             dur_ms = (time.perf_counter() - t0) * 1e3
@@ -1162,7 +1193,7 @@ class ServingEngine:
             _obs_trace.trace_event(req.trace_id, "prefill_chunk",
                                    request=req.request_id, dur_ms=dur_ms,
                                    start=start, tokens=n_real)
-        return cb, logits
+        return cb, logits, bool(live)
 
     def _finish_chunked(self, req: _Request, slot: int, logits) -> None:
         """Final chunk done: register the prompt's full pages in the prefix
@@ -1235,14 +1266,25 @@ class ServingEngine:
         self._pos[i] = 0
         self._pt_dirty = True
 
+    def _upload(self, host: np.ndarray) -> jax.Array:
+        """A copy of ``host`` on the weights' device. A copy, because the engine
+        goes on writing its packed host arrays while the step that was given
+        them is in flight, and the CPU backend's arrays share the memory they
+        were made from. Committed to the device, as what a program returns is:
+        ``jax.jit`` builds an executable anew for an operand that is committed
+        where it was not, so a program fed a token from the host and one fed
+        the sampler's output must look alike, or the first chunk that meets
+        live rows compiles."""
+        return jax.device_put(np.array(host), self._device)
+
     def _upload_packed_state(self) -> None:
         # page tables / seeds / temps only change at slot (un)assignment;
         # re-upload them then, not per token (pos/toks change every step)
         if self._pt_dirty:
-            self._pt_dev = ((_upload(self._page_tables), _upload(self._win_tables))
-                            if self.window else (_upload(self._page_tables),))
-            self._seeds_dev = _upload(self._seeds)
-            self._temps_dev = _upload(self._temps)
+            self._pt_dev = ((self._upload(self._page_tables), self._upload(self._win_tables))
+                            if self.window else (self._upload(self._page_tables),))
+            self._seeds_dev = self._upload(self._seeds)
+            self._temps_dev = self._upload(self._temps)
             self._pt_dirty = False
 
     def _commit(self, i: int, req: _Request, tok: int, t_now: float) -> bool:
@@ -1283,6 +1325,16 @@ class ServingEngine:
         if self.draft_cache is not None and self.spec_k > 0:
             self._spec_decode()
             return
+        live = self._live_slots()
+        if not live:
+            self._land()  # a step with no live sequence is never dispatched
+            return
+        self._decode_step(live, self._decode_program)
+
+    def _live_slots(self) -> List[int]:
+        """The slots the next decode step carries: every sequence that wants a
+        token beyond the one it may have in flight. One whose last token by
+        count is in flight idles from this step on."""
         prev = self._inflight
         live = []
         for i, req in enumerate(self._slots):
@@ -1292,42 +1344,56 @@ class ServingEngine:
             if len(req.tokens) + flying < req.max_new_tokens:
                 live.append(i)
             else:
-                # its last token by count is in flight: it idles from this step on
                 self._rest_slot(i)
-        if not live:
-            self._land()  # a step with no live sequence is never dispatched
-            return
+        return live
+
+    def _decode_program(self, toks, tables, pos):
+        logits, state, *counted = self.runner.decode_cfn(
+            self.params, toks, self.cache.state, tables, pos)
+        self.cache.rebind(state)
+        return logits, counted
+
+    def _decode_step(self, live: List[int], program) -> Optional[Exception]:
+        """Dispatch the decode step of the ``live`` slots through ``program``
+        (``_decode_program``, or a chunk's program that takes the step's rows
+        beside its own: ``_run_chunk``), then fetch and commit the step before
+        it. Returns what a failed dispatch raised (``_dispatch``), else None."""
         # taken out while the next step is dispatched: a failure's clean-up, which
         # lands "the step in flight", then finds none, and prev is settled below
-        self._inflight = None
+        prev, self._inflight = self._inflight, None
         t0 = time.perf_counter()
         with _obs_runtime.phase("engine:upload"):
             if self.window:
                 for i in live:
                     self._window_step(i)
             self._upload_packed_state()
+        step = failed = None
         with (_obs_runtime.step_span("serve_decode", active=len(live))
               if _obs.enabled() else _NULL):
-            step = self._dispatch(live, prev, t0)
+            try:
+                step = self._dispatch(live, prev, t0, program)
+            except Exception as e:
+                failed = e
             fetched = self._fetch(prev)
         self._commit_step(prev, fetched)
         self._inflight = step
+        return failed
 
-    def _dispatch(self, live: List[int], prev: Optional[_Step], t0: float) -> Optional[_Step]:
-        """Enqueue the decode step of the ``live`` slots and its sampler. The
-        token a sequence feeds is the one the sampler produced for it in
-        ``prev``, the step in flight, still on the device; with no step in
-        flight the tokens go up from the host. Returns the step, or None after
-        a failure (every live sequence failed, pages returned)."""
+    def _dispatch(self, live: List[int], prev: Optional[_Step], t0: float, program) -> _Step:
+        """Enqueue the decode step of the ``live`` slots — ``program(toks,
+        tables, pos)`` runs it, rebinds the cache and returns (logits (max_batch,
+        V), what the program counted) — and its sampler. The token a sequence
+        feeds is the one the sampler produced for it in ``prev``, the step in
+        flight, still on the device; with no step in flight the tokens go up
+        from the host. After a failure every live sequence has failed, its
+        pages returned, and the exception goes on to the caller."""
         phase = _obs_runtime.phase
         try:
             with phase("engine:upload"):
-                toks = prev.nxt if prev is not None else _upload(self._toks[:, None])
-                pos = _upload(self._pos)
+                toks = prev.nxt if prev is not None else self._upload(self._toks[:, None])
+                pos = self._upload(self._pos)
             with phase("engine:dispatch"):
-                logits, state, *counted = self.runner.decode_cfn(
-                    self.params, toks, self.cache.state, self._pt_dev, pos)
-                self.cache.rebind(state)
+                logits, counted = program(toks, self._pt_dev, pos)
                 # the NEXT token's position is pos+1 (this step wrote pos)
                 nxt = self._step_sampler(logits, self._seeds_dev, pos, self._temps_dev)
                 # the host's copy starts as the sampler ends, not when the fetch asks
@@ -1342,7 +1408,7 @@ class ServingEngine:
                 self._fail(self._slots[i], e)
                 self._clear_slot(i)
             self._drop_lost_pools(e)
-            return None
+            raise
         self.decode_steps += 1
         if _obs.enabled():
             # what the step read and held follows from the positions it was given
