@@ -1,0 +1,8 @@
+"""Device self time a decode-program run, inside the decode program, of the XLA ops whose trace
+symbols ran under `head` and `embed` (the final norm, the head, the loss where there is one; the
+embedding). The parts add up to reasoning_xla_ms_per_iter."""
+from benchmark.lib import scopes
+
+
+def read(run):
+    return scopes.reasoning_ms_per_iter(run, "head")

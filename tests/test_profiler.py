@@ -262,9 +262,9 @@ def _synthetic_events():
          "args": {"hlo_module": "jit_xla_fusion_7", "hlo_op": "dot.3"}},
         {"ph": "X", "pid": 1, "tid": 9, "ts": 120.0, "dur": 40.0, "name": "tanh.1",
          "args": {"hlo_module": "jit_xla_fusion_7", "hlo_op": "tanh.1"}},
-        # joined by scoped-op-name substring (the TPU metadata path)
+        # joined through the op map: the instruction's scope path in its executable's HLO
         {"ph": "X", "pid": 1, "tid": 9, "ts": 170.0, "dur": 30.0,
-         "name": "fusion.9", "args": {"tf_op": "tt_optimizer/add", "hlo_op": "fusion.9"}},
+         "name": "fusion.9", "args": {"hlo_module": "jit_tt_train_step", "hlo_op": "fusion.9"}},
         # a collective and a transfer
         {"ph": "X", "pid": 1, "tid": 9, "ts": 210.0, "dur": 25.0,
          "name": "all-reduce.2", "args": {"hlo_module": "jit_xla_fusion_7"}},
@@ -278,6 +278,26 @@ def _synthetic_events():
     ]
 
 
+_STEP_HLO = """HloModule jit_tt_train_step, is_scheduled=true
+
+%fused_computation.9 (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  ROOT %add.1 = f32[8]{0} add(%p0, %p0), metadata={op_name="jit(tt_train_step)/tt_optimizer/add"}
+}
+
+ENTRY %main.1 (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %dot.4 = f32[8]{0} dot(%a, %a), metadata={op_name="jit(tt_train_step)/tt_fwd_bwd/jit(xla_fusion_12)/xla_fusion_12/bwd/mlp/dot_general"}
+  ROOT %fusion.9 = f32[8]{0} fusion(%dot.4), kind=kLoop, calls=%fused_computation.9, metadata={op_name="jit(tt_train_step)/tt_optimizer/add"}
+}
+"""
+
+
+def _step_op_map():
+    ops = obs_profiler.parse_hlo_text(_STEP_HLO)
+    return {ops.module: ops}
+
+
 class TestAttribution:
     def test_synthetic_breakdown(self):
         regions = {
@@ -286,7 +306,8 @@ class TestAttribution:
             "tt_optimizer": {"bsym_ids": [], "flops": 0.0, "bytes": 0,
                              "executor": "trainstep", "kind": "compute"},
         }
-        prof = obs_profiler.attribute(_synthetic_events(), region_map=regions, n_steps=1)
+        prof = obs_profiler.attribute(_synthetic_events(), region_map=regions, n_steps=1,
+                                      op_map=_step_op_map())
         assert prof.total_device_us == pytest.approx(215.0)  # host event excluded
         assert prof.regions["xla_fusion_7"].us == pytest.approx(165.0)
         assert prof.regions["tt_optimizer"].us == pytest.approx(30.0)
@@ -304,16 +325,43 @@ class TestAttribution:
         assert prof.exposed_comms_us == pytest.approx(40.0)
         assert prof.overlap_frac == pytest.approx(0.0)
 
-    def test_longest_region_name_wins(self):
+    @pytest.mark.parametrize("hlo_op, wanted", [
+        ("dot.4", "xla_fusion_12"),     # exact segment: never the prefix xla_fusion_1
+        ("fusion.9", "tt_optimizer"),   # no fusion region on the path: the phase
+        ("copy.7", "tt_train_step"),    # not in the map: the module's own name
+    ])
+    def test_finest_registered_region_on_the_path_wins(self, hlo_op, wanted):
         regions = {
             "xla_fusion_1": {"bsym_ids": [], "flops": 0.0, "bytes": 0},
             "xla_fusion_12": {"bsym_ids": [], "flops": 0.0, "bytes": 0},
+            "tt_fwd_bwd": {"bsym_ids": [], "flops": 0.0, "bytes": 0, "level": 1},
+            "tt_optimizer": {"bsym_ids": [], "flops": 0.0, "bytes": 0, "level": 1},
+            "tt_train_step": {"bsym_ids": [], "flops": 0.0, "bytes": 0, "level": 2},
         }
+        evs = [{"ph": "X", "pid": 1, "tid": 9, "ts": 0.0, "dur": 10.0, "name": hlo_op,
+                "args": {"hlo_module": "jit_tt_train_step(42)", "hlo_op": hlo_op}}]
+        prof = obs_profiler.attribute(evs, region_map=regions, op_map=_step_op_map())
+        assert set(prof.regions) == {wanted}
+
+    def test_a_name_in_an_events_text_is_no_join(self):
+        # the substring join is gone: a region's name inside an event's name or metadata
+        # puts nothing on it
+        regions = {"xla_fusion_12": {"bsym_ids": [], "flops": 0.0, "bytes": 0}}
         evs = [{"ph": "X", "pid": 1, "tid": 9, "ts": 0.0, "dur": 10.0,
-                "name": "fusion", "args": {"tf_op": "step/xla_fusion_12/dot"}}]
-        prof = obs_profiler.attribute(evs, region_map=regions)
-        assert "xla_fusion_12" in prof.regions
-        assert "xla_fusion_1" not in prof.regions
+                "name": "fusion", "args": {"tf_op": "step/xla_fusion_12/dot", "hlo_op": "fusion"}}]
+        prof = obs_profiler.attribute(evs, region_map=regions, op_map={})
+        assert not prof.regions and prof.unattributed_us == pytest.approx(10.0)
+
+    def test_a_store_served_region_goes_by_the_name_it_was_registered_under(self):
+        # the publishing process called it xla_fusion_3; here it runs as xla_fusion_7
+        text = _STEP_HLO.replace("jit_tt_train_step", "jit_xla_fusion_3").replace(
+            "tt_train_step", "xla_fusion_3")
+        ops = obs_profiler.parse_hlo_text(text, region="xla_fusion_7")
+        regions = {"xla_fusion_7": {"bsym_ids": [], "flops": 0.0, "bytes": 0}}
+        evs = [{"ph": "X", "pid": 1, "tid": 9, "ts": 0.0, "dur": 10.0, "name": "dot.4",
+                "args": {"hlo_module": "jit_xla_fusion_3", "hlo_op": "dot.4"}}]
+        prof = obs_profiler.attribute(evs, region_map=regions, op_map={ops.module: ops})
+        assert set(prof.regions) == {"xla_fusion_7"}
 
 
 # ---------------------------------------------------------------------------

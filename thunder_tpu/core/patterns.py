@@ -16,7 +16,7 @@ from typing import Any, Callable, Optional, Sequence
 
 from .proxies import Proxy, variableify
 from .symbol import BoundSymbol
-from .trace import TraceCtx, from_trace, tracectx
+from .trace import TraceCtx, from_trace, rebinding, tracectx
 
 
 class MatchState:
@@ -160,7 +160,7 @@ class Pattern:
         for state, indices in matches:
             old_out_proxies = [p for p in trace.bound_symbols[indices[-1]].flat_proxy_outs()]
             with tracectx(new_trace) as trc:
-                with trc.push_scope() as recorded:
+                with trc.push_scope() as recorded, rebinding(trace.bound_symbols[indices[-1]]):
                     new_out = builder(**state.bindings)
             new_out_proxies = [p for p in _flat(new_out) if isinstance(p, Proxy)]
             for old, new in zip(old_out_proxies, new_out_proxies):

@@ -15,7 +15,7 @@ from typing import Any, Callable, Optional, Sequence
 from .baseutils import SymbolInterface, check
 from .codeutils import ContextInterner, prettyprint, flat_proxies
 from .proxies import Proxy, variableify
-from .trace import SCOPE_TAG, get_tracectx
+from .trace import SCOPE_TAG, get_tracectx, scope_of
 
 
 class _ThreadLocalStack(threading.local):
@@ -134,7 +134,7 @@ class Symbol(SymbolInterface):
         if self._bind_postprocess is not None:
             self._bind_postprocess(bsym)
         if trc.labels:
-            bsym.tags.add(SCOPE_TAG + trc.labels[-1])
+            bsym.tags.add(SCOPE_TAG + "/".join(trc.labels))
         trc.add_bound_symbol(bsym)
         return out
 
@@ -245,8 +245,10 @@ class BoundSymbol:
             return ""
         return "  # " + "; ".join(f"{t.name}: {t.type_string()}" for t in ts[:3])
 
-    def exec_lines(self, idx: int, interner: ContextInterner) -> list[str]:
-        """Executable form: impl callables interned into the namespace."""
+    def exec_lines(self, idx: int, interner: ContextInterner, *, scoped: bool = False) -> list[str]:
+        """Executable form: impl callables interned into the namespace. With ``scoped``
+        (the callable is traced into a compiled program: trace.python_callable) a
+        symbol runs under the ``jax.named_scope`` of its ``named_scope`` path."""
         from .prims import PrimIDs
 
         if self.sym.id == PrimIDs.RETURN:
@@ -266,9 +268,8 @@ class BoundSymbol:
         )
         key = interner.intern(fn, f"{_ident(self.sym.name)}_")
         line = f"{self._fmt_output(interner)} = {key}({self._fmt_args(interner)})"
-        scope = next((t[len(SCOPE_TAG):] for t in self.tags
-                      if isinstance(t, str) and t.startswith(SCOPE_TAG)), None)
-        if scope is not None:  # trace.named_scope
+        scope = scope_of(self) if scoped else None
+        if scope is not None:
             import jax
 
             return [f"with {interner.intern(jax.named_scope, 'named_scope_')}({scope!r}):", f"  {line}"]

@@ -38,11 +38,12 @@ SCOPE_TAG = "scope:"
 
 @contextmanager
 def named_scope(name: str):
-    """Every symbol bound inside runs under ``jax.named_scope(name)`` in the
-    compiled program, so that a device profile can put an op down to the part
-    of the model it belongs to. The name rides on the bound symbol as the tag
-    ``scope:<name>`` (symbol.BoundSymbol.exec_lines reads it), which every pass
-    keeps. Outside a trace it does nothing."""
+    """Every symbol bound inside runs under ``jax.named_scope`` of the open
+    scopes' path (``attn/rope``) where it is traced into a compiled program,
+    so that a device profile can put an op down to the part of the model it
+    belongs to (observability/profiler.py: ``op_scopes``). The path rides on
+    the bound symbol as the tag ``scope:<path>`` (symbol.BoundSymbol.exec_lines
+    reads it), which every pass keeps. Outside a trace it does nothing."""
     trc = get_tracectx()
     if trc is None:
         yield
@@ -52,6 +53,33 @@ def named_scope(name: str):
         yield
     finally:
         trc.labels.pop()
+
+
+def scope_of(bsym) -> Optional[str]:
+    """The ``named_scope`` path a bound symbol carries (``bwd/attn/rope``), or None."""
+    for t in bsym.tags:
+        if isinstance(t, str) and t.startswith(SCOPE_TAG):
+            return t[len(SCOPE_TAG):]
+    return None
+
+
+@contextmanager
+def rebinding(bsym, pass_name: str = ""):
+    """For a transform that binds new symbols in place of ``bsym``: what is bound
+    inside carries ``bsym``'s scope path, below ``pass_name`` (``bwd``,
+    ``recompute``) where one is given. A ``bsym`` without a scope leaves the
+    open scopes as they are, so the members of a composite that is being taken
+    apart inherit; with a ``pass_name`` they become the pass alone."""
+    trc = get_tracectx()
+    path = scope_of(bsym)
+    if trc is None or not (path or pass_name):
+        yield
+        return
+    saved, trc.labels = trc.labels, [p for p in (pass_name, path) if p]
+    try:
+        yield
+    finally:
+        trc.labels = saved
 
 
 class TraceProvenance:
@@ -83,7 +111,7 @@ class TraceCtx(baseutils.TraceInterface):
         # thunder/core/jit_ext.py:2149)
         self.side_effects: list = []
         # names of the open `named_scope`s, innermost last: a symbol bound
-        # under one carries it as a tag and runs under jax.named_scope
+        # under them carries their path as a tag and is traced under jax.named_scope
         self.labels: list[str] = []
 
     # ---- naming ----
@@ -160,12 +188,22 @@ class TraceCtx(baseutils.TraceInterface):
         return self.python()
 
     # ---- compiling to a callable ----
-    def python_callable(self, **ctx_overrides) -> Callable:
-        """exec() the printed source with op implementations bound in the namespace."""
+    def python_callable(self, *, scoped: Optional[bool] = None, **ctx_overrides) -> Callable:
+        """exec() the printed source with op implementations bound in the namespace.
+
+        ``scoped`` says whether the callable is traced into a compiled program: only
+        then does a symbol enter the ``jax.named_scope`` of its ``named_scope`` path,
+        which is a trace-time name and must cost a program that runs op by op on the
+        host nothing. A fusion region's callable is (executors/xlaex.py); any other is
+        where it is built under an outer ``jax.jit`` (TrainStep's whole step)."""
+        if scoped is None:
+            import jax
+
+            scoped = not jax.core.trace_ctx.is_top_level()
         interner = ContextInterner()
         lines: list[str] = []
         for i, bsym in enumerate(self.bound_symbols):
-            lines.extend(bsym.exec_lines(i, interner))
+            lines.extend(bsym.exec_lines(i, interner, scoped=scoped))
         sig = ", ".join(p.name for p in self.args)
         fname = self.name_of_fn()
         body = [f"  {ln}" for ln in lines] or ["  pass"]

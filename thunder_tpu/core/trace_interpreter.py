@@ -11,7 +11,7 @@ from typing import Any, Callable, Optional
 from .prims import PrimIDs
 from .proxies import Proxy
 from .symbol import BoundSymbol
-from .trace import TraceCtx, from_trace, tracectx
+from .trace import TraceCtx, from_trace, rebinding, tracectx
 
 
 class TraceSubstitutionProcessor:
@@ -65,11 +65,14 @@ class TraceSubstitutionProcessor:
                 margs = self.lookup(bsym.args)
                 mkwargs = self.lookup(bsym.kwargs)
                 scope_start = len(new_trace.bound_symbols)
-                replaced = self.visitor(bsym, margs, mkwargs)
-                if replaced is None:
-                    out = bsym.sym(*margs, **mkwargs)
-                else:
-                    out = replaced
+                # what the visitor binds in the symbol's place (autocast's casts), and the
+                # members of a composite bound again, carry the symbol's named_scope path
+                with rebinding(bsym):
+                    replaced = self.visitor(bsym, margs, mkwargs)
+                    if replaced is None:
+                        out = bsym.sym(*margs, **mkwargs)
+                    else:
+                        out = replaced
                 if bsym.tags:
                     # tags (e.g. RECOMPUTE_IN_BACKWARD) survive the rewrite —
                     # losing them silently disables activation checkpointing

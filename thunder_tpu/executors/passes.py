@@ -12,7 +12,7 @@ from typing import Sequence
 from ..analysis import manager as _an
 from ..core.prims import PrimIDs
 from ..core.symbol import BoundSymbol, OpTags
-from ..core.trace import TraceCtx, from_trace, tracectx
+from ..core.trace import TraceCtx, from_trace, rebinding, tracectx
 from ..extend import Executor, FusionExecutor, get_always_executors
 from ..observability import events as _obs
 from ..observability import metrics as _obs_metrics
@@ -50,9 +50,10 @@ def transform_for_execution(trace: TraceCtx, executors: Sequence[Executor],
             if ex.can_execute(bsym):
                 info = ex.implmap.get(bsym.sym.id)
                 if info is not None and info.execution_transform is not None:
-                    # re-trace the replacement into prims/ops of the executor
+                    # re-trace the replacement into prims/ops of the executor,
+                    # under the named_scope path of the symbol it replaces
                     new_trc = TraceCtx(None)
-                    with tracectx(new_trc):
+                    with tracectx(new_trc), rebinding(bsym):
                         info.execution_transform(*bsym.args, **bsym.kwargs)
                     for sub in new_trc.bound_symbols:
                         lower(sub)

@@ -107,7 +107,7 @@ class XLAFusionExecutor(FusionExecutor):
         name = f"xla_fusion_{self._fusion_counter - 1}"
         subtrace._name = name
 
-        raw_fn = subtrace.python_callable()
+        raw_fn = subtrace.python_callable(scoped=True)  # traced by the jax.jit below
 
         def scoped_fn(*args):
             # the HLO traced under this scope carries the fusion name, so

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
+from ..core.trace import named_scope
 from ..ops import ltorch
 
 
@@ -227,15 +228,16 @@ def _apply_rope(x, cos, sin, n_elem: int):
         return x
     hs = x.shape[-1]
     h = n_elem // 2
-    x1 = x[..., :h]
-    x2 = x[..., h:n_elem]
-    c = cos[..., :h]
-    s = sin[..., :h]
-    out1 = x1 * c - x2 * s
-    out2 = x2 * c + x1 * s
-    if n_elem < hs:
-        return ltorch.cat([out1, out2, x[..., n_elem:]], -1)
-    return ltorch.cat([out1, out2], -1)
+    with named_scope("rope"):
+        x1 = x[..., :h]
+        x2 = x[..., h:n_elem]
+        c = cos[..., :h]
+        s = sin[..., :h]
+        out1 = x1 * c - x2 * s
+        out2 = x2 * c + x1 * s
+        if n_elem < hs:
+            return ltorch.cat([out1, out2, x[..., n_elem:]], -1)
+        return ltorch.cat([out1, out2], -1)
 
 
 class Block(nn.Module):
@@ -248,16 +250,19 @@ class Block(nn.Module):
         self.mlp = {"LLaMAMLP": LLaMAMLP, "GptNeoxMLP": GptNeoxMLP}[cfg.mlp_class_name](cfg, dtype)
 
     def forward(self, x, cos, sin):
-        return self.tail(x, self.attn(self.norm_1(x), cos, sin))
+        with named_scope("attn"):
+            h = self.attn(self.norm_1(x), cos, sin)
+        return self.tail(x, h)
 
     def tail(self, x, h):
         """The block's output from its input ``x`` and its attention's output
         ``h``: the residuals and the MLP. What a caller that runs the attention
-        itself (the cached and the paged engines) asks of a block."""
-        if self.cfg.parallel_residual:
-            return x + h + self.mlp(self.norm_2(x))
-        x = x + h
-        return x + self.mlp(self.norm_2(x))
+        itself (the cached and the paged engines) asks of a block. The attention's
+        residual is the last of ``attn``; the norm, the MLP and its residual are ``mlp``."""
+        with named_scope("attn"):
+            x_h = x + h
+        with named_scope("mlp"):
+            return x_h + self.mlp(self.norm_2(x if self.cfg.parallel_residual else x_h))
 
 
 class GPT(nn.Module):
@@ -276,15 +281,17 @@ class GPT(nn.Module):
         from ..transforms import remat
 
         B, T = idx.shape
-        cos, sin = rope_slice(self.cos, self.sin, T)
-        x = self.wte(idx)
+        with named_scope("attn/rope"):
+            cos, sin = rope_slice(self.cos, self.sin, T)
+        with named_scope("embed"):
+            x = self.wte(idx)
         for block in self.h:
             if self.cfg.activation_checkpoint:
                 x = remat.checkpoint(block)(x, cos, sin)
             else:
                 x = block(x, cos, sin)
-        x = self.ln_f(x)
-        return self.lm_head(x)
+        with named_scope("head"):
+            return self.lm_head(self.ln_f(x))
 
 
 class GPTForCausalLM(nn.Module):
@@ -298,9 +305,10 @@ class GPTForCausalLM(nn.Module):
     def forward(self, idx, targets):
         logits = self.gpt(idx)
         B, T, V = logits.shape
-        return ltorch.cross_entropy(
-            ltorch.reshape(logits, (B * T, V)), ltorch.reshape(targets, (B * T,))
-        )
+        with named_scope("head"):
+            return ltorch.cross_entropy(
+                ltorch.reshape(logits, (B * T, V)), ltorch.reshape(targets, (B * T,))
+            )
 
 
 def rope_slice(cos_full, sin_full, T: int):

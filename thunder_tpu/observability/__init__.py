@@ -82,12 +82,14 @@ from .telemetry import (  # noqa: F401
 from .profiler import (  # noqa: F401
     DeviceProfile,
     attribute,
+    op_scopes,
     profile,
     profile_steps,
     region_info,
     regions,
     register_region,
     resolve,
+    scope_of,
 )
 
 

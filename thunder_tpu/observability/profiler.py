@@ -4,19 +4,27 @@ The host side of the pipeline is already legible (events.py spans); this
 module makes the *device* side legible. ``profile_steps`` wraps
 ``jax.profiler.trace`` around N step calls, parses the captured
 trace-event stream (the perfetto JSON export — stdlib-parseable, available
-on CPU and TPU), and joins device durations back to trace symbols through
-the **region registry**: every fusion region the executor passes form is
-registered here as ``name → {bsym ids, flops, bytes}``, and the region
-name reaches the device events two ways —
+on CPU and TPU), and joins device durations back to trace symbols in two steps:
 
-  * the region's jitted callable is named after it (executors/xlaex.py
-    sets ``__name__ = "xla_fusion_N"``), so its HLO module is
-    ``jit_xla_fusion_N`` and every device event carries that in
-    ``args.hlo_module`` (the join that works even on the CPU backend);
-  * the region's computation is traced under ``jax.named_scope(name)``,
-    so on TPU the op metadata (``tf_op``/``long_name``/scope paths)
-    carries the name even when regions are inlined into one whole-step
-    program (TrainStep).
+  * **the op map** (``op_scopes``). A device event is ``(module, instruction)``:
+    the program it ran in and the HLO instruction it is. What a profile does
+    *not* carry is where the instruction came from (XLA's fusions keep their
+    numbers and an event is named by the instruction's HLO text; measured on
+    the v5e, PR 27). The executable knows: ``Compiled.as_text()`` prints the
+    optimised module with ``metadata={op_name="jit(f)/../xla_fusion_0/bwd/mlp/dot_general"}``
+    on every instruction, those inside fused computations included. The path is
+    made of the ``jax.named_scope``s the op was traced under: the fusion region
+    (executors/xlaex.py), the step's phases (``tt_fwd_bwd``, ``tt_optimizer``),
+    and the ``core.trace.named_scope`` path of the trace symbol (``attn/rope``,
+    below ``bwd`` or ``recompute`` for what autodiff bound). Every executable
+    the program holds is registered here, weakly, where it is installed; its
+    text is parsed on the first request and kept. Nothing runs at compile time
+    or in a step.
+  * **the region registry**: every fusion region the executor passes form is
+    registered as ``name → {bsym ids, flops, bytes}``; ``attribute`` puts an
+    event on the finest registered region on its instruction's path, and on
+    the region its module is named after (``jit_xla_fusion_N``) where the map
+    does not know the instruction.
 
 The result is a ``DeviceProfile``: per-region device time split into
 compute / collective / transfer, model FLOPs/bytes per region (the
@@ -34,6 +42,9 @@ import os
 import re
 import tempfile
 import threading
+import time
+import weakref
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterable, Optional
 
@@ -55,10 +66,9 @@ def register_region(name: str, *, bsym_ids: Iterable = (), executor: str = "",
 
     ``level`` is the attribution granularity: 0 = fusion region (finest),
     1 = program phase (tt_fwd_bwd / tt_optimizer), 2 = whole program
-    (tt_train_step). When several registered names match one device event
-    (a TPU op carries its full scope path AND its enclosing jit module
-    name), the smallest level wins — time lands on the finest region that
-    claims it."""
+    (tt_train_step). Where several registered names lie on the scope path
+    of one device event's instruction, the smallest level wins — time lands
+    on the finest region that claims it."""
     info = {
         "name": name,
         "bsym_ids": [str(b) for b in bsym_ids],
@@ -76,8 +86,15 @@ def register_trace_regions(trace) -> int:
     """Walk an execution trace and register every fusion-executor region
     (any executor's — xla, pallas, ...) under its region name, with the
     flops/bytes cost of its subsymbols. Called by executors/passes.py after
-    the fusion passes; returns the number of regions registered."""
+    the fusion passes; returns the number of regions registered. A region
+    that runs as an executable of its own (not inlined into an outer
+    ``jax.jit``) is also one ``op_scopes`` reads."""
+    import jax
+
+    from ..compile_service.parallel_compile import _region_avals
+
     n = 0
+    standalone = jax.core.trace_ctx.is_top_level()
     for bsym in getattr(trace, "bound_symbols", ()):
         ex = getattr(bsym.sym, "executor", None)
         if ex is None or not getattr(ex, "is_fusion_executor", lambda: False)():
@@ -93,6 +110,9 @@ def register_trace_regions(trace) -> int:
             bytes=cost["bytes"],
             kind="compute",
         )
+        if standalone and hasattr(bsym.impl, "_prewarmed"):
+            register_executable(bsym.impl, _RegionCompiled(_region_avals(bsym)),
+                                region=bsym.sym.name)
         n += 1
     return n
 
@@ -118,6 +138,285 @@ def resolve(name: str) -> list[str]:
 def clear_regions() -> None:
     with _REGISTRY_LOCK:
         _REGIONS.clear()
+        _EXECUTABLES.clear()
+
+
+# ---------------------------------------------------------------------------
+# the op map: (module, instruction) -> the scope path its trace symbols ran under
+# ---------------------------------------------------------------------------
+
+# the scope names that are a part of a model: `attn/kv_write` is `kv_write`, and
+# `attn/rope` is `attn` (`rope` is a finer name, for a reader of the path)
+PARTS = frozenset({
+    "embed", "attn", "kv_write", "mlp", "head",                           # models/litgpt.py, serving/runner.py
+    "mamba", "gmu", "window_attn", "full_attn", "cross_attn",             # models/sambay.py
+    "mla_attn", "moe_router", "moe_experts", "shared_expert",             # models/latent_moe.py, moe.py
+})
+PASSES = ("fwd", "bwd", "recompute", "optimizer")
+UNSCOPED = "unscoped"
+_REGION_SEG = re.compile(r"^(xla_fusion_\d+|tt_optimizer)$")
+_WRAPPER_SEG = re.compile(r"^\w*\((.*)\)$")  # jit(f), pjit(f), transpose(jvp(f))
+# segments JAX and the step put on a path that name no part of a model
+_STRUCTURAL_SEG = re.compile(r"^(tt_fwd_bwd|tt_train_step|shard_map|pmap|while|body|cond|branch_\d+_fun|"
+                             r"closed_call|custom_[jv][jv]p_call|core_call|remat)$")
+_DOT_OPCODES = ("dot", "convolution")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_OPCODE = re.compile(r"([\w\-]+)\(")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_RUN_ID = re.compile(r"\(\d+\)$")  # jit_tt_train_step(1234567): one run of the module
+
+
+def path_scope(op_name: str) -> tuple:
+    """``(region, pass, part)`` of one ``op_name``. ``region`` is the last
+    ``xla_fusion_<n>`` / ``tt_optimizer`` segment (``""`` without one); ``pass``
+    is ``recompute`` or ``bwd`` where the path says so (also for what JAX's own
+    ``checkpoint`` and transposition name), ``optimizer`` under ``tt_optimizer``,
+    else ``fwd``; ``part`` is the innermost segment that is one of ``PARTS``,
+    else the outermost scope name a model gave, else ``optimizer`` or ``unscoped``."""
+    return _path_scope(op_name)[:3]
+
+
+def _path_scope(op_name: str) -> tuple:
+    """:func:`path_scope` and, fourth, the model's own scope names on the path
+    (``attn/rope``: what a reader of the finer names wants; ``""`` without any)."""
+    # where XLA merged two instructions it joins their paths with ";": the first is the one kept
+    segs = op_name.split(";")[0].split("/")[:-1]  # the last is the primitive's name
+    region, pass_name, part, named = "", "fwd", None, []
+    for seg in segs:
+        if _REGION_SEG.match(seg):
+            region = seg
+        if seg == "tt_optimizer":
+            pass_name = "optimizer"
+        elif seg in ("recompute", "rematted_computation", "checkpoint"):
+            pass_name = "recompute"
+        elif seg == "bwd" or seg.startswith("transpose("):
+            pass_name = pass_name if pass_name == "recompute" else "bwd"
+        elif not (_WRAPPER_SEG.match(seg) or _REGION_SEG.match(seg) or _STRUCTURAL_SEG.match(seg)):
+            named.append(seg)
+            if seg in PARTS:
+                part = seg
+    if part is None:
+        part = named[0] if named else ("optimizer" if pass_name == "optimizer" else UNSCOPED)
+    return region, pass_name, part, "/".join(named)
+
+
+class OpMap(dict):
+    """``{instruction: op_name}`` of one executable, from its optimised HLO text.
+    The members of a fused computation are listed under their fusion, as
+    ``<fusion>/<member>``; an instruction without ``op_name`` metadata maps to ``""``.
+    ``region`` is the name the executable was registered under (a region served by
+    the artifact store prints the publishing process's name in its paths),
+    ``others`` the further executables that print the same module name."""
+
+    def __init__(self, module: str, region: str = ""):
+        super().__init__()
+        self.module, self.region = module, region
+        self.members: dict = {}  # fusion instruction -> [(member key, opcode)]
+        self.users: dict = {}    # instruction without an op_name -> the instructions that read it
+        self.others: list = []
+        self._scopes: dict = {}
+
+    def holding(self, instructions) -> "OpMap":
+        """Of the executables of this module name, the one that holds most of
+        ``instructions`` (this one where there is no other)."""
+        if not self.others:
+            return self
+        names = list(instructions)
+        return max([self, *self.others], key=lambda m: sum(n in m for n in names))
+
+    def scope(self, instruction: str) -> tuple:
+        """``(region, pass, part)`` of one instruction. A fusion goes to the scope
+        that holds most of its ``dot`` / ``convolution`` members, else to its own
+        ``op_name``'s (to most of its members' where that names no part); one whose
+        members span more than one part reads as a pair, ``mlp+optimizer``, the
+        part it goes to first. An instruction XLA gave no ``op_name`` at all (a
+        copy it inserted, the ``-start`` / ``-done`` of a prefetch) goes where the
+        first instruction that reads its result goes. An unknown instruction is
+        ``("", "fwd", "unscoped")``."""
+        return self._found(instruction)[:3]
+
+    def finer(self, instruction: str) -> str:
+        """The model's own scope names on the path of the scope the instruction goes
+        to, ``attn/rope`` (``""`` for none): the finer names below a part."""
+        return self._found(instruction)[3]
+
+    def _found(self, instruction: str, hops: int = 4) -> tuple:
+        found = self._scopes.get(instruction)
+        if found is None:
+            found = self._scope(instruction)
+            if found[2] == UNSCOPED and self.get(instruction) == "" and hops:
+                by_reader = (self._found(user, hops - 1) for user in self.users.get(instruction, ()))
+                found = next((sc for sc in by_reader if sc[2] != UNSCOPED), found)
+            self._scopes[instruction] = found
+        return found
+
+    def _scope(self, instruction: str) -> tuple:
+        own = _path_scope(self.get(instruction, ""))
+        members = [(_path_scope(self[key]), opcode) for key, opcode in self.members.get(instruction, ())
+                   if self[key]]
+        if members:
+            dots = Counter(sc for sc, opcode in members if opcode in _DOT_OPCODES)
+            spread = Counter(sc for sc, _ in members if sc[2] != UNSCOPED)
+            if dots:
+                own = dots.most_common(1)[0][0]
+            elif own[2] == UNSCOPED and spread:
+                own = spread.most_common(1)[0][0]
+            other = Counter()
+            for sc, n in spread.items():
+                if sc[2] != own[2]:
+                    other[sc[2]] += n
+            if other:
+                own = own[:2] + (f"{own[2]}+{other.most_common(1)[0][0]}", own[3])
+        return (self.region or own[0],) + own[1:]
+
+
+def parse_hlo_text(text: str, region: str = "") -> OpMap:
+    """The :class:`OpMap` of one ``Compiled.as_text()``."""
+    head = re.match(r"HloModule ([\w.\-]+)", text)
+    ops = OpMap(head.group(1) if head else "", region)
+    computations: dict = {}  # computation -> [(instruction, opcode, op_name)]
+    callers: list = []       # (instruction, called computation) of fusions
+    current = None
+    for line in text.splitlines():
+        if current is None:
+            m = _HLO_COMPUTATION.match(line)
+            if m:
+                current = computations.setdefault(m.group(1), [])
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        rest = line[m.end():]
+        if rest.startswith("("):  # a tuple type: skip to its closing bracket
+            depth = 0
+            for i, ch in enumerate(rest):
+                depth += ch == "("
+                depth -= ch == ")"
+                if depth == 0:
+                    break
+            rest = rest[i + 1:]
+        else:
+            rest = rest.partition(" ")[2]
+        opcode = _HLO_OPCODE.match(rest.lstrip())
+        opcode = opcode.group(1) if opcode else ""
+        name = _HLO_OP_NAME.search(line)
+        current.append((m.group(1), opcode, name.group(1) if name else ""))
+        for operand in _HLO_OPERAND.findall(rest):
+            if operand in ops.users:
+                ops.users[operand].append(m.group(1))
+        if not name and opcode not in ("parameter", "constant"):
+            ops.users[m.group(1)] = []
+        if opcode == "fusion":
+            called = _HLO_CALLS.search(line)
+            if called:
+                callers.append((m.group(1), called.group(1)))
+    fused = {called for _, called in callers}
+    for comp, instructions in computations.items():
+        if comp not in fused:
+            for instruction, _, op_name in instructions:
+                ops[instruction] = op_name
+    for fusion, called in callers:
+        for instruction, opcode, op_name in computations.get(called, ()):
+            key = f"{fusion}/{instruction}"
+            ops[key] = op_name
+            ops.members.setdefault(fusion, []).append((key, opcode))
+    return ops
+
+
+class _RegionCompiled:
+    """How ``op_scopes`` gets a fusion region's executable: the one the compile
+    service installed (compiled ahead or served by the store), else the region's
+    ``jax.jit`` lowered again for its inputs, which JAX's caches serve where the
+    first call compiled it."""
+
+    def __init__(self, avals):
+        self.avals = avals
+        self.lowered_again = None
+
+    def __call__(self, impl):
+        if impl._prewarmed is not None:
+            return impl._prewarmed
+        if self.lowered_again is None and self.avals is not None:
+            self.lowered_again = impl.jitted.lower(*self.avals).compile()
+        return self.lowered_again
+
+
+@dataclass
+class _Held:
+    holder: Any              # weakref to what holds the executable
+    compiled_of: Callable    # holder -> Compiled or None
+    region: str
+    compiled: Any = None     # the Compiled the map was parsed from
+    ops: Optional[OpMap] = None
+
+
+_EXECUTABLES: dict = {}  # id of the _Held -> _Held, dropped as its holder dies
+# what the last ``op_scopes()`` cost: seconds to get the executables (a road that
+# compiles shows here), seconds to print and parse their text, and their count
+op_scopes_cost = {"executables": 0, "same_name": 0, "get_s": 0.0, "parse_s": 0.0}
+
+
+def register_executable(holder, compiled_of: Callable, *, region: str = "") -> None:
+    """Make the executable ``compiled_of(holder)`` one of those ``op_scopes``
+    reads, for as long as ``holder`` lives. Costs a weak reference; the
+    executable is asked for, printed and parsed on the first request only."""
+    held = _Held(None, compiled_of, region)
+    # no lock in the callback: it may run wherever the collector does
+    held.holder = weakref.ref(holder, lambda _, key=id(held): _EXECUTABLES.pop(key, None))
+    _EXECUTABLES[id(held)] = held
+
+
+def op_scopes() -> dict:
+    """``{module name: OpMap}`` for every executable the process holds: each
+    fusion region that runs as a program of its own and each ``TrainStep``.
+    The module name is the one the executable's text gives (``jit_tt_train_step``:
+    what the profiler's ``XLA Modules`` line and an event's ``hlo_module`` carry).
+    Two executables that print the same name, as two served by the artifact store
+    may, are both kept: ``OpMap.others`` / ``OpMap.holding``."""
+    out: dict = {}
+    cost = {"executables": 0, "same_name": 0, "get_s": 0.0, "parse_s": 0.0}
+    for h in list(_EXECUTABLES.values()):
+        holder = h.holder()
+        if holder is None:
+            continue
+        t0 = time.perf_counter()
+        try:
+            compiled = h.compiled_of(holder)
+        except Exception as e:  # a map is missing; the profile is still read
+            _obs.event("profile_error", stage="op_scopes", region=h.region, error=str(e)[:200])
+            compiled = None
+        t1 = time.perf_counter()
+        cost["get_s"] += t1 - t0
+        if compiled is None:
+            continue
+        if h.ops is None or h.compiled is not compiled:
+            h.compiled, h.ops = compiled, parse_hlo_text(compiled.as_text(), h.region)
+            cost["parse_s"] += time.perf_counter() - t1
+        cost["executables"] += 1
+        h.ops.others = []  # of this request: a map is kept, who shares its name may change
+        first = out.setdefault(h.ops.module, h.ops)
+        if first is not h.ops:
+            cost["same_name"] += 1
+            first.others.append(h.ops)
+    op_scopes_cost.update(cost)
+    return out
+
+
+def scope_of(module: str, instruction: str) -> tuple:
+    """``(region, pass, part)`` of one device event: ``module`` as the ``XLA
+    Modules`` line or ``hlo_module`` names it (a run id in brackets is dropped),
+    ``instruction`` as ``hlo_op`` or the event's HLO text names it.
+    ``("", "fwd", "unscoped")`` where no executable holds it."""
+    ops = op_scopes().get(_RUN_ID.sub("", module))
+    if ops is None:
+        return "", "fwd", UNSCOPED
+    return ops.holding([instruction]).scope(instruction)
 
 
 # ---------------------------------------------------------------------------
@@ -345,22 +644,42 @@ def _overlap_len(start: float, end: float, union: Iterable) -> float:
     return total
 
 
+def _region_of(ev_name: str, args: dict, reg: dict, op_map: dict) -> Optional[str]:
+    """The registered region one device event belongs to: the finest (lowest
+    ``level``) registered name that is a segment of its instruction's scope path
+    (or the name its executable was registered under), by the op map; where the
+    map does not hold the instruction, the region its module is named after."""
+    mod = _RUN_ID.sub("", args.get("hlo_module", ""))
+    ops = op_map.get(mod)
+    if ops is not None:
+        instruction = args.get("hlo_op") or ev_name
+        ops = ops.holding([instruction])
+        if instruction in ops:
+            segs = [_WRAPPER_SEG.sub(r"\1", seg) for seg in ops[instruction].split("/")[:-1]]
+            # innermost first, so that of two regions of one level the inner one wins
+            on_path = [n for n in (ops.scope(instruction)[0], *reversed(segs)) if n in reg]
+            if on_path:
+                return min(on_path, key=lambda n: reg[n].get("level", 0))
+    mod = mod[4:] if mod.startswith("jit_") else mod
+    return mod if mod in reg else None
+
+
 def attribute(trace_events: list[dict], *, region_map: Optional[dict] = None,
-              n_steps: int = 1) -> DeviceProfile:
+              n_steps: int = 1, op_map: Optional[dict] = None) -> DeviceProfile:
     """Join device-side trace events to registered regions.
 
-    Join per event: every registered region name occurring in the event's
-    name / op metadata / ``hlo_module`` (minus its ``jit_`` prefix) is a
-    candidate; the finest (lowest ``level``) candidate wins, longest name
-    breaking ties — so a TPU op that carries both its scope path
-    (``...tt_fwd_bwd/xla_fusion_3/dot``) and its enclosing module
-    (``jit_tt_train_step``) lands on ``xla_fusion_3``, while a CPU event
-    with only the module name still attributes to the whole-step bucket.
-    Unmatched device events fall into the unattributed bucket."""
+    Join per event: an event is ``(hlo_module, hlo_op)``, and ``op_map``
+    (``op_scopes()`` unless given) says which scope path that instruction was
+    traced under; the finest (lowest ``level``) registered region on the path
+    wins — an op of the whole-step program whose path is
+    ``jit(tt_train_step)/tt_fwd_bwd/xla_fusion_3/bwd/mlp/dot_general`` lands on
+    ``xla_fusion_3``, one under ``tt_optimizer`` alone on that. An event whose
+    instruction no executable holds attributes to the region its module is
+    named after (``jit_xla_fusion_3``), and else falls into the unattributed
+    bucket."""
     reg = region_map if region_map is not None else regions()
-    # (level, -len) order: finest granularity first, longest name first so
-    # "xla_fusion_12" wins over "xla_fusion_1"
-    names_ranked = sorted(reg, key=lambda n: (reg[n].get("level", 0), -len(n)))
+    if op_map is None:
+        op_map = op_scopes()
 
     proc_names: dict = {}
     thread_names: dict = {}
@@ -401,15 +720,7 @@ def attribute(trace_events: list[dict], *, region_map: Optional[dict] = None,
         if cat == "compute" and ts is not None and dur > 0:
             compute_ivals.setdefault(ev.get("pid"), []).append((ts, ts + dur))
 
-        target = None
-        hay = name + " " + " ".join(str(v) for v in args.values())
-        mod = args.get("hlo_module", "")
-        if mod.startswith("jit_"):
-            hay += " " + mod[4:]
-        for rname in names_ranked:
-            if rname in hay:
-                target = rname
-                break
+        target = _region_of(name, args, reg, op_map)
         if cat != "compute":
             comms_slices.append((ev.get("pid"), ts, dur, target))
         if target is None:

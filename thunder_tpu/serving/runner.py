@@ -52,6 +52,7 @@ import functools
 import math
 from typing import Optional
 
+from ..core.trace import named_scope
 from ..inference import cached_sdpa, split_qkv_rope
 from ..observability import runtime as _obs_runtime
 from ..ops import clang, ltorch
@@ -137,6 +138,13 @@ def _page_blocks(x, ps: int):
     return ltorch.permute(ltorch.reshape(x, (Hkv, T // ps, ps, D)), (1, 0, 2, 3))
 
 
+def _write_pages(pool, page_ids, rows, ps: int):
+    """pool[page_ids] = the page blocks of ``rows`` (1, Hkv, T, D): a prompt's or a chunk's
+    whole pages."""
+    with named_scope("kv_write"):
+        return ltorch.index_put(pool, (page_ids,), _page_blocks(rows, ps))
+
+
 def _write_tokens(pool, page, slot, tok):
     """pool[page[n], :, slot[n]] = tok[n] on a head-major pool
     (P, Hkv, ps, D): page/slot (N,) int32, tok (N, Hkv, D). One joint
@@ -149,10 +157,11 @@ def _write_tokens(pool, page, slot, tok):
         return ltorch.reshape(ltorch.expand(ltorch.reshape(v, shape), (N, Hkv)),
                               (N * Hkv,))
 
-    heads = prims.iota(Hkv, dtype=dtypes.int32, device=tok.device)
-    return ltorch.index_put(
-        pool, (rows(page, (N, 1)), rows(heads, (1, Hkv)), rows(slot, (N, 1))),
-        ltorch.reshape(tok, (N * Hkv, D)))
+    with named_scope("kv_write"):
+        heads = prims.iota(Hkv, dtype=dtypes.int32, device=tok.device)
+        return ltorch.index_put(
+            pool, (rows(page, (N, 1)), rows(heads, (1, Hkv)), rows(slot, (N, 1))),
+            ltorch.reshape(tok, (N * Hkv, D)))
 
 
 def quantize_for_serving(gpt, mode: Optional[str]):
@@ -292,12 +301,12 @@ class DenseBlock:
         y = attend(_spread_queries(q, self.pack, g), kp, vp, table, where, self.scale)
         return _own_lanes(y, self.pack, g)
 
-    def _out(self, x, y, T: int):
-        """y (B, n_head, T, hs) attention output -> the block's output."""
+    def _proj(self, x, y, T: int):
+        """y (B, n_head, T, hs) attention output -> what the attention adds to ``x``."""
         cfg = self.cfg
         y = ltorch.reshape(ltorch.permute(y, (0, 2, 1, 3)),
                            (x.shape[0], T, cfg.n_head * cfg.head_size))
-        return self.block.tail(x, self.block.attn.proj(y))
+        return self.block.attn.proj(y)
 
     def prefill(self, step, x, state):
         """Dense causal attention over the padded prompt and page write-out
@@ -311,13 +320,15 @@ class DenseBlock:
         ps = step.page_size
         page_ids = step.page_ids["full"]
         q_per_kv = cfg.n_head // cfg.n_query_groups
-        q, k, v = self._qkv(step, x)
-        k_rows, v_rows = self._rows(k, v)
-        kp = ltorch.index_put(state[0], (page_ids,), _page_blocks(k_rows, ps))
-        vp = ltorch.index_put(state[1], (page_ids,), _page_blocks(v_rows, ps))
-        kq = _repeat_kv(k, q_per_kv) if cfg.n_query_groups != cfg.n_head else k
-        vq = _repeat_kv(v, q_per_kv) if cfg.n_query_groups != cfg.n_head else v
-        return self._out(x, cached_sdpa(q, kq, vq, 0), T), (kp, vp)
+        with named_scope("attn"):
+            q, k, v = self._qkv(step, x)
+            k_rows, v_rows = self._rows(k, v)
+            kp = _write_pages(state[0], page_ids, k_rows, ps)
+            vp = _write_pages(state[1], page_ids, v_rows, ps)
+            kq = _repeat_kv(k, q_per_kv) if cfg.n_query_groups != cfg.n_head else k
+            vq = _repeat_kv(v, q_per_kv) if cfg.n_query_groups != cfg.n_head else v
+            h = self._proj(x, cached_sdpa(q, kq, vq, 0), T)
+        return self.block.tail(x, h), (kp, vp)
 
     def _decode_rows(self, step, q, k, v, state):
         """One token a sequence, q (B, n_head, 1, hs), k and v (B, n_query_groups, 1,
@@ -346,18 +357,22 @@ class DenseBlock:
         each slot before seq_lens ever admits it."""
         ps = step.page_size
         k_rows, v_rows = self._rows(k, v)
-        kp = ltorch.index_put(state[0], (step.chunk_pages["full"],), _page_blocks(k_rows, ps))
-        vp = ltorch.index_put(state[1], (step.chunk_pages["full"],), _page_blocks(v_rows, ps))
+        kp = _write_pages(state[0], step.chunk_pages["full"], k_rows, ps)
+        vp = _write_pages(state[1], step.chunk_pages["full"], v_rows, ps)
         y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"], step.q_pos)
         return y, (kp, vp)
 
     def decode(self, step, x, state):
-        y, state = self._decode_rows(step, *self._qkv(step, x), state)
-        return self._out(x, y, 1), state
+        with named_scope("attn"):
+            y, state = self._decode_rows(step, *self._qkv(step, x), state)
+            h = self._proj(x, y, 1)
+        return self.block.tail(x, h), state
 
     def chunk(self, step, x, state):
-        y, state = self._chunk_rows(step, *self._qkv(step, x), state)
-        return self._out(x, y, x.shape[1]), state
+        with named_scope("attn"):
+            y, state = self._chunk_rows(step, *self._qkv(step, x), state)
+            h = self._proj(x, y, x.shape[1])
+        return self.block.tail(x, h), state
 
     def mixed(self, step, x, state):
         """A chunk's T rows and, after them, one row a decode slot, x (1, T + B,
@@ -368,27 +383,32 @@ class DenseBlock:
         programs' ``Step``s). The two write to different pages: a decode row
         to its own sequence's, an idle one to the null page."""
         T = step.chunk.T
-        q, k, v = self._qkv(step, x)
 
         def seqs(a):  # the decode rows, a sequence each: (1, H, B, hs) <-> (B, H, 1, hs)
             return ltorch.permute(a, (2, 1, 0, 3))
 
-        y_c, state = self._chunk_rows(step.chunk, q[:, :, :T], k[:, :, :T], v[:, :, :T], state)
-        y_d, state = self._decode_rows(step.decode, seqs(q[:, :, T:]), seqs(k[:, :, T:]),
-                                       seqs(v[:, :, T:]), state)
-        return self._out(x, ltorch.cat([y_c, seqs(y_d)], 2), x.shape[1]), state
+        with named_scope("attn"):
+            q, k, v = self._qkv(step, x)
+            y_c, state = self._chunk_rows(step.chunk, q[:, :, :T], k[:, :, :T], v[:, :, :T], state)
+            y_d, state = self._decode_rows(step.decode, seqs(q[:, :, T:]), seqs(k[:, :, T:]),
+                                           seqs(v[:, :, T:]), state)
+            h = self._proj(x, ltorch.cat([y_c, seqs(y_d)], 2), x.shape[1])
+        return self.block.tail(x, h), state
 
     def verify(self, step, x, state):
         """Writes k/v for ALL k+1 tokens at positions pos..pos+k. Rollback is
         free: the scheduler commits only the accepted prefix; rejected
         positions hold stale k/v that the next committed token's write
         replaces before any mask admits it."""
-        q, k, v = self._qkv(step, x)
-        k_tok, v_tok = self._tokens(k, v)
-        kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
-        vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
-        y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"], step.pos_mat)
-        return self._out(x, y, x.shape[1]), (kp, vp)
+        with named_scope("attn"):
+            q, k, v = self._qkv(step, x)
+            k_tok, v_tok = self._tokens(k, v)
+            kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
+            vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
+            y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"],
+                            step.pos_mat)
+            h = self._proj(x, y, x.shape[1])
+        return self.block.tail(x, h), (kp, vp)
 
 
 class DenseGPT:
@@ -407,6 +427,10 @@ class DenseGPT:
         Decode and verify clamp positions past the table: those slots'
         logits are garbage and the accept rule never commits them. A mixed
         program's rows are gathered by position, all of them at once."""
+        with named_scope("attn/rope"):
+            step.shared["cos"], step.shared["sin"] = self._rope_rows(step)
+
+    def _rope_rows(self, step):
         from ..core import prims
 
         gpt, n_elem = self.gpt, self.cfg.rope_n_elem
@@ -426,7 +450,7 @@ class DenseGPT:
             rows = ltorch.reshape(ltorch.clamp(pos, max=self.max_positions - 1), (-1,))
             cos = ltorch.reshape(clang.take(cos_t, rows, 0), (B, 1, -1, n_elem))
             sin = ltorch.reshape(clang.take(sin_t, rows, 0), (B, 1, -1, n_elem))
-        step.shared["cos"], step.shared["sin"] = cos, sin
+        return cos, sin
 
     def embed(self, toks):
         return self.gpt.wte(toks)
@@ -493,6 +517,10 @@ class PagedGPTRunner:
         the order of ``page_kinds``."""
         return dict(zip(self.page_kinds, per_kind))
 
+    def _embed(self, toks):
+        with named_scope("embed"):  # whatever the served model is; `head` likewise, at its calls
+            return self.model.embed(toks)
+
     def _run_layers(self, step, x, state):
         step.states = list(state)
         for i, layer in enumerate(self.model.layers):
@@ -511,15 +539,16 @@ class PagedGPTRunner:
         step = Step("prefill", self.page_size, T=T, page_ids=self._by_kind(page_ids),
                     last=last_pos, slot=slot)
         self.model.begin(step)
-        x, state = self._run_layers(step, self.model.embed(idx), state)
-        # logits at the TRUE last token (the bucket pads past it)
-        x_last = prims.dynamic_slice(x, (0, last_pos, 0), (B, 1, x.shape[-1]))
-        return self.model.head(x_last)[:, 0], state
+        x, state = self._run_layers(step, self._embed(idx), state)
+        with named_scope("head"):  # logits at the TRUE last token (the bucket pads past it)
+            x_last = prims.dynamic_slice(x, (0, last_pos, 0), (B, 1, x.shape[-1]))
+            return self.model.head(x_last)[:, 0], state
 
     # -- decode -----------------------------------------------------------
     def _decode_step(self, tables, pos) -> Step:
         step = Step("decode", self.page_size, tables=self._by_kind(tables), pos=pos)
-        step.page_of, step.slot_in_page = _token_pages(step.tables, pos, self.page_size)
+        with named_scope("kv_write"):
+            step.page_of, step.slot_in_page = _token_pages(step.tables, pos, self.page_size)
         step.seq_lens = pos + 1  # attention covers the token being written
         step.live = ltorch.gt(pos, 0)
         return step
@@ -546,8 +575,10 @@ class PagedGPTRunner:
         layers counted them (bus on at trace time)."""
         step = self._decode_step(tables, pos)
         self.model.begin(step)
-        x, state = self._run_layers(step, self.model.embed(toks), state)
-        return (self.model.head(x[:, -1]), state) + self._counted(step)
+        x, state = self._run_layers(step, self._embed(toks), state)
+        with named_scope("head"):
+            logits = self.model.head(x[:, -1])
+        return (logits, state) + self._counted(step)
 
     # -- chunked prefill --------------------------------------------------
     def _forward_chunk(self, idx, table_rows, state, start_pos, last_rel, slot, rows=None):
@@ -585,18 +616,20 @@ class PagedGPTRunner:
         step.q_pos = ltorch.reshape(
             prims.iota(T, dtype=dtypes.int32, device=idx.device) + start_pos, (1, T))
         if rows is None:
-            x, state = self._run_layers(step, self.model.embed(idx), state)
-            x_last = prims.dynamic_slice(x, (0, last_rel, 0), (B, 1, x.shape[-1]))
-            return self.model.head(x_last)[:, 0], state
+            x, state = self._run_layers(step, self._embed(idx), state)
+            with named_scope("head"):
+                x_last = prims.dynamic_slice(x, (0, last_rel, 0), (B, 1, x.shape[-1]))
+                return self.model.head(x_last)[:, 0], state
         toks, dec_tables, pos = rows
         n = toks.shape[0]
         both = Step("mixed", ps, chunk=step, decode=self._decode_step(dec_tables, pos))
         self.model.begin(both)
-        x = self.model.embed(ltorch.cat([idx, ltorch.reshape(toks, (1, n))], 1))
+        x = self._embed(ltorch.cat([idx, ltorch.reshape(toks, (1, n))], 1))
         x, state = self._run_layers(both, x, state)
-        x_last = prims.dynamic_slice(x, (0, last_rel, 0), (1, 1, x.shape[-1]))
-        logits = self.model.head(ltorch.cat([x_last, x[:, T:]], 1))[0]  # (1 + Bcap, V)
-        return (logits[:1], logits[1:], state) + self._counted(both)
+        with named_scope("head"):
+            x_last = prims.dynamic_slice(x, (0, last_rel, 0), (1, 1, x.shape[-1]))
+            logits = self.model.head(ltorch.cat([x_last, x[:, T:]], 1))[0]  # (1 + Bcap, V)
+            return (logits[:1], logits[1:], state) + self._counted(both)
 
     # -- speculative verify -----------------------------------------------
     def _forward_verify(self, toks, state, tables, pos):
@@ -611,8 +644,10 @@ class PagedGPTRunner:
         pos_mat = ltorch.reshape(pos, (B, 1)) + ltorch.reshape(offs, (1, K1))  # (B, K1)
         step = Step("verify", self.page_size, tables=self._by_kind(tables), pos_mat=pos_mat)
         self.model.begin(step)
-        page_of, slot = _token_pages(step.tables, pos_mat, self.page_size)
-        step.page_of = {k: ltorch.reshape(v, (B * K1,)) for k, v in page_of.items()}
-        step.slot_in_page = ltorch.reshape(slot, (B * K1,))
-        x, state = self._run_layers(step, self.model.embed(toks), state)
-        return self.model.head(x), state  # (B, K1, V)
+        with named_scope("kv_write"):
+            page_of, slot = _token_pages(step.tables, pos_mat, self.page_size)
+            step.page_of = {k: ltorch.reshape(v, (B * K1,)) for k, v in page_of.items()}
+            step.slot_in_page = ltorch.reshape(slot, (B * K1,))
+        x, state = self._run_layers(step, self._embed(toks), state)
+        with named_scope("head"):
+            return self.model.head(x), state  # (B, K1, V)

@@ -168,7 +168,8 @@ def _sample_tokens(logits, seeds, pos, temps):
         sampled = jax.random.categorical(key, l / safe_t)
         return jnp.where(t > 0, sampled, jnp.argmax(l, -1))
 
-    return jax.vmap(one)(logits, seeds, pos, temps).astype(jnp.int32)
+    with jax.named_scope("head"):  # a trace-time name: the sampler is the head's last step
+        return jax.vmap(one)(logits, seeds, pos, temps).astype(jnp.int32)
 
 
 def _sample_step(logits, seeds, pos, temps):

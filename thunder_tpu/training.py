@@ -167,6 +167,11 @@ class TrainStep:
         # tuple so a flip selects/rebuilds instead of silently running stale
         self._mode_cache: dict = {}
         self._active_mode = self._mode_key()
+        # the whole-step executable is one observability.op_scopes() reads (a weak
+        # reference: nothing is asked of the step until a profile is read)
+        from .observability import profiler as _obs_profiler
+
+        _obs_profiler.register_executable(self, TrainStep.compiled)
 
     # every compiled artifact + trace-derived metadata that depends on the
     # module's train/eval mode (the FSDP param gather is shape-only and is
@@ -1029,24 +1034,37 @@ class TrainStep:
     def compile_stats(self):
         return getattr(self, "_vag", None) and self._vag._cs
 
-    def memory_analysis(self):
-        """Compiled-program memory analysis of the last-built step."""
+    def compiled(self):
+        """The executable of the last-built step: the AOT one where the step holds it,
+        else (the distributed road, or a step with no artifact store) the jitted step
+        lowered again for the last batch, which finds the executable the last step ran
+        in JAX's own caches. None before the first step. What ``memory_analysis``
+        and ``observability.op_scopes`` read."""
         if self._jitted is None or getattr(self, "last_batch", None) is None:
             return None
         if isinstance(self._jitted, _CompiledWithFallback):
             compiled = self._jitted._compiled
             if compiled is not None:
-                return compiled.memory_analysis()
+                return compiled
             jitted = self._jitted._jit_fn
             if jitted is None:
                 return None
         else:
             jitted = self._jitted
-        trainable, frozen = self._split_params()
-        tparams = {k: p.data for k, p in trainable.items()}
-        fparams = {k: getattr(p, "data", p) for k, p in frozen.items()}
-        args, kwargs = self.last_batch
-        return jitted.lower(tparams, fparams, self.opt_state, args, kwargs).compile().memory_analysis()
+        again = getattr(self, "_lowered_again", None)
+        if again is None or again[0] is not jitted:
+            trainable, frozen = self._split_params()
+            tparams = {k: p.data for k, p in trainable.items()}
+            fparams = {k: getattr(p, "data", p) for k, p in frozen.items()}
+            args, kwargs = self.last_batch
+            again = self._lowered_again = (
+                jitted, jitted.lower(tparams, fparams, self.opt_state, args, kwargs).compile())
+        return again[1]
+
+    def memory_analysis(self):
+        """Compiled-program memory analysis of the last-built step."""
+        compiled = self.compiled()
+        return None if compiled is None else compiled.memory_analysis()
 
 
 def _dist_global_norm(param_grads: dict, plan):

@@ -23,7 +23,7 @@ from ..core import dtypes, prims
 from ..core.prims import PrimIDs
 from ..core.proxies import NumberProxy, Proxy, TensorProxy, variableify
 from ..core.symbol import BoundSymbol, OpTags, Symbol
-from ..core.trace import TraceCtx, from_trace, tracectx
+from ..core.trace import TraceCtx, from_trace, rebinding, tracectx
 from ..core.transform_common import dce
 from ..common import EpilogueMixin
 from ..ops import clang
@@ -895,6 +895,7 @@ class TapeEntry(NamedTuple):
     outputs: tuple  # mapped flat tensor output proxies
     residuals: tuple
     fallback_impl: Optional[Callable]
+    origin: BoundSymbol  # the forward symbol: its named_scope path names the backward's
 
 
 def _flat_tensors(x) -> tuple:
@@ -1042,7 +1043,9 @@ def forward_and_backward_traces(trace: TraceCtx, *, grad_all_inexact_args: bool 
         tagged = in_recompute or (OpTags.RECOMPUTE_IN_BACKWARD in getattr(bsym, "tags", ()))
         scope_start = len(fwd.bound_symbols)
         try:
-            _process_inner(bsym, tagged)
+            # the augmented forward's symbols keep the named_scope path of the one processed
+            with rebinding(bsym):
+                _process_inner(bsym, tagged)
         finally:
             if tagged:
                 for nb in fwd.bound_symbols[scope_start:]:
@@ -1075,7 +1078,7 @@ def forward_and_backward_traces(trace: TraceCtx, *, grad_all_inexact_args: bool 
             if res is not NotImplemented:  # rules may decline (e.g. kernel shape checkers)
                 map_out(bsym.output, res.out)
                 new_outs = _flat_tensors(res.out)
-                tape.append(TapeEntry(bsym.sym.id, in_tensors, new_outs, tuple(res.residuals), None))
+                tape.append(TapeEntry(bsym.sym.id, in_tensors, new_outs, tuple(res.residuals), None, bsym))
                 for o in new_outs:
                     if _is_diff_dtype(o):
                         diff.add(o.name)
@@ -1112,7 +1115,7 @@ def forward_and_backward_traces(trace: TraceCtx, *, grad_all_inexact_args: bool 
         new_out, res_proxy = outs_and_res
         map_out(bsym.output, new_out)
         new_outs = _flat_tensors(new_out)
-        tape.append(TapeEntry(("fallback", bsym.sym.id), in_tensors, new_outs, (res_proxy,), bwd_sym))
+        tape.append(TapeEntry(("fallback", bsym.sym.id), in_tensors, new_outs, (res_proxy,), bwd_sym, bsym))
         for o in new_outs:
             if _is_diff_dtype(o):
                 diff.add(o.name)
@@ -1182,7 +1185,8 @@ def forward_and_backward_traces(trace: TraceCtx, *, grad_all_inexact_args: bool 
                 materialize(p.name)
             rmargs = tuple(res_lookup_early(a, saved_mirror) for a in rb.args)
             rmkwargs = {k: res_lookup_early(v, saved_mirror) for k, v in rb.kwargs.items()}
-            new_out = rb.sym(*rmargs, **rmkwargs)
+            with rebinding(rb, "recompute"):
+                new_out = rb.sym(*rmargs, **rmkwargs)
             _map_into(rb.output, new_out, saved_mirror)
 
         grad_map: dict[str, Proxy] = dict(cot_map)
@@ -1205,35 +1209,31 @@ def forward_and_backward_traces(trace: TraceCtx, *, grad_all_inexact_args: bool 
             grad_map[p.name] = g if prev is None else prims.add(prev, g)
 
         for entry in reversed(tape):
-            cots = []
-            any_cot = False
-            for o in entry.outputs:
-                c = grad_map.get(o.name)
-                if c is not None:
-                    any_cot = True
-                else:
-                    c = clang.full(o.shape, 0.0, dtype=o.dtype, device=o.device) if _is_diff_dtype(o) else None
-                cots.append(c)
-            if not any_cot:
+            if not any(o.name in grad_map for o in entry.outputs):
                 continue
-            # fill missing cotangents with zeros for multi-output rules
-            cots = [c for c, o in zip(cots, entry.outputs) if _is_diff_dtype(o) or c is not None]
-            for r in entry.residuals:
-                if isinstance(r, Proxy):
-                    materialize(r.name)
-            if entry.fallback_impl is not None:
-                res = res_lookup(entry.residuals[0])
-                meta_spec = tuple((p.shape, p.dtype, p.device) for p in entry.inputs)
-                grads = entry.fallback_impl(res, meta_spec, *cots)
-            else:
-                rule = backward_impls[entry.sym_id]
-                res = tuple(res_lookup(r) for r in entry.residuals)
-                grads = rule(*res, *cots)
-            if not isinstance(grads, tuple):
-                grads = (grads,)
-            for p, g in zip(entry.inputs, grads):
-                if isinstance(p, TensorProxy) and g is not None and _is_diff_dtype(p):
-                    accumulate(p, g)
+            # what the rule binds, the zeros it is fed and the sums its gradients
+            # go into carry `bwd/<named_scope path of the forward symbol>`
+            with rebinding(entry.origin, "bwd"):
+                # fill missing cotangents with zeros for multi-output rules
+                cots = [grad_map.get(o.name) if o.name in grad_map
+                        else clang.full(o.shape, 0.0, dtype=o.dtype, device=o.device)
+                        for o in entry.outputs if _is_diff_dtype(o) or o.name in grad_map]
+                for r in entry.residuals:
+                    if isinstance(r, Proxy):
+                        materialize(r.name)
+                if entry.fallback_impl is not None:
+                    res = res_lookup(entry.residuals[0])
+                    meta_spec = tuple((p.shape, p.dtype, p.device) for p in entry.inputs)
+                    grads = entry.fallback_impl(res, meta_spec, *cots)
+                else:
+                    rule = backward_impls[entry.sym_id]
+                    res = tuple(res_lookup(r) for r in entry.residuals)
+                    grads = rule(*res, *cots)
+                if not isinstance(grads, tuple):
+                    grads = (grads,)
+                for p, g in zip(entry.inputs, grads):
+                    if isinstance(p, TensorProxy) and g is not None and _is_diff_dtype(p):
+                        accumulate(p, g)
 
         grads_out = []
         for p in grad_args:

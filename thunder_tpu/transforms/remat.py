@@ -74,7 +74,7 @@ class RematTransform(Transform):
                 new.append(bsym)
                 continue
             raw = getattr(impl, "subtrace", None)
-            inner = raw.python_callable() if raw is not None else jitted
+            inner = raw.python_callable(scoped=True) if raw is not None else jitted
             ck = jax.jit(jax.checkpoint(inner, policy=self.policy))
 
             def wrapped(*args, __ck=ck):
