@@ -460,3 +460,23 @@ def test_the_latent_decode_kernel_compiles_for_the_v5e_at_the_cells_shapes(one_c
         pallasex.paged_latent_decode(jnp.zeros((2, 4, 320), bf), jnp.zeros((9, 64, 320), bf),
                                      jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), jnp.int32), 0.1, 256,
                                      interpret=False)
+
+
+def test_the_rope_flash_pair_compiles_for_the_v5e_at_a_quarter_of_a_head_of_64(one_chip, monkeypatch):
+    """pythia-410m's attention (heads of 64, 16 columns rotated, T 2,048, bf16) through Mosaic: the
+    rope-fused forward and single-pass backward at a rotary width narrower than the head. Kept in
+    this file because only one test file of a run may describe the TPU (it holds libtpu's lock)."""
+    bf, (B, H, T, D, n_elem) = jnp.bfloat16, (1, 2, 2048, 64, 16)
+    monkeypatch.setattr(pallasex, "_on_tpu", lambda: True)   # lower through Mosaic, not interpreted
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, table, lse = sds((B, H, T, D), bf), sds((T, n_elem), jnp.float32), sds((B, H, T), jnp.float32)
+    with jax.enable_x64(False):
+        fwd = jax.jit(lambda q, k, v, c, s: pallasex.flash_rope_attention_forward(
+            q, k, v, c, s, causal=True)).lower(q, q, q, table, table).compile()
+        bwd = jax.jit(lambda q, k, v, o, l, c, s, do: pallasex.flash_rope_attention_backward(
+            q, k, v, o, l, c, s, do, causal=True)).lower(q, q, q, q, lse, table, table, q).compile()
+    for compiled in (fwd, bwd):
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1

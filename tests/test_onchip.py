@@ -366,6 +366,51 @@ def test_flash_kernels_lower_via_mosaic():
     assert np.isfinite(np.asarray(dq, np.float32)).all()
 
 
+@pytest.mark.parametrize("H,Hkv,T,D,n_elem", [(16, 16, 2048, 64, 16), (16, 16, 2048, 64, 64),
+                                              (8, 2, 4096, 128, 128), (8, 2, 4096, 128, 32)],
+                         ids=["pythia-quarter-of-64", "llama-350m-64", "mistral-g4-128",
+                              "g4-quarter-of-128"])
+def test_rope_flash_kernels_on_chip(H, Hkv, T, D, n_elem):
+    """The rope-fused flash pair compiled by Mosaic at the train cells' heads, at a rotary width
+    of the whole head and of a quarter of it: the output and dq, dk, dv against float32 rope
+    and softmax attention in plain jax (bf16 operands: a hundredth of the largest entry)."""
+    from thunder_tpu.executors import pallasex
+    from thunder_tpu.models.litgpt import build_rope_cache
+
+    assert not pallasex._interpret()
+    rng = np.random.RandomState(0)
+    g = H // Hkv
+    q = jnp.asarray(rng.randn(1, H, T, D), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.randn(1, Hkv, T, D), jnp.bfloat16) for _ in range(2))
+    do = jnp.asarray(rng.randn(1, H, T, D), jnp.bfloat16)
+    cos, sin = build_rope_cache(T, n_elem, 10000, jnp.float32)
+    assert cos.shape == (T, n_elem)
+
+    def rope(x):
+        h = n_elem // 2
+        x1, x2, rest = x[..., :h], x[..., h:n_elem], x[..., n_elem:]
+        c, s = cos[:, :h], sin[:, :h]
+        out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], -1)
+        return out.astype(jnp.bfloat16).astype(jnp.float32)  # rounded once, as the kernels do
+
+    def ref(q, k, v):
+        kk, vv = (jnp.repeat(t, g, axis=1) for t in (rope(k), v))
+        mask = jnp.tril(jnp.ones((T, T), bool))
+        return _attention_ref(rope(q), kk, vv, mask)
+
+    f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+    want, vjp = jax.vjp(ref, *f32)
+    want_g = vjp(do.astype(jnp.float32))
+    o, lse = jax.jit(lambda *a: pallasex.flash_rope_attention_forward(*a, causal=True))(q, k, v, cos, sin)
+    got_g = jax.jit(lambda *a: pallasex.flash_rope_attention_backward(*a, causal=True))(
+        q, k, v, o, lse, cos, sin, do)
+    for name, got, ref_ in zip(("o", "dq", "dk", "dv"), (o, *got_g), (want, *want_g)):
+        got, ref_ = np.asarray(got, np.float32), np.asarray(ref_)
+        assert np.isfinite(got).all(), name
+        assert np.abs(got - ref_).max() < 0.01 * np.abs(ref_).max() + 1e-3, (
+            name, float(np.abs(got - ref_).max()), float(np.abs(ref_).max()))
+
+
 def test_flash_bwd_claimed_inside_train_step():
     """The executor-claimed sdpa grad must survive into TrainStep's backward
     trace (flash_attention_bwd symbol present, not the composite decomp)."""

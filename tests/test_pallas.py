@@ -101,27 +101,37 @@ _FLASH_VMEM_CASES = ([(T, D, g, ("flash_attention", "rope_sdpa"))
                         (16384, 128, 4, ()), (16384, 64, 1, ()), (32768, 128, 1, ())])
 
 
-@pytest.mark.parametrize("kernel", ["flash_attention", "rope_sdpa"])
+def _claimed_and_declines(check):
+    """What a checker said, and the `pallas.decline.*` counters it left on the bus."""
+    from thunder_tpu import observability
+
+    observability.enable()
+    observability.reset()
+    try:
+        claimed = check()
+        return claimed, [k for k in observability.counters() if k.startswith("pallas.decline.")]
+    finally:
+        observability.disable()
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "rope_sdpa", "rope_sdpa-quarter"])
 @pytest.mark.parametrize("T,D,g,fits", _FLASH_VMEM_CASES,
                          ids=[f"T{T}-D{D}-g{g}" for T, D, g, _ in _FLASH_VMEM_CASES])
 def test_flash_checkers_claim_what_fits_vmem_and_decline_the_rest(kernel, T, D, g, fits):
     """A sequence whose whole-length K and V (and rope tables) the estimate says do not fit is
     declined with `pallas.decline.<kernel>.vmem` on the bus (the rope checker goes through the
     plain one, whose counter it is when the plain kernel does not fit either) and XLA's
-    composite runs; it used to be claimed and then refused by the compiler."""
-    from thunder_tpu import observability
-
-    q, kv, table = _Operand((4, 8 * g, T, D)), _Operand((4, 8, T, D)), _Operand((T, D), "float32")
-    observability.enable()
-    observability.reset()
-    try:
-        if kernel == "flash_attention":
-            claimed = pallasex.flash_attention_supported(q, kv, kv, None, 0.0, True, None)
-        else:
-            claimed = pallasex.rope_sdpa_supported(q, kv, kv, table, table, True, None)
-        declines = [k for k in observability.counters() if k.startswith("pallas.decline.")]
-    finally:
-        observability.disable()
+    composite runs; it used to be claimed and then refused by the compiler. Tables of a
+    quarter of the head (pythia's `(T, 16)` at heads of 64) claim and decline where the whole
+    head's do: the kernels widen them to the head, and the estimate counts them so."""
+    kernel, _, quarter = kernel.partition("-")
+    q, kv = _Operand((4, 8 * g, T, D)), _Operand((4, 8, T, D))
+    table = _Operand((T, D // 4 if quarter else D), "float32")
+    if kernel == "flash_attention":
+        check = lambda: pallasex.flash_attention_supported(q, kv, kv, None, 0.0, True, None)  # noqa: E731
+    else:
+        check = lambda: pallasex.rope_sdpa_supported(q, kv, kv, table, table, True, None)  # noqa: E731
+    claimed, declines = _claimed_and_declines(check)
     assert claimed == (kernel in fits)
     if claimed:
         assert declines == []
@@ -223,17 +233,23 @@ def test_sdpa_gqa_short_seq_falls_back_to_composite(rng):
         tt.jit(lambda q, k, v: ltorch.sdpa(q, k, v))(q, k, v)
 
 
-def test_rope_sdpa_fused_matches_decomposition(rng):
+@pytest.mark.parametrize("g", [1, 4])
+@pytest.mark.parametrize("D,n_elem", [(64, 64), (64, 16), (128, 32), (128, 128)])
+def test_rope_sdpa_fused_matches_decomposition(rng, D, n_elem, g):
     """Fused rope+flash (in-kernel rope + in-kernel rope-VJP rotation) vs the
-    decomposed rope->sdpa path, fwd and grads (f32, interpret mode)."""
+    decomposed rope->sdpa path, fwd and grads (f32, interpret mode), at a rotary
+    width of the whole head and of a quarter of it (the tables' width says which),
+    one query head a KV head and four."""
     import math
 
     import thunder_tpu as tt
     from thunder_tpu.models.litgpt import build_rope_cache
 
-    B, H, T, D = 1, 2, 1024, 64  # T=1024: the fused kernel actually claims
-    q, k, v = (jnp.asarray(rng.randn(B, H, T, D).astype(np.float32)) for _ in range(3))
-    cos, sin = build_rope_cache(T, D, 10000, jnp.float32)
+    B, Hkv, T = 1, 2, 1024  # T=1024: the fused kernel actually claims
+    q = jnp.asarray(rng.randn(B, Hkv * g, T, D).astype(np.float32))
+    k, v = (jnp.asarray(rng.randn(B, Hkv, T, D).astype(np.float32)) for _ in range(2))
+    cos, sin = build_rope_cache(T, n_elem, 10000, jnp.float32)
+    assert cos.shape == (T, n_elem)
 
     calls = {"n": 0}
     orig_fwd = pallasex.flash_rope_attention_forward
@@ -245,17 +261,15 @@ def test_rope_sdpa_fused_matches_decomposition(rng):
     pallasex.flash_rope_attention_forward = spy
 
     def loss(q, k, v, c, s):
-        return ltorch.sum(ltorch.rope_sdpa(q, k, v, c, s, is_causal=True,
-                                           scale=1.0 / math.sqrt(D)))
+        o = ltorch.rope_sdpa(q, k, v, c, s, is_causal=True, scale=1.0 / math.sqrt(D))
+        return ltorch.sum(o * o)  # a cotangent that differs by column
 
-    import thunder_tpu.executors.pallasex as px
-
-    orig = px.rope_sdpa_supported
-    px.rope_sdpa_supported = lambda *a, **kw: False
+    orig = pallasex.rope_sdpa_supported
+    pallasex.rope_sdpa_supported = lambda *a, **kw: False
     try:
         ref_loss, ref_g = tt.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v, cos, sin)
     finally:
-        px.rope_sdpa_supported = orig
+        pallasex.rope_sdpa_supported = orig
     try:
         got_loss, got_g = tt.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v, cos, sin)
     finally:
@@ -264,7 +278,38 @@ def test_rope_sdpa_fused_matches_decomposition(rng):
     np.testing.assert_allclose(float(got_loss), float(ref_loss), rtol=1e-6)
     for i, name in enumerate(["dq", "dk", "dv"]):
         np.testing.assert_allclose(np.asarray(got_g[0][i]), np.asarray(ref_g[0][i]),
-                                   atol=1e-4, err_msg=name)
+                                   atol=2e-4, err_msg=name)
+    if n_elem < D:  # the columns past the width pass: their rows of the rope are the identity
+        plain = np.asarray(jax.jit(lambda x: pallasex._rope_block(
+            x, *pallasex._rope_tables(cos, sin, D)))(q[0, 0]))
+        np.testing.assert_array_equal(plain[:, n_elem:], np.asarray(q[0, 0, :, n_elem:]))
+
+
+def test_the_rotation_matrix_of_the_full_width_is_the_one_it_was():
+    """`_rot_matrix(D, D)` is rotate_half over the head, as before the kernels took a width;
+    at a narrower width it is rotate_half over the first columns and zero elsewhere."""
+    full = np.asarray(pallasex._rot_matrix(8, 8, jnp.float32))
+    x = np.arange(1.0, 9.0, dtype=np.float32)
+    np.testing.assert_array_equal(x @ full, np.concatenate([-x[4:], x[:4]]))
+    part = np.asarray(pallasex._rot_matrix(8, 4, jnp.float32))
+    np.testing.assert_array_equal(x @ part, np.array([-3, -4, 1, 2, 0, 0, 0, 0], np.float32))
+    assert not part[4:].any() and not part[:, 4:].any()
+    np.testing.assert_array_equal(part[:4, :4], np.asarray(pallasex._rot_matrix(4, 4, jnp.float32)))
+
+
+@pytest.mark.parametrize("width, reason", [(15, "width"), (63, "width"), (66, "width"),
+                                           (128, "width"), (0, "width"), (None, "tables")])
+def test_rope_checker_declines_a_width_it_does_not_take_by_name(width, reason):
+    """An odd rotary width, one wider than the head or none, and tables that are not two
+    (T, n) of one shape: declined as `pallas.decline.rope_sdpa.<reason>`, alone on the bus."""
+    T, D = 2048, 64
+    q = _Operand((4, 8, T, D))
+    cos = _Operand((T, 16 if width is None else width), "float32")
+    sin = _Operand((T, 32), "float32") if width is None else cos
+    claimed, declines = _claimed_and_declines(
+        lambda: pallasex.rope_sdpa_supported(q, q, q, cos, sin, True, None))
+    assert not claimed
+    assert declines == [f"pallas.decline.rope_sdpa.{reason}"]
 
 
 @pytest.mark.parametrize("dtype,atol", [(np.float32, 2e-3)])

@@ -138,6 +138,104 @@ def test_a_scope_is_the_path_of_the_open_scopes_and_outside_a_trace_it_is_nothin
         assert float(jnp.ones(()) + 1) == 2.0
 
 
+# -- which road the rope takes ---------------------------------------------------------------------
+
+class Roads:
+    """A pythia-shaped model long enough for the flash checkers to claim (T 1,024, heads of
+    64, a quarter of each rotated), traced twice from one set of weights: as it runs, and with
+    the rope-flash checker saying no, which is the decomposed road of a CPU or a short sequence."""
+
+    def __init__(self):
+        from thunder_tpu.executors import pallasex
+
+        cfg = litgpt.Config(**dict(SHAPES["pythia"], block_size=1024, n_head=2, n_embd=128),
+                            activation_checkpoint=True)
+        assert (cfg.head_size, cfg.rope_n_elem) == (64, 16)
+        self.cfg = cfg
+        model = litgpt.GPTForCausalLM(cfg)
+        toks = np.random.default_rng(0).integers(0, 300, (1, 1025)).astype(np.int32)
+        self.vag = {}
+        self.out = {}
+        claims = pallasex.rope_sdpa_supported
+        for road in ("fused", "decomposed"):
+            self.vag[road] = tt.value_and_grad(tt.jit(model))
+            if road == "decomposed":
+                pallasex.rope_sdpa_supported = lambda *a, **kw: False
+            try:
+                self.out[road] = self.vag[road](toks[:, :-1], toks[:, 1:])
+            finally:
+                pallasex.rope_sdpa_supported = claims
+
+    def symbols(self, road: str, which: str) -> list:
+        cs = self.vag[road]._cs
+        if which == "traced":
+            return list(cs.last_traces[0].bound_symbols)
+        return region_symbols({"forward": cs.last_traces, "backward": cs.last_backward_traces}[which][-1])
+
+
+@pytest.fixture(scope="module")
+def roads():
+    return Roads()
+
+
+def test_a_partial_rope_is_traced_as_one_rope_sdpa_a_layer_and_the_kernels_claim_it(roads):
+    r = roads
+    n = r.cfg.n_layer
+    traced = [b.sym.name for b in r.symbols("fused", "traced")]
+    assert traced.count("rope_sdpa") == n and "sdpa" not in traced
+    fwd = r.symbols("fused", "forward")
+    bwd = r.symbols("fused", "backward")
+    names = lambda syms: [b.sym.name for b in syms]
+    assert names(fwd).count("rope_flash_fwd") == n
+    assert names(bwd).count("rope_flash_fwd") == n  # the blocks are recomputed
+    assert names(bwd).count("rope_flash_bwd") == n
+    assert not [x for x in names(fwd) + names(bwd) if "flash_attention" in x]
+    # no rotation is left to XLA: under attn/rope only the tables' slice, made once a step
+    left = [b.sym.name for b in fwd + bwd if (scope_of(b) or "").endswith("attn/rope")]
+    assert len(left) <= 2 and set(left) <= {"slice_prim"}, left
+
+
+def test_the_decomposed_road_of_rope_sdpa_rotates_under_the_scope_rope(roads):
+    r = roads
+    assert [b.sym.name for b in r.symbols("decomposed", "traced")].count("rope_sdpa") == r.cfg.n_layer
+    fwd = {scope_of(b) for b in r.symbols("decomposed", "forward")}
+    bwd = {scope_of(b) for b in r.symbols("decomposed", "backward")}
+    assert "attn/rope" in fwd and {"bwd/attn/rope", "recompute/attn/rope"} <= bwd
+    rotated = [b for b in r.symbols("decomposed", "forward")
+               if scope_of(b) == "attn/rope" and b.sym.name == "cat"]
+    assert len(rotated) == 2 * r.cfg.n_layer  # q and k of every layer, three parts each
+    assert all(len(b.args[0]) == 3 for b in rotated)
+
+
+def test_the_two_roads_give_one_loss_and_one_gradient(roads):
+    r = roads
+    (loss, grads), (ref_loss, ref_grads) = r.out["fused"], r.out["decomposed"]
+    assert np.isfinite(float(loss))
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-6)
+    assert set(grads) == set(ref_grads) and len(grads) > 20
+    for name in grads:
+        np.testing.assert_allclose(np.asarray(grads[name]), np.asarray(ref_grads[name]),
+                                   atol=1e-6, rtol=1e-4, err_msg=name)
+
+
+def test_a_context_parallel_trace_keeps_the_decomposed_rope_and_plain_sdpa():
+    """Ring attention rewrites plain sdpa symbols: under an open context-parallel context the
+    model rotates with `_apply_rope` (local rows, global positions) and calls `sdpa`."""
+    from thunder_tpu.parallel import make_mesh
+    from thunder_tpu.parallel.context_parallel import context_parallel
+
+    cfg = litgpt.Config(**SHAPES["pythia"])
+    toks = np.random.default_rng(0).integers(0, 300, (2, 65)).astype(np.int32)
+    tm = tt.jit(litgpt.GPTForCausalLM(cfg))
+    context_parallel(tm, make_mesh({"sp": 2}))
+    step = TrainStep(tm, optim.SGD(lr=0.0))
+    assert np.isfinite(float(step(toks[:, :-1], toks[:, 1:])))
+    traced = step._vag._cs.last_traces[0].bound_symbols
+    names = [b.sym.name for b in traced]
+    assert names.count("ring_attention") == cfg.n_layer and "rope_sdpa" not in names
+    assert {"attn/rope"} <= {scope_of(b) for b in traced}
+
+
 # -- a scope is a trace-time name --------------------------------------------------------------
 
 def lone(x):  # one elementwise op is not worth a region: it runs op by op

@@ -268,7 +268,11 @@ def _lane_padded(D: int) -> int:
 
 def _rope_tables_vmem_bytes(block_q: int, Tk: int, Dp: int) -> int:
     """The f32 cos/sin of the rope-flash kernels: the q block's rows in two
-    buffers and the whole-length tables in one (their block never changes)."""
+    buffers and the whole-length tables in one (their block never changes).
+    The kernels take their tables as wide as the head whatever the rotary
+    width (`pallasex._rope_tables` widens a `(T, n_elem)` pair with cos 1 and
+    sin 0), and a head narrower than the 128 lanes is padded to them: `Dp`
+    is the head's padded width, so the estimate is the same at every width."""
     return (2 * 2 * block_q + 2 * Tk) * Dp * 4
 
 

@@ -324,49 +324,70 @@ def flash_attention_backward(q, k, v, o, lse, do, *, causal: bool = True, scale=
 # ===========================================================================
 
 
-def _rot_matrix(D: int, dtype):
-    """rotate_half as a constant matmul: rotate(x) = x @ R with
-    R[i, j] = -1 at i == j + D/2, +1 at i == j - D/2. Lane-slicing halves of
-    a bf16 tile in-kernel lowers to catastrophic VREG shuffles on Mosaic;
-    one (N, D) @ (D, D) dot is MXU-trivial instead."""
-    h = D // 2
+def _rot_matrix(D: int, n_elem: int, dtype):
+    """rotate_half over the first `n_elem` of D columns as a constant matmul:
+    rotate(x) = x @ R with R[i, j] = -1 at i == j + n_elem/2 (j in the first
+    half), +1 at i == j - n_elem/2 (j in the second), zero on every row and
+    column past `n_elem`. Lane-slicing halves of a bf16 tile in-kernel lowers
+    to catastrophic VREG shuffles on Mosaic; one (N, D) @ (D, D) dot is
+    MXU-trivial instead, and is the same dot whatever the rotary width."""
+    h = n_elem // 2
     ii = jax.lax.broadcasted_iota(jnp.int32, (D, D), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (D, D), 1)
-    r = jnp.where(ii == jj + h, -1.0, 0.0) + jnp.where(ii + h == jj, 1.0, 0.0)
-    return r.astype(dtype)
+    part = n_elem < D  # at the full width the two diagonals end where the matrix does
+    lower = (ii == jj + h) & (jj < h) if part else ii == jj + h
+    r = jnp.where(lower, -1.0, 0.0)
+    upper = (ii + h == jj) & (jj < n_elem) if part else ii + h == jj
+    return (r + jnp.where(upper, 1.0, 0.0)).astype(dtype)
 
 
-def _rope_block(x, c, s):
-    """x (N, D) f32 -> rope'd (N, D); cos/sin (N, D) duplicated-half caches."""
-    rot = jax.lax.dot_general(x, _rot_matrix(x.shape[-1], x.dtype),
+def _rope_block(x, c, s, n_elem: int):
+    """x (N, D) f32 -> rope'd (N, D); cos/sin (N, D) duplicated-half caches
+    over the first `n_elem` columns, cos 1 and sin 0 on the columns that pass."""
+    rot = jax.lax.dot_general(x, _rot_matrix(x.shape[-1], n_elem, x.dtype),
                               (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     return x * c + rot * s
 
 
-def _rope_vjp_block(dxr, c, s):
-    """VJP of _rope_block wrt x: dx = dxr*c + (dxr*s) @ R^T."""
+def _rope_vjp_block(dxr, c, s, n_elem: int):
+    """VJP of _rope_block wrt x: dx = dxr*c + (dxr*s) @ R^T (dx = dxr on the
+    columns that pass: c is 1 and s is 0 there)."""
     ds = dxr * s
-    rot = jax.lax.dot_general(ds, _rot_matrix(dxr.shape[-1], ds.dtype),
+    rot = jax.lax.dot_general(ds, _rot_matrix(dxr.shape[-1], n_elem, ds.dtype),
                               (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
     return dxr * c + rot
 
 
+def _rope_tables(cos, sin, D: int):
+    """The kernels' tables, f32 and D wide: a rotary width `n_elem` < D reads
+    cos 1 and sin 0 on the columns past it (widened here, once a call: a few
+    hundred KB that XLA makes once a program, where a lane concatenate inside
+    the kernel would be made once a tile)."""
+    cos, sin = cos.astype(jnp.float32), sin.astype(jnp.float32)
+    n_elem = cos.shape[-1]
+    if n_elem < D:
+        cos = jnp.pad(cos, ((0, 0), (0, D - n_elem)), constant_values=1.0)
+        sin = jnp.pad(sin, ((0, 0), (0, D - n_elem)))
+    return cos, sin, n_elem
+
+
 def _flash_rope_fwd_kernel(q_ref, k_ref, v_ref, cq_ref, sq_ref, ck_ref, sk_ref,
-                           o_ref, lse_ref, *, block_k: int, causal: bool, scale: float):
+                           o_ref, lse_ref, *, block_k: int, causal: bool, scale: float,
+                           n_elem: int):
     block_q, D = q_ref.shape
     T = k_ref.shape[0]
     qi = pl.program_id(2)
 
-    q = _rope_block(q_ref[:].astype(jnp.float32), cq_ref[:], sq_ref[:]).astype(q_ref.dtype)
+    q = _rope_block(q_ref[:].astype(jnp.float32), cq_ref[:], sq_ref[:], n_elem).astype(q_ref.dtype)
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
     def body(j, carry):
         o_acc, m, l = carry
         k_blk = _rope_block(k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32),
                             ck_ref[pl.ds(j * block_k, block_k), :],
-                            sk_ref[pl.ds(j * block_k, block_k), :]).astype(k_ref.dtype)
+                            sk_ref[pl.ds(j * block_k, block_k), :], n_elem).astype(k_ref.dtype)
         v_blk = v_ref[pl.ds(j * block_k, block_k), :]
         ss = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * (scale * LOG2E)
@@ -397,7 +418,9 @@ def _flash_rope_fwd_kernel(q_ref, k_ref, v_ref, cq_ref, sq_ref, ck_ref, sk_ref,
 def flash_rope_attention_forward(q, k, v, cos, sin, *, causal: bool = True, scale=None,
                                  block_q: int = DEFAULT_BLOCK_Q,
                                  block_k: int = DEFAULT_BLOCK_K):
-    """q,k,v PRE-rope (B, H, T, D); cos/sin (T, D) duplicated-half caches."""
+    """q,k,v PRE-rope (B, H, T, D); cos/sin (T, n_elem) duplicated-half caches
+    of an even rotary width n_elem <= D: the first n_elem columns of every
+    head are rotated, the rest pass."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     B, H, T, D = q.shape
     Hkv = k.shape[1]
@@ -405,10 +428,10 @@ def flash_rope_attention_forward(q, k, v, cos, sin, *, causal: bool = True, scal
     block_q = min(block_q, T)
     block_k = min(block_k, T)
     block_q, block_k = _cap_blocks_for_dtype(q, block_q, block_k, T, T, k, v)
-    cos = cos.astype(jnp.float32)
-    sin = sin.astype(jnp.float32)
+    cos, sin, n_elem = _rope_tables(cos, sin, D)
     o, lse = pl.pallas_call(
-        functools.partial(_flash_rope_fwd_kernel, block_k=block_k, causal=causal, scale=scale),
+        functools.partial(_flash_rope_fwd_kernel, block_k=block_k, causal=causal, scale=scale,
+                          n_elem=n_elem),
         grid=(B, H, T // block_q),
         in_specs=[
             pl.BlockSpec((None, None, block_q, D), lambda b, h, i: (b, h, i, 0)),
@@ -436,7 +459,7 @@ def _flash_rope_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
                                  cq_ref, sq_ref, ck_ref, sk_ref,
                                  dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                                  block_k: int, causal: bool, scale: float,
-                                 g: int, n_i: int):
+                                 g: int, n_i: int, n_elem: int):
     """Single-pass rope backward (see _flash_bwd_fused_kernel): rope applied
     in-kernel on q/k loads, rope VJP on the dq carry at write and on the dk
     scratch at the final i."""
@@ -454,7 +477,8 @@ def _flash_rope_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
         n_j = jnp.minimum(n_j, ((ii + 1) * block_q + block_k - 1) // block_k)
 
     for h in range(g):  # static unroll over the q-head group (1 for MHA)
-        q = _rope_block(q_ref[h].astype(jnp.float32), cq_ref[:], sq_ref[:]).astype(q_ref.dtype)
+        q = _rope_block(q_ref[h].astype(jnp.float32), cq_ref[:], sq_ref[:],
+                        n_elem).astype(q_ref.dtype)
         do = do_ref[h]
         lse2 = lse_ref[h][:, 0] * LOG2E
         delta = delta_ref[h][:, 0]
@@ -463,18 +487,19 @@ def _flash_rope_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
         def body(j, dq_acc):
             sl = pl.ds(j * block_k, block_k)
             k_blk = _rope_block(k_ref[sl, :].astype(jnp.float32),
-                                ck_ref[sl, :], sk_ref[sl, :]).astype(k_ref.dtype)
+                                ck_ref[sl, :], sk_ref[sl, :], n_elem).astype(k_ref.dtype)
             k_pos_t = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
             return _fused_bwd_tile(q, do, lse2, delta, k_blk, v_ref[sl, :],
                                    sl, k_pos_t, q_pos_t, causal, scale,
                                    dk_scr, dv_scr, dq_acc)
 
         dq = jax.lax.fori_loop(0, n_j, body, jnp.zeros((block_q, D), jnp.float32))
-        dq_ref[h] = _rope_vjp_block(dq, cq_ref[:], sq_ref[:]).astype(dq_ref.dtype)
+        dq_ref[h] = _rope_vjp_block(dq, cq_ref[:], sq_ref[:], n_elem).astype(dq_ref.dtype)
 
     @pl.when(ii == n_i - 1)
     def _write():
-        dk_ref[:] = _rope_vjp_block(dk_scr[:], ck_ref[:], sk_ref[:]).astype(dk_ref.dtype)
+        dk_ref[:] = _rope_vjp_block(dk_scr[:], ck_ref[:], sk_ref[:],
+                                    n_elem).astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
@@ -489,8 +514,7 @@ def flash_rope_attention_backward(q, k, v, o, lse, cos, sin, do, *, causal: bool
     g = H // Hkv
     block_q, block_k = _cap_blocks_for_dtype(q, min(block_q, T), min(block_k, T), T, T, k, v, do)
     block_q, block_k = _fused_bwd_blocks(block_q, block_k, T, T)
-    cos = cos.astype(jnp.float32)
-    sin = sin.astype(jnp.float32)
+    cos, sin, n_elem = _rope_tables(cos, sin, D)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     lse4 = lse[..., None]
     delta4 = delta[..., None]
@@ -501,7 +525,7 @@ def flash_rope_attention_backward(q, k, v, o, lse, cos, sin, do, *, causal: bool
     n_i = T // block_q
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_rope_bwd_fused_kernel, block_k=block_k,
-                          causal=causal, scale=scale, g=g, n_i=n_i),
+                          causal=causal, scale=scale, g=g, n_i=n_i, n_elem=n_elem),
         grid=(B, Hkv, n_i),
         in_specs=[
             pl.BlockSpec((None, None, g, block_q, D), lambda b, hk, i: (b, hk, 0, i, 0)),
@@ -535,18 +559,24 @@ def flash_rope_attention_backward(q, k, v, o, lse, cos, sin, do, *, causal: bool
 
 def rope_sdpa_supported(q, k, v, cos, sin, is_causal=True, scale=None) -> bool:
     """Claim fused rope+attention when the plain flash checker would claim
-    the sdpa AND rope covers the full (even) head dim."""
+    the sdpa AND the tables are (T, n_elem) for an even rotary width
+    n_elem <= head dim (read off the tables, nothing else says it): the
+    kernels rotate the first n_elem columns of every head and pass the rest.
+    A width they do not take is declined by name (`pallas.decline.rope_sdpa.
+    width`, or `.tables` where cos and sin are not two (T, n) tables)."""
     if getattr(q, "ndim", 0) != 4:
         return False
     D = q.shape[-1]
     T = q.shape[-2]
-    return (
-        flash_attention_supported(q, k, v, None, 0.0, is_causal, scale)
-        and D % 2 == 0
-        and getattr(cos, "shape", None) == (T, D)
-        and getattr(sin, "shape", None) == (T, D)
-        and _flash_fits_vmem("rope_sdpa", q, k, rope=True)
-    )
+    if not flash_attention_supported(q, k, v, None, 0.0, is_causal, scale):
+        return False
+    shape = tuple(getattr(cos, "shape", ()))
+    if shape != tuple(getattr(sin, "shape", ())) or len(shape) != 2 or shape[0] != T:
+        return _decline("rope_sdpa", "tables")
+    n_elem = shape[1]
+    if n_elem <= 0 or n_elem % 2 or n_elem > D:
+        return _decline("rope_sdpa", "width")
+    return _flash_fits_vmem("rope_sdpa", q, k, rope=True)
 
 
 def _rope_sdpa_impl(q, k, v, cos, sin, is_causal=True, scale=None):

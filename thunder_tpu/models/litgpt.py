@@ -191,12 +191,13 @@ class CausalSelfAttention(nn.Module):
         n_elem = cfg.rope_n_elem
         from ..parallel.context_parallel import current_seq_parallel_ctx
 
-        if n_elem == hs and hs % 2 == 0 and current_seq_parallel_ctx() is None:
+        if 0 < n_elem <= hs and n_elem % 2 == 0 and current_seq_parallel_ctx() is None:
             # fused rope+attention symbol (GQA included: the kernel indexes
-            # kv blocks by q_head // group): the pallas executor applies
-            # rope in-kernel and rotates the rope VJP in-kernel in backward;
-            # ring-attention CP rewrites plain sdpa bsyms, so it keeps the
-            # decomposed path
+            # kv blocks by q_head // group; a rotary width narrower than the
+            # head included: the tables are (T, n_elem) and say it): the pallas
+            # executor applies rope in-kernel and rotates the rope VJP in-kernel
+            # in backward; ring-attention CP rewrites plain sdpa bsyms, so it
+            # keeps the decomposed path
             y = ltorch.rope_sdpa(q, k, v, cos, sin, is_causal=True,
                                  scale=1.0 / math.sqrt(hs))
         else:
@@ -219,7 +220,11 @@ def _repeat_kv(x, n: int):
 
 
 def _apply_rope(x, cos, sin, n_elem: int):
-    """Half-split RoPE. Structured as half-width muls with ONE final concat:
+    """Half-split RoPE over the first `n_elem` columns, for the roads that do
+    not go through `ltorch.rope_sdpa`: a context-parallel trace (ring attention
+    rewrites plain sdpa), an odd rotary width, and serving, which rotates a
+    prompt's or a step's rows itself (`inference.split_qkv_rope`). Structured
+    as half-width muls with ONE final concat:
     the cat([-x2, x1])-then-multiply form pays an extra full-width
     materialize + awkward slice/negate fusions in XLA (profiled ~16 ms/step
     on llama-350m); with duplicated-half caches cos[:d/2] == cos[d/2:], so

@@ -18,6 +18,7 @@ from ..core import dtypes, prims
 from ..core.baseutils import canonicalize_dim, check
 from ..core.proxies import NumberProxy, TensorProxy, pyval, register_method
 from ..core.symbol import OpTags, Symbol
+from ..core.trace import named_scope
 from . import clang
 
 _torch_symbols: dict[str, Symbol] = {}
@@ -838,24 +839,37 @@ def rms_norm(a, normalized_shape, weight=None, eps=1e-6):
 def rope_sdpa(q, k, v, cos, sin, is_causal=True, scale=None):
     """Fused half-split RoPE + scaled-dot-product attention.
 
-    q/k arrive PRE-rope; cos/sin are (T, head_dim) duplicated-half caches.
+    q/k arrive PRE-rope; cos/sin are (T, n_elem) duplicated-half caches of an
+    even rotary width n_elem <= head_dim, read off the tables' last dimension:
+    the first n_elem columns of every head are rotated and the rest pass
+    (pythia rotates a quarter of its heads, llama and mistral all of them).
     The pallas executor claims this whole (rope applied in-kernel, rope VJP
     rotated in-kernel on the dq/dk accumulators — the separate rope
     slice/negate/cat fusions and their backward passes disappear). The
-    decomposition below is the unclaimed/CPU path and the grad fallback."""
+    decomposition below is the unclaimed/CPU path and the grad fallback; its
+    rotation is bound under the scope ``rope`` like `litgpt._apply_rope`'s."""
     hs = q.shape[-1]
-    h = hs // 2
+    n_elem = cos.shape[-1]
+    check(tuple(cos.shape) == tuple(sin.shape),
+          lambda: f"rope_sdpa: cos {tuple(cos.shape)} and sin {tuple(sin.shape)} differ")
+    check(0 < n_elem <= hs and n_elem % 2 == 0,
+          lambda: f"rope_sdpa: rotary width {n_elem} must be even and in (0, {hs}]")
+    h = n_elem // 2
 
     def rope(x):
-        x1 = x[..., :h]
-        x2 = x[..., h:]
-        c = cos[..., :h]
-        s_ = sin[..., :h]
-        out = cat([x1 * c - x2 * s_, x2 * c + x1 * s_], -1)
-        # rope math runs f32 (f32 cos/sin promote), but the attention matmuls
-        # must keep the input compute dtype (autocast bf16 would otherwise be
-        # silently undone on the unclaimed path)
-        return clang.maybe_convert_to_dtype(out, x.dtype)
+        with named_scope("rope"):
+            x1 = x[..., :h]
+            x2 = x[..., h:n_elem]
+            c = cos[..., :h]
+            s_ = sin[..., :h]
+            parts = [x1 * c - x2 * s_, x2 * c + x1 * s_]
+            if n_elem < hs:
+                parts.append(x[..., n_elem:])
+            out = cat(parts, -1)
+            # rope math runs f32 (f32 cos/sin promote), but the attention matmuls
+            # must keep the input compute dtype (autocast bf16 would otherwise be
+            # silently undone on the unclaimed path)
+            return clang.maybe_convert_to_dtype(out, x.dtype)
 
     return sdpa.meta(rope(q), rope(k), v, is_causal=is_causal, scale=scale,
                      enable_gqa=q.shape[1] != k.shape[1])
