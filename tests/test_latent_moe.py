@@ -480,3 +480,66 @@ def test_the_rope_flash_pair_compiles_for_the_v5e_at_a_quarter_of_a_head_of_64(o
             q, k, v, o, l, c, s, do, causal=True)).lower(q, q, q, q, lse, table, table, q).compile()
     for compiled in (fwd, bwd):
         assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+# -- the same two kernels at their second shape: rows 640, values 512, 64 heads; d 6144, 16 held ------------
+# (LongCat-Flash's cut, `longcat-flash-omni-ep32-l4.serve-trajectories`; kept in this file for the lock too)
+
+@pytest.mark.parametrize("tokens_in", [256, 768], ids=["decode-256-slots", "chunk-512-beside-256"])
+def test_the_ragged_kernel_compiles_for_the_v5e_at_a_model_width_of_6144(one_chip, tokens_in):
+    from thunder_tpu.analysis import budget
+
+    bf, E, D, H, k = jnp.bfloat16, 16, 6144, 2048, 12
+    tile = moe.ragged_tile(tokens_in * k, 512 + 256)      # the rows spread over routed and identity outputs
+    R = -(-tokens_in * k // tile) * tile + E * tile
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(lambda r, g, u, d, s: pallasex.ragged_mlp_fused(r, g, u, d, s, tile, interpret=False)).lower(
+            sds((R, D), bf), sds((E, D, H), bf), sds((E, D, H), bf), sds((E, H, D), bf), sds((E,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert (tile, R) == ((16, 3328) if tokens_in == 256 else (32, 9728))
+    # the checker's `vmem` arm at its second shape: 512 hidden columns a weight tile, as at d 4096
+    assert budget.ragged_mlp_block_h(tile, D, H, 2, 2) == 512 and budget.ragged_mlp_block_h(128, 4 * D, H, 2, 2) == 0
+
+
+def test_the_latent_decode_kernel_compiles_for_the_v5e_at_rows_of_640_and_64_heads(one_chip):
+    from thunder_tpu.analysis import budget
+
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(lambda q, p, t, n: pallasex.paged_latent_decode(q, p, t, n, 192 ** -0.5, 512, interpret=False)).lower(
+            sds((256, 64, 640), bf), sds((4609, 64, 640), bf), sds((256, 36), jnp.int32), sds((256,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    # `analysis/budget.latent_pages_per_step` at both shapes: 8 pages a step fit either; rows of 576 fill no lane group
+    assert budget.latent_pages_per_step(64, 640, 512, 64, 2, 2) == 8 == budget.latent_pages_per_step(64, 384, 256, 32, 2, 2)
+    assert PagedLatent(576).row == 640
+    with pytest.raises(ValueError, match="128 lanes"):
+        pallasex.paged_latent_decode(jnp.zeros((2, 64, 576), bf), jnp.zeros((9, 64, 576), bf),
+                                     jnp.zeros((2, 4), jnp.int32), jnp.ones((2,), jnp.int32), 0.1, 512,
+                                     interpret=False)
+
+
+@pytest.mark.parametrize("rows", [256, 768], ids=["decode-256-slots", "chunk-512-beside-256"])
+def test_the_rms_norm_kernel_compiles_for_the_v5e_at_a_width_of_6144(one_chip, rows):
+    """256 rows of 6,144 bfloat16 in two buffers each way and a float32 copy are 18.11M of Mosaic's
+    16M (PR 38's first chip run fell there): the block follows the width."""
+    from thunder_tpu.analysis import budget
+
+    assert [budget.rms_norm_block_rows(d, 2) for d in (512, 1536, 4096, 6144)] == [256, 256, 256, 128]
+    bf = jnp.bfloat16
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, bf, sharding=one_chip)
+
+    with jax.enable_x64(False), pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pallasex, "_interpret", lambda: False)   # through Mosaic, not interpreted
+        text = jax.jit(lambda x, w: pallasex.fused_rms_norm(x, w, 1e-5)).lower(
+            sds((rows, 6144)), sds((6144,))).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1

@@ -480,11 +480,11 @@ def test_attribute_puts_an_event_on_the_region_of_its_instruction():
 def test_scope_names_are_what_the_models_use():
     import inspect
 
-    from thunder_tpu.models import latent_moe, moe, sambay
+    from thunder_tpu.models import latent_moe, moe, sambay, shortcut_moe
     from thunder_tpu.serving import runner
 
     used = set()
-    for mod in (litgpt, sambay, latent_moe, moe, runner):
+    for mod in (litgpt, sambay, latent_moe, moe, shortcut_moe, runner):
         used |= set(re.findall(r'named_scope\("([\w/]+)"\)', inspect.getsource(mod)))
     used = {seg for name in used for seg in name.split("/")}
     assert used - {"rope"} <= PARTS, used - PARTS

@@ -205,6 +205,15 @@ def grouped_mlp_vmem_bytes(block_c: int, D: int, H: int,
     return w + xb + inter + out + acc
 
 
+def rms_norm_block_rows(D: int, itemsize: int) -> int:
+    """Rows a block of the fused RMSNorm kernel holds (pallasex `_rms_kernel`): the most of
+    256, 128, ... 8 whose working set fits the scoped VMEM limit: the input and the output
+    block in two buffers each and one float32 copy of the rows (what Mosaic allocates: 18.11M
+    for 256 rows of 6,144 bfloat16, 12 bytes a number, against its 16M). 256 up to a width of
+    4,096 in bfloat16, as it always was; 128 at 6,144."""
+    return next((rows for rows in (256, 128, 64, 32, 16) if within_vmem(rows * D * (4 * itemsize + 4))), 8)
+
+
 # scoped VMEM the ragged expert kernel asks Mosaic for: it streams weight tiles
 # and is the faster the larger (the more contiguous) they are; three eighths of
 # a v5e core's 128 MiB

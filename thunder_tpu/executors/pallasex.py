@@ -850,8 +850,12 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
     o_ref[:] = ((x * jax.lax.rsqrt(ms + eps)) * w).astype(o_ref.dtype)
 
 
-def fused_rms_norm(x2d, w, eps: float = 1e-6, block_n: int = 256):
+def fused_rms_norm(x2d, w, eps: float = 1e-6, block_n: int | None = None):
+    from ..analysis import budget as _budget
+
     N, D = x2d.shape
+    if block_n is None:  # 256 rows, fewer where a row is too wide for that many in VMEM
+        block_n = _budget.rms_norm_block_rows(D, x2d.dtype.itemsize)
     block_n = min(block_n, N)
     return pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),

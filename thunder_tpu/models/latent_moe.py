@@ -8,9 +8,10 @@ One layer, ``x`` the residual stream, RMSNorm, no biases:
     h = x + MLA(norm_1(x));   y = h + Experts(norm_2(h))
 
 * **MLA** (multi-head latent attention). Queries through a low-rank pair:
-  ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb``, a head ``[q_nope | q_rope]``.
+  ``c_q = s_q RMSNorm(x W_qa)``, ``q = c_q W_qb``, a head ``[q_nope | q_rope]``.
   Keys and values through ONE latent a token: ``[c | k_r] = x W_kva``,
-  ``c_kv = RMSNorm(c)``, ``k_rope = rope(k_r)`` (one head, shared by all);
+  ``c_kv = s_kv RMSNorm(c)``, ``k_rope = rope(k_r)`` (one head, shared by all;
+  ``s_q``, ``s_kv``: ``Config.q_lora_scale``, ``kv_lora_scale``, 1 in DeepSeek's layer);
   a head's ``[k_nope | v] = c_kv W_kvb``. Scores are ``(q_nope . k_nope +
   q_rope . k_rope) * scale`` under a causal float32 softmax. Rope is on
   interleaved pairs with YaRN frequencies (arXiv:2309.00071); queries are
@@ -77,6 +78,10 @@ class Config:
     mscale: float = 1.0
     mscale_all_dim: float = 0.0      # 0: the softmax scale is plain qk_head_dim ** -0.5
     query_scaling_beta: float = 0.0
+    # what the two normed low-rank streams are multiplied by (LongCat-Flash's
+    # ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: ``(n_embd / rank) ** 0.5``); 1 traces nothing
+    q_lora_scale: float = 1.0
+    kv_lora_scale: float = 1.0
 
     @property
     def qk_head_dim(self) -> int:
@@ -129,6 +134,13 @@ def rope_tables(cfg: Config):
             jnp.asarray(np.sin(angle) * factor, jnp.float32))
 
 
+def _scaled(x, factor: float):
+    """``x * factor`` in float32, handed back in x's type; ``x`` itself at 1."""
+    if factor == 1.0:
+        return x
+    return ltorch.to(ltorch.to(x, dtype=dtypes.float32) * factor, dtype=x.dtype)
+
+
 def rope_interleaved(x, cos, sin):
     """Rope on the interleaved pairs ``(x[2i], x[2i + 1])`` of the last axis, in
     float32, handed back in x's type; cos and sin broadcast against
@@ -166,7 +178,8 @@ class LatentAttention(nn.Module):
         cfg = self.cfg
         B, T, _ = x.shape
         cos, sin, qscale = where
-        q = ltorch.reshape(self.q_b(self.q_norm(self.q_a(x))), (B, T, cfg.n_head, cfg.qk_head_dim))
+        c_q = _scaled(self.q_norm(self.q_a(x)), cfg.q_lora_scale)
+        q = ltorch.reshape(self.q_b(c_q), (B, T, cfg.n_head, cfg.qk_head_dim))
         q = ltorch.to(ltorch.to(q, dtype=dtypes.float32) * qscale, dtype=q.dtype)
         return (q[..., :cfg.qk_nope_head_dim],
                 rope_interleaved(q[..., cfg.qk_nope_head_dim:], cos, sin))
@@ -177,7 +190,8 @@ class LatentAttention(nn.Module):
         r = self.cfg.kv_lora_rank
         cos, sin, _ = where
         ckr = self.kv_a(x)
-        return self.kv_norm(ckr[..., :r]), rope_interleaved(ckr[..., r:], cos[:, :, 0], sin[:, :, 0])
+        return (_scaled(self.kv_norm(ckr[..., :r]), self.cfg.kv_lora_scale),
+                rope_interleaved(ckr[..., r:], cos[:, :, 0], sin[:, :, 0]))
 
     def rows(self, c, k_rope):
         """(B, T, row): the pool's rows, zeros over the padding."""
@@ -334,12 +348,18 @@ class LatentMoE(nn.Module):
         super().__init__()
         self.cfg = cfg
         self.wte = nn.Embedding(cfg.vocab_size, cfg.n_embd, dtype=dtype)
-        self.h = nn.ModuleList([Block(cfg, dtype) for _ in range(cfg.n_layer)])
+        self.h = nn.ModuleList(self.blocks(cfg, dtype))
         self.ln_f = nn.RMSNorm(cfg.n_embd, eps=cfg.norm_eps, dtype=dtype)
         self.lm_head = nn.Linear(cfg.n_embd, cfg.vocab_size, bias=False, dtype=dtype)
         cos, sin = rope_tables(cfg)
         self.register_buffer("cos", cos)
         self.register_buffer("sin", sin)
+
+    @staticmethod
+    def blocks(cfg: Config, dtype) -> list:
+        """The served layers, in order (a model with another layer brings its own:
+        models/shortcut_moe.py)."""
+        return [Block(cfg, dtype) for _ in range(cfg.n_layer)]
 
     def where(self, pos):
         """What a layer needs of the positions pos (B, T) int32: the rope rows
@@ -360,10 +380,13 @@ class LatentMoE(nn.Module):
         pos = ltorch.expand(ltorch.reshape(prims.iota(T, dtype=dtypes.int32, device=idx.device), (1, T)),
                             (B, T))
         where = self.where(pos)
-        x = self.wte(idx)
+        return self.lm_head(self.ln_f(self.through(self.wte(idx), where)))
+
+    def through(self, x, where):
+        """The layers over whole sequences x (B, T, D) with no cache."""
         for block in self.h:
             x = block(x, where)
-        return self.lm_head(self.ln_f(x))
+        return x
 
     def serving(self):
         """This model as the paged engine serves it (serving/runner.py)."""

@@ -213,27 +213,38 @@ def ragged_experts(xf, idx, w, panels, held: tuple, *, live=None, n_routed: int 
 class HeldExperts(nn.Module):
     """An expert layer that is TOLD WHICH EXPERTS IT HOLDS, as one chip of an
     expert-parallel group holds them (DeepSeek-V3's layer, arXiv:2412.19437):
-    the router scores all ``n_routed`` experts in float32 (sigmoid, with a
-    per-expert bias added for the choice only), the ``n_expert_per_token``
+    the router scores all ``n_routed`` experts in float32 (``score``: a sigmoid
+    an expert, or one softmax over them all; a per-expert bias is added for the
+    choice only), the ``n_expert_per_token``
     largest are chosen, their scores
     normalised (``norm_topk_prob``) and scaled; the layer computes the rows of
     its own experts ``held = (lo, hi)`` through ``ragged_experts``, drops no
     token, leaves out what the absent experts would add, and adds the shared
     expert, which every chip of the group computes alike. On one chip it runs
-    without the exchange; nothing stands in for the other chips."""
+    without the exchange; nothing stands in for the other chips.
+
+    ``n_zero`` further router outputs, numbered after the routed experts, are
+    ZERO-COMPUTE (identity) experts (LongCat-Flash,
+    arXiv:2509.01322): one of them gives the token back as it is, so what they add is
+    ``(sum of the chosen ones' weights) * x``. A token is at home where its
+    attention runs, so this chip computes that part for every token: no row of
+    it enters a ragged group and no panel is read for it."""
 
     def __init__(self, n_embd: int, width: int, n_routed: int, held: tuple, n_expert_per_token: int,
                  *, n_shared: int = 1, norm_topk_prob: bool = True, routed_scaling_factor: float = 1.0,
-                 dtype=jnp.float32):
+                 score: str = "sigmoid", n_zero: int = 0, dtype=jnp.float32):
         super().__init__()
         lo, hi = held
         if not 0 <= lo < hi <= n_routed:
             raise ValueError(f"experts held [{lo}, {hi}) are no range of the {n_routed} routed")
+        if score not in ("sigmoid", "softmax"):
+            raise ValueError(f"a router scores by 'sigmoid' or 'softmax', not {score!r}")
         self.n_routed, self.held, self.k = n_routed, (lo, hi), n_expert_per_token
         self.norm_topk_prob, self.scaling = norm_topk_prob, routed_scaling_factor
+        self.score, self.n_zero = score, n_zero
         e = hi - lo
-        self.gate = nn.Linear(n_embd, n_routed, bias=False, dtype=dtype)
-        self.e_score_correction_bias = nn.Parameter(jnp.zeros((n_routed,), jnp.float32))
+        self.gate = nn.Linear(n_embd, n_routed + n_zero, bias=False, dtype=dtype)
+        self.e_score_correction_bias = nn.Parameter(jnp.zeros((n_routed + n_zero,), jnp.float32))
         self.w_gate = nn.Parameter(jnp.zeros((e, n_embd, width), dtype))
         self.w_up = nn.Parameter(jnp.zeros((e, n_embd, width), dtype))
         self.w_down = nn.Parameter(jnp.zeros((e, width, n_embd), dtype))
@@ -249,7 +260,7 @@ class HeldExperts(nn.Module):
 
         f32 = dtypes.float32
         logits = ltorch.linear(ltorch.to(xf, dtype=f32), ltorch.to(self.gate.weight, dtype=f32))
-        s = ltorch.sigmoid(logits)
+        s = ltorch.sigmoid(logits) if self.score == "sigmoid" else ltorch.softmax(logits, -1)
         _, idx = ltorch.topk(s + self.e_score_correction_bias, self.k, -1)
         w = ltorch.take_along_dim(s, idx, 1)
         if self.norm_topk_prob:
@@ -258,8 +269,9 @@ class HeldExperts(nn.Module):
 
     def forward(self, x, live=None, counted=None, counted_rows=None):
         """x (B, T, D); live (B * T,) bool or None marks the tokens that are no
-        padding. With ``counted`` (a list) the layer appends its four
-        ``serving.runner.ROUTING_COUNTERS`` of this call as one (4,) int32, of
+        padding. With ``counted`` (a list) the layer appends its
+        ``serving.runner.ROUTING_COUNTERS`` of this call as one int32 vector
+        (the first four; with identity experts the fifth too), of
         the tokens ``counted_rows`` (B * T,) bool marks where it is given (the
         decode rows of a program that runs a prompt chunk beside them)."""
         from ..core import dtypes
@@ -270,9 +282,11 @@ class HeldExperts(nn.Module):
         xf = ltorch.reshape(x, (N, D))
         with named_scope("moe_router"):
             idx, w = self.route(xf)
+        # which of a token's choices are identity experts: they enter no group below (idx >= hi)
+        zero = ltorch.ge(idx, self.n_routed) if self.n_zero else None
         with named_scope("moe_experts"):
             out, counts, here = ragged_experts(xf, idx, w, (self.w_gate, self.w_up, self.w_down),
-                                               self.held, live=live, n_routed=self.n_routed)
+                                               self.held, live=live, n_routed=self.n_routed + self.n_zero)
             if counted is not None:
                 i32 = dtypes.int32
                 if counted_rows is not None:
@@ -285,12 +299,20 @@ class HeldExperts(nn.Module):
                         ltorch.where(here, flat - lo, hi - lo), hi - lo + 1)[:, :hi - lo], dtype=i32), 0)
                 routed = (ltorch.full((), N * self.k, dtype=i32, device=xf.device) if live is None
                           else ltorch.sum(ltorch.to(live, dtype=i32)) * self.k)
-                counted.append(ltorch.stack([
-                    ltorch.to(routed, dtype=i32),
-                    ltorch.sum(ltorch.to(here, dtype=i32)),
-                    ltorch.sum(ltorch.to(ltorch.gt(counts, 0), dtype=i32)),
-                    ltorch.amax(counts)], 0))
-            out = ltorch.to(out, dtype=x.dtype)
+                tally = [ltorch.to(routed, dtype=i32),
+                         ltorch.sum(ltorch.to(here, dtype=i32)),
+                         ltorch.sum(ltorch.to(ltorch.gt(counts, 0), dtype=i32)),
+                         ltorch.amax(counts)]
+                if self.n_zero:  # rows of the counted tokens that chose an identity expert
+                    chose = zero if live is None else ltorch.logical_and(zero, ltorch.unsqueeze(live, 1))
+                    tally.append(ltorch.sum(ltorch.to(chose, dtype=i32)))
+                counted.append(ltorch.stack(tally, 0))
+            if not self.n_zero:
+                out = ltorch.to(out, dtype=x.dtype)
+        if self.n_zero:
+            with named_scope("zero_experts"):  # added in float32, rounded once with the held part
+                g0 = ltorch.sum(ltorch.where(zero, w, 0.0), -1, keepdim=True)
+                out = ltorch.to(out + ltorch.to(xf, dtype=dtypes.float32) * g0, dtype=x.dtype)
         if self.shared_width:
             with named_scope("shared_expert"):
                 out = out + self.shared_down(ltorch.silu(self.shared_gate(xf)) * self.shared_up(xf))

@@ -151,6 +151,7 @@ PARTS = frozenset({
     "embed", "attn", "kv_write", "mlp", "head",                           # models/litgpt.py, serving/runner.py
     "mamba", "gmu", "window_attn", "full_attn", "cross_attn",             # models/sambay.py
     "mla_attn", "moe_router", "moe_experts", "shared_expert",             # models/latent_moe.py, moe.py
+    "zero_experts", "dense_ffn",                                          # models/moe.py, shortcut_moe.py
 })
 PASSES = ("fwd", "bwd", "recompute", "optimizer")
 UNSCOPED = "unscoped"

@@ -80,10 +80,13 @@ LANES = 128  # a TPU vector register's lanes: what a pool row should fill
 
 # What a decode program whose layers route tokens to experts counts of one step, summed over
 # its layers (``serve.<name>`` on the bus): rows routed (live tokens x experts a token), those
-# on experts held here, held experts with a row, the most rows on one held expert. A layer
-# leaves its four in ``step.shared["counted"]`` when it is traced with the bus on, the program
-# then has one small output more, and the scheduler records it as the step's tokens land.
-ROUTING_COUNTERS = ("moe.rows_routed", "moe.rows_held", "moe.experts_touched", "moe.rows_max")
+# on experts held here, held experts with a row, the most rows on one held expert; and, only
+# where the layers have zero-compute (identity) experts, a fifth: rows that chose one of those.
+# A layer leaves its counts in ``step.shared["counted"]`` when it is traced with the bus on, the
+# program then has one small output more (as many numbers as its layers count, in this order),
+# and the scheduler records it as the step's tokens land.
+ROUTING_COUNTERS = ("moe.rows_routed", "moe.rows_held", "moe.experts_touched", "moe.rows_max",
+                    "moe.rows_zero")
 
 
 def heads_a_row(n_kv_heads: int, head_size: int) -> int:
@@ -571,8 +574,8 @@ class PagedGPTRunner:
         (Bcap, n_pages_max) int32 page table; pos (Bcap,) int32 — each
         sequence's write position (= tokens already cached; idle slots carry
         pos 0 and a null-page row). Returns (logits (Bcap, V), new state), and
-        after them the (4,) int32 ``ROUTING_COUNTERS`` of the step where the
-        layers counted them (bus on at trace time)."""
+        after them the int32 ``ROUTING_COUNTERS`` of the step (four, or five) where
+        the layers counted them (bus on at trace time)."""
         step = self._decode_step(tables, pos)
         self.model.begin(step)
         x, state = self._run_layers(step, self._embed(toks), state)
