@@ -415,6 +415,47 @@ def test_decode_rows_beside_a_chunk_share_its_ragged_call_and_give_the_same_toke
     assert symbols.count("paged_latent_attention") == 2 * layers   # the chunk's queries; the decode rows
 
 
+@pytest.mark.parametrize("mixes", [True, False], ids=["chunks-mixed", "chunks-two-programs"])
+def test_arrivals_join_the_step_in_flight_and_a_first_token_may_end_its_request(mixes):
+    """A request decodes and three arrive two passes apart: a whole-prompt bucket whose first
+    token is its `eos_id`, two through chunks. Nothing lands at an activation: every step but the
+    first is dispatched with the step before unfetched and its routing counters land with it,
+    every activation but the first finds a step in flight, the ended request costs one step
+    whose token is thrown away (and whose rows the program still routed), and each request has the tokens it has served
+    alone."""
+    model = seeded(TINY)
+    toks = tokens(140, seed=4)
+    requests = [(toks[:12], 40), (toks[5:25], 9), (toks[:50], 6), (toks[:130], 6)]
+    alone = [served(model, [r])[0][0].new_tokens for r in requests]
+    eng = ServingEngine(model, **dict(ENGINE, max_batch=3))
+    eng._mixes = mixes
+    observability.enable()
+    try:
+        observability.reset()
+        futs = []
+        for i, (p, n) in enumerate(requests):
+            futs.append(eng.submit(p, max_new_tokens=n, eos_id=int(alone[1][0]) if i == 1 else None))
+            for _ in range(2):
+                eng._step_once()
+                assert eng._inflight is not None
+        eng.drain()
+        c = observability.counters()
+    finally:
+        observability.disable()
+        observability.reset()
+    got = [f.result(timeout=5) for f in futs]
+    assert got[1].finish_reason == "eos" and np.array_equal(got[1].new_tokens, alone[1][:1])
+    for i in (0, 2, 3):
+        assert np.array_equal(got[i].new_tokens, alone[i])
+    assert c["serve.decode_overlapped"] == c["serve.decode_steps"] - 1 == eng.decode_steps - 1
+    assert c["serve.activations"] == 4 and c["serve.activations_joined"] == 3
+    assert c["serve.decode_discarded"] == 1
+    assert c["serve.tokens"] == (40 - 1) + 2 * (6 - 1)
+    # the program routed the discarded row too: it cannot know
+    assert c["serve.moe.rows_routed"] == 2 * TINY.n_layer * (c["serve.tokens"] + 1)
+    assert c.get("serve.pool_copied", 0) == 0 and eng.cache.allocator.n_used == 0
+
+
 # -- both kernels through the v5e's compiler, at the published widths (no chip needed) --------------------
 
 @pytest.fixture(scope="module")

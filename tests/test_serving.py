@@ -861,15 +861,23 @@ def _mixed_requests(rng, gpt):
             for L, n, temp, seed in _MIXED]
 
 
-def _alone(gpt, reqs):
-    """What each request gives served alone, one engine for all, one request at a time."""
-    engine = _engine(gpt)
+def _submit(engine, req, **kw):
+    p, n, temp, seed = req
+    return engine.submit(p, max_new_tokens=n, temperature=temp, seed=seed, **kw)
+
+
+def _alone_on(engine, reqs):
+    """What each request gives served alone on ``engine``, one request at a time."""
     out = []
-    for p, n, temp, seed in reqs:
-        fut = engine.submit(p, max_new_tokens=n, temperature=temp, seed=seed)
+    for req in reqs:
+        fut = _submit(engine, req)
         engine.drain()
         out.append(fut.result().new_tokens)
     return out
+
+
+def _alone(gpt, reqs):
+    return _alone_on(_engine(gpt), reqs)
 
 
 def _bus_counters(run):
@@ -887,8 +895,8 @@ def _bus_counters(run):
 
 @pytest.mark.parametrize("end", ["length", "eos", "cancel", "preempt"])
 def test_requests_admitted_while_others_decode_give_what_each_gives_alone(gpt, rng, end):
-    """Admissions land the step in flight and the next step is fed from the host; every other
-    step is fed the sampler's output on the device. Token for token nothing may show: ends by
+    """Admissions join the step in flight: the next step is fed their first tokens on the device,
+    beside the sampler's output for every other sequence. Token for token nothing may show: ends by
     length, by an `eos_id` the seeded run is known to produce (the step dispatched before the
     end was seen is thrown away), by a cancelled Future, and across a preemption."""
     reqs = _mixed_requests(rng, gpt)
@@ -933,10 +941,9 @@ def test_requests_admitted_while_others_decode_give_what_each_gives_alone(gpt, r
         assert res.finish_reason == ("eos" if i in eos else "length")
     assert engine._inflight is None and engine.cache.allocator.n_used == 0
     assert all(s is None for s in engine._slots)
-    # a token is thrown away only where the host learned of an end at a commit, and then only
-    # if a step was in flight (an admission's prefill may have landed it: the eos case)
-    discarded = counters.get("serve.decode_discarded", 0)
-    assert discarded <= 1 and (end == "eos" or discarded == (end == "cancel"))
+    # a token is thrown away only where the host learned of an end at a commit with a step in
+    # flight: nothing lands that step any more, so each such end costs exactly one
+    assert counters.get("serve.decode_discarded", 0) == (end in ("eos", "cancel"))
     if end != "cancel":
         # committed tokens only: each request's first token is its prefill's
         assert counters["serve.tokens"] == sum(len(w) - 1 for w in want)
@@ -987,9 +994,9 @@ def test_a_request_alone_runs_the_decode_steps_it_needs_and_no_more(gpt, dense, 
 
 
 def test_most_decode_steps_are_dispatched_with_the_step_before_unfetched(gpt, rng):
-    """`serve.decode_overlapped` over `serve.decode_steps`: every step but the first after an
-    activation. Four requests admitted in one pass and one admitted later: 2 of 23 steps follow
-    an activation. Without `eos_id` or a cancel no token is thrown away."""
+    """`serve.decode_overlapped` over `serve.decode_steps`: every step but the first. Four
+    requests admitted in one pass, before any step, and one admitted later, which joins the step
+    in flight and lands nothing. Without `eos_id` or a cancel no token is thrown away."""
     engine = _engine(gpt)
 
     def run():
@@ -1006,14 +1013,15 @@ def test_most_decode_steps_are_dispatched_with_the_step_before_unfetched(gpt, rn
     assert got == [24, 20, 16, 12, 8]
     steps = counters["serve.decode_steps"]
     assert steps == engine.decode_steps == 23
-    assert counters["serve.decode_overlapped"] == steps - 2
-    assert counters["serve.decode_overlapped"] / steps > 0.9
+    assert counters["serve.decode_overlapped"] == steps - 1
+    assert counters["serve.activations"] == 5 and counters["serve.activations_joined"] == 1
     assert "serve.decode_discarded" not in counters
     assert counters["serve.tokens"] == sum(got) - len(got)
 
 
 def test_the_speculative_path_records_no_overlapped_step(gpt, rng):
-    """Verify needs the accepted count on the host: its path fetches before it dispatches."""
+    """Verify needs the accepted count on the host: its path fetches before it dispatches, so an
+    engine with a draft model lands at every activation and none of them joins a step."""
     engine = _engine(gpt, draft_gpt=gpt, spec_k=2)
 
     def run():
@@ -1025,6 +1033,270 @@ def test_the_speculative_path_records_no_overlapped_step(gpt, rng):
     res, counters = _bus_counters(run)
     assert res.n_new_tokens == 9 and counters["serve.decode_steps"] > 0
     assert "serve.decode_overlapped" not in counters and engine._inflight is None
+    assert counters["serve.activations"] == 1 and "serve.activations_joined" not in counters
+    assert not engine._firsts
+
+
+# ---------------------------------------------------------------------------
+# an activation joins the pipeline: a first token stays on the device for the next step
+# ---------------------------------------------------------------------------
+
+# (prompt length, tokens asked for, temperature, seed): request 0 outlives the others, so some
+# sequence is live in every pass; with chunks of 16 the prompts of 23 and 40 go through chunks
+_JOINING = [(6, 30, 0.0, 0), (23, 5, 0.9, 42), (9, 7, 0.0, 0), (40, 4, 0.7, 7), (12, 6, 1.1, 3)]
+
+
+def _joining_engine(gpt, kind: str, **kw):
+    """The engines this file builds that keep a step in flight: whole-prompt prefills, chunks
+    whose program carries the decode step, and chunks beside a decode program."""
+    if kind == "whole-prompt":
+        return _engine(gpt, **kw)
+    return _chunking_engine(gpt, kind == "chunks-mixed", **kw)
+
+
+_KINDS = ["whole-prompt", "chunks-mixed", "chunks-two-programs"]
+
+
+def _joining_requests(rng, gpt):
+    return [(rng.randint(0, gpt.cfg.vocab_size, (L,)).astype(np.int32), n, temp, seed)
+            for L, n, temp, seed in _JOINING]
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_an_activation_joins_the_step_in_flight_and_nothing_lands(gpt, rng, kind):
+    """Request 0 decodes and the others arrive two passes apart. Each prompt's program goes
+    behind the step in flight, its first token is merged into the next step's tokens on the
+    device and read after that step's dispatch: every step but the first finds the step before
+    it unfetched, every activation but the first finds a step in flight, no token is thrown
+    away, and every request has the tokens the engine gives it served alone."""
+    reqs = _joining_requests(rng, gpt)
+    want = _alone_on(_joining_engine(gpt, kind), reqs)
+    engine = _joining_engine(gpt, kind, max_batch=3)
+
+    def run():
+        futs = []
+        for req in reqs:
+            futs.append(_submit(engine, req))
+            for _ in range(2):
+                engine._step_once()
+                assert engine._inflight is not None   # nothing drained the pipeline
+        engine.drain()
+        return futs
+
+    futs, counters = _bus_counters(run)
+    for fut, w in zip(futs, want):
+        res = fut.result(timeout=5)
+        np.testing.assert_array_equal(res.new_tokens, w)
+        assert res.ttft_s > 0 and res.finish_reason == "length"
+    steps = counters["serve.decode_steps"]
+    assert steps == engine.decode_steps and counters["serve.decode_overlapped"] == steps - 1
+    assert counters["serve.activations"] == len(reqs)
+    assert counters["serve.activations_joined"] == len(reqs) - 1
+    assert "serve.decode_discarded" not in counters
+    assert counters["serve.tokens"] == sum(len(w) - 1 for w in want)
+    assert engine._inflight is None and not engine._firsts and not engine._feeds
+    assert engine.cache.allocator.n_used == 0 and all(s is None for s in engine._slots)
+
+
+@pytest.mark.parametrize("beside", [False, True], ids=["alone", "beside-a-decoding-request"])
+@pytest.mark.parametrize("kind", _KINDS)
+def test_a_first_token_that_ends_its_request_costs_one_step_whose_token_is_thrown_away(
+        gpt, rng, kind, beside):
+    """`eos_id` equal to the first token: the host learns of it after the slot's first step was
+    dispatched. The request retires with one token and its pages are free at once, that step's
+    token for the slot is the one `serve.decode_discarded` counts, and the request served next
+    in the slot gives what it gives alone."""
+    reqs = _joining_requests(rng, gpt)
+    want = _alone_on(_joining_engine(gpt, kind), reqs)
+    engine = _joining_engine(gpt, kind, max_batch=2)
+
+    def submit(i, **kw):
+        return _submit(engine, reqs[i], **kw)
+
+    def run():
+        futs = {}
+        if beside:
+            futs[0] = submit(0)
+            for _ in range(3):
+                engine._step_once()
+        used = engine.cache.allocator.n_used
+        futs[1] = submit(1, eos_id=int(want[1][0]))
+        while not futs[1].done():
+            engine._step_once()
+        assert engine.cache.allocator.n_used == used   # its pages are back as it retires
+        futs[2] = submit(2)                             # takes the slot the ended request left
+        engine.drain()
+        return futs
+
+    futs, counters = _bus_counters(run)
+    ended = futs.pop(1).result(timeout=5)
+    assert ended.finish_reason == "eos" and ended.n_new_tokens == 1
+    np.testing.assert_array_equal(ended.new_tokens, want[1][:1])
+    for i, fut in futs.items():
+        np.testing.assert_array_equal(fut.result(timeout=5).new_tokens, want[i])
+    assert counters["serve.decode_discarded"] == 1
+    assert counters["serve.tokens"] == sum(len(want[i]) - 1 for i in futs)
+    assert engine._inflight is None and engine.cache.allocator.n_used == 0
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_a_request_that_wants_one_token_takes_no_slot(gpt, rng, kind):
+    """The host knows at admission that the first token is the last: the request is never
+    activated, no step holds it, and its token is read behind the next dispatch like any other
+    first token. The request decoding beside it is not disturbed."""
+    reqs = _joining_requests(rng, gpt)
+    want = _alone_on(_joining_engine(gpt, kind), reqs)
+    engine = _joining_engine(gpt, kind, max_batch=2)
+
+    def run():
+        first = _submit(engine, reqs[0])
+        for _ in range(3):
+            engine._step_once()
+        ones = [_submit(engine, (p, 1, temp, seed)) for p, _, temp, seed in reqs[1:]]
+        engine.drain()
+        return first, ones
+
+    (first, ones), counters = _bus_counters(run)
+    np.testing.assert_array_equal(first.result(timeout=5).new_tokens, want[0])
+    for fut, w in zip(ones, want[1:]):
+        res = fut.result(timeout=5)
+        assert res.n_new_tokens == 1 and res.finish_reason == "length" and res.ttft_s > 0
+        np.testing.assert_array_equal(res.new_tokens, w[:1])
+    steps = counters["serve.decode_steps"]
+    assert steps == len(want[0]) - 1 and counters["serve.decode_overlapped"] == steps - 1
+    assert counters["serve.activations"] == 1 and "serve.decode_discarded" not in counters
+    assert counters["serve.retired"] == len(reqs) and engine.cache.allocator.n_used == 0
+
+
+def test_a_future_cancelled_before_its_first_token_is_read_retires_at_that_read(gpt, rng):
+    """The caller gives up between the dispatch of the prompt's program and the read of its first
+    token: the read finds the Future cancelled and retires the request, the step that was
+    dispatched with its slot throws that slot's token away, and the others go on."""
+    reqs = _joining_requests(rng, gpt)
+    want = _alone(gpt, reqs)
+    engine = _engine(gpt, max_batch=2)
+    dispatch = engine._dispatch
+
+    def run():
+        first = _submit(engine, reqs[0])
+        for _ in range(3):
+            engine._step_once()
+        gone = _submit(engine, reqs[2])
+
+        def cancel_then_dispatch(*args):
+            assert engine._firsts and gone.cancel()   # its prompt's program is dispatched, no more
+            engine._dispatch = dispatch
+            return dispatch(*args)
+
+        engine._dispatch = cancel_then_dispatch
+        engine._step_once()
+        assert engine._slots.count(None) == 1 and not engine._firsts   # retired at the read
+        last = _submit(engine, reqs[4])
+        engine.drain()
+        return first, gone, last
+
+    (first, gone, last), counters = _bus_counters(run)
+    assert gone.cancelled() and counters["serve.cancelled"] == 1
+    np.testing.assert_array_equal(first.result(timeout=5).new_tokens, want[0])
+    np.testing.assert_array_equal(last.result(timeout=5).new_tokens, want[4])
+    assert counters["serve.decode_discarded"] == 1
+    assert counters["serve.decode_overlapped"] == counters["serve.decode_steps"] - 1
+    assert engine._inflight is None and engine.cache.allocator.n_used == 0
+
+
+@pytest.mark.parametrize("kind", _KINDS)
+def test_a_resumed_request_joins_the_step_in_flight_with_the_token_it_kept(gpt, rng, kind):
+    """A preemption lands the step in flight (the victim keeps its token); the resume does not:
+    the victim's prompt and tokens are prefilled behind the step in flight, its last token goes
+    up from the host and is merged into the next step's tokens, and its stream goes on bit for
+    bit."""
+    reqs = _joining_requests(rng, gpt)
+    want = _alone_on(_joining_engine(gpt, kind), reqs)
+    engine = _joining_engine(gpt, kind, max_batch=2)
+
+    def submit(i, lane="interactive"):
+        return _submit(engine, reqs[i], lane=lane)
+
+    futs = {0: submit(0), 4: submit(4, lane="batch")}
+    for _ in range(4):
+        engine._step_once()
+    assert engine._preempt_one() and engine._inflight is None and engine.preempted == 1
+    futs[2] = submit(2)   # takes the victim's slot ahead of it: the victim waits for a slot
+
+    def run():
+        engine.drain()
+
+    _, counters = _bus_counters(run)
+    for i, fut in futs.items():
+        np.testing.assert_array_equal(fut.result(timeout=5).new_tokens, want[i])
+    assert engine.resumed == 1 and counters["serve.resumed"] == 1
+    # request 2 found no step in flight (the preemption had landed it); the victim found one
+    assert counters["serve.activations"] == 2 and counters["serve.activations_joined"] == 1
+    assert counters["serve.decode_overlapped"] == counters["serve.decode_steps"] - 1
+    assert "serve.decode_discarded" not in counters and engine.cache.allocator.n_used == 0
+
+
+def test_a_request_that_waits_for_pages_with_no_victim_to_spill_lands_nothing(gpt, rng):
+    """The head of the line cannot reserve its pages and no batch-lane sequence can be spilled
+    for it: the pipeline goes on as it was, pass after pass, until a retirement frees pages."""
+    reqs = _joining_requests(rng, gpt)
+    want = _alone(gpt, reqs)
+    engine = _engine(gpt, max_batch=3, n_pages=1 + 5 + 8)   # request 0 holds 5 pages, request 3 eight
+
+    def run():
+        futs = [_submit(engine, reqs[0])]
+        for _ in range(3):
+            engine._step_once()
+        futs += [_submit(engine, reqs[3]), _submit(engine, reqs[1])]   # request 1 wants 4: none is free
+        for _ in range(3):
+            engine._step_once()
+            assert engine._inflight is not None and len(engine._pending) == 1
+        engine.drain()
+        return futs
+
+    futs, counters = _bus_counters(run)
+    for i, fut in zip((0, 3, 1), futs):
+        np.testing.assert_array_equal(fut.result(timeout=5).new_tokens, want[i])
+    assert engine.preempted == 0
+    assert counters["serve.decode_overlapped"] == counters["serve.decode_steps"] - 1
+    assert counters["serve.activations_joined"] == 2
+
+
+@pytest.mark.parametrize("kind,name,i", [("whole-prompt", "prefill_cfn", 3), ("chunks-mixed", "prefill_cfn", 4),
+                                         ("chunks-two-programs", "chunk_cfn", 3)])
+def test_a_prompt_program_that_raises_with_a_step_in_flight_fails_that_request_only(gpt, rng, kind,
+                                                                                      name, i):
+    """The failure's clean-up lands the step in flight: the sequences in it keep their tokens and
+    go on. (A chunk's program that carries the decode step is that step: its failure is the
+    step's, `test_decode_failure_fails_active_batch`.)"""
+    reqs = _joining_requests(rng, gpt)
+    want = _alone_on(_joining_engine(gpt, kind), reqs)
+    engine = _joining_engine(gpt, kind, max_batch=3)
+    program = getattr(engine.runner, name)
+
+    def submit(i):
+        return _submit(engine, reqs[i])
+
+    def boom(*a, **kw):
+        raise RuntimeError("injected prompt failure")
+
+    first, second = submit(0), submit(2)
+    for _ in range(3):
+        engine._step_once()
+    assert engine._inflight is not None
+    used = engine.cache.allocator.n_used
+    setattr(engine.runner, name, boom)
+    failed = submit(i)
+    engine._step_once()
+    with pytest.raises(RuntimeError, match="injected"):
+        failed.result(timeout=5)
+    assert engine.cache.allocator.n_used == used
+    setattr(engine.runner, name, program)
+    again = submit(i)
+    engine.drain()
+    for j, fut in ((0, first), (2, second), (i, again)):
+        np.testing.assert_array_equal(fut.result(timeout=5).new_tokens, want[j])
+    assert engine._inflight is None and engine.cache.allocator.n_used == 0
 
 
 # ---------------------------------------------------------------------------
@@ -1122,7 +1394,7 @@ def test_decode_rows_that_ride_in_a_chunks_program_give_what_the_two_programs_gi
 def test_a_mixed_step_is_counted_as_the_two_dispatches_were(gpt, rng):
     """One request decodes, a prompt of 40 arrives: its chunks at 0, 16 and 32 (a rung of 8) each
     carry a decode step (`serve.decode_mixed` 3 of 11), the step after its activation is fed
-    from the host, and every counter of the decode step and of the chunks reads what it reads
+    its first token on the device, and every counter of the decode step and of the chunks reads what it reads
     with a chunk program and a decode program a pass."""
     def run(mixes):
         engine = _chunking_engine(gpt, mixes, max_batch=4)
@@ -1150,7 +1422,8 @@ def test_a_mixed_step_is_counted_as_the_two_dispatches_were(gpt, rng):
     assert {k: one[k] for k in same} == {k: two[k] for k in same}
     assert one["serve.decode_steps"] == engine.decode_steps == 11
     assert one["serve.tokens"] == (12 - 1) + (4 - 1)
-    assert one["serve.decode_overlapped"] == 11 - 2   # the first step, and the one after the activation
+    assert one["serve.decode_overlapped"] == 11 - 1   # the first step
+    assert one["serve.activations"] == 2 and one["serve.activations_joined"] == 1
     assert one["serve.paged.chunk_pages_spanned"] == 3 * 8 and one["serve.paged.chunk_pages_live"] == 2 + 4 + 5
     assert one["serve.paged.pages_spanned"] == 11 * 4 * 8
     # the sequence that was chunked joins the decode step one pass later than it did: its rows

@@ -116,8 +116,10 @@ def test_prefill_then_decode_agrees_with_the_reference_alone_and_batched(engine)
 
 def test_sequences_admitted_while_others_decode_give_what_each_gives_alone(model):
     """One decode step stays in flight: window pages are taken and handed back for the step
-    being dispatched while the step before it may still read them, and an admission lands the
-    step in flight. Token for token nothing may show, and no page of either kind is left."""
+    being dispatched while the step before it may still read them, and an admission joins the
+    step in flight: its final chunk's program runs behind it and the first token is merged into
+    the next step's tokens on the device. Token for token nothing may show, no page of either
+    kind is left, and only the first step finds no step before it unfetched."""
     eng = engine_for(model, max_batch=3)
     reqs = list(zip(prompts([p for p, _ in SAMPLE], seed=7), [n for _, n in SAMPLE]))
     alone = [serve(eng, [p], [n])[0] for p, n in reqs]
@@ -139,8 +141,9 @@ def test_sequences_admitted_while_others_decode_give_what_each_gives_alone(model
     assert eng._inflight is None
     assert eng.cache.allocator.n_used == 0 and eng.cache.window_allocator.n_used == 0
     steps = counters["serve.decode_steps"]
-    # four activations in a run of about ninety steps, and the passes whose only work is a chunk
-    assert counters["serve.decode_overlapped"] / steps > 0.85
+    # four activations in a run of about ninety steps: two before any step, two that join
+    assert counters["serve.decode_overlapped"] == steps - 1
+    assert counters["serve.activations"] == 4 and counters["serve.activations_joined"] == 2
     assert "serve.decode_discarded" not in counters
     assert counters["serve.tokens"] == sum(n - 1 for _, n in SAMPLE)
 
@@ -204,6 +207,76 @@ def test_a_request_alone_leaves_its_slot_as_its_last_needed_step_left_it(model):
     witness.drain()
     assert np.array_equal(fut.result(timeout=5).new_tokens[:n], res.new_tokens)
     assert any(not np.array_equal(a, b) for a, b in zip(kept, recurrent_rows(witness, 0)))
+
+
+@pytest.mark.parametrize("beside", [False, True], ids=["alone", "beside-a-decoding-request"])
+@pytest.mark.parametrize("ended", [0, 2], ids=["whole-prompt", "chunked"])
+def test_a_first_token_that_ends_its_request_leaves_its_slot_fit_for_the_next(model, ended, beside):
+    """`eos_id` equal to the first token, which the host reads after the slot's first decode step
+    was dispatched: that step has advanced the slot's scan state and conv tail and written a
+    window page, and its token is the one thrown away. The request retires with one token and no
+    page of either kind; the request that takes the slot next starts its recurrent rows anew in
+    its prefill and gives the tokens it gives served alone."""
+    reqs = list(zip(prompts([p for p, _ in SAMPLE], seed=13), [n for _, n in SAMPLE]))
+    alone = [serve(engine_for(model), [p], [n])[0] for p, n in reqs]
+    eng = engine_for(model, max_batch=2)
+    observability.enable()
+    observability.reset()
+    try:
+        futs = {}
+        if beside:
+            futs[1] = eng.submit(reqs[1][0], max_new_tokens=reqs[1][1])
+            for _ in range(3):
+                eng._step_once()
+        held = (eng.cache.allocator.n_used, eng.cache.window_allocator.n_used)
+        p, n = reqs[ended]
+        first = eng.submit(p, max_new_tokens=n, eos_id=int(alone[ended].new_tokens[0]))
+        while not first.done():
+            eng._step_once()
+        assert (eng.cache.allocator.n_used, eng.cache.window_allocator.n_used) == held
+        slot = eng._slots.index(None)
+        assert all(np.abs(r).max() > 0 for r in recurrent_rows(eng, slot))   # what the step left
+        futs[3] = eng.submit(reqs[3][0], max_new_tokens=reqs[3][1])
+        eng._step_once()
+        assert eng._chunking.get(slot) is not None or eng._slots[slot] is not None
+        eng.drain()
+        counters = observability.counters()
+    finally:
+        observability.disable()
+        observability.reset()
+    res = first.result(timeout=5)
+    assert res.finish_reason == "eos" and res.n_new_tokens == 1
+    assert res.new_tokens[0] == alone[ended].new_tokens[0]
+    for i, fut in futs.items():
+        assert np.array_equal(fut.result(timeout=5).new_tokens, alone[i].new_tokens)
+    assert counters["serve.decode_discarded"] == 1
+    # alone, the discarded step lands in a pass with no live sequence and the next request's
+    # first step has none before it
+    assert counters["serve.decode_overlapped"] == counters["serve.decode_steps"] - (1 if beside else 2)
+    assert eng._inflight is None and not eng._firsts
+    assert eng.cache.allocator.n_used == 0 and eng.cache.window_allocator.n_used == 0
+
+
+def test_a_request_that_wants_one_token_is_served_beside_a_decoding_one(model):
+    """It takes no slot and no step: its prompt's program writes the slot's recurrent rows and
+    nothing reads them, and the next request into the slot starts them anew."""
+    reqs = list(zip(prompts([p for p, _ in SAMPLE], seed=17), [n for _, n in SAMPLE]))
+    alone = [serve(engine_for(model), [p], [n])[0] for p, n in reqs]
+    eng = engine_for(model, max_batch=2)
+    long = eng.submit(reqs[1][0], max_new_tokens=reqs[1][1])
+    for _ in range(3):
+        eng._step_once()
+    ones = [eng.submit(reqs[i][0], max_new_tokens=1) for i in (0, 2)]
+    for _ in range(4):
+        eng._step_once()
+    last = eng.submit(reqs[3][0], max_new_tokens=reqs[3][1])
+    eng.drain()
+    for i, fut in zip((0, 2), ones):
+        res = fut.result(timeout=5)
+        assert res.n_new_tokens == 1 and res.new_tokens[0] == alone[i].new_tokens[0]
+    assert np.array_equal(long.result(timeout=5).new_tokens, alone[1].new_tokens)
+    assert np.array_equal(last.result(timeout=5).new_tokens, alone[3].new_tokens)
+    assert eng.cache.allocator.n_used == 0 and eng.cache.window_allocator.n_used == 0
 
 
 def test_the_halved_window_control_fails(engine):

@@ -44,12 +44,22 @@ The plain decode path keeps ONE decode step in flight: a pass of the loop
 dispatches the next step, whose tokens are the sampler's output of the step
 before and never leave the device, and only then fetches and commits that
 step before's tokens, so admission, uploads, the dispatch and the runtime's
-wake-up all run under a program the chip already has. Whatever changes the
-slots for another reason (an activation, a preemption, a failure, stop)
-lands the step in flight first (``_land``). A sequence whose last token by
-count is in flight is left out of the next step, and a step with no live
-sequence is never dispatched; an end the host learns at commit (``eos_id``, a
-cancelled Future) costs one step whose token is thrown away.
+wake-up all run under a program the chip already has. An activation JOINS
+that pipeline: the prompt's program and the sampler of its first token are
+dispatched behind the step in flight and nothing is fetched; the slot is live
+at once, its first token is written into the next step's tokens on the device
+(``_merge``, as is a token the host owns: a resumed victim's, a prefix hit's),
+and the host reads it only after that next step is dispatched, behind the
+fetch and commit of the step before, and commits it before the slot's token of
+that step. So what is in flight is at most one decode step, the prompt
+programs dispatched since, and their first tokens. What needs the host level
+with the device lands all of it first (``_land``): a preemption, a failure,
+stop and drain, a pass with no live sequence, and the speculative path, which
+fetches before it dispatches. A sequence whose last token by count is in
+flight is left out of the next step, a request that wants one token is never
+activated, and a step with no live sequence is never dispatched; an end the
+host learns at a commit (``eos_id``, a cancelled Future; a first token's too)
+costs one step whose token is thrown away.
 
 Per-request observability rides the existing bus: request-id-tagged spans,
 ``serve.*`` counters, and flight-recorder records per decode iteration
@@ -148,7 +158,10 @@ class _Request:
 
 @dataclass
 class _Step:
-    """A decode step in flight: dispatched, its sampled tokens not fetched."""
+    """A decode step in flight: dispatched, its sampled tokens not fetched. A
+    sequence activated since the step before is in it from its first step on,
+    fed the first token that ``_First`` holds; that token is read and committed
+    before this step lands."""
 
     nxt: jax.Array               # (max_batch, 1) int32 on the device: the next step's tokens
     reqs: Dict[int, _Request]    # slot -> the sequence that was in the step
@@ -156,6 +169,18 @@ class _Step:
     # what the program counted of its own work (ROUTING_COUNTERS, int32 on the device), where
     # it was traced with the bus on and its layers route; it lands with the tokens
     counted: Optional[jax.Array] = None
+
+
+@dataclass
+class _First:
+    """A new sequence's first token in flight: sampled behind its prompt's
+    program, not read by the host. It is read before the first step that holds
+    the sequence becomes the step in flight (``_read_firsts``)."""
+
+    req: _Request
+    slot: int                    # where the prompt was prefilled; the sequence's, if it was activated
+    tok: jax.Array               # (1,) int32 on the device
+    t0: float = 0.0              # when a whole-prompt prefill began; 0.0 behind a final chunk
 
 
 def _sample_tokens(logits, seeds, pos, temps):
@@ -177,6 +202,12 @@ def _sample_step(logits, seeds, pos, temps):
     the (max_batch, 1) column the next step's program takes, so that they
     need not leave the device in between."""
     return _sample_tokens(logits, seeds, pos + 1, temps)[:, None]
+
+
+def _merge_token(toks, slot, tok):
+    """The (max_batch, 1) tokens of the next decode step with ``tok`` (1,), a
+    token that step's sampler did not make, in row ``slot``."""
+    return toks.at[slot, 0].set(tok[0])
 
 
 class ServingEngine:
@@ -308,6 +339,7 @@ class ServingEngine:
         self.window = self.cache.window
         self._sampler = jax.jit(_sample_tokens)
         self._step_sampler = jax.jit(_sample_step)
+        self._merge = jax.jit(_merge_token)
 
         self.prefix = (PrefixCache(self.cache.allocator, page_size)
                        if prefix_sharing else None)
@@ -359,6 +391,10 @@ class ServingEngine:
         self._pt_dirty = True
         self._slots: List[Optional[_Request]] = [None] * max_batch
         self._inflight: Optional[_Step] = None  # the plain decode path's step in flight
+        self._firsts: deque = deque()  # _First: first tokens not read yet, oldest first
+        # slot -> (1,) token on the device that the next step is fed in place of the sampler's
+        # output of the step before: every sequence activated since the last dispatch
+        self._feeds: Dict[int, jax.Array] = {}
         # a chunk's program takes the decode step's rows too, and in a pass with a chunk due
         # the step rides in it (one read of the weights for both), where every layer of the
         # model can run both kinds of rows at once and the decode path is the plain one; the
@@ -524,8 +560,8 @@ class ServingEngine:
     def warmup(self, prompt_lens, max_new_tokens: int = 3) -> None:
         """Pre-compile the decode step and the prefill bucket for each
         prompt length (steady state then never recompiles). Three tokens a
-        request: the prefill's, one from a step fed from the host and one
-        from a step fed the sampler's output on the device."""
+        request: the prefill's, one from a step fed it on the device by the
+        merge and one from a step fed the sampler's output of the step before."""
         for L in prompt_lens:
             self.submit(np.zeros((L,), np.int32), max_new_tokens)
         self.drain()
@@ -858,13 +894,14 @@ class ServingEngine:
         """Spill the most recently admitted batch-lane sequence: free its
         pages (shared ones just decref — the prefix cache keeps them warm)
         and requeue it at the FRONT of the batch lane for resume."""
-        self._land()  # the victim keeps the token it has in flight
-        victim = None
-        for i, r in enumerate(self._slots):
-            if (r is not None and r.lane == "batch"
-                    and (victim is None
-                         or r.admit_seq > self._slots[victim].admit_seq)):
-                victim = i
+        def newest():
+            batch = [i for i, r in enumerate(self._slots) if r is not None and r.lane == "batch"]
+            return max(batch, key=lambda i: self._slots[i].admit_seq, default=None)
+
+        if newest() is None:
+            return False  # and nothing has landed: a page-starved head of line drains no pipeline
+        self._land()  # the victim keeps the token it has in flight, which may have been its last
+        victim = newest()
         if victim is None:
             return False
         req = self._slots[victim]
@@ -1003,7 +1040,8 @@ class ServingEngine:
 
     def _prefill_phase(self, req: _Request):
         """engine:prefill for one request's whole-prompt prefill or one of
-        its chunks: host preparation, uploads, dispatch, first-token fetch."""
+        its chunks: host preparation, uploads, the dispatch of the program and
+        of its first token's sampler. The token is read later (``_read_firsts``)."""
         return _obs_runtime.phase("engine:prefill", request=req.request_id,
                                   trace_id=req.trace_id)
 
@@ -1040,14 +1078,7 @@ class ServingEngine:
                         self.draft_cache.state, last, slot_dev)
                     self.draft_cache.rebind(dstate)
                 if not resumed:
-                    tok0 = self._sampler(logits,
-                                         jnp.asarray([req.seed], jnp.uint32),
-                                         jnp.asarray([L], jnp.int32),
-                                         jnp.asarray([req.temperature], jnp.float32))
-                    # behind the step in flight on the device: commit that one
-                    # while the prefill runs, then wait for the first token
-                    self._land()
-                    tok0 = int(np.asarray(tok0)[0])
+                    tok0 = self._sample_first(req, logits, L)
         except Exception as e:
             self._fail(req, e)
             self._drop_lost_pools(e)
@@ -1056,35 +1087,59 @@ class ServingEngine:
             self._window_trim(req, L)
         if self.prefix is not None:
             self.prefix.insert(prompt_eff, req.pages)
-        t_done = time.perf_counter()
-        if obs_on:
-            util = round(self.cache.utilization(), 4)
-            _obs_metrics.record_serve("prefills", event=True,
-                                      request=req.request_id, bucket=bucket,
-                                      prompt_len=L, ms=round((t_done - t0) * 1e3, 3),
-                                      pool_utilization=util)
-            _obs_metrics.record_serve("prefill_tokens", delta=L)
-            _obs_tel.observe("serve.prefill_ms", (t_done - t0) * 1e3)
-            _obs_tel.set_gauge("serve.pool_utilization", util)
-            _obs_tel.set_gauge("serve.pages_in_use", self.cache.allocator.n_used)
-            _obs_tel.set_gauge("serve.page_fragmentation",
-                               round(self.page_fragmentation(), 4))
-            _obs_trace.trace_event(req.trace_id, "prefill",
-                                   request=req.request_id,
-                                   dur_ms=(t_done - t0) * 1e3, bucket=bucket,
-                                   prompt_len=L)
         if resumed:
             # the spilled stream already owns its next token; no sampling
             # (and t_first keeps the FIRST life's stamp — TTFT is end-to-end)
+            self._record_prefill(req, t0, time.perf_counter())
             self._on_resume(req)
             self._activate(req, slot, pos=L, tok=req.tokens[-1])
             return
-        req.t_first = req.t_last = t_done
-        req.tokens.append(tok0)
-        if self._finished(req, tok0):
-            self._retire(req)
+        self._join(_First(req, slot, tok0, t0), pos=L)
+
+    def _record_prefill(self, req: _Request, t0: float, t_done: float) -> None:
+        """The records of a whole-prompt prefill that began at ``t0`` (bus on):
+        at ``t_done`` its first token was read, or a resumed one's program was
+        dispatched."""
+        if not _obs.enabled():
             return
-        self._activate(req, slot, pos=L, tok=tok0)
+        L, ms = len(req.prompt_eff), (t_done - t0) * 1e3
+        util = round(self.cache.utilization(), 4)
+        _obs_metrics.record_serve("prefills", event=True,
+                                  request=req.request_id, bucket=req.bucket,
+                                  prompt_len=L, ms=round(ms, 3),
+                                  pool_utilization=util)
+        _obs_metrics.record_serve("prefill_tokens", delta=L)
+        _obs_tel.observe("serve.prefill_ms", ms)
+        _obs_tel.set_gauge("serve.pool_utilization", util)
+        _obs_tel.set_gauge("serve.pages_in_use", self.cache.allocator.n_used)
+        _obs_tel.set_gauge("serve.page_fragmentation",
+                           round(self.page_fragmentation(), 4))
+        _obs_trace.trace_event(req.trace_id, "prefill",
+                               request=req.request_id,
+                               dur_ms=ms, bucket=req.bucket,
+                               prompt_len=L)
+
+    def _sample_first(self, req: _Request, logits, pos: int) -> jax.Array:
+        """Dispatch the sampler of ``req``'s first token, the one at position
+        ``pos``, behind its prompt's program: (1,) int32 that stays on the device
+        for the next step, its copy to the host started."""
+        tok = self._sampler(logits, jnp.asarray([req.seed], jnp.uint32),
+                            jnp.asarray([pos], jnp.int32),
+                            jnp.asarray([req.temperature], jnp.float32))
+        tok.copy_to_host_async()
+        return tok
+
+    def _join(self, first: _First, *, pos: int) -> None:
+        """A prompt's program and its first token's sampler are dispatched: the
+        sequence takes its slot now, to be fed that token on the device, and the
+        host reads the token behind the next dispatch (``_read_firsts``). A
+        request that wants one token takes no slot. With a draft model the next
+        step is fed from the host, so everything lands here."""
+        self._firsts.append(first)
+        if first.req.max_new_tokens > 1:
+            self._activate(first.req, first.slot, pos=pos, tok=first.tok)
+        if self.draft_cache is not None:
+            self._land()
 
     def _advance_prefills(self) -> bool:
         """Run queued prefill chunks under the per-iteration token budget.
@@ -1220,25 +1275,27 @@ class ServingEngine:
             self._activate(req, slot, pos=L_eff, tok=req.tokens[-1])
             return
         try:
-            tok0 = self._sampler(logits, jnp.asarray([req.seed], jnp.uint32),
-                                 jnp.asarray([L_eff], jnp.int32),
-                                 jnp.asarray([req.temperature], jnp.float32))
-            self._land()  # as in _prefill: commit the step in flight under the chunk
-            tok0 = int(np.asarray(tok0)[0])
+            tok0 = self._sample_first(req, logits, L_eff)
         except Exception as e:
             self._fail(req, e)
             return
-        req.t_first = req.t_last = time.perf_counter()
-        req.tokens.append(tok0)
-        if self._finished(req, tok0):
-            self._retire(req)
-            return
-        self._activate(req, slot, pos=L_eff, tok=tok0)
+        self._join(_First(req, slot, tok0), pos=L_eff)
 
-    def _activate(self, req: _Request, slot: int, *, pos: int, tok: int) -> None:
-        # its first token comes from the host, so the next step is fed from the
-        # host: no step may be in flight (where a first token was fetched, none is)
-        self._land()
+    def _activate(self, req: _Request, slot: int, *, pos: int, tok) -> None:
+        """``req`` takes ``slot`` and is in the next decode step, writing
+        position ``pos``. Nothing lands: the step in flight, if there is one,
+        does not hold the slot, and the next one is fed ``tok`` for it on the
+        device in place of the sampler's output (``_dispatch``). ``tok`` is a
+        first token in flight, (1,) on the device, or an int the host owns (a
+        resumed victim's last token, a prefix hit's last prompt token)."""
+        if _obs.enabled():
+            _obs_metrics.record_serve("activations")
+            if self._inflight is not None:
+                _obs_metrics.record_serve("activations_joined")
+        if isinstance(tok, int):
+            self._toks[slot] = tok
+            tok = self._upload(np.array([tok], np.int32))
+        self._feeds[slot] = tok
         self._slots[slot] = req
         self._page_tables[slot] = self.cache.page_table_row(req.pages,
                                                             self.n_pages_max)
@@ -1246,13 +1303,13 @@ class ServingEngine:
             self._window_cover(req, pos, pos + 1)
             self._win_tables[slot] = self._window_row(req)
         self._pos[slot] = pos
-        self._toks[slot] = tok
         self._seeds[slot] = req.seed
         self._temps[slot] = req.temperature
         self._pt_dirty = True
 
     def _clear_slot(self, i: int) -> None:
         self._slots[i] = None
+        self._feeds.pop(i, None)
         self._rest_slot(i)
         self._toks[i] = 0
         self._seeds[i] = 0
@@ -1334,14 +1391,16 @@ class ServingEngine:
 
     def _live_slots(self) -> List[int]:
         """The slots the next decode step carries: every sequence that wants a
-        token beyond the one it may have in flight. One whose last token by
-        count is in flight idles from this step on."""
+        token beyond those it may have in flight, a first token not read yet
+        and one of the step in flight. One whose last token by count is in
+        flight idles from this step on."""
         prev = self._inflight
+        unread = {id(first.req) for first in self._firsts}
         live = []
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
-            flying = prev is not None and prev.reqs.get(i) is req
+            flying = (id(req) in unread) + (prev is not None and prev.reqs.get(i) is req)
             if len(req.tokens) + flying < req.max_new_tokens:
                 live.append(i)
             else:
@@ -1358,7 +1417,10 @@ class ServingEngine:
         """Dispatch the decode step of the ``live`` slots through ``program``
         (``_decode_program``, or a chunk's program that takes the step's rows
         beside its own: ``_run_chunk``), then fetch and commit the step before
-        it. Returns what a failed dispatch raised (``_dispatch``), else None."""
+        it, and then the first tokens of the sequences activated since: their
+        prompts' programs ran behind that step, and the chip has the new step
+        queued while the host waits for them. Returns what a failed dispatch
+        raised (``_dispatch``), else None."""
         # taken out while the next step is dispatched: a failure's clean-up, which
         # lands "the step in flight", then finds none, and prev is settled below
         prev, self._inflight = self._inflight, None
@@ -1377,6 +1439,7 @@ class ServingEngine:
                 failed = e
             fetched = self._fetch(prev)
         self._commit_step(prev, fetched)
+        self._read_firsts()
         self._inflight = step
         return failed
 
@@ -1386,12 +1449,17 @@ class ServingEngine:
         V), what the program counted) — and its sampler. The token a sequence
         feeds is the one the sampler produced for it in ``prev``, the step in
         flight, still on the device; with no step in flight the tokens go up
-        from the host. After a failure every live sequence has failed, its
-        pages returned, and the exception goes on to the caller."""
+        from the host. A sequence activated since the last dispatch was in
+        neither: its token (``_feeds``) is merged in on the device, one small
+        program a token, so a first token need not have reached the host. After
+        a failure every live sequence has failed, its pages returned, and the
+        exception goes on to the caller."""
         phase = _obs_runtime.phase
         try:
             with phase("engine:upload"):
                 toks = prev.nxt if prev is not None else self._upload(self._toks[:, None])
+                for slot, tok in self._feeds.items():
+                    toks = self._merge(toks, self._upload(np.int32(slot)), tok)
                 pos = self._upload(self._pos)
             with phase("engine:dispatch"):
                 logits, counted = program(toks, self._pt_dev, pos)
@@ -1405,11 +1473,14 @@ class ServingEngine:
             # the packed step failed: every live sequence is implicated —
             # fail their futures and return their pages rather than hanging
             # the whole engine (pending requests still get admitted)
+            self._land()  # a first token not read yet may end its sequence before the failure does
             for i in live:
-                self._fail(self._slots[i], e)
-                self._clear_slot(i)
+                if self._slots[i] is not None:
+                    self._fail(self._slots[i], e)
+                    self._clear_slot(i)
             self._drop_lost_pools(e)
             raise
+        self._feeds.clear()
         self.decode_steps += 1
         if _obs.enabled():
             # what the step read and held follows from the positions it was given
@@ -1471,12 +1542,49 @@ class ServingEngine:
                 self._commit(i, req, int(nxt[i]), t_now)
 
     def _land(self) -> None:
-        """Fetch and commit the step in flight, if there is one: the one place
-        that does. Whatever changes the slots outside ``_decode`` calls it
-        first, so a dispatch that finds a step in flight finds every live
-        sequence in it."""
+        """Bring the host level with the device: fetch and commit the step in
+        flight, if there is one, and then the first tokens not read yet (none
+        of their sequences is in that step). The one place that does, for what
+        cannot go on beside a step in flight: a preemption (the victim keeps
+        its token in flight), a failure's clean-up, stop and drain, a pass with
+        no live sequence, the speculative path. An activation is not among
+        them (``_activate``)."""
         step, self._inflight = self._inflight, None
         self._commit_step(step, self._fetch(step))
+        self._read_firsts()
+
+    def _read_firsts(self) -> None:
+        """Read and commit the first tokens in flight, oldest first; each wait
+        ends when its prompt's program does, which is when ``t_first`` is
+        stamped. A token that ends its request retires it and frees the slot:
+        the token a step in flight made for the slot is then thrown away at
+        that step's commit, and the next prefill into the slot starts its
+        recurrent rows anew. A read that fails fails that request."""
+        while self._firsts:
+            first = self._firsts.popleft()  # out before anything below can land again
+            req, slot = first.req, first.slot
+            try:
+                with _obs_runtime.phase("engine:fetch"):
+                    tok = int(np.asarray(first.tok)[0])
+            except Exception as e:
+                self._fail(req, e)
+                if self._slots[slot] is req:
+                    self._clear_slot(slot)
+                self._drop_lost_pools(e)
+                continue
+            with _obs_runtime.phase("engine:commit"):
+                t_now = time.perf_counter()
+                if first.t0:
+                    self._record_prefill(req, first.t0, t_now)
+                req.t_first = req.t_last = t_now
+                req.tokens.append(tok)
+                activated = self._slots[slot] is req  # not one that wanted this token only
+                if activated:
+                    self._toks[slot] = tok
+                if self._finished(req, tok):
+                    self._retire(req)
+                    if activated:
+                        self._clear_slot(slot)
 
     def _record_state(self, active: int) -> None:
         """What the cached state of this decode step's sequences took, summed
