@@ -87,7 +87,7 @@ def split_qkv_rope(block, cfg, x_n, cos, sin):
     math can never drift between solo and continuously-batched decoding
     (the serving tests pin exact token equality between the two).
     Returns q (B, nh, T, hs), k/v (B, ng, T, hs)."""
-    from .models.litgpt import _apply_rope
+    from .models.litgpt import _apply_rope, norm_qk
 
     B, T, _ = x_n.shape
     nh, ng, hs = cfg.n_head, cfg.n_query_groups, cfg.head_size
@@ -97,6 +97,7 @@ def split_qkv_rope(block, cfg, x_n, cos, sin):
     q = ltorch.reshape(qkv[:, :, :, :q_per_kv, :], (B, T, nh, hs))
     k = ltorch.reshape(qkv[:, :, :, q_per_kv: q_per_kv + 1, :], (B, T, ng, hs))
     v = ltorch.reshape(qkv[:, :, :, q_per_kv + 1:, :], (B, T, ng, hs))
+    q, k = norm_qk(block.attn, q, k)
     q = ltorch.permute(q, (0, 2, 1, 3))
     k = ltorch.permute(k, (0, 2, 1, 3))
     v = ltorch.permute(v, (0, 2, 1, 3))

@@ -44,6 +44,9 @@ class Config:
     rope_base: int = 10000
     lm_head_bias: bool = False
     shared_embedding: bool = False
+    # an RMSNorm over head_size, with a learned weight, on every head of q and of k before
+    # rope (Qwen3's q_norm / k_norm; litgpt's key of the same name)
+    norm_qk: bool = False
     # recompute each transformer block in the backward instead of saving its
     # activations (remat.checkpoint -> RECOMPUTE_IN_BACKWARD machinery)
     activation_checkpoint: bool = False
@@ -169,8 +172,14 @@ class CausalSelfAttention(nn.Module):
         shape = (cfg.n_head + 2 * cfg.n_query_groups) * cfg.head_size
         self.attn = nn.Linear(cfg.n_embd, shape, bias=cfg.bias, dtype=dtype)
         self.proj = nn.Linear(cfg.n_head * cfg.head_size, cfg.n_embd, bias=cfg.bias, dtype=dtype)
+        if cfg.norm_qk:
+            self.norm_q = nn.RMSNorm(cfg.head_size, eps=cfg.norm_eps, dtype=dtype)
+            self.norm_k = nn.RMSNorm(cfg.head_size, eps=cfg.norm_eps, dtype=dtype)
 
-    def forward(self, x, cos, sin):
+    def forward(self, x, cos, sin, block_length=None):
+        """``block_length`` K: attention is BLOCK-causal, a position sees every earlier block
+        of K positions and the whole of its own (generation by diffusion over blocks); None
+        is plain causal."""
         cfg = self.cfg
         B, T, _ = x.shape
         nh, ng, hs = cfg.n_head, cfg.n_query_groups, cfg.head_size
@@ -184,6 +193,7 @@ class CausalSelfAttention(nn.Module):
         q = ltorch.reshape(q, (B, T, nh, hs))
         k = ltorch.reshape(k, (B, T, ng, hs))
         v = ltorch.reshape(v, (B, T, ng, hs))
+        q, k = norm_qk(self, q, k)
         q = ltorch.permute(q, (0, 2, 1, 3))  # (B, nh, T, hs)
         k = ltorch.permute(k, (0, 2, 1, 3))
         v = ltorch.permute(v, (0, 2, 1, 3))
@@ -191,7 +201,8 @@ class CausalSelfAttention(nn.Module):
         n_elem = cfg.rope_n_elem
         from ..parallel.context_parallel import current_seq_parallel_ctx
 
-        if 0 < n_elem <= hs and n_elem % 2 == 0 and current_seq_parallel_ctx() is None:
+        if (block_length is None and 0 < n_elem <= hs and n_elem % 2 == 0
+                and current_seq_parallel_ctx() is None):
             # fused rope+attention symbol (GQA included: the kernel indexes
             # kv blocks by q_head // group; a rotary width narrower than the
             # head included: the tables are (T, n_elem) and say it): the pallas
@@ -206,9 +217,29 @@ class CausalSelfAttention(nn.Module):
             if ng != nh:
                 k = _repeat_kv(k, q_per_kv)
                 v = _repeat_kv(v, q_per_kv)
-            y = ltorch.sdpa(q, k, v, is_causal=True, scale=1.0 / math.sqrt(hs))
+            # the flash kernels compare positions by index: a block-causal mask goes explicitly
+            mask = None if block_length is None else block_causal_mask(T, block_length, x.device)
+            y = ltorch.sdpa(q, k, v, attn_mask=mask, is_causal=mask is None, scale=1.0 / math.sqrt(hs))
         y = ltorch.reshape(ltorch.permute(y, (0, 2, 1, 3)), (B, T, nh * hs))
         return self.proj(y)
+
+
+def norm_qk(attn, q, k):
+    """The per-head RMSNorm of q and of k (any layout whose last dimension is the head's),
+    before rope, where ``cfg.norm_qk`` says so; else q and k as they are. The ONE place it is
+    applied from: ``CausalSelfAttention.forward`` and ``inference.split_qkv_rope`` both call
+    it, so the compiled forward and the served layers cannot drift."""
+    if not attn.cfg.norm_qk:
+        return q, k
+    return attn.norm_q(q), attn.norm_k(k)
+
+
+def block_causal_mask(T: int, block_length: int, device):
+    """(T, T) bool: position t sees position s where s's block is t's or an earlier one."""
+    from ..core import dtypes, prims
+
+    blk = ltorch.floor_divide(prims.iota(T, dtype=dtypes.int32, device=device), block_length)
+    return ltorch.ge(ltorch.unsqueeze(blk, 1), ltorch.unsqueeze(blk, 0))
 
 
 def _repeat_kv(x, n: int):

@@ -29,6 +29,11 @@ Two compiled programs:
   prefix. Rolled-back positions are simply never committed — their page
   slots hold stale values that the next committed token overwrites.
 
+* block — a pass of generation by diffusion over blocks (``block_cfn``): the
+  verify program with another mask. K rows a slot, true positions for rope and
+  the writes, the block's LAST position as every row's coverage; all K rows'
+  keys and values written every pass (docs/serving.md).
+
 All are pure functional: cached state goes in, updated state comes out. Every
 program CONSUMES the state it is given (`donated_argnums`): the caller rebinds
 the returned arrays and never reads the old ones again, so XLA writes the new
@@ -54,6 +59,7 @@ from typing import Optional
 
 from ..core.trace import named_scope
 from ..inference import cached_sdpa, split_qkv_rope
+from ..observability import events as _obs_events
 from ..observability import runtime as _obs_runtime
 from ..ops import clang, ltorch
 from .kv_pages import PagedKV, PagedLatent
@@ -219,13 +225,19 @@ class Step:
              ``last`` the true last token; ``slot`` the decode slot it is for
     chunk    ``tables[kind]`` (1, n_pages_max) the sequence's whole table;
              ``chunk_pages[kind]`` the pages this chunk writes; ``start_pos``;
-             ``q_pos`` (1, T) absolute positions; ``last`` relative to the
+             ``q_pos`` (1, T) absolute positions and ``mask_pos`` (1, T) what
+             each row's attention covers (``q_pos``; under a block length the
+             last position of the row's block); ``last`` relative to the
              chunk; ``slot``
     decode   ``tables[kind]`` (B, n_pages_max); ``pos`` (B,) write positions;
              ``page_of[kind]`` (B,) and ``slot_in_page`` (B,) where each
              token's k/v lands; ``seq_lens`` (B,); ``live`` (B,) bool, false
              for idle slots (they carry pos 0 and a null-page row)
-    verify   as decode with K1 tokens a sequence: ``pos_mat`` (B, K1);
+    verify   as decode with K1 tokens a sequence: ``pos_mat`` (B, K1) the TRUE
+             positions, for rope and the writes; ``mask_pos`` (B, K1) what each
+             row's attention covers (keys at positions <= it): ``pos_mat`` in a
+             speculative step, the block's last position in a block pass
+             (``block`` true: ``live`` (B,) says which slots hold a sequence);
              ``page_of[kind]`` and ``slot_in_page`` flat (B * K1,)
     mixed    a chunk's T rows and after them one row a decode slot: ``chunk``
              and ``decode``, the two programs' own ``Step``s; ``states`` and
@@ -304,6 +316,10 @@ class DenseBlock:
         y = attend(_spread_queries(q, self.pack, g), kp, vp, table, where, self.scale)
         return _own_lanes(y, self.pack, g)
 
+    def _tail(self, step, x, h):
+        """The block's output from its input and its attention's output (``block.tail``)."""
+        return self.block.tail(x, h)
+
     def _proj(self, x, y, T: int):
         """y (B, n_head, T, hs) attention output -> what the attention adds to ``x``."""
         cfg = self.cfg
@@ -331,7 +347,7 @@ class DenseBlock:
             kq = _repeat_kv(k, q_per_kv) if cfg.n_query_groups != cfg.n_head else k
             vq = _repeat_kv(v, q_per_kv) if cfg.n_query_groups != cfg.n_head else v
             h = self._proj(x, cached_sdpa(q, kq, vq, 0), T)
-        return self.block.tail(x, h), (kp, vp)
+        return self._tail(step, x, h), (kp, vp)
 
     def _decode_rows(self, step, q, k, v, state):
         """One token a sequence, q (B, n_head, 1, hs), k and v (B, n_query_groups, 1,
@@ -362,20 +378,20 @@ class DenseBlock:
         k_rows, v_rows = self._rows(k, v)
         kp = _write_pages(state[0], step.chunk_pages["full"], k_rows, ps)
         vp = _write_pages(state[1], step.chunk_pages["full"], v_rows, ps)
-        y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"], step.q_pos)
+        y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"], step.mask_pos)
         return y, (kp, vp)
 
     def decode(self, step, x, state):
         with named_scope("attn"):
             y, state = self._decode_rows(step, *self._qkv(step, x), state)
             h = self._proj(x, y, 1)
-        return self.block.tail(x, h), state
+        return self._tail(step, x, h), state
 
     def chunk(self, step, x, state):
         with named_scope("attn"):
             y, state = self._chunk_rows(step, *self._qkv(step, x), state)
             h = self._proj(x, y, x.shape[1])
-        return self.block.tail(x, h), state
+        return self._tail(step, x, h), state
 
     def mixed(self, step, x, state):
         """A chunk's T rows and, after them, one row a decode slot, x (1, T + B,
@@ -396,33 +412,55 @@ class DenseBlock:
             y_d, state = self._decode_rows(step.decode, seqs(q[:, :, T:]), seqs(k[:, :, T:]),
                                            seqs(v[:, :, T:]), state)
             h = self._proj(x, ltorch.cat([y_c, seqs(y_d)], 2), x.shape[1])
-        return self.block.tail(x, h), state
+        return self._tail(step, x, h), state
 
     def verify(self, step, x, state):
         """Writes k/v for ALL k+1 tokens at positions pos..pos+k. Rollback is
         free: the scheduler commits only the accepted prefix; rejected
         positions hold stale k/v that the next committed token's write
-        replaces before any mask admits it."""
+        replaces before any mask admits it. A row's coverage is ``step.mask_pos``:
+        its own position in a speculative verify step, its block's LAST position
+        in a pass of generation by diffusion over blocks (every row of a block
+        sees the whole block), which is what makes the two one method."""
         with named_scope("attn"):
             q, k, v = self._qkv(step, x)
             k_tok, v_tok = self._tokens(k, v)
             kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
             vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
             y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"],
-                            step.pos_mat)
+                            step.mask_pos)
             h = self._proj(x, y, x.shape[1])
-        return self.block.tail(x, h), (kp, vp)
+        return self._tail(step, x, h), (kp, vp)
+
+
+class RoutedBlock(DenseBlock):
+    """A dense-attention block whose tail routes its tokens to held experts
+    (models/block_moe.py: ``block.experts`` a ``moe.HeldExperts``): the tail is told
+    which rows are padding (``step.shared["live"]``: an idle slot, a bucket's tail enter
+    no group and read no panel) and, in the decode program and in a block pass traced
+    with the bus on, counts its routing (``ROUTING_COUNTERS``)."""
+
+    # a block's K rows a slot ride in no chunk's program yet: the engine runs the two apart
+    mixed = None
+
+    def _tail(self, step, x, h):
+        counted = None
+        if (step.program == "decode" or getattr(step, "block", False)) and _obs_events.enabled():
+            counted = step.shared.setdefault("counted", [])  # a trace-time gate, as in latent_moe.Block
+        return self.block.tail(x, h, step.shared["live"], counted)
 
 
 class DenseGPT:
-    """models.litgpt.GPT (or moe.MoEGPT) as a served model: its blocks as
-    layers, its rope table gathered once a program at the program's
+    """models.litgpt.GPT (or moe.MoEGPT, block_moe.BlockMoE) as a served model: its
+    blocks as layers, its rope table gathered once a program at the program's
     positions, its embedding and its untied head."""
 
     def __init__(self, gpt):
         self.gpt = gpt
         self.cfg = gpt.cfg
-        self.layers = [DenseBlock(block, gpt.cfg) for block in gpt.h]
+        self.layers = [(RoutedBlock if hasattr(block, "experts") else DenseBlock)(block, gpt.cfg)
+                       for block in gpt.h]
+        self.routes = any(isinstance(layer, RoutedBlock) for layer in self.layers)
         self.max_positions = gpt.cos.shape[0]  # the rope table's rows
 
     def begin(self, step) -> None:
@@ -432,6 +470,25 @@ class DenseGPT:
         program's rows are gathered by position, all of them at once."""
         with named_scope("attn/rope"):
             step.shared["cos"], step.shared["sin"] = self._rope_rows(step)
+        if self.routes:
+            with named_scope("moe_router"):
+                step.shared["live"] = self._live_rows(step)
+
+    @staticmethod
+    def _live_rows(step):
+        """(rows,) bool: the program's rows that are no padding, for the expert layers."""
+        from ..core import dtypes, prims
+
+        if step.program in ("prefill", "chunk"):
+            t = prims.iota(step.T, dtype=dtypes.int32, device=step.last.device)
+            return ltorch.le(t, step.last)
+        if step.program == "decode":
+            return step.live
+        # verify: a sequence's rows are live together; a block pass says which are (``live``),
+        # a speculative step's idle slots carry position 0
+        live = step.live if getattr(step, "block", False) else ltorch.gt(step.pos_mat[:, 0], 0)
+        B, K1 = step.pos_mat.shape
+        return ltorch.reshape(ltorch.expand(ltorch.unsqueeze(live, 1), (B, K1)), (B * K1,))
 
     def _rope_rows(self, step):
         from ..core import prims
@@ -465,13 +522,16 @@ class DenseGPT:
 class PagedGPTRunner:
     """Traces and caches the paged prefill/decode programs for one model."""
 
-    def __init__(self, gpt, *, page_size: int):
+    def __init__(self, gpt, *, page_size: int, block_length: Optional[int] = None):
         from .. import jit as _jit
         from ..nn.module import functional_params
 
         self.gpt = gpt
         self.cfg = gpt.cfg
         self.page_size = page_size
+        # generation by diffusion over blocks of this many positions: a prompt chunk's rows
+        # attend BLOCK-causally, and ``block_cfn`` runs a pass
+        self.block_length = block_length
         # a model that is no dense GPT says how it is served; a GPT is its blocks
         self.model = gpt.serving() if hasattr(gpt, "serving") else DenseGPT(gpt)
         self.page_kinds = tuple(k for k in ("full", "window") if any(
@@ -480,6 +540,9 @@ class PagedGPTRunner:
         # the chunk program takes the decode step's rows too where EVERY layer can run both
         # kinds of rows through its weights at once (a ``mixed`` beside chunk and decode)
         self.mixes = all(callable(getattr(layer, "mixed", None)) for layer in self.model.layers)
+        # a pass over blocks needs layers whose ``verify`` takes the mask apart from the
+        # positions: paged keys and values of every position (``DenseBlock``), no other
+        self.blocks = all(isinstance(layer, DenseBlock) for layer in self.model.layers)
 
         def prefill(params, idx, page_ids, state, last_pos, slot):
             with functional_params(gpt, params):
@@ -497,10 +560,15 @@ class PagedGPTRunner:
             with functional_params(gpt, params):
                 return self._forward_verify(toks, state, tables, pos)
 
+        def block(params, toks, state, tables, pos):
+            with functional_params(gpt, params):
+                return self._forward_verify(toks, state, tables, pos, block=True)
+
         prefill.__name__ = "serve_prefill"
         decode.__name__ = "serve_decode"
         chunk_prefill.__name__ = "serve_chunk_prefill"
         verify.__name__ = "serve_verify"
+        block.__name__ = "serve_block"
         # the calling convention, not a knob: every call site passes the
         # cache's state and rebinds the returned one on its next line, so
         # the state is given up (its position in each signature above)
@@ -514,6 +582,7 @@ class PagedGPTRunner:
         self.chunk_cfn = _annotated(_jit(chunk_prefill, donated_argnums=(3,), **both),
                                     "serve_chunk_prefill")
         self.verify_cfn = _annotated(_jit(verify, donated_argnums=(2,)), "serve_verify")
+        self.block_cfn = _annotated(_jit(block, donated_argnums=(2,)), "serve_block")
 
     def _by_kind(self, per_kind) -> dict:
         """The programs take page ids and tables as one array a page kind, in
@@ -618,6 +687,8 @@ class PagedGPTRunner:
             for kind, row in tables.items()}
         step.q_pos = ltorch.reshape(
             prims.iota(T, dtype=dtypes.int32, device=idx.device) + start_pos, (1, T))
+        K = self.block_length
+        step.mask_pos = step.q_pos if K is None else ltorch.floor_divide(step.q_pos, K) * K + (K - 1)
         if rows is None:
             x, state = self._run_layers(step, self._embed(idx), state)
             with named_scope("head"):
@@ -635,17 +706,31 @@ class PagedGPTRunner:
             return (logits[:1], logits[1:], state) + self._counted(both)
 
     # -- speculative verify -----------------------------------------------
-    def _forward_verify(self, toks, state, tables, pos):
+    def _forward_verify(self, toks, state, tables, pos, block: bool = False):
         """toks (Bcap, k+1): each sequence's current token followed by its k
         draft proposals; pos (Bcap,) int32 — the position of toks[:, 0].
         Returns (logits (Bcap, k+1, V), new state) — logits at every
-        position, so ONE packed target step scores every proposal."""
+        position, so ONE packed target step scores every proposal.
+
+        With ``block`` the rows are a BLOCK of generation by diffusion (``block_cfn``):
+        toks (Bcap, K) the block's tokens, the mask token where a position is not
+        filled yet, pos the block's first position; every row covers the keys up to
+        the block's LAST position, so it sees the whole block and all before it.
+        All K rows' keys and values are written, as a verify step's are: a denoise
+        pass's are replaced by the next pass's, and the pass that runs the finished
+        block leaves the ones that stay. An idle slot carries a null-page row (its
+        position may be 0 or stale; a live sequence's first block may be at 0 too).
+        Returns after the state the routing counters, as the decode program does."""
         from ..core import dtypes, prims
 
         B, K1 = toks.shape
         offs = prims.iota(K1, dtype=dtypes.int32, device=toks.device)
         pos_mat = ltorch.reshape(pos, (B, 1)) + ltorch.reshape(offs, (1, K1))  # (B, K1)
-        step = Step("verify", self.page_size, tables=self._by_kind(tables), pos_mat=pos_mat)
+        step = Step("verify", self.page_size, tables=self._by_kind(tables), pos_mat=pos_mat,
+                    mask_pos=pos_mat, block=block)
+        if block:
+            step.mask_pos = ltorch.expand(ltorch.reshape(pos + (K1 - 1), (B, 1)), (B, K1))
+            step.live = ltorch.gt(step.tables[self.page_kinds[0]][:, 0], 0)
         self.model.begin(step)
         with named_scope("kv_write"):
             page_of, slot = _token_pages(step.tables, pos_mat, self.page_size)
@@ -653,4 +738,5 @@ class PagedGPTRunner:
             step.slot_in_page = ltorch.reshape(slot, (B * K1,))
         x, state = self._run_layers(step, self._embed(toks), state)
         with named_scope("head"):
-            return self.model.head(x), state  # (B, K1, V)
+            logits = self.model.head(x)  # (B, K1, V)
+        return (logits, state) + (self._counted(step) if block else ())

@@ -61,6 +61,17 @@ activated, and a step with no live sequence is never dispatched; an end the
 host learns at a commit (``eos_id``, a cancelled Future; a first token's too)
 costs one step whose token is thrown away.
 
+Generation by diffusion over blocks (``block_diffusion=``, docs/serving.md) is
+a third decode order on the same pipeline: a pass carries a block of K
+positions a sequence (``runner.block_cfn``), its sampler (``_block_sample``)
+fills some masked positions or, for a block with none, moves the slot on, and
+the blocks, their masked flags and their positions stay on the device from
+pass to pass. One pass is in flight; the host reads the record of the pass
+before it (``_commit_block_pass``) and assumes nothing about how many
+positions a pass filled. Prompts' whole blocks go through the chunk program
+under the block-causal mask; an activation merges the prompt's tail and mask
+tokens into the next pass's blocks on the device (``_activate_block``).
+
 Per-request observability rides the existing bus: request-id-tagged spans,
 ``serve.*`` counters, and flight-recorder records per decode iteration
 (docs/serving.md, docs/observability.md). The loop itself runs under
@@ -120,6 +131,12 @@ class RequestResult:
     # are back in the pool; their rows stay as written until another sequence takes them, which
     # is how a test reads what the engine cached for a request it served alone
     pages: tuple = ()
+    # generation by diffusion over blocks only: (position, pass) of every generated position in
+    # the order it was filled (passes numbered from 0 over the request, commit passes included),
+    # and, where ``engine.record_block_states`` was set, the block going INTO each pass:
+    # (first position, its tokens (K,), its masked flags (K,))
+    unmasked: tuple = ()
+    block_states: tuple = ()
 
 
 @dataclass
@@ -154,6 +171,15 @@ class _Request:
     covered: int = 0             # prefix-cache token coverage of prompt_eff
     n_shared: int = 0            # leading shared pages in .pages
     chunk_pos: int = -1          # next chunk start (chunk mode only)
+    # generation by diffusion over blocks: the prompt's last L mod K tokens, which open the
+    # first generated block; the block the next fetched pass is about and how many of its
+    # leading tokens were given; passes fetched; RequestResult.unmasked / .block_states
+    block_tail: Optional[np.ndarray] = None
+    block_pos: int = 0
+    block_given: int = 0
+    n_passes: int = 0
+    unmasked: List[tuple] = field(default_factory=list)
+    block_states: List[tuple] = field(default_factory=list)
 
 
 @dataclass
@@ -163,7 +189,9 @@ class _Step:
     fed the first token that ``_First`` holds; that token is read and committed
     before this step lands."""
 
-    nxt: jax.Array               # (max_batch, 1) int32 on the device: the next step's tokens
+    # (max_batch, 1) int32 on the device: the next step's tokens; of a pass over blocks the
+    # (max_batch, 4 K + 1) record ``_block_sample`` makes for the host
+    nxt: jax.Array
     reqs: Dict[int, _Request]    # slot -> the sequence that was in the step
     t0: float                    # when the pass that dispatched it began its decode
     # what the program counted of its own work (ROUTING_COUNTERS, int32 on the device), where
@@ -210,6 +238,96 @@ def _merge_token(toks, slot, tok):
     return toks.at[slot, 0].set(tok[0])
 
 
+@dataclass(frozen=True)
+class _BlockSpec:
+    """``ServingEngine(block_diffusion=)`` checked: generation by diffusion over blocks of ``K``
+    positions, ``n`` the positions a denoise pass fills at least."""
+
+    K: int
+    n: int
+    dynamic: bool
+    threshold: float
+    mask_id: int
+
+    @classmethod
+    def of(cls, spec: dict, vocab: int) -> "_BlockSpec":
+        known = {"block_length", "denoising_steps", "strategy", "threshold", "mask_id"}
+        if set(spec) - known or not {"block_length", "mask_id"} <= set(spec):
+            raise ValueError(f"block_diffusion= takes the keys {sorted(known)} (block_length and "
+                             f"mask_id always), not {sorted(spec)}")
+        K, S = int(spec["block_length"]), int(spec.get("denoising_steps", spec["block_length"]))
+        strategy = spec.get("strategy", "low_confidence_dynamic")
+        if K < 1 or not 1 <= S <= K:
+            raise ValueError(f"block_diffusion=: block_length={K} must be >= 1 and denoising_steps={S} "
+                             f"between 1 and it")
+        if strategy not in ("low_confidence_dynamic", "low_confidence_static"):
+            raise ValueError(f"block_diffusion=: strategy {strategy!r} is neither "
+                             f"'low_confidence_dynamic' nor 'low_confidence_static'")
+        if not 0 <= int(spec["mask_id"]) < vocab:
+            raise ValueError(f"block_diffusion=: mask_id={spec['mask_id']} is no row of the "
+                             f"embedding's {vocab}")
+        return cls(K, -(-K // S), strategy == "low_confidence_dynamic",
+                   float(spec.get("threshold", 0.9)), int(spec["mask_id"]))
+
+
+def _block_sample(logits, toks, masked, pos, end, seeds, temps, *, spec: _BlockSpec):
+    """What follows a pass over blocks, for every slot at once: logits (B, K, V) of the
+    block's rows, the block (``toks`` (B, K), ``masked`` (B, K) bool, ``pos`` (B,) its first
+    position, ``end`` (B,) where the sequence's last block ends). A block with a masked
+    position was DENOISED: each masked position's candidate ``x0`` (temperature 0: the
+    argmax; else the position-keyed draw, ``fold_in(PRNGKey(seed), position)``: a position is
+    filled once; never the mask token, whose logit is left out) and its confidence ``c =
+    softmax(logits / T)[x0]``; filled are the ``n``
+    masked positions of largest ``c`` (ties to the lower position), or under the dynamic
+    strategy every masked position with ``c > threshold`` where those are at least ``n``.
+    A block with none was in its COMMIT pass (the keys and values that pass wrote are the
+    ones that stay): the slot moves on by K to an all-masked block, unless that was the
+    sequence's last, which then stays as it is (running it again writes the same rows).
+    An idle slot is a finished sequence with ``end`` 0. Returns the next pass's (toks, masked,
+    pos, end) and the (B, 4 K + 1) int32 record the host reads a pass later: pos, the block
+    going in (tokens, masked), and the block as denoised (tokens, masked)."""
+    B, K, _ = logits.shape
+    i32 = jnp.int32
+
+    def candidates(draws: bool):
+        def one(l, s, p, t):
+            l = l.astype(jnp.float32).at[spec.mask_id].set(-jnp.inf)  # the mask token is no candidate
+            scaled = l / jnp.where(t > 0, t, 1.0)
+            x0 = jnp.argmax(l, -1)
+            if draws:
+                drawn = jax.random.categorical(jax.random.fold_in(jax.random.PRNGKey(s), p), scaled)
+                x0 = jnp.where(t > 0, drawn, x0)
+            return x0.astype(i32), jnp.exp(scaled[x0] - jax.nn.logsumexp(scaled))
+
+        return lambda: jax.vmap(jax.vmap(one, in_axes=(0, None, 0, None)))(logits, seeds, at, temps)
+
+    with jax.named_scope("unmask"):
+        at = pos[:, None] + jnp.arange(K, dtype=i32)[None, :]
+        # a pass whose every sequence is greedy draws nothing: the noise of a draw is a number a
+        # vocabulary entry and row (2.3 of the sampler's 3.3 ms at 256 rows of 151,936: PR 41)
+        x0, c = jax.lax.cond(jnp.any(temps > 0), candidates(True), candidates(False))
+        n_fill = jnp.minimum(spec.n, jnp.sum(masked, -1, dtype=i32))[:, None]
+        c_masked = jnp.where(masked, c, -jnp.inf)
+        rank = jnp.argsort(jnp.argsort(-c_masked, axis=-1, stable=True), axis=-1, stable=True)
+        fill = masked & (rank < n_fill)
+        if spec.dynamic:
+            high = masked & (c > spec.threshold)
+            fill = jnp.where(jnp.sum(high, -1, dtype=i32)[:, None] >= n_fill, high, fill)
+        toks_dn, masked_dn = jnp.where(fill, x0, toks), masked & ~fill
+        commit = ~jnp.any(masked, -1)
+        moves = (commit & (pos + K < end))[:, None]
+        rec = jnp.concatenate([pos[:, None], toks, masked.astype(i32), toks_dn, masked_dn.astype(i32)], 1)
+        return (jnp.where(moves, spec.mask_id, toks_dn).astype(i32), moves | masked_dn,
+                jnp.where(moves[:, 0], pos + K, pos), end, rec)
+
+
+def _merge_block(toks, masked, pos, end, slot, row_toks, row_masked, row_pos, row_end):
+    """The next pass's blocks with slot ``slot``'s replaced: a sequence activated since the
+    last dispatch (its prompt's tail and mask tokens), or a slot gone idle."""
+    return (toks.at[slot].set(row_toks), masked.at[slot].set(row_masked),
+            pos.at[slot].set(row_pos), end.at[slot].set(row_end))
+
+
 class ServingEngine:
     """Continuous-batching inference over a models.litgpt.GPT (or MoEGPT), or
     over any model whose ``serving()`` gives its layers (serving/runner.py):
@@ -241,6 +359,14 @@ class ServingEngine:
     quantize        weight-only quantization applied before tracing:
                     None/"none" or "int8" (int8 x bf16 decode compute via
                     the Pallas dequant-in-kernel linear on TPU)
+    block_diffusion generation by diffusion over blocks (docs/serving.md): a dict
+                    ``{"block_length": K, "denoising_steps": S, "strategy":
+                    "low_confidence_dynamic" | "low_confidence_static",
+                    "threshold": tau, "mask_id": m}``. A pass carries a block of K
+                    positions a sequence, unmasks some of them, and the block's keys
+                    and values stay only from the pass that runs it with none masked.
+                    Needs layers that cache keys and values of every position;
+                    ``prefix_sharing`` and ``draft_gpt`` are refused with it
     """
 
     def __init__(self, gpt, *, max_batch: int = 8, page_size: int = 16,
@@ -250,7 +376,7 @@ class ServingEngine:
                  chunk_tokens: Optional[int] = None,
                  prefill_budget: Optional[int] = None, draft_gpt=None,
                  spec_k: Optional[int] = None, preemption: bool = True,
-                 quantize: Optional[str] = None):
+                 quantize: Optional[str] = None, block_diffusion: Optional[dict] = None):
         # weight-only quantization must precede BOTH the program tracing and
         # the named_parameters snapshot below (runner.quantize_for_serving)
         gpt = quantize_for_serving(gpt, quantize)
@@ -260,7 +386,10 @@ class ServingEngine:
         self.max_batch = max_batch
         self.page_size = page_size
         self.max_seq = max_seq or cfg.block_size
-        self.runner = PagedGPTRunner(gpt, page_size=page_size)
+        self.block = (_BlockSpec.of(block_diffusion, getattr(cfg, "padded_vocab_size", cfg.vocab_size))
+                      if block_diffusion is not None else None)
+        self.runner = PagedGPTRunner(gpt, page_size=page_size,
+                                     block_length=self.block.K if self.block else None)
         # a model with a position table can serve no more positions than it has
         rope_rows = getattr(self.runner.model, "max_positions", None)
         if rope_rows is not None and self.max_seq > rope_rows:
@@ -327,6 +456,28 @@ class ServingEngine:
                     "draft_gpt= (speculative decoding) cannot serve a model with window "
                     "layers: verify writes k+1 positions and window pages are taken for one "
                     "position a step, so the others would be written to no page")
+        if self.block is not None:
+            if not self.runner.blocks:
+                raise ValueError(
+                    "block_diffusion= cannot serve a model with window, recurrent or latent layers: "
+                    "a pass writes a block's K positions and attends them under a mask of its own "
+                    "(the block's last position for every row), which only layers that cache keys "
+                    "and values of every position run (serving/runner.py: DenseBlock.verify)")
+            if prefix_sharing:
+                raise ValueError(
+                    "prefix_sharing=True cannot go with block_diffusion=: a prompt's last L mod K "
+                    "tokens are not prefilled (they open the first generated block), so the page "
+                    "they share with the block holds rows no prompt alone determines")
+            if draft_gpt is not None:
+                raise ValueError(
+                    "draft_gpt= (speculative decoding) cannot go with block_diffusion=: a pass "
+                    "already yields up to K tokens a sequence, and the verify step's accept rule "
+                    "is the one-token-a-step sampler's")
+            if self.chunk_tokens % self.block.K:
+                raise ValueError(
+                    f"chunk_tokens={self.chunk_tokens} must be a multiple of block_length="
+                    f"{self.block.K}: a prompt chunk ends on a block's edge, or its last rows "
+                    f"would attend keys the next chunk has yet to write")
         self.params = {k: p.data for k, p in gpt.named_parameters()}
         # what the programs are given beside the weights goes to the weights' device, committed
         # to it as what the programs return is (``_upload``)
@@ -340,6 +491,22 @@ class ServingEngine:
         self._sampler = jax.jit(_sample_tokens)
         self._step_sampler = jax.jit(_sample_step)
         self._merge = jax.jit(_merge_token)
+        if self.block is not None:
+            K, spec = self.block.K, self.block
+
+            def serve_unmask(*block):  # the executable's name in a device trace
+                return _block_sample(*block, spec=spec)
+
+            self._block_sampler = jax.jit(serve_unmask)
+            self._block_merge = jax.jit(_merge_block)
+            # every slot's block (tokens, masked, first position, the sequence's end): on the
+            # device from pass to pass, the sampler's output the next pass's input
+            self._blk = tuple(jax.device_put(a, self._device) for a in (
+                np.zeros((max_batch, K), np.int32), np.zeros((max_batch, K), bool),
+                np.zeros((max_batch,), np.int32), np.zeros((max_batch,), np.int32)))
+            self._idle_block = (np.zeros((K,), np.int32), np.zeros((K,), bool), np.int32(0), np.int32(0))
+        # with it set a retired request's result carries the block going into each of its passes
+        self.record_block_states = False
 
         self.prefix = (PrefixCache(self.cache.allocator, page_size)
                        if prefix_sharing else None)
@@ -399,7 +566,8 @@ class ServingEngine:
         # the step rides in it (one read of the weights for both), where every layer of the
         # model can run both kinds of rows at once and the decode path is the plain one; the
         # rows of a chunk dispatch no step rides in are idle slots, all of them
-        self._mixes = self.runner.mixes and draft_gpt is None
+        # ... and a block's K rows a slot ride in no chunk's program: the two run apart
+        self._mixes = self.runner.mixes and draft_gpt is None and self.block is None
         self._idle_rows = (
             self._upload(self._toks[:, None]),
             tuple(self._upload(self._page_tables) for _ in self.runner.page_kinds),
@@ -429,6 +597,9 @@ class ServingEngine:
         self.spec_accepted = 0
         self.preempted = 0
         self.resumed = 0
+        self.block_passes = 0    # passes over blocks fetched, and those of them that were commit
+        self.block_commits = 0   # passes for at least one sequence
+        self.blocks_done = 0     # blocks committed, over the sequences
 
         # SLO measurement substrate (observability/slo.py): a declarative
         # policy gets a sliding-window monitor (breach events/counters) and
@@ -467,10 +638,11 @@ class ServingEngine:
         L = int(prompt.shape[0])
         worst = self._pages_needed(L, max_new_tokens)
         usable = self.cache.n_pages - 1
-        if L < 1 or L + max_new_tokens > self.max_seq or max_new_tokens < 1:
+        if L < 1 or self._life(L, max_new_tokens) > self.max_seq or max_new_tokens < 1:
             fut.set_exception(ValueError(
                 f"request {rid}: prompt_len={L} + max_new_tokens={max_new_tokens} "
-                f"must fit max_seq={self.max_seq} (and both be >= 1)"))
+                f"must fit max_seq={self.max_seq} (and both be >= 1)"
+                + (f", the last block of {self.block.K} whole" if self.block else "")))
             return fut
         if worst > usable:
             fut.set_exception(ValueError(
@@ -587,6 +759,9 @@ class ServingEngine:
             "preempted": self.preempted,
             "resumed": self.resumed,
         }
+        if self.block is not None:
+            out.update(block_passes=self.block_passes, block_commits=self.block_commits,
+                       blocks_done=self.blocks_done)
         if self.prefix is not None:
             out["prefix_cache_pages"] = len(self.prefix)
         if self.slo_policy is not None:
@@ -682,6 +857,12 @@ class ServingEngine:
                 warnings.warn(f"serving loop error (contained): {e!r}")
                 time.sleep(1e-2)
 
+    def _life(self, L: int, max_new: int) -> int:
+        """Positions a request writes over its lifetime: prompt and answer, and under
+        ``block_diffusion=`` the last block whole."""
+        K = self.block.K if self.block is not None else 1
+        return -(-(L + max_new) // K) * K
+
     def _pages_needed(self, L: int, max_new: int) -> int:
         """Worst-case pages over the request lifetime: the bucketed prefill
         writes bucket//page_size pages, growth extends to L+max_new tokens.
@@ -689,9 +870,11 @@ class ServingEngine:
         mid-flight out-of-pages (the admission policy; docs/serving.md).
         Window pages need no reservation a request: their pool holds every
         slot's worst case (``__init__``), which is small by construction."""
-        bucket = self.ladder.bucket_for(L)
-        return max(bucket // self.page_size,
-                   PagedKVCache.pages_for(L + max_new, self.page_size))
+        life = PagedKVCache.pages_for(self._life(L, max_new), self.page_size)
+        if self.block is not None:  # whole blocks of the prompt go through the chunk program
+            whole = L // self.block.K * self.block.K
+            return max(life, self._final_chunk_end(whole, 0) // self.page_size) if whole else life
+        return max(self.ladder.bucket_for(L) // self.page_size, life)
 
     def _step_once(self) -> None:
         obs_on = _obs.enabled()
@@ -745,6 +928,8 @@ class ServingEngine:
                 self._admit_hit(req, free_slots[0])
             elif req.admit_mode == "chunk":
                 self._start_chunk(req, free_slots[0])
+            elif req.admit_mode == "block":  # a prompt shorter than a block: nothing to prefill
+                self._activate_block(req, free_slots[0])
             else:
                 with self._prefill_phase(req):
                     self._prefill(req, free_slots[0])
@@ -755,6 +940,8 @@ class ServingEngine:
         cache (already incref'd by match), private ones from the free-list.
         On shortage every side effect is undone and False is returned — the
         request stays at its queue head."""
+        if self.block is not None:
+            return self._reserve_block_pages(req)
         ps = self.page_size
         resumed = bool(req.tokens)
         # a resumed victim re-prefills prompt + all-but-the-last committed
@@ -815,6 +1002,33 @@ class ServingEngine:
             _obs_trace.trace_event(
                 req.trace_id, "admitted", request=req.request_id, mode=mode,
                 covered=covered, shared_pages=n_shared, pages=len(req.pages),
+                queued_ms=round((req.t_admit - req.t_submit) * 1e3, 3))
+        return True
+
+    def _reserve_block_pages(self, req: _Request) -> bool:
+        """``_reserve_pages`` under ``block_diffusion=``: the whole blocks of the prompt (of a
+        resumed victim: of prompt and committed tokens, which end on a block's edge) go
+        through the chunk program, block-causal; the last ``L mod K`` tokens open the first
+        generated block. Pages for the whole lifetime, the last block whole."""
+        K, ps = self.block.K, self.page_size
+        whole = (np.concatenate([req.prompt, np.asarray(req.tokens, np.int32)])
+                 if req.tokens else req.prompt)
+        Lb = len(whole) // K * K
+        need = PagedKVCache.pages_for(self._life(len(req.prompt), req.max_new_tokens), ps)
+        if Lb:
+            need = max(need, self._final_chunk_end(Lb, 0) // ps)
+        if not self.cache.allocator.can_alloc(need):
+            return False
+        req.prompt_eff, req.block_tail = whole[:Lb], whole[Lb:]
+        req.covered = req.n_shared = 0
+        req.admit_mode = "chunk" if Lb else "block"
+        req.pages = self.cache.allocator.alloc(need)
+        if not req.t_admit:
+            req.t_admit = time.perf_counter()
+        if req.trace_id is not None:
+            _obs_trace.trace_event(
+                req.trace_id, "admitted", request=req.request_id, mode=req.admit_mode,
+                covered=0, shared_pages=0, pages=len(req.pages),
                 queued_ms=round((req.t_admit - req.t_submit) * 1e3, 3))
         return True
 
@@ -1270,6 +1484,11 @@ class ServingEngine:
                                self.cache.allocator.n_used)
             _obs_tel.set_gauge("serve.page_fragmentation",
                                round(self.page_fragmentation(), 4))
+        if self.block is not None:  # no first token: the first block is generated as every other
+            if req.tokens:
+                self._on_resume(req)
+            self._activate_block(req, slot)
+            return
         if req.tokens:
             self._on_resume(req)
             self._activate(req, slot, pos=L_eff, tok=req.tokens[-1])
@@ -1295,7 +1514,28 @@ class ServingEngine:
         if isinstance(tok, int):
             self._toks[slot] = tok
             tok = self._upload(np.array([tok], np.int32))
-        self._feeds[slot] = tok
+        self._take_slot(req, slot, pos, tok)
+
+    def _activate_block(self, req: _Request, slot: int) -> None:
+        """``_activate`` under ``block_diffusion=``: ``req`` takes ``slot`` and its first block,
+        the prompt's tail and mask tokens after it, is merged into the next pass's blocks on
+        the device (``_dispatch_block``). Nothing lands."""
+        spec, tail = self.block, req.block_tail
+        if _obs.enabled():
+            _obs_metrics.record_serve("activations")
+            if self._inflight is not None:
+                _obs_metrics.record_serve("activations_joined")
+        toks = np.full((spec.K,), spec.mask_id, np.int32)
+        toks[:len(tail)] = tail
+        pos = len(req.prompt_eff)
+        req.block_pos, req.block_given = pos, len(tail)
+        # what a preempted victim had filled of a block it never committed is filled anew
+        req.unmasked = [u for u in req.unmasked if u[0] < pos]
+        self._take_slot(req, slot, pos, (toks, np.arange(spec.K) >= len(tail), np.int32(pos),
+                                         np.int32(self._life(len(req.prompt), req.max_new_tokens))))
+
+    def _take_slot(self, req: _Request, slot: int, pos: int, feed) -> None:
+        self._feeds[slot] = feed
         self._slots[slot] = req
         self._page_tables[slot] = self.cache.page_table_row(req.pages,
                                                             self.n_pages_max)
@@ -1310,6 +1550,8 @@ class ServingEngine:
     def _clear_slot(self, i: int) -> None:
         self._slots[i] = None
         self._feeds.pop(i, None)
+        if self.block is not None:
+            self._feeds[i] = self._idle_block  # the slot's block on the device goes idle too
         self._rest_slot(i)
         self._toks[i] = 0
         self._seeds[i] = 0
@@ -1383,7 +1625,8 @@ class ServingEngine:
         if self.draft_cache is not None and self.spec_k > 0:
             self._spec_decode()
             return
-        live = self._live_slots()
+        live = ([i for i, req in enumerate(self._slots) if req is not None]
+                if self.block is not None else self._live_slots())
         if not live:
             self._land()  # a step with no live sequence is never dispatched
             return
@@ -1454,6 +1697,8 @@ class ServingEngine:
         program a token, so a first token need not have reached the host. After
         a failure every live sequence has failed, its pages returned, and the
         exception goes on to the caller."""
+        if self.block is not None:
+            return self._dispatch_block(live, prev, t0)
         phase = _obs_runtime.phase
         try:
             with phase("engine:upload"):
@@ -1473,12 +1718,7 @@ class ServingEngine:
             # the packed step failed: every live sequence is implicated —
             # fail their futures and return their pages rather than hanging
             # the whole engine (pending requests still get admitted)
-            self._land()  # a first token not read yet may end its sequence before the failure does
-            for i in live:
-                if self._slots[i] is not None:
-                    self._fail(self._slots[i], e)
-                    self._clear_slot(i)
-            self._drop_lost_pools(e)
+            self._fail_live(live, e)
             raise
         self._feeds.clear()
         self.decode_steps += 1
@@ -1491,6 +1731,51 @@ class ServingEngine:
         self._pos[live] += 1
         return _Step(nxt, {i: self._slots[i] for i in live}, t0, *counted)
 
+    def _fail_live(self, live: List[int], e: Exception) -> None:
+        """The packed step failed: every live sequence is implicated."""
+        self._land()  # a first token not read yet may end its sequence before the failure does
+        for i in live:
+            if self._slots[i] is not None:
+                self._fail(self._slots[i], e)
+                self._clear_slot(i)
+        self._drop_lost_pools(e)
+
+    def _dispatch_block(self, live: List[int], prev: Optional[_Step], t0: float) -> _Step:
+        """``_dispatch`` under ``block_diffusion=``: enqueue ONE pass over every slot's block
+        and its sampler (``_block_sample``). The blocks, their masked flags and positions
+        never leave the device: the sampler's output is the next pass's input, and the host
+        reads the small record of this pass behind the next dispatch. A slot in a denoise
+        pass and one in its commit pass share the dispatch; which a slot is in, the device
+        knows and the host learns from the record. A sequence activated since the last
+        dispatch, and a slot gone idle, are merged in first (``_feeds``)."""
+        phase = _obs_runtime.phase
+        try:
+            with phase("engine:upload"):
+                blk = self._blk
+                for slot, row in self._feeds.items():
+                    blk = self._block_merge(*blk, self._upload(np.int32(slot)),
+                                            *(self._upload(a) for a in row))
+            with phase("engine:dispatch"):
+                logits, state, *counted = self.runner.block_cfn(
+                    self.params, blk[0], self.cache.state, self._pt_dev, blk[2])
+                self.cache.rebind(state)
+                *nxt, rec = self._block_sampler(logits, *blk, self._seeds_dev, self._temps_dev)
+                self._blk = tuple(nxt)
+                rec.copy_to_host_async()
+                for c in counted:
+                    c.copy_to_host_async()
+        except Exception as e:
+            self._fail_live(live, e)
+            raise
+        self._feeds.clear()
+        self.decode_steps += 1
+        if _obs.enabled():
+            _obs_metrics.record_serve("decode_steps")
+            _obs_metrics.record_serve("decode_overlapped", delta=int(prev is not None))
+            self._record_state(len(live))
+            self._record_chunk_pages(self._pos[live], self.block.K)
+        return _Step(rec, {i: self._slots[i] for i in live}, t0, *counted)
+
     def _fetch(self, step: Optional[_Step]) -> Optional[np.ndarray]:
         """The sampled tokens of ``step`` on the host, (max_batch,). None where
         there is no step, or it failed: then the sequences that were in it
@@ -1499,7 +1784,8 @@ class ServingEngine:
             return None
         with _obs_runtime.phase("engine:fetch"):
             try:
-                return np.asarray(step.nxt)[:, 0]
+                nxt = np.asarray(step.nxt)
+                return nxt if self.block is not None else nxt[:, 0]
             except Exception as e:
                 for i, req in step.reqs.items():
                     if self._slots[i] is req:
@@ -1514,6 +1800,9 @@ class ServingEngine:
         a cancelled Future) or was failed has left its slot: its token is
         thrown away."""
         if nxt is None:
+            return
+        if self.block is not None:
+            self._commit_block_pass(step, nxt)
             return
         with _obs_runtime.phase("engine:commit"):
             # with the bus on, the step's own records count as commit too
@@ -1540,6 +1829,81 @@ class ServingEngine:
                     dur_ms=dur_ms, step=self.decode_steps, active=len(step.reqs))
             for i, req in kept:
                 self._commit(i, req, int(nxt[i]), t_now)
+
+    def _commit_block_pass(self, step: _Step, rec: np.ndarray) -> None:
+        """``_commit_step`` under ``block_diffusion=``: what the pass ``step`` did for each
+        sequence that was in it, from the record its sampler made (``_block_sample``). A
+        DENOISE pass filled positions: which and with what is read off the record, never
+        assumed, and they are noted (``RequestResult.unmasked``) and counted as tokens
+        produced. A COMMIT pass ran the finished block: its generated tokens go to
+        ``req.tokens`` now (a block is what a stream could show), ``t_first`` is the first
+        block's, and the sequence may end here (``max_new_tokens`` reached: the first that
+        many are returned; ``eos_id`` among the block's: through it; a cancelled Future)."""
+        K = self.block.K
+        obs_on = _obs.enabled()
+        with _obs_runtime.phase("engine:commit"):
+            t_now = time.perf_counter()
+            kept = [(i, req) for i, req in step.reqs.items() if self._slots[i] is req]
+            filled = commits = 0
+            for i, req in kept:
+                pos = int(rec[i, 0])
+                toks_in, masked_in = rec[i, 1:1 + K], rec[i, 1 + K:1 + 2 * K].astype(bool)
+                if pos != req.block_pos:  # the books are wrong: never commit another block's rows
+                    self._fail(req, RuntimeError(
+                        f"request {req.request_id}: a pass ran the block at {pos}, the host "
+                        f"expected the one at {req.block_pos}"))
+                    self._clear_slot(i)
+                    continue
+                if self.record_block_states:
+                    req.block_states.append((pos, toks_in.copy(), masked_in.copy()))
+                req.n_passes += 1
+                if masked_in.any():
+                    toks_dn, masked_dn = rec[i, 1 + 2 * K:1 + 3 * K], rec[i, 1 + 3 * K:].astype(bool)
+                    new = np.flatnonzero(masked_in & ~masked_dn)
+                    req.unmasked.extend((pos + int(j), req.n_passes - 1) for j in new)
+                    filled += len(new)
+                    if req.future.cancelled():
+                        self._retire(req)
+                        self._clear_slot(i)
+                    continue
+                commits += 1
+                new = [int(t) for t in toks_in[req.block_given:]]
+                req.block_given = 0
+                req.block_pos = self._pos[i] = pos + K
+                if req.t_first == 0.0:
+                    req.t_first = t_now
+                req.t_last = t_now
+                if req.eos_id is not None and req.eos_id in new:
+                    new = new[:new.index(req.eos_id) + 1]
+                req.tokens.extend(new)
+                del req.tokens[req.max_new_tokens:]
+                if self._finished(req, req.tokens[-1]):
+                    self._retire(req)
+                    self._clear_slot(i)
+            self.block_passes += 1
+            self.block_commits += int(commits > 0)
+            self.blocks_done += commits
+            if obs_on:
+                dur_ms = (t_now - step.t0) * 1e3
+                _obs_metrics.record_serve("tokens", delta=filled)
+                _obs_metrics.record_serve("block_passes")
+                _obs_metrics.record_serve("block_commits", delta=int(commits > 0))
+                _obs_metrics.record_serve("blocks_done", delta=commits)
+                _obs_metrics.record_serve("block_slot_passes", delta=len(kept))
+                _obs_metrics.record_serve("block_slot_commits", delta=commits)
+                if step.counted is not None:
+                    for name, n in zip(ROUTING_COUNTERS, np.asarray(step.counted)):
+                        _obs_metrics.record_serve(name, delta=int(n))
+                if len(kept) < len(step.reqs):
+                    _obs_metrics.record_serve("decode_discarded", delta=len(step.reqs) - len(kept))
+                _obs_flight.record_step(dur_ms, fn="serve_decode", active=len(step.reqs),
+                                        unmasked=filled)
+                _obs_tel.observe("serve.decode_ms", dur_ms)
+                if kept:
+                    _obs_tel.observe("serve.tokens_per_pass", filled / len(kept))
+                _obs_trace.trace_step(
+                    [req.trace_id for req in step.reqs.values()], "block_pass",
+                    dur_ms=dur_ms, step=self.decode_steps, active=len(step.reqs), unmasked=filled)
 
     def _land(self) -> None:
         """Bring the host level with the device: fetch and commit the step in
@@ -1762,7 +2126,9 @@ class ServingEngine:
         # t_first == 0.0 only for a prefix-hit request cancelled before its
         # first committed token — report a zero TTFT rather than a negative
         ttft = (req.t_first - req.t_submit) if req.t_first else 0.0
-        tbot = ((req.t_last - req.t_first) / (n_new - 1)) if n_new > 1 else 0.0
+        # what t_first stamps: the first token, or under block_diffusion= the first block
+        first = self.block.K if self.block is not None else 1
+        tbot = ((req.t_last - req.t_first) / (n_new - first)) if n_new > first else 0.0
         if req.future.cancelled():
             # a client-side cancel is not a completion: tag it so latency
             # percentiles (obs_summary) aren't polluted by truncated samples
@@ -1779,7 +2145,7 @@ class ServingEngine:
             # a one-token request has no between-token interval: exclude it
             # from the tbot population (online AND offline percentiles use
             # the same rule) rather than stream a 0.0 placeholder
-            tbot_ms = tbot * 1e3 if n_new > 1 else None
+            tbot_ms = tbot * 1e3 if n_new > first else None
             if self.slo_policy is not None:
                 slo_met = self.slo_policy.request_met(ttft_ms, tbot_ms)
                 self.requests_retired += 1
@@ -1828,6 +2194,8 @@ class ServingEngine:
             slo_met=slo_met,
             queue_s=req.t_admit - req.t_submit,
             pages=held,
+            unmasked=tuple(req.unmasked),
+            block_states=tuple(req.block_states),
         )
         try:
             # a cancel() from the caller thread can land at ANY point, so a
