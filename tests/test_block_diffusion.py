@@ -162,45 +162,144 @@ def reference_keys(config, params, toks, layer):
     return np.asarray(made["k"])
 
 
-@pytest.mark.parametrize("block_length,page_size", [(4, 8), (3, 4)])
-def test_every_pass_equals_the_reference_and_only_a_commit_pass_leaves_its_keys(rng, block_length, page_size):
-    """Each pass's record against the reference's full forward over the tokens that went into it
-    (blocks of 3 on pages of 4 straddle page edges); after the request, the cached key rows are
-    the reference's over the FINISHED tokens, which a denoise pass's (computed over mask tokens)
-    are not."""
+def pool_rows(pool, pages, n):
+    """``served_keys`` of a pool already on the host, (P, H, ps, D)."""
+    got = pool[np.asarray(pages, np.int32)]
+    return got.transpose(0, 2, 1, 3).reshape(-1, pool.shape[1], pool.shape[3])[:n]
+
+
+# (block length, page size, strategy, threshold): blocks of 3 on pages of 4 straddle page edges; a
+# threshold of 0.9 no pass clears (two denoise passes a block), 0.01 some do, 0.0 all (one a block)
+ROADS = [(4, 8, "low_confidence_static", 0.9), (4, 8, "low_confidence_dynamic", 0.01),
+         (3, 4, "low_confidence_static", 0.9), (3, 4, "low_confidence_dynamic", 0.01),
+         (4, 8, "low_confidence_dynamic", 0.0)]
+
+
+@pytest.fixture(scope="module", params=ROADS, ids=lambda r: f"K{r[0]}-{r[2][15:]}-{r[3]}")
+def spied(request):
+    """One request served alone with every run of the block program noted: what it was given
+    (the two blocks, the first's position, which are live), the logits it returned and the
+    last layer's key pool before and after."""
+    K_, ps, strategy, threshold = request.param
     gpt = seeded(tiny_block_moe(head_size=32), 1)
-    config, params = config_of(gpt, block_length=block_length), params_of(gpt)
-    engine = engine_of(gpt, block_length=block_length, page_size=page_size, chunk_tokens=24, max_seq=96)
+    config = config_of(gpt, block_length=K_, strategy=strategy, threshold=threshold)
+    engine = engine_of(gpt, block_length=K_, page_size=ps, chunk_tokens=24, max_seq=96,
+                       strategy=strategy, threshold=threshold)
     engine.record_block_states = True
-    p = prompt(rng, 14)
+    last, inner, calls = engine.cfg.n_layer - 1, engine.runner.block_cfn, []
+
+    def block_cfn(params, toks, state, tables, pos, live):
+        before = np.asarray(state[last][0])  # read before the program consumes it
+        out = inner(params, toks, state, tables, pos, live)
+        calls.append({"toks": np.asarray(toks)[0], "pos": int(np.asarray(pos)[0]), "live": np.asarray(live)[0],
+                      "logits": np.asarray(out[0])[0], "before": before, "after": np.asarray(out[1][last][0])})
+        return out
+
+    engine.runner.block_cfn = block_cfn
+    p = prompt(np.random.default_rng(11), 14)
     fut = engine.submit(p, max_new_tokens=9)
     engine.drain()
     res = fut.result()
-    # replay: the tokens going into each pass give, by the reference, the choices the next state shows
-    seq = list(p[:len(p) // block_length * block_length])
-    states = res.block_states
-    last = engine.cfg.n_layer - 1
-    denoise_keys = None
+    # the sequence as it was settled: the prompt's whole blocks, then every settled block
+    seq = list(p[:len(p) // K_ * K_]) + [int(t) for _, toks, m in res.block_states if not m.any() for t in toks]
+    return {"K": K_, "ps": ps, "engine": engine, "config": config, "params": params_of(gpt), "prompt": p,
+            "res": res, "calls": calls, "seq": seq, "last": last}
+
+
+def test_a_pass_that_settles_a_block_and_denoises_the_next_equals_the_reference_on_both(spied):
+    """The logits of the K denoised rows are the reference's full forward over the settled
+    sequence and the block going in, so they saw the first block's keys as THIS pass wrote them
+    (the pool held a denoise pass's until then); the K settled rows' keys are the reference's
+    over the final tokens."""
+    K_, seq, res = spied["K"], spied["seq"], spied["res"]
+    joined = 0
+    for call in spied["calls"]:
+        pos, (settles, denoises) = call["pos"], call["live"]
+        if denoises:
+            toks = np.asarray(seq[:pos + K_] + list(call["toks"][K_:]), np.int32)
+            want = np.asarray(REF.forward(spied["config"], spied["params"], toks))[pos + K_:]
+            np.testing.assert_allclose(call["logits"], want, atol=2e-4)
+        if settles:
+            assert list(call["toks"][:K_]) == seq[pos:pos + K_]
+            want = reference_keys(spied["config"], spied["params"], np.asarray(seq[:pos + K_], np.int32), spied["last"])
+            got = pool_rows(call["after"], res.pages, pos + K_)
+            np.testing.assert_allclose(got[pos:].reshape(K_, -1), want[pos:].reshape(K_, -1), atol=2e-5)
+            # ... and other rows than the pass found there: a denoise pass's, computed over mask tokens
+            stale = pool_rows(call["before"], res.pages, pos + K_)
+            assert np.abs(stale[pos:] - got[pos:]).max() > 1e-3
+            joined += int(denoises)
+    assert joined == spied["engine"].block_settles_joined > 0
+
+
+def test_a_block_that_is_not_live_writes_no_row_of_the_sequence(spied):
+    """A settled block is written once: in its later passes the first block is padding, and in
+    the pass that settles a sequence's last block the second is. What a pass changes of the pool
+    are the rows of its live blocks (and the null page, where padding rows go)."""
+    K_, ps, res = spied["K"], spied["ps"], spied["res"]
+    seen = set()
+    for call in spied["calls"]:
+        pos, live = call["pos"], tuple(bool(f) for f in call["live"])
+        seen.add(live)
+        before, after = (pool_rows(call[k], res.pages, len(res.pages) * ps) for k in ("before", "after"))
+        changed = set(np.flatnonzero(np.abs(after - before).reshape(len(after), -1).max(-1) > 0).tolist())
+        allowed = {pos + b * K_ + j for b in range(2) if live[b] for j in range(K_)}
+        assert changed <= allowed and (changed or not any(live))
+    # the pass after the prompt and a block's later passes; a settle beside a denoise; the last
+    # block's settling; and the pass in flight when that landed, thrown away
+    assert seen == {(False, True), (True, True), (True, False), (False, False)}
+
+
+def test_the_last_block_is_settled_before_the_future_resolves(spied):
+    """The pool's rows are the reference's over the finished tokens for EVERY returned position,
+    the last block's included: its settling is a pass of its own (a commit pass), fetched before
+    the request retires."""
+    seq, res, engine = spied["seq"], spied["res"], spied["engine"]
+    n = len(seq)
+    assert n == -(-len(res.tokens) // spied["K"]) * spied["K"] and list(res.tokens) == seq[:len(res.tokens)]
+    want = reference_keys(spied["config"], spied["params"], np.asarray(seq, np.int32), spied["last"])
+    got = served_keys(engine, spied["last"], res.pages, n)
+    np.testing.assert_allclose(got.reshape(n, -1), want.reshape(n, -1), atol=2e-5)
+    final = [c for c in spied["calls"] if c["live"].any()][-1]
+    assert tuple(final["live"]) == (True, False) and final["pos"] == n - spied["K"]
+    assert res.n_new_tokens == 9 and np.array_equal(res.tokens[:14], spied["prompt"])
+
+
+def test_block_states_and_unmasked_keep_the_order_a_replay_walks(spied):
+    """``[.., denoise, denoise, settled, denoise, ..]``: a block's denoise entries with fewer and
+    fewer masked, then its settled entry (none masked), BEFORE the next block's first denoise entry
+    though one pass made both; ``unmasked`` numbers the entries. Each denoise entry's fills are the
+    reference's choice for the tokens that went in (what ``drivers/serve_denoise.py: replay`` walks)."""
+    K_, res, config = spied["K"], spied["res"], spied["config"]
+    states, seq = res.block_states, list(spied["prompt"][:len(spied["prompt"]) // K_ * K_])
+    want = REF.generate(config, spied["params"], spied["prompt"], 9)
+    assert len(states) == want["passes"] and list(res.unmasked) == want["order"]
+    for (pos, toks, masked), (w_pos, w_toks, w_masked) in zip(states, want["states"]):
+        assert pos == w_pos and list(toks) == list(w_toks) and np.array_equal(masked, w_masked)
+    filled_at = {}
+    for position, n in res.unmasked:
+        filled_at.setdefault(n, []).append(position)
     for n, (pos, toks, masked) in enumerate(states):
         assert pos == len(seq)
-        logits = np.asarray(REF.forward(config, params, np.asarray(seq + list(toks), np.int32)))[pos:]
         if masked.any():
-            nxt_pos, nxt_toks, nxt_masked = states[n + 1]
-            assert nxt_pos == pos
-            filled = masked & ~nxt_masked
-            assert filled.any() and not (nxt_masked & ~masked).any()
-            assert np.array_equal(nxt_toks[filled], logits.argmax(-1)[filled])
-            denoise_keys = reference_keys(config, params, np.asarray(seq + list(toks), np.int32), last)
+            nxt_pos, _, nxt_masked = states[n + 1]  # the same block, further on
+            assert nxt_pos == pos and not (nxt_masked & ~masked).any()
+            assert filled_at[n] == [pos + int(j) for j in np.flatnonzero(masked & ~nxt_masked)]
         else:
-            seq += list(toks)
-    n_rows = len(seq)
-    got = served_keys(engine, last, res.pages, n_rows)
-    want = reference_keys(config, params, np.asarray(seq, np.int32), last)
-    np.testing.assert_allclose(got.reshape(n_rows, -1), want.reshape(n_rows, -1), atol=2e-5)
-    # the last block's rows as its last denoise pass computed them are other rows
-    lo = n_rows - block_length
-    assert np.abs(denoise_keys[lo:].reshape(block_length, -1) - got[lo:].reshape(block_length, -1)).max() > 1e-3
-    assert np.array_equal(res.tokens[:len(p)], p) and res.n_new_tokens == 9
+            assert n not in filled_at
+            seq += [int(t) for t in toks]
+    assert seq == spied["seq"] and not states[-1][2].any()
+
+
+def test_joined_settles_and_the_last_block_make_up_the_blocks_done(spied):
+    stats, res = spied["engine"].stats(), spied["res"]
+    blocks = sum(1 for s in res.block_states if not s[2].any())
+    assert stats["blocks_done"] == blocks == -(-(14 % spied["K"] + 9) // spied["K"])
+    assert stats["block_settles_joined"] == blocks - 1  # every block but the sequence's last
+    # a sequence pass a denoise, and one more for the last block's settling; then the pass in flight
+    denoises = len(res.block_states) - blocks
+    assert stats["block_passes"] == len(spied["calls"]) == denoises + 1 + 1
+    if spied["config"]["generation"]["confidence_threshold"] == 0.0:
+        assert denoises == blocks  # a block a pass
 
 
 def test_reference_replay_of_generates_own_states_gives_its_choices_and_the_served_keys(gpt, rng):
@@ -249,20 +348,24 @@ def test_engine_follows_reference_generate_token_for_token_and_position_for_posi
     # the positions that were returned, in the order they were filled
     assert list(res.unmasked) == want["order"]
     assert MASK not in res.new_tokens
-    if threshold == 0.0:
-        assert want["passes"] == 2 * -(-(L % K + n_new) // K)  # one denoise pass and the commit pass a block
+    if threshold == 0.0:  # one denoise and one settling a block by the reference's count: a pass a block, and one
+        assert want["passes"] == 2 * -(-(L % K + n_new) // K) and engine.block_passes - 1 == want["passes"] // 2 + 1
 
 
-def test_alone_equals_batched_tokens_and_orders(gpt, rng):
+@pytest.mark.parametrize("block_length,page_size,strategy,threshold", ROADS[:4])
+def test_alone_equals_batched_tokens_and_orders(rng, block_length, page_size, strategy, threshold):
+    gpt = seeded(tiny_block_moe(head_size=32), 1)
+    keys = dict(block_length=block_length, page_size=page_size, chunk_tokens=24, max_seq=96, strategy=strategy,
+                threshold=threshold)
     reqs = [(13, 10), (8, 12), (3, 5), (37, 7), (21, 9), (16, 4)]
     prompts = [prompt(rng, L) for L, _ in reqs]
     alone = []
+    engine = engine_of(gpt, **keys)
     for p, (_, n) in zip(prompts, reqs):
-        engine = engine_of(gpt)
         fut = engine.submit(p, max_new_tokens=n)
         engine.drain()
         alone.append(fut.result())
-    engine = engine_of(gpt)  # 4 slots for 6 requests: slots are reused
+    engine = engine_of(gpt, **keys)  # 4 slots for 6 requests: slots are reused
     futs = [engine.submit(p, max_new_tokens=n) for p, (_, n) in zip(prompts, reqs)]
     engine.drain()
     for a, f in zip(alone, futs):
@@ -302,7 +405,7 @@ def test_max_new_tokens_off_a_block_boundary_returns_what_was_asked(gpt, rng, n_
     assert res.tbot_s == 0.0 if n_new <= K else res.tbot_s > 0.0
 
 
-def test_eos_ends_a_sequence_at_its_blocks_commit(gpt, rng):
+def test_eos_ends_a_sequence_at_its_blocks_settling(gpt, rng):
     p = prompt(rng, 12)
     engine = engine_of(gpt)
     free = engine.submit(p, max_new_tokens=12)
@@ -315,6 +418,41 @@ def test_eos_ends_a_sequence_at_its_blocks_commit(gpt, rng):
     first = list(toks).index(eos)
     assert res.finish_reason == "eos" and list(res.new_tokens) == list(toks[:first + 1])
     assert engine.cache.allocator.n_used == 0 and not engine._has_work()
+
+
+def test_eos_in_a_block_throws_away_the_rows_that_rode_along(gpt, rng):
+    """The pass that settles the block with ``eos_id`` in it has denoised the next block too: those
+    rows, and the pass in flight behind them, are counted as discarded, and none of their
+    positions is returned."""
+    p = prompt(rng, 12)
+    engine = engine_of(gpt)
+    free = engine.submit(p, max_new_tokens=12)
+    engine.drain()
+    toks = free.result().new_tokens
+    eos = int(toks[5])
+    first = list(toks).index(eos)
+    observability.enable()
+    try:
+        observability.reset()
+        engine = engine_of(gpt)
+        engine.record_block_states = True
+        fut = engine.submit(p, max_new_tokens=12, eos_id=eos)
+        engine.drain()
+        c = observability.counters()
+    finally:
+        observability.disable()
+    res = fut.result()
+    ended = 12 + first // K * K  # the block the sequence ended in
+    assert res.finish_reason == "eos" and res.n_new_tokens == first + 1 < 12
+    assert res.block_states[-1][0] == ended and not res.block_states[-1][2].any()
+    assert all(pos < ended + K for pos, _ in res.unmasked)
+    # the next block's first denoise in the settling pass, and its second in the pass behind it
+    assert c["serve.decode_discarded"] == 2 and c["serve.block_settles_joined"] == c["serve.blocks_done"] == first // K + 1
+    assert engine.stats()["block_settles_joined"] == engine.blocks_done
+    # the rows routed are those of every block run, the thrown away ones too
+    runs = len(res.block_states) + c["serve.decode_discarded"]
+    assert c["serve.moe.rows_routed"] == runs * K * gpt.cfg.n_expert_per_token * gpt.cfg.n_layer
+    assert c["serve.tokens"] == len(res.unmasked)  # what was thrown away counts for no token
 
 
 def test_preempted_victim_resumes_from_its_committed_blocks(gpt, rng):
@@ -425,11 +563,15 @@ def test_a_request_whose_last_block_does_not_fit_is_refused(gpt, rng):
 
 # -- counters ------------------------------------------------------------------------------------------
 
-def test_counters_add_up(gpt, rng):
+@pytest.mark.parametrize("block_length,page_size,strategy,threshold", ROADS[:4])
+def test_counters_add_up(rng, block_length, page_size, strategy, threshold):
+    gpt = seeded(tiny_block_moe(head_size=32), 1)
+    Kb = block_length
     observability.enable()
     try:
         observability.reset()
-        engine = engine_of(gpt)
+        engine = engine_of(gpt, block_length=Kb, page_size=page_size, chunk_tokens=24, max_seq=96,
+                           strategy=strategy, threshold=threshold)
         reqs = [(13, 10), (8, 12), (3, 5), (21, 9), (37, 7)]
         futs = [engine.submit(prompt(rng, L), max_new_tokens=n) for L, n in reqs]
         engine.drain()
@@ -440,23 +582,27 @@ def test_counters_add_up(gpt, rng):
     unmasked = sum(len(r.unmasked) for r in results)
     assert c["serve.tokens"] == unmasked
     # every generated position is filled once; the last block is generated whole
-    assert unmasked == sum(-(-(L % K + n) // K) * K - L % K for L, n in reqs)
+    assert unmasked == sum(-(-(L % Kb + n) // Kb) * Kb - L % Kb for L, n in reqs)
     assert c["serve.block_passes"] == c["serve.decode_steps"] == engine.block_passes
-    assert c["serve.block_slot_passes"] == sum(r_passes for r_passes in
-                                               [max(p for _, p in r.unmasked) + 2 for r in results])
-    assert c["serve.blocks_done"] == c["serve.block_slot_commits"] == engine.blocks_done \
-        == sum(-(-(L % K + n) // K) for L, n in reqs)
-    assert c["serve.blocks_done"] * K >= c["serve.tokens"]
+    blocks = sum(-(-(L % Kb + n) // Kb) for L, n in reqs)
+    assert c["serve.blocks_done"] == c["serve.block_slot_commits"] == engine.blocks_done == blocks
+    # every block but a sequence's last is settled in the pass that first denoises the next
+    assert c["serve.block_settles_joined"] == engine.block_settles_joined == blocks - len(reqs)
+    # a sequence's passes: one a denoise (a settling rides in the next block's first), and one
+    # more that settles its last block
+    denoises = sum(len({n for _, n in r.unmasked}) for r in results)
+    assert c["serve.block_slot_passes"] == denoises + len(reqs)
+    assert c["serve.blocks_done"] * Kb >= c["serve.tokens"]
     assert 0 < c["serve.block_commits"] <= c["serve.block_passes"]
-    assert c["serve.block_slot_passes"] - c["serve.block_slot_commits"] >= c["serve.blocks_done"]  # >= 1 denoise a block
     assert c["serve.decode_overlapped"] >= c["serve.block_passes"] - 3
-    # the routing counters of the passes: every live row routed to n_expert_per_token experts, all held
+    # the routing counters of the passes: the rows of every block RUN (a settled and a denoised
+    # block a pass are two) routed to n_expert_per_token experts, all held; padding routes nowhere
     assert c["serve.moe.rows_routed"] == c["serve.moe.rows_held"] \
-        == (c["serve.block_slot_passes"] + c["serve.decode_discarded"]) * K * gpt.cfg.n_expert_per_token \
-        * gpt.cfg.n_layer
+        == (blocks + denoises) * Kb * gpt.cfg.n_expert_per_token * gpt.cfg.n_layer
     assert c["serve.decode_discarded"] == len(reqs)  # the pass in flight when a sequence's last block landed
     stats = engine.stats()
     assert stats["blocks_done"] == c["serve.blocks_done"] and stats["block_passes"] == c["serve.block_passes"]
+    assert stats["block_settles_joined"] == c["serve.block_settles_joined"]
 
 
 # -- the two kernels through the v5e's compiler at the published widths (no chip needed) -----------------
@@ -474,7 +620,7 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("tokens_in", [256, 512], ids=["pass-64-slots-x-4", "chunk-512"])
+@pytest.mark.parametrize("tokens_in", [256, 512], ids=["chunk-256", "pass-64-slots-x-two-blocks-or-chunk-512"])
 def test_the_ragged_kernel_compiles_for_the_v5e_at_128_experts_of_768(one_chip, tokens_in):
     """The ragged kernel's third shape: 128 held experts of width 768 at d 2048, 8 a token; the
     ``vmem`` and ``lanes`` arms of analysis/memory.py pass it (a whole panel of 768 hidden
@@ -498,10 +644,12 @@ def test_the_ragged_kernel_compiles_for_the_v5e_at_128_experts_of_768(one_chip, 
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
-@pytest.mark.parametrize("B,T", [(64, 4), (1, 512)], ids=["pass-64-slots-x-4", "chunk-512"])
+@pytest.mark.parametrize("B,T", [(64, 4), (64, 8), (1, 512)],
+                         ids=["64-slots-x-a-block-of-4", "pass-64-slots-x-two-blocks", "chunk-512"])
 def test_the_paged_chunk_kernel_compiles_for_the_v5e_at_a_block_of_4(one_chip, B, T):
-    """The paged chunk kernel at a pass's shape (64 sequences of 4 rows, 8 query heads a key head:
-    32 rows a key head) and at a prompt chunk's, over the cell's pools."""
+    """The paged chunk kernel at one block a slot (64 sequences of 4 rows, 8 query heads a key head:
+    32 rows a key head), at a pass's shape (two blocks a slot: 8 rows, 64 a key head) and at a prompt
+    chunk's, over the cell's pools."""
     from thunder_tpu.executors import pallasex
 
     bf = jnp.bfloat16

@@ -22,7 +22,8 @@ held: drop-free through ``moe.ragged_experts``. The attention is ``litgpt.Causal
 so the model is served as a dense rope GPT is (``serving/runner.py: DenseGPT``; its blocks route,
 so each is a ``RoutedBlock`` there): paged keys and values of every position, the block program
 ``verify`` with the block's last position as every row's coverage. How a block is generated
-(denoise passes, the commit pass) is the engine's (``serving/scheduler.py``, ``block_diffusion=``).
+(its denoise passes, and the run over its final tokens that settles its keys and values, inside
+the next block's first pass) is the engine's (``serving/scheduler.py``, ``block_diffusion=``).
 
 Scopes a device profile is split by: ``attn`` (``rope`` within), ``moe_router``,
 ``moe_experts``, ``head``.

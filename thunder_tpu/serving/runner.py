@@ -30,9 +30,13 @@ Two compiled programs:
   slots hold stale values that the next committed token overwrites.
 
 * block — a pass of generation by diffusion over blocks (``block_cfn``): the
-  verify program with another mask. K rows a slot, true positions for rope and
-  the writes, the block's LAST position as every row's coverage; all K rows'
-  keys and values written every pass (docs/serving.md).
+  verify program with another mask. TWO adjacent blocks a slot, 2K rows: the
+  block the sequence has just finished, run once more over its final tokens so
+  that its keys and values stay (it is SETTLED), and the next block, which is
+  denoised; true positions for rope and the writes, the last position of a
+  row's own block as its coverage, the head over the second block's K rows
+  only. Either half of a slot may be inactive (``live``): its rows write to the
+  null page and route to no expert (docs/serving.md).
 
 All are pure functional: cached state goes in, updated state comes out. Every
 program CONSUMES the state it is given (`donated_argnums`): the caller rebinds
@@ -236,8 +240,10 @@ class Step:
     verify   as decode with K1 tokens a sequence: ``pos_mat`` (B, K1) the TRUE
              positions, for rope and the writes; ``mask_pos`` (B, K1) what each
              row's attention covers (keys at positions <= it): ``pos_mat`` in a
-             speculative step, the block's last position in a block pass
-             (``block`` true: ``live`` (B,) says which slots hold a sequence);
+             speculative step, the last position of the row's block in a block
+             pass (``block`` true: K1 = 2K, and ``live`` (B, K1) says which
+             rows, a block of a slot's two together, are run for a sequence;
+             the others carry position 0 and the null page);
              ``page_of[kind]`` and ``slot_in_page`` flat (B * K1,)
     mixed    a chunk's T rows and after them one row a decode slot: ``chunk``
              and ``decode``, the two programs' own ``Step``s; ``states`` and
@@ -421,7 +427,9 @@ class DenseBlock:
         replaces before any mask admits it. A row's coverage is ``step.mask_pos``:
         its own position in a speculative verify step, its block's LAST position
         in a pass of generation by diffusion over blocks (every row of a block
-        sees the whole block), which is what makes the two one method."""
+        sees the whole block; the rows of the second of a slot's two blocks see
+        the first as written here, just above), which is what makes the two one
+        method."""
         with named_scope("attn"):
             q, k, v = self._qkv(step, x)
             k_tok, v_tok = self._tokens(k, v)
@@ -440,7 +448,7 @@ class RoutedBlock(DenseBlock):
     no group and read no panel) and, in the decode program and in a block pass traced
     with the bus on, counts its routing (``ROUTING_COUNTERS``)."""
 
-    # a block's K rows a slot ride in no chunk's program yet: the engine runs the two apart
+    # a pass's two blocks a slot ride in no chunk's program yet: the engine runs the two apart
     mixed = None
 
     def _tail(self, step, x, h):
@@ -484,10 +492,11 @@ class DenseGPT:
             return ltorch.le(t, step.last)
         if step.program == "decode":
             return step.live
-        # verify: a sequence's rows are live together; a block pass says which are (``live``),
-        # a speculative step's idle slots carry position 0
-        live = step.live if getattr(step, "block", False) else ltorch.gt(step.pos_mat[:, 0], 0)
         B, K1 = step.pos_mat.shape
+        if getattr(step, "block", False):  # a block pass says which rows are (``live``)
+            return ltorch.reshape(step.live, (B * K1,))
+        # a speculative step: a sequence's rows are live together, idle slots carry position 0
+        live = ltorch.gt(step.pos_mat[:, 0], 0)
         return ltorch.reshape(ltorch.expand(ltorch.unsqueeze(live, 1), (B, K1)), (B * K1,))
 
     def _rope_rows(self, step):
@@ -560,9 +569,9 @@ class PagedGPTRunner:
             with functional_params(gpt, params):
                 return self._forward_verify(toks, state, tables, pos)
 
-        def block(params, toks, state, tables, pos):
+        def block(params, toks, state, tables, pos, live):
             with functional_params(gpt, params):
-                return self._forward_verify(toks, state, tables, pos, block=True)
+                return self._forward_verify(toks, state, tables, pos, live)
 
         prefill.__name__ = "serve_prefill"
         decode.__name__ = "serve_decode"
@@ -706,37 +715,48 @@ class PagedGPTRunner:
             return (logits[:1], logits[1:], state) + self._counted(both)
 
     # -- speculative verify -----------------------------------------------
-    def _forward_verify(self, toks, state, tables, pos, block: bool = False):
+    def _forward_verify(self, toks, state, tables, pos, live=None):
         """toks (Bcap, k+1): each sequence's current token followed by its k
         draft proposals; pos (Bcap,) int32 — the position of toks[:, 0].
         Returns (logits (Bcap, k+1, V), new state) — logits at every
         position, so ONE packed target step scores every proposal.
 
-        With ``block`` the rows are a BLOCK of generation by diffusion (``block_cfn``):
-        toks (Bcap, K) the block's tokens, the mask token where a position is not
-        filled yet, pos the block's first position; every row covers the keys up to
-        the block's LAST position, so it sees the whole block and all before it.
-        All K rows' keys and values are written, as a verify step's are: a denoise
-        pass's are replaced by the next pass's, and the pass that runs the finished
-        block leaves the ones that stay. An idle slot carries a null-page row (its
-        position may be 0 or stale; a live sequence's first block may be at 0 too).
-        Returns after the state the routing counters, as the decode program does."""
+        With ``live`` the rows are a pass of generation by diffusion over blocks
+        (``block_cfn``): toks (Bcap, 2K) TWO adjacent blocks a slot, pos the first
+        position of the first, live (Bcap, 2) bool which of the two are run. The first
+        is the block the sequence has finished, over its final tokens: the keys and
+        values this pass writes for it are the ones that stay (it is SETTLED). The
+        second is the block being denoised, the mask token where a position is not
+        filled yet: its keys and values are replaced by the next pass's. A row covers
+        the keys up to the LAST position of its own block, so the first block's rows
+        see nothing of the second and the second's see the first as this pass wrote
+        it. A block that is not live (no block to settle: the pass after a prompt, a
+        block's later denoise passes; nothing to denoise: a sequence's last block
+        being settled; an idle slot, both) carries its rows as padding: position 0,
+        the null page, no expert. Returns (logits (Bcap, K, V) of the SECOND block's
+        rows, new state) and after them the routing counters, as the decode program
+        does."""
         from ..core import dtypes, prims
 
         B, K1 = toks.shape
+        block = live is not None
         offs = prims.iota(K1, dtype=dtypes.int32, device=toks.device)
         pos_mat = ltorch.reshape(pos, (B, 1)) + ltorch.reshape(offs, (1, K1))  # (B, K1)
         step = Step("verify", self.page_size, tables=self._by_kind(tables), pos_mat=pos_mat,
                     mask_pos=pos_mat, block=block)
         if block:
-            step.mask_pos = ltorch.expand(ltorch.reshape(pos + (K1 - 1), (B, 1)), (B, K1))
-            step.live = ltorch.gt(step.tables[self.page_kinds[0]][:, 0], 0)
+            K = K1 // 2
+            step.live = ltorch.reshape(ltorch.expand(ltorch.unsqueeze(live, 2), (B, 2, K)), (B, K1))
+            step.pos_mat = pos_mat = ltorch.where(step.live, pos_mat, 0)
+            step.mask_pos = ltorch.floor_divide(pos_mat, K) * K + (K - 1)
         self.model.begin(step)
         with named_scope("kv_write"):
             page_of, slot = _token_pages(step.tables, pos_mat, self.page_size)
+            if block:
+                page_of = {k: ltorch.where(step.live, v, 0) for k, v in page_of.items()}
             step.page_of = {k: ltorch.reshape(v, (B * K1,)) for k, v in page_of.items()}
             step.slot_in_page = ltorch.reshape(slot, (B * K1,))
         x, state = self._run_layers(step, self._embed(toks), state)
         with named_scope("head"):
-            logits = self.model.head(x)  # (B, K1, V)
+            logits = self.model.head(x[:, K1 // 2:] if block else x)  # (B, K or K1, V)
         return (logits, state) + (self._counted(step) if block else ())

@@ -62,15 +62,17 @@ host learns at a commit (``eos_id``, a cancelled Future; a first token's too)
 costs one step whose token is thrown away.
 
 Generation by diffusion over blocks (``block_diffusion=``, docs/serving.md) is
-a third decode order on the same pipeline: a pass carries a block of K
-positions a sequence (``runner.block_cfn``), its sampler (``_block_sample``)
-fills some masked positions or, for a block with none, moves the slot on, and
-the blocks, their masked flags and their positions stay on the device from
-pass to pass. One pass is in flight; the host reads the record of the pass
-before it (``_commit_block_pass``) and assumes nothing about how many
-positions a pass filled. Prompts' whole blocks go through the chunk program
-under the block-causal mask; an activation merges the prompt's tail and mask
-tokens into the next pass's blocks on the device (``_activate_block``).
+a third decode order on the same pipeline: a pass carries TWO adjacent blocks
+of K positions a sequence (``runner.block_cfn``): the block it has finished,
+whose keys and values this pass SETTLES, and the next one, which it denoises.
+Its sampler (``_block_sample``) fills some of that block's masked positions
+and, once none is left, makes it the next pass's block to settle. The blocks,
+their masked flags and their positions stay on the device from pass to pass.
+One pass is in flight; the host reads the record of the pass before it
+(``_commit_block_pass``) and assumes nothing about how many positions a pass
+filled. Prompts' whole blocks go through the chunk program under the
+block-causal mask; an activation merges the prompt's tail and mask tokens into
+the next pass's blocks on the device (``_activate_block``).
 
 Per-request observability rides the existing bus: request-id-tagged spans,
 ``serve.*`` counters, and flight-recorder records per decode iteration
@@ -132,9 +134,11 @@ class RequestResult:
     # is how a test reads what the engine cached for a request it served alone
     pages: tuple = ()
     # generation by diffusion over blocks only: (position, pass) of every generated position in
-    # the order it was filled (passes numbered from 0 over the request, commit passes included),
-    # and, where ``engine.record_block_states`` was set, the block going INTO each pass:
-    # (first position, its tokens (K,), its masked flags (K,))
+    # the order it was filled, and, where ``engine.record_block_states`` was set, the block going
+    # INTO each pass: (first position, its tokens (K,), its masked flags (K,)). A "pass" here is
+    # one run of ONE block, numbered from 0 over the request: every denoise pass of a block and
+    # then its settling, none masked, which comes before the next block's first denoise pass
+    # though one dispatch ran both (what a loop of one block a forward would record)
     unmasked: tuple = ()
     block_states: tuple = ()
 
@@ -172,8 +176,9 @@ class _Request:
     n_shared: int = 0            # leading shared pages in .pages
     chunk_pos: int = -1          # next chunk start (chunk mode only)
     # generation by diffusion over blocks: the prompt's last L mod K tokens, which open the
-    # first generated block; the block the next fetched pass is about and how many of its
-    # leading tokens were given; passes fetched; RequestResult.unmasked / .block_states
+    # first generated block; the position the next fetched pass's record must carry (the first
+    # of its two blocks: K before the block being denoised) and how many leading tokens of the
+    # first generated block were given; block runs recorded; RequestResult.unmasked / .block_states
     block_tail: Optional[np.ndarray] = None
     block_pos: int = 0
     block_given: int = 0
@@ -190,7 +195,7 @@ class _Step:
     before this step lands."""
 
     # (max_batch, 1) int32 on the device: the next step's tokens; of a pass over blocks the
-    # (max_batch, 4 K + 1) record ``_block_sample`` makes for the host
+    # (max_batch, 5 K + 3) record ``_block_sample`` makes for the host
     nxt: jax.Array
     reqs: Dict[int, _Request]    # slot -> the sequence that was in the step
     t0: float                    # when the pass that dispatched it began its decode
@@ -270,24 +275,32 @@ class _BlockSpec:
                    float(spec.get("threshold", 0.9)), int(spec["mask_id"]))
 
 
-def _block_sample(logits, toks, masked, pos, end, seeds, temps, *, spec: _BlockSpec):
-    """What follows a pass over blocks, for every slot at once: logits (B, K, V) of the
-    block's rows, the block (``toks`` (B, K), ``masked`` (B, K) bool, ``pos`` (B,) its first
-    position, ``end`` (B,) where the sequence's last block ends). A block with a masked
-    position was DENOISED: each masked position's candidate ``x0`` (temperature 0: the
+def _block_sample(logits, toks, masked, pos, end, live, seeds, temps, *, spec: _BlockSpec):
+    """What follows a pass over blocks, for every slot at once. A slot's state is what the pass
+    was given: ``toks`` (B, 2 K) two adjacent blocks, the one to settle and the one being
+    denoised; ``masked`` (B, K) bool, the second's positions not filled yet; ``pos`` (B,) the
+    first position of the FIRST (K before the block being denoised: -K while that is a
+    sequence's block at 0); ``end`` (B,) where the sequence's last block ends; ``live`` (B, 2)
+    bool, which of the two the pass ran. ``logits`` (B, K, V) are the second block's rows'.
+
+    The second block is DENOISED: each masked position's candidate ``x0`` (temperature 0: the
     argmax; else the position-keyed draw, ``fold_in(PRNGKey(seed), position)``: a position is
     filled once; never the mask token, whose logit is left out) and its confidence ``c =
-    softmax(logits / T)[x0]``; filled are the ``n``
-    masked positions of largest ``c`` (ties to the lower position), or under the dynamic
-    strategy every masked position with ``c > threshold`` where those are at least ``n``.
-    A block with none was in its COMMIT pass (the keys and values that pass wrote are the
-    ones that stay): the slot moves on by K to an all-masked block, unless that was the
-    sequence's last, which then stays as it is (running it again writes the same rows).
-    An idle slot is a finished sequence with ``end`` 0. Returns the next pass's (toks, masked,
-    pos, end) and the (B, 4 K + 1) int32 record the host reads a pass later: pos, the block
-    going in (tokens, masked), and the block as denoised (tokens, masked)."""
+    softmax(logits / T)[x0]``; filled are the ``n`` masked positions of largest ``c`` (ties to
+    the lower position), or under the dynamic strategy every masked position with ``c >
+    threshold`` where those are at least ``n``. Once no position is left masked the block is
+    FINISHED and the slot moves on by K: the finished block is the next pass's first, to be
+    settled there (its keys and values as that pass writes them, over its final tokens, are the
+    ones that stay), beside the sequence's next block, all masked, as the second; after a
+    sequence's LAST block the second is not live, and the pass that settles the last block
+    denoises nothing. A first block has been settled by the pass that carried it: it is not
+    live in the next one, so a settled row is written once. An idle slot has neither live and
+    ``end`` 0. Returns the next pass's (toks, masked, pos, end, live) and the (B, 5 K + 3)
+    int32 record the host reads a pass later: pos, live, the first block's tokens, the second
+    going in (tokens, masked) and as denoised (tokens, masked)."""
     B, K, _ = logits.shape
     i32 = jnp.int32
+    cur = toks[:, K:]
 
     def candidates(draws: bool):
         def one(l, s, p, t):
@@ -302,7 +315,7 @@ def _block_sample(logits, toks, masked, pos, end, seeds, temps, *, spec: _BlockS
         return lambda: jax.vmap(jax.vmap(one, in_axes=(0, None, 0, None)))(logits, seeds, at, temps)
 
     with jax.named_scope("unmask"):
-        at = pos[:, None] + jnp.arange(K, dtype=i32)[None, :]
+        at = (pos + K)[:, None] + jnp.arange(K, dtype=i32)[None, :]
         # a pass whose every sequence is greedy draws nothing: the noise of a draw is a number a
         # vocabulary entry and row (2.3 of the sampler's 3.3 ms at 256 rows of 151,936: PR 41)
         x0, c = jax.lax.cond(jnp.any(temps > 0), candidates(True), candidates(False))
@@ -313,19 +326,25 @@ def _block_sample(logits, toks, masked, pos, end, seeds, temps, *, spec: _BlockS
         if spec.dynamic:
             high = masked & (c > spec.threshold)
             fill = jnp.where(jnp.sum(high, -1, dtype=i32)[:, None] >= n_fill, high, fill)
-        toks_dn, masked_dn = jnp.where(fill, x0, toks), masked & ~fill
-        commit = ~jnp.any(masked, -1)
-        moves = (commit & (pos + K < end))[:, None]
-        rec = jnp.concatenate([pos[:, None], toks, masked.astype(i32), toks_dn, masked_dn.astype(i32)], 1)
-        return (jnp.where(moves, spec.mask_id, toks_dn).astype(i32), moves | masked_dn,
-                jnp.where(moves[:, 0], pos + K, pos), end, rec)
+        toks_dn, masked_dn = jnp.where(fill, x0, cur), masked & ~fill
+        done = live[:, 1] & ~jnp.any(masked_dn, -1)  # finished by this pass: settled in the next
+        opens = done & (pos + 2 * K < end)           # ... beside the sequence's next block
+        rec = jnp.concatenate([pos[:, None], live.astype(i32), toks[:, :K], cur, masked.astype(i32),
+                               toks_dn, masked_dn.astype(i32)], 1)
+        # the block as it stands, twice: to be settled if it is finished (else not live), and
+        # to be denoised further, where the sequence's next block, all masked, does not take its place
+        nxt = jnp.concatenate([toks_dn, jnp.where(done[:, None], spec.mask_id, toks_dn)], 1)
+        return (nxt.astype(i32), jnp.where(done[:, None], opens[:, None], masked_dn),
+                jnp.where(done, pos + K, pos), end,
+                jnp.stack([done, jnp.where(done, opens, live[:, 1])], 1), rec)
 
 
-def _merge_block(toks, masked, pos, end, slot, row_toks, row_masked, row_pos, row_end):
+def _merge_block(toks, masked, pos, end, live, slot, row_toks, row_masked, row_pos, row_end, row_live):
     """The next pass's blocks with slot ``slot``'s replaced: a sequence activated since the
-    last dispatch (its prompt's tail and mask tokens), or a slot gone idle."""
+    last dispatch (nothing to settle; its prompt's tail and mask tokens to denoise), or a slot
+    gone idle."""
     return (toks.at[slot].set(row_toks), masked.at[slot].set(row_masked),
-            pos.at[slot].set(row_pos), end.at[slot].set(row_end))
+            pos.at[slot].set(row_pos), end.at[slot].set(row_end), live.at[slot].set(row_live))
 
 
 class ServingEngine:
@@ -363,8 +382,9 @@ class ServingEngine:
                     ``{"block_length": K, "denoising_steps": S, "strategy":
                     "low_confidence_dynamic" | "low_confidence_static",
                     "threshold": tau, "mask_id": m}``. A pass carries a block of K
-                    positions a sequence, unmasks some of them, and the block's keys
-                    and values stay only from the pass that runs it with none masked.
+                    positions a sequence and unmasks some of them; the block's keys
+                    and values stay only from a run of it with none masked, which
+                    rides in the next block's first pass.
                     Needs layers that cache keys and values of every position;
                     ``prefix_sharing`` and ``draft_gpt`` are refused with it
     """
@@ -460,8 +480,8 @@ class ServingEngine:
             if not self.runner.blocks:
                 raise ValueError(
                     "block_diffusion= cannot serve a model with window, recurrent or latent layers: "
-                    "a pass writes a block's K positions and attends them under a mask of its own "
-                    "(the block's last position for every row), which only layers that cache keys "
+                    "a pass writes two blocks of K positions and attends them under a mask of its own "
+                    "(the last position of a row's block), which only layers that cache keys "
                     "and values of every position run (serving/runner.py: DenseBlock.verify)")
             if prefix_sharing:
                 raise ValueError(
@@ -499,12 +519,13 @@ class ServingEngine:
 
             self._block_sampler = jax.jit(serve_unmask)
             self._block_merge = jax.jit(_merge_block)
-            # every slot's block (tokens, masked, first position, the sequence's end): on the
-            # device from pass to pass, the sampler's output the next pass's input
-            self._blk = tuple(jax.device_put(a, self._device) for a in (
-                np.zeros((max_batch, K), np.int32), np.zeros((max_batch, K), bool),
-                np.zeros((max_batch,), np.int32), np.zeros((max_batch,), np.int32)))
-            self._idle_block = (np.zeros((K,), np.int32), np.zeros((K,), bool), np.int32(0), np.int32(0))
+            # every slot's two blocks (``_block_sample``: tokens, the second's masked flags, the
+            # first's position, the sequence's end, which are live): on the device from pass to
+            # pass, the sampler's output the next pass's input
+            self._idle_block = (np.zeros((2 * K,), np.int32), np.zeros((K,), bool), np.int32(0),
+                                np.int32(0), np.zeros((2,), bool))
+            self._blk = tuple(jax.device_put(np.stack([a] * max_batch), self._device)
+                              for a in self._idle_block)
         # with it set a retired request's result carries the block going into each of its passes
         self.record_block_states = False
 
@@ -566,7 +587,7 @@ class ServingEngine:
         # the step rides in it (one read of the weights for both), where every layer of the
         # model can run both kinds of rows at once and the decode path is the plain one; the
         # rows of a chunk dispatch no step rides in are idle slots, all of them
-        # ... and a block's K rows a slot ride in no chunk's program: the two run apart
+        # ... and a pass's two blocks a slot ride in no chunk's program: the two run apart
         self._mixes = self.runner.mixes and draft_gpt is None and self.block is None
         self._idle_rows = (
             self._upload(self._toks[:, None]),
@@ -597,9 +618,10 @@ class ServingEngine:
         self.spec_accepted = 0
         self.preempted = 0
         self.resumed = 0
-        self.block_passes = 0    # passes over blocks fetched, and those of them that were commit
-        self.block_commits = 0   # passes for at least one sequence
-        self.blocks_done = 0     # blocks committed, over the sequences
+        self.block_passes = 0    # passes over blocks fetched, and those of them that settled
+        self.block_commits = 0   # a block for at least one sequence
+        self.blocks_done = 0     # blocks settled, over the sequences, and those of them whose
+        self.block_settles_joined = 0  # pass denoised the sequence's next block too
 
         # SLO measurement substrate (observability/slo.py): a declarative
         # policy gets a sliding-window monitor (breach events/counters) and
@@ -761,7 +783,7 @@ class ServingEngine:
         }
         if self.block is not None:
             out.update(block_passes=self.block_passes, block_commits=self.block_commits,
-                       blocks_done=self.blocks_done)
+                       blocks_done=self.blocks_done, block_settles_joined=self.block_settles_joined)
         if self.prefix is not None:
             out["prefix_cache_pages"] = len(self.prefix)
         if self.slo_policy is not None:
@@ -1519,20 +1541,23 @@ class ServingEngine:
     def _activate_block(self, req: _Request, slot: int) -> None:
         """``_activate`` under ``block_diffusion=``: ``req`` takes ``slot`` and its first block,
         the prompt's tail and mask tokens after it, is merged into the next pass's blocks on
-        the device (``_dispatch_block``). Nothing lands."""
+        the device (``_dispatch_block``) as the block to denoise, with none to settle: what is
+        before it went through the chunk program. Nothing lands."""
         spec, tail = self.block, req.block_tail
+        K = spec.K
         if _obs.enabled():
             _obs_metrics.record_serve("activations")
             if self._inflight is not None:
                 _obs_metrics.record_serve("activations_joined")
-        toks = np.full((spec.K,), spec.mask_id, np.int32)
-        toks[:len(tail)] = tail
+        toks = np.full((2 * K,), spec.mask_id, np.int32)
+        toks[K:K + len(tail)] = tail
         pos = len(req.prompt_eff)
-        req.block_pos, req.block_given = pos, len(tail)
-        # what a preempted victim had filled of a block it never committed is filled anew
+        req.block_pos, req.block_given = pos - K, len(tail)
+        # what a preempted victim had filled of a block it never settled is filled anew
         req.unmasked = [u for u in req.unmasked if u[0] < pos]
-        self._take_slot(req, slot, pos, (toks, np.arange(spec.K) >= len(tail), np.int32(pos),
-                                         np.int32(self._life(len(req.prompt), req.max_new_tokens))))
+        self._take_slot(req, slot, pos, (toks, np.arange(K) >= len(tail), np.int32(pos - K),
+                                         np.int32(self._life(len(req.prompt), req.max_new_tokens)),
+                                         np.array([False, True])))
 
     def _take_slot(self, req: _Request, slot: int, pos: int, feed) -> None:
         self._feeds[slot] = feed
@@ -1741,12 +1766,13 @@ class ServingEngine:
         self._drop_lost_pools(e)
 
     def _dispatch_block(self, live: List[int], prev: Optional[_Step], t0: float) -> _Step:
-        """``_dispatch`` under ``block_diffusion=``: enqueue ONE pass over every slot's block
-        and its sampler (``_block_sample``). The blocks, their masked flags and positions
-        never leave the device: the sampler's output is the next pass's input, and the host
-        reads the small record of this pass behind the next dispatch. A slot in a denoise
-        pass and one in its commit pass share the dispatch; which a slot is in, the device
-        knows and the host learns from the record. A sequence activated since the last
+        """``_dispatch`` under ``block_diffusion=``: enqueue ONE pass over every slot's two
+        blocks and its sampler (``_block_sample``). The blocks, their masked flags and
+        positions never leave the device: the sampler's output is the next pass's input, and
+        the host reads the small record of this pass behind the next dispatch. A slot whose
+        pass settles a block and denoises the next, one in a later denoise pass of its block
+        and one settling its sequence's last block share the dispatch; which a slot is in, the
+        device knows and the host learns from the record. A sequence activated since the last
         dispatch, and a slot gone idle, are merged in first (``_feeds``)."""
         phase = _obs_runtime.phase
         try:
@@ -1756,8 +1782,9 @@ class ServingEngine:
                     blk = self._block_merge(*blk, self._upload(np.int32(slot)),
                                             *(self._upload(a) for a in row))
             with phase("engine:dispatch"):
+                toks, _, pos, _, live2 = blk
                 logits, state, *counted = self.runner.block_cfn(
-                    self.params, blk[0], self.cache.state, self._pt_dev, blk[2])
+                    self.params, toks, self.cache.state, self._pt_dev, pos, live2)
                 self.cache.rebind(state)
                 *nxt, rec = self._block_sampler(logits, *blk, self._seeds_dev, self._temps_dev)
                 self._blk = tuple(nxt)
@@ -1773,7 +1800,9 @@ class ServingEngine:
             _obs_metrics.record_serve("decode_steps")
             _obs_metrics.record_serve("decode_overlapped", delta=int(prev is not None))
             self._record_state(len(live))
-            self._record_chunk_pages(self._pos[live], self.block.K)
+            # the host holds the position of the block a sequence denoises, as of the last record
+            # it read: the two blocks' queries start K before it
+            self._record_chunk_pages(np.maximum(self._pos[live] - self.block.K, 0), 2 * self.block.K)
         return _Step(rec, {i: self._slots[i] for i in live}, t0, *counted)
 
     def _fetch(self, step: Optional[_Step]) -> Optional[np.ndarray]:
@@ -1832,57 +1861,71 @@ class ServingEngine:
 
     def _commit_block_pass(self, step: _Step, rec: np.ndarray) -> None:
         """``_commit_step`` under ``block_diffusion=``: what the pass ``step`` did for each
-        sequence that was in it, from the record its sampler made (``_block_sample``). A
-        DENOISE pass filled positions: which and with what is read off the record, never
-        assumed, and they are noted (``RequestResult.unmasked``) and counted as tokens
-        produced. A COMMIT pass ran the finished block: its generated tokens go to
-        ``req.tokens`` now (a block is what a stream could show), ``t_first`` is the first
-        block's, and the sequence may end here (``max_new_tokens`` reached: the first that
-        many are returned; ``eos_id`` among the block's: through it; a cancelled Future)."""
+        sequence that was in it, from the record its sampler made (``_block_sample``), the
+        block it SETTLED first and then the block it denoised, as a loop of one block a
+        forward would have run them. A settled block's keys and values are in the pool as
+        they stay: its generated tokens go to ``req.tokens`` now (a block is what a stream
+        could show), ``t_first`` is the first block's, and the sequence may end here
+        (``max_new_tokens`` reached: the first that many are returned; ``eos_id`` among the
+        block's: through it; a cancelled Future); what the pass did for the next block is
+        then thrown away. A DENOISE filled positions: which and with what is read off the
+        record, never assumed, and they are noted (``RequestResult.unmasked``) and counted as
+        tokens produced. Only the pass that settles a sequence's LAST block denoises nothing:
+        that one is a commit pass and nothing else."""
         K = self.block.K
         obs_on = _obs.enabled()
         with _obs_runtime.phase("engine:commit"):
             t_now = time.perf_counter()
             kept = [(i, req) for i, req in step.reqs.items() if self._slots[i] is req]
-            filled = commits = 0
+            filled = commits = joined = cut = 0
             for i, req in kept:
-                pos = int(rec[i, 0])
-                toks_in, masked_in = rec[i, 1:1 + K], rec[i, 1 + K:1 + 2 * K].astype(bool)
+                pos, settles, denoises = int(rec[i, 0]), bool(rec[i, 1]), bool(rec[i, 2])
+                first, toks_in, masked_in, toks_dn, masked_dn = (
+                    rec[i, 3 + j * K:3 + (j + 1) * K] for j in range(5))
                 if pos != req.block_pos:  # the books are wrong: never commit another block's rows
                     self._fail(req, RuntimeError(
-                        f"request {req.request_id}: a pass ran the block at {pos}, the host "
-                        f"expected the one at {req.block_pos}"))
+                        f"request {req.request_id}: a pass ran the blocks at {pos}, the host "
+                        f"expected those at {req.block_pos}"))
                     self._clear_slot(i)
                     continue
-                if self.record_block_states:
-                    req.block_states.append((pos, toks_in.copy(), masked_in.copy()))
-                req.n_passes += 1
-                if masked_in.any():
-                    toks_dn, masked_dn = rec[i, 1 + 2 * K:1 + 3 * K], rec[i, 1 + 3 * K:].astype(bool)
+                if settles:
+                    if self.record_block_states:
+                        req.block_states.append((pos, first.copy(), np.zeros((K,), bool)))
+                    req.n_passes += 1
+                    commits += 1
+                    joined += denoises
+                    new = [int(t) for t in first[req.block_given:]]
+                    req.block_given = 0
+                    if req.t_first == 0.0:
+                        req.t_first = t_now
+                    req.t_last = t_now
+                    if req.eos_id is not None and req.eos_id in new:
+                        new = new[:new.index(req.eos_id) + 1]
+                    req.tokens.extend(new)
+                    del req.tokens[req.max_new_tokens:]
+                    if self._finished(req, req.tokens[-1]):
+                        cut += denoises  # the next block's rows rode along for nothing
+                        self._retire(req)
+                        self._clear_slot(i)
+                        continue
+                if denoises:
+                    masked_in, masked_dn = masked_in.astype(bool), masked_dn.astype(bool)
+                    if self.record_block_states:
+                        req.block_states.append((pos + K, toks_in.copy(), masked_in))
+                    req.n_passes += 1
                     new = np.flatnonzero(masked_in & ~masked_dn)
-                    req.unmasked.extend((pos + int(j), req.n_passes - 1) for j in new)
+                    req.unmasked.extend((pos + K + int(j), req.n_passes - 1) for j in new)
                     filled += len(new)
+                    if not masked_dn.any():  # finished: the next pass settles it, K further on
+                        req.block_pos = pos + K
+                        self._pos[i] = pos + 2 * K
                     if req.future.cancelled():
                         self._retire(req)
                         self._clear_slot(i)
-                    continue
-                commits += 1
-                new = [int(t) for t in toks_in[req.block_given:]]
-                req.block_given = 0
-                req.block_pos = self._pos[i] = pos + K
-                if req.t_first == 0.0:
-                    req.t_first = t_now
-                req.t_last = t_now
-                if req.eos_id is not None and req.eos_id in new:
-                    new = new[:new.index(req.eos_id) + 1]
-                req.tokens.extend(new)
-                del req.tokens[req.max_new_tokens:]
-                if self._finished(req, req.tokens[-1]):
-                    self._retire(req)
-                    self._clear_slot(i)
             self.block_passes += 1
             self.block_commits += int(commits > 0)
             self.blocks_done += commits
+            self.block_settles_joined += joined
             if obs_on:
                 dur_ms = (t_now - step.t0) * 1e3
                 _obs_metrics.record_serve("tokens", delta=filled)
@@ -1891,11 +1934,13 @@ class ServingEngine:
                 _obs_metrics.record_serve("blocks_done", delta=commits)
                 _obs_metrics.record_serve("block_slot_passes", delta=len(kept))
                 _obs_metrics.record_serve("block_slot_commits", delta=commits)
+                _obs_metrics.record_serve("block_settles_joined", delta=joined)
                 if step.counted is not None:
                     for name, n in zip(ROUTING_COUNTERS, np.asarray(step.counted)):
                         _obs_metrics.record_serve(name, delta=int(n))
-                if len(kept) < len(step.reqs):
-                    _obs_metrics.record_serve("decode_discarded", delta=len(step.reqs) - len(kept))
+                discarded = len(step.reqs) - len(kept) + cut
+                if discarded:
+                    _obs_metrics.record_serve("decode_discarded", delta=discarded)
                 _obs_flight.record_step(dur_ms, fn="serve_decode", active=len(step.reqs),
                                         unmasked=filled)
                 _obs_tel.observe("serve.decode_ms", dur_ms)
