@@ -10,8 +10,9 @@ import numpy as np
 import pytest
 
 import thunder_tpu as tt
-from benchmark.lib import manifest
+from benchmark.lib import harness, manifest
 from thunder_tpu import observability
+from thunder_tpu.executors import pallasex
 from thunder_tpu.models import litgpt
 from thunder_tpu.models.block_moe import tiny_block_moe
 from thunder_tpu.serving import ServingEngine
@@ -175,14 +176,23 @@ ROADS = [(4, 8, "low_confidence_static", 0.9), (4, 8, "low_confidence_dynamic", 
          (4, 8, "low_confidence_dynamic", 0.0)]
 
 
-@pytest.fixture(scope="module", params=ROADS, ids=lambda r: f"K{r[0]}-{r[2][15:]}-{r[3]}")
+# the last road with the Pallas kernels claimed and interpreted: the page writer, the paged chunk
+# kernel and the ragged expert kernel in the block program
+SPIED = [r + (False,) for r in ROADS] + [(4, 8, "low_confidence_dynamic", 0.01, True)]
+
+
+@pytest.fixture(scope="module", params=SPIED,
+                ids=lambda r: f"K{r[0]}-{r[2][15:]}-{r[3]}" + ("-kernels" if r[4] else ""))
 def spied(request):
     """One request served alone with every run of the block program noted: what it was given
     (the two blocks, the first's position, which are live), the logits it returned and the
     last layer's key pool before and after."""
-    K_, ps, strategy, threshold = request.param
+    K_, ps, strategy, threshold, kernels = request.param
     gpt = seeded(tiny_block_moe(head_size=32), 1)
     config = config_of(gpt, block_length=K_, strategy=strategy, threshold=threshold)
+    claims = pytest.MonkeyPatch()
+    if kernels:
+        claims.setattr(pallasex, "_claims_on_platform", lambda: True)
     engine = engine_of(gpt, block_length=K_, page_size=ps, chunk_tokens=24, max_seq=96,
                        strategy=strategy, threshold=threshold)
     engine.record_block_states = True
@@ -197,13 +207,25 @@ def spied(request):
 
     engine.runner.block_cfn = block_cfn
     p = prompt(np.random.default_rng(11), 14)
-    fut = engine.submit(p, max_new_tokens=9)
-    engine.drain()
-    res = fut.result()
+    try:
+        fut = engine.submit(p, max_new_tokens=9)
+        engine.drain()
+        res = fut.result()
+    finally:
+        claims.undo()
     # the sequence as it was settled: the prompt's whole blocks, then every settled block
     seq = list(p[:len(p) // K_ * K_]) + [int(t) for _, toks, m in res.block_states if not m.any() for t in toks]
     return {"K": K_, "ps": ps, "engine": engine, "config": config, "params": params_of(gpt), "prompt": p,
-            "res": res, "calls": calls, "seq": seq, "last": last}
+            "res": res, "calls": calls, "seq": seq, "last": last, "kernels": kernels,
+            "claims": harness.pallas_claims(tt.last_traces(inner._cfn)[-1])}
+
+
+def test_the_block_program_writes_its_rows_through_one_writer_a_layer(spied):
+    """Where the kernels claim, the page writer takes the block program's 2K rows a slot, one call
+    a layer beside the chunk kernel and the ragged kernel; elsewhere the row scatter does."""
+    n = spied["engine"].cfg.n_layer
+    want = {"thunder.paged_write": n, "thunder.paged_chunk_attention": n, "thunder.ragged_mlp": n}
+    assert spied["claims"] == (want if spied["kernels"] else {})
 
 
 def test_a_pass_that_settles_a_block_and_denoises_the_next_equals_the_reference_on_both(spied):

@@ -1469,7 +1469,8 @@ def test_nothing_is_built_when_a_chunk_first_meets_live_rows(gpt, rng):
 
 def test_the_chunk_program_takes_each_kind_of_row_through_its_own_attention(gpt, rng):
     """One `paged_chunk_attention` and one `paged_attention` a layer in the chunk program's
-    trace (never a second chunk call for the decode rows), the decode program's as it was; an
+    trace (never a second chunk call for the decode rows), the decode rows written by one
+    `paged_write` a layer as in the decode program (the chunk's by whole pages); an
     engine with a draft model keeps the chunk program without decode rows and never mixes."""
     def attends(cfn):
         from collections import Counter
@@ -1487,8 +1488,9 @@ def test_the_chunk_program_takes_each_kind_of_row_through_its_own_attention(gpt,
     engine = _engine(gpt, chunk_tokens=16)
     got, counters = _bus_counters(lambda: serve(engine))
     assert counters["serve.decode_mixed"] == 3
-    assert attends(engine.runner.chunk_cfn) == {"paged_chunk_attention": n, "paged_attention": n}
-    assert attends(engine.runner.decode_cfn) == {"paged_attention": n}
+    assert attends(engine.runner.chunk_cfn) == {"paged_chunk_attention": n, "paged_attention": n,
+                                                "paged_write": n}
+    assert attends(engine.runner.decode_cfn) == {"paged_attention": n, "paged_write": n}
     drafted = _engine(gpt, chunk_tokens=16, draft_gpt=gpt, spec_k=2)
     assert not drafted._mixes and drafted._idle_rows is None
     want, counters = _bus_counters(lambda: serve(drafted))

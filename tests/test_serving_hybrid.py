@@ -19,7 +19,7 @@ from thunder_tpu.models.litgpt import Config as GPTConfig, GPT
 from thunder_tpu.nn.module import functional_params
 from thunder_tpu.ops import ltorch
 from thunder_tpu.serving import ServingEngine
-from thunder_tpu.serving.kv_pages import Recurrent
+from thunder_tpu.serving.kv_pages import PagedKV, Recurrent
 
 pytestmark = pytest.mark.serve
 
@@ -388,9 +388,14 @@ def test_the_shared_pool_exists_once_and_state_follows_the_declarations(engine):
 
 
 def _claims(cfn) -> dict:
-    """What Pallas claimed of the paged symbols in a program's executed trace."""
+    """What Pallas claimed of the paged attention symbols in a program's executed trace."""
     return {k: v for k, v in harness.pallas_claims(tt.last_traces(cfn._cfn)[-1]).items()
-            if "paged" in k}
+            if "paged" in k and k != "thunder.paged_write"}
+
+
+def _writes(cfn) -> int:
+    """The page writer's claims in a program's executed trace."""
+    return harness.pallas_claims(tt.last_traces(cfn._cfn)[-1])["thunder.paged_write"]
 
 
 def test_the_programs_claim_what_the_builders_state(model, pallas_claims):
@@ -403,6 +408,10 @@ def test_the_programs_claim_what_the_builders_state(model, pallas_claims):
     assert _claims(eng.runner.decode_cfn) == want["decode_cfn"] == {"thunder.paged_attention": 4}
     assert _claims(eng.runner.chunk_cfn) == want["chunk_cfn"] == {"thunder.paged_chunk_attention": 4}
     assert _claims(eng.runner.prefill_cfn) == {}
+    # one writer a layer that owns pools, in the decode program only: prompts write whole pages
+    owners = sum(isinstance(layer.cache, PagedKV) for layer in eng.runner.model.layers)
+    assert owners > 0 and _writes(eng.runner.decode_cfn) == owners
+    assert _writes(eng.runner.chunk_cfn) == _writes(eng.runner.prefill_cfn) == 0
 
     cfg = GPTConfig.from_name("tiny-llama2", block_size=64)
     gpt = GPT(cfg, dtype=jnp.float32)
@@ -417,11 +426,15 @@ def test_the_programs_claim_what_the_builders_state(model, pallas_claims):
     assert _claims(dense.runner.chunk_cfn) == dict(stated["chunk_cfn"],
                                                    **{"thunder.paged_attention": cfg.n_layer})
     assert _claims(dense.runner.prefill_cfn) == {}
+    # a writer a layer wherever a program's rows are not whole pages: the decode rows
+    assert _writes(dense.runner.decode_cfn) == _writes(dense.runner.chunk_cfn) == cfg.n_layer
     drafted = ServingEngine(gpt, draft_gpt=GPT(cfg, dtype=jnp.float32), spec_k=2, **spec)
     serve(drafted, prompts([10, 40], seed=9), [5, 5])
     assert _claims(drafted.runner.verify_cfn) == {"thunder.paged_chunk_attention": cfg.n_layer}
+    assert _writes(drafted.runner.verify_cfn) == cfg.n_layer
     # an engine with a draft model keeps the chunk program that takes no decode rows
     assert _claims(drafted.runner.chunk_cfn) == stated["chunk_cfn"]
+    assert _writes(drafted.runner.chunk_cfn) == 0
 
 
 # ---------------------------------------------------------------------------

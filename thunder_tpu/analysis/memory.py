@@ -125,6 +125,37 @@ def paged_pages_per_step(page_size: int, D: int, g: int, kv_itemsize: int, q_ite
     return 0
 
 
+def paged_write_tile_rows(itemsize: int) -> int:
+    """Rows of one aligned tile of a pool's page: what the page writer copies
+    out of HBM and back, since the compiler refuses a copy of fewer rows of a
+    packed type (8 rows of 32 bits, 16 of bf16, 32 of 8 bits)."""
+    return 32 // itemsize
+
+
+def paged_write_vmem_bytes(seqs: int, rows: int, n_kv_heads: int, D: int, Dv: int,
+                           itemsize: int) -> int:
+    """Estimated per-program VMEM working set of the page writer (pallasex
+    `_paged_write_kernel`), which does ``seqs`` sequences of ``rows`` new rows
+    each: two aligned tiles a sequence of every KV head of the key pool and of
+    the value pool, which its own copies fill and empty, and the blocks of new
+    rows Mosaic's pipeline double-buffers ((rows x heads, width) a sequence,
+    padded to whole tiles)."""
+    tr = paged_write_tile_rows(itemsize)
+    tiles = 2 * seqs * n_kv_heads * tr * (D + Dv) * itemsize
+    new = 2 * seqs * (-(-rows * n_kv_heads // tr) * tr) * (D + Dv) * itemsize
+    return tiles + new
+
+
+def paged_write_seqs_per_step(B: int, rows: int, n_kv_heads: int, D: int, Dv: int,
+                              itemsize: int) -> int:
+    """Sequences a program of the page writer does: the most that divide ``B``
+    and fit the budget, so that all their copies are in flight at once; 0 when
+    not one sequence fits, and the checker then declines."""
+    limit = paged_vmem_limit()
+    return next((s for s in range(B, 0, -1) if B % s == 0 and within_vmem(
+        paged_write_vmem_bytes(s, rows, n_kv_heads, D, Dv, itemsize), limit)), 0)
+
+
 # query rows of one KV head (g x the tile) and keys of one loop step the chunk
 # kernel multiplies at once. What a step costs beside its scores (the running
 # maximum, the rescale of the accumulator: columns of f32, a vreg for 8 rows)

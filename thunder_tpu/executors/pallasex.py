@@ -1813,6 +1813,206 @@ ex.register_implementation("thunder.paged_chunk_attention", _paged_chunk_attenti
 
 
 # ---------------------------------------------------------------------------
+# Paged write — a program's new key and value rows into both pools of a layer
+# ---------------------------------------------------------------------------
+#
+# A decode step writes one row a sequence and KV head, a pass over blocks or a
+# speculative verify step a few consecutive ones; XLA lowers that to a scatter
+# whose cost follows the rows, one update a (token, head) row. The compiler
+# refuses a copy of a single row of a packed type, so this kernel copies the
+# ALIGNED TILES a sequence's rows fall in (``paged_write_tile_rows``: 16 rows of
+# bf16, every KV head at once) out of HBM, patches the rows in VMEM and copies
+# the tiles back: all reads in flight, then every patch, then all writes. K1
+# consecutive rows of one sequence lie in at most two tiles, which may be on
+# two pages. A row on the null page (an idle slot, the inactive block of a
+# pass, a speculative row past the table) is skipped: the null page is masked
+# everywhere, so what it holds is never read, and no two sequences then share
+# a tile (a live sequence never writes a shared page: copy-on-write,
+# serving/kv_pages.py). The pools are aliased to the outputs and stay in HBM.
+
+
+def _paged_write_kernel(pg_ref, sl_ref, kr_ref, vr_ref, k_in, v_in, k_out, v_out,
+                        k_buf, v_buf, sems, *, rows: int):
+    # grid (B // seqs,): a program does `seqs` sequences of `rows` rows. pg_ref
+    # and sl_ref (B * rows,) hold the page and slot of row r of sequence b at
+    # b * rows + r; kr_ref (seqs, rows * Hkv, D) and vr_ref (.., Dv) the new
+    # rows, row r of head h at r * Hkv + h. k_in / v_in and k_out / v_out are
+    # the same pools (aliased), in HBM. k_buf (seqs, 2, Hkv, tile rows, D) and
+    # v_buf (.., Dv) hold a sequence's two tiles; sems (2,) is [reads, writes].
+    seqs, _, Hkv, tr = k_buf.shape[:4]
+    first_seq = pl.program_id(0) * seqs
+
+    def tiles(s):
+        """Sequence s's rows: the index of its first into the tables, whether
+        one is live, the tiles (page, first slot) of its first and its last
+        live row, and whether those are two."""
+        base = (first_seq + s) * rows
+        page_a = slot_a = page_z = slot_z = jnp.int32(0)
+        found = jnp.bool_(False)
+        for r in range(rows):
+            page, slot = pg_ref[base + r], sl_ref[base + r]
+            live = page != 0
+            first = live & jnp.logical_not(found)
+            page_a, slot_a = jnp.where(first, page, page_a), jnp.where(first, slot, slot_a)
+            page_z, slot_z = jnp.where(live, page, page_z), jnp.where(live, slot, slot_z)
+            found = found | live
+        a, z = (page_a, slot_a // tr * tr), (page_z, slot_z // tr * tr)
+        return base, found, (a, z), (a[0] != z[0]) | (a[1] != z[1])
+
+    def each(fn):
+        """fn(s, t, page, first slot, base) for every tile t a sequence s writes."""
+        def body(s, carry):
+            base, found, ends, two = tiles(s)
+            for t, (page, t0), cond in ((0, ends[0], found), (1, ends[1], found & two)):
+                pl.when(cond)(functools.partial(fn, s, t, page, t0, base))
+            return carry
+
+        jax.lax.fori_loop(0, seqs, body, 0)
+
+    def copies(s, t, page, t0, write: bool):
+        at = (page, slice(None), pl.ds(pl.multiple_of(t0, tr), tr), slice(None))
+        if write:
+            return [pltpu.make_async_copy(buf.at[s, t], pool.at[at], sems.at[1])
+                    for buf, pool in ((k_buf, k_out), (v_buf, v_out))]
+        return [pltpu.make_async_copy(pool.at[at], buf.at[s, t], sems.at[0])
+                for buf, pool in ((k_buf, k_in), (v_buf, v_in))]
+
+    def start(write):
+        def run(s, t, page, t0, base):
+            for c in copies(s, t, page, t0, write):
+                c.start()
+        return run
+
+    def wait(write):
+        def run(s, t, page, t0, base):
+            for c in copies(s, t, page, t0, write):
+                c.wait()
+        return run
+
+    def patch(s, t, page, t0, base):
+        # row r goes to sublane slot - t0 of this tile where it is on its page
+        # and in its range: a dead row's page is 0, never a tile's
+        where = [(pg_ref[base + r] == page) & (sl_ref[base + r] >= t0) & (sl_ref[base + r] < t0 + tr)
+                 for r in range(rows)]
+        offset = [sl_ref[base + r] - t0 for r in range(rows)]
+        for buf, new in ((k_buf, kr_ref), (v_buf, vr_ref)):
+            at_row = jax.lax.broadcasted_iota(jnp.int32, buf.shape[3:], 0)
+            for h in range(Hkv):
+                tile = buf[s, t, h]
+                for r in range(rows):
+                    row = jnp.broadcast_to(new[s, pl.ds(r * Hkv + h, 1), :], tile.shape)
+                    tile = jnp.where(where[r] & (at_row == offset[r]), row, tile)
+                buf[s, t, h] = tile
+
+    each(start(False))
+    each(wait(False))
+    each(patch)
+    each(start(True))
+    each(wait(True))
+
+
+_PAGED_WRITE_REFUSALS = {
+    "lanes": _PAGED_REFUSALS["lanes"],
+    "rows": "a sequence's rows span more than two tiles of the pool (more rows than one tile holds)",
+    "vmem": "not one sequence's two tiles and rows fit the VMEM budget (analysis.budget.paged_vmem_limit)",
+}
+
+
+def _paged_write_blocks(B: int, K1: int, Hkv: int, D: int, Dv: int, itemsize: int):
+    """(rows of a tile, sequences a program) of the page writer: 0 sequences
+    when not one fits the budget."""
+    from ..analysis import budget as _budget
+
+    return (_budget.paged_write_tile_rows(itemsize),
+            _budget.paged_write_seqs_per_step(B, K1, Hkv, D, Dv, itemsize))
+
+
+def _paged_write_refusal(K1: int, D: int, Dv: int, tile_rows: int, seqs: int, compiled: bool):
+    """Why the page writer cannot take these rows and pools, or None: ``"lanes"``
+    when it is to be compiled and a pool's rows do not fill the 128 lanes (as
+    the paged attention kernels), ``"rows"`` when a sequence's rows may span more
+    than two tiles, ``"vmem"`` when not one sequence a program fits."""
+    if compiled and (D % 128 or Dv % 128):
+        return "lanes"
+    if K1 > tile_rows:
+        return "rows"
+    return None if seqs else "vmem"
+
+
+def paged_write_rows(k_pool, v_pool, page_of, slot_in_page, k_rows, v_rows, *,
+                     interpret: bool | None = None):
+    """k_rows (B, K1, Hkv, D) and v_rows (B, K1, Hkv, Dv) into the pools (P,
+    Hkv, page_size, D / Dv) at page_of / slot_in_page (B, K1) int32 ->
+    (k_pool, v_pool), written in place (the pools are aliased to the outputs).
+    A sequence's live rows are consecutive positions; rows on page 0 are not
+    written."""
+    B, K1, Hkv, D = k_rows.shape
+    Dv = v_rows.shape[3]
+    interpret = _interpret() if interpret is None else interpret
+    tr, seqs = _paged_write_blocks(B, K1, Hkv, D, Dv, k_pool.dtype.itemsize)
+    refusal = _paged_write_refusal(K1, D, Dv, tr, seqs, not interpret)
+    if refusal and not (interpret and refusal == "vmem"):  # the interpreter has no VMEM
+        raise ValueError(f"paged_write_rows cannot take {K1} rows a sequence into pools "
+                         f"{tuple(k_pool.shape)} and {tuple(v_pool.shape)}: {_PAGED_WRITE_REFUSALS[refusal]}")
+    seqs = seqs or B
+
+    def new(width):
+        return pl.BlockSpec((seqs, K1 * Hkv, width), lambda i, *_: (i, 0, 0))
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B // seqs,),
+        in_specs=[new(D), new(Dv), hbm, hbm],
+        out_specs=[hbm, hbm],
+        scratch_shapes=[pltpu.VMEM((seqs, 2, Hkv, tr, D), k_pool.dtype),
+                        pltpu.VMEM((seqs, 2, Hkv, tr, Dv), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    return tuple(pl.pallas_call(
+        functools.partial(_paged_write_kernel, rows=K1),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+    )(page_of.reshape(B * K1).astype(jnp.int32), slot_in_page.reshape(B * K1).astype(jnp.int32),
+      k_rows.reshape(B, K1 * Hkv, D), v_rows.reshape(B, K1 * Hkv, Dv), k_pool, v_pool))
+
+
+def paged_write_supported(k_pool, v_pool, page_of, slot_in_page, k_rows, v_rows) -> bool:
+    """Checker for thunder.paged_write: claims on the chip (`_claims_on_platform`)
+    where pools and rows agree (one dtype, whole tiles a page), the pools' rows
+    fill the lanes, a sequence's rows fit two tiles and one sequence a program
+    fits the VMEM budget; otherwise the row scatter of the decomposition runs."""
+    if not _claims_on_platform():
+        return False
+    ndims = ((k_pool, 4), (v_pool, 4), (page_of, 2), (slot_in_page, 2), (k_rows, 4), (v_rows, 4))
+    if any(getattr(t, "ndim", 0) != n for t, n in ndims):
+        return False
+    P, Hkv, ps, D = k_pool.shape
+    B, K1 = page_of.shape
+    Dv = v_pool.shape[3]
+    if not (tuple(v_pool.shape[:3]) == (P, Hkv, ps) and tuple(slot_in_page.shape) == (B, K1)
+            and tuple(k_rows.shape) == (B, K1, Hkv, D) and tuple(v_rows.shape) == (B, K1, Hkv, Dv)
+            and len({str(t.dtype) for t in (k_pool, v_pool, k_rows, v_rows)}) == 1):
+        return False
+    tr, seqs = _paged_write_blocks(B, K1, Hkv, D, Dv,
+                                   jnp.dtype(str(k_pool.dtype).rpartition(".")[2]).itemsize)
+    if ps % tr:  # a page of whole tiles
+        return False
+    refusal = _paged_write_refusal(K1, D, Dv, tr, seqs, _on_tpu())
+    return _decline("paged_write", refusal) if refusal else True
+
+
+def _paged_write_impl(k_pool, v_pool, page_of, slot_in_page, k_rows, v_rows):
+    return paged_write_rows(k_pool, v_pool, page_of, slot_in_page, k_rows, v_rows)
+
+
+ex.register_implementation("thunder.paged_write", _paged_write_impl, checker=paged_write_supported)
+
+
+# ---------------------------------------------------------------------------
 # Paged attention — decode over ONE latent pool (multi-head latent attention)
 # ---------------------------------------------------------------------------
 #

@@ -352,9 +352,8 @@ class DiffAttention(nn.Module):
             kind = "full"
         else:
             kind = self.cache.kind
-            page, slot = step.page_of[kind], step.slot_in_page
-            state = (_write_tokens(state[0], page, slot, k[:, :, 0]),
-                     _write_tokens(state[1], page, slot, v[:, :, 0]))
+            state = _write_tokens(state, step.page_of[kind], step.slot_in_page,
+                                  ltorch.permute(k, (0, 2, 1, 3)), ltorch.permute(v, (0, 2, 1, 3)))
         kp, vp = self._pools(step, state)
         y = ltorch.paged_attention(q[:, :, 0], kp, vp, step.tables[kind], step.seq_lens,
                                    scale=self.scale, window=self.window)

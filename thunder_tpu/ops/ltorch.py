@@ -1030,6 +1030,48 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, q_pos, scale=None, wi
     return prims.matmul(probs, v)  # (B, H, T, Dv)
 
 
+def _write_rows(pool, page, slot, rows):
+    """pool[page[n], :, slot[n]] = rows[n] on a head-major pool (P, Hkv, ps,
+    D): page/slot (N,), rows (N, Hkv, D). One joint index_put over (page,
+    head, slot) rows of D."""
+    N, Hkv, D = rows.shape
+
+    def spread(v, shape):  # v, viewed as `shape`, spread over the (N, Hkv) rows
+        return reshape(expand(reshape(v, shape), (N, Hkv)), (N * Hkv,))
+
+    heads = prims.iota(Hkv, dtype=dtypes.int32, device=rows.device)
+    return index_put(pool, (spread(page, (N, 1)), spread(heads, (1, Hkv)), spread(slot, (N, 1))),
+                     reshape(rows, (N * Hkv, D)))
+
+
+@torchsymbol(name="paged_write", id="thunder.paged_write")
+def paged_write(k_pool, v_pool, page_of, slot_in_page, k_rows, v_rows):
+    """A program's new key and value rows into both pools of a layer.
+
+    k_pool       (P, Hkv, page_size, D), v_pool (P, Hkv, page_size, Dv) — the
+                 layer's head-major pools (serving/kv_pages.py)
+    page_of      (B, K1) int — the page each row lands in; the null page 0 for
+                 a row that is no sequence's (an idle slot, the inactive block
+                 of a pass, a speculative row past the table)
+    slot_in_page (B, K1) int — its slot there
+    k_rows       (B, K1, Hkv, D), v_rows (B, K1, Hkv, Dv) — the rows: K1
+                 consecutive positions of sequence b
+
+    Returns (k_pool, v_pool) with every row at its page and slot. What lands on
+    the null page is unspecified: it is masked everywhere. The decomposition is
+    one row scatter a pool (CPU and shapes the kernel declines); on the chip the
+    pallas executor claims it with a writer that copies the aligned tiles a
+    sequence's rows fall in out of HBM, patches them and copies them back
+    (executors/pallasex.py: paged_write_rows)."""
+    B, K1, Hkv, D = k_rows.shape
+    check(tuple(page_of.shape) == (B, K1) and tuple(slot_in_page.shape) == (B, K1),
+          lambda: f"paged_write: page_of {page_of.shape} and slot_in_page {slot_in_page.shape} "
+                  f"must be (B, K1)=({B}, {K1})")
+    page, slot = reshape(page_of, (B * K1,)), reshape(slot_in_page, (B * K1,))
+    return (_write_rows(k_pool, page, slot, reshape(k_rows, (B * K1, Hkv, D))),
+            _write_rows(v_pool, page, slot, reshape(v_rows, (B * K1, Hkv, v_rows.shape[-1]))))
+
+
 @torchsymbol(name="paged_latent_attention", id="thunder.paged_latent_attention")
 def paged_latent_attention(q, pool, page_table, q_pos, scale, v_width):
     """Attention against a paged LATENT pool (multi-head latent attention in

@@ -14,7 +14,8 @@ Two compiled programs:
   keeps the steady-state MRU bookkeeping.
 * decode — ONE program for the whole engine: every active sequence
   contributes one token; k/v land in the pool at (page_table[pos//ps],
-  pos%ps) via a batched index_put and attention runs over the pages
+  pos%ps) through ltorch.paged_write (a pallas writer of the aligned tiles on
+  TPU, a row scatter on CPU) and attention runs over the pages
   (ltorch.paged_attention — pallas kernel on TPU, jax gather on CPU).
 * chunk_prefill — one CHUNK of a long (or prefix-shared) prompt: page-
   aligned writes starting at an arbitrary page boundary `start_pos`, with
@@ -158,23 +159,15 @@ def _write_pages(pool, page_ids, rows, ps: int):
         return ltorch.index_put(pool, (page_ids,), _page_blocks(rows, ps))
 
 
-def _write_tokens(pool, page, slot, tok):
-    """pool[page[n], :, slot[n]] = tok[n] on a head-major pool
-    (P, Hkv, ps, D): page/slot (N,) int32, tok (N, Hkv, D). One joint
-    index_put over (page, head, slot) rows of D."""
-    from ..core import dtypes, prims
-
-    N, Hkv, D = tok.shape
-
-    def rows(v, shape):  # v, viewed as `shape`, spread over the (N, Hkv) rows
-        return ltorch.reshape(ltorch.expand(ltorch.reshape(v, shape), (N, Hkv)),
-                              (N * Hkv,))
-
+def _write_tokens(state, page, slot, k_tok, v_tok):
+    """Both pools of a layer, ``state`` (keys, values) (P, Hkv, ps, D), with a
+    program's new rows k_tok / v_tok (B, K1, Hkv, D) at page / slot ((B,) or
+    (B * K1,): the ``Step``'s ``page_of`` and ``slot_in_page``):
+    ``ltorch.paged_write``, a Pallas writer on the chip."""
+    B, K1 = k_tok.shape[:2]
     with named_scope("kv_write"):
-        heads = prims.iota(Hkv, dtype=dtypes.int32, device=tok.device)
-        return ltorch.index_put(
-            pool, (rows(page, (N, 1)), rows(heads, (1, Hkv)), rows(slot, (N, 1))),
-            ltorch.reshape(tok, (N * Hkv, D)))
+        return ltorch.paged_write(state[0], state[1], ltorch.reshape(page, (B, K1)),
+                                  ltorch.reshape(slot, (B, K1)), k_tok, v_tok)
 
 
 def quantize_for_serving(gpt, mode: Optional[str]):
@@ -310,10 +303,8 @@ class DenseBlock:
 
     def _tokens(self, k, v):
         """The pool rows of k and v (B, n_query_groups, T, hs), a token each:
-        two (B * T, heads, width)."""
-        return tuple(ltorch.reshape(ltorch.permute(rows, (0, 2, 1, 3)),
-                                    (-1, self.cache.heads, self.cache.k_dim))
-                     for rows in self._rows(k, v))
+        two (B, T, heads, width)."""
+        return tuple(ltorch.permute(rows, (0, 2, 1, 3)) for rows in self._rows(k, v))
 
     def _paged(self, attend, q, kp, vp, table, where):
         """``attend`` (a paged attention of ops/ltorch.py) for q (B, n_head, T,
@@ -359,9 +350,7 @@ class DenseBlock:
         """One token a sequence, q (B, n_head, 1, hs), k and v (B, n_query_groups, 1,
         hs): each token's k/v to its page and slot, then attention over the
         sequence's pages. Returns (y (B, n_head, 1, hs), new state)."""
-        k_tok, v_tok = self._tokens(k, v)
-        kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
-        vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
+        kp, vp = _write_tokens(state, step.page_of["full"], step.slot_in_page, *self._tokens(k, v))
 
         def attend(q4, kp, vp, table, seq_lens, scale):  # one query a sequence: (B, H, D)
             B, H, _, D = q4.shape
@@ -432,9 +421,8 @@ class DenseBlock:
         method."""
         with named_scope("attn"):
             q, k, v = self._qkv(step, x)
-            k_tok, v_tok = self._tokens(k, v)
-            kp = _write_tokens(state[0], step.page_of["full"], step.slot_in_page, k_tok)
-            vp = _write_tokens(state[1], step.page_of["full"], step.slot_in_page, v_tok)
+            kp, vp = _write_tokens(state, step.page_of["full"], step.slot_in_page,
+                                   *self._tokens(k, v))
             y = self._paged(ltorch.paged_chunk_attention, q, kp, vp, step.tables["full"],
                             step.mask_pos)
             h = self._proj(x, y, x.shape[1])
