@@ -6,7 +6,7 @@ One process, one TPU, the entry points a user calls, at the full width and
 depth of llama-350m with random weights from a seed:
 
 * trainer — ``tt.jit(GPTForCausalLM, [AutocastTransform])`` + ``TrainStep``
-  with AdamW at B=4, T=2048 (what ``bench.py`` builds): a forward-only loss,
+  with AdamW at B=4, T=2048: a forward-only loss,
   then a handful of steps on one fixed batch;
 * meshes — only where the host has four chips: the same step through
   ``fsdp`` on ``{"fsdp": 4}`` and ``ddp`` x ``fsdp`` on ``{"dp": 2, "fsdp": 2}``,

@@ -65,7 +65,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "telemetry: live-telemetry test (streaming percentiles, metrics "
-        "exporter, SLO monitors, perf gate; filter with -m telemetry / "
+        "exporter, SLO monitors; filter with -m telemetry / "
         "-m 'not telemetry')")
     config.addinivalue_line(
         "markers",
@@ -86,8 +86,7 @@ def pytest_configure(config):
         "markers",
         "perf: performance-lever correctness test (overlap cache keys, "
         "bucketed grad-sync bit-identity, fused fp8 kernel parity, int8 "
-        "decode token-identity, committed-artifact schema gates; filter "
-        "with -m perf / -m 'not perf')")
+        "decode token-identity; filter with -m perf / -m 'not perf')")
     config.addinivalue_line(
         "markers",
         "moe: mixture-of-experts test (grouped-dispatch bit-identity, "

@@ -664,47 +664,3 @@ class TestRegionInterfaces:
         bad.bound_symbols = bsyms
         with pytest.raises(TraceCheckError, match="region interface"):
             analysis.verify_trace(bad)
-
-
-# ---------------------------------------------------------------------------
-# perf gate learns the estimator key
-# ---------------------------------------------------------------------------
-
-
-class TestPerfGateMemKey:
-    def _gate(self, base, cur):
-        import importlib.util
-        import os
-
-        spec = importlib.util.spec_from_file_location(
-            "perf_gate", os.path.join(os.path.dirname(__file__), "..",
-                                      "tools", "perf_gate.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.run_gate([base], [cur], tolerance=0.10, slack_ms=1.0)
-
-    def test_mem_peak_estimated_regression_gates(self):
-        base = {"metric": "m", "value": 100.0, "mem_peak_estimated": 1.0}
-        worse = {"metric": "m", "value": 100.0, "mem_peak_estimated": 1.5}
-        n_reg, n_checked, _ = self._gate(base, worse)
-        assert n_checked == 1 and n_reg == 1
-
-    def test_mem_peak_estimated_within_band_passes(self):
-        base = {"metric": "m", "value": 100.0, "mem_peak_estimated": 1.0}
-        ok = {"metric": "m", "value": 100.0, "mem_peak_estimated": 1.05}
-        n_reg, n_checked, _ = self._gate(base, ok)
-        assert n_checked == 1 and n_reg == 0
-
-    def test_mem_peak_estimated_missing_gates(self):
-        # a broken estimator (bench omits the key) must fail the gate, not
-        # silently skip the comparison
-        base = {"metric": "m", "value": 100.0, "mem_peak_estimated": 1.0}
-        broken = {"metric": "m", "value": 100.0}
-        n_reg, n_checked, lines = self._gate(base, broken)
-        assert n_checked == 1 and n_reg == 1
-        assert any("MISSING" in ln for ln in lines)
-        # but a key that is legitimately mode-gated (mfu_measured without
-        # BENCH_OBS) still skips quietly
-        base2 = {"metric": "m", "value": 100.0, "mfu_measured": 0.5}
-        n_reg2, _, _ = self._gate(base2, {"metric": "m", "value": 100.0})
-        assert n_reg2 == 0

@@ -523,22 +523,3 @@ class TestCacheInspect:
         out = obs_summary.render(recs)
         assert "== compile ==" in out
 
-
-class TestPerfGateCompileKeys:
-    def test_bench_compile_artifact_gates(self):
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        import perf_gate
-
-        assert perf_gate._direction("compile_time_warm_s") == "down"
-        assert perf_gate._direction("warm_over_cold") == "down"
-        assert perf_gate._direction("artifact_hits_warm") == "up"
-        assert perf_gate._direction("compile_time_cold_s") is None  # informational
-        path = os.path.join(REPO, "BENCH_COMPILE.json")
-        assert os.path.exists(path), "committed compile-ladder artifact missing"
-        rows = perf_gate.load_rows(path)
-        assert rows and all("compile_time_warm_s" in r for r in rows)
-        # the acceptance ladder: warm well under cold on at least one config
-        assert any(r.get("warm_over_cold") is not None
-                   and r["warm_over_cold"] <= 0.25 for r in rows)
-        # self-compare smoke exercises the gate machinery end to end
-        assert perf_gate.main(["--check", path]) == 0

@@ -99,25 +99,6 @@ def test_quickstart_runs_with_trace_checking(script):
         f"{script} under TT_CHECK_TRACES=1 failed:\n{out.stderr[-1500:]}")
 
 
-@pytest.mark.perf
-@pytest.mark.parametrize("artifact", ["BENCH_MFU.json", "BENCH_FP8.json",
-                                      "BENCH_MOE.json", "BENCH_LONGCTX.json"])
-def test_perf_gate_checks_committed_artifacts(artifact):
-    """The committed MFU/fp8 rows stay loadable and gateable: perf_gate
-    --check self-compares the artifact (exercising the parse + compare
-    path the regression gate uses), so a schema drift in bench.py's
-    writers rots loudly here instead of silently ungating CI."""
-    path = os.path.join(REPO, artifact)
-    assert os.path.exists(path), f"{artifact} is a committed artifact"
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "perf_gate.py"),
-         "--check", path],
-        capture_output=True, text=True, timeout=120, cwd=REPO)
-    assert out.returncode == 0, (
-        f"perf_gate --check {artifact} failed:\n{out.stdout}\n{out.stderr}")
-    assert "perf gate: ok" in out.stdout
-
-
 def test_chip_smoke_refuses_without_a_tpu(tmp_path):
     """chip_smoke.py's contract off the chip: with no TPU it refuses to run —
     non-zero exit, the platform it found named, no result line — also from a

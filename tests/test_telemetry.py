@@ -1,17 +1,12 @@
 """Live telemetry (ISSUE 11): streaming percentiles, observability.snapshot(),
-the Prometheus exporter, SLO monitors with reason-coded breaches, and the
-perf regression gate.
+the Prometheus exporter and SLO monitors with reason-coded breaches.
 
 Acceptance pins: online p50/p90/p99 from the streaming histograms agree with
 tools/obs_summary.py's offline percentiles on the SAME run within estimator
 tolerance; a deterministic CPU serving run driven past a configured SLO
-emits a reason-coded slo.breach event with a goodput gauge < 1.0; and
-tools/perf_gate.py exits non-zero on an injected regression (and 0 on the
-committed artifacts — the smoke invocation that exercises the gate on every
-tier-1 run).
+emits a reason-coded slo.breach event with a goodput gauge < 1.0.
 """
 import importlib.util
-import json
 import os
 import re
 import time
@@ -609,81 +604,6 @@ class TestExporter:
         assert p.returncode == 0, p.stderr
         assert "imported None" in p.stdout
         assert "exporter failed to start" in p.stderr
-
-
-# ---------------------------------------------------------------------------
-# perf regression gate
-# ---------------------------------------------------------------------------
-
-
-class TestPerfGate:
-    def test_smoke_check_committed_serving_artifact(self, capsys):
-        """The tier-1 smoke invocation: the gate must accept the committed
-        BENCH_SERVE.json against itself (exercising load + compare)."""
-        gate = _load_tool("perf_gate")
-        rc = gate.main(["--check", os.path.join(REPO, "BENCH_SERVE.json")])
-        assert rc == 0
-        assert "perf gate: ok" in capsys.readouterr().out
-
-    def test_smoke_check_committed_jsonl_artifact(self, capsys):
-        gate = _load_tool("perf_gate")
-        rc = gate.main(["--check", os.path.join(REPO, "BENCH_LATEST.jsonl")])
-        assert rc == 0
-
-    def test_injected_regression_exits_nonzero(self, tmp_path, capsys):
-        """Acceptance: a degraded fresh artifact fails the gate."""
-        gate = _load_tool("perf_gate")
-        base = os.path.join(REPO, "BENCH_SERVE.json")
-        rows = json.load(open(base))
-        # the serving artifact accumulates one row per workload variant
-        # (e.g. bf16 + int8 decode); gate semantics are per-metric, so
-        # mutating the first row exercises them
-        row = dict(rows[0]) if isinstance(rows, list) else rows
-        row["value"] *= 0.5            # throughput collapse
-        row["tbot_ms_p99"] = row["tbot_ms_p99"] * 2 + 10  # latency blowout
-        row["recompiles_steady_state"] = 3                # zero-tolerance key
-        cur = tmp_path / "fresh.json"
-        cur.write_text(json.dumps(row))
-        rc = gate.main(["--baseline", base, "--current", str(cur)])
-        assert rc == 1
-        out = capsys.readouterr().out
-        assert out.count("REGRESSION") == 3
-
-    def test_improvement_and_jitter_pass(self, tmp_path):
-        gate = _load_tool("perf_gate")
-        base = os.path.join(REPO, "BENCH_SERVE.json")
-        rows = json.load(open(base))
-        # the serving artifact accumulates one row per workload variant
-        # (e.g. bf16 + int8 decode); gate semantics are per-metric, so
-        # mutating the first row exercises them
-        row = dict(rows[0]) if isinstance(rows, list) else rows
-        row["value"] *= 1.5                       # improvement
-        row["ttft_ms_p99"] *= 1.05                # within the band
-        row["tbot_ms_p50"] += 0.5                 # under the ms slack floor
-        cur = tmp_path / "fresh.json"
-        cur.write_text(json.dumps(row))
-        assert gate.main(["--baseline", base, "--current", str(cur)]) == 0
-
-    def test_missing_and_empty_artifacts_exit_2(self, tmp_path):
-        gate = _load_tool("perf_gate")
-        assert gate.main(["--check", str(tmp_path / "nope.json")]) == 2
-        empty = tmp_path / "empty.json"
-        empty.write_text("")
-        assert gate.main(["--check", str(empty)]) == 2
-
-    def test_unmatched_metric_is_not_gated(self, tmp_path, capsys):
-        gate = _load_tool("perf_gate")
-        base = os.path.join(REPO, "BENCH_SERVE.json")
-        rows = json.load(open(base))
-        # the serving artifact accumulates one row per workload variant
-        # (e.g. bf16 + int8 decode); gate semantics are per-metric, so
-        # mutating the first row exercises them
-        row = dict(rows[0]) if isinstance(rows, list) else rows
-        row["metric"] = "a different benchmark entirely"
-        cur = tmp_path / "fresh.json"
-        cur.write_text(json.dumps(row))
-        rc = gate.main(["--baseline", base, "--current", str(cur)])
-        assert rc == 2  # nothing comparable -> unusable, not a pass
 
 
 # ---------------------------------------------------------------------------

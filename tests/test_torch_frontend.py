@@ -420,9 +420,9 @@ def test_tensor_metadata_methods():
 def test_hf_coverage_harness_subset():
     """The HF coverage harness (reference jit_coverage_hf.py role): fwd+bwd
     parity on two architectures (the full matrix runs via
-    `python -m thunder_tpu.benchmarks.hf_coverage`)."""
+    `python -m thunder_tpu.interop.hf_coverage`)."""
     pytest.importorskip("transformers")
-    from thunder_tpu.benchmarks.hf_coverage import _configs, run_model
+    from thunder_tpu.interop.hf_coverage import _configs, run_model
 
     cfgs = _configs()
     for name in ("qwen2", "bert"):

@@ -656,7 +656,7 @@ def __getattr__(name):
     import importlib
 
     if name in ("nn", "optim", "models", "parallel", "training", "inference",
-                "transforms", "utils", "benchmarks", "recipes", "plugins", "frontend",
+                "transforms", "utils", "recipes", "plugins", "frontend",
                 "robustness", "data", "compile_service", "serving"):
         return importlib.import_module(f".{name}", __name__)
     raise AttributeError(f"module 'thunder_tpu' has no attribute '{name}'")

@@ -32,7 +32,7 @@ Concurrency contract:
   keeps the most recently used K entries (last-K by manifest/access time).
 
 The store keeps plain process-local counters (hits/misses/evicts/
-publishes) unconditionally — cheap ints, readable by bench.py without the
+publishes) unconditionally — cheap ints, readable by callers without the
 observability bus — and ALSO records ``artifact.*`` counters plus
 ``compile_artifact_hit/miss/evict`` events when the bus is enabled.
 """
@@ -155,7 +155,7 @@ class ArtifactStore:
     def __init__(self, root: str):
         self.root = os.path.abspath(root)
         self._lock = threading.Lock()
-        # process-local traffic counters, kept unconditionally (bench.py and
+        # process-local traffic counters, kept unconditionally (callers and
         # tests read them without enabling the bus)
         self.hits = 0
         self.misses = 0

@@ -217,7 +217,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                             block_k: int, causal: bool, scale: float,
                             g: int, n_i: int):
-    """Single-pass backward (PROFILE_350M.md lever 2): grid (B, Hkv, T//block_q)
+    """Single-pass backward: grid (B, Hkv, T//block_q)
     with k/v full-T resident; each program computes s/p ONCE per (i, j) tile
     and emits BOTH its dq tile (written per program) and the dk/dv
     contributions (f32 VMEM scratch accumulated across the i axis, written at
@@ -1000,7 +1000,7 @@ ex.register_implementation("quant.linear_int8", _int8_linear_impl,
 # quantize(x), quantize(w), the fp8 dot, and a separate abs-max reduction
 # over each operand for the history roll — each streaming the operand
 # through HBM again. The profiler tags the quantize/amax passes memory-bound
-# (BENCH_FP8: the fp8 road measured 0.83x bf16 at 7B-shape width, i.e. the
+# (the fp8 road once measured 0.83x bf16 at 7B-shape width, i.e. the
 # scaling overhead ATE the matmul win). This kernel folds all of it into the
 # matmul's VMEM pass: each (block_m, block_k) x block and (block_n, block_k)
 # w block is cast to f32 once, clipped/scaled to e4m3, max-reduced into the

@@ -7,7 +7,7 @@ torch eager, and reports per-model status plus which torch ops fell back to
 the host-eager path (the coverage signal: a fallback is correct but slow).
 
 Usage:
-    python -m thunder_tpu.benchmarks.hf_coverage [--models gpt2,llama,...]
+    python -m thunder_tpu.interop.hf_coverage [--models gpt2,llama,...]
     # writes the report table to --out (default HF_COVERAGE.md: a generated
     # file, not committed)
 """

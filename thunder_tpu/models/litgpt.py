@@ -113,8 +113,7 @@ configs: dict[str, dict] = {
     ),
     # Llama-2-7B at full width (4096 / head_dim 128 / MLP 11008 / vocab 32k)
     # truncated to 4 blocks: the deepest 7B-dims stack whose AdamW f32 state
-    # fits one 16 GB chip — per-layer compute is EXACTLY the 7B model's, so
-    # its MFU is the honest single-chip 7B-shape number (BENCH_7B.json)
+    # fits one 16 GB chip — per-layer compute is EXACTLY the 7B model's
     "llama-7b-block4": dict(
         name="llama-7b-block4", block_size=4096, vocab_size=32000, padded_vocab_size=32000,
         n_layer=4, n_head=32, n_embd=4096, intermediate_size=11008,

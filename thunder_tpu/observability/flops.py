@@ -13,7 +13,7 @@ The model is cross-checkable against XLA's own numbers: ``xla_cost`` reads
 this for a lone matmul).
 
 Conventions: FLOPs count multiply-accumulate as 2 ops (matching XLA's
-cost_analysis and the 6N training-step accounting in bench.py); bytes are
+cost_analysis and the 6N training-step accounting); bytes are
 the HBM-visible traffic — every input read once plus every output written
 once (fusion means intermediates stay in registers/VMEM, so a REGION's
 bytes are its fused interface, not the sum of its members').
@@ -23,7 +23,7 @@ from __future__ import annotations
 from typing import Iterable, Optional
 
 # (bf16 MXU peak TFLOP/s, HBM GB/s) by ``jax.devices()[0].device_kind``: the
-# one peaks table (bench.py and benchmarks/litgpt_bench.py read it). Source:
+# package's peaks table (the profiler's roofline tags read it). Source:
 # Google Cloud TPU documentation, per-chip figures; only the v5e kind string
 # has been seen on a chip from this repo. The "cpu" row is nominal — it keeps
 # roofline tags defined in the CPU tests and is no measured peak.
@@ -342,7 +342,7 @@ def roofline_tag(flops: float, nbytes: int, *, category: str = "compute",
 def measured_mfu(flops: float, device_us: float,
                  peak_tflops: Optional[float] = None) -> Optional[float]:
     """Model FLOPs / (measured device seconds × peak) — the measured
-    counterpart of bench.py's analytic `mfu` (docs/performance.md)."""
+    counterpart of the analytic 6N-FLOPs MFU (docs/performance.md)."""
     if not device_us or device_us <= 0:
         return None
     if peak_tflops is None:

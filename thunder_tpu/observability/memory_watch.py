@@ -9,9 +9,8 @@ against reality and an OOM stops being an opaque RESOURCE_EXHAUSTED crash:
   per-step observability touch it is gated behind ONE ``events.enabled()``
   read: disabled, it does no sampling, takes no lock, allocates nothing.
   On backends without device memory introspection (the CPU backend returns
-  ``memory_stats() is None``) it falls back to host RSS so the series —
-  and the bench key ``mem_peak_measured`` — exist everywhere, tagged with
-  their source.
+  ``memory_stats() is None``) it falls back to host RSS so the series
+  exist everywhere, tagged with their source.
 * ``census`` — a ``jax.live_arrays()`` inventory grouped by (shape, dtype),
   top-N by resident bytes. Walking every live buffer is NOT a per-step
   price, so the periodic timeline emission hides behind the deep flag
@@ -184,9 +183,8 @@ def note_estimate(estimate: Optional[dict]) -> None:
 def reconcile(measured_peak_bytes: Optional[float],
               estimated_peak_bytes: Optional[float], *,
               context: str = "bench") -> Optional[float]:
-    """One explicit estimate-vs-measured check (bench rows call this with
-    the device peak next to ``mem_peak_estimated``): returns the
-    measured/estimated ratio, emitting one ``mem.estimate_drift`` event
+    """One explicit estimate-vs-measured check of a device peak against
+    its static estimate: returns the measured/estimated ratio, emitting one ``mem.estimate_drift`` event
     when they diverge beyond ``_DRIFT_RATIO`` in either direction."""
     if not measured_peak_bytes or not estimated_peak_bytes:
         return None

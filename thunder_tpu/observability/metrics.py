@@ -244,7 +244,7 @@ def record_serve(outcome: str, delta: int = 1, event: bool = False, **attrs) -> 
     observability is disabled, like every outcome here): ``prefix_hits`` /
     ``prefix_tokens_saved`` (copy-on-write prefix cache), ``spec_proposed``
     / ``spec_accepted`` (speculative draft tokens offered / verified —
-    their ratio is the accept rate perf_gate.py gates), and the
+    their ratio is the accept rate), and the
     lane-scheduling events ``preempted`` / ``resumed``. ``serve_retired``
     events carry ``lane=`` so obs_summary.py can split latency percentiles
     per lane."""

@@ -47,9 +47,8 @@ from . import events
 
 def percentile(xs, q: float):
     """Nearest-rank percentile over a concrete sample list — THE rank
-    convention shared by the SLO monitors and the bench harnesses, and
-    mirrored by tools/obs_summary.py (kept standalone-stdlib, so its copy
-    is deliberate). StreamingHistogram.quantile matches it within alpha —
+    convention of the SLO monitors, mirrored by tools/obs_summary.py (kept
+    standalone-stdlib, so its copy is deliberate). StreamingHistogram.quantile matches it within alpha —
     the documented online/offline agreement depends on one convention."""
     if not xs:
         return 0.0

@@ -29,10 +29,9 @@ Chrome trace-event JSON (load in chrome://tracing or Perfetto — "X"
 complete events for phases with a duration, "i" instants otherwise).
 ``tools/obs_summary.py trace <request_id>`` wraps both for the CLI.
 
-``disabled_overhead_us()`` is the perf-gate probe (bench key
-``obs_overhead_us``, tools/perf_gate.py): it times the disabled-path guard
-sequence a serving step pays so the trace-id plumbing can never silently
-grow the hot path.
+``disabled_overhead_us()`` times the disabled-path guard sequence a
+serving step pays, so that the trace-id plumbing can be seen if it ever
+grows the hot path.
 """
 from __future__ import annotations
 
@@ -198,16 +197,13 @@ def write_chrome_trace(path: str, records: list[dict], *,
 def disabled_overhead_us(n: int = 20_000, repeats: int = 5) -> float:
     """Per-step cost, in microseconds, of the observability guards a
     serving decode step pays with the bus in its CURRENT state — run it
-    after ``observability.disable()`` to measure the disabled path (the
-    bench harness does; tools/perf_gate.py gates the resulting
-    ``obs_overhead_us`` key, lower-is-better).
+    after ``observability.disable()`` to measure the disabled path.
 
     One probe iteration touches the same guard sequence a decode iteration
     does: the bus-enabled read, a shared trace_step call, and a
     trace_event call on an untraced request — all of which must exit
     within a few attribute reads. Min-of-repeats over a large n keeps the
-    number stable enough to gate without slack (perf_gate grants the
-    "ms"-key slack floor only to millisecond metrics)."""
+    number stable enough to compare without slack."""
     enabled = _events.enabled
     best = float("inf")
     for _ in range(repeats):

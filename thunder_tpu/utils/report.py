@@ -328,7 +328,7 @@ def profile_summary(fn, *args, steps: int = 3, top: int = 12, trace_dir: Optiona
                     **kwargs) -> dict:
     """Run ``fn`` under jax.profiler and aggregate device time by op bucket.
 
-    The programmatic form of the analysis behind PROFILE_350M.md (reference
+    The programmatic form of a profile's per-step breakdown (reference
     report.py's timing tables): buckets pallas kernels by fusion name,
     groups XLA fusions/copies by kind, and returns ms-per-step numbers —
     enough to name a bottleneck without opening tensorboard.

@@ -1,8 +1,7 @@
 """Pretty-print thunder_tpu observability JSONL timelines.
 
 Reads one or more event-bus exports (TT_OBS_FILE=..., observability.dump(),
-per-process shards, or the bench artifact OBS_TIMELINE.jsonl) and renders
-the views an operator actually wants: the compile-phase span tree with
+or per-process shards) and renders the views an operator actually wants: the compile-phase span tree with
 durations, cache traffic and recompile reasons, step-latency statistics,
 a per-host fleet breakdown (step latency + straggler flags per shard),
 a memory section (watermarks, pressure crossings, estimate drift, OOM
@@ -73,7 +72,7 @@ def load_many(paths: list[str]) -> list[dict]:
 
 def _sid(r: dict, key: str = "span"):
     """Span identity: (pid, span id). Span ids restart at 1 in every
-    process, and a bench artifact concatenates several processes' records —
+    process, and a timeline may concatenate several processes' records —
     pid keeps their trees from colliding (absent pid → one shared bucket)."""
     return (r.get("pid", 0), r.get(key))
 
@@ -591,7 +590,7 @@ def render_perf(recs: list[dict]) -> str:
         out += ["", "== step spikes (flight recorder) ==", *spikes]
     if not out:
         return ("(no device_profile records — capture one with "
-                "observability.profile_steps(...) or BENCH_OBS=1)")
+                "observability.profile_steps(...))")
     return "\n".join(out)
 
 
