@@ -86,6 +86,14 @@ def applies(entry: dict, cell: str) -> bool:
     return "workloads" not in entry or cell in entry["workloads"]
 
 
+def listed(manifest: dict, cell: str) -> tuple:
+    """``(end_to_end, per_layer)``: the entries ``cell`` reports, read off the manifest alone. A
+    per-layer entry is reported where it applies and the metric it moves is reported."""
+    e2e = [m for m in manifest["end_to_end"] if applies(m, cell)]
+    reported = {m["name"] for m in e2e}
+    return e2e, [m for m in manifest["per_layer"] if applies(m, cell) and m["moves"] in reported]
+
+
 def resolve(manifest: dict, cell: str, *, root: str = ROOT, rehearse: bool = False) -> Cell:
     """The cell ``cell`` with its configuration, traffic and metric lists. With
     ``rehearse`` the tiny sizes of each file's ``rehearsal`` block are laid
@@ -101,8 +109,6 @@ def resolve(manifest: dict, cell: str, *, root: str = ROOT, rehearse: bool = Fal
     if rehearse:
         config = merged(config, config.get("rehearsal", {}))
         traffic = merged(traffic, traffic.get("rehearsal", {}))
-    e2e = [m for m in manifest["end_to_end"] if applies(m, cell)]
-    reported = {m["name"] for m in e2e}
-    per_layer = [m for m in manifest["per_layer"] if applies(m, cell) and m["moves"] in reported]
+    e2e, per_layer = listed(manifest, cell)
     return Cell(cell, int(w["chips"]), w["config"], config, w["traffic"], traffic, e2e,
                 per_layer, root)

@@ -8,7 +8,10 @@ overlapped when nothing was in flight: the first after an activation (a prefill'
 comes from the host and lands the step in flight), after a preemption or a failure.
 `serve.decode_discarded` counts the tokens of such steps thrown away at their commit, because the
 sequence had ended in between (`eos_id`, a cancelled request); `serve.tokens` never holds them.
+An engine that generates by diffusion over blocks counts its passes the same way. A traced serve
+run logs the share (`bench: decode overlapped % ...`).
 """
+from .harness import say
 
 
 def overlapped_pct(run):
@@ -19,3 +22,9 @@ def overlapped_pct(run):
     if "serve.decode_overlapped" not in run.counters or not steps:
         return None
     return 100.0 * run.counters["serve.decode_overlapped"] / steps
+
+
+def log(run) -> None:
+    say(f"decode overlapped % {overlapped_pct(run)} (serve.decode_overlapped "
+        f"{run.counters.get('serve.decode_overlapped')} of serve.decode_steps "
+        f"{run.counters.get('serve.decode_steps', 0)})")

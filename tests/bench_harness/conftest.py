@@ -44,13 +44,15 @@ def _add_dummy_cell(root) -> str:
     (bench / "layer_metrics" / "dummy_steps.py").write_text(
         '"""Steps completed in the window."""\n\n\ndef read(run):\n    return run.stats.get("steps")\n')
     return _add_entries(root, "dummy-6l", config, "train-b2-dummy", "train_tokens_per_s_per_chip",
-                        "dummy_steps", "host_clock")
+                        ["dummy_steps"], "host_clock")
 
 
-def _add_entries(root, config_name: str, config: dict, traffic: str, moves: str, metric: str,
+def _add_entries(root, config_name: str, config: dict, traffic: str, moves: str, metrics: list,
                  source: str) -> str:
     """The manifest entries of one new configuration with one cell, which reports the
-    end-to-end metric ``moves`` and one per-layer metric of its own. Returns the cell's name."""
+    end-to-end metric ``moves`` and per-layer ``metrics`` of its own, each appended at the end of
+    its list; the cell goes onto the ``workloads`` of ``moves`` and of ``recompiles_in_window``.
+    Returns the cell's name."""
     with open(root / "BENCHMARK.json") as f:
         man = json.load(f)
     cell = f"{config_name}.{traffic}"
@@ -59,12 +61,11 @@ def _add_entries(root, config_name: str, config: dict, traffic: str, moves: str,
                            "reduced": config["reduced"], "why": "a test"})
     man["workloads"].append({"name": cell, "config": config_name, "traffic": traffic,
                              "chips": 1, "why": "a test"})
-    for m in man["end_to_end"]:
-        if m["name"] == moves:
+    for m in man["end_to_end"] + man["per_layer"]:
+        if m["name"] in (moves, "recompiles_in_window"):
             m["workloads"].append(cell)
-    man["per_layer"].append({"name": metric, "unit": "count", "better": "higher",
-                             "source": source, "layer": "entry", "moves": moves,
-                             "workloads": [cell]})
+    man["per_layer"] += [{"name": metric, "unit": "count", "better": "higher", "source": source,
+                          "layer": "entry", "moves": moves, "workloads": [cell]} for metric in metrics]
     (root / "BENCHMARK.json").write_text(json.dumps(man))
     return cell
 
@@ -142,4 +143,35 @@ def _add_served_only_cell(root) -> str:
         '"""Decode steps in the window."""\n\n\ndef read(run):\n'
         '    return run.stats.get("decode_steps")\n')
     return _add_entries(root, "dummy-hybrid", config, "serve-dummy", "serve_tpot_p50_ms",
-                        "dummy_decode_steps", "program_counter")
+                        ["dummy_decode_steps"], "program_counter")
+
+
+@pytest.fixture
+def add_next_cell():
+    return _add_next_cell
+
+
+NEXT_METRICS = [f"next_part_{i:02d}_ms_per_iter" for i in range(20)]
+
+
+def _add_next_cell(root) -> str:
+    """What the PR after the last accepted configuration does: one configuration more, one serve
+    cell on it and twenty per-layer entries listed for that cell alone, files and entries only.
+    Returns the new cell's name."""
+    bench = root / "benchmark"
+    with open(bench / "configs" / "mistral-7b-v0.3-l8.json") as f:
+        config = json.load(f)
+    config["source"] = "https://example.org/next/config.json"
+    config["num_hidden_layers"], config["reduced"] = 4, ["num_hidden_layers"]
+    config["reduced_from"] = {"num_hidden_layers": 32}
+    (bench / "configs" / "next-l4.json").write_text(json.dumps(config))
+    with open(bench / "traffic" / "serve-chat.json") as f:
+        traffic = json.load(f)
+    traffic["loop"]["rate_rps"] = 3.0
+    (bench / "traffic" / "serve-next.json").write_text(json.dumps(traffic))
+    for name in NEXT_METRICS:
+        (bench / "layer_metrics" / f"{name}.py").write_text(
+            '"""A part of the decode step, for a test."""\n\n\ndef read(run):\n'
+            '    return run.stats.get("part_ms")\n')
+    return _add_entries(root, "next-l4", config, "serve-next", "serve_tpot_p50_ms", NEXT_METRICS,
+                        "device_trace")

@@ -1,6 +1,8 @@
 """The chip's idle time put down to the phases of the serving loop (benchmark/lib/phases.py) on
 a hand-built trace of two passes of the loop (benchmark/fixtures/trace_engine_loop.json), the
-queue wait from hand-built bus records, and the readers of both end to end in a rehearsal.
+queue wait from hand-built bus records, and the readers of both end to end in a rehearsal. Each of
+the two cells lists one idle entry, the eight shares together; the shares are on the log line
+`bench: idle by phase`.
 
 The fixture's idle gaps and what each is put down to (ns; the window is 0 .. 10000):
 
@@ -13,21 +15,23 @@ The fixture's idle gaps and what each is put down to (ns; the window is 0 .. 100
     9000 .. 9100   in_program 100
     9800 .. 10000  fetch 100, commit 40, iteration alone 10, nothing 50
 """
+import dataclasses
 import os
 from types import SimpleNamespace
 
 import pytest
 
 from benchmark.lib import manifest, phases, xplane
-from test_rehearsal import SERVE, last_line, run_cell
+from test_rehearsal import last_line, run_cell
 
 MAN = manifest.load_manifest()
 FIXTURE = os.path.join(manifest.ROOT, "benchmark", "fixtures", "trace_engine_loop.json")
 WANT = {"admit": 180, "prefill": 1050, "upload": 430, "dispatch": 780, "fetch": 400,
         "commit": 340, "unattributed": 520, "in_program": 300, "wait": 0}
-NEW = {prefix: {f"{prefix}_idle_{share}_ms_per_iter" for share in phases.SHARES} | queue
-       for prefix, queue in (("chat", {"chat_queue_wait_p50_ms", "chat_queue_wait_p95_ms"}),
-                             ("longprompt", {"longprompt_queue_wait_p50_ms"}))}
+NEW = {"chat": {"chat_idle_ms_per_iter", "chat_queue_wait_p50_ms", "chat_queue_wait_p95_ms"},
+       "longprompt": {"longprompt_idle_ms_per_iter", "longprompt_queue_wait_p50_ms"}}
+CELL = {"chat": "mistral-7b-v0.3-l8.serve-chat", "longprompt": "mistral-7b-v0.3-l8.serve-longprompt"}
+MOVES = {"chat": "serve_tpot_p50_ms", "longprompt": "serve_total_tokens_per_s"}
 
 
 @pytest.fixture(scope="module")
@@ -115,12 +119,28 @@ def test_every_new_reader_reads_its_share_per_iteration(fake_run):
             for names in NEW.values() for name in names}
     for prefix in NEW:
         for share in phases.SHARES:
-            assert read[f"{prefix}_idle_{share}_ms_per_iter"] == WANT[share] / 1e6 / 2
+            assert phases.idle_ms_per_iter(fake_run, share) == WANT[share] / 1e6 / 2
+        # the cell's one idle entry is the eight shares added: the chip's idle time, as no
+        # engine:wait lies under it
+        assert read[f"{prefix}_idle_ms_per_iter"] == pytest.approx(
+            sum(phases.idle_ms_per_iter(fake_run, share) for share in phases.SHARES), abs=0, rel=1e-12)
+        assert read[f"{prefix}_idle_ms_per_iter"] == pytest.approx(4000 / 1e6 / 2)
         assert read[f"{prefix}_queue_wait_p50_ms"] == 3.0
-        assert sum(read[f"{prefix}_idle_{share}_ms_per_iter"] for share in phases.SHARES) \
-            == pytest.approx(4000 / 1e6 / 2)
     assert read["chat_queue_wait_p95_ms"] == pytest.approx(4.0 + 0.8 * 101.0)
     assert all(isinstance(v, float) for v in read.values())
+
+
+@pytest.mark.parametrize("prefix", sorted(NEW))
+def test_the_idle_entry_reads_a_true_zero_as_zero(prefix, fake_run):
+    """A window whose chip was never idle: every share is 0, and so is the sum, which the line
+    then carries (a `None` would leave a listed metric out of it)."""
+    chip = dataclasses.replace(fake_run.trace.devices[0], gaps=[])
+    chip.busy_ns = chip.window_ns
+    fake_run.trace = SimpleNamespace(devices=[chip], host=fake_run.trace.host,
+                                     host_count=fake_run.trace.host_count)
+    reader = manifest.load_module(manifest.ROOT, "layer_metrics", f"{prefix}_idle_ms_per_iter")
+    got = reader.read(fake_run)
+    assert got == 0.0 and isinstance(got, float)
 
 
 def test_a_program_without_the_phases_reports_none_and_does_not_raise(fake_run):
@@ -133,24 +153,33 @@ def test_a_program_without_the_phases_reports_none_and_does_not_raise(fake_run):
     assert phases.idle_ms_per_iter(fake_run, "admit") is None
 
 
-@pytest.mark.parametrize("cell", SERVE)
-def test_the_manifest_lists_the_new_metrics_for_their_cell_only(cell):
-    prefix = "chat" if cell.endswith("serve-chat") else "longprompt"
-    listed = {m["name"]: m for m in manifest.resolve(MAN, cell).per_layer}
-    assert NEW[prefix] <= set(listed)
-    assert not any(name in listed for other in NEW if other != prefix for name in NEW[other])
-    for name in NEW[prefix]:
-        m = listed[name]
-        assert (m["unit"], m["better"], m["source"], m["layer"]) == ("ms", "lower", "program_span",
-                                                                     "entry")
-        assert m["workloads"] == [cell]
+def check_listing(man):
+    """Each cell's idle sum and queue waits are listed for it alone, under `entry`; none of the
+    eight shares is listed for any cell."""
+    entries = {m["name"]: m for m in man["per_layer"]}
+    for prefix, names in NEW.items():
+        cell = CELL[prefix]
+        for name in names:
+            m = entries[name]
+            assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"], m["workloads"]) == (
+                "ms", "lower", "program_span", "entry", MOVES[prefix], [cell]), name
+        for w in man["workloads"]:
+            listed = {m["name"] for m in manifest.listed(man, w["name"])[1]}
+            assert names & listed == (names if w["name"] == cell else set()), w["name"]
+            assert not any(f"{prefix}_idle_{share}_ms_per_iter" in listed for share in phases.SHARES)
 
 
-@pytest.mark.parametrize("cell", SERVE)
-def test_a_rehearsal_reads_every_new_metric_of_its_cell(cell):
+@pytest.mark.parametrize("prefix,cell", list(CELL.items()), ids=list(CELL.values()))
+def test_the_manifest_lists_the_new_metrics_for_their_cell_only(prefix, cell):
+    listed = {m["name"] for m in manifest.resolve(MAN, cell).per_layer}
+    assert NEW[prefix] <= listed
+    check_listing(MAN)
+
+
+@pytest.mark.parametrize("prefix,cell", list(CELL.items()), ids=list(CELL.values()))
+def test_a_rehearsal_reads_every_new_metric_of_its_cell(prefix, cell):
     line = last_line(run_cell(["--workload", cell, "--seed", "2147483659", "--seconds", "3",
                                "--trace", "1", "--rehearse"]))
-    prefix = "chat" if cell.endswith("serve-chat") else "longprompt"
     assert line["correct"] is True
     assert NEW[prefix] <= set(line["rehearsal"]["metrics_read"])
     assert line["rehearsal"]["breakdown_read"] == ["device_ops", "idle_gaps"]

@@ -1,20 +1,25 @@
-"""The two readers of `serve.pool_donated` / `serve.pool_copied` (benchmark/lib/pool.py): what
-they make of a run with both counters, with one and with neither, where the manifest lists them,
-and both end to end in a rehearsal, where the CPU backend donates like the chip."""
+"""`serve.pool_donated` / `serve.pool_copied` (benchmark/lib/pool.py): the share of dispatches that
+consumed the KV pool read 100 in every run of every cell, so it is a line on a traced serve run's
+log (`bench: pool donated % ...`) and no entry of the manifest. What the share and its line make of
+a run with both counters, with one and with neither, for each retired entry; that the manifest lists
+none of them; and the line end to end in a rehearsal, where the CPU backend donates like the chip."""
+import re
 from types import SimpleNamespace
 
 import pytest
 
 from benchmark.lib import manifest, pool
-from test_rehearsal import SERVE, last_line, run_cell
+from test_rehearsal import last_line, run_cell
 
 MAN = manifest.load_manifest()
-NEW = {"chat": "chat_pool_donated_pct", "longprompt": "longprompt_pool_donated_pct"}
-MOVES = {"chat": "serve_tpot_p50_ms", "longprompt": "serve_total_tokens_per_s"}
-
-
-def prefix_of(cell: str) -> str:
-    return "chat" if cell.endswith("serve-chat") else "longprompt"
+# the entries the line took the place of, by the cell they were listed for
+RETIRED = {"mistral-7b-v0.3-l8.serve-chat": "chat_pool_donated_pct",
+           "mistral-7b-v0.3-l8.serve-longprompt": "longprompt_pool_donated_pct",
+           "phi-4-mini-flash-reasoning.serve-reasoning": "reasoning_pool_donated_pct",
+           "mistral-small-4-119b-ep4-l6.serve-rollouts": "rollouts_pool_donated_pct"}
+PREFIX = {"mistral-7b-v0.3-l8.serve-chat": "chat", "mistral-7b-v0.3-l8.serve-longprompt": "longprompt"}
+LINE = re.compile(r"^bench: pool donated % (\S+) \(serve\.pool_donated (\d+), serve\.pool_copied (\d+)\)$",
+                  re.M)
 
 
 @pytest.mark.parametrize("counters,want", [
@@ -22,35 +27,48 @@ def prefix_of(cell: str) -> str:
     ({"serve.pool_donated": 3, "serve.pool_copied": 1}, 75.0),
     ({"serve.pool_donated": 7}, 100.0),
     ({"serve.pool_copied": 5}, 0.0),                 # a true zero is a reading
-    ({"serve.tokens": 9, "serve.decode_steps": 3}, None),   # the parent: neither counter
+    ({"serve.tokens": 9, "serve.decode_steps": 3}, None),   # a program without the counters
     ({}, None),
 ])
-@pytest.mark.parametrize("name", sorted(NEW.values()))
-def test_the_readers_on_a_run_with_both_counters_with_one_and_with_neither(name, counters, want):
+@pytest.mark.parametrize("name", sorted(RETIRED.values()))
+def test_the_log_line_carries_what_the_retired_entry_read(name, counters, want, capsys):
     run = SimpleNamespace(counters=counters)
-    got = manifest.load_module(manifest.ROOT, "layer_metrics", name).read(run)
-    assert got == want == pool.donated_pct(run)
-    assert want is None or isinstance(got, float)
+    assert pool.donated_pct(run) == want
+    assert want is None or isinstance(pool.donated_pct(run), float)
+    pool.log(run)
+    (value, donated, copied), = LINE.findall(capsys.readouterr().out)
+    assert value == repr(want)
+    assert (int(donated), int(copied)) == (counters.get("serve.pool_donated", 0),
+                                           counters.get("serve.pool_copied", 0))
+    assert not any(m["name"] == name for m in MAN["per_layer"])
 
 
-@pytest.mark.parametrize("cell", SERVE)
+def check_listing(man):
+    """No cell lists a donated share; what chat and long prompts list under `executors` (their XLA
+    time) is each cell's own."""
+    names = {m["name"] for m in man["per_layer"]}
+    assert not names & set(RETIRED.values())
+    for cell, prefix in PREFIX.items():
+        mine = [m for m in manifest.listed(man, cell)[1] if m["layer"] == "executors"]
+        assert mine
+        for m in mine:
+            assert m["name"].startswith(prefix + "_") and m["workloads"] == [cell], m["name"]
+
+
+@pytest.mark.parametrize("cell", PREFIX)
 def test_the_manifest_lists_each_for_its_own_cell_under_executors(cell):
-    listed = {m["name"]: m for m in manifest.resolve(MAN, cell).per_layer}
-    mine, other = NEW[prefix_of(cell)], NEW[{"chat": "longprompt", "longprompt": "chat"}[prefix_of(cell)]]
-    assert other not in listed
-    m = listed[mine]
-    assert (m["unit"], m["better"], m["source"], m["layer"]) == ("%", "higher", "program_counter",
-                                                                 "executors")
-    assert m["moves"] == MOVES[prefix_of(cell)] and m["workloads"] == [cell]
-    assert [e["name"] for e in MAN["per_layer"][-2:]] == sorted(NEW.values())  # appended, last
+    check_listing(MAN)
+    assert not {m["name"] for m in manifest.listed(MAN, cell)[1]} & set(RETIRED.values())
 
 
-@pytest.mark.parametrize("cell", SERVE)
-def test_a_rehearsal_reads_the_metric_of_its_cell(cell, copy):
+@pytest.mark.parametrize("cell", PREFIX)
+def test_a_rehearsal_logs_the_share_of_its_cell(cell, copy):
     # in a copy of the benchmark: test_rehearsal.py and test_phases.py trace the same cells
     # into the checkout's .bench_out, and the files of one directory run side by side
-    line = last_line(run_cell(["--workload", cell, "--seed", "2147483777", "--seconds", "3",
-                               "--trace", "1", "--rehearse"], root=str(copy)))
+    proc = run_cell(["--workload", cell, "--seed", "2147483777", "--seconds", "3",
+                     "--trace", "1", "--rehearse"], root=str(copy))
+    line = last_line(proc)
     assert line["correct"] is True and line["failed"] == 0
-    assert NEW[prefix_of(cell)] in line["rehearsal"]["metrics_read"]
+    (value, donated, copied), = LINE.findall(proc.stdout)
+    assert value == "100.0" and int(donated) > 0 and int(copied) == 0
     assert line["rehearsal"]["compiles_in_window"] == 0

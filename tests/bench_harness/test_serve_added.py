@@ -15,6 +15,13 @@ from test_rehearsal import last_line, run_cell
 
 MAN = manifest.load_manifest()
 CELL = "phi-4-mini-flash-reasoning.serve-reasoning"
+METRICS = {"reasoning_batch_occupancy", "reasoning_decode_device_ms_per_iter", "reasoning_decode_iter_ms_p50",
+           "reasoning_decode_mfu", "reasoning_idle_ms_per_iter", "reasoning_out_tokens_per_s",
+           "reasoning_shared_kv_attn_ms_per_iter", "reasoning_shared_kv_attn_roofline", "reasoning_ssm_ms_per_iter",
+           "reasoning_state_mb_per_seq", "reasoning_window_attn_ms_per_iter", "reasoning_window_attn_roofline",
+           "reasoning_window_pages_freed_per_iter", "reasoning_xla_head_ms_per_iter",
+           "reasoning_xla_mixer_ms_per_iter", "reasoning_xla_mlp_ms_per_iter", "reasoning_xla_ms_per_iter",
+           "reasoning_xla_unscoped_ms_per_iter"}
 
 
 def test_the_driver_offers_and_measures_as_serve_does_and_judges_a_sample_of_its_own():
@@ -121,24 +128,34 @@ def test_the_configuration_is_the_published_one_with_nothing_cut():
     assert not hasattr(c.builder, "build_loss_model")
 
 
-def test_the_manifest_lists_the_reasoning_metrics_for_this_cell_only():
-    mine = {m["name"]: m for m in MAN["per_layer"] if m["name"].startswith("reasoning_")}
-    assert len(mine) == 15
+def check_listing(man):
+    """The cell's entries by name: each listed for it alone, moving its `serve_tpot_p50_ms`."""
+    mine = {m["name"]: m for m in man["per_layer"] if m["name"].startswith("reasoning_")}
+    assert set(mine) == METRICS
     for m in mine.values():
         assert m["workloads"] == [CELL] and m["moves"] == "serve_tpot_p50_ms"
-        assert callable(manifest.resolve(MAN, CELL).reader(m["name"]).read)
-    listed = {m["name"] for m in manifest.resolve(MAN, CELL).per_layer}
-    assert listed == set(mine) | {"recompiles_in_window"}
-    assert {m["name"] for m in manifest.resolve(MAN, CELL).end_to_end} == {"serve_tpot_p50_ms", "setup_s"}
-    for other in (w["name"] for w in MAN["workloads"] if w["name"] != CELL):
-        assert not any(m["name"] in mine for m in manifest.resolve(MAN, other).per_layer)
+    e2e, per_layer = manifest.listed(man, CELL)
+    assert {m["name"] for m in per_layer} == METRICS | {"recompiles_in_window"}
+    assert {m["name"] for m in e2e} == {"serve_tpot_p50_ms", "setup_s"}
+    for other in (w["name"] for w in man["workloads"] if w["name"] != CELL):
+        assert not any(m["name"] in mine for m in manifest.listed(man, other)[1])
+
+
+def test_the_manifest_lists_the_reasoning_metrics_for_this_cell_only():
+    check_listing(MAN)
+    cell = manifest.resolve(MAN, CELL)
+    for name in METRICS:
+        assert callable(cell.reader(name).read)
 
 
 def test_a_rehearsal_reads_what_needs_no_tpu():
-    line = last_line(run_cell(["--workload", CELL, "--seed", "2147483777", "--seconds", "3",
-                               "--trace", "1", "--rehearse"]))
+    proc = run_cell(["--workload", CELL, "--seed", "2147483777", "--seconds", "3",
+                     "--trace", "1", "--rehearse"])
+    line = last_line(proc)
     assert line["correct"] is True and line["failed"] == 0
     assert set(line["rehearsal"]["metrics_read"]) >= {
         "recompiles_in_window", "reasoning_decode_iter_ms_p50", "reasoning_batch_occupancy",
-        "reasoning_out_tokens_per_s", "reasoning_pool_donated_pct", "reasoning_state_mb_per_seq",
+        "reasoning_out_tokens_per_s", "reasoning_state_mb_per_seq",
         "reasoning_window_pages_freed_per_iter", "reasoning_idle_ms_per_iter"}
+    # every array of the state is donated, pools and recurrent arrays alike: on the log
+    assert "bench: pool donated % 100.0 (" in proc.stdout

@@ -19,9 +19,9 @@ MAN = manifest.load_manifest()
 CELL = "mistral-small-4-119b-ep4-l6.serve-rollouts"
 CONFIG = "mistral-small-4-119b-ep4-l6"
 METRICS = {"rollouts_decode_iter_ms_p50", "rollouts_decode_device_ms_per_iter", "rollouts_out_tokens_per_s",
-           "rollouts_batch_occupancy", "rollouts_idle_ms_per_iter", "rollouts_decode_overlapped_pct",
-           "rollouts_pool_donated_pct", "rollouts_prefill_share_pct", "rollouts_moe_ms_per_iter",
-           "rollouts_moe_roofline", "rollouts_mla_attn_ms_per_iter", "rollouts_mla_attn_roofline",
+           "rollouts_batch_occupancy", "rollouts_idle_ms_per_iter", "rollouts_prefill_share_pct",
+           "rollouts_moe_ms_per_iter", "rollouts_moe_roofline", "rollouts_mla_attn_ms_per_iter",
+           "rollouts_mla_attn_roofline",
            "rollouts_xla_ms_per_iter", "rollouts_held_rows_pct", "rollouts_expert_rows_max_over_mean",
            "rollouts_latent_kb_per_token", "rollouts_decode_mfu"}
 
@@ -202,22 +202,29 @@ def test_the_configuration_is_the_published_one_cut_to_one_chip_of_four():
     assert round(layer / 1e6, 1) == 859.1 and round((6 * layer + 2 * p["head"] + 4096) / 1e9, 2) == 5.42
 
 
-def test_the_manifest_lists_the_rollouts_metrics_for_this_cell_only():
-    mine = {m["name"]: m for m in MAN["per_layer"] if m["name"].startswith("rollouts_")}
+def check_listing(man):
+    """The cell's entries by name: each listed for it alone, moving its `serve_tpot_p50_ms`; the
+    cell on its configuration."""
+    mine = {m["name"]: m for m in man["per_layer"] if m["name"].startswith("rollouts_")}
     assert set(mine) == METRICS
     for m in mine.values():
         assert m["workloads"] == [CELL] and m["moves"] == "serve_tpot_p50_ms"
-        assert callable(manifest.resolve(MAN, CELL).reader(m["name"]).read)
         assert m["unit"] == "%" if m["name"].endswith("_roofline") or m["name"].endswith("_mfu") else True
-    listed = {m["name"] for m in manifest.resolve(MAN, CELL).per_layer}
-    assert listed == set(mine) | {"recompiles_in_window"}
-    assert {m["name"] for m in manifest.resolve(MAN, CELL).end_to_end} == {"serve_tpot_p50_ms", "setup_s"}
-    for other in (w["name"] for w in MAN["workloads"] if w["name"] != CELL):
-        assert not any(m["name"] in mine for m in manifest.resolve(MAN, other).per_layer)
-    # appended: the entries that were there come first, in the order they had
-    assert [m["name"] for m in MAN["per_layer"]][-len(mine):] == [m for m in
-                                                                  (e["name"] for e in MAN["per_layer"]) if m in mine]
-    assert MAN["workloads"][-1]["name"] == CELL and MAN["configs"][-1]["name"] == CONFIG
+    e2e, per_layer = manifest.listed(man, CELL)
+    assert {m["name"] for m in per_layer} == METRICS | {"recompiles_in_window"}
+    assert {m["name"] for m in e2e} == {"serve_tpot_p50_ms", "setup_s"}
+    for other in (w["name"] for w in man["workloads"] if w["name"] != CELL):
+        assert not any(m["name"] in mine for m in manifest.listed(man, other)[1])
+    cell = {w["name"]: w for w in man["workloads"]}[CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "serve-rollouts", 1)
+    assert CONFIG in {c["name"] for c in man["configs"]}
+
+
+def test_the_manifest_lists_the_rollouts_metrics_for_this_cell_only():
+    check_listing(MAN)
+    cell = manifest.resolve(MAN, CELL)
+    for name in METRICS:
+        assert callable(cell.reader(name).read)
 
 
 def test_the_new_kernel_classes_are_tried_after_the_ones_that_were_there():
@@ -243,8 +250,7 @@ def test_a_rehearsal_reads_what_needs_no_tpu():
     assert line["correct"] is True and line["failed"] == 0
     assert set(line["rehearsal"]["metrics_read"]) >= {
         "recompiles_in_window", "rollouts_decode_iter_ms_p50", "rollouts_batch_occupancy",
-        "rollouts_out_tokens_per_s", "rollouts_pool_donated_pct", "rollouts_decode_overlapped_pct",
-        "rollouts_held_rows_pct", "rollouts_expert_rows_max_over_mean", "rollouts_latent_kb_per_token",
-        "rollouts_idle_ms_per_iter"}
+        "rollouts_out_tokens_per_s", "rollouts_held_rows_pct", "rollouts_expert_rows_max_over_mean",
+        "rollouts_latent_kb_per_token", "rollouts_idle_ms_per_iter"}
     assert {"sample_first_latent_distance", "sample_latent_distance", "sample_latent_padding",
             "sample_margin"} <= set(line["compared"])

@@ -102,25 +102,28 @@ def test_the_model_the_builder_makes_has_as_many_parameters_as_the_file_says():
     assert not np.array_equal(np.asarray(parameters["h.1.mlp.up.weight"].data), np.asarray(parameters["h.3.mlp.up.weight"].data))
 
 
-def test_the_manifest_lists_the_trajectories_metrics_for_this_cell_only():
-    names = [m["name"] for m in MAN["per_layer"]]
-    mine = {m["name"]: m for m in MAN["per_layer"] if m["name"].startswith("trajectories_")}
-    assert list(mine) == METRICS
-    # appended in one piece, after every entry that was there
-    first = names.index(METRICS[0])
-    assert names[first:first + len(METRICS)] == METRICS and not any(n.startswith("trajectories_") for n in names[:first])
+def check_listing(man):
+    """The cell's entries by name: each listed for it alone, moving its `serve_tpot_p50_ms`; the
+    cell on its configuration."""
+    mine = {m["name"]: m for m in man["per_layer"] if m["name"].startswith("trajectories_")}
+    assert set(mine) == set(METRICS)
     for m in mine.values():
         assert m["workloads"] == [CELL] and m["moves"] == "serve_tpot_p50_ms"
-        assert callable(manifest.resolve(MAN, CELL).reader(m["name"]).read)
         assert m["unit"] == "%" if m["name"].endswith("_roofline") or m["name"].endswith("_mfu") else True
         assert m["layer"] in {"entry", "executors", "kernels", "serving_state", "device"}
-    listed = {m["name"] for m in manifest.resolve(MAN, CELL).per_layer}
-    assert listed == set(mine) | {"recompiles_in_window"}
-    assert {m["name"] for m in manifest.resolve(MAN, CELL).end_to_end} == {"serve_tpot_p50_ms", "setup_s"}
-    for other in (w["name"] for w in MAN["workloads"] if w["name"] != CELL):
-        assert not any(m["name"] in mine for m in manifest.resolve(MAN, other).per_layer)
-    cell = next(w for w in MAN["workloads"] if w["name"] == CELL)
+    e2e, per_layer = manifest.listed(man, CELL)
+    assert {m["name"] for m in per_layer} == set(METRICS) | {"recompiles_in_window"}
+    assert {m["name"] for m in e2e} == {"serve_tpot_p50_ms", "setup_s"}
+    for other in (w["name"] for w in man["workloads"] if w["name"] != CELL):
+        assert not any(m["name"] in mine for m in manifest.listed(man, other)[1])
+    cell = {w["name"]: w for w in man["workloads"]}[CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "serve-trajectories", 1)
+
+
+def test_the_manifest_lists_the_trajectories_metrics_for_this_cell_only():
+    check_listing(MAN)
+    for name in METRICS:
+        assert callable(manifest.resolve(MAN, CELL).reader(name).read)
     # no kernel class of its own: the two kernels are recognised by the patterns that were there
     from benchmark.lib import readers
 
