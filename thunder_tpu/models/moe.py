@@ -221,7 +221,10 @@ class HeldExperts(nn.Module):
     its own experts ``held = (lo, hi)`` through ``ragged_experts``, drops no
     token, leaves out what the absent experts would add, and adds the shared
     expert, which every chip of the group computes alike. On one chip it runs
-    without the exchange; nothing stands in for the other chips.
+    without the exchange; nothing stands in for the other chips. ``n_shared``
+    shared experts of ``width`` each are one fused SwiGLU ``n_shared * width``
+    wide, whose output is their SUM; ``shared="average"`` divides it by
+    ``n_shared`` (Command A+'s ``shared_expert_combination_strategy``).
 
     ``n_zero`` further router outputs, numbered after the routed experts, are
     ZERO-COMPUTE (identity) experts (LongCat-Flash,
@@ -231,14 +234,17 @@ class HeldExperts(nn.Module):
     it enters a ragged group and no panel is read for it."""
 
     def __init__(self, n_embd: int, width: int, n_routed: int, held: tuple, n_expert_per_token: int,
-                 *, n_shared: int = 1, norm_topk_prob: bool = True, routed_scaling_factor: float = 1.0,
-                 score: str = "sigmoid", n_zero: int = 0, dtype=jnp.float32):
+                 *, n_shared: int = 1, shared: str = "sum", norm_topk_prob: bool = True,
+                 routed_scaling_factor: float = 1.0, score: str = "sigmoid", n_zero: int = 0,
+                 dtype=jnp.float32):
         super().__init__()
         lo, hi = held
         if not 0 <= lo < hi <= n_routed:
             raise ValueError(f"experts held [{lo}, {hi}) are no range of the {n_routed} routed")
         if score not in ("sigmoid", "softmax"):
             raise ValueError(f"a router scores by 'sigmoid' or 'softmax', not {score!r}")
+        if shared not in ("sum", "average"):
+            raise ValueError(f"shared experts combine by 'sum' or 'average', not {shared!r}")
         self.n_routed, self.held, self.k = n_routed, (lo, hi), n_expert_per_token
         self.norm_topk_prob, self.scaling = norm_topk_prob, routed_scaling_factor
         self.score, self.n_zero = score, n_zero
@@ -249,6 +255,8 @@ class HeldExperts(nn.Module):
         self.w_up = nn.Parameter(jnp.zeros((e, n_embd, width), dtype))
         self.w_down = nn.Parameter(jnp.zeros((e, width, n_embd), dtype))
         self.shared_width = n_shared * width
+        # what the shared output is multiplied by; at 1 nothing is traced
+        self.shared_scale = 1.0 / n_shared if shared == "average" and n_shared > 1 else 1.0
         if n_shared:
             self.shared_gate = nn.Linear(n_embd, self.shared_width, bias=False, dtype=dtype)
             self.shared_up = nn.Linear(n_embd, self.shared_width, bias=False, dtype=dtype)
@@ -315,7 +323,8 @@ class HeldExperts(nn.Module):
                 out = ltorch.to(out + ltorch.to(xf, dtype=dtypes.float32) * g0, dtype=x.dtype)
         if self.shared_width:
             with named_scope("shared_expert"):
-                out = out + self.shared_down(ltorch.silu(self.shared_gate(xf)) * self.shared_up(xf))
+                shared = self.shared_down(ltorch.silu(self.shared_gate(xf)) * self.shared_up(xf))
+                out = out + (shared if self.shared_scale == 1.0 else shared * self.shared_scale)
         return ltorch.reshape(out, (B, T, D))
 
 

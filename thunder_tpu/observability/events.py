@@ -255,6 +255,17 @@ def inc(name: str, delta: int = 1, **attrs) -> None:
         })
 
 
+def raise_to(name: str, value: int, **attrs) -> None:
+    """Raise a named counter to ``value`` where it is below: a high-water mark
+    that ``reset`` clears as it clears every counter."""
+    if not _BUS.enabled:
+        return
+    with _BUS.lock:  # re-entrant: the read and the bump are one step
+        below = _BUS.counters.get(name, 0)
+        if value > below:
+            inc(name, value - below, **attrs)
+
+
 def counters() -> dict[str, int]:
     with _BUS.lock:
         return dict(_BUS.counters)

@@ -255,6 +255,15 @@ def record_serve(outcome: str, delta: int = 1, event: bool = False, **attrs) -> 
         events.event(f"serve_{outcome}", **attrs)
 
 
+def record_serve_peak(outcome: str, value: int) -> None:
+    """``serve.<outcome>`` as a high-water mark: raised to ``value`` where
+    that is above it, so it reads the most seen since the bus was reset
+    (pages of a pool kind in use at one step)."""
+    if not events.enabled():
+        return
+    events.raise_to(f"serve.{outcome}", value)
+
+
 def record_moe(expert_load, dropped_tokens, router_entropy, **attrs) -> None:
     """Routing health for one MoE step (models/moe.py buffers or the
     EP stats dict from parallel/expert_parallel.py): counter ``moe.steps``

@@ -133,6 +133,11 @@ class RequestResult:
     # are back in the pool; their rows stay as written until another sequence takes them, which
     # is how a test reads what the engine cached for a request it served alone
     pages: tuple = ()
+    # window layers only: the window pages the sequence held when it retired, as (page index,
+    # page) pairs in position order (their rows stay as written, as ``pages``' do), and the most
+    # it held at once
+    window_pages: tuple = ()
+    window_pages_peak: int = 0
     # generation by diffusion over blocks only: (position, pass) of every generated position in
     # the order it was filled, and, where ``engine.record_block_states`` was set, the block going
     # INTO each pass: (first position, its tokens (K,), its masked flags (K,)). A "pass" here is
@@ -166,6 +171,7 @@ class _Request:
     # pages of the window layers' pools, by page index (position // page_size):
     # only those that intersect the window are held
     win_pages: Dict[int, int] = field(default_factory=dict)
+    win_peak: int = 0
     bucket: int = 0
     # admission-time routing state (set by _reserve_pages each admission —
     # a preempted request is re-routed from scratch on resume)
@@ -1214,6 +1220,7 @@ class ServingEngine:
         missing = [j for j in range(lo // ps, (hi - 1) // ps + 1) if j not in req.win_pages]
         if missing:
             req.win_pages.update(zip(missing, self.cache.window_allocator.alloc(len(missing))))
+            req.win_peak = max(req.win_peak, len(req.win_pages))
 
     def _window_trim(self, req: _Request, pos: int) -> None:
         """Free the window pages the query at ``pos`` (the next position to be
@@ -2000,13 +2007,18 @@ class ServingEngine:
         step by step (bus on): pages of the pools without a window (again as
         ``state.latent_pages`` where layers cache latent rows), pages of the
         window pools, bytes of recurrent state. Over ``serve.tokens`` (the
-        sequences, summed the same way) they give the state a sequence holds."""
-        _obs_metrics.record_serve("state.shared_kv_pages", delta=self.cache.allocator.n_used)
+        sequences, summed the same way) they give the state a sequence holds.
+        Beside them the most pages of each kind in use at one step since the
+        bus was reset (``state.full_pages_peak``, ``state.window_pages_peak``)."""
+        full = self.cache.allocator.n_used
+        _obs_metrics.record_serve("state.shared_kv_pages", delta=full)
+        _obs_metrics.record_serve_peak("state.full_pages_peak", full)
         if self.latent:
-            _obs_metrics.record_serve("state.latent_pages", delta=self.cache.allocator.n_used)
+            _obs_metrics.record_serve("state.latent_pages", delta=full)
         if self.window:
-            _obs_metrics.record_serve("state.window_pages",
-                                      delta=self.cache.window_allocator.n_used)
+            window = self.cache.window_allocator.n_used
+            _obs_metrics.record_serve("state.window_pages", delta=window)
+            _obs_metrics.record_serve_peak("state.window_pages_peak", window)
         if self.recurrent:
             _obs_metrics.record_serve("state.recurrent_bytes",
                                       delta=active * self.cache.recurrent_bytes_per_slot())
@@ -2165,7 +2177,7 @@ class ServingEngine:
         return len(req.tokens) >= req.max_new_tokens
 
     def _retire(self, req: _Request) -> None:
-        held = tuple(req.pages)
+        held, win_held = tuple(req.pages), tuple(sorted(req.win_pages.items()))
         self._free_pages(req)
         n_new = len(req.tokens)
         # t_first == 0.0 only for a prefix-hit request cancelled before its
@@ -2239,6 +2251,8 @@ class ServingEngine:
             slo_met=slo_met,
             queue_s=req.t_admit - req.t_submit,
             pages=held,
+            window_pages=win_held,
+            window_pages_peak=req.win_peak,
             unmasked=tuple(req.unmasked),
             block_states=tuple(req.block_states),
         )
