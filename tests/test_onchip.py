@@ -366,14 +366,18 @@ def test_flash_kernels_lower_via_mosaic():
     assert np.isfinite(np.asarray(dq, np.float32)).all()
 
 
-@pytest.mark.parametrize("H,Hkv,T,D,n_elem", [(16, 16, 2048, 64, 16), (16, 16, 2048, 64, 64),
-                                              (8, 2, 4096, 128, 128), (8, 2, 4096, 128, 32)],
-                         ids=["pythia-quarter-of-64", "llama-350m-64", "mistral-g4-128",
-                              "g4-quarter-of-128"])
-def test_rope_flash_kernels_on_chip(H, Hkv, T, D, n_elem):
+@pytest.mark.parametrize("H,Hkv,T,D,n_elem,causal,block_k", [
+    (16, 16, 2048, 64, 16, True, None), (16, 16, 2048, 64, 64, True, None),
+    (8, 2, 4096, 128, 128, True, None), (8, 2, 4096, 128, 32, True, None),
+    (16, 16, 2048, 64, 16, False, None), (16, 16, 2048, 64, 16, True, 256)],
+    ids=["pythia-quarter-of-64", "llama-350m-64", "mistral-g4-128", "g4-quarter-of-128",
+         "pythia-quarter-of-64-noncausal", "pythia-quarter-of-64-two-diagonal-tiles"])
+def test_rope_flash_kernels_on_chip(H, Hkv, T, D, n_elem, causal, block_k):
     """The rope-fused flash pair compiled by Mosaic at the train cells' heads, at a rotary width
     of the whole head and of a quarter of it: the output and dq, dk, dv against float32 rope
-    and softmax attention in plain jax (bf16 operands: a hundredth of the largest entry)."""
+    and softmax attention in plain jax (bf16 operands: a hundredth of the largest entry). At
+    pythia's shape also without the mask (every tile interior) and with key tiles of 256, so
+    that each query block of 512 has two diagonal tiles."""
     from thunder_tpu.executors import pallasex
     from thunder_tpu.models.litgpt import build_rope_cache
 
@@ -395,15 +399,17 @@ def test_rope_flash_kernels_on_chip(H, Hkv, T, D, n_elem):
 
     def ref(q, k, v):
         kk, vv = (jnp.repeat(t, g, axis=1) for t in (rope(k), v))
-        mask = jnp.tril(jnp.ones((T, T), bool))
+        mask = jnp.tril(jnp.ones((T, T), bool)) if causal else jnp.ones((T, T), bool)
         return _attention_ref(rope(q), kk, vv, mask)
 
     f32 = [t.astype(jnp.float32) for t in (q, k, v)]
     want, vjp = jax.vjp(ref, *f32)
     want_g = vjp(do.astype(jnp.float32))
-    o, lse = jax.jit(lambda *a: pallasex.flash_rope_attention_forward(*a, causal=True))(q, k, v, cos, sin)
-    got_g = jax.jit(lambda *a: pallasex.flash_rope_attention_backward(*a, causal=True))(
-        q, k, v, o, lse, cos, sin, do)
+    blocks = {} if block_k is None else {"block_k": block_k}
+    o, lse = jax.jit(lambda *a: pallasex.flash_rope_attention_forward(
+        *a, causal=causal, **blocks))(q, k, v, cos, sin)
+    got_g = jax.jit(lambda *a: pallasex.flash_rope_attention_backward(
+        *a, causal=causal, **blocks))(q, k, v, o, lse, cos, sin, do)
     for name, got, ref_ in zip(("o", "dq", "dk", "dv"), (o, *got_g), (want, *want_g)):
         got, ref_ = np.asarray(got, np.float32), np.asarray(ref_)
         assert np.isfinite(got).all(), name

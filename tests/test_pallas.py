@@ -1,6 +1,7 @@
 """Pallas kernel coverage (interpret mode on CPU — the same kernels lower via
 Mosaic on TPU). Reference analogs: sdpaex/cudnnex flash attention
 (thunder/executors/sdpaex.py), triton/apex cross-entropy, fused RMSNorm."""
+import functools
 import math
 
 import numpy as np
@@ -94,11 +95,16 @@ def test_checker_accepts_gpt2_shapes():
 
 # the cells' and chip_smoke.py's shapes (T 2,048 and 4,096; heads 64 and 128; groups 1 and 4)
 # fit; 8,192 fits the plain kernels only (the rope tables stay whole in VMEM beside K and V);
-# 16,384 fits neither forward under the compiler's 16 MiB
+# 16,384 fits neither forward under the compiler's 16 MiB. At heads of 128 the last lengths
+# the v5e's compiler takes for the forward's 512 x 512 tile, 13,312 plain and 6,144 with rope,
+# fit, and the next ones do not
 _FLASH_VMEM_CASES = ([(T, D, g, ("flash_attention", "rope_sdpa"))
                       for T in (2048, 4096) for D in (64, 128) for g in (1, 4)]
                      + [(8192, 128, 4, ("flash_attention",)), (8192, 64, 1, ("flash_attention",)),
-                        (16384, 128, 4, ()), (16384, 64, 1, ()), (32768, 128, 1, ())])
+                        (16384, 128, 4, ()), (16384, 64, 1, ()), (32768, 128, 1, ()),
+                        (6144, 128, 4, ("flash_attention", "rope_sdpa")),
+                        (7168, 128, 4, ("flash_attention",)), (13312, 128, 1, ("flash_attention",)),
+                        (14336, 128, 1, ())])
 
 
 def _claimed_and_declines(check):
@@ -334,3 +340,83 @@ def test_flash_gqa_matches_reference(rng, dtype, atol):
     for got, want, name in zip((dq, dk, dv), ref, "dq dk dv".split()):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-3,
                                    err_msg=name)
+
+
+def _rope_ref(x, cos, sin):
+    """rotate_half rope over the first `n_elem` columns, the rest passed."""
+    n_elem = cos.shape[-1]
+    h = n_elem // 2
+    x1, x2, rest = x[..., :h], x[..., h:n_elem], x[..., n_elem:]
+    c, s = cos[:, :h], sin[:, :h]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], -1)
+
+
+# (pair, causal, head dim, rotary width, q heads a KV head, block_q, block_k) at T 256: a query
+# block of 64 has interior tiles and one diagonal tile of 64 keys, or two of 32
+_SPLIT_CASES = [("rope", True, 64, 16, 1, 64, 32), ("rope", False, 128, 128, 4, 64, 64),
+                ("plain", True, 128, None, 4, 64, 64), ("plain", False, 64, None, 1, 64, 32)]
+
+
+@pytest.mark.parametrize("pair,causal,D,n_elem,g,block_q,block_k", _SPLIT_CASES,
+                         ids=[f"{p}-{'causal' if c else 'full'}-D{D}-g{g}-{bq}x{bk}"
+                              for p, c, D, _, g, bq, bk in _SPLIT_CASES])
+def test_flash_pairs_split_key_tiles_match_reference(rng, pair, causal, D, n_elem, g,
+                                                     block_q, block_k):
+    """Both flash pairs, forward and backward, at blocks small enough that a query block has
+    interior key tiles (run by the forward with no mask) and one or two diagonal ones (the
+    mask, and only there): against float32 attention in plain jax."""
+    from thunder_tpu.models.litgpt import build_rope_cache
+
+    B, Hkv, T = 1, 2 if g == 1 else 1, 256
+    q, do = (jnp.asarray(rng.randn(B, Hkv * g, T, D).astype(np.float32)) for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(B, Hkv, T, D).astype(np.float32)) for _ in range(2))
+    blocks = {"causal": causal, "block_q": block_q, "block_k": block_k}
+    if pair == "rope":
+        cos, sin = build_rope_cache(T, n_elem, 10000, jnp.float32)
+        o, lse = pallasex.flash_rope_attention_forward(q, k, v, cos, sin, **blocks)
+        got = pallasex.flash_rope_attention_backward(q, k, v, o, lse, cos, sin, do, **blocks)
+        rope, atol = functools.partial(_rope_ref, cos=cos, sin=sin), (2e-4, 2e-4)
+    else:
+        o, lse = pallasex.flash_attention_forward(q, k, v, **blocks)
+        got = pallasex.flash_attention_backward(q, k, v, o, lse, do, **blocks)
+        rope, atol = (lambda x: x), (2e-3, 5e-3)
+
+    def ref(q, k, v):
+        kk, vv = (jnp.repeat(t, g, axis=1) for t in (rope(k), v))
+        return _ref_attn(rope(q), kk, vv, causal=causal)
+
+    def ref_and_grads(q, k, v, do):
+        want, vjp = jax.vjp(ref, q, k, v)
+        return want, vjp(do)
+
+    want, want_g = jax.jit(ref_and_grads)(q, k, v, do)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want), atol=atol[0], err_msg="o")
+    for got_, want_, name in zip(got, want_g, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(got_), np.asarray(want_), atol=atol[1], err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_tile_counters_at_pythias_shape(causal):
+    """At pythia's shape (B 4, H 16, T 2,048, head 64, rotary 16) both forwards run blocks of 512
+    queries by 512 keys: under the causal mask a (batch, head) has 6 interior tiles and 4
+    diagonal ones and no tile past its last query, without it 16 interior ones; each traced
+    call counts them times B * H = 64 (`pallas.flash.tiles_interior` / `.tiles_diagonal`)."""
+    from thunder_tpu import observability
+
+    B, H, T, D = 4, 16, 2048, 64
+    x = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16)
+    table = jax.ShapeDtypeStruct((T, 16), jnp.float32)
+    calls = [(functools.partial(pallasex.flash_rope_attention_forward, causal=causal),
+              (x, x, x, table, table)),
+             (functools.partial(pallasex.flash_attention_forward, causal=causal), (x, x, x))]
+    observability.enable()
+    try:
+        for call, args in calls:
+            observability.reset()
+            jax.eval_shape(call, *args)
+            counters = observability.counters()
+            assert (counters.get("pallas.flash.tiles_interior", 0),
+                    counters.get("pallas.flash.tiles_diagonal", 0)) == (
+                        (6 * 64, 4 * 64) if causal else (16 * 64, 0))
+    finally:
+        observability.disable()

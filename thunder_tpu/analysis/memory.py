@@ -324,12 +324,12 @@ def flash_fwd_vmem_bytes(block_q: int, block_k: int, Tk: int, D: int,
     lse blocks, two buffers each; the f32 scores and probabilities of one
     (block_q, block_k) tile; with rope the cos/sin tables. Rows
     narrower than the 128 lanes are padded to them. Held against the v5e's
-    compiler (PR 29; bf16 and f32, heads of 64 and 128, groups of 1 and 4, the
-    longest multiple of 1,024 that compiles): at heads of 128 it says "fits" up
-    to that length and not beyond (bf16 11,264 plain and 5,120 with rope; f32
-    7,168 and 4,096); at heads of 64 the compiler takes more in some cases (up
-    to 18,432 plain, 11,264-16,384 with rope), so there the estimate declines
-    early and never late."""
+    compiler at the tile the forward runs (512 x 512 in bf16, 256 x 256 in
+    f32; bf16 and f32, heads of 64 and 128, groups of 1 and 4, the longest
+    multiple of 1,024 that compiles): at heads of 128 it says "fits" up to that
+    length and not beyond (bf16 13,312 plain and 6,144 with rope; f32 7,168
+    and 4,096); at heads of 64 the compiler takes 11,264 or more everywhere,
+    so there the estimate declines early and never late."""
     Dp = _lane_padded(D)
     kv = 2 * 2 * Tk * Dp * kv_itemsize
     qo = 2 * 2 * block_q * Dp * q_itemsize + 2 * block_q * 128 * 4

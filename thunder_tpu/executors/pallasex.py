@@ -32,15 +32,19 @@ from ..observability import events as _obs
 ex = OperatorExecutor("pallas")
 register_executor(ex)
 
-# swept on v5e (llama-350m, B=4, T=2048, D=64, fwd+bwd step): 512/1024 gave
-# 39.4% MFU vs 23.8% at 128/128 — large q blocks amortize the k/v loop,
-# k-major blocks keep the MXU fed during the online-softmax accumulation
+# Flash blocks, from the shapes (analysis/memory.py flash_block_cap caps 4-byte
+# operands at 256; `_fused_bwd_blocks`). Read on a TPU v5e, one call of the
+# rope pair under the causal mask at pythia-410m's attention (B 4, 16 heads of
+# 64, T 2,048):
+# - the forward runs 512 x 512: its key tile defaults to the query tile, so no
+#   tile computes keys past its last query. Laid out queries by keys it read
+#   1.40 ms there against 1.55 at 512 x 1,024 (1,024-row query blocks: 1.57).
+# - the single-pass backward runs 512 x 512: 2.33 ms against 2.63 at
+#   512 x 256.
+# DEFAULT_BLOCK_K is the key length the checkers claim in multiples of, and the
+# ring path's key tile.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
-# single-pass fused backward blocks (swept on v5e across llama-350m/llama-1b/
-# nanogpt shapes: 512/512 wins everywhere — 4.11/2.75/2.80 ms fwd+bwd vs
-# 4.52/3.24/3.42 for a dq pass and a dkv pass; 1024-row q blocks blow the
-# 16 MB VMEM limit)
 _FUSED_BLOCK_Q = 512
 _FUSED_BLOCK_K = 512
 # scoped VMEM the single-pass backward asks Mosaic for: it keeps whole-length
@@ -98,58 +102,110 @@ def _interpret() -> bool:
 # ===========================================================================
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int, causal: bool,
-                      scale: float, q_offset_blocks: int):
-    # q_ref: (block_q, D); k_ref/v_ref: (T, D); o_ref: (block_q, D); lse_ref: (block_q, 1)
-    block_q, D = q_ref.shape
-    T = k_ref.shape[0]
-    qi = pl.program_id(2)
+def _diagonal_tiles(i, block_q: int, block_k: int, n_k: int, minimum=jnp.minimum):
+    """The key tiles query block `i` runs under a causal mask, ``[lo, hi)``: the
+    ``lo`` before them end at or before its first query (interior: nothing to
+    mask), those from ``hi`` on begin past its last (never computed). `minimum`
+    is `min` where `i` is a Python int (the counters), `jnp.minimum` in a kernel."""
+    return (minimum((i * block_q) // block_k, n_k),
+            minimum(((i + 1) * block_q + block_k - 1) // block_k, n_k))
 
-    # inputs stay low-precision so the dots ride the MXU's native bf16 path
-    # (fp32 operands run the MXU at a fraction of peak); accumulation is
-    # always f32 via preferred_element_type, scores/softmax stay f32
-    q = q_ref[:]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
-    # base-2 softmax: fold log2(e) into the dot scale so the per-element
-    # softmax uses the VPU's native exp2 with no premultiply pass — the
-    # running max/sum track log2 units; lse converts back to natural log once
+def _causal_keep(shape, q_axis: int, q0_minus_k0):
+    """Where a (query, key) pair of a diagonal tile is kept (key <= query): the
+    tile's first query is `q0_minus_k0` past its first key, queries run along
+    `q_axis` and keys along the other."""
+    q = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return k - q <= q0_minus_k0
+
+
+def _over_key_tiles(tile, carry, i, block_q: int, block_k: int, n_k: int, causal: bool):
+    """``carry = tile(masked, j, carry)`` over the forward's query block i's key
+    tiles: the interior ones in one loop with ``masked`` False, the diagonal
+    ones in a second with it True (every tile interior where not causal)."""
+    if not causal:
+        return jax.lax.fori_loop(0, n_k, functools.partial(tile, False), carry)
+    lo, hi = _diagonal_tiles(i, block_q, block_k, n_k)
+    carry = jax.lax.fori_loop(0, lo, functools.partial(tile, False), carry)
+    return jax.lax.fori_loop(lo, hi, functools.partial(tile, True), carry)
+
+
+def _count_tiles(B: int, H: int, T: int, Tk: int, block_q: int, block_k: int, causal: bool):
+    """`pallas.flash.tiles_interior` / `.tiles_diagonal`: the tiles one forward
+    call runs without and with the causal mask, counted a (batch, query head)
+    times B * H. The backward masks every tile under the causal mask and is not
+    counted."""
+    n_k = Tk // block_k
+    interior = diagonal = 0
+    for i in range(T // block_q):
+        lo, hi = _diagonal_tiles(i, block_q, block_k, n_k, min) if causal else (n_k, n_k)
+        interior, diagonal = interior + lo, diagonal + hi - lo
+    _obs.inc("pallas.flash.tiles_interior", interior * B * H)
+    _obs.inc("pallas.flash.tiles_diagonal", diagonal * B * H)
+
+
+def _flash_fwd_body(q, kv_at, o_ref, lse_ref, *, qi, block_k: int, n_k: int, causal: bool,
+                    scale: float):
+    """One query block's online softmax over its key tiles, shared by the plain
+    and rope forwards; ``kv_at(j)`` loads key tile j's K and V. Base-2 softmax:
+    log2(e) is folded into the scale of the f32 scores so the VPU runs its
+    native exp2; the running max and sum track log2 units and lse converts back
+    to natural log once. Interior tiles run with no mask; only the diagonal
+    ones build it, in a loop of their own (none where not causal).
+
+    A tile is laid out keys by queries (keys on the sublanes, queries on the
+    lanes): the max and the sum over the keys run down the sublanes, where
+    queries by keys took a cross-lane reduction of every row, and p @ v is
+    computed as v^T p, a (D, block_q) product as wide as the query block, which
+    at a head of 64 fills the MXU's 128 columns where (block_q, 64) filled half.
+    o and lse are transposed back once, at the write."""
+    block_q, D = q.shape
+    q0 = qi * block_q
     scale2 = scale * LOG2E
 
-    def body(j, carry):
-        o_acc, m, l = carry
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale2  # (bq, bk)
-        if causal:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.exp2(s - m_new[:, None])
+    def tile(masked, j, carry):
+        o_t, m, l = carry
+        k_blk, v_blk = kv_at(j)
+        # inputs stay low-precision so the dots ride the MXU's native bf16 path;
+        # accumulation is f32 via preferred_element_type, scores/softmax stay f32
+        s_t = jax.lax.dot_general(k_blk, q, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32) * scale2  # (bk, bq)
+        if masked:
+            s_t = jnp.where(_causal_keep(s_t.shape, 1, q0 - j * block_k), s_t, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s_t, axis=0, keepdims=True))  # (1, bq)
+        p_t = jnp.exp2(s_t - m_new)
         corr = jnp.exp2(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=1)
-        o_new = o_acc * corr[:, None] + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_new = l * corr + jnp.sum(p_t, axis=0, keepdims=True)
+        o_new = o_t * corr + jax.lax.dot_general(
+            v_blk, p_t.astype(v_blk.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (D, bq)
         return o_new, m_new, l_new
 
-    n_k = T // block_k
-    if causal:
-        # skip fully-masked k blocks: only blocks intersecting the causal
-        # triangle ([0, (qi+1)*block_q)) contribute
-        n_k = jnp.minimum(n_k, ((qi + 1) * block_q + block_k - 1) // block_k)
-    o0 = jnp.zeros((block_q, D), jnp.float32)
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    o, m, l = jax.lax.fori_loop(0, n_k, body, (o0, m0, l0))
+    o_t, m, l = _over_key_tiles(
+        tile, (jnp.zeros((D, block_q), jnp.float32), jnp.full((1, block_q), NEG_INF, jnp.float32),
+               jnp.zeros((1, block_q), jnp.float32)),
+        qi, block_q, block_k, n_k, causal)
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[:] = (o / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[:] = ((m + jnp.log2(l_safe)) * LN2)[:, None]
+    o_ref[:] = jnp.transpose(o_t / l_safe).astype(o_ref.dtype)
+    # a (1, bq) row to the (bq, 1) column: through an (8, bq) tile, which Mosaic transposes
+    lse = jnp.broadcast_to((m + jnp.log2(l_safe)) * LN2, (8, block_q))
+    lse_ref[:] = jnp.transpose(lse)[:, :1]
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int, causal: bool,
+                      scale: float):
+    # q_ref: (block_q, D); k_ref/v_ref: (T, D); o_ref: (block_q, D); lse_ref: (block_q, 1)
+    def kv_at(j):
+        sl = pl.ds(j * block_k, block_k)
+        return k_ref[sl, :], v_ref[sl, :]
+
+    _flash_fwd_body(q_ref[:], kv_at, o_ref, lse_ref, qi=pl.program_id(2), block_k=block_k,
+                    n_k=k_ref.shape[0] // block_k, causal=causal, scale=scale)
 
 
 def flash_attention_forward(q, k, v, *, causal: bool = True, scale=None,
-                            block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
+                            block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_Q):
     """q,k,v: (B, H, T, D) -> (o, lse). Head dims below the 128-lane tile
     (64 for llama-class models) are handled by Mosaic's implicit minor-dim
     padding in VMEM — no HBM-level zero-pad copies or doubled k/v traffic."""
@@ -158,14 +214,12 @@ def flash_attention_forward(q, k, v, *, causal: bool = True, scale=None,
     Tk = k.shape[2]
     Hkv = k.shape[1]
     g = H // Hkv  # GQA group: kv head = q head // g (1 for MHA)
-    block_q = min(block_q, T)
-    block_k = min(block_k, Tk)
-    block_q, block_k = _cap_blocks_for_dtype(q, block_q, block_k, T, Tk, k, v)
+    block_q, block_k = _cap_blocks_for_dtype(q, min(block_q, T), min(block_k, Tk), T, Tk, k, v)
     grid = (B, H, T // block_q)
+    _count_tiles(B, H, T, Tk, block_q, block_k, causal)
 
     o, lse = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, block_k=block_k, causal=causal, scale=scale,
-                          q_offset_blocks=0),
+        functools.partial(_flash_fwd_kernel, block_k=block_k, causal=causal, scale=scale),
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, None, block_q, D), lambda b, h, i: (b, h, i, 0)),
@@ -190,15 +244,15 @@ def flash_attention_forward(q, k, v, *, causal: bool = True, scale=None,
 # ===========================================================================
 
 
-def _fused_bwd_tile(q, do, lse2, delta, k_blk, v_blk, sl, k_pos_t, q_pos_t,
-                    causal, scale, dk_scr, dv_scr, dq_acc):
+def _fused_bwd_tile(q, do, lse2, delta, k_blk, v_blk, sl, q0_minus_k0, causal, scale,
+                    dk_scr, dv_scr, dq_acc):
     """One (i, j) tile of the single-pass backward, shared by the plain and
     rope fused kernels: computes s/p ONCE, accumulates dk/dv into the VMEM
     scratch slice and returns the updated dq accumulator. Transposed orientation (rows = k positions)."""
     s_t = jax.lax.dot_general(k_blk, q, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32) * (scale * LOG2E)
     if causal:
-        s_t = jnp.where(k_pos_t <= q_pos_t, s_t, NEG_INF)
+        s_t = jnp.where(_causal_keep(s_t.shape, 1, q0_minus_k0), s_t, NEG_INF)
     p_t = jnp.exp2(s_t - lse2[None, :])
     dv_c = jax.lax.dot_general(p_t.astype(do.dtype), do, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
@@ -213,6 +267,28 @@ def _fused_bwd_tile(q, do, lse2, delta, k_blk, v_blk, sl, k_pos_t, q_pos_t,
                                         preferred_element_type=jnp.float32)
 
 
+def _fused_bwd_rows(q, do, lse, delta, kv_at, dk_scr, dv_scr, *,
+                    ii, block_k: int, n_k: int, causal: bool, scale: float):
+    """One query head's block of a backward program over its key tiles
+    (``kv_at(sl)`` loads K and V at a slice), in one loop that masks every tile
+    under the causal mask and skips those wholly past the block's last query:
+    accumulates dk/dv and returns dq in f32. (Split as the forward's is, into
+    interior tiles with no mask and diagonal ones, it read about 1% slower on a
+    TPU v5e at B 4, 16 heads of 64, T 2,048.)"""
+    block_q, D = q.shape
+    lse2 = lse * LOG2E
+    if causal:
+        n_k = _diagonal_tiles(ii, block_q, block_k, n_k)[1]
+
+    def tile(j, dq_acc):
+        sl = pl.ds(j * block_k, block_k)
+        k_blk, v_blk = kv_at(sl)
+        return _fused_bwd_tile(q, do, lse2, delta, k_blk, v_blk, sl, ii * block_q - j * block_k,
+                               causal, scale, dk_scr, dv_scr, dq_acc)
+
+    return jax.lax.fori_loop(0, n_k, tile, jnp.zeros((block_q, D), jnp.float32))
+
+
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                             block_k: int, causal: bool, scale: float,
@@ -223,8 +299,6 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     contributions (f32 VMEM scratch accumulated across the i axis, written at
     the last i) — vs the two-pass design this halves the backward exp and
     QK^T work (5 dots + 1 exp per tile instead of 7 + 2)."""
-    Tk, D = k_ref.shape
-    block_q = q_ref.shape[1]
     ii = pl.program_id(2)
 
     @pl.when(ii == 0)
@@ -232,25 +306,13 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    n_j = Tk // block_k
-    if causal:
-        n_j = jnp.minimum(n_j, ((ii + 1) * block_q + block_k - 1) // block_k)
+    def kv_at(sl):
+        return k_ref[sl, :], v_ref[sl, :]
 
     for h in range(g):  # static unroll over the q-head group (1 for MHA)
-        q = q_ref[h]
-        do = do_ref[h]
-        lse2 = lse_ref[h][:, 0] * LOG2E
-        delta = delta_ref[h][:, 0]
-        q_pos_t = ii * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
-
-        def body(j, dq_acc):
-            sl = pl.ds(j * block_k, block_k)
-            k_pos_t = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
-            return _fused_bwd_tile(q, do, lse2, delta, k_ref[sl, :], v_ref[sl, :],
-                                   sl, k_pos_t, q_pos_t, causal, scale,
-                                   dk_scr, dv_scr, dq_acc)
-
-        dq = jax.lax.fori_loop(0, n_j, body, jnp.zeros((block_q, D), jnp.float32))
+        dq = _fused_bwd_rows(q_ref[h], do_ref[h], lse_ref[h][:, 0], delta_ref[h][:, 0], kv_at,
+                             dk_scr, dv_scr, ii=ii, block_k=block_k,
+                             n_k=k_ref.shape[0] // block_k, causal=causal, scale=scale)
         dq_ref[h] = dq.astype(dq_ref.dtype)
 
     @pl.when(ii == n_i - 1)
@@ -376,48 +438,21 @@ def _rope_tables(cos, sin, D: int):
 def _flash_rope_fwd_kernel(q_ref, k_ref, v_ref, cq_ref, sq_ref, ck_ref, sk_ref,
                            o_ref, lse_ref, *, block_k: int, causal: bool, scale: float,
                            n_elem: int):
-    block_q, D = q_ref.shape
-    T = k_ref.shape[0]
-    qi = pl.program_id(2)
-
     q = _rope_block(q_ref[:].astype(jnp.float32), cq_ref[:], sq_ref[:], n_elem).astype(q_ref.dtype)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
 
-    def body(j, carry):
-        o_acc, m, l = carry
-        k_blk = _rope_block(k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32),
-                            ck_ref[pl.ds(j * block_k, block_k), :],
-                            sk_ref[pl.ds(j * block_k, block_k), :], n_elem).astype(k_ref.dtype)
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :]
-        ss = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * (scale * LOG2E)
-        if causal:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            ss = jnp.where(k_pos <= q_pos, ss, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(ss, axis=1))
-        pp = jnp.exp2(ss - m_new[:, None])
-        corr = jnp.exp2(m - m_new)
-        l_new = l * corr + jnp.sum(pp, axis=1)
-        o_new = o_acc * corr[:, None] + jax.lax.dot_general(
-            pp.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return o_new, m_new, l_new
+    def kv_at(j):
+        sl = pl.ds(j * block_k, block_k)
+        k_blk = _rope_block(k_ref[sl, :].astype(jnp.float32), ck_ref[sl, :], sk_ref[sl, :],
+                            n_elem).astype(k_ref.dtype)
+        return k_blk, v_ref[sl, :]
 
-    n_k = T // block_k
-    if causal:
-        n_k = jnp.minimum(n_k, ((qi + 1) * block_q + block_k - 1) // block_k)
-    o0 = jnp.zeros((block_q, D), jnp.float32)
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    o, m, l = jax.lax.fori_loop(0, n_k, body, (o0, m0, l0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[:] = (o / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[:] = ((m + jnp.log2(l_safe)) * LN2)[:, None]
+    _flash_fwd_body(q, kv_at, o_ref, lse_ref, qi=pl.program_id(2), block_k=block_k,
+                    n_k=k_ref.shape[0] // block_k, causal=causal, scale=scale)
 
 
 def flash_rope_attention_forward(q, k, v, cos, sin, *, causal: bool = True, scale=None,
                                  block_q: int = DEFAULT_BLOCK_Q,
-                                 block_k: int = DEFAULT_BLOCK_K):
+                                 block_k: int = DEFAULT_BLOCK_Q):
     """q,k,v PRE-rope (B, H, T, D); cos/sin (T, n_elem) duplicated-half caches
     of an even rotary width n_elem <= D: the first n_elem columns of every
     head are rotated, the rest pass."""
@@ -425,10 +460,9 @@ def flash_rope_attention_forward(q, k, v, cos, sin, *, causal: bool = True, scal
     B, H, T, D = q.shape
     Hkv = k.shape[1]
     g = H // Hkv  # GQA group (1 for MHA)
-    block_q = min(block_q, T)
-    block_k = min(block_k, T)
-    block_q, block_k = _cap_blocks_for_dtype(q, block_q, block_k, T, T, k, v)
+    block_q, block_k = _cap_blocks_for_dtype(q, min(block_q, T), min(block_k, T), T, T, k, v)
     cos, sin, n_elem = _rope_tables(cos, sin, D)
+    _count_tiles(B, H, T, T, block_q, block_k, causal)
     o, lse = pl.pallas_call(
         functools.partial(_flash_rope_fwd_kernel, block_k=block_k, causal=causal, scale=scale,
                           n_elem=n_elem),
@@ -463,8 +497,6 @@ def _flash_rope_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
     """Single-pass rope backward (see _flash_bwd_fused_kernel): rope applied
     in-kernel on q/k loads, rope VJP on the dq carry at write and on the dk
     scratch at the final i."""
-    Tk, D = k_ref.shape
-    block_q = q_ref.shape[1]
     ii = pl.program_id(2)
 
     @pl.when(ii == 0)
@@ -472,28 +504,17 @@ def _flash_rope_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    n_j = Tk // block_k
-    if causal:
-        n_j = jnp.minimum(n_j, ((ii + 1) * block_q + block_k - 1) // block_k)
+    def kv_at(sl):
+        k_blk = _rope_block(k_ref[sl, :].astype(jnp.float32), ck_ref[sl, :], sk_ref[sl, :],
+                            n_elem).astype(k_ref.dtype)
+        return k_blk, v_ref[sl, :]
 
     for h in range(g):  # static unroll over the q-head group (1 for MHA)
         q = _rope_block(q_ref[h].astype(jnp.float32), cq_ref[:], sq_ref[:],
                         n_elem).astype(q_ref.dtype)
-        do = do_ref[h]
-        lse2 = lse_ref[h][:, 0] * LOG2E
-        delta = delta_ref[h][:, 0]
-        q_pos_t = ii * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
-
-        def body(j, dq_acc):
-            sl = pl.ds(j * block_k, block_k)
-            k_blk = _rope_block(k_ref[sl, :].astype(jnp.float32),
-                                ck_ref[sl, :], sk_ref[sl, :], n_elem).astype(k_ref.dtype)
-            k_pos_t = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
-            return _fused_bwd_tile(q, do, lse2, delta, k_blk, v_ref[sl, :],
-                                   sl, k_pos_t, q_pos_t, causal, scale,
-                                   dk_scr, dv_scr, dq_acc)
-
-        dq = jax.lax.fori_loop(0, n_j, body, jnp.zeros((block_q, D), jnp.float32))
+        dq = _fused_bwd_rows(q, do_ref[h], lse_ref[h][:, 0], delta_ref[h][:, 0], kv_at,
+                             dk_scr, dv_scr, ii=ii, block_k=block_k,
+                             n_k=k_ref.shape[0] // block_k, causal=causal, scale=scale)
         dq_ref[h] = _rope_vjp_block(dq, cq_ref[:], sq_ref[:], n_elem).astype(dq_ref.dtype)
 
     @pl.when(ii == n_i - 1)
@@ -688,8 +709,9 @@ def _flash_fits_vmem(kernel: str, q, k, *, rope: bool) -> bool:
     T, D, Tk, g = q.shape[-2], q.shape[-1], k.shape[-2], q.shape[1] // k.shape[1]
     q_item = jnp.dtype(str(q.dtype).rpartition(".")[2]).itemsize
     kv_item = jnp.dtype(str(k.dtype).rpartition(".")[2]).itemsize
+    # the forward's tile as it runs (its key tile defaults to the query tile)
     block_q, block_k = _budget.flash_block_cap(
-        max(q_item, kv_item), min(DEFAULT_BLOCK_Q, T), min(DEFAULT_BLOCK_K, Tk), T, Tk)
+        max(q_item, kv_item), min(DEFAULT_BLOCK_Q, T), min(DEFAULT_BLOCK_Q, Tk), T, Tk)
     fwd = _budget.flash_fwd_vmem_bytes(block_q, block_k, Tk, D, q_item, kv_item, rope=rope)
     bwd = _budget.flash_bwd_vmem_bytes(*_fused_bwd_blocks(block_q, block_k, T, Tk),
                                        Tk, D, g, q_item, kv_item, rope=rope)
